@@ -117,3 +117,35 @@ def test_key_locks_pruned_after_missed_fetch(tmp_path):
     with cache.fetch_lock(mid):
         cache.put(write_artifact(cache, mid, 10))
     assert mid in cache._key_locks
+
+
+def test_close_stops_the_eviction_worker(tmp_path):
+    """The worker used to loop forever on a sentinel nothing sent: every
+    cache ever built left a thread behind."""
+    cache = ModelDiskCache(str(tmp_path / "c"), 1 << 20)
+    worker = cache._evict_worker
+    assert worker.is_alive()
+    cache.close()
+    cache.close()  # idempotent
+    assert not worker.is_alive()
+    # an eviction after close still happens, in the evicting thread
+    for i in range(2):
+        d = tmp_path / "c" / f"m{i}" / "1"
+        d.mkdir(parents=True)
+        (d / "blob").write_bytes(b"x" * (700 << 10))
+        cache.put(Model(identifier=ModelId(f"m{i}", 1), path=str(d),
+                        size_on_disk=700 << 10))
+    assert not (tmp_path / "c" / "m0").exists()
+
+
+def test_dropped_cache_takes_its_worker_with_it(tmp_path):
+    """The worker holds its cache weakly: a cache that is dropped without
+    close() is collected, and its finalizer stops the thread."""
+    import gc
+
+    cache = ModelDiskCache(str(tmp_path / "c"), 1 << 20)
+    worker = cache._evict_worker
+    del cache
+    gc.collect()
+    worker.join(timeout=5.0)
+    assert not worker.is_alive()

@@ -83,7 +83,7 @@ def test_cold_stage_histograms_recorded(tmp_path):
 
 def test_cli_warm_populates_compile_cache(tmp_path, monkeypatch):
     """`tpuserve warm <artifact>` compiles the serving programs through the
-    real runtime and persists them in serving.compile_cache_dir — the deploy
+    real runtime and persists them in the compile cache directory — the deploy
     image bake step that turns a node's first cold load into a compile-cache
     hit (SURVEY §7 hard part (a))."""
     import os
@@ -117,11 +117,17 @@ def test_cli_warm_populates_compile_cache(tmp_path, monkeypatch):
             f for f in os.listdir(cache_dir) if not f.startswith(".")
         ] if cache_dir.exists() else []
         assert entries, "compile cache dir is empty after warm"
-        # no cache dir configured -> explicit error, not a silent no-op warm
+        # no cache dir configured -> the fixed default directory
+        # (utils/compile_cache.py), never "no cache" and never a temp name
+        from tfservingcache_tpu.utils import compile_cache
+
+        default_dir = tmp_path / "default-cache"
+        monkeypatch.setattr(compile_cache, "DEFAULT_DIR", str(default_dir))
         monkeypatch.setenv("TPUSC_SERVING_COMPILE_CACHE_DIR", "")
-        assert cli_main(["warm", art]) == 2
+        assert cli_main(["warm", art, "--batches", "1"]) == 0
+        assert jax.config.jax_compilation_cache_dir == str(default_dir)
     finally:
-        # the runtime flips the PROCESS-GLOBAL jax compilation cache dir;
+        # warm flips the PROCESS-GLOBAL jax compilation cache dir;
         # later tests' cold-compile behavior must not depend on this tmp dir
         jax.config.update("jax_compilation_cache_dir", prior_cache_dir)
         _cc.reset_cache()  # un-pin the tmp dir for later tests too

@@ -37,7 +37,6 @@ GATED_TOOLS = [
     ROOT / "tools" / "fleet_top.py",
     ROOT / "tools" / "slo_report.py",
     ROOT / "tools" / "tenant_top.py",
-    ROOT / "tools" / "tpu_bench_watcher.py",
 ]
 GATE_PATHS = [ROOT / "tfservingcache_tpu", *GATED_TOOLS]
 

@@ -16,6 +16,7 @@ import os
 import queue
 import shutil
 import threading
+import weakref
 from contextlib import contextmanager
 from typing import Callable, Iterator
 
@@ -40,6 +41,23 @@ def dir_size_bytes(path: str) -> int:
             except OSError:
                 pass
     return total
+
+
+def _evict_loop(cache_ref: "weakref.ref[ModelDiskCache]", jobs: queue.Queue) -> None:
+    """Eviction worker. ``None`` is the exit sentinel (``close()`` or the
+    cache's finalizer sends it)."""
+    while True:
+        item = jobs.get()
+        try:
+            cache = cache_ref()
+            if item is None or cache is None:
+                return
+            cache._evict_impl(*item)
+        except Exception:  # noqa: BLE001 - worker must survive bad evictions
+            log.exception("eviction failed")
+        finally:
+            cache = None  # no strong reference while blocked on the queue
+            jobs.task_done()
 
 
 @lockchecked
@@ -72,12 +90,25 @@ class ModelDiskCache:
         # another model's key lock — two concurrent misses evicting each
         # other's models would otherwise ABBA-deadlock.
         self._evict_queue: queue.Queue = queue.Queue()
+        # The worker holds this cache only weakly between jobs, and the
+        # finalizer sends its exit sentinel: close() stops the thread, and a
+        # cache that is merely dropped still takes its thread with it.
         self._evict_worker = threading.Thread(
-            target=self._evict_loop, name="tpusc-disk-evict", daemon=True
+            target=_evict_loop, args=(weakref.ref(self), self._evict_queue),
+            name="tpusc-disk-evict", daemon=True,
+        )
+        self._stop_worker = weakref.finalize(
+            self, self._evict_queue.put, None
         )
         self._evict_worker.start()
         if recover:
             self._recover_index()
+
+    def close(self) -> None:
+        """Finish the queued evictions and stop the worker. Idempotent. An
+        eviction triggered after close runs in the evicting thread."""
+        self._stop_worker()
+        self._evict_worker.join(timeout=5.0)
 
     @contextmanager
     def fetch_lock(self, model_id: ModelId) -> Iterator[None]:
@@ -159,19 +190,10 @@ class ModelDiskCache:
 
     # -- internals ----------------------------------------------------------
     def _evict(self, model_id: ModelId, entry: LRUEntry[Model]) -> None:
-        self._evict_queue.put((model_id, entry))
-
-    def _evict_loop(self) -> None:
-        while True:
-            item = self._evict_queue.get()
-            try:
-                if item is None:
-                    return
-                self._evict_impl(*item)
-            except Exception:  # noqa: BLE001 - worker must survive bad evictions
-                log.exception("eviction failed")
-            finally:
-                self._evict_queue.task_done()
+        if self._stop_worker.alive:
+            self._evict_queue.put((model_id, entry))
+        else:
+            self._evict_impl(model_id, entry)
 
     def drain_evictions(self) -> None:
         """Block until all queued evictions have completed (tests, shutdown)."""

@@ -325,7 +325,7 @@ class CacheManager:
 
                     runtime.precompile_from_meta(load_artifact_meta(local_path))
                 except Exception as e:  # noqa: BLE001 - advisory hint only
-                    log.debug("early precompile for %s skipped: %s", model_id, e)
+                    log.warning("early precompile for %s skipped: %s", model_id, e)
 
         with TRACER.span("provider_fetch", model=str(model_id)):
             size = self.provider.model_size(model_id.name, model_id.version)
@@ -438,3 +438,4 @@ class CacheManager:
             stragglers = list(self._load_workers)
         for t in stragglers:
             t.join(timeout=5.0)
+        self.disk_cache.close()

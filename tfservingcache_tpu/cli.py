@@ -48,7 +48,8 @@ def main(argv: list[str] | None = None) -> int:
     wrm = sub.add_parser(
         "warm",
         help="pre-populate the persistent XLA compile cache "
-        "(serving.compile_cache_dir) with an artifact's serving programs — "
+        "(JAX_COMPILATION_CACHE_DIR, else serving.compile_cache_dir, else "
+        "the in-checkout default) with an artifact's serving programs — "
         "bake into the deploy image so a node's FIRST cold load is a "
         "compile-cache hit (SURVEY §7: load-bearing for the <=2s target)",
     )
@@ -70,16 +71,17 @@ def main(argv: list[str] | None = None) -> int:
     cfg = load_config(args.config)
     setup_logging(cfg.logging.level, cfg.logging.fmt)
     if cfg.serving.platform:
-        # before any backend init (serve AND export both touch jax): a
-        # JAX_PLATFORMS env var alone does not beat an installed PJRT
-        # plugin's registration — only the config update reliably selects
+        # before any backend init (serve AND export both touch jax)
         import jax
 
         jax.config.update("jax_platforms", cfg.serving.platform)
 
     if args.cmd == "serve":
         from tfservingcache_tpu.server import run_server
+        from tfservingcache_tpu.utils import compile_cache
 
+        log.info("compile cache: %s",
+                 compile_cache.configure(cfg.serving.compile_cache_dir))
         run_server(cfg)
         return 0
     if args.cmd == "export":
@@ -139,14 +141,9 @@ def _warm(cfg, args) -> int:
     from tfservingcache_tpu.cache.disk_cache import dir_size_bytes
     from tfservingcache_tpu.runtime.model_runtime import TPUModelRuntime
     from tfservingcache_tpu.types import Model, ModelId
+    from tfservingcache_tpu.utils import compile_cache
 
-    if not cfg.serving.compile_cache_dir:
-        log.error(
-            "serving.compile_cache_dir is not set: there is no persistent "
-            "cache to warm (set it in config.yaml or TPUSC_SERVING_"
-            "COMPILE_CACHE_DIR)"
-        )
-        return 2
+    cache_dir = compile_cache.configure(cfg.serving.compile_cache_dir)
     art = os.path.abspath(args.artifact)
     version_s = os.path.basename(art)
     name = os.path.basename(os.path.dirname(art))
@@ -197,7 +194,7 @@ def _warm(cfg, args) -> int:
     dt = time.perf_counter() - t0
     print(
         f"warmed {mid} ({family}): {', '.join(compiled)} in {dt:.1f}s -> "
-        f"{cfg.serving.compile_cache_dir}"
+        f"{cache_dir}"
     )
     return 0
 

@@ -31,7 +31,10 @@ class ServingConfig:
     max_concurrent_models: int = 16        # models resident in HBM at once
     hbm_capacity_bytes: int = 8 << 30      # HBM byte budget for pinned params
     warmup: bool = True                    # run one predict to pin+compile on load
-    compile_cache_dir: str = ""            # persistent XLA compile cache ("" = off)
+    # persistent XLA compile cache directory. JAX_COMPILATION_CACHE_DIR, when
+    # set, wins over this; "" = the fixed in-checkout default
+    # (utils/compile_cache.py holds the rule)
+    compile_cache_dir: str = ""
     # cold-load (fetch+compile) deadline; 0 disables. The reference hardcodes
     # a 10 s fetch timeout (main.go:122); XLA first-compiles can take longer,
     # so the default is looser. Enforced by CacheManager.ensure_servable.

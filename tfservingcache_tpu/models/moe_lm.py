@@ -95,7 +95,7 @@ def _moe_block(params: dict, x: jax.Array, cfg: dict) -> tuple[jax.Array, jax.Ar
     return y.reshape(b, s, d), aux
 
 
-def _forward(params: dict, input_ids: jax.Array, cfg: dict) -> tuple[jax.Array, jax.Array]:
+def _forward(params: dict, input_ids: jax.Array, cfg: dict, mesh=None) -> tuple[jax.Array, jax.Array]:  # static-bounded: mesh -- one Mesh object per runtime lifetime
     dtype = jnp.dtype(cfg["dtype"])
     x = params["embed"][input_ids].astype(dtype)
     aux_total = jnp.zeros((), jnp.float32)
@@ -104,6 +104,7 @@ def _forward(params: dict, input_ids: jax.Array, cfg: dict) -> tuple[jax.Array, 
             jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"]),
             _rmsnorm(x, layer["ln1"]),
             cfg,
+            mesh,
         )
         moe_params = {
             "router": layer["moe"]["router"],  # stays f32 inside the block
@@ -122,9 +123,16 @@ def _forward(params: dict, input_ids: jax.Array, cfg: dict) -> tuple[jax.Array, 
 def build(config: dict) -> ModelDef:
     cfg = config
 
-    def apply(params, inputs):
-        logits, _ = _forward(params, inputs["input_ids"].astype(jnp.int32), cfg)
-        return {"logits": logits}
+    def make_apply(mesh=None):
+        def apply(params, inputs):
+            logits, _ = _forward(
+                params, inputs["input_ids"].astype(jnp.int32), cfg, mesh
+            )
+            return {"logits": logits}
+
+        return apply
+
+    apply = make_apply(None)
 
     def init(rng):
         d, v, ff, e = cfg["d_model"], cfg["vocab_size"], cfg["d_ff"], cfg["n_experts"]
@@ -209,4 +217,7 @@ def build(config: dict) -> ModelDef:
         # the box, full (B, S, V) logits via output_filter=["logits"]
         default_outputs=["last_token_logits"],
         store_param_dtype=cfg["dtype"],
+        # the shared attention block must know when it is traced into a
+        # chip group's partitioned program (transformer_lm._attention_block)
+        bind_mesh=make_apply,
     )

@@ -99,8 +99,11 @@ def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None) -> jax.Ar
         out = ring_attention(q, k, v, mesh, axis="model", causal=True)
     else:
         # GQA handled inside attention (grouped K/V, never materialized via
-        # repeat — that would negate GQA's HBM saving at llama-7b scale)
-        out = attention(q, k, v, causal=True)                           # (b,h,s,hd)
+        # repeat — that would negate GQA's HBM saving at llama-7b scale).
+        # On a chip group this block is traced into a GSPMD-partitioned
+        # program, which the gate must know: it cannot see it from shapes.
+        out = attention(q, k, v, causal=True,
+                        partitioned=mesh is not None and mesh.size > 1)  # (b,h,s,hd)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, d_model)
     return out @ params["wo"]
 
@@ -249,6 +252,8 @@ def build(config: dict) -> ModelDef:
         # apply casts weights to cfg dtype anyway; storing them f32 doubled
         # the cold-path transfer (round-2 cold p50 3.14 s was ~80% device_put)
         store_param_dtype=cfg["dtype"],
-        # ring mode needs the serving group's mesh inside the computation
-        bind_mesh=make_apply if ring else None,
+        # the computation must know the serving group's mesh: ring mode
+        # shards the sequence over it, and plain TP must keep the bare flash
+        # kernel out of a partitioned program (_attention_block)
+        bind_mesh=make_apply,
     )

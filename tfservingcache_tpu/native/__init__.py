@@ -10,10 +10,12 @@ pkg/cachemanager/lrucache.go); here the equivalent hot-path pieces are C++
   - byte-budgeted LRU index (``NativeLRUCache`` — same semantics as
     ``cache.lru.LRUCache``)
 
-Loading order: prebuilt ``libtpusc_native.so`` next to this file, else a
-one-shot ``make`` build if a toolchain exists, else ``load()`` returns None
-and callers fall back to the pure-Python implementations.  Set
-``TPUSC_NO_NATIVE=1`` to force the fallback.
+Loading order: ``make`` (re)builds ``libtpusc_native.so`` next to this file
+from ``src/tpusc_native.cc`` when a toolchain exists (the library is a build
+product, never committed); an existing library is used when the toolchain is
+gone; else ``load()`` returns None and callers use the pure-Python
+implementations. Which tier serves is logged once. Set ``TPUSC_NO_NATIVE=1``
+to force the Python tier.
 """
 
 from __future__ import annotations
@@ -27,6 +29,9 @@ from typing import Any, Callable, Generic, Hashable, Iterator, TypeVar
 from tfservingcache_tpu.cache.lru import CapacityError, LRUEntry
 
 from tfservingcache_tpu.utils.lockcheck import lockchecked
+from tfservingcache_tpu.utils.logging import get_logger
+
+log = get_logger("native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libtpusc_native.so")
@@ -129,6 +134,7 @@ def load() -> ctypes.CDLL | None:
             return _lib
         _load_attempted = True
         if os.environ.get("TPUSC_NO_NATIVE"):
+            log.info("native tier off (TPUSC_NO_NATIVE): pure-Python tier")
             return None
         # Always (re)run make when a toolchain exists — it no-ops when the .so
         # is current and rebuilds after source edits, so a stale library can't
@@ -141,15 +147,19 @@ def load() -> ctypes.CDLL | None:
                 capture_output=True,
                 timeout=120,
             )
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as e:
             if not os.path.exists(_LIB_PATH):
+                log.warning("native tier did not build (%s): pure-Python "
+                            "tier", e)
                 return None
         try:
             _lib = _bind(ctypes.CDLL(_LIB_PATH))
-        except (OSError, AttributeError):
+        except (OSError, AttributeError) as e:
             # AttributeError: a stale prebuilt .so predating a newer symbol
             # (no toolchain to rebuild) must not take down the whole tier
+            log.warning("native tier did not load (%s): pure-Python tier", e)
             return None
+        log.info("native tier: %s", _LIB_PATH)
         return _lib
 
 

@@ -45,19 +45,3 @@ def chip_groups(devices, group_size: int) -> list[list]:
 def group_mesh(devices, group_size: int, group_index: int, axis: str = "model") -> Mesh:
     groups = chip_groups(devices, group_size)
     return Mesh(np.array(groups[group_index]), (axis,))
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across the jax versions this repo meets: the
-    top-level alias (with its ``check_vma`` knob) postdates 0.4.x, where
-    the API lives at ``jax.experimental.shard_map.shard_map`` and the
-    same knob is spelled ``check_rep``."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)

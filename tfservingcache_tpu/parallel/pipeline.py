@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tfservingcache_tpu.parallel.mesh import compat_shard_map
-
 
 def stack_stage_params(stage_params: list[Any]) -> Any:
     """Stack per-stage pytrees into one pytree with leading dim n_stages
@@ -113,7 +111,7 @@ def pipeline_apply(
     mb = x.shape[0] // n_micro
     xm = x.reshape((n_micro, mb) + x.shape[1:])
 
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _pipeline_shard_fn,
             stage_fn=stage_fn,

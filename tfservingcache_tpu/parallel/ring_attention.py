@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tfservingcache_tpu.parallel.mesh import compat_shard_map
-
 NEG_INF = -1e30
 
 
@@ -98,19 +96,25 @@ def _ring_shard_fn(q, k, v, *, axis: str, n_shards: int, causal: bool,  # static
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
-def _pick_impl(impl: str, s_local: int, d: int) -> str:
+def _pick_impl(impl: str, s_local: int, heads: int, d: int) -> str:
     """"auto": Pallas carry kernel on TPU when the shard shape qualifies
-    (128-multiple local seq, MXU-friendly head dim), einsum elsewhere."""
+    (128-multiple local seq, MXU-friendly head dim), einsum elsewhere. The
+    choice is recorded (ops.attention.dispatch_tally)."""
     if impl != "auto":
         return impl
-    from tfservingcache_tpu.ops.attention import TPU_BACKENDS
+    from tfservingcache_tpu.ops.attention import (
+        _kernel_refusal,
+        _record_dispatch,
+    )
 
-    if (
-        jax.default_backend() in TPU_BACKENDS
-        and s_local % 128 == 0
-        and d % 64 == 0
-    ):
+    why = _kernel_refusal(d, heads, heads)
+    if why is None and s_local % 128:
+        why = f"local seq {s_local} not a multiple of 128"
+    if why is None:
+        _record_dispatch("ring_attention", "kernel", "flash_carry",
+                         (s_local, heads, d))
         return "flash"
+    _record_dispatch("ring_attention", "reference", why, (s_local, heads, d))
     return "xla"
 
 
@@ -132,9 +136,9 @@ def ring_attention(
     n_shards = mesh.shape[axis]
     if q.shape[2] % n_shards:
         raise ValueError(f"sequence {q.shape[2]} not divisible by {n_shards} ring shards")
-    impl = _pick_impl(impl, q.shape[2] // n_shards, q.shape[3])
+    impl = _pick_impl(impl, q.shape[2] // n_shards, q.shape[1], q.shape[3])
     spec = P(None, None, axis, None)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_shard_fn, axis=axis, n_shards=n_shards,
                           causal=causal, impl=impl, interpret=interpret),
         mesh=mesh,

@@ -23,9 +23,7 @@ The accumulation window is therefore exactly the device's own busy time:
 
 Whether coalescing wins over independent dispatch is an empirical, shape-
 dependent question — bench.py measures warm QPS batcher on vs off with
-varied payloads; round 2's "batcher loses 31%" verdict was measured with
-identical repeated payloads a transport cache could answer, so trust only
-the varied-payload numbers.
+varied payloads.
 
 Calls are thread-blocking by design — they arrive on the protocol backend's
 executor threads (protocol/local_backend.py), never on the event loop.

@@ -926,11 +926,6 @@ class TPUModelRuntime(BaseRuntime):
         self.metrics = metrics
         self.mesh = mesh  # jax.sharding.Mesh for multi-chip models (parallel/)
         self.group = group  # chip-group index on this host (metrics label)
-        if self.cfg.compile_cache_dir:
-            # persistent XLA compile cache: restart != recompile-the-world
-            # (SURVEY.md §5 checkpoint/resume note)
-            jax.config.update("jax_compilation_cache_dir", self.cfg.compile_cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
         # LOCAL devices: in a multi-controller (cross-host) deployment
         # jax.devices() includes peers' non-addressable chips — the
         # single-device path and health probe must stay on this process's own
@@ -1546,7 +1541,7 @@ class TPUModelRuntime(BaseRuntime):
                     return  # family executable already live: nothing to hide
             self._precompile_async(model_def, abs_params)
         except Exception as e:  # noqa: BLE001 - advisory only
-            log.debug("early precompile skipped: %s", e)
+            log.warning("early precompile skipped: %s", e)
 
     def _warmup_sig(self, model_def: ModelDef) -> tuple:
         return tuple(sorted(

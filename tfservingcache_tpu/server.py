@@ -172,6 +172,8 @@ class CacheNode:
                     host_tier_bytes=cfg.cache.host_tier_bytes,
                 ))]
 
+        if runtime is None:
+            self._log_device_use(runtimes)
         self.groups: list[ServingGroup] = []
         for pos, (i, rt) in enumerate(runtimes):
             manager = CacheManager(
@@ -251,6 +253,34 @@ class CacheNode:
             self.groups.append(group)
         self._health_task: asyncio.Task | None = None
 
+    def _log_device_use(self, runtimes) -> None:
+        """Say at start how much of the host this node drives: the default
+        ``mesh.chips_per_group: 1`` builds ONE runtime that places everything
+        on the first local device, whatever the host holds."""
+        import jax
+
+        used = set()
+        for _i, rt in runtimes:
+            mesh = getattr(rt, "mesh", None)
+            if mesh is not None:
+                used.update(d for d in mesh.devices.flat
+                            if d.process_index == jax.process_index())
+            else:
+                used.update(getattr(rt, "_devices", [])[:1])
+        local = jax.local_devices()
+        log.info(
+            "using %d of %d local %s devices in %d chip group(s) "
+            "(mesh.chips_per_group=%d)",
+            len(used), len(local), local[0].platform, len(runtimes),
+            self.cfg.mesh.chips_per_group,
+        )
+        if len(used) < len(local):
+            log.warning(
+                "%d local devices stay idle: one runtime serves from one "
+                "device or one chip group; set mesh.chips_per_group to shard "
+                "models over more chips", len(local) - len(used),
+            )
+
     # group-0 aliases: the single-group shape most callers/tests use
     @property
     def manager(self) -> CacheManager:
@@ -314,6 +344,7 @@ class CacheNode:
             await self.work_server.close()
         for mgr in self._follower_managers:
             mgr.close()
+        self.disk_cache.close()
         close_provider = getattr(self.provider, "close", None)
         if close_provider is not None:
             close_provider()
@@ -380,9 +411,7 @@ async def serve(cfg: Config) -> None:
 
 def run_server(cfg: Config) -> None:
     if cfg.serving.platform:
-        # must happen before backend init: a JAX_PLATFORMS env var alone does
-        # not beat an installed PJRT plugin's registration (see conftest.py) —
-        # only the config update reliably selects the platform
+        # must happen before backend init
         import jax
 
         jax.config.update("jax_platforms", cfg.serving.platform)

@@ -1,15 +1,18 @@
 #!/usr/bin/env python
-"""Run the TPU-gated kernel tests on real hardware.
+"""Run the TPU-gated kernel tests on the chip.
 
 The CPU test harness (tests/conftest.py) pins JAX to a virtual CPU mesh, so
-the hardware proofs in tests/test_attention.py are skipped there. This tool
-re-runs them with the real backend enabled:
+the hardware proofs in tests/test_attention.py and tests/test_paged_kernel.py
+are skipped there. This tool re-runs them with the real backend:
 
     python tools/tpu_kernel_check.py            # kernel tests only
     python tools/tpu_kernel_check.py -k gqa     # extra pytest args pass through
 
-Exit code is pytest's — 0 means the Pallas kernel compiled via Mosaic,
-matched the jnp reference, and beat it at every gated shape.
+This parent never imports JAX: a chip belongs to one process, and the pytest
+child is the one that needs it. Exit code is pytest's — 0 means every Pallas
+kernel compiled via Mosaic and matched the jnp reference at every gated
+shape. Without a TPU the child refuses to start (tests/conftest.py): a check
+whose every test skipped must not read as green.
 """
 
 from __future__ import annotations
@@ -24,20 +27,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def main() -> int:
     env = dict(os.environ)
     env["TPUSC_TEST_ON_TPU"] = "1"  # tests/conftest.py skips the CPU pinning
-    if env.get("JAX_PLATFORMS") == "cpu":
-        del env["JAX_PLATFORMS"]
     extra = sys.argv[1:]
     if not extra:
         # default: just the hardware-gated proofs. The interpret-mode tests'
         # 2e-5 tolerances are calibrated for CPU math and would spuriously
         # fail against the MXU's bf16-pass f32 matmuls.
         extra = ["-k", "on_tpu"]
-    # -s: the gated tests print per-shape flash/jnp ms + TF/s — the artifact
-    # must carry the measured magnitudes, not just PASS/FAIL (VERDICT r3
-    # missing #2: "commit magnitudes, not verdicts"). test_paged_kernel.py
-    # carries the `paged_decode` entries: kernel-vs-gather+einsum max-abs-err
-    # and the bandwidth-proxy timing ratio at S in {4,16,32} lanes, plus the
-    # int8 in-kernel dequant proof.
+    # -s: the gated tests print per-shape errors and timings — the output
+    # must carry the measured magnitudes, not just PASS/FAIL.
+    # test_paged_kernel.py carries the paged entries: the bring-up matrix
+    # (decode and verify x bf16/int8 x GQA group 1/2/4 x head_dim 64/128,
+    # compile + parity) and the kernel-vs-gather+einsum timing at S in
+    # {4,16,32} lanes (printed, not asserted).
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
