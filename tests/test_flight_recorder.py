@@ -61,6 +61,7 @@ def _clean_recorder():
     yield
     RECORDER.clear()
     RECORDER.configure(flight_dir="")
+    RECORDER.metrics = None
 
 
 # -- ring semantics -----------------------------------------------------------
@@ -388,11 +389,12 @@ def test_slo_breach_dumps_once_and_phases_reconcile(tmp_path):
     eng = ContinuousGenerateEngine(rt, slots=2, chunk_tokens=2, metrics=metrics)
     old_threshold = TRACER.slow_threshold_s
     old_hook = TRACER.slow_hook
-    RECORDER.configure(flight_dir=str(flight))
+    RECORDER.configure(flight_dir=str(flight), metrics=metrics)
     RECORDER.install_slow_hook(TRACER)
     TRACER.configure(slow_threshold_s=1e-6)
     try:
-        with TRACER.span("rest", path="/v1/models/lm:generate"):
+        with TRACER.span("rest", path="/v1/models/lm:generate") as sp:
+            sp.attrs["model"] = str(mid)   # the backend stamps it on the root
             eng.generate(mid, np.array([[3, 5, 7]], np.int32), max_new_tokens=6)
         dumps = [f for f in os.listdir(flight) if "slo_breach" in f]
         assert len(dumps) == 1, dumps
@@ -408,6 +410,19 @@ def test_slo_breach_dumps_once_and_phases_reconcile(tmp_path):
             assert got == pytest.approx(want, abs=1e-3), phase
         # the ring made it into the dump too
         assert payload["models"][str(mid)]["recorded_steps"] > 0
+        # a second breach of the same model inside the cooldown writes
+        # nothing and counts as suppressed
+        with TRACER.span("rest", path="/v1/models/lm:generate") as sp:
+            sp.attrs["model"] = str(mid)
+            eng.generate(mid, np.array([[3, 5, 7]], np.int32), max_new_tokens=6)
+        assert len([f for f in os.listdir(flight) if "slo_breach" in f]) == 1
+
+        def dumps(outcome):
+            return metrics.registry.get_sample_value(
+                "tpusc_flight_dumps_total",
+                {"reason": "slo_breach", "outcome": outcome})
+        assert dumps("written") == 1
+        assert dumps("suppressed") == 1
     finally:
         TRACER.slow_hook = old_hook
         TRACER.configure(slow_threshold_s=old_threshold)
@@ -416,17 +431,33 @@ def test_slo_breach_dumps_once_and_phases_reconcile(tmp_path):
 
 
 def test_dump_dedup_cooldown_and_spool_bound(tmp_path):
+    metrics = Metrics()
     fr = FlightRecorder(flight_dir=str(tmp_path), max_dumps=3,
                         dump_cooldown_s=60.0)
+    fr.configure(metrics=metrics)
     fr.record("m@1", "continuous", 1.0, 8, 4, 1, 0)
-    # dedup key: one incident = one file
-    assert fr.dump("slo_breach", dedup_key=("slo", "t1")) is not None
-    assert fr.dump("slo_breach", dedup_key=("slo", "t1")) is None
-    assert fr.dump("slo_breach", dedup_key=("slo", "t2")) is not None
-    # cooldown per (reason, model)
+    # dedup key: one incident = at most one file ...
+    assert fr.dump("slo_breach", dedup_key=("slo", "t1"), model="m@1") is not None
+    assert fr.dump("slo_breach", dedup_key=("slo", "t1"), model="m@1") is None
+    # ... and a keyed dump obeys the (reason, model) cooldown like any other:
+    # a second breach of the same model inside it writes nothing
+    assert fr.dump("slo_breach", dedup_key=("slo", "t2"), model="m@1") is None
+    assert fr.dump("slo_breach", dedup_key=("slo", "t3"), model="other@1") is not None
+    # cooldown per (reason, model), unkeyed
     assert fr.dump("page_exhaustion", model="m@1") is not None
     assert fr.dump("page_exhaustion", model="m@1") is None
     assert fr.dump("page_exhaustion", model="other@1") is not None
+    # both outcomes are counted
+    def dumps(reason, outcome):
+        return metrics.registry.get_sample_value(
+            "tpusc_flight_dumps_total", {"reason": reason, "outcome": outcome})
+    assert dumps("slo_breach", "written") == 2
+    assert dumps("slo_breach", "suppressed") == 2
+    assert dumps("page_exhaustion", "written") == 2
+    assert dumps("page_exhaustion", "suppressed") == 1
+    # once the cooldown has passed the same model dumps again
+    fr.dump_cooldown_s = 0.0
+    assert fr.dump("slo_breach", dedup_key=("slo", "t4"), model="m@1") is not None
     # spool bounded at max_dumps, oldest pruned
     for i in range(4):
         assert fr.dump("engine_crash", dedup_key=("c", i)) is not None
@@ -628,3 +659,78 @@ async def test_engine_dump_tool_marks_unknown_model(capsys):
         assert "no such model" not in out and "real@1" in out
     finally:
         await rest.close()
+
+
+# -- the boundary split (ISSUE 23) ---------------------------------------------
+
+def test_ring_split_fields_sit_at_the_end():
+    """Appended, never inserted: the 16 older names keep their positions
+    (older dumps and tools index by them), the split is the last three."""
+    assert STEP_FIELDS[-3:] == ("prefill_ms", "chunk_ms", "emit_ms")
+    assert STEP_FIELDS[:3] == ("t_wall", "engine", "step_ms")
+    assert STEP_FIELDS[14:16] == ("drafted", "accepted")
+    fr = FlightRecorder()
+    fr.record("m@1", "continuous", step_ms=9.0, chunk=8, active=4, admitted=1,
+              retired=0, prefill_ms=2.0, chunk_ms=5.0, emit_ms=0.5)
+    step = fr.snapshot()["models"]["m@1"]["steps"][0]
+    assert list(step) == list(STEP_FIELDS)
+    assert (step["prefill_ms"], step["chunk_ms"], step["emit_ms"]) == (2.0, 5.0, 0.5)
+
+
+def test_stub_engine_split_sums_under_step_ms():
+    """prefill_ms + chunk_ms + emit_ms are parts of step_ms on every
+    boundary: what is left is the engine's self time, never negative."""
+    slots = 4
+    eng = ContinuousGenerateEngine(_StubRuntime(slots), slots=slots, chunk_tokens=4)
+    try:
+        eng.generate(ModelId("stub", 1), np.ones((12, 4), np.int32),
+                     max_new_tokens=9)
+    finally:
+        eng.close()
+    steps = RECORDER.snapshot(tail=RECORDER.ring_entries)["models"]["stub@1"]["steps"]
+    assert any(s["chunk"] > 0 for s in steps) and any(s["admitted"] for s in steps)
+    for s in steps:
+        parts = s["prefill_ms"] + s["chunk_ms"] + s["emit_ms"]
+        assert min(s["prefill_ms"], s["chunk_ms"], s["emit_ms"]) >= 0.0
+        # each field is rounded to 1e-4 ms on its own
+        assert parts <= s["step_ms"] + 4e-4, s
+        if s["chunk"] > 0:
+            assert s["chunk_ms"] > 0.0
+        if s["admitted"]:
+            assert s["prefill_ms"] > 0.0
+
+
+def test_sixteen_field_dump_still_renders(tmp_path, capsys):
+    """A dump written before the split (16-field tuples in its ring) goes
+    through the zip fallback and the tool prints it without the split."""
+    fr = FlightRecorder(flight_dir=str(tmp_path))
+    old = (time.time(), "continuous", 1.5, 8, 4, 1, 1, 3, 5, 2, 1, 2.0, 1, 1, 0, 0)
+    assert len(old) == 16
+    ring = fr._ring("m@1")
+    ring.append(old)
+    fr.record("m@1", "continuous", step_ms=2.5, chunk=8, active=4, admitted=0,
+              retired=0, prefill_ms=0.0, chunk_ms=2.0, emit_ms=0.25)
+    steps = fr.snapshot()["models"]["m@1"]["steps"]
+    assert "chunk_ms" not in steps[0] and steps[0]["accepted"] == 0
+    assert steps[1]["chunk_ms"] == 2.0
+    path = fr.dump("slo_breach", dedup_key=("slo", "old"))
+    mod = _load_engine_dump_module()
+    assert mod.main([path]) == 0
+    out = capsys.readouterr().out
+    assert "step=    1.50ms chunk=  8" in out          # the 16-field row
+    assert "(prefill=0.00 chunk=2.00 emit=0.25 self=0.25)" in out
+
+
+def test_record_with_split_fields_under_50us():
+    """The three more fields ride inside record()'s budget."""
+    fr = FlightRecorder()
+    per_rec = []
+    for _ in range(10):
+        t0 = time.perf_counter()
+        for _ in range(1000):
+            fr.record("m@1", "continuous", step_ms=1.0, chunk=8, active=4,
+                      admitted=1, retired=1, pages_used=3, pages_free=5,
+                      wasted=2, queue_depth=1, oldest_wait_ms=2.0,
+                      prefill_ms=0.31234, chunk_ms=0.54321, emit_ms=0.01234)
+        per_rec.append((time.perf_counter() - t0) / 1000)
+    assert statistics.median(per_rec) < 50e-6, per_rec

@@ -582,11 +582,13 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_host_tier_bytes",
     "tpusc_host_tier_bytes_peak",
     "tpusc_fleet_model_replicas",
+    "tpusc_flight_dumps",
     "tpusc_model_replicas_target",
     "tpusc_models_resident",
     "tpusc_peer_fetch_bytes",
     "tpusc_peer_health_score",
     "tpusc_peer_status_age_seconds",
+    "tpusc_pool_wait_seconds",
     "tpusc_reload_source",
     "tpusc_prefix_cache_bytes",
     "tpusc_prefix_cache_hits",

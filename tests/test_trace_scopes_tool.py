@@ -1,0 +1,96 @@
+"""tools/trace_scopes.py on a small recorded capture: a toy program with the
+program's scope names and ``tpusc.*`` annotations, 3 launches on one v5e chip
+(my chip run, PR 23). ``toy_v5e_rows.json`` is what ``load`` gave for
+``toy_v5e.xplane.pb.gz`` (operation names cut at `` = ``): the reductions run
+on the rows, the loader on the capture itself."""
+
+import importlib.util
+import json
+import os
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+DATA = os.path.join(HERE, "data")
+
+
+@pytest.fixture(scope="module")
+def tool():
+    spec = importlib.util.spec_from_file_location(
+        "trace_scopes", os.path.join(HERE, "..", "tools", "trace_scopes.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def rows():
+    with open(os.path.join(DATA, "toy_v5e_rows.json")) as f:
+        return [tuple(r) for r in json.load(f)]
+
+
+def test_device_seconds_by_program(tool, rows):
+    assert tool.by_program(rows) == [("jit_step", pytest.approx(332.688e-6), 3)]
+
+
+def test_device_seconds_by_scope_names_each_operations_owner(tool, rows):
+    table = tool.by_scope(rows)
+    by_key = {r[:3]: r[3:] for r in table}
+    assert by_key[("jit_step", "layer/attn", "convolution_tanh_fusion")] == (
+        pytest.approx(138.809e-6), 12)
+    assert by_key[("jit_step", "layer/kv_write", "dynamic-update-slice")][1] == 12
+    assert by_key[("jit_step", "lm_head", "convolution_reduce_fusion")][1] == 3
+    # what the compiler added carries no scope, and says so
+    assert by_key[("jit_step", "(no scope)", "copy-done")][1] == 6
+    # the while wrapper spans its children and is left out
+    assert not [r for r in table if r[2] == "while"]
+    assert [r[3] for r in table] == sorted((r[3] for r in table), reverse=True)
+    total = sum(r[3] for r in table)
+    assert total == pytest.approx(tool.by_program(rows)[0][1], rel=0.05)
+
+
+def test_clock_shift_is_bounded_by_every_launch(tool, rows):
+    lo, hi, launches = tool.clock_shift_ns(rows)
+    assert (lo, hi, launches) == (1264245, 1691159, 3)
+    # without the runtime's launch events the capture cannot say
+    quiet = [r for r in rows if r[2] not in ("DoEnqueueProgram", "CompleteCallbacks")]
+    assert tool.clock_shift_ns(quiet) == (0, 0, 0)
+
+
+def test_idle_time_goes_to_the_innermost_annotation_open_meanwhile(tool, rows):
+    lo, hi, _ = tool.clock_shift_ns(rows)
+    table = {r[0]: r[1:] for r in tool.idle_by_annotation(rows, (lo + hi) // 2)}
+    # two gaps between three launches: the host slept 2 ms in tpusc.emit in each
+    assert table["tpusc.emit"] == (pytest.approx(5.04e-3, rel=0.02), 2)
+    assert 1.0e-3 < table["tpusc.decode_chunk"][0] < 2.5e-3
+    assert table["tpusc.boundary"][0] < 1e-4
+    # unshifted, the device's events sit before the host launched them: the
+    # same idle time lands elsewhere, which is why the shift is applied
+    raw = {r[0]: r[1] for r in tool.idle_by_annotation(rows)}
+    assert raw.get("tpusc.emit", 0.0) != pytest.approx(table["tpusc.emit"][0], rel=0.02)
+    assert tool.idle_by_annotation([]) == []
+
+
+def test_loader_reads_scopes_off_the_capture(tool, rows):
+    loaded = tool.load(os.path.join(DATA, "toy_v5e.xplane.pb.gz"))
+    assert len(loaded) == len(rows)
+    cut = [(p, l, n.split(" = ")[0], s, d, x) for p, l, n, s, d, x in loaded]
+    assert cut == rows
+    scopes = {x["scope"] for *_r, x in loaded if x.get("scope")}
+    assert "jit(step)/while/body/closed_call/layer/attn/dot_general" in scopes
+    assert {n for _p, _l, n, *_r in loaded if n.startswith("tpusc.")} == {
+        "tpusc.boundary", "tpusc.decode_chunk", "tpusc.emit"}
+
+
+def test_wire_reader_and_name_helpers(tool):
+    # field 1 varint 300, field 2 bytes "ab", field 3 fixed32
+    msg = bytes([0x08, 0xAC, 0x02, 0x12, 0x02]) + b"ab" + bytes([0x1D, 1, 0, 0, 0])
+    assert list(tool.wire_fields(msg)) == [(1, 300), (2, b"ab"), (3, b"\x01\x00\x00\x00")]
+    assert tool.short("%fusion.12 = bf16[8]{0} fusion(%p)") == "fusion"
+    assert tool.short("jit__paged_decode_chunk_jit(123456)") == "jit__paged_decode_chunk_jit"
+    assert tool.short("%paged_decode_attention_kernel.3") == "paged_decode_attention_kernel"
+    assert tool.scope_of("jit(f)/jit(main)/while/body/closed_call/layer/kv_write/scatter") \
+        == "layer/kv_write"
+    assert tool.scope_of("jit(f)/transpose") == "(no scope)"
+    assert tool.scope_of(None) == "(no scope)"
+    assert tool.main([]) == 2

@@ -59,6 +59,7 @@ def init_cache(cfg: dict, batch: int, max_len: int, mesh=None) -> dict:
     return cache
 
 
+@jax.named_scope("sample")
 def _sample(logits, rng, temperature, top_k):
     """logits (B, V) -> token ids (B,).
 
@@ -203,6 +204,7 @@ def _generate_from_cache_jit(
     return toks
 
 
+@jax.named_scope("sample")
 def _sample_per_row(logits, rng, temperature, top_k):
     """Per-row sampling params: logits (S, V), temperature (S,) f32,
     top_k (S,) i32 -> token ids (S,). The continuous engine packs unrelated
@@ -312,6 +314,7 @@ def _sample_logits_jit(last, rng, temperature, top_k):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
+@jax.named_scope("kv_write")
 def _slot_insert_jit(slot_k, slot_v, pk, pv, idx):
     """Copy one admitted request's prefill K/V (layers, 1, n_kv, P_pad, hd)
     into slot row ``idx`` of the slot array (layers, S, n_kv, max_seq, hd).
@@ -472,51 +475,62 @@ def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
     off = pos % page_tokens
     quantized = "k_scale" in cache
 
-    x = params["embed"][tok[:, None]].astype(dtype)              # (S, 1, d)
+    with jax.named_scope("embed"):
+        x = params["embed"][tok[:, None]].astype(dtype)          # (S, 1, d)
     new_k, new_v, new_ks, new_vs = [], [], [], []
     for li, layer in enumerate(params["layers"]):
-        attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-        h = _rmsnorm(x, layer["ln1"])
-        q = (h @ attn["wq"]).reshape(s_lanes, 1, n_heads, head_dim).transpose(0, 2, 1, 3)
-        k = (h @ attn["wk"]).reshape(s_lanes, 1, n_kv, head_dim).transpose(0, 2, 1, 3)
-        v = (h @ attn["wv"]).reshape(s_lanes, 1, n_kv, head_dim).transpose(0, 2, 1, 3)
-        q = _rope_per_example(q, positions, cfg["rope_theta"])
-        k = _rope_per_example(k, positions, cfg["rope_theta"])
+        with jax.named_scope("layer"):
+            with jax.named_scope("attn"):
+                attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
+                h = _rmsnorm(x, layer["ln1"])
+                q = (h @ attn["wq"]).reshape(s_lanes, 1, n_heads, head_dim).transpose(0, 2, 1, 3)
+                k = (h @ attn["wk"]).reshape(s_lanes, 1, n_kv, head_dim).transpose(0, 2, 1, 3)
+                v = (h @ attn["wv"]).reshape(s_lanes, 1, n_kv, head_dim).transpose(0, 2, 1, 3)
+                q = _rope_per_example(q, positions, cfg["rope_theta"])
+                k = _rope_per_example(k, positions, cfg["rope_theta"])
+            with jax.named_scope("kv_read"):
+                # this layer's slice of the arena
+                k_layer, v_layer = cache["k"][li], cache["v"][li]
 
-        # scatter each lane's single new row into its current page; lanes
-        # parked on the trash page may collide — last-writer-wins junk that
-        # no live lane's block table can reach
-        k_row, v_row = k[:, :, 0, :], v[:, :, 0, :]              # (S, n_kv, hd)
-        ks_arena = vs_arena = None
+            # scatter each lane's single new row into its current page; lanes
+            # parked on the trash page may collide — last-writer-wins junk that
+            # no live lane's block table can reach
+            with jax.named_scope("kv_write"):
+                k_row, v_row = k[:, :, 0, :], v[:, :, 0, :]      # (S, n_kv, hd)
+                ks_arena = vs_arena = None
+                if quantized:
+                    k_row, k_s = _quantize_kv_rows(k_row)
+                    v_row, v_s = _quantize_kv_rows(v_row)
+                    ks_arena = cache["k_scale"][li].at[page, :, off].set(k_s)
+                    vs_arena = cache["v_scale"][li].at[page, :, off].set(v_s)
+                    new_ks.append(ks_arena)
+                    new_vs.append(vs_arena)
+                k_arena = k_layer.at[page, :, off, :].set(
+                    k_row.astype(cache["k"].dtype)
+                )
+                v_arena = v_layer.at[page, :, off, :].set(
+                    v_row.astype(cache["v"].dtype)
+                )
+                new_k.append(k_arena)
+                new_v.append(v_arena)
+
+            with jax.named_scope("attn"):
+                out = paged_attention(q, k_arena, v_arena, tables, pos, page_tokens,
+                                      k_scale=ks_arena, v_scale=vs_arena,
+                                      kernel=kernel)
+                out = out.reshape(s_lanes, n_heads, 1, head_dim).astype(x.dtype)
+                out = out.transpose(0, 2, 1, 3).reshape(s_lanes, 1, cfg["d_model"])
+                x = x + out @ attn["wo"]
+            x = x + _ffn_block(layer, x, cfg, family, dtype)
+    with jax.named_scope("lm_head"):
+        x = _rmsnorm(x, params["ln_f"])
+        logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+    with jax.named_scope("kv_write"):
+        # the per-layer slices back into one arena
+        new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
         if quantized:
-            k_row, k_s = _quantize_kv_rows(k_row)
-            v_row, v_s = _quantize_kv_rows(v_row)
-            ks_arena = cache["k_scale"][li].at[page, :, off].set(k_s)
-            vs_arena = cache["v_scale"][li].at[page, :, off].set(v_s)
-            new_ks.append(ks_arena)
-            new_vs.append(vs_arena)
-        k_arena = cache["k"][li].at[page, :, off, :].set(
-            k_row.astype(cache["k"].dtype)
-        )
-        v_arena = cache["v"][li].at[page, :, off, :].set(
-            v_row.astype(cache["v"].dtype)
-        )
-        new_k.append(k_arena)
-        new_v.append(v_arena)
-
-        out = paged_attention(q, k_arena, v_arena, tables, pos, page_tokens,
-                              k_scale=ks_arena, v_scale=vs_arena,
-                              kernel=kernel)
-        out = out.reshape(s_lanes, n_heads, 1, head_dim).astype(x.dtype)
-        out = out.transpose(0, 2, 1, 3).reshape(s_lanes, 1, cfg["d_model"])
-        x = x + out @ attn["wo"]
-        x = x + _ffn_block(layer, x, cfg, family, dtype)
-    x = _rmsnorm(x, params["ln_f"])
-    logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
-    new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if quantized:
-        new_cache["k_scale"] = jnp.stack(new_ks)
-        new_cache["v_scale"] = jnp.stack(new_vs)
+            new_cache["k_scale"] = jnp.stack(new_ks)
+            new_cache["v_scale"] = jnp.stack(new_vs)
     return logits, new_cache
 
 
@@ -554,53 +568,62 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     off = positions % page_tokens
     quantized = "k_scale" in cache
 
-    x = params["embed"][toks].astype(dtype)                      # (S, T, d)
+    with jax.named_scope("embed"):
+        x = params["embed"][toks].astype(dtype)                  # (S, T, d)
     new_k, new_v, new_ks, new_vs = [], [], [], []
     for li, layer in enumerate(params["layers"]):
-        attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-        h = _rmsnorm(x, layer["ln1"])
-        q = (h @ attn["wq"]).reshape(s_lanes, t_q, n_heads, head_dim).transpose(0, 2, 1, 3)
-        k = (h @ attn["wk"]).reshape(s_lanes, t_q, n_kv, head_dim).transpose(0, 2, 1, 3)
-        v = (h @ attn["wv"]).reshape(s_lanes, t_q, n_kv, head_dim).transpose(0, 2, 1, 3)
-        q = _rope_per_example(q, positions, cfg["rope_theta"])
-        k = _rope_per_example(k, positions, cfg["rope_theta"])
+        with jax.named_scope("layer"):
+            with jax.named_scope("attn"):
+                attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
+                h = _rmsnorm(x, layer["ln1"])
+                q = (h @ attn["wq"]).reshape(s_lanes, t_q, n_heads, head_dim).transpose(0, 2, 1, 3)
+                k = (h @ attn["wk"]).reshape(s_lanes, t_q, n_kv, head_dim).transpose(0, 2, 1, 3)
+                v = (h @ attn["wv"]).reshape(s_lanes, t_q, n_kv, head_dim).transpose(0, 2, 1, 3)
+                q = _rope_per_example(q, positions, cfg["rope_theta"])
+                k = _rope_per_example(k, positions, cfg["rope_theta"])
+            with jax.named_scope("kv_read"):
+                k_layer, v_layer = cache["k"][li], cache["v"][li]
 
-        # scatter the T new rows per lane: advanced indices (S, T) at arena
-        # dims 0 and 2 straddle the head slice, so the updated block is
-        # (S, T, n_kv, hd) — the natural layout of the projection
-        k_rows = k.transpose(0, 2, 1, 3)                         # (S, T, n_kv, hd)
-        v_rows = v.transpose(0, 2, 1, 3)
-        ks_arena = vs_arena = None
+            # scatter the T new rows per lane: advanced indices (S, T) at arena
+            # dims 0 and 2 straddle the head slice, so the updated block is
+            # (S, T, n_kv, hd) — the natural layout of the projection
+            with jax.named_scope("kv_write"):
+                k_rows = k.transpose(0, 2, 1, 3)                 # (S, T, n_kv, hd)
+                v_rows = v.transpose(0, 2, 1, 3)
+                ks_arena = vs_arena = None
+                if quantized:
+                    k_rows, k_s = _quantize_kv_rows(k_rows)
+                    v_rows, v_s = _quantize_kv_rows(v_rows)
+                    ks_arena = cache["k_scale"][li].at[pages, :, off].set(k_s)
+                    vs_arena = cache["v_scale"][li].at[pages, :, off].set(v_s)
+                    new_ks.append(ks_arena)
+                    new_vs.append(vs_arena)
+                k_arena = k_layer.at[pages, :, off, :].set(
+                    k_rows.astype(cache["k"].dtype)
+                )
+                v_arena = v_layer.at[pages, :, off, :].set(
+                    v_rows.astype(cache["v"].dtype)
+                )
+                new_k.append(k_arena)
+                new_v.append(v_arena)
+
+            with jax.named_scope("attn"):
+                out = paged_attention_verify(
+                    q, k_arena, v_arena, tables, pos, page_tokens,
+                    k_scale=ks_arena, v_scale=vs_arena, kernel=kernel,
+                )
+                out = out.reshape(s_lanes, n_heads, t_q, head_dim).astype(x.dtype)
+                out = out.transpose(0, 2, 1, 3).reshape(s_lanes, t_q, cfg["d_model"])
+                x = x + out @ attn["wo"]
+            x = x + _ffn_block(layer, x, cfg, family, dtype)
+    with jax.named_scope("lm_head"):
+        x = _rmsnorm(x, params["ln_f"])
+        logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+    with jax.named_scope("kv_write"):
+        new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
         if quantized:
-            k_rows, k_s = _quantize_kv_rows(k_rows)
-            v_rows, v_s = _quantize_kv_rows(v_rows)
-            ks_arena = cache["k_scale"][li].at[pages, :, off].set(k_s)
-            vs_arena = cache["v_scale"][li].at[pages, :, off].set(v_s)
-            new_ks.append(ks_arena)
-            new_vs.append(vs_arena)
-        k_arena = cache["k"][li].at[pages, :, off, :].set(
-            k_rows.astype(cache["k"].dtype)
-        )
-        v_arena = cache["v"][li].at[pages, :, off, :].set(
-            v_rows.astype(cache["v"].dtype)
-        )
-        new_k.append(k_arena)
-        new_v.append(v_arena)
-
-        out = paged_attention_verify(
-            q, k_arena, v_arena, tables, pos, page_tokens,
-            k_scale=ks_arena, v_scale=vs_arena, kernel=kernel,
-        )
-        out = out.reshape(s_lanes, n_heads, t_q, head_dim).astype(x.dtype)
-        out = out.transpose(0, 2, 1, 3).reshape(s_lanes, t_q, cfg["d_model"])
-        x = x + out @ attn["wo"]
-        x = x + _ffn_block(layer, x, cfg, family, dtype)
-    x = _rmsnorm(x, params["ln_f"])
-    logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
-    new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if quantized:
-        new_cache["k_scale"] = jnp.stack(new_ks)
-        new_cache["v_scale"] = jnp.stack(new_vs)
+            new_cache["k_scale"] = jnp.stack(new_ks)
+            new_cache["v_scale"] = jnp.stack(new_vs)
     return logits, new_cache
 
 
@@ -651,6 +674,7 @@ def _paged_prefill_chunk_jit(params, arena_k, arena_v, scales, table_row,
 @functools.partial(
     jax.jit, donate_argnums=(0, 1, 2), static_argnames=("page_tokens",)
 )
+@jax.named_scope("kv_write")
 def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
                       page_tokens):
     """Scatter one admitted request's prefill K/V (layers, 1, n_kv, P_pad,
@@ -694,6 +718,7 @@ def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
 
 
 @jax.jit
+@jax.named_scope("kv_read")
 def _paged_gather_prefix_jit(arena_k, arena_v, scales, pages):
     """Gather ``n`` full shared-prefix pages into the dense
     (layers, 1, n_kv, n*page_tokens, hd) layout `_slot_prefill_from_cache_jit`
@@ -827,6 +852,7 @@ def _paged_decode_chunk_jit(
             jnp.transpose(toks, (1, 0)))  # (S, chunk)
 
 
+@jax.named_scope("ffn")
 def _ffn_block(layer: dict, x, cfg: dict, family: str, dtype):
     """The family-specific second half of a decoder layer (input is the
     residual stream BEFORE its norm; returns the residual delta)."""
@@ -855,59 +881,69 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
     head_dim = cfg["d_model"] // n_heads
     positions = start_pos[:, None] + jnp.arange(s_len)[None, :]   # (B, S)
 
-    x = params["embed"][input_ids].astype(dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(dtype)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-        h = _rmsnorm(x, layer["ln1"])
-        q = (h @ attn["wq"]).reshape(b, s_len, n_heads, head_dim).transpose(0, 2, 1, 3)
-        k = (h @ attn["wk"]).reshape(b, s_len, n_kv, head_dim).transpose(0, 2, 1, 3)
-        v = (h @ attn["wv"]).reshape(b, s_len, n_kv, head_dim).transpose(0, 2, 1, 3)
-        q = _rope_per_example(q, positions, cfg["rope_theta"])
-        k = _rope_per_example(k, positions, cfg["rope_theta"])
+        with jax.named_scope("layer"):
+            with jax.named_scope("attn"):
+                attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
+                h = _rmsnorm(x, layer["ln1"])
+                q = (h @ attn["wq"]).reshape(b, s_len, n_heads, head_dim).transpose(0, 2, 1, 3)
+                k = (h @ attn["wk"]).reshape(b, s_len, n_kv, head_dim).transpose(0, 2, 1, 3)
+                v = (h @ attn["wv"]).reshape(b, s_len, n_kv, head_dim).transpose(0, 2, 1, 3)
+                q = _rope_per_example(q, positions, cfg["rope_theta"])
+                k = _rope_per_example(k, positions, cfg["rope_theta"])
+            with jax.named_scope("kv_read"):
+                k_layer, v_layer = cache["k"][li], cache["v"][li]
 
-        # scatter each example's K/V row into its own cache offset
-        def upd(cache_l, kv):
-            def one(c, kv_b, p):
-                return jax.lax.dynamic_update_slice(c, kv_b, (0, p, 0))
-            return jax.vmap(one)(cache_l, kv, start_pos)
+            # scatter each example's K/V row into its own cache offset
+            def upd(cache_l, kv):
+                def one(c, kv_b, p):
+                    return jax.lax.dynamic_update_slice(c, kv_b, (0, p, 0))
+                return jax.vmap(one)(cache_l, kv, start_pos)
 
-        k_cache = upd(cache["k"][li], k.astype(cache["k"].dtype))
-        v_cache = upd(cache["v"][li], v.astype(cache["v"].dtype))
-        new_k.append(k_cache)
-        new_v.append(v_cache)
+            with jax.named_scope("kv_write"):
+                k_cache = upd(k_layer, k.astype(cache["k"].dtype))
+                v_cache = upd(v_layer, v.astype(cache["v"].dtype))
+                new_k.append(k_cache)
+                new_v.append(v_cache)
 
-        # per-example visibility: key pos <= query pos. GQA grouped-K/V form:
-        # query heads fold into (kv_head, group) so the cache is read as-is,
-        # never repeated up to n_heads (the repeat would materialize
-        # group x cache bytes every step at exactly the scale GQA exists for)
-        d = q.shape[-1]
-        group = n_heads // n_kv
-        # dots read the caches in their stored dtype: upcasting K/V to f32
-        # here doubled the HBM bytes of the cache read EVERY decode step —
-        # the read that dominates decode. Scores/softmax still accumulate
-        # f32 via preferred_element_type (the flash-kernel recipe).
-        qg = q.reshape(b, n_kv, group, s_len, d)
-        s = jnp.einsum(
-            "bkgqd,bkld->bkgql", qg, k_cache,
-            preferred_element_type=jnp.float32,
-        )
-        s = s / math.sqrt(d)
-        k_pos = jnp.arange(k_cache.shape[2])
-        mask = k_pos[None, None, :] <= positions[:, :, None]      # (B, S, max_len)
-        s = jnp.where(mask[:, None, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum(
-            "bkgql,bkld->bkgqd", p.astype(v_cache.dtype), v_cache,
-            preferred_element_type=jnp.float32,
-        )
-        out = out.reshape(b, n_heads, s_len, d).astype(x.dtype)
-        out = out.transpose(0, 2, 1, 3).reshape(b, s_len, cfg["d_model"])
-        x = x + out @ attn["wo"]
-        x = x + _ffn_block(layer, x, cfg, family, dtype)
-    x = _rmsnorm(x, params["ln_f"])
-    logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
-    return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+            # per-example visibility: key pos <= query pos. GQA grouped-K/V form:
+            # query heads fold into (kv_head, group) so the cache is read as-is,
+            # never repeated up to n_heads (the repeat would materialize
+            # group x cache bytes every step at exactly the scale GQA exists for)
+            with jax.named_scope("attn"):
+                d = q.shape[-1]
+                group = n_heads // n_kv
+                # dots read the caches in their stored dtype: upcasting K/V to f32
+                # here doubled the HBM bytes of the cache read EVERY decode step —
+                # the read that dominates decode. Scores/softmax still accumulate
+                # f32 via preferred_element_type (the flash-kernel recipe).
+                qg = q.reshape(b, n_kv, group, s_len, d)
+                s = jnp.einsum(
+                    "bkgqd,bkld->bkgql", qg, k_cache,
+                    preferred_element_type=jnp.float32,
+                )
+                s = s / math.sqrt(d)
+                k_pos = jnp.arange(k_cache.shape[2])
+                mask = k_pos[None, None, :] <= positions[:, :, None]      # (B, S, max_len)
+                s = jnp.where(mask[:, None, None], s, -1e30)
+                p = jax.nn.softmax(s, axis=-1)
+                out = jnp.einsum(
+                    "bkgql,bkld->bkgqd", p.astype(v_cache.dtype), v_cache,
+                    preferred_element_type=jnp.float32,
+                )
+                out = out.reshape(b, n_heads, s_len, d).astype(x.dtype)
+                out = out.transpose(0, 2, 1, 3).reshape(b, s_len, cfg["d_model"])
+                x = x + out @ attn["wo"]
+            x = x + _ffn_block(layer, x, cfg, family, dtype)
+    with jax.named_scope("lm_head"):
+        x = _rmsnorm(x, params["ln_f"])
+        logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+    with jax.named_scope("kv_write"):
+        new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+    return logits, new_cache
 
 
 def _rope_per_example(x, positions, theta):

@@ -75,6 +75,7 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return rot.reshape(x.shape).astype(x.dtype)
 
 
+@jax.named_scope("attn")
 def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None) -> jax.Array:  # static-bounded: mesh -- one Mesh object per runtime lifetime
     b, s, d_model = x.shape
     n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"]
@@ -108,6 +109,7 @@ def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None) -> jax.Ar
     return out @ params["wo"]
 
 
+@jax.named_scope("ffn")
 def _mlp_block(params: dict, x: jax.Array) -> jax.Array:
     gate = jax.nn.silu(x @ params["w1"])
     up = x @ params["w3"]
@@ -116,21 +118,24 @@ def _mlp_block(params: dict, x: jax.Array) -> jax.Array:
 
 def _forward(params: dict, input_ids: jax.Array, cfg: dict, mesh=None) -> jax.Array:
     dtype = jnp.dtype(cfg["dtype"])
-    x = params["embed"][input_ids].astype(dtype)                        # (b,s,d)
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(dtype)                    # (b,s,d)
     for layer in params["layers"]:
-        x = x + _attention_block(
-            jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"]),
-            _rmsnorm(x, layer["ln1"]),
-            cfg,
-            mesh,
-        )
-        x = x + _mlp_block(
-            jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"]),
-            _rmsnorm(x, layer["ln2"]),
-        )
-    x = _rmsnorm(x, params["ln_f"])
-    # logits in f32 for a stable softmax/argmax downstream
-    return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+        with jax.named_scope("layer"):
+            x = x + _attention_block(
+                jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"]),
+                _rmsnorm(x, layer["ln1"]),
+                cfg,
+                mesh,
+            )
+            x = x + _mlp_block(
+                jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"]),
+                _rmsnorm(x, layer["ln2"]),
+            )
+    with jax.named_scope("lm_head"):
+        x = _rmsnorm(x, params["ln_f"])
+        # logits in f32 for a stable softmax/argmax downstream
+        return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
 
 
 @register("transformer_lm", DEFAULT_CONFIG)

@@ -530,6 +530,7 @@ def flash_attention_carry(
 # Paged-KV attention (continuous decode engine)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_read")
 def paged_gather_kv(
     pages: jax.Array, tables: jax.Array, page_tokens: int
 ) -> jax.Array:
@@ -657,6 +658,7 @@ def paged_verify_attention(
     return out.reshape(s_lanes, hq, t, d)
 
 
+@jax.named_scope("kv_read")
 def dequantize_pages(pages: jax.Array, scales: jax.Array) -> jax.Array:
     """Expand an int8 page arena ``(n_pages, Hkv, page_tokens, D)`` against
     its per-(page, head, token) f32 scales ``(n_pages, Hkv, page_tokens)``

@@ -17,6 +17,7 @@ import contextvars
 import json
 import queue as queue_mod
 import secrets
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Mapping
 
@@ -38,7 +39,7 @@ from tfservingcache_tpu.runtime.base import (
 )
 from tfservingcache_tpu.types import ModelId, ModelState
 from tfservingcache_tpu.utils.logging import get_logger
-from tfservingcache_tpu.utils.tracing import TRACER
+from tfservingcache_tpu.utils.tracing import TRACER, current_span, host_span
 
 log = get_logger("local_backend")
 
@@ -96,6 +97,7 @@ class LocalServingBackend(ServingBackend):
         # JAX dispatch is effectively serialized per device; a few workers
         # keep fetch/compile of different models overlapping inference.
         self._pool = ThreadPoolExecutor(max_workers=max_workers, thread_name_prefix="tpusc-serve")
+        self._pool_wait_observers: dict[str, Any] = {}   # what -> histogram child's observe
         # batch_window_ms > 0 enables the continuous batcher (batches form
         # while the device is busy — no timed window exists anymore, the
         # knob is the on/off switch; see runtime/batcher.py)
@@ -155,12 +157,35 @@ class LocalServingBackend(ServingBackend):
             )
             self._spec_draft_name = str(spec_draft_model or "")
 
-    async def _run(self, fn, *args):
+    async def _run(self, fn, *args, what: str = "codec"):
+        """The one door into the serving pool. The wait for a thread (submit
+        -> worker start) becomes a ``pool_wait`` child of the ambient span
+        and an observation of ``tpusc_pool_wait_seconds{what}``: a streamed
+        ``:generate`` holds its thread until the row ends, so a saturated
+        pool shows here and nowhere else on the server's side."""
         # copy_context: the executor job joins the request's ambient trace
         # (utils.tracing) instead of starting an orphan root
         ctx = contextvars.copy_context()
+        parent = current_span()
+        observe = self._pool_wait_observers.get(what)
+        if observe is None and getattr(self.manager, "metrics", None) is not None:
+            # the labelled child, looked up once a word
+            observe = self.manager.metrics.pool_wait.labels(what).observe
+            self._pool_wait_observers[what] = observe
+        submitted = time.monotonic()
+
+        def job():
+            wait = time.monotonic() - submitted
+            if parent is not None:
+                TRACER.attach(parent, "pool_wait", wait,
+                              start_s=time.time() - wait, what=what)
+            if observe is not None:
+                observe(wait)
+            with host_span("serve"):
+                return fn(*args)
+
         return await asyncio.get_running_loop().run_in_executor(
-            self._pool, lambda: ctx.run(fn, *args)
+            self._pool, ctx.run, job
         )
 
     async def _run_bounded(self, what: str, model_id, fn, *args):
@@ -172,7 +197,7 @@ class LocalServingBackend(ServingBackend):
         backstop when the device call itself hangs. The executor thread is
         NOT interrupted: the 504 is about the client's bound, stragglers
         finish (or hang) in the pool."""
-        fut = self._run(fn, *args)
+        fut = self._run(fn, *args, what=what)
         timeout = self.manager.load_timeout_s
         try:
             return await (asyncio.wait_for(fut, timeout) if timeout else fut)
@@ -553,7 +578,8 @@ class LocalServingBackend(ServingBackend):
                 return resp
             targets.extend(ModelId(mc.name, v) for v in versions)
         results = await asyncio.gather(
-            *(self._run(self._ensure_sync, t) for t in targets), return_exceptions=True
+            *(self._run(self._ensure_sync, t, what="ensure") for t in targets),
+            return_exceptions=True,
         )
         resp = sv.ReloadConfigResponse()
         errors = [r for r in results if isinstance(r, BaseException)]
@@ -953,7 +979,7 @@ class LocalServingBackend(ServingBackend):
                 q.put(("err", e))
 
         loop = asyncio.get_running_loop()
-        task = asyncio.ensure_future(self._run(worker))
+        task = asyncio.ensure_future(self._run(worker, what="generate"))
         try:
             streamed = 0
             while True:

@@ -289,6 +289,7 @@ class RestServingServer:
         # trace instead of starting a fresh one
         remote_ctx = parse_traceparent(request.headers.get("traceparent"))
         sp = None
+        streaming = False
         try:
             with remote_parent(remote_ctx), \
                     TRACER.span("rest", path=path, method=request.method) as sp:
@@ -296,13 +297,28 @@ class RestServingServer:
                     request.method, name, version, verb, body, label=label,
                     query=dict(request.query),
                 )
+                streaming = getattr(resp, "token_stream", None) is not None
+                if streaming:
+                    # streaming generate (ISSUE 19): the root stays open
+                    # until the stream ended (EOF, error frame or client
+                    # gone), and the stream's pool job starts from the drain
+                    # below, inside it: pool_wait, ensure_servable/load and
+                    # the engine's phase attrs sit on the one root whose
+                    # duration is what the client saw
+                    return await self._stream_rest(
+                        request, resp, sp, remote_ctx
+                    ), sp, verb_label
         except BackendError as e:
+            if streaming:
+                raise  # the status line has shipped: nothing left to answer
             response = self._fail(web.Response(
                 status=e.http_status,
                 body=json.dumps({"error": str(e)}).encode(),
                 content_type="application/json",
             ))
         except Exception as e:  # noqa: BLE001
+            if streaming:
+                raise
             log.exception("unhandled REST error for %s", path)
             response = self._fail(web.Response(
                 status=500,
@@ -312,12 +328,6 @@ class RestServingServer:
         else:
             if resp.status >= 400 and self.metrics is not None:
                 self.metrics.request_failures.labels("rest").inc()
-            if getattr(resp, "token_stream", None) is not None:
-                # streaming generate (ISSUE 19): headers ship on prepare(),
-                # so the trace/status piggyback must attach before the drain
-                return await self._stream_rest(
-                    request, resp, sp, remote_ctx
-                ), sp, verb_label
             response = web.Response(
                 status=resp.status,
                 body=resp.body,
@@ -354,6 +364,10 @@ class RestServingServer:
         headers["Content-Type"] = resp.content_type
         stream = web.StreamResponse(status=resp.status, headers=headers)
         if remote_ctx is not None and sp is not None:
+            # headers ship at prepare(), so the piggybacked subtree is the
+            # root as it stands NOW (still open; the whole stream is in
+            # /monitoring/traces once it ended)
+            sp.duration_s = time.monotonic() - sp.t0
             stream.headers[TRACE_SUBTREE_HEADER] = serialize_span(sp)
         if (
             self.status_collector is not None

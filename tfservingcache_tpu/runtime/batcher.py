@@ -53,7 +53,7 @@ from tfservingcache_tpu.utils.accounting import LEDGER
 from tfservingcache_tpu.utils.flight_recorder import RECORDER
 from tfservingcache_tpu.utils.lockcheck import lockchecked
 from tfservingcache_tpu.utils.logging import get_logger
-from tfservingcache_tpu.utils.tracing import TRACER, current_ids
+from tfservingcache_tpu.utils.tracing import TRACER, current_ids, host_span
 
 log = get_logger("runtime.batcher")
 
@@ -933,7 +933,8 @@ class _ContinuousScheduler:
                     self.pending.clear()
                     break
             try:
-                state = self._step(rt, state, lanes)
+                with host_span("boundary"):
+                    state = self._step(rt, state, lanes)
             except BaseException as e:  # noqa: BLE001 - triage the in-flight rows
                 # eviction mid-decode (ModelNotLoadedError) or a device
                 # failure: the slot state may hold poisoned K/V, so it is
@@ -996,395 +997,397 @@ class _ContinuousScheduler:
         prefix_hits_n = 0
         prefill_s_sum = 0.0
         tokens_in_n = 0
-        while free:
-            with self.cv:
-                if not self.pending:
-                    break
-                # admission orders by (priority rank, submit seq): strict
-                # class precedence, FIFO inside a class. With every queued
-                # row the same class this is min-seq = the leftmost row —
-                # exactly the old popleft, so priority-free traffic keeps
-                # its byte-identical admission order. O(n) scan; the queue
-                # is bounded by client concurrency.
-                best = 0
-                for qi in range(1, len(self.pending)):
-                    r = self.pending[qi]
-                    b = self.pending[best]
-                    if (r.rank, r.seq) < (b.rank, b.seq):
-                        best = qi
-                req = self.pending[best]
-                del self.pending[best]
-                if eng.metrics is not None:
-                    eng.metrics.batcher_queue_depth.labels("generate").dec()
-            reserved_idx = None
-            d_st = None
-            d_pk = d_pv = None
-            try:
-                if state is None:
-                    if eng.page_tokens is None and \
-                            eng.share_prefix_bytes is None and \
-                            eng.arena_dtype is None and \
-                            eng.paged_kernel is None:
-                        # no engine-level override: the runtime's ServingConfig
-                        # decides (and stub runtimes keep their 2-arg surface)
-                        state = rt.slot_decode_state(self.model_id, eng.slots)
-                    else:
-                        kw = {}
-                        if eng.page_tokens is not None:
-                            kw["page_tokens"] = eng.page_tokens
-                            kw["arena_pages"] = eng.arena_pages
-                        if eng.share_prefix_bytes is not None:
-                            kw["share_prefix_bytes"] = eng.share_prefix_bytes
-                        if eng.arena_dtype is not None:
-                            kw["arena_dtype"] = eng.arena_dtype
-                        if eng.paged_kernel is not None:
-                            kw["paged_kernel"] = eng.paged_kernel
-                        state = rt.slot_decode_state(
-                            self.model_id, eng.slots, **kw
+        with host_span("admit"):
+            while free:
+                with self.cv:
+                    if not self.pending:
+                        break
+                    # admission orders by (priority rank, submit seq): strict
+                    # class precedence, FIFO inside a class. With every queued
+                    # row the same class this is min-seq = the leftmost row —
+                    # exactly the old popleft, so priority-free traffic keeps
+                    # its byte-identical admission order. O(n) scan; the queue
+                    # is bounded by client concurrency.
+                    best = 0
+                    for qi in range(1, len(self.pending)):
+                        r = self.pending[qi]
+                        b = self.pending[best]
+                        if (r.rank, r.seq) < (b.rank, b.seq):
+                            best = qi
+                    req = self.pending[best]
+                    del self.pending[best]
+                    if eng.metrics is not None:
+                        eng.metrics.batcher_queue_depth.labels("generate").dec()
+                reserved_idx = None
+                d_st = None
+                d_pk = d_pv = None
+                try:
+                    if state is None:
+                        if eng.page_tokens is None and \
+                                eng.share_prefix_bytes is None and \
+                                eng.arena_dtype is None and \
+                                eng.paged_kernel is None:
+                            # no engine-level override: the runtime's ServingConfig
+                            # decides (and stub runtimes keep their 2-arg surface)
+                            state = rt.slot_decode_state(self.model_id, eng.slots)
+                        else:
+                            kw = {}
+                            if eng.page_tokens is not None:
+                                kw["page_tokens"] = eng.page_tokens
+                                kw["arena_pages"] = eng.arena_pages
+                            if eng.share_prefix_bytes is not None:
+                                kw["share_prefix_bytes"] = eng.share_prefix_bytes
+                            if eng.arena_dtype is not None:
+                                kw["arena_dtype"] = eng.arena_dtype
+                            if eng.paged_kernel is not None:
+                                kw["paged_kernel"] = eng.paged_kernel
+                            state = rt.slot_decode_state(
+                                self.model_id, eng.slots, **kw
+                            )
+                        # fresh state: every lane is idle, so the draft (if
+                        # configured and resident) can attach right away
+                        self._spec_setup(rt, state, lanes)
+                    d_st = getattr(state, "spec_draft", None)
+                    prompt = req.prompt
+                    remaining = req.max_new - len(req.tokens)
+                    if req.tokens:
+                        # crash-recovered row (tokens were emitted before the
+                        # old scheduler died): re-prefill prompt + emitted
+                        # tokens, so the next sampled token continues the stream
+                        # exactly where it broke — greedy output is identical to
+                        # an uninterrupted decode, and a shared-prefix hit on
+                        # the original prompt makes the replay cheap
+                        prompt = np.concatenate(
+                            [prompt, np.asarray(req.tokens, np.int32)]
                         )
-                    # fresh state: every lane is idle, so the draft (if
-                    # configured and resident) can attach right away
-                    self._spec_setup(rt, state, lanes)
-                d_st = getattr(state, "spec_draft", None)
-                prompt = req.prompt
-                remaining = req.max_new - len(req.tokens)
-                if req.tokens:
-                    # crash-recovered row (tokens were emitted before the
-                    # old scheduler died): re-prefill prompt + emitted
-                    # tokens, so the next sampled token continues the stream
-                    # exactly where it broke — greedy output is identical to
-                    # an uninterrupted decode, and a shared-prefix hit on
-                    # the original prompt makes the replay cheap
-                    prompt = np.concatenate(
-                        [prompt, np.asarray(req.tokens, np.int32)]
-                    )
-                p = prompt.shape[0]
-                if p + remaining > state.max_seq:
-                    req.error = RuntimeError_(
-                        f"prompt {p} + max_new_tokens {remaining} exceeds "
-                        f"max_seq {state.max_seq}"
-                    )
-                    req.done.set()
-                    continue
-                plan = None
-                kind = None
-                resume = None   # (parked, covered, n_pages) when resuming
-                share = getattr(state, "prefix_index", None) is not None
-                if getattr(state, "paged", False):
-                    # admission is gated on free PAGES, not just free lanes:
-                    # the row's whole prompt + max_new budget is reserved up
-                    # front so a mid-decode row can never starve for a page.
-                    # With a draft attached the budget grows by spec_tokens
-                    # of headroom — a verify round started one token short
-                    # of max_new still writes K/V rows at pos..pos+spec, and
-                    # those writes must land on pages this row owns (never
-                    # shared/trash), so the overshoot is reserved up front
-                    # and handed back through release_pages at retirement.
-                    headroom = state.spec_tokens if d_st is not None else 0
-                    budget = min(p + remaining + headroom,
-                                 state.pages_per_slot * state.page_tokens)
-                    need = state.pages_needed(budget)
-                    if need > state.arena_pages:
+                    p = prompt.shape[0]
+                    if p + remaining > state.max_seq:
                         req.error = RuntimeError_(
-                            f"request needs {need} KV pages "
-                            f"({budget} tokens) but the arena has only "
-                            f"{state.arena_pages}"
+                            f"prompt {p} + max_new_tokens {remaining} exceeds "
+                            f"max_seq {state.max_seq}"
                         )
                         req.done.set()
                         continue
-                    idx = free[-1]  # the lane free.pop() will hand out below
-                    shared_pages = ()
-                    cow_headroom = 0
-                    if req.preempt_parked is not None and \
-                            hasattr(rt, "plan_conversation_resume"):
-                        # preempted row coming back: its own parked pages
-                        # beat both the conversation tier and the radix
-                        # index — they cover prompt + every emitted token,
-                        # so the resume prefill is O(1) (the single row the
-                        # park could not cover)
-                        rplan = rt.plan_conversation_resume(
-                            state, prompt, req.preempt_parked
-                        )
-                        if rplan is not None:
-                            resume = (req.preempt_parked, rplan[0], rplan[1])
-                    if resume is None and req.conversation_id and \
-                            eng.conversation_tier is not None and \
-                            hasattr(rt, "plan_conversation_resume"):
-                        # resume beats cold prefill AND the shared-prefix
-                        # plan: parked pages cover the whole history (prompt
-                        # + prior turns' emitted tokens), where the radix
-                        # index at best covers what is still arena-resident.
-                        # The lookup PEEKS, so a lane that crashes mid-decode
-                        # can resume again from the same ancestor.
-                        parked, _outcome = eng.conversation_tier.get(
-                            req.conversation_id, str(self.model_id)
-                        )
-                        if parked is not None:
+                    plan = None
+                    kind = None
+                    resume = None   # (parked, covered, n_pages) when resuming
+                    share = getattr(state, "prefix_index", None) is not None
+                    if getattr(state, "paged", False):
+                        # admission is gated on free PAGES, not just free lanes:
+                        # the row's whole prompt + max_new budget is reserved up
+                        # front so a mid-decode row can never starve for a page.
+                        # With a draft attached the budget grows by spec_tokens
+                        # of headroom — a verify round started one token short
+                        # of max_new still writes K/V rows at pos..pos+spec, and
+                        # those writes must land on pages this row owns (never
+                        # shared/trash), so the overshoot is reserved up front
+                        # and handed back through release_pages at retirement.
+                        headroom = state.spec_tokens if d_st is not None else 0
+                        budget = min(p + remaining + headroom,
+                                     state.pages_per_slot * state.page_tokens)
+                        need = state.pages_needed(budget)
+                        if need > state.arena_pages:
+                            req.error = RuntimeError_(
+                                f"request needs {need} KV pages "
+                                f"({budget} tokens) but the arena has only "
+                                f"{state.arena_pages}"
+                            )
+                            req.done.set()
+                            continue
+                        idx = free[-1]  # the lane free.pop() will hand out below
+                        shared_pages = ()
+                        cow_headroom = 0
+                        if req.preempt_parked is not None and \
+                                hasattr(rt, "plan_conversation_resume"):
+                            # preempted row coming back: its own parked pages
+                            # beat both the conversation tier and the radix
+                            # index — they cover prompt + every emitted token,
+                            # so the resume prefill is O(1) (the single row the
+                            # park could not cover)
                             rplan = rt.plan_conversation_resume(
-                                state, prompt, parked
+                                state, prompt, req.preempt_parked
                             )
                             if rplan is not None:
-                                resume = (parked, rplan[0], rplan[1])
-                    if share and resume is None:
-                        plan = rt.shared_prefix_plan(state, prompt)
-                        if plan is not None:
-                            # map the indexed prefix read-only; reserve only
-                            # the private remainder. An exact hit with a
-                            # mid-page tail also needs one CoW page in hand
-                            # — its first decode write lands in the shared
-                            # boundary page.
-                            shared_pages = plan.mapped_pages()
-                            if plan.kind == "exact" and plan.tail_len > 0:
-                                cow_headroom = 1
-                    ok = state.reserve_pages(
-                        idx, budget, shared_pages, cow_headroom
-                    )
-                    if not ok and share:
-                        # page pressure: cold index-only prefix pages must
-                        # lose the fight to a live admission (protecting the
-                        # plan's own mapped pages), else sharing would turn
-                        # the blocks-never-fails queue into a deadlock
-                        want = (max(0, need - len(shared_pages)) + cow_headroom
-                                - len(state.free_pages))
-                        if want > 0 and rt.reclaim_prefix_pages(
-                            state, want, shared_pages
-                        ):
-                            ok = state.reserve_pages(
-                                idx, budget, shared_pages, cow_headroom
+                                resume = (req.preempt_parked, rplan[0], rplan[1])
+                        if resume is None and req.conversation_id and \
+                                eng.conversation_tier is not None and \
+                                hasattr(rt, "plan_conversation_resume"):
+                            # resume beats cold prefill AND the shared-prefix
+                            # plan: parked pages cover the whole history (prompt
+                            # + prior turns' emitted tokens), where the radix
+                            # index at best covers what is still arena-resident.
+                            # The lookup PEEKS, so a lane that crashes mid-decode
+                            # can resume again from the same ancestor.
+                            parked, _outcome = eng.conversation_tier.get(
+                                req.conversation_id, str(self.model_id)
                             )
-                    if ok and d_st is not None:
-                        # the draft arena mirrors the reservation (its rows
-                        # for pos..pos+spec are written every round). No
-                        # shared pages: the draft state has no prefix index,
-                        # every draft page is private by construction. The
-                        # cap keeps a shorter draft max_seq from deadlocking
-                        # (the auto-sized draft arena always covers slots x
-                        # pages_per_slot, so a capped reservation succeeds
-                        # whenever the lane itself is free).
-                        d_budget = min(
-                            budget, d_st.pages_per_slot * d_st.page_tokens
-                        )
-                        if not d_st.reserve_pages(idx, d_budget):
-                            state.release_pages(idx)
-                            ok = False
-                    if not ok and hasattr(rt, "park_lane"):
-                        # priority preemption (ISSUE 19): a higher-class
-                        # arrival that still can't reserve parks the
-                        # lowest-class decoding lane's KV (pages are COPIES
-                        # through the PR 18 codec, so the conservation
-                        # census stays exact), requeues it for an
-                        # O(new tokens) parked-KV resume, and retries the
-                        # reservation. One victim may not free enough —
-                        # keep hunting until the reserve succeeds or no
-                        # preemptible lane remains.
-                        while not ok:
-                            vidx = self._pick_victim(lanes, req)
-                            if vidx is None or not self._preempt(
-                                rt, state, lanes, vidx
-                            ):
-                                break
-                            # the victim's lane frees too — at the FRONT of
-                            # the free list, so free[-1] (the lane reserved
-                            # as `idx` above) is untouched
-                            free.insert(0, vidx)
-                            ok = state.reserve_pages(
-                                idx, budget, shared_pages, cow_headroom
-                            )
-                            if ok and d_st is not None:
-                                d_budget = min(
-                                    budget,
-                                    d_st.pages_per_slot * d_st.page_tokens,
+                            if parked is not None:
+                                rplan = rt.plan_conversation_resume(
+                                    state, prompt, parked
                                 )
-                                if not d_st.reserve_pages(idx, d_budget):
-                                    state.release_pages(idx)
-                                    ok = False
-                                    break
-                    if not ok:
-                        # arena exhausted: the queue BLOCKS, never fails —
-                        # the row goes back to the FRONT (FIFO preserved)
-                        # and retirements below recycle pages for the next
-                        # chunk boundary's retry. Can't deadlock: with no
-                        # active lanes every page is free or reclaimable
-                        # from the prefix index, and need <= arena_pages
-                        # was checked above.
-                        with self.cv:
-                            self.pending.appendleft(req)
-                            if eng.metrics is not None:
-                                eng.metrics.batcher_queue_depth.labels(
-                                    "generate"
-                                ).inc()
-                        RECORDER.dump(
-                            "page_exhaustion", model=str(self.model_id),
-                            needed_pages=need, free_pages=len(state.free_pages),
-                            arena_pages=state.arena_pages,
+                                if rplan is not None:
+                                    resume = (parked, rplan[0], rplan[1])
+                        if share and resume is None:
+                            plan = rt.shared_prefix_plan(state, prompt)
+                            if plan is not None:
+                                # map the indexed prefix read-only; reserve only
+                                # the private remainder. An exact hit with a
+                                # mid-page tail also needs one CoW page in hand
+                                # — its first decode write lands in the shared
+                                # boundary page.
+                                shared_pages = plan.mapped_pages()
+                                if plan.kind == "exact" and plan.tail_len > 0:
+                                    cow_headroom = 1
+                        ok = state.reserve_pages(
+                            idx, budget, shared_pages, cow_headroom
                         )
-                        break
-                    reserved_idx = idx
-                pf0 = time.monotonic()
-                seed = secrets.randbits(31)
-                if (
-                    reserved_idx is not None
-                    and eng.prefill_chunk_tokens > 0
-                    and resume is None and plan is None and d_st is None
-                    and p > eng.prefill_chunk_tokens
-                    and hasattr(rt, "slot_prefill_chunk")
-                ):
-                    # chunked-prefill interleaving (ISSUE 19): pages are
-                    # reserved but NOTHING is written yet — the lane enters
-                    # its PREFILLING state and _prefill_phase advances it
-                    # one fixed-size chunk per boundary while other lanes
-                    # keep decoding between chunks. pos holds the past-
-                    # reservation sentinel so the decode chunk's frozen
-                    # rewrite of this inactive lane hits the trash-page
-                    # redirect, never the reserved rows the chunks fill.
-                    # Resume/shared hits and spec-draft engines keep the
-                    # single-dispatch path (their prefill is already the
-                    # short suffix, or the draft arena must mirror it).
-                    idx = free.pop()
-                    req.pf_prompt = prompt
-                    req.pf_pos = 0
-                    req.pf_seed = seed
-                    now = time.monotonic()
-                    req.admitted_t = now
-                    state.active[idx] = False
-                    state.pos[idx] = state.pages_per_slot * state.page_tokens
-                    state.temps[idx] = req.temperature
-                    state.topks[idx] = req.top_k
-                    lanes[idx] = req
-                    eng.admitted += 1
-                    admitted_any = True
-                    admitted_n += 1
-                    if eng.metrics is not None:
-                        eng.metrics.gen_admission_wait.labels(
-                            "continuous"
-                        ).observe(max(0.0, now - req.enqueue_t))
-                    continue
-                if resume is not None and reserved_idx is not None:
-                    # O(new tokens) turn resume: parked pages re-import into
-                    # the lane's private reservation, only the suffix past
-                    # the common history prefix runs through prefill
-                    tok, pk, pv, last = rt.slot_resume_prefill(
-                        self.model_id, state, reserved_idx, prompt,
-                        resume[0], resume[1], resume[2],
-                        req.temperature, req.top_k, seed,
-                    )
-                    kind = "resume"
-                    hit = True
-                    req.preempt_parked = None
-                elif share:
-                    tok, pk, pv, kind, last = rt.slot_prefill_shared(
-                        self.model_id, state, prompt, req.temperature,
-                        req.top_k, seed, plan,
-                    )
-                    hit = kind != "miss"
+                        if not ok and share:
+                            # page pressure: cold index-only prefix pages must
+                            # lose the fight to a live admission (protecting the
+                            # plan's own mapped pages), else sharing would turn
+                            # the blocks-never-fails queue into a deadlock
+                            want = (max(0, need - len(shared_pages)) + cow_headroom
+                                    - len(state.free_pages))
+                            if want > 0 and rt.reclaim_prefix_pages(
+                                state, want, shared_pages
+                            ):
+                                ok = state.reserve_pages(
+                                    idx, budget, shared_pages, cow_headroom
+                                )
+                        if ok and d_st is not None:
+                            # the draft arena mirrors the reservation (its rows
+                            # for pos..pos+spec are written every round). No
+                            # shared pages: the draft state has no prefix index,
+                            # every draft page is private by construction. The
+                            # cap keeps a shorter draft max_seq from deadlocking
+                            # (the auto-sized draft arena always covers slots x
+                            # pages_per_slot, so a capped reservation succeeds
+                            # whenever the lane itself is free).
+                            d_budget = min(
+                                budget, d_st.pages_per_slot * d_st.page_tokens
+                            )
+                            if not d_st.reserve_pages(idx, d_budget):
+                                state.release_pages(idx)
+                                ok = False
+                        if not ok and hasattr(rt, "park_lane"):
+                            # priority preemption (ISSUE 19): a higher-class
+                            # arrival that still can't reserve parks the
+                            # lowest-class decoding lane's KV (pages are COPIES
+                            # through the PR 18 codec, so the conservation
+                            # census stays exact), requeues it for an
+                            # O(new tokens) parked-KV resume, and retries the
+                            # reservation. One victim may not free enough —
+                            # keep hunting until the reserve succeeds or no
+                            # preemptible lane remains.
+                            while not ok:
+                                vidx = self._pick_victim(lanes, req)
+                                if vidx is None or not self._preempt(
+                                    rt, state, lanes, vidx
+                                ):
+                                    break
+                                # the victim's lane frees too — at the FRONT of
+                                # the free list, so free[-1] (the lane reserved
+                                # as `idx` above) is untouched
+                                free.insert(0, vidx)
+                                ok = state.reserve_pages(
+                                    idx, budget, shared_pages, cow_headroom
+                                )
+                                if ok and d_st is not None:
+                                    d_budget = min(
+                                        budget,
+                                        d_st.pages_per_slot * d_st.page_tokens,
+                                    )
+                                    if not d_st.reserve_pages(idx, d_budget):
+                                        state.release_pages(idx)
+                                        ok = False
+                                        break
+                        if not ok:
+                            # arena exhausted: the queue BLOCKS, never fails —
+                            # the row goes back to the FRONT (FIFO preserved)
+                            # and retirements below recycle pages for the next
+                            # chunk boundary's retry. Can't deadlock: with no
+                            # active lanes every page is free or reclaimable
+                            # from the prefix index, and need <= arena_pages
+                            # was checked above.
+                            with self.cv:
+                                self.pending.appendleft(req)
+                                if eng.metrics is not None:
+                                    eng.metrics.batcher_queue_depth.labels(
+                                        "generate"
+                                    ).inc()
+                            RECORDER.dump(
+                                "page_exhaustion", model=str(self.model_id),
+                                needed_pages=need, free_pages=len(state.free_pages),
+                                arena_pages=state.arena_pages,
+                            )
+                            break
+                        reserved_idx = idx
+                    pf0 = time.monotonic()
+                    seed = secrets.randbits(31)
+                    if (
+                        reserved_idx is not None
+                        and eng.prefill_chunk_tokens > 0
+                        and resume is None and plan is None and d_st is None
+                        and p > eng.prefill_chunk_tokens
+                        and hasattr(rt, "slot_prefill_chunk")
+                    ):
+                        # chunked-prefill interleaving (ISSUE 19): pages are
+                        # reserved but NOTHING is written yet — the lane enters
+                        # its PREFILLING state and _prefill_phase advances it
+                        # one fixed-size chunk per boundary while other lanes
+                        # keep decoding between chunks. pos holds the past-
+                        # reservation sentinel so the decode chunk's frozen
+                        # rewrite of this inactive lane hits the trash-page
+                        # redirect, never the reserved rows the chunks fill.
+                        # Resume/shared hits and spec-draft engines keep the
+                        # single-dispatch path (their prefill is already the
+                        # short suffix, or the draft arena must mirror it).
+                        idx = free.pop()
+                        req.pf_prompt = prompt
+                        req.pf_pos = 0
+                        req.pf_seed = seed
+                        now = time.monotonic()
+                        req.admitted_t = now
+                        state.active[idx] = False
+                        state.pos[idx] = state.pages_per_slot * state.page_tokens
+                        state.temps[idx] = req.temperature
+                        state.topks[idx] = req.top_k
+                        lanes[idx] = req
+                        eng.admitted += 1
+                        admitted_any = True
+                        admitted_n += 1
+                        if eng.metrics is not None:
+                            eng.metrics.gen_admission_wait.labels(
+                                "continuous"
+                            ).observe(max(0.0, now - req.enqueue_t))
+                        continue
+                    with host_span("prefill"):
+                        if resume is not None and reserved_idx is not None:
+                            # O(new tokens) turn resume: parked pages re-import into
+                            # the lane's private reservation, only the suffix past
+                            # the common history prefix runs through prefill
+                            tok, pk, pv, last = rt.slot_resume_prefill(
+                                self.model_id, state, reserved_idx, prompt,
+                                resume[0], resume[1], resume[2],
+                                req.temperature, req.top_k, seed,
+                            )
+                            kind = "resume"
+                            hit = True
+                            req.preempt_parked = None
+                        elif share:
+                            tok, pk, pv, kind, last = rt.slot_prefill_shared(
+                                self.model_id, state, prompt, req.temperature,
+                                req.top_k, seed, plan,
+                            )
+                            hit = kind != "miss"
+                        else:
+                            tok, pk, pv, hit = rt.slot_prefill(
+                                self.model_id, prompt, req.temperature,
+                                req.top_k, seed=seed,
+                            )
+                            last = None
+                        if d_st is not None and reserved_idx is not None:
+                            # greedy draft prefill (temperature 0, sampled token
+                            # ignored — only the draft's K/V rows matter). Runs even
+                            # on an exact target prefix hit: the draft arena has no
+                            # prefix index to skip into.
+                            _, d_pk, d_pv, _ = rt.slot_prefill(
+                                state.spec_draft_id, prompt, 0.0, 0, seed=seed,
+                            )
+                except BaseException as e:  # noqa: BLE001
+                    # the req is already out of `pending` and not yet in `lanes`
+                    # — without this the _loop doom sweep would miss it and its
+                    # waiter would block until timeout
+                    if reserved_idx is not None:
+                        state.release_pages(reserved_idx)
+                        if d_st is not None:
+                            d_st.release_pages(reserved_idx)
+                    self._fail([req], e)
+                    raise
+                now = time.monotonic()
+                req.prefill_s = now - pf0
+                req.admitted_t = now
+                if req.first_tok_t is None:
+                    # a recovered row keeps its ORIGINAL first-token stamp —
+                    # TTFT is a client-experienced clock, and the client saw
+                    # its first token before the crash
+                    req.first_tok_t = now
+                req.prefix_hit = hit
+                self._emit(req, int(tok))
+                if kind == "exact":
+                    pass  # zero prefill compute
+                elif kind == "resume":
+                    req.prefill_tokens += p - resume[1]
+                elif kind == "shared":
+                    req.prefill_tokens += p - plan.covered
                 else:
-                    tok, pk, pv, hit = rt.slot_prefill(
-                        self.model_id, prompt, req.temperature,
-                        req.top_k, seed=seed,
-                    )
-                    last = None
-                if d_st is not None and reserved_idx is not None:
-                    # greedy draft prefill (temperature 0, sampled token
-                    # ignored — only the draft's K/V rows matter). Runs even
-                    # on an exact target prefix hit: the draft arena has no
-                    # prefix index to skip into.
-                    _, d_pk, d_pv, _ = rt.slot_prefill(
-                        state.spec_draft_id, prompt, 0.0, 0, seed=seed,
-                    )
-            except BaseException as e:  # noqa: BLE001
-                # the req is already out of `pending` and not yet in `lanes`
-                # — without this the _loop doom sweep would miss it and its
-                # waiter would block until timeout
-                if reserved_idx is not None:
-                    state.release_pages(reserved_idx)
-                    if d_st is not None:
-                        d_st.release_pages(reserved_idx)
-                self._fail([req], e)
-                raise
-            now = time.monotonic()
-            req.prefill_s = now - pf0
-            req.admitted_t = now
-            if req.first_tok_t is None:
-                # a recovered row keeps its ORIGINAL first-token stamp —
-                # TTFT is a client-experienced clock, and the client saw
-                # its first token before the crash
-                req.first_tok_t = now
-            req.prefix_hit = hit
-            self._emit(req, int(tok))
-            if kind == "exact":
-                pass  # zero prefill compute
-            elif kind == "resume":
-                req.prefill_tokens += p - resume[1]
-            elif kind == "shared":
-                req.prefill_tokens += p - plan.covered
-            else:
-                req.prefill_tokens += p
-            eng.admitted += 1
-            admitted_any = True
-            admitted_n += 1
-            prefill_s_sum += req.prefill_s
-            tokens_in_n += p
-            if hit:
-                prefix_hits_n += 1
+                    req.prefill_tokens += p
+                eng.admitted += 1
+                admitted_any = True
+                admitted_n += 1
+                prefill_s_sum += req.prefill_s
+                tokens_in_n += p
+                if hit:
+                    prefix_hits_n += 1
+                    if eng.metrics is not None:
+                        # exact = radix full-skip (zero prefill compute);
+                        # resume = parked-conversation re-import (suffix-only
+                        # prefill over re-imported pages); shared = radix
+                        # partial hit AND legacy dense-cache reuse (both paid
+                        # only a suffix prefill)
+                        eng.metrics.gen_prefix_hits.labels(
+                            "continuous",
+                            kind if kind in ("exact", "resume") else "shared",
+                        ).inc()
                 if eng.metrics is not None:
-                    # exact = radix full-skip (zero prefill compute);
-                    # resume = parked-conversation re-import (suffix-only
-                    # prefill over re-imported pages); shared = radix
-                    # partial hit AND legacy dense-cache reuse (both paid
-                    # only a suffix prefill)
-                    eng.metrics.gen_prefix_hits.labels(
-                        "continuous",
-                        kind if kind in ("exact", "resume") else "shared",
-                    ).inc()
-            if eng.metrics is not None:
-                eng.metrics.gen_admission_wait.labels("continuous").observe(
-                    max(0.0, now - req.enqueue_t)
-                )
-            if (eos is not None and int(tok) == eos) or remaining <= 1:
-                # done at prefill: the lane was never consumed
-                if reserved_idx is not None:
-                    self._retire_pages(state, reserved_idx, req)
-                req.finish_t = now
-                req.done.set()
-                retired_n += 1
-                continue
-            idx = free.pop()
-            if pk is None:
-                # exact shared-prefix hit: the prompt's K/V already lives in
-                # the mapped pages — nothing to insert. Its first decode
-                # write (pos = p) lands mid-way into the SHARED boundary
-                # page, so that one page is CoW'd now, while the headroom
-                # page reserved for it is guaranteed free (same scheduler
-                # turn, nothing ran in between).
-                if plan is not None and plan.tail_len > 0:
-                    rt.slot_cow(state, idx, plan.n_full)
-            elif kind == "resume":
-                # suffix-only insert over the re-imported pages: rows below
-                # the resume boundary already hold the parked bytes (the
-                # lane owns them privately — no trash redirect needed for
-                # correctness, but the suffix prefill only produced junk
-                # there, same as the shared case)
-                rt.slot_admit(state, idx, pk, pv, base_tokens=resume[1])
-            elif plan is not None and kind == "shared":
-                # suffix-only insert: rows below the shared boundary stay in
-                # the read-only mapped pages, the jit redirects them to trash
-                rt.slot_admit(state, idx, pk, pv, base_tokens=plan.covered)
-            else:
-                rt.slot_admit(state, idx, pk, pv)
-            if share and pk is not None:
-                # publish this lane's prompt pages so later same-prefix
-                # admissions share them (exact hits are already indexed)
-                rt.shared_prefix_publish(state, idx, prompt, last)
-            if d_pk is not None:
-                # the draft lane rides the same index: its prompt K/V lands
-                # on the pages reserved above, all private
-                rt.slot_admit(d_st, idx, d_pk, d_pv)
-            state.tok[idx] = int(tok)
-            state.pos[idx] = p
-            state.active[idx] = True
-            state.temps[idx] = req.temperature
-            state.topks[idx] = req.top_k
-            lanes[idx] = req
+                    eng.metrics.gen_admission_wait.labels("continuous").observe(
+                        max(0.0, now - req.enqueue_t)
+                    )
+                if (eos is not None and int(tok) == eos) or remaining <= 1:
+                    # done at prefill: the lane was never consumed
+                    if reserved_idx is not None:
+                        self._retire_pages(state, reserved_idx, req)
+                    req.finish_t = now
+                    req.done.set()
+                    retired_n += 1
+                    continue
+                idx = free.pop()
+                if pk is None:
+                    # exact shared-prefix hit: the prompt's K/V already lives in
+                    # the mapped pages — nothing to insert. Its first decode
+                    # write (pos = p) lands mid-way into the SHARED boundary
+                    # page, so that one page is CoW'd now, while the headroom
+                    # page reserved for it is guaranteed free (same scheduler
+                    # turn, nothing ran in between).
+                    if plan is not None and plan.tail_len > 0:
+                        rt.slot_cow(state, idx, plan.n_full)
+                elif kind == "resume":
+                    # suffix-only insert over the re-imported pages: rows below
+                    # the resume boundary already hold the parked bytes (the
+                    # lane owns them privately — no trash redirect needed for
+                    # correctness, but the suffix prefill only produced junk
+                    # there, same as the shared case)
+                    rt.slot_admit(state, idx, pk, pv, base_tokens=resume[1])
+                elif plan is not None and kind == "shared":
+                    # suffix-only insert: rows below the shared boundary stay in
+                    # the read-only mapped pages, the jit redirects them to trash
+                    rt.slot_admit(state, idx, pk, pv, base_tokens=plan.covered)
+                else:
+                    rt.slot_admit(state, idx, pk, pv)
+                if share and pk is not None:
+                    # publish this lane's prompt pages so later same-prefix
+                    # admissions share them (exact hits are already indexed)
+                    rt.shared_prefix_publish(state, idx, prompt, last)
+                if d_pk is not None:
+                    # the draft lane rides the same index: its prompt K/V lands
+                    # on the pages reserved above, all private
+                    rt.slot_admit(d_st, idx, d_pk, d_pv)
+                state.tok[idx] = int(tok)
+                state.pos[idx] = p
+                state.active[idx] = True
+                state.temps[idx] = req.temperature
+                state.topks[idx] = req.top_k
+                lanes[idx] = req
         if admitted_any:
             eng._set_active(
                 self.model_id, sum(l is not None for l in lanes)
@@ -1394,9 +1397,10 @@ class _ContinuousScheduler:
             # chunked-prefill interleave: every PREFILLING lane advances
             # exactly ONE chunk per boundary, so a long prompt's prefill is
             # spread across boundaries instead of monopolizing one dispatch
-            pf_chunks, pf_toks, pf_s, pf_retired = self._prefill_phase(
-                rt, state, lanes, eos
-            )
+            with host_span("prefill"):
+                pf_chunks, pf_toks, pf_s, pf_retired = self._prefill_phase(
+                    rt, state, lanes, eos
+                )
             retired_n += pf_retired
             prefill_s_sum += pf_s
             tokens_in_n += pf_toks
@@ -1465,60 +1469,64 @@ class _ContinuousScheduler:
                     pg = int(state.block_tables[cidx, slot])
                     if pg and int(state.page_refs[pg]) > 1:
                         rt.slot_cow(state, cidx, slot)
-        accept = None
-        if use_spec:
-            try:
-                toks, accept = rt.slot_decode_spec_round(state)
-            except ModelNotLoadedError as e:
-                if rt.is_loaded(self.model_id):
-                    # the draft was evicted between the residency check and
-                    # the round: detach and decode plain — target lanes are
-                    # untouched (the round failed before any state update)
-                    log.info(
-                        "continuous spec detach model=%s (%s)",
-                        self.model_id, e,
-                    )
-                    state.spec_draft = None
-                    state.spec_draft_id = None
-                    state.spec_tokens = 0
-                else:
-                    raise
-        if accept is None:
-            toks = rt.slot_decode_chunk(state, chunk)
-        else:
-            # ring/ledger semantics: a spec round can emit up to spec+1
-            # tokens per lane in one dispatch — that is its "chunk"
-            chunk = state.spec_tokens + 1
+        chunk_t0 = time.monotonic()
+        with host_span("decode_chunk"):
+            accept = None
+            if use_spec:
+                try:
+                    toks, accept = rt.slot_decode_spec_round(state)
+                except ModelNotLoadedError as e:
+                    if rt.is_loaded(self.model_id):
+                        # the draft was evicted between the residency check and
+                        # the round: detach and decode plain — target lanes are
+                        # untouched (the round failed before any state update)
+                        log.info(
+                            "continuous spec detach model=%s (%s)",
+                            self.model_id, e,
+                        )
+                        state.spec_draft = None
+                        state.spec_draft_id = None
+                        state.spec_tokens = 0
+                    else:
+                        raise
+            if accept is None:
+                toks = rt.slot_decode_chunk(state, chunk)
+            else:
+                # ring/ledger semantics: a spec round can emit up to spec+1
+                # tokens per lane in one dispatch — that is its "chunk"
+                chunk = state.spec_tokens + 1
         eng.chunks += 1
         now = time.monotonic()
         wasted = 0
         drafted = spec_span * active_rows if accept is not None else 0
         accepted = int(accept.sum()) if accept is not None else 0
-        for idx, req in enumerate(lanes):
-            if req is None or req.pf_pos is not None:
-                continue
-            # spec rounds emit a VARIABLE per-row prefix (the accepted
-            # draft run + the verify's correction token); plain chunks
-            # emit exactly `chunk` tokens per live lane
-            n_emit = chunk if accept is None else int(accept[idx])
-            for j in range(n_emit):
-                t = int(toks[idx, j])
-                self._emit(req, t)
-                if (eos is not None and t == eos) or len(req.tokens) >= req.max_new:
-                    # retire NOW: steps the chunk computed past this point
-                    # were for a finished request — the waste continuous
-                    # batching exists to bound (< chunk, vs batch-drain
-                    # padding under coalesce). Under spec this also drops
-                    # accepted tokens past a mid-round EOS.
-                    wasted += n_emit - (j + 1)
-                    state.active[idx] = False
-                    lanes[idx] = None
-                    if getattr(state, "paged", False):
-                        self._retire_pages(state, idx, req)
-                    req.finish_t = now
-                    req.done.set()
-                    retired_n += 1
-                    break
+        with host_span("emit"):
+            for idx, req in enumerate(lanes):
+                if req is None or req.pf_pos is not None:
+                    continue
+                # spec rounds emit a VARIABLE per-row prefix (the accepted
+                # draft run + the verify's correction token); plain chunks
+                # emit exactly `chunk` tokens per live lane
+                n_emit = chunk if accept is None else int(accept[idx])
+                for j in range(n_emit):
+                    t = int(toks[idx, j])
+                    self._emit(req, t)
+                    if (eos is not None and t == eos) or len(req.tokens) >= req.max_new:
+                        # retire NOW: steps the chunk computed past this point
+                        # were for a finished request — the waste continuous
+                        # batching exists to bound (< chunk, vs batch-drain
+                        # padding under coalesce). Under spec this also drops
+                        # accepted tokens past a mid-round EOS.
+                        wasted += n_emit - (j + 1)
+                        state.active[idx] = False
+                        lanes[idx] = None
+                        if getattr(state, "paged", False):
+                            self._retire_pages(state, idx, req)
+                        req.finish_t = now
+                        req.done.set()
+                        retired_n += 1
+                        break
+        emit_s = time.monotonic() - now
         if wasted and eng.metrics is not None:
             eng.metrics.gen_wasted_steps.labels("continuous").inc(wasted)
         if accept is not None and hasattr(rt, "_spec_observe"):
@@ -1535,15 +1543,18 @@ class _ContinuousScheduler:
             prefix_hits_n, prefill_s_sum, tokens_in_n,
             drafted=drafted, accepted=accepted,
             emitted=accepted if accept is not None else None,
+            chunk_s=now - chunk_t0, emit_s=emit_s,
         )
         return state
 
     def _record_step(
         self, state, chunk, active, admitted, retired, wasted, step_t0,
         prefix_hits=0, prefill_s=0.0, tokens_in=0,
-        drafted=0, accepted=0, emitted=None,
+        drafted=0, accepted=0, emitted=None, chunk_s=0.0, emit_s=0.0,
     ) -> None:
-        """One flight-recorder ring entry per chunk boundary, plus the
+        """One flight-recorder ring entry per chunk boundary (``step_ms``
+        split into the prefill clocks ``_step`` already keeps, the decode
+        chunk and the emission loop; the rest is the engine's own), plus the
         oldest-queued-age gauge (`gen_admission_wait` only observes at
         admission — a row starved behind page exhaustion is invisible there
         until it finally admits; this gauge shows it starving)."""
@@ -1592,6 +1603,8 @@ class _ContinuousScheduler:
             wasted=wasted, queue_depth=depth, oldest_wait_ms=wait_ms,
             pages_shared=shared, prefix_hits=prefix_hits,
             drafted=drafted, accepted=accepted,
+            prefill_ms=prefill_s * 1e3, chunk_ms=chunk_s * 1e3,
+            emit_ms=emit_s * 1e3,
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

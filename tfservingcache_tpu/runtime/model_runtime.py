@@ -41,7 +41,7 @@ from tfservingcache_tpu.utils.flight_recorder import RECORDER
 from tfservingcache_tpu.utils.lockcheck import lockchecked
 from tfservingcache_tpu.utils.logging import get_logger
 from tfservingcache_tpu.utils.metrics import Metrics
-from tfservingcache_tpu.utils.tracing import TRACER
+from tfservingcache_tpu.utils.tracing import TRACER, current_span
 
 log = get_logger("runtime")
 
@@ -2924,6 +2924,22 @@ class TPUModelRuntime(BaseRuntime):
 
     # -- unload / introspection --------------------------------------------
     def _on_evict(self, model_id: ModelId, entry: LRUEntry[LoadedModel]) -> None:
+        """``_resident``'s eviction callback. Where ``_resident.put`` makes
+        room inside a ``load`` the eviction is that request's time: an
+        ``evict`` span under the load (attrs: victim, bytes, ``demoted``).
+        Outside any request (unload, close) there is no trace to join."""
+        if current_span() is None:
+            self._evict(model_id, entry)
+            return
+        with TRACER.span(
+            "evict", victim=str(model_id), bytes=entry.size_bytes
+        ) as sp:
+            sp.attrs["demoted"] = self._evict(model_id, entry)
+
+    def _evict(self, model_id: ModelId, entry: LRUEntry[LoadedModel]) -> str:
+        """-> how the victim reached the host tier: ``retained`` (its packed
+        entry was already there: an LRU touch), ``queued`` (handed to the
+        demote worker) or ``none`` (no host tier)."""
         self._set_state(model_id, ModelState.UNLOADING)
         if self._prefix_cache is not None:
             # an unloaded model's prefix KV must not outlive it in HBM
@@ -2947,8 +2963,12 @@ class TPUModelRuntime(BaseRuntime):
         # slow demotion cannot block concurrent hits on other models. The
         # queue item holds the LoadedModel, keeping the device arrays alive
         # until the worker has copied them out.
-        if self._host_tier is not None and not self._host_tier.touch(model_id):
-            self._demote_queue.put(("demote", model_id, entry.payload))
+        demoted = "none"
+        if self._host_tier is not None:
+            demoted = "retained"
+            if not self._host_tier.touch(model_id):
+                self._demote_queue.put(("demote", model_id, entry.payload))
+                demoted = "queued"
         # Only the LRU's reference is dropped; in-flight predicts holding the
         # LoadedModel keep the device arrays alive until they finish, then XLA
         # frees the HBM when the last reference goes. (Nulling the fields here
@@ -2975,6 +2995,7 @@ class TPUModelRuntime(BaseRuntime):
             self.metrics.evictions.labels("hbm").inc()
         self._update_gauges()
         log.info("unloaded %s (freed %d HBM bytes)", model_id, entry.size_bytes)
+        return demoted
 
     def unload(self, model_id: ModelId) -> None:
         self._resident.remove(model_id, run_callback=True)

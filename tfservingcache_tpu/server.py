@@ -377,6 +377,7 @@ async def serve(cfg: Config) -> None:
         noisy_min_step_s=cfg.observability.noisy_neighbor_min_step_s,
     )
     node = CacheNode(cfg)
+    RECORDER.configure(metrics=node.metrics)   # tpusc_flight_dumps_total
     if cfg.observability.lab_faults:
         # scenario-lab chaos drill (lab/faults.py): armed ONLY when the
         # operator set observability.lab_faults (or its env override) — the

@@ -24,9 +24,10 @@ it went wrong. This module is the box's flight recorder:
   retention path), page-exhaustion blocking, and engine-thread crash each
   write the full ring + engine state to a bounded spool dir
   (``observability.flight_dir``). Dumps are deduplicated (per trace id)
-  and rate-limited (per reason+model cooldown) so one incident is one
-  file, not a disk-filling stream. ``tools/engine_dump.py`` pretty-prints
-  them for postmortems.
+  AND rate-limited (per reason+model cooldown, keyed dumps included) so
+  one incident is one file, not a disk-filling stream;
+  ``tpusc_flight_dumps_total{reason,outcome}`` counts both outcomes.
+  ``tools/engine_dump.py`` pretty-prints them for postmortems.
 
 Like the tracer (utils/tracing.py) the recorder is a process-wide default
 instance: diagnostics are write-mostly and bounded, so a global keeps
@@ -76,6 +77,12 @@ STEP_FIELDS = (
     # appended fields (ISSUE 16 in-engine speculative decoding)
     "drafted",         # draft tokens proposed this step (0 = plain chunk)
     "accepted",        # tokens emitted by the verify round this step
+    # appended fields (ISSUE 23 boundary split): parts of step_ms; what is
+    # left of it is the engine's self time (admission scan, page accounting,
+    # ring and ledger writes)
+    "prefill_ms",      # admission prefills + chunked-prefill phase
+    "chunk_ms",        # decode chunk / spec round: upload, dispatch, wait, fetch
+    "emit_ms",         # emission and retirement loop
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -87,8 +94,8 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     full-width tuples, so the common case is a literal build (~3x faster
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
-    from pre-ISSUE-9 dumps) fall back to zip."""
-    if len(e) == 16:
+    from dumps older than the newest appended field) fall back to zip."""
+    if len(e) == 19:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -96,6 +103,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "queue_depth": e[10], "oldest_wait_ms": e[11],
             "pages_shared": e[12], "prefix_hits": e[13],
             "drafted": e[14], "accepted": e[15],
+            "prefill_ms": e[16], "chunk_ms": e[17], "emit_ms": e[18],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -159,6 +167,7 @@ class FlightRecorder:
         self.flight_dir = flight_dir
         self.max_dumps = max(1, int(max_dumps))
         self.dump_cooldown_s = float(dump_cooldown_s)
+        self.metrics: Any = None             # utils.metrics.Metrics, once served
         self._lock = threading.Lock()        # structure mutations only
         self._rings: dict[str, _Ring] = {}  # guarded-by: _lock
         self._phases: dict[str, collections.deque] = {}  # guarded-by: _lock
@@ -183,11 +192,15 @@ class FlightRecorder:
         ring_entries: int | None = None,
         max_dumps: int | None = None,
         dump_cooldown_s: float | None = None,
+        metrics: Any = None,
     ) -> None:
         """Apply config to the process-wide recorder (server startup). An
         empty/None ``flight_dir`` keeps dumps disabled; existing rings keep
-        their size (resizing would drop the history worth keeping)."""
+        their size (resizing would drop the history worth keeping).
+        ``metrics`` is the node's registry, for the dump counter."""
         with self._lock:
+            if metrics is not None:
+                self.metrics = metrics
             if flight_dir is not None:
                 self.flight_dir = flight_dir or None
             if ring_entries is not None:
@@ -200,14 +213,17 @@ class FlightRecorder:
     def install_slow_hook(self, tracer: Any) -> None:
         """Hook the tracer's slow-trace retention path: every root span
         that crosses ``slow_threshold_s`` (the same tail-sampling gate that
-        keeps the trace findable) also triggers one engine dump, deduped by
-        trace id so one breached request is exactly one file."""
+        keeps the trace findable) asks for an engine dump, deduped by trace
+        id so one breached request is at most one file, and held to the
+        model's cooldown so a stream of breaches (every streamed chat
+        request outlasts the threshold) is one file a cooldown."""
         tracer.slow_hook = self._on_slow_trace
 
     def _on_slow_trace(self, span: Any) -> None:
         self.dump(
             "slo_breach",
             dedup_key=("slo", span.trace_id),
+            model=span.attrs.get("model"),
             trace_id=span.trace_id,
             root_span=span.name,
             duration_s=round(span.duration_s, 6),
@@ -240,12 +256,16 @@ class FlightRecorder:
         prefix_hits: int = 0,
         drafted: int = 0,
         accepted: int = 0,
+        prefill_ms: float = 0.0,
+        chunk_ms: float = 0.0,
+        emit_ms: float = 0.0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
             retired, pages_used, pages_free, wasted, queue_depth,
             round(oldest_wait_ms, 3), pages_shared, prefix_hits,
             drafted, accepted,
+            round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
         ))
 
     def note_phases(
@@ -464,23 +484,35 @@ class FlightRecorder:
     ) -> str | None:
         """Write the full ring + engine state to the spool dir. Returns the
         file path, or None when dumps are disabled / deduped / cooling
-        down. Never raises: a failing dump must not fail the request or
-        kill the scheduler thread that tripped it."""
+        down: a ``dedup_key`` writes at most once, and every dump, keyed or
+        not, waits out ``dump_cooldown_s`` since the last file of its
+        ``(reason, model)``. ``tpusc_flight_dumps_total{reason,outcome}``
+        counts what was written and what was held back. Never raises: a
+        failing dump must not fail the request or kill the scheduler
+        thread that tripped it."""
         if self.flight_dir is None:
             return None
         now = time.monotonic()
+        cool_key = (reason, model or "")
         with self._lock:
-            if dedup_key is not None:
-                if dedup_key in self._dumped_keys:
-                    return None
+            last = self._last_dump.get(cool_key)
+            seen = dedup_key is not None and dedup_key in self._dumped_keys
+            write = not seen and (
+                last is None or now - last >= self.dump_cooldown_s
+            )
+            if dedup_key is not None and not seen:
+                # remembered even when the cooldown holds the file back: one
+                # incident is judged once
                 self._dumped_keys.append(dedup_key)
-            else:
-                cool_key = (reason, model or "")
-                last = self._last_dump.get(cool_key)
-                if last is not None and now - last < self.dump_cooldown_s:
-                    return None
+            if write:
                 self._last_dump[cool_key] = now
-            seq = next(self._dump_seq)
+                seq = next(self._dump_seq)
+        if self.metrics is not None:
+            self.metrics.flight_dumps.labels(
+                reason, "written" if write else "suppressed"
+            ).inc()
+        if not write:
+            return None
         try:
             payload = self.snapshot(tail=self.ring_entries, row_budget=None)
             payload.update(

@@ -240,6 +240,30 @@ class Metrics:
             "freeze_scheduler|stall_store|corrupt_peer_chunk|drop_peer)",
             ["kind"], registry=r,
         )
+        # Flight-recorder dumps (utils/flight_recorder.py dump()): files
+        # written and requests for one held back by dedup or cooldown. An
+        # operator alerts on `written`; `suppressed` says how many more
+        # breaches the cooldown swallowed.
+        self.flight_dumps = Counter(
+            "tpusc_flight_dumps",
+            "Flight-recorder anomaly dumps asked for (reason=slo_breach|"
+            "page_exhaustion|engine_crash|..., outcome=written|suppressed: "
+            "held back by trace-id dedup or the per-model cooldown)",
+            ["reason", "outcome"], registry=r,
+        )
+        # The serving pool (protocol/local_backend.py _run): submit -> worker
+        # start of every pool job. count/sum against the request rate give
+        # the busy threads; a streamed :generate holds its thread until the
+        # row ends, so this wait is where a saturated pool shows.
+        self.pool_wait = Histogram(
+            "tpusc_pool_wait_seconds",
+            "Wait for one of the serving pool's threads, submit to worker "
+            "start (what=predict|generate|ensure|session_run|..., codec for "
+            "the REST parse/encode hops)",
+            ["what"], registry=r,
+            buckets=(.0001, .00025, .0005, .001, .0025, .005, .01, .025, .05,
+                     .1, .25, .5, 1, 2.5, 5, 10, 30),
+        )
         # Per-request phase attribution (runtime/batcher.py engines): where
         # a generate request's wall time went — admission queue, prompt
         # prefill, decode steps, or response assembly. The same clocks land

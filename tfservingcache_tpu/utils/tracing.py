@@ -21,9 +21,18 @@ which is exactly when the one 4-second outlier you need has been evicted.
 Roots slower than ``slow_threshold_s`` are retained in a separate bounded
 buffer and surface via ``query(min_duration_s=...)``.
 
-Overhead when idle: one contextvar lookup, two ``monotonic()`` calls, and
-one 64-bit random id per span — cheap enough to leave always-on (guarded by
-tests/test_observability.py); the buffers bound memory.
+Device clock: every span also enters a ``jax.profiler.TraceAnnotation``
+named ``tpusc.<span name>``, so a profiler capture (``POST
+/monitoring/profiler``) holds the request's spans as host events on the same
+clock as the device's operations. ``host_span(name)`` is that annotation
+alone, for threads with no request context (the engine's scheduler): it
+writes nothing to the span ring. ``tools/trace_scopes.py`` reads both.
+
+What a span costs, with no capture running: one contextvar lookup, two
+``monotonic()`` calls, one 64-bit random id and one annotation object (under
+1 us of the span's few) — cheap enough to leave always-on (guarded at
+< 25 us a span by tests/test_observability.py, ``host_span`` alone at < 5 us
+by tests/test_tracing.py); the buffers bound memory.
 """
 
 from __future__ import annotations
@@ -38,7 +47,7 @@ import time
 import zlib
 
 from tfservingcache_tpu.utils.lockcheck import lockchecked
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Iterator
 
@@ -60,6 +69,31 @@ _TRACEPARENT_RE = re.compile(
 # SystemRandom would be overkill (ids are diagnostics, not secrets) and
 # os.urandom costs a syscall per span; Random is a few hundred ns.
 _rand = random.Random()
+
+
+# jax.profiler.TraceAnnotation, resolved at first use (jax is imported
+# lazily: the tracer must not drag it into processes that never serve);
+# a nullcontext where jax is absent
+_annotation: Any = None
+
+
+def _resolve_annotation() -> Any:
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation as cls
+    except Exception:  # noqa: BLE001 - no jax here: spans stay host-only
+        def cls(_name: str) -> Any:
+            return nullcontext()
+    _annotation = cls
+    return cls
+
+
+def host_span(name: str) -> Any:
+    """A ``tpusc.<name>`` event in the profiler's own trace while a capture
+    runs, on the device's clock; nothing otherwise, and nothing in the span
+    ring either way. The event starts when this is called (TraceMe starts in
+    its constructor), so call it in the ``with`` statement itself."""
+    return (_annotation or _resolve_annotation())("tpusc." + name)
 
 
 def _new_span_id() -> str:
@@ -116,6 +150,12 @@ def current_ids() -> tuple[str, str] | None:
     if sp is None:
         return None
     return sp.trace_id, sp.span_id
+
+
+def current_span() -> "Span | None":
+    """The innermost open span of this context, for a hop that must hand it
+    to another thread (``Tracer.attach`` takes the parent explicitly)."""
+    return _current_span.get()
 
 
 @dataclass
@@ -280,12 +320,14 @@ class Tracer:
                 sp.trace_id = _new_trace_id()
             sp.root = sp
         token = _current_span.set(sp)
+        mark = host_span(name)
         try:
             yield sp
         except BaseException as e:
             sp.error = f"{type(e).__name__}: {e}"
             raise
         finally:
+            mark.__exit__(None, None, None)
             sp.duration_s = time.monotonic() - sp.t0
             _current_span.reset(token)
             if parent is not None:

@@ -60,8 +60,14 @@ def _fmt_step(s: dict) -> str:
         f"pages={shared}s+{max(0, used - shared)}p"
         f"/{s.get('pages_free', 0)}f"
     )
+    # the boundary's split (ISSUE 23); absent in dumps of 16-field rings
+    split = ""
+    if "chunk_ms" in s:   # the three fields come together
+        pre, chunk, emit = s["prefill_ms"], s["chunk_ms"], s["emit_ms"]
+        own = max(0.0, s.get("step_ms", 0) - pre - chunk - emit)
+        split = f"(prefill={pre:.2f} chunk={chunk:.2f} emit={emit:.2f} self={own:.2f}) "
     return (
-        f"  {s.get('engine', '?'):<10} step={s.get('step_ms', 0):>8.2f}ms "
+        f"  {s.get('engine', '?'):<10} step={s.get('step_ms', 0):>8.2f}ms {split}"
         f"chunk={s.get('chunk', 0):>3} active={s.get('active', 0):>3} "
         f"+{s.get('admitted', 0)}/-{s.get('retired', 0)} "
         f"wasted={s.get('wasted', 0):>3} "
