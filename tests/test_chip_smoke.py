@@ -9,6 +9,7 @@
 - the attention gates record the branch they traced.
 """
 
+import contextlib
 import os
 import re
 import subprocess
@@ -222,6 +223,8 @@ def test_kernel_refusal_reasons(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert att._kernel_refusal(128, 32, 32) is None
     assert att._kernel_refusal(64, 8, 2) is None
+    # the paged decode gate: its kernel copies pages out of HBM itself
+    assert "128" in att._kernel_refusal(64, 8, 2, head_multiple=128)
     assert "head_dim" in att._kernel_refusal(48, 4, 4)
     assert "kv heads" in att._kernel_refusal(64, 6, 4)
     q = jax.ShapeDtypeStruct((1, 4, 128, 64), jnp.bfloat16)
@@ -239,9 +242,9 @@ def _export_tpu(fn, *args):
     return jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
 
-def _paged_args(g, d, quantized, t_q):
+def _paged_args(g, d, quantized, t_q, lanes=4, hkv=4, pps=8):
     s = jax.ShapeDtypeStruct
-    lanes, hkv, pt, pps = 4, 4, 16, 8
+    pt = 16
     n_pages = lanes * pps + 1
     q = s((lanes, hkv * g, t_q, d), jnp.bfloat16)
     pages = s((n_pages, hkv, pt, d), jnp.int8 if quantized else jnp.bfloat16)
@@ -275,16 +278,21 @@ def test_flash_kernels_lower_for_tpu(variant, monkeypatch):
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("g", [1, 2, 4])
+@pytest.mark.parametrize("g", [1, 2, 3, 4])
 @pytest.mark.parametrize("kernel", ["decode", "verify"])
 def test_paged_kernels_lower_for_tpu(kernel, g, d, quantized):
     """The int8 arms failed here before this test existed: a per-head
     ``(1, 1, page_tokens)`` scale block is neither the array's own last two
-    dims nor an (8, 128) multiple, which the Pallas TPU lowering refuses."""
+    dims nor an (8, 128) multiple, which the Pallas TPU lowering refuses.
+    The decode kernel refuses head 64 by name (it copies pages out of HBM
+    itself, and Mosaic slices an HBM operand in whole 128-lane tiles)."""
     args, pt = _paged_args(g, d, quantized, 1 if kernel == "decode" else 5)
     fn = (att.paged_decode_attention_kernel if kernel == "decode"
           else att.paged_verify_attention_kernel)
-    _export_tpu(lambda *a: fn(*a, page_tokens=pt), *args)
+    refused = kernel == "decode" and d % 128
+    with (pytest.raises(ValueError, match="multiple of 128") if refused
+          else contextlib.nullcontext()):
+        _export_tpu(lambda *a: fn(*a, page_tokens=pt), *args)
 
 
 # -- kernels compile for a v5e (Mosaic), still without a device ----------------
@@ -340,13 +348,23 @@ def _compile_only_main():
     for kernel, t_q in (("decode", 1), ("verify", 5)):
         fn = (att.paged_decode_attention_kernel if kernel == "decode"
               else att.paged_verify_attention_kernel)
-        for g in (1, 2, 4):
-            for d in (64, 128):
+        for g in (1, 2, 3, 4):
+            # head 64: the decode kernel refuses it (ValueError, the test
+            # above); the verify kernel's blocks come through the pipeline
+            for d in ((128,) if kernel == "decode" else (64, 128)):
                 for quantized in (False, True):
                     args, pt = _paged_args(g, d, quantized, t_q)
                     compile_(f"{kernel} g={g} d={d} int8={quantized}",
                              lambda *a: fn(*a, page_tokens=pt),
                              *on(one, args))
+    # the benchmark's steady cell: 32 lanes, 8 kv heads x group 4 of 128,
+    # 128 table slots over a 4096-page arena, with the chunk's active vector
+    args, pt = _paged_args(4, 128, False, 1, lanes=32, hkv=8, pps=128)
+    args[1] = args[2] = s((4096,) + args[1].shape[1:], jnp.bfloat16)
+    compile_("decode at the steady cell's shape",
+             lambda *a: att.paged_decode_attention_kernel(
+                 *a[:5], None, None, a[5], page_tokens=pt),
+             *on(one, args + [s((32,), jnp.bool_)]))
     # a tensor-parallel :predict at a flash-qualifying shape: the gate must
     # keep the bare Mosaic kernel out of the partitioned program (the TPU
     # lowering refuses to partition one). The gates ask jax.default_backend()
@@ -373,9 +391,10 @@ def _compile_only_main():
 def test_kernels_compile_for_v5e_without_a_device():
     """Lowering proves the Pallas front end accepts a kernel; Mosaic is what
     has to compile it. libtpu can describe a v5e topology and compile for it
-    with no chip attached, so the block shapes, the sub-tile int8 pages, the
-    g < 8 query blocks and the in-kernel scale rows all meet the real
-    compiler here. Skipped where libtpu cannot describe the topology."""
+    with no chip attached, so the block shapes, the page copies out of HBM
+    (sub-tile int8 pages among them), the g < 8 query blocks and the scale
+    rows all meet the real compiler here. Skipped where libtpu cannot
+    describe the topology."""
     r = _run(["-c", "import sys; sys.path.insert(0, 'tests'); "
                     "import test_chip_smoke; "
                     "test_chip_smoke._compile_only_main()"],

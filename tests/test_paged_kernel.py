@@ -1,10 +1,12 @@
 """Fused Pallas paged-attention decode kernel (`serving.kv_paged_kernel`):
-interpret-mode kernel-vs-reference parity (ragged pos, page_tokens in
-{8,16}, GQA groups in {1,4}, int8 arenas), byte-for-byte reference
+interpret-mode kernel-vs-reference parity (ragged pos and block edges,
+page_tokens in {8,16}, GQA groups in {1,3,4}, int8 arenas, inactive lanes,
+a poisoned arena), byte-for-byte reference
 dispatch with the knob off, greedy token-for-token parity kernel-on vs
 kernel-off through the continuous engine, and the hardware-gated
 `paged_decode` entries tools/tpu_kernel_check.py runs on a real chip
-(max-abs-err + bandwidth-proxy timing at S in {4,16,32} lanes)."""
+(max-abs-err + bandwidth-proxy timing at S in {4,16,32} lanes and at the
+benchmark's steady cell: 10 of 32 lanes active, ragged lengths)."""
 
 import jax
 import jax.numpy as jnp
@@ -45,9 +47,10 @@ TINY = {
 PT = 8
 
 
-def _arena(lanes, hq, hkv, d, pps, pt, seed=0, dtype=np.float32):
-    """Random scattered arena + ragged pos: every lane's pages land at
-    shuffled arena slots (page 0 stays trash), trailing table slots 0."""
+def _arena(lanes, hq, hkv, d, pps, pt, seed=0, dtype=np.float32, pos=None):
+    """Random scattered arena + ragged pos (random unless given): every
+    lane's pages land at shuffled arena slots (page 0 stays trash),
+    trailing table slots 0."""
     rng = np.random.default_rng(seed)
     n_pages = lanes * pps + 1
     perm = rng.permutation(np.arange(1, n_pages))
@@ -55,7 +58,8 @@ def _arena(lanes, hq, hkv, d, pps, pt, seed=0, dtype=np.float32):
     k_pages = rng.standard_normal((n_pages, hkv, pt, d)).astype(dtype)
     v_pages = rng.standard_normal((n_pages, hkv, pt, d)).astype(dtype)
     q = rng.standard_normal((lanes, hq, 1, d)).astype(dtype)
-    pos = rng.integers(0, pps * pt, lanes).astype(np.int32)
+    if pos is None:
+        pos = rng.integers(0, pps * pt, lanes).astype(np.int32)
     # park table slots past each lane's live pages on trash, as the real
     # block tables do — the kernel's clamped index map must never read them
     for s in range(lanes):
@@ -64,24 +68,75 @@ def _arena(lanes, hq, hkv, d, pps, pt, seed=0, dtype=np.float32):
     return q, k_pages, v_pages, tables, pos
 
 
+def _edge_case(hq, hkv, d, pt, seed):
+    """The shapes the block loop can get wrong, in one arena: ``pos`` at 0,
+    on a compute block's last token and on the next block's first, a full
+    table, a mid-page lane; ``pages_per_slot`` NOT a multiple of the block;
+    two INACTIVE lanes with a stale high ``pos`` and a zeroed table (a
+    retired lane, a lane in chunked prefill). Returns the clean arena (for
+    the reference), ``active``, and a POISONED copy of the pages: NaN in
+    every page no live lane's table reaches below its ``pos``, the trash
+    page included — a kernel that reads a dead page fails the comparison."""
+    block = att.PAGED_BLOCK_TOKENS
+    pps = (block + 2 * pt) // pt + 1           # one block, two pages, one odd
+    assert pps % (block // pt)
+    pos = np.array([0, block - 1, block, pps * pt - 1, 2 * pt + 5,
+                    pps * pt - 3, pps * pt], np.int32)
+    active = np.array([1, 1, 1, 1, 1, 0, 0], bool)
+    q, kp, vp, tables, _ = _arena(len(pos), hq, hkv, d, pps, pt, seed=seed,
+                                  pos=pos)
+    tables[~active] = 0
+    poison = np.ones(kp.shape[0], bool)
+    poison[tables[tables > 0]] = False
+    return q, kp, vp, tables, pos, active, poison
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("layout", ["ragged", "edges"])
 @pytest.mark.parametrize("pt", [8, 16])
-@pytest.mark.parametrize("g", [1, 4])  # GQA group size hq/hkv
-def test_kernel_matches_reference_interpret(pt, g):
+@pytest.mark.parametrize("g", [1, 3, 4])  # GQA group size hq/hkv
+def test_kernel_matches_reference_interpret(pt, g, layout, quantized):
     """Interpret-mode kernel parity against the gather+einsum reference
-    over scattered pages and ragged pos, at MHA (g=1) and GQA (g=4)."""
-    hkv = 2
-    q, kp, vp, tables, pos = _arena(
-        lanes=5, hq=hkv * g, hkv=hkv, d=16, pps=4, pt=pt, seed=g * 7 + pt
-    )
-    want = np.asarray(paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(pos), pt,
-    ))
+    over scattered pages, at MHA (g=1) and GQA (g=3, 4), float and int8
+    arenas. ``ragged``: random pos, every lane active (no ``active``
+    operand). ``edges``: ``_edge_case`` — block boundaries, inactive lanes
+    (rows of zeros), and a poisoned arena under the kernel only."""
+    hkv, d = 2, 16
+    seed = g * 7 + pt
+    if layout == "ragged":
+        q, kp, vp, tables, pos = _arena(
+            lanes=5, hq=hkv * g, hkv=hkv, d=d, pps=4, pt=pt, seed=seed
+        )
+        active = poison = None
+    else:
+        q, kp, vp, tables, pos, active, poison = _edge_case(
+            hkv * g, hkv, d, pt, seed
+        )
+    q, tables, pos = jnp.asarray(q), jnp.asarray(tables), jnp.asarray(pos)
+    kern_pages, scales = [jnp.asarray(kp), jnp.asarray(vp)], []
+    ref_pages = list(kern_pages)
+    if quantized:
+        kq, ks = generation._quantize_kv_rows(kern_pages[0])
+        vq, vs = generation._quantize_kv_rows(kern_pages[1])
+        kern_pages, scales = [kq, vq], [ks, vs]
+        ref_pages = [dequantize_pages(kq, ks), dequantize_pages(vq, vs)]
+    if poison is not None:
+        bad = jnp.asarray(poison)
+        kern_pages = [
+            jnp.where(bad[:, None, None, None],
+                      127 if quantized else np.nan, x).astype(x.dtype)
+            for x in kern_pages
+        ]
+        scales = [jnp.where(bad[:, None, None], np.nan, x) for x in scales]
+    want = np.asarray(paged_decode_attention(q, *ref_pages, tables, pos, pt))
     got = np.asarray(paged_decode_attention_kernel(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(tables), jnp.asarray(pos),
+        q, *kern_pages, tables, pos, *scales,
+        active=None if active is None else jnp.asarray(active),
         page_tokens=pt, interpret=True,
     ))
+    if active is not None:
+        assert (got[~active] == 0).all()
+        got, want = got[active], want[active]
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
@@ -196,45 +251,66 @@ def test_greedy_parity_kernel_on_vs_off(tmp_path, interpret_kernel):
     jax.default_backend() != "tpu",
     reason="needs real TPU (conftest forces CPU; run via tools/tpu_kernel_check.py)",
 )
-@pytest.mark.parametrize("lanes", [4, 16, 32])
+@pytest.mark.parametrize("lanes", [4, 16, 32, "cell"])
 def test_paged_decode_kernel_on_tpu(lanes):
     """Hardware proof for the paged decode kernel: Mosaic-compiles and
     matches the gather+einsum reference. The timing ratio against the
-    reference is PRINTED, not asserted: it is a measurement for PERF.md (the
-    kernel's cells and its roofline share are ROADMAP S3's), and a bring-up
-    check must stay about compile + parity. Both sides stream the same live
-    KV bytes; the reference streams them twice (gather out + einsum in)."""
+    reference is PRINTED, not asserted: it is a measurement for PERF.md, and
+    a bring-up check must stay about compile + parity. Rows 4 / 16 / 32:
+    every lane live at 1024 tokens, so both sides stream the same KV bytes
+    (the reference twice: gather out + einsum in). Row ``cell``: the
+    benchmark's steady cell (``mistral7b-chat-steady``): 32 query heads over
+    8 kv heads of 128, 128 table slots, 32 lanes of which 10 are active
+    with ragged lengths 100-1900 and 22 are retired (stale pos, zeroed
+    table) — the kernel's work follows the 10, the reference's all 32."""
     from tfservingcache_tpu.utils.benchtime import chained_device_time
 
-    hq, hkv, d, pt, pps = 8, 8, 128, 16, 64  # 1024-token logical rows
+    pt = 16
+    if lanes == "cell":
+        lanes, hq, hkv, d, pps = 32, 32, 8, 128, 128
+        rng = np.random.default_rng(24)
+        pos = rng.integers(100, 1900, lanes).astype(np.int32)
+        active = np.arange(lanes) < 10
+    else:
+        hq, hkv, d, pps = 8, 8, 128, 64  # 1024-token logical rows
+        # long-lived lanes: bandwidth-bound shape, not mask-bound
+        pos = np.full((lanes,), pps * pt - 1, np.int32)
+        active = np.ones(lanes, bool)
     q, kp, vp, tables, pos = _arena(
-        lanes, hq, hkv, d, pps, pt, seed=lanes
+        lanes, hq, hkv, d, pps, pt, seed=lanes, pos=pos
     )
-    # long-lived lanes: bandwidth-bound shape, not mask-bound
-    pos = np.full((lanes,), pps * pt - 1, np.int32)
-    tables[:, :] = np.arange(1, lanes * pps + 1).reshape(lanes, pps)
+    tables[~active] = 0
+    live_tokens = int((pos + 1)[active].sum())
     q, kp, vp = (jnp.asarray(q, jnp.bfloat16), jnp.asarray(kp, jnp.bfloat16),
                  jnp.asarray(vp, jnp.bfloat16))
     tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+    act = jnp.asarray(active)
 
     out = paged_decode_attention_kernel(
-        q, kp, vp, tables, pos, page_tokens=pt
+        q, kp, vp, tables, pos, active=act, page_tokens=pt
     )
     ref = paged_decode_attention(q, kp, vp, tables, pos, pt)
-    err = float(jnp.max(jnp.abs(out - ref)))
+    assert not np.asarray(out)[~active].any()
+    err = float(jnp.max(jnp.abs(out - ref)[act]))
     assert err < 3e-2, f"paged kernel diverges: max abs err {err}"
 
+    # the arena rides as an argument: closed over, it would be compiled into
+    # the timing loop as a constant of hundreds of MB
+    args = (q, kp, vp, tables, pos, act)
     t_kern = chained_device_time(
-        lambda q: paged_decode_attention_kernel(
-            q, kp, vp, tables, pos, page_tokens=pt
-        ), (q,)
+        lambda q, kp, vp, tables, pos, act: paged_decode_attention_kernel(
+            q, kp, vp, tables, pos, active=act, page_tokens=pt
+        ), args
     )
     t_ref = chained_device_time(
-        lambda q: paged_decode_attention(q, kp, vp, tables, pos, pt), (q,)
+        lambda q, kp, vp, tables, pos, act: paged_decode_attention(
+            q, kp, vp, tables, pos, pt
+        ), args
     )
-    kv_bytes = 2 * lanes * pps * hkv * pt * d * kp.dtype.itemsize
+    kv_bytes = 2 * live_tokens * hkv * d * kp.dtype.itemsize
     print(
-        f"\n[paged_decode] S={lanes} hq={hq} hkv={hkv} d={d} pt={pt}: "
+        f"\n[paged_decode] S={lanes} active={int(active.sum())} "
+        f"live_tokens={live_tokens} hq={hq} hkv={hkv} d={d} pt={pt}: "
         f"kernel {t_kern*1e3:.3f} ms ({kv_bytes/t_kern/1e9:.0f} GB/s proxy), "
         f"gather+einsum {t_ref*1e3:.3f} ms, speedup {t_ref/t_kern:.2f}x, "
         f"max_abs_err {err:.4f}",
@@ -271,9 +347,9 @@ def test_paged_decode_int8_on_tpu():
     err = float(jnp.max(jnp.abs(out8 - out16)))
     assert err < 5e-2, f"int8 kernel diverges from bf16: max abs err {err}"
     t8 = chained_device_time(
-        lambda q: paged_decode_attention_kernel(
+        lambda q, kq, vq, tables, pos, ks, vs: paged_decode_attention_kernel(
             q, kq, vq, tables, pos, ks, vs, page_tokens=pt
-        ), (q16,)
+        ), (q16, kq, vq, tables, pos, ks, vs)
     )
     print(
         f"\n[paged_decode int8] S={lanes}: kernel {t8*1e3:.3f} ms, "
@@ -313,16 +389,28 @@ def _tpu_case(g, d, quantized, t_q=1, seed=0):
 )
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("g", [1, 2, 4])
+@pytest.mark.parametrize("g", [1, 2, 3, 4])
 def test_paged_decode_shapes_on_tpu(g, d, quantized):
-    """Bring-up matrix for the decode kernel: the (g, d) query block with
-    g < 8, the (g, 128) scratch rows, head_dim 64 (half a lane tile) and
-    the int8 (16, d) page block all meet Mosaic here; compile + parity
-    against the jnp reference (dequantized pages for int8), no timing."""
+    """Bring-up matrix for the decode kernel: the (hkv, g, d) query block
+    with g < 8, the (hkv, g, 128) scratch rows, the int8 page copies and
+    the gathered scale rows all meet Mosaic here; compile + parity against
+    the jnp reference (dequantized pages for int8), no timing. Head 64 is
+    refused BY NAME: the kernel copies pages out of HBM itself and Mosaic
+    slices an HBM operand in whole 128-lane tiles only, so the kernel
+    raises and the ``paged_attention`` gate traces the reference there."""
     kern_args, (kr, vr), pt = _tpu_case(g, d, quantized, seed=g * 10 + d)
-    out = paged_decode_attention_kernel(*kern_args, page_tokens=pt)
     q, _, _, tables, pos = kern_args[:5]
     ref = paged_decode_attention(q, kr, vr, tables, pos, pt)
+    if d % 128:
+        with pytest.raises(ValueError, match="multiple of 128"):
+            paged_decode_attention_kernel(*kern_args, page_tokens=pt)
+        why = ("paged_attention", "reference",
+               f"head_dim={d} not a multiple of 128")
+        before = att.dispatch_tally().get(why, 0)
+        out = paged_attention(*kern_args[:5], pt, *kern_args[5:])
+        assert att.dispatch_tally().get(why, 0) == before + 1
+    else:
+        out = paged_decode_attention_kernel(*kern_args, page_tokens=pt)
     err = float(jnp.max(jnp.abs(out - ref)))
     print(f"\n[paged_decode shapes] g={g} d={d} "
           f"{'int8' if quantized else 'bf16'}: max_abs_err {err:.4f}",

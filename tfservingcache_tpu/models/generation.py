@@ -442,7 +442,7 @@ def _quantize_kv_rows(x):
 
 
 def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
-                        page_tokens: int, kernel: bool = False):
+                        page_tokens: int, kernel: bool = False, active=None):
     """One decode step (s_len=1 per lane) against the paged arena — the
     block-table counterpart of ``_forward_cached_dyn``. Each lane writes its
     new K/V at ``tables[lane, pos // page_tokens]`` offset ``pos %
@@ -455,7 +455,11 @@ def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
 
     An int8 arena (``cache["k_scale"]`` present) quantizes each lane's new
     row at write time — per-row scales, so resident rows are never
-    requantized — and attention dequantizes on the read side."""
+    requantized — and attention dequantizes on the read side.
+
+    ``active`` (the chunk's frozen ``(S,)`` vector, default all lanes) goes
+    to ``paged_attention`` as it is: the caller discards an inactive lane's
+    token, so the kernel reads no page for it."""
     from tfservingcache_tpu.ops.attention import paged_attention
 
     dtype = jnp.dtype(cfg["dtype"])
@@ -517,7 +521,7 @@ def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
             with jax.named_scope("attn"):
                 out = paged_attention(q, k_arena, v_arena, tables, pos, page_tokens,
                                       k_scale=ks_arena, v_scale=vs_arena,
-                                      kernel=kernel)
+                                      kernel=kernel, active=active)
                 out = out.reshape(s_lanes, n_heads, 1, head_dim).astype(x.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(s_lanes, 1, cfg["d_model"])
                 x = x + out @ attn["wo"]
@@ -831,7 +835,7 @@ def _paged_decode_chunk_jit(
         cache, tok, pos = carry
         logits, cache = _paged_forward_step(
             params, tok, cache, tables, pos, cfg, family,
-            page_tokens, kernel=kernel,
+            page_tokens, kernel=kernel, active=active,
         )
         nxt = _sample_per_row(logits[:, 0], rng, temperature, top_k)
         nxt = jnp.where(active, nxt, tok)

@@ -62,16 +62,18 @@ def dispatch_tally() -> dict[tuple[str, str, str], int]:
         return dict(_DISPATCH_TALLY)
 
 
-def _kernel_refusal(head_dim: int, hq: int, hkv: int) -> str | None:
+def _kernel_refusal(head_dim: int, hq: int, hkv: int,
+                    head_multiple: int = 64) -> str | None:
     """Why the TPU kernels cannot take these shapes on this backend (None =
     they can). Shared by every gate: the backend must be the TPU, head_dim a
-    multiple of 64 (Mosaic pads the 128-lane dim) and the query heads a
-    multiple of the kv heads."""
+    multiple of ``head_multiple`` (64 where Mosaic's own pipeline fetches the
+    blocks and pads the 128-lane dim) and the query heads a multiple of the
+    kv heads."""
     backend = jax.default_backend()
     if backend != "tpu":
         return f"backend={backend}"
-    if head_dim % 64:
-        return f"head_dim={head_dim} not a multiple of 64"
+    if head_dim % head_multiple:
+        return f"head_dim={head_dim} not a multiple of {head_multiple}"
     if hq % hkv:
         return f"q heads {hq} not a multiple of kv heads {hkv}"
     return None
@@ -687,86 +689,151 @@ def _head_scale_row(scale_ref, head):
     return scale_ref[0, pl.ds(head, 1), :]
 
 
+# Tokens one compute block of the paged decode kernel covers: that many
+# tokens' pages are fetched by one round of DMAs and multiplied as one
+# (tokens, d) operand per kv head. 128 fills the MXU's contraction side of the
+# value product; a constant of the kernel, not a serving option.
+PAGED_BLOCK_TOKENS = 128
+
+
 def _paged_decode_kernel(
-    tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest, sm_scale: float,
-    page_tokens: int, num_pages: int, quantized: bool,
+    tables_ref, pos_ref, active_ref, q_ref, k_hbm, v_hbm, *rest,
+    sm_scale: float, page_tokens: int, block_pages: int, quantized: bool,
 ):
-    """One (lane, kv-head, table-slot) grid step of paged decode attention.
+    """One LANE of paged decode attention: a grid step per lane, and inside
+    it a loop over the lane's LIVE pages only, ``block_pages`` at a time.
 
-    The grid's last dimension walks the lane's block-table row; the
-    BlockSpec index maps (scalar-prefetched tables/pos) turn each step into
-    a DMA of exactly one arena page — the kernel reads the arena IN PLACE,
-    so the ``pages[tables]`` gathered intermediate of ``paged_gather_kv``
-    (a full extra HBM round-trip of every lane's live KV per decode step)
-    never exists. Online-softmax carry lives in VMEM scratch exactly like
-    ``_flash_streamed_kernel``; table slots past ``pos // page_tokens`` are
-    clamped to the last live page by the index map (consecutive equal block
-    indices elide the re-fetch) and skipped by ``pl.when``, so bytes
-    streamed track each lane's true length, not pages_per_slot.
+    K and V stay in HBM. The arena's layer slice is page-major
+    ``(n_pages, hkv, pt, d)``, so one page with all its kv heads is one
+    contiguous run: each block is ``block_pages`` page copies for K and as
+    many for V (``make_async_copy`` into ``buf[slot, :, p]``, a destination
+    strided over heads so that every head's block is contiguous for the
+    product), double-buffered — block n+1 lands while block n is multiplied.
+    All heads of a block are computed in the step that fetched it, with the
+    online-softmax carry in VMEM scratch. Work follows the tokens in flight:
+    a lane costs ``ceil((pos // pt + 1) / block_pages)`` blocks, and an
+    inactive lane (``active_ref`` 0: retired with a stale ``pos``, or in
+    chunked prefill) starts no DMA and writes zeros.
 
-    ``quantized``: K/V blocks arrive int8 with per-(page, head, token) f32
-    scale rows; dequant happens here, on the VMEM-resident page — int8
-    halves the HBM bytes per KV token, which is the whole win."""
+    The last block's slots past the lane's live pages re-fetch its last live
+    page (index clamped): their scores are masked, but the value product
+    still multiplies what the slot holds, so it must be finite — never a
+    dead table entry's trash page or a never-written VMEM slot.
+
+    ``quantized``: pages arrive int8; the lane's per-token f32 scale rows
+    ``(1, hkv, 1, tokens)`` come as ordinary VMEM blocks (the wrapper
+    gathered them: a scale page's 16-wide rows are under the 128 lanes a
+    manual copy can slice) and are applied to scores and probabilities
+    (``_widen_int8``) — int8 halves the HBM bytes per KV token."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_s, m_s, l_s = rest
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sem, acc_s, m_s, l_s = rest
     else:
-        o_ref, acc_s, m_s, l_s = rest
+        o_ref, k_buf, v_buf, sem, acc_s, m_s, l_s = rest
 
-    s_i = pl.program_id(0)
-    h_i = pl.program_id(1)
-    j = pl.program_id(2)
-    pos = pos_ref[s_i]
-
-    @pl.when(j == 0)
-    def _init():
-        acc_s[...] = jnp.zeros_like(acc_s)
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-
+    lane = pl.program_id(0)
+    pos = pos_ref[lane]
+    hkv, _, d = q_ref.shape[1:]
+    block_tokens = block_pages * page_tokens
     # a table slot is live iff its first token is at or before pos — the
     # same visibility rule as the reference mask, so the two paths reduce
     # over the same token set
-    @pl.when(j <= pos // page_tokens)
-    def _body():
-        q = q_ref[0, 0]                                     # (g, d)
-        k = k_ref[0, 0]                                     # (pt, d)
-        v = v_ref[0, 0]
+    n_live = jnp.minimum(pos // page_tokens + 1, tables_ref.shape[1])
+    n_blocks = jnp.where(active_ref[lane] != 0,
+                         pl.cdiv(n_live, block_pages), 0)
+
+    def block_copies(blk, slot):
+        copies = []
+        for p in range(block_pages):
+            page = tables_ref[
+                lane, jnp.minimum(blk * block_pages + p, n_live - 1)
+            ]
+            copies += [
+                pltpu.make_async_copy(
+                    k_hbm.at[page], k_buf.at[slot, :, p], sem.at[0, slot]),
+                pltpu.make_async_copy(
+                    v_hbm.at[page], v_buf.at[slot, :, p], sem.at[1, slot]),
+            ]
+        return copies
+
+    acc_s[...] = jnp.zeros_like(acc_s)
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for c in block_copies(0, 0):
+            c.start()
+
+    def block_step(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            for c in block_copies(blk + 1, 1 - slot):
+                c.start()
+
+        for c in block_copies(blk, slot):
+            c.wait()
+        q = q_ref[0]                                        # (hkv, g, d)
+        k = k_buf[slot]                                     # (hkv, bp, pt, d)
+        v = v_buf[slot]
         if quantized:
             k, v, q = _widen_int8(k, v, q)
+        k = k.reshape(hkv, block_tokens, d)
+        v = v.reshape(hkv, block_tokens, d)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * sm_scale                                        # (g, pt) f32
+        ) * sm_scale                                        # (hkv, g, T) f32
         if quantized:
-            s = s * _head_scale_row(ks_ref, h_i)
-        k_pos = j * page_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
+            tok0 = pl.multiple_of(blk * block_tokens, block_tokens)
+            s = s * ks_ref[0, :, :, pl.ds(tok0, block_tokens)]
+        k_pos = blk * block_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2
         )
         s = jnp.where(k_pos <= pos, s, NEG_INF)
-        m_prev = m_s[:, :1]
-        l_prev = l_s[:, :1]
+        m_prev = m_s[:, :, :1]
+        l_prev = l_s[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # j <= pos//page_tokens guarantees >= 1 visible token in this page,
-        # so m_new is finite and masked entries underflow to exactly 0
+        # a block that is run holds the lane's token blk * T <= pos, so
+        # m_new is finite and masked entries underflow to exactly 0
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pw = p * _head_scale_row(vs_ref, h_i) if quantized else p
+        if quantized:
+            p = p * vs_ref[0, :, :, pl.ds(tok0, block_tokens)]
         pv = jax.lax.dot_general(
-            pw.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )
+        )                                                   # (hkv, g, d)
         acc_s[...] = acc_s[...] * alpha + pv
         m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
         l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
+        return carry
 
-    @pl.when(j == num_pages - 1)
-    def _final():
-        o_ref[0, 0] = (
-            acc_s[...] / jnp.maximum(l_s[:, :1], 1e-30)
-        ).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, block_step, None)
+    o_ref[0] = (
+        acc_s[...] / jnp.maximum(l_s[:, :, :1], 1e-30)
+    ).astype(o_ref.dtype)
+
+
+def _lane_scale_rows(scale, tables, pos, block_tokens: int):
+    """Each lane's scale rows in token order, ``(S, hkv, 1, tokens)`` with
+    ``tokens`` rounded up to whole compute blocks: ``scale`` is
+    ``(n_pages, hkv, pt)``, a page's rows are 16 lanes wide, so XLA gathers
+    them (1/32 of the int8 KV bytes at head 128) where the pages themselves
+    are copied by the kernel. Rows above ``pos`` are zeroed: the kernel
+    multiplies masked scores and zero probabilities by them, and a dead
+    table slot's trash page may hold anything."""
+    s_lanes, pps = tables.shape
+    _, hkv, pt = scale.shape
+    rows = scale[tables].transpose(0, 2, 1, 3).reshape(s_lanes, hkv, pps * pt)
+    rows = jnp.where(jnp.arange(pps * pt) <= pos[:, None, None], rows, 0.0)
+    pad = -(pps * pt) % block_tokens
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))[:, :, None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("page_tokens", "interpret"))
@@ -778,6 +845,7 @@ def paged_decode_attention_kernel(
     pos: jax.Array,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    active: jax.Array | None = None,
     *,
     page_tokens: int,
     interpret: bool = False,
@@ -785,14 +853,19 @@ def paged_decode_attention_kernel(
     """Fused paged decode attention: same contract as
     ``paged_decode_attention`` (q ``(S, Hq, 1, D)``, arena pages
     ``(n_pages, Hkv, page_tokens, D)``, tables ``(S, pages_per_slot)``,
-    pos ``(S,)`` -> f32 ``(S, Hq, 1, D)``), but ONE pass over the KV bytes:
-    block tables and positions ride in as scalar-prefetch operands so the
-    Pallas pipeline itself walks each lane's pages straight out of the
-    arena. With ``k_scale``/``v_scale`` (``(n_pages, Hkv, page_tokens)``
-    f32) the arena is int8 and dequantized in VMEM per streamed page.
+    pos ``(S,)`` -> f32 ``(S, Hq, 1, D)``), but ONE pass over the LIVE KV
+    bytes: the grid is one step a lane, block tables, positions and the
+    ``active`` vector ride in as scalar-prefetch operands, and the kernel
+    copies each active lane's live pages straight out of the arena,
+    ``PAGED_BLOCK_TOKENS`` tokens a block (``_paged_decode_kernel``). With
+    ``k_scale``/``v_scale`` (``(n_pages, Hkv, page_tokens)`` f32) the arena
+    is int8 and dequantized in VMEM per fetched block. ``active`` (``(S,)``
+    bool, default all true) marks lanes whose output the caller keeps: an
+    inactive lane's row is zeros, whatever its ``pos`` and table say.
 
-    Tables/pos are TRACED data (SMEM), same discipline as the reference
-    path: page recycling/admission churn never mints a new program."""
+    Tables/pos/active are TRACED data (SMEM), same discipline as the
+    reference path: page recycling/admission churn never mints a new
+    program."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -802,61 +875,55 @@ def paged_decode_attention_kernel(
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     if pt != page_tokens:
         raise ValueError(f"arena page_tokens {pt} != {page_tokens}")
+    if d % 128 and not interpret:
+        # Mosaic: "Slice shape along dimension 3 must be aligned to tiling
+        # (128)" — a manual copy cannot take a narrower page out of HBM
+        raise ValueError(f"head_dim {d} not a multiple of 128")
     g = hq // hkv
-    pps = tables.shape[1]
     sm_scale = 1.0 / math.sqrt(d)
     quantized = k_scale is not None
+    block_pages = max(1, PAGED_BLOCK_TOKENS // page_tokens)
 
     qg = q.reshape(s_lanes, hkv, g, d)
     tables = tables.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
+    if active is None:
+        active = jnp.ones((s_lanes,), jnp.int32)
+    active = active.astype(jnp.int32)
 
-    def q_index(s, h, j, tbl, ps):
-        return (s, h, 0, 0)
+    def lane_index(s, tbl, ps, act):
+        return (s, 0, 0, 0)
 
-    def kv_index(s, h, j, tbl, ps):
-        # clamp dead trailing slots to the lane's last live page: the block
-        # index repeats, so the pipeline skips the re-fetch — streamed bytes
-        # scale with pos, and the trash page behind unreserved entries is
-        # only ever touched where the reference would read it too
-        jj = jnp.minimum(j, ps[s] // page_tokens)
-        return (tbl[s, jj], h, 0, 0)
-
-    def scale_index(s, h, j, tbl, ps):
-        jj = jnp.minimum(j, ps[s] // page_tokens)
-        return (tbl[s, jj], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), q_index),
-        pl.BlockSpec((1, 1, pt, d), kv_index),
-        pl.BlockSpec((1, 1, pt, d), kv_index),
-    ]
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [pl.BlockSpec((1, hkv, g, d), lane_index), hbm, hbm]
     operands = [qg, k_pages, v_pages]
+    page_buf = pltpu.VMEM((2, hkv, block_pages, pt, d), k_pages.dtype)
+    scratch_shapes = [page_buf, page_buf]
     if quantized:
-        # a page's scales for EVERY kv head: the TPU lowering wants a
-        # block's last two dims to be the array's own (or 8x128 multiples),
-        # which a per-head (1, 1, pt) block is not; the kernel picks its
-        # head's row (_head_scale_row)
-        in_specs += [
-            pl.BlockSpec((1, hkv, pt), scale_index),
-            pl.BlockSpec((1, hkv, pt), scale_index),
+        operands += [
+            _lane_scale_rows(sc, tables, pos, block_pages * pt)
+            for sc in (k_scale, v_scale)
         ]
-        operands += [k_scale, v_scale]
+        in_specs += [
+            pl.BlockSpec((1, hkv, 1, operands[-1].shape[-1]), lane_index)
+        ] * 2
+    scratch_shapes += [
+        pltpu.SemaphoreType.DMA((2, 2)),            # (k | v, slot)
+        pltpu.VMEM((hkv, g, d), jnp.float32),       # acc
+        pltpu.VMEM((hkv, g, 128), jnp.float32),     # m (lane-bcast)
+        pltpu.VMEM((hkv, g, 128), jnp.float32),     # l (lane-bcast)
+    ]
 
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, page_tokens=page_tokens,
-        num_pages=pps, quantized=quantized,
+        block_pages=block_pages, quantized=quantized,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_lanes, hkv, pps),
+        num_scalar_prefetch=3,
+        grid=(s_lanes,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), q_index),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),      # acc
-            pltpu.VMEM((g, 128), jnp.float32),    # m (lane-bcast)
-            pltpu.VMEM((g, 128), jnp.float32),    # l (lane-bcast)
-        ],
+        out_specs=pl.BlockSpec((1, hkv, g, d), lane_index),
+        scratch_shapes=scratch_shapes,
     )
     out = pl.pallas_call(
         kernel,
@@ -864,9 +931,9 @@ def paged_decode_attention_kernel(
         out_shape=jax.ShapeDtypeStruct((s_lanes, hkv, g, d), jnp.float32),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel",)
         ),
-    )(tables, pos, *operands)
+    )(tables, pos, active, *operands)
     return out.reshape(s_lanes, hq, 1, d)
 
 
@@ -905,7 +972,7 @@ PAGED_KERNEL_INTERPRET = False
 
 
 def _paged_kernel_traced(gate: str, kernel: bool, q: jax.Array,
-                         k_pages: jax.Array) -> bool:
+                         k_pages: jax.Array, head_multiple: int = 64) -> bool:
     """The paged gates' shared decision, recorded: True = trace the Pallas
     kernel, False = trace the gather+einsum reference."""
     if not kernel:
@@ -913,7 +980,8 @@ def _paged_kernel_traced(gate: str, kernel: bool, q: jax.Array,
     elif PAGED_KERNEL_INTERPRET:
         why = None
     else:
-        why = _kernel_refusal(q.shape[-1], q.shape[1], k_pages.shape[1])
+        why = _kernel_refusal(q.shape[-1], q.shape[1], k_pages.shape[1],
+                              head_multiple)
     shapes = (q.shape, k_pages.shape, (str(k_pages.dtype),))
     if why is None:
         _record_dispatch(
@@ -935,21 +1003,27 @@ def paged_attention(  # static-bounded: kernel, page_tokens, PAGED_KERNEL_INTERP
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     kernel: bool = True,
+    active: jax.Array | None = None,
 ) -> jax.Array:
     """Paged decode dispatch, mirroring ``attention``'s gate: the fused
     Pallas kernel on the TPU backend when shapes qualify (head_dim a
-    multiple of 64 — Mosaic pads the lane dim; GQA divisibility), the
-    gather+einsum reference everywhere else. ``kernel=False``
-    (serving.kv_paged_kernel, and every mesh runtime) forces the reference
-    path unconditionally. On a TPU with ``kernel=True`` and qualifying
-    shapes there is no quiet way out: the kernel is traced, and a kernel
-    that fails to lower or compile raises. An int8 arena (``k_scale``
-    present) is dequantized in-kernel on the fast path; the reference
-    materializes the dequantized pages first (same math, minus the
-    bandwidth win). The branch taken is recorded (``dispatch_tally``)."""
-    if _paged_kernel_traced("paged_attention", kernel, q, k_pages):
+    multiple of 128: the kernel copies whole pages out of the arena itself,
+    and Mosaic slices an HBM operand only in whole 128-lane tiles; GQA
+    divisibility), the gather+einsum reference everywhere else.
+    ``kernel=False`` (serving.kv_paged_kernel, and every mesh runtime)
+    forces the reference path unconditionally. On a TPU with ``kernel=True``
+    and qualifying shapes there is no quiet way out: the kernel is traced,
+    and a kernel that fails to lower or compile raises. An int8 arena
+    (``k_scale`` present) is dequantized in-kernel on the fast path; the
+    reference materializes the dequantized pages first (same math, minus the
+    bandwidth win). ``active`` (``(S,)`` bool, default all true) names the
+    lanes whose rows the caller keeps: the kernel does no work for the
+    others and returns zeros there, the reference computes them like any
+    lane. The branch taken is recorded (``dispatch_tally``)."""
+    if _paged_kernel_traced("paged_attention", kernel, q, k_pages,
+                            head_multiple=128):
         return paged_decode_attention_kernel(
-            q, k_pages, v_pages, tables, pos, k_scale, v_scale,
+            q, k_pages, v_pages, tables, pos, k_scale, v_scale, active,
             page_tokens=page_tokens, interpret=PAGED_KERNEL_INTERPRET,
         )
     if k_scale is not None:
@@ -966,7 +1040,10 @@ def _paged_verify_kernel(
 ):
     """One (lane, kv-head, table-slot) grid step of paged VERIFY attention.
 
-    Same streaming skeleton as ``_paged_decode_kernel``, but the query
+    The grid's last dimension walks the lane's block-table row; the
+    BlockSpec index maps (scalar-prefetched tables/pos) turn each step into
+    a DMA of exactly one arena page, read IN PLACE, with the online-softmax
+    carry in VMEM scratch like ``_flash_streamed_kernel``. The query
     block carries T query positions folded into the row axis — row ``r``
     of the ``(T*g, d)`` block is query offset ``r // g`` of the lane, at
     position ``pos + r // g``. One extra iota-compare per page gives each
@@ -1055,9 +1132,9 @@ def paged_verify_attention_kernel(
     ``paged_verify_attention`` (q ``(S, Hq, T, D)``, arena pages, tables,
     pos -> f32 ``(S, Hq, T, D)``) with one pass over the KV bytes. The T
     query positions fold into the GQA group axis — blocks become
-    ``(T*g, d)`` with row ``r`` at query offset ``r // g`` — so the grid,
-    index maps, and scalar-prefetch discipline are identical to
-    ``paged_decode_attention_kernel`` and T never becomes a grid dim.
+    ``(T*g, d)`` with row ``r`` at query offset ``r // g`` — so the grid is
+    ``(lanes, kv_heads, pages_per_slot)``, tables and pos ride in as
+    scalar-prefetch operands and T never becomes a grid dim.
     T is a shape, not a static arg: one program per (config, spec_tokens)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1087,9 +1164,10 @@ def paged_verify_attention_kernel(
         return (s, h, 0, 0)
 
     def kv_index(s, h, j, tbl, ps):
-        # the last live page now holds pos + T - 1 (draft rows written
-        # this round); clamp dead trailing slots to it, same elision as
-        # the decode kernel
+        # the last live page holds pos + T - 1 (draft rows written this
+        # round); clamp dead trailing slots to it: the block index repeats,
+        # so the pipeline skips the re-fetch, and the trash page behind
+        # unreserved entries is never touched
         jj = jnp.minimum(j, (ps[s] + t_q - 1) // page_tokens)
         return (tbl[s, jj], h, 0, 0)
 

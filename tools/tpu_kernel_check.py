@@ -36,9 +36,11 @@ def main() -> int:
     # -s: the gated tests print per-shape errors and timings — the output
     # must carry the measured magnitudes, not just PASS/FAIL.
     # test_paged_kernel.py carries the paged entries: the bring-up matrix
-    # (decode and verify x bf16/int8 x GQA group 1/2/4 x head_dim 64/128,
-    # compile + parity) and the kernel-vs-gather+einsum timing at S in
-    # {4,16,32} lanes (printed, not asserted).
+    # (decode x bf16/int8 x GQA group 1/2/3/4 x head_dim 64/128, verify x
+    # group 1/2/4; compile + parity, and for decode at head 64 the refusal
+    # by name with the gate's reference) and the kernel-vs-gather+einsum
+    # timing at S in {4,16,32} full lanes and at the benchmark's steady
+    # cell, 10 of 32 lanes active (printed, not asserted).
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
