@@ -913,7 +913,7 @@ def bench_zoo_cold(tmp: str) -> dict:
                 "vocab_size": 512, "d_model": 128, "n_layers": 2,
                 "n_heads": 4, "n_kv_heads": 2, "d_ff": 256, "max_seq": 128,
                 "dtype": "bfloat16",
-                **({"n_experts": 4, "capacity_factor": 2.0,
+                **({"n_experts": 4, "top_k": 2,
                     "aux_loss_weight": 0.01} if family == "moe_lm" else {}),
             }
         manager = None

@@ -299,8 +299,8 @@ class _StubRuntime:
     def __init__(self, slots):
         self._state = _StubState(slots)
 
-    def family_of(self, _m):
-        return "transformer_lm"
+    def engine_ready_of(self, _m):
+        return True
 
     def eos_id_of(self, _m):
         return None

@@ -147,8 +147,8 @@ class _StubRuntime:
     def __init__(self, slots):
         self._state = _StubState(slots)
 
-    def family_of(self, _m):
-        return "transformer_lm"
+    def engine_ready_of(self, _m):
+        return True
 
     def eos_id_of(self, _m):
         return None
@@ -666,7 +666,7 @@ async def test_engine_dump_tool_marks_unknown_model(capsys):
 def test_ring_split_fields_sit_at_the_end():
     """Appended, never inserted: the 16 older names keep their positions
     (older dumps and tools index by them), the split is the last three."""
-    assert STEP_FIELDS[-3:] == ("prefill_ms", "chunk_ms", "emit_ms")
+    assert STEP_FIELDS[16:19] == ("prefill_ms", "chunk_ms", "emit_ms")
     assert STEP_FIELDS[:3] == ("t_wall", "engine", "step_ms")
     assert STEP_FIELDS[14:16] == ("drafted", "accepted")
     fr = FlightRecorder()

@@ -52,7 +52,7 @@ CASES = {
         None,
     ),
     "moe_lm": (
-        {**LM_TINY, "n_experts": 4, "capacity_factor": 2.0},
+        {**LM_TINY, "n_experts": 4, "top_k": 2},
         {"input_ids": np.array([[1, 2, 3, 4]], np.int32)},
         {"labels": np.array([[1, 2, 3, 4]], np.int32)},
         None,

@@ -567,6 +567,8 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_gen_kv_pages_used_peak",
     "tpusc_gen_preemptions",
     "tpusc_gen_prefill_chunks",
+    "tpusc_moe_assignments",
+    "tpusc_moe_expert_rows",
     "tpusc_gen_prefix_hits",
     "tpusc_gen_oldest_queued_age_seconds",
     "tpusc_gen_stream_frames",

@@ -171,15 +171,23 @@ def test_moe_expert_parallel_matches_replicated():
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
 
 
-def test_moe_capacity_drops_are_residual_passthrough():
-    """With capacity 0 slots unavailable... a tiny capacity factor forces
-    drops; output must stay finite (dropped tokens ride the residual)."""
-    cfg = {**MOE_TINY, "capacity_factor": 0.1}
-    model = build("moe_lm", cfg)
-    params = model.init(jax.random.PRNGKey(1))
-    ids = np.ones((2, 16), np.int32)  # identical tokens -> one expert floods
-    out = np.asarray(model.apply(params, {"input_ids": ids})["logits"])
-    assert np.all(np.isfinite(out))
+def test_moe_flooded_expert_drops_nothing():
+    """Identical tokens flood one expert: the layer has no capacity, so every
+    row is answered alike (what a capacity of 0.1 x tokens / experts used to
+    drop rode the residual and answered otherwise), under top-1 and top-2."""
+    for top_k in (1, 2):
+        model = build("moe_lm", {**MOE_TINY, "top_k": top_k})
+        params = model.init(jax.random.PRNGKey(1))
+        ids = np.ones((2, 16), np.int32)  # identical tokens -> one expert floods
+        x = params["embed"][ids].astype(jnp.bfloat16)
+        from tfservingcache_tpu.models.moe_lm import _moe_block
+
+        y, stats = _moe_block(params["layers"][0], x, model.config, jnp.bfloat16)
+        assert float(stats["experts_hit"]) == top_k
+        assert float(stats["expert_rows_max"]) == ids.size
+        y = np.asarray(y, np.float32).reshape(ids.size, -1)
+        assert np.all(np.isfinite(y)) and np.abs(y[0]).max() > 0
+        np.testing.assert_array_equal(y, np.broadcast_to(y[0], y.shape))
 
 
 def test_pipeline_matches_sequential_and_grads():

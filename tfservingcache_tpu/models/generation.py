@@ -28,7 +28,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from tfservingcache_tpu.models.transformer_lm import _rmsnorm
+from tfservingcache_tpu.models.moe_lm import _moe_block
+from tfservingcache_tpu.models.transformer_lm import (
+    _output_logits,
+    _qkv,
+    _rmsnorm,
+)
 
 # The slot-decode jits donate their K/V buffers (in-place update on TPU);
 # CPU/interpreter backends cannot honor donation and warn on EVERY dispatch
@@ -442,7 +447,8 @@ def _quantize_kv_rows(x):
 
 
 def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
-                        page_tokens: int, kernel: bool = False, active=None):
+                        page_tokens: int, kernel: bool = False, active=None,
+                        moe_stats: list | None = None):
     """One decode step (s_len=1 per lane) against the paged arena — the
     block-table counterpart of ``_forward_cached_dyn``. Each lane writes its
     new K/V at ``tables[lane, pos // page_tokens]`` offset ``pos %
@@ -459,7 +465,10 @@ def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
 
     ``active`` (the chunk's frozen ``(S,)`` vector, default all lanes) goes
     to ``paged_attention`` as it is: the caller discards an inactive lane's
-    token, so the kernel reads no page for it."""
+    token, so the kernel reads no page for it, and an expert layer routes
+    it to no expert. Each expert layer's ``(experts_hit, expert_rows_max)``
+    is appended to ``moe_stats`` where the caller gives a list (a dense
+    model appends nothing)."""
     from tfservingcache_tpu.ops.attention import paged_attention
 
     dtype = jnp.dtype(cfg["dtype"])
@@ -486,10 +495,7 @@ def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
         with jax.named_scope("layer"):
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                h = _rmsnorm(x, layer["ln1"])
-                q = (h @ attn["wq"]).reshape(s_lanes, 1, n_heads, head_dim).transpose(0, 2, 1, 3)
-                k = (h @ attn["wk"]).reshape(s_lanes, 1, n_kv, head_dim).transpose(0, 2, 1, 3)
-                v = (h @ attn["wv"]).reshape(s_lanes, 1, n_kv, head_dim).transpose(0, 2, 1, 3)
+                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
                 q = _rope_per_example(q, positions, cfg["rope_theta"])
                 k = _rope_per_example(k, positions, cfg["rope_theta"])
             with jax.named_scope("kv_read"):
@@ -525,10 +531,9 @@ def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
                 out = out.reshape(s_lanes, n_heads, 1, head_dim).astype(x.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(s_lanes, 1, cfg["d_model"])
                 x = x + out @ attn["wo"]
-            x = x + _ffn_block(layer, x, cfg, family, dtype)
-    with jax.named_scope("lm_head"):
-        x = _rmsnorm(x, params["ln_f"])
-        logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+            x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
+                               moe_stats=moe_stats)
+    logits = _output_logits(params, x, dtype)
     with jax.named_scope("kv_write"):
         # the per-layer slices back into one arena
         new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
@@ -579,10 +584,7 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
         with jax.named_scope("layer"):
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                h = _rmsnorm(x, layer["ln1"])
-                q = (h @ attn["wq"]).reshape(s_lanes, t_q, n_heads, head_dim).transpose(0, 2, 1, 3)
-                k = (h @ attn["wk"]).reshape(s_lanes, t_q, n_kv, head_dim).transpose(0, 2, 1, 3)
-                v = (h @ attn["wv"]).reshape(s_lanes, t_q, n_kv, head_dim).transpose(0, 2, 1, 3)
+                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
                 q = _rope_per_example(q, positions, cfg["rope_theta"])
                 k = _rope_per_example(k, positions, cfg["rope_theta"])
             with jax.named_scope("kv_read"):
@@ -619,10 +621,8 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                 out = out.reshape(s_lanes, n_heads, t_q, head_dim).astype(x.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(s_lanes, t_q, cfg["d_model"])
                 x = x + out @ attn["wo"]
-            x = x + _ffn_block(layer, x, cfg, family, dtype)
-    with jax.named_scope("lm_head"):
-        x = _rmsnorm(x, params["ln_f"])
-        logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+            x = x + _ffn_block(layer, x, cfg, dtype)
+    logits = _output_logits(params, x, dtype)
     with jax.named_scope("kv_write"):
         new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
         if quantized:
@@ -827,52 +827,65 @@ def _paged_decode_chunk_jit(
     each lane reads through its block table. ``tables`` is traced (a tiny
     (S, pages_per_slot) i32 H2D copy per chunk), so recycling pages never
     mints a new program; compiled-program count stays one per chunk size
-    (x2 for the ``kernel`` boolean — the serving.kv_paged_kernel gate)."""
+    (x2 for the ``kernel`` boolean — the serving.kv_paged_kernel gate).
+
+    The last output is the chunk's routing stats for a model with expert
+    layers: float32 ``(experts_hit, expert_rows_max)``, each a mean over the
+    chunk's steps and layers, computed by the program and fetched with the
+    tokens; ``None`` (no output at all) for a dense model, whose program is
+    therefore the one it was."""
     cfg = dict(cfg_key)
     quantized = scales is not None
 
     def step(carry, rng):
         cache, tok, pos = carry
+        layer_stats: list = []
         logits, cache = _paged_forward_step(
             params, tok, cache, tables, pos, cfg, family,
-            page_tokens, kernel=kernel, active=active,
+            page_tokens, kernel=kernel, active=active, moe_stats=layer_stats,
         )
         nxt = _sample_per_row(logits[:, 0], rng, temperature, top_k)
         nxt = jnp.where(active, nxt, tok)
         pos = pos + active.astype(jnp.int32)
-        return (cache, nxt, pos), nxt
+        # (2,): the step's mean over its expert layers; nothing for a dense model
+        stats = jnp.mean(jnp.stack(layer_stats), axis=0) if layer_stats else None
+        return (cache, nxt, pos), (nxt, stats)
 
     cache = {"k": arena_k, "v": arena_v}
     if quantized:
         cache["k_scale"] = scales["k"]
         cache["v_scale"] = scales["v"]
-    (cache, tok, pos), toks = jax.lax.scan(
+    (cache, tok, pos), (toks, stats) = jax.lax.scan(
         step, (cache, tok, pos), rngs, length=chunk
     )
     scales = (
         {"k": cache["k_scale"], "v": cache["v_scale"]} if quantized else None
     )
+    if stats is not None:
+        stats = jnp.mean(stats, axis=0)
     return (cache["k"], cache["v"], scales, tok, pos,
-            jnp.transpose(toks, (1, 0)))  # (S, chunk)
+            jnp.transpose(toks, (1, 0)), stats)  # (S, chunk), (2,) | None
 
 
-@jax.named_scope("ffn")
-def _ffn_block(layer: dict, x, cfg: dict, family: str, dtype):
-    """The family-specific second half of a decoder layer (input is the
-    residual stream BEFORE its norm; returns the residual delta)."""
-    h = _rmsnorm(x, layer["ln2"])
-    if family == "moe_lm":
-        from tfservingcache_tpu.models.moe_lm import _moe_block
-
-        moe = {
-            "router": layer["moe"]["router"],  # routing stays f32
-            "w1": layer["moe"]["w1"].astype(dtype),
-            "w2": layer["moe"]["w2"].astype(dtype),
-        }
-        y, _aux = _moe_block(moe, h, cfg)  # aux loss is a training-only signal
+def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
+               moe_stats: list | None = None):
+    """The second half of a decoder layer (input is the residual stream
+    BEFORE its norm; returns the residual delta), chosen by what the layer
+    holds: ``moe`` = the routed expert layer, else the dense SwiGLU ``mlp``.
+    ``row_mask`` (one flag a row of ``x`` flattened) marks rows whose answer
+    nobody reads: the expert layer routes them nowhere. An expert layer's
+    routing stats (``experts_hit``, ``expert_rows_max``) are appended to
+    ``moe_stats`` where the caller gives a list."""
+    if "moe" in layer:
+        y, stats = _moe_block(layer, x, cfg, dtype, row_mask=row_mask)
+        if moe_stats is not None:
+            moe_stats.append(
+                jnp.stack([stats["experts_hit"], stats["expert_rows_max"]]))
         return y
-    mlp = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"])
-    return (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
+    with jax.named_scope("ffn"):
+        h = _rmsnorm(x, layer["ln2"])
+        mlp = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"])
+        return (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
 
 
 def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
@@ -892,10 +905,7 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
         with jax.named_scope("layer"):
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                h = _rmsnorm(x, layer["ln1"])
-                q = (h @ attn["wq"]).reshape(b, s_len, n_heads, head_dim).transpose(0, 2, 1, 3)
-                k = (h @ attn["wk"]).reshape(b, s_len, n_kv, head_dim).transpose(0, 2, 1, 3)
-                v = (h @ attn["wv"]).reshape(b, s_len, n_kv, head_dim).transpose(0, 2, 1, 3)
+                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
                 q = _rope_per_example(q, positions, cfg["rope_theta"])
                 k = _rope_per_example(k, positions, cfg["rope_theta"])
             with jax.named_scope("kv_read"):
@@ -941,10 +951,8 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
                 out = out.reshape(b, n_heads, s_len, d).astype(x.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(b, s_len, cfg["d_model"])
                 x = x + out @ attn["wo"]
-            x = x + _ffn_block(layer, x, cfg, family, dtype)
-    with jax.named_scope("lm_head"):
-        x = _rmsnorm(x, params["ln_f"])
-        logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+            x = x + _ffn_block(layer, x, cfg, dtype)
+    logits = _output_logits(params, x, dtype)
     with jax.named_scope("kv_write"):
         new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
     return logits, new_cache
@@ -981,9 +989,10 @@ def generate(  # static-bounded: cfg_key, max_new_tokens, return_cache -- cfg_ke
     int32 token ids; with ``return_cache`` also the final KV arrays (the
     prefix cache stores them for reuse).
     """
-    if model_def.family not in ("transformer_lm", "moe_lm"):
+    if not model_def.engine_ready:
         raise ValueError(
-            f"generation supports transformer_lm/moe_lm, not {model_def.family!r}"
+            "generation supports the decoder-LM families (transformer_lm, "
+            f"moe_lm: ModelDef.engine_ready), not {model_def.family!r}"
         )
     input_ids = jnp.asarray(input_ids, jnp.int32)
     b, s = input_ids.shape

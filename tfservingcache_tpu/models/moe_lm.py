@@ -1,23 +1,28 @@
-"""moe_lm — mixture-of-experts decoder LM (Switch-style top-1 routing).
+"""moe_lm — mixture-of-experts decoder LM: a dropless top-k expert layer.
 
 No reference counterpart (the reference serves opaque SavedModels and
-implements no parallelism — SURVEY.md §2 inventory); this family exists so
-expert parallelism is a first-class, servable capability: expert weights
-carry an ``("expert", …)`` partition rule, so on a mesh with an "expert"
-axis each chip group holds E/ep experts and XLA inserts the dispatch/combine
-all-to-alls from the shardings.
+implements no parallelism — SURVEY.md §2 inventory). The family covers the
+open top-k expert decoders (OLMoE-1B-7B is its benchmark configuration:
+64 experts of width 1024, 8 a token, QK-norm, an untied head) and, at
+``top_k`` 1, the Switch layer it began as. Attention, RoPE, the KV cache
+layout and ``models/generation.py`` are transformer_lm's; what differs is
+in the params: a layer holds ``moe`` (``router`` and the stacked SwiGLU
+experts ``w1``, ``w3`` ``(e, d, ff)``, ``w2`` ``(e, ff, d)``) where the dense
+family holds ``mlp``, and ``qk_norm`` / ``tie_embeddings`` add the
+``q_norm`` / ``k_norm`` and ``lm_head`` leaves that switch those on
+(``transformer_lm._qkv``, ``_output_logits``).
 
-TPU-first routing design: the GShard/Switch dense-dispatch formulation —
-one-hot dispatch/combine tensors contracted with einsum — keeps every shape
-static under jit (no data-dependent gather), trades a capacity-factor bound
-(dropped tokens pass through the residual) for MXU-friendly dense matmuls.
-Routing runs in f32; expert FFNs in bf16.
+The expert layer is ``ops/moe.py``: router in float32, top-k over the
+softmax of all experts, gates the softmax values themselves unless
+``norm_topk_prob``, tokens grouped by expert and multiplied by the experts
+they were routed to and no others. There is no capacity and no dropped
+token, and a row's answer does not depend on the rows beside it, so the
+family is engine-ready: it co-batches and shares decode steps like the
+dense one.
 
-Serving caveat inherent to capacity routing: expert capacity is computed
-over the whole flattened (padded) batch, so which tokens drop depends on
-batch composition — outputs are deterministic per padded shape but NOT
-batch-composition-invariant. The generate coalescer therefore never
-co-batches moe_lm requests (runtime/batcher.py).
+Expert weights carry an ``("expert", None, None)`` partition rule, so on a
+mesh with an "expert" axis each chip group holds E/ep experts; there the
+grouped product is ``jax.lax.ragged_dot`` (the Pallas kernel is single-chip).
 """
 
 from __future__ import annotations
@@ -29,7 +34,12 @@ import jax
 import jax.numpy as jnp
 
 from tfservingcache_tpu.models.registry import ModelDef, TensorSpec, register
-from tfservingcache_tpu.models.transformer_lm import _attention_block, _rmsnorm
+from tfservingcache_tpu.models.transformer_lm import (
+    _attention_block,
+    _output_logits,
+    _rmsnorm,
+)
+from tfservingcache_tpu.ops.moe import moe_experts
 
 DEFAULT_CONFIG: dict[str, Any] = {
     "vocab_size": 2048,
@@ -39,7 +49,10 @@ DEFAULT_CONFIG: dict[str, Any] = {
     "n_kv_heads": 8,
     "d_ff": 512,            # per-expert FFN width
     "n_experts": 8,
-    "capacity_factor": 1.25,
+    "top_k": 1,             # experts a token (1 = Switch)
+    "norm_topk_prob": False,  # gates = softmax values, not renormalised
+    "qk_norm": False,       # RMSNorm over the whole q and k projections
+    "tie_embeddings": True,  # False = a separate lm_head leaf
     "aux_loss_weight": 0.01,
     "max_seq": 1024,
     "rope_theta": 10000.0,
@@ -47,75 +60,43 @@ DEFAULT_CONFIG: dict[str, Any] = {
 }
 
 
-def _moe_block(params: dict, x: jax.Array, cfg: dict) -> tuple[jax.Array, jax.Array]:
-    """Top-1 routed expert FFN over (B, S, D) -> (output, aux_loss).
-
-    Dense GShard dispatch: tokens -> (token, expert, capacity_slot) one-hot,
-    experts applied batched over their leading (sharded) axis, combine
-    weighted by the router gate. Tokens past an expert's capacity drop (the
-    residual connection carries them unchanged).
-    """
+@jax.named_scope("ffn")
+def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
+               partitioned: bool = False) -> tuple[jax.Array, dict]:
+    """The expert half of a layer over the residual stream ``x (B, S, D)``
+    BEFORE its norm -> (residual delta, the layer's routing stats).
+    ``row_mask (B*S,)`` marks rows whose answer nobody reads."""
     b, s, d = x.shape
-    e = cfg["n_experts"]
-    t = b * s
-    capacity = max(1, math.ceil(cfg["capacity_factor"] * t / e))
-    xt = x.reshape(t, d)
-
-    router_logits = (xt.astype(jnp.float32) @ params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(router_logits, axis=-1)              # (t, e) f32
-    gate = jnp.max(probs, axis=-1)                              # (t,)
-    expert_ix = jnp.argmax(probs, axis=-1)                      # (t,)
-    onehot = jax.nn.one_hot(expert_ix, e, dtype=jnp.float32)    # (t, e)
-
-    # position of each token within its expert's queue (0-based); tokens at
-    # position >= capacity are dropped
-    pos = jnp.cumsum(onehot, axis=0) * onehot - onehot          # (t, e)
-    keep = onehot * (pos < capacity)
-    dispatch = keep[..., None] * jax.nn.one_hot(
-        pos.astype(jnp.int32), capacity, dtype=jnp.float32
-    )
-    # (t, e, c)
-
-    # NOTE: the dispatch gather-matmul stays f32 — bf16 operands change the
-    # EP-sharded cross-device reduction enough to break parity with the
-    # replicated path (tests/test_parallel.py), and routing fidelity beats
-    # the marginal MXU win here
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, xt.astype(jnp.float32))
-    expert_in = expert_in.astype(x.dtype)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, params["w1"]))
-    out_e = jnp.einsum("ecf,efd->ecd", h, params["w2"])         # (e, c, d)
-
-    combine = dispatch * gate[:, None, None]
-    y = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), out_e)
-
-    # Switch load-balance aux loss: e * sum_e(frac_tokens_e * mean_prob_e)
-    frac_tokens = jnp.mean(onehot, axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    aux = e * jnp.sum(frac_tokens * mean_prob)
-    return y.reshape(b, s, d), aux
+    moe = {"router": layer["moe"]["router"]}            # routing stays f32
+    moe.update((w, layer["moe"][w].astype(dtype)) for w in ("w1", "w2", "w3"))
+    y, stats = moe_experts(
+        _rmsnorm(x, layer["ln2"]).reshape(b * s, d), moe, int(cfg["top_k"]),
+        norm_topk=bool(cfg["norm_topk_prob"]), row_mask=row_mask,
+        partitioned=partitioned)
+    return y.reshape(b, s, d), stats
 
 
 def _forward(params: dict, input_ids: jax.Array, cfg: dict, mesh=None) -> tuple[jax.Array, jax.Array]:  # static-bounded: mesh -- one Mesh object per runtime lifetime
     dtype = jnp.dtype(cfg["dtype"])
-    x = params["embed"][input_ids].astype(dtype)
+    e = cfg["n_experts"]
+    partitioned = mesh is not None and mesh.size > 1
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(dtype)
     aux_total = jnp.zeros((), jnp.float32)
     for layer in params["layers"]:
-        x = x + _attention_block(
-            jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"]),
-            _rmsnorm(x, layer["ln1"]),
-            cfg,
-            mesh,
-        )
-        moe_params = {
-            "router": layer["moe"]["router"],  # stays f32 inside the block
-            "w1": layer["moe"]["w1"].astype(dtype),
-            "w2": layer["moe"]["w2"].astype(dtype),
-        }
-        y, aux = _moe_block(moe_params, _rmsnorm(x, layer["ln2"]), cfg)
-        x = x + y
-        aux_total = aux_total + aux
-    x = _rmsnorm(x, params["ln_f"])
-    logits = (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+        with jax.named_scope("layer"):
+            x = x + _attention_block(
+                jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"]),
+                _rmsnorm(x, layer["ln1"]),
+                cfg,
+                mesh,
+            )
+            y, stats = _moe_block(layer, x, cfg, dtype, partitioned=partitioned)
+            x = x + y
+        # Switch load-balance aux loss: e * sum_e(frac_tokens_e * mean_prob_e)
+        frac = jnp.mean(jnp.sum(jax.nn.one_hot(stats["experts"], e), axis=1), axis=0)
+        aux_total = aux_total + e * jnp.sum(frac * jnp.mean(stats["probs"], axis=0))
+    logits = _output_logits(params, x, dtype)
     return logits, aux_total / max(len(params["layers"]), 1)
 
 
@@ -145,7 +126,7 @@ def build(config: dict) -> ModelDef:
 
         layers = []
         for i in range(cfg["n_layers"]):
-            ks = jax.random.split(keys[i], 7)
+            ks = jax.random.split(keys[i], 8)
             layers.append(
                 {
                     "attn": {
@@ -158,16 +139,23 @@ def build(config: dict) -> ModelDef:
                         "router": dense(ks[4], d, (d, e)),
                         "w1": dense(ks[5], d, (e, d, ff)),
                         "w2": dense(ks[6], ff, (e, ff, d)),
+                        "w3": dense(ks[7], d, (e, d, ff)),
                     },
                     "ln1": jnp.ones((d,), jnp.float32),
                     "ln2": jnp.ones((d,), jnp.float32),
                 }
             )
-        return {
+            if cfg["qk_norm"]:
+                layers[-1]["attn"]["q_norm"] = jnp.ones((n_heads * head_dim,), jnp.float32)
+                layers[-1]["attn"]["k_norm"] = jnp.ones((n_kv * head_dim,), jnp.float32)
+        params = {
             "embed": dense(keys[-1], d, (v, d)),
             "layers": layers,
             "ln_f": jnp.ones((d,), jnp.float32),
         }
+        if not cfg["tie_embeddings"]:
+            params["lm_head"] = dense(jax.random.fold_in(keys[-1], 1), d, (d, v))
+        return params
 
     def loss(params, inputs, targets):
         logits, aux = _forward(params, inputs["input_ids"].astype(jnp.int32), cfg)
@@ -184,10 +172,12 @@ def build(config: dict) -> ModelDef:
     # data-only, data x expert, or data x expert x model meshes unchanged.
     partition_rules = {
         "embed": (None, "model"),
+        "lm_head": (None, "model"),
         r"layers/\d+/attn/w[qkv]": (None, "model"),
         r"layers/\d+/attn/wo": ("model", None),
+        r"layers/\d+/attn/[qk]_norm": (None,),
         r"layers/\d+/moe/router": (None,),
-        r"layers/\d+/moe/w[12]": ("expert", None, None),
+        r"layers/\d+/moe/w[123]": ("expert", None, None),
         r".*ln.*": (None,),
     }
 
@@ -220,4 +210,6 @@ def build(config: dict) -> ModelDef:
         # the shared attention block must know when it is traced into a
         # chip group's partitioned program (transformer_lm._attention_block)
         bind_mesh=make_apply,
+        # no capacity, no dropped token: a row's answer is its own
+        engine_ready=True,
     )

@@ -112,6 +112,12 @@ class ModelDef:
     # group. Plain TP families leave it None — their sharding is declarative
     # (partition_rules) and XLA inserts the collectives.
     bind_mesh: Callable[[Any], Callable[[Any, Mapping[str, Any]], dict[str, Any]]] | None = None
+    # the family declares that the continuous engine may serve it: its only
+    # per-request layer state is K/V rows in the decoder-LM cache layout
+    # (models/generation.py), and its step is row-invariant — a row's logits
+    # do not depend on the rows beside it, so strangers can share a decode
+    # step. The engine, the arena and the coalescer ask this, not the name.
+    engine_ready: bool = False
 
 
 _REGISTRY: dict[str, Callable[[dict[str, Any]], ModelDef]] = {}

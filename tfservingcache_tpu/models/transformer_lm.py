@@ -75,14 +75,41 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return rot.reshape(x.shape).astype(x.dtype)
 
 
+def _qkv(attn: dict, h: jax.Array, n_heads: int, n_kv: int):
+    """The normed activations ``h (B, S, d)`` -> q ``(B, n_heads, S, hd)`` and
+    k, v ``(B, n_kv, S, hd)``, not yet rotated: the one projection of every
+    forward (here and the four in models/generation.py). ``q_norm`` /
+    ``k_norm`` leaves in ``attn`` switch on QK-norm as OLMoE has it: an
+    RMSNorm with a learned gain over the WHOLE query and key projection,
+    before the heads are split. A layer without the leaves traces the three
+    products and nothing else."""
+    b, s, _ = h.shape
+
+    def proj(w, n, gain=None):
+        t = h @ attn[w]
+        if gain in attn:
+            t = _rmsnorm(t, attn[gain])
+        return t.reshape(b, s, n, t.shape[-1] // n).transpose(0, 2, 1, 3)
+
+    return (proj("wq", n_heads, "q_norm"), proj("wk", n_kv, "k_norm"),
+            proj("wv", n_kv))
+
+
+@jax.named_scope("lm_head")
+def _output_logits(params: dict, x: jax.Array, dtype) -> jax.Array:
+    """Final norm and output head -> float32 logits (a stable softmax/argmax
+    downstream). An ``lm_head (d, vocab)`` leaf is the untied head; without
+    it the head is the embedding."""
+    x = _rmsnorm(x, params["ln_f"])
+    if "lm_head" in params:
+        return (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
+    return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+
+
 @jax.named_scope("attn")
 def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None) -> jax.Array:  # static-bounded: mesh -- one Mesh object per runtime lifetime
     b, s, d_model = x.shape
-    n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"]
-    head_dim = d_model // n_heads
-    q = (x @ params["wq"]).reshape(b, s, n_heads, head_dim).transpose(0, 2, 1, 3)
-    k = (x @ params["wk"]).reshape(b, s, n_kv, head_dim).transpose(0, 2, 1, 3)
-    v = (x @ params["wv"]).reshape(b, s, n_kv, head_dim).transpose(0, 2, 1, 3)
+    q, k, v = _qkv(params, x, cfg["n_heads"], cfg["n_kv_heads"])
     positions = jnp.arange(s)
     q = _rope(q, positions, cfg["rope_theta"])
     k = _rope(k, positions, cfg["rope_theta"])
@@ -132,10 +159,7 @@ def _forward(params: dict, input_ids: jax.Array, cfg: dict, mesh=None) -> jax.Ar
                 jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"]),
                 _rmsnorm(x, layer["ln2"]),
             )
-    with jax.named_scope("lm_head"):
-        x = _rmsnorm(x, params["ln_f"])
-        # logits in f32 for a stable softmax/argmax downstream
-        return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
+    return _output_logits(params, x, dtype)
 
 
 @register("transformer_lm", DEFAULT_CONFIG)
@@ -261,4 +285,5 @@ def build(config: dict) -> ModelDef:
         # shards the sequence over it, and plain TP must keep the bare flash
         # kernel out of a partitioned program (_attention_block)
         bind_mesh=make_apply,
+        engine_ready=True,
     )

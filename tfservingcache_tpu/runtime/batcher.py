@@ -437,8 +437,8 @@ class GenerateCoalescer:
         seed: int | None = None,
     ) -> np.ndarray:
         ids = np.asarray(input_ids, np.int32)
-        family = getattr(self.runtime, "family_of", lambda _m: None)(model_id)
-        if ids.ndim == 2 and family == "transformer_lm":
+        ready = getattr(self.runtime, "engine_ready_of", lambda _m: False)(model_id)
+        if ids.ndim == 2 and ready:
             # oversized prompts must fail loudly AT SUBMIT (mirroring the
             # continuous engine): before this check they joined a pending
             # batch, the leader's drain raised for everyone, and joiners
@@ -455,13 +455,13 @@ class GenerateCoalescer:
             seed is not None
             or ids.ndim != 2
             or ids.shape[0] >= self.max_batch
-            or family != "transformer_lm"
+            or not ready
         ):
             # seeded = reproducible solo; malformed shapes fall through so the
-            # runtime raises its own clean error; capacity-routed families
-            # (moe_lm) never co-batch — expert capacity is computed over the
-            # whole flattened batch, so co-batched strangers would change
-            # which of THIS request's tokens the router drops
+            # runtime raises its own clean error; a family co-batches only if
+            # its ModelDef declares a row-invariant step (engine_ready): one
+            # whose answers depend on the rows beside them would let
+            # co-batched strangers change THIS request's tokens
             return self.runtime.generate(
                 model_id, ids, prompt_lengths=prompt_lengths,
                 max_new_tokens=max_new_tokens, temperature=temperature,
@@ -1570,6 +1570,17 @@ class _ContinuousScheduler:
             eng.metrics.gen_oldest_queued_age.labels("continuous").set(
                 wait_ms / 1e3
             )
+        # an expert model's routing numbers came back with the chunk's
+        # tokens (plain chunks only: a spec round runs the verify step)
+        moe_stats = (0.0, 0.0)
+        if chunk and drafted == 0 and getattr(state, "moe_stats", None):
+            moe_stats = state.moe_stats
+            if eng.metrics is not None:
+                label = eng.metrics.model_label(
+                    self.model_id.name, self.model_id.version)
+                eng.metrics.moe_assignments.labels(label).inc(
+                    active * chunk * dict(state.cfg_key).get("top_k", 1))
+                eng.metrics.moe_expert_rows.labels(label).observe(moe_stats[1])
         paged = state is not None and getattr(state, "paged", False)
         shared = 0
         if paged and hasattr(state, "page_stats"):
@@ -1605,6 +1616,7 @@ class _ContinuousScheduler:
             drafted=drafted, accepted=accepted,
             prefill_ms=prefill_s * 1e3, chunk_ms=chunk_s * 1e3,
             emit_ms=emit_s * 1e3,
+            experts_hit=moe_stats[0], expert_rows_max=moe_stats[1],
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:
@@ -1843,7 +1855,7 @@ class ContinuousGenerateEngine:
     finished rows retire immediately.
 
     Scope mirrors the coalescer's exclusions: explicitly seeded requests
-    (reproducible solo stream), non-transformer_lm families, malformed
+    (reproducible solo stream), families not engine_ready, malformed
     params, and LOCKSTEP mesh runtimes (``runtime.mesh_lockstep`` — a
     cross-process group's device-op stream must not depend on a host
     scheduler thread) all fall through to ``runtime.generate``. A
@@ -2124,7 +2136,7 @@ class ContinuousGenerateEngine:
                 f"unknown priority {priority!r} (expected high|normal|low)"
             )
         ids = np.asarray(input_ids, np.int32)
-        family = getattr(self.runtime, "family_of", lambda _m: None)(model_id)
+        ready = getattr(self.runtime, "engine_ready_of", lambda _m: False)(model_id)
         # mesh_lockstep (ISSUE 20): only CROSS-PROCESS groups (or meshes
         # with serving.mesh_fast_path off) fall back to the solo/coalesce
         # path now — a single-process mesh runs the continuous paged engine
@@ -2137,7 +2149,7 @@ class ContinuousGenerateEngine:
             )
             or ids.ndim != 2
             or not ids.size
-            or family != "transformer_lm"
+            or not ready
         )
         lengths = None
         if not solo:

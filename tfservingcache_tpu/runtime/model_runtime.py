@@ -673,6 +673,9 @@ class SlotDecodeState:
     temps: np.ndarray                # (S,) f32 per-lane temperature
     topks: np.ndarray                # (S,) i32 per-lane top_k
     chunk_counter: int = 0           # host-side PRNG stream for chunk keys
+    # the last decode chunk's routing stats of a model with expert layers:
+    # (experts_hit, expert_rows_max), chunk means; None for a dense model
+    moe_stats: tuple | None = None
     # -- paged-arena bookkeeping (scheduler-thread-owned; page_tokens == 0
     # means dense mode and none of these are consulted) --
     page_tokens: int = 0
@@ -1850,11 +1853,13 @@ class TPUModelRuntime(BaseRuntime):
         loaded = self._resident.get(model_id)
         if loaded is None:
             raise ModelNotLoadedError(f"model {model_id} is not loaded")
-        if loaded.model_def.family not in ("transformer_lm", "moe_lm"):
+        if not loaded.model_def.engine_ready:
             raise RuntimeError_(
-                f"generate is supported for transformer_lm/moe_lm models, "
-                f"not {loaded.model_def.family!r}"
+                "generate is supported for the decoder-LM families "
+                "(transformer_lm, moe_lm: ModelDef.engine_ready), not "
+                f"{loaded.model_def.family!r}"
             )
+        self._refuse_experts_on_mesh(loaded)
         draft = None
         if draft_model_id is not None:
             if temperature > 0.0:
@@ -2040,11 +2045,13 @@ class TPUModelRuntime(BaseRuntime):
         loaded = self._resident.get(model_id)
         if loaded is None:
             raise ModelNotLoadedError(f"model {model_id} is not loaded")
-        if loaded.model_def.family != "transformer_lm":
+        if not loaded.model_def.engine_ready:
             raise RuntimeError_(
-                "continuous decode supports transformer_lm only, not "
+                "continuous decode supports the decoder-LM families "
+                "(transformer_lm, moe_lm: ModelDef.engine_ready), not "
                 f"{loaded.model_def.family!r}"
             )
+        self._refuse_experts_on_mesh(loaded)
         with self._slot_lock:
             st = self._slot_states.get(model_id)
             if st is not None:
@@ -2781,7 +2788,7 @@ class TPUModelRuntime(BaseRuntime):
             if _PAGECHECK:
                 _check_trash_unreachable(state)
             (state.k, state.v, state.scales, tok, pos,
-             toks) = _paged_decode_chunk_jit(
+             toks, stats) = _paged_decode_chunk_jit(
                 loaded.params, state.k, state.v, state.scales,
                 np.asarray(state.block_tables, np.int32),
                 state.tok, state.pos, state.active, rngs,
@@ -2790,6 +2797,7 @@ class TPUModelRuntime(BaseRuntime):
                 page_tokens=state.page_tokens, kernel=state.kernel,
             )
         else:
+            stats = None
             state.k, state.v, tok, pos, toks = _decode_chunk_jit(
                 loaded.params, state.k, state.v,
                 state.tok, state.pos, state.active, rngs,
@@ -2798,9 +2806,13 @@ class TPUModelRuntime(BaseRuntime):
             )
         # np.array (not asarray): device_get hands back READ-ONLY views and
         # the scheduler writes these mirrors at the next admission
-        state.tok = np.array(jax.device_get(tok), dtype=np.int32)
-        state.pos = np.array(jax.device_get(pos), dtype=np.int32)
-        return np.asarray(jax.device_get(toks))
+        # one fetch: an expert model's two routing numbers ride with the tokens
+        tok, pos, toks, stats = jax.device_get((tok, pos, toks, stats))
+        state.tok = np.array(tok, dtype=np.int32)
+        state.pos = np.array(pos, dtype=np.int32)
+        state.moe_stats = None if stats is None else (
+            float(stats[0]), float(stats[1]))
+        return np.asarray(toks)
 
     @_mesh_serialized
     def slot_attach_draft(self, state: SlotDecodeState, draft_id: ModelId,
@@ -2828,10 +2840,11 @@ class TPUModelRuntime(BaseRuntime):
         if loaded is None or draft is None:
             missing = state.model_id if loaded is None else draft_id
             raise ModelNotLoadedError(f"model {missing} is not loaded")
-        if draft.model_def.family != "transformer_lm":
+        if not draft.model_def.engine_ready:
             raise RuntimeError_(
-                "continuous speculation supports transformer_lm drafts "
-                f"only, not {draft.model_def.family!r}"
+                "continuous speculation supports decoder-LM drafts "
+                "(transformer_lm, moe_lm: ModelDef.engine_ready) only, not "
+                f"{draft.model_def.family!r}"
             )
         if (draft.model_def.config["vocab_size"]
                 != loaded.model_def.config["vocab_size"]):
@@ -3363,11 +3376,31 @@ class TPUModelRuntime(BaseRuntime):
         )
 
     def family_of(self, model_id: ModelId) -> str | None:
-        """Family of a resident model (None when not loaded) — the generate
-        coalescer keys on this: capacity-routed families (moe_lm) must not
-        co-batch, their expert routing depends on batch composition."""
+        """Family of a resident model (None when not loaded)."""
         loaded = self._resident.get(model_id, touch=False)
         return None if loaded is None else loaded.model_def.family
+
+    def engine_ready_of(self, model_id: ModelId) -> bool:
+        """Whether a resident model's family declares itself engine-ready
+        (``ModelDef.engine_ready``: KV pages its only layer state, a
+        row-invariant step) — what the coalescer and the continuous engine
+        ask before they co-batch it. False when not loaded."""
+        loaded = self._resident.get(model_id, touch=False)
+        return loaded is not None and loaded.model_def.engine_ready
+
+    def _refuse_experts_on_mesh(self, loaded: LoadedModel) -> None:
+        """``:generate`` of an expert model on a TPU chip group is refused by
+        name: the grouped expert kernel is single-chip and the generate
+        programs cannot tell that they are partitioned (``:predict`` can, and
+        takes ``jax.lax.ragged_dot`` there)."""
+        import jax
+
+        if (self.mesh is not None and jax.default_backend() == "tpu"
+                and "n_experts" in loaded.model_def.config):
+            raise RuntimeError_(
+                "generate of an expert model on a chip group is not supported: "
+                "the grouped expert kernel (ops/moe.py) is single-chip"
+            )
 
     def signature(self, model_id: ModelId):
         loaded = self._resident.get(model_id, touch=False)

@@ -83,6 +83,12 @@ STEP_FIELDS = (
     "prefill_ms",      # admission prefills + chunked-prefill phase
     "chunk_ms",        # decode chunk / spec round: upload, dispatch, wait, fetch
     "emit_ms",         # emission and retirement loop
+    # appended fields (ISSUE 25 expert layer): a model with expert layers
+    # computes them inside the decode chunk and they come back with its
+    # tokens; 0 for a dense model, for a spec round and for a boundary that
+    # ran no chunk
+    "experts_hit",      # distinct experts a layer a step routed to (chunk mean)
+    "expert_rows_max",  # most rows one expert took in a step (chunk mean)
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -95,7 +101,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 19:
+    if len(e) == 21:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -104,6 +110,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "pages_shared": e[12], "prefix_hits": e[13],
             "drafted": e[14], "accepted": e[15],
             "prefill_ms": e[16], "chunk_ms": e[17], "emit_ms": e[18],
+            "experts_hit": e[19], "expert_rows_max": e[20],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -259,6 +266,8 @@ class FlightRecorder:
         prefill_ms: float = 0.0,
         chunk_ms: float = 0.0,
         emit_ms: float = 0.0,
+        experts_hit: float = 0.0,
+        expert_rows_max: float = 0.0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -266,6 +275,7 @@ class FlightRecorder:
             round(oldest_wait_ms, 3), pages_shared, prefix_hits,
             drafted, accepted,
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
+            round(experts_hit, 3), round(expert_rows_max, 3),
         ))
 
     def note_phases(

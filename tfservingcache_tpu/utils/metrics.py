@@ -347,6 +347,23 @@ class Metrics:
             "long-prompt prefill was broken up",
             registry=r,
         )
+        # the expert layer (ops/moe.py): both from the two numbers a decode
+        # chunk's program returns with its tokens (flight recorder ring
+        # fields experts_hit / expert_rows_max)
+        self.moe_assignments = Counter(
+            "tpusc_moe_assignments",
+            "Token-to-expert assignments routed by decode chunks of models "
+            "with expert layers, a layer (live lanes x top_k x steps)",
+            ["model"], registry=r,
+        )
+        self.moe_expert_rows = Histogram(
+            "tpusc_moe_expert_rows",
+            "Rows the busiest expert of a layer took in one decode step "
+            "(mean over a chunk's steps and layers): 1 = every routed expert "
+            "read for a single row",
+            ["model"], registry=r,
+            buckets=(1, 1.5, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64, 128, 256),
+        )
         self.gen_stream_frames = Counter(
             "tpusc_gen_stream_frames",
             "Token frames written to streaming generate clients "

@@ -66,6 +66,10 @@ def _fmt_step(s: dict) -> str:
         pre, chunk, emit = s["prefill_ms"], s["chunk_ms"], s["emit_ms"]
         own = max(0.0, s.get("step_ms", 0) - pre - chunk - emit)
         split = f"(prefill={pre:.2f} chunk={chunk:.2f} emit={emit:.2f} self={own:.2f}) "
+    # expert routing (ISSUE 25); absent in older dumps, 0 for dense models
+    if s.get("experts_hit"):
+        split += (f"experts={s['experts_hit']:.1f} "
+                  f"rows_max={s.get('expert_rows_max', 0):.1f} ")
     return (
         f"  {s.get('engine', '?'):<10} step={s.get('step_ms', 0):>8.2f}ms {split}"
         f"chunk={s.get('chunk', 0):>3} active={s.get('active', 0):>3} "

@@ -41,10 +41,17 @@ def main() -> int:
     # by name with the gate's reference) and the kernel-vs-gather+einsum
     # timing at S in {4,16,32} full lanes and at the benchmark's steady
     # cell, 10 of 32 lanes active (printed, not asserted).
+    # test_olmoe.py carries the grouped expert kernel's rows at
+    # OLMoE-1B-7B's widths: the grouped product with 25 / 64 of 64 experts
+    # hit by 1, 4 and 48 rows each and with 63 experts empty, and the whole
+    # layer at a decode step of 4 / 8 / 32 live lanes and prefills of 512 /
+    # 2048 tokens, kernel against jax.lax.ragged_dot (ms, GB/s of the routed
+    # experts' weights).
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
         os.path.join(REPO, "tests", "test_paged_kernel.py"),
+        os.path.join(REPO, "tests", "test_olmoe.py"),
         "-v", "-rs", "-s", "--no-header",
         *extra,
     ]
