@@ -32,8 +32,25 @@ else:
 
 import asyncio  # noqa: E402
 import inspect  # noqa: E402
+import subprocess  # noqa: E402
 
 import pytest  # noqa: E402
+
+
+def pytest_configure(config):
+    """Build the native library ONCE, before pytest-xdist starts its workers
+    (this hook runs in the controller first; a worker has ``workerinput``).
+    The library is a build product, absent from a fresh checkout, and every
+    worker's first ``native.load()`` runs ``make``: built here, those are
+    no-ops. No toolchain: the native tests skip, as before."""
+    if hasattr(config, "workerinput"):
+        return
+    native = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tfservingcache_tpu", "native")
+    try:
+        subprocess.run(["make", "-C", native], capture_output=True, timeout=120)
+    except (OSError, subprocess.SubprocessError):
+        pass
 
 
 @pytest.hookimpl(tryfirst=True)

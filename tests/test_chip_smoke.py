@@ -242,16 +242,20 @@ def _export_tpu(fn, *args):
     return jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
 
-def _paged_args(g, d, quantized, t_q, lanes=4, hkv=4, pps=8):
+def _paged_args(g, d, quantized, t_q, lanes=4, hkv=4, pps=8, layers=2,
+                n_pages=None):
+    """Operands of a paged kernel on the whole arena ``(layers, n_pages, hkv,
+    16, d)``, as the model steps hand it over (read at ``layer=layers - 1``)."""
     s = jax.ShapeDtypeStruct
     pt = 16
-    n_pages = lanes * pps + 1
+    n_pages = n_pages or lanes * pps + 1
     q = s((lanes, hkv * g, t_q, d), jnp.bfloat16)
-    pages = s((n_pages, hkv, pt, d), jnp.int8 if quantized else jnp.bfloat16)
-    args = [q, pages, pages, s((lanes, pps), jnp.int32),
+    arena = s((layers, n_pages, hkv, pt, d),
+              jnp.int8 if quantized else jnp.bfloat16)
+    args = [q, arena, arena, s((lanes, pps), jnp.int32),
             s((lanes,), jnp.int32)]
     if quantized:
-        scale = s((n_pages, hkv, pt), jnp.float32)
+        scale = s((layers, n_pages, hkv, pt), jnp.float32)
         args += [scale, scale]
     return args, pt
 
@@ -292,7 +296,7 @@ def test_paged_kernels_lower_for_tpu(kernel, g, d, quantized):
     refused = kernel == "decode" and d % 128
     with (pytest.raises(ValueError, match="multiple of 128") if refused
           else contextlib.nullcontext()):
-        _export_tpu(lambda *a: fn(*a, page_tokens=pt), *args)
+        _export_tpu(lambda *a: fn(*a, page_tokens=pt, layer=1), *args)
 
 
 # -- kernels compile for a v5e (Mosaic), still without a device ----------------
@@ -355,15 +359,16 @@ def _compile_only_main():
                 for quantized in (False, True):
                     args, pt = _paged_args(g, d, quantized, t_q)
                     compile_(f"{kernel} g={g} d={d} int8={quantized}",
-                             lambda *a: fn(*a, page_tokens=pt),
+                             lambda *a: fn(*a, page_tokens=pt, layer=1),
                              *on(one, args))
     # the benchmark's steady cell: 32 lanes, 8 kv heads x group 4 of 128,
-    # 128 table slots over a 4096-page arena, with the chunk's active vector
-    args, pt = _paged_args(4, 128, False, 1, lanes=32, hkv=8, pps=128)
-    args[1] = args[2] = s((4096,) + args[1].shape[1:], jnp.bfloat16)
+    # 128 table slots over the 8-layer, 4096-page arena read at its last
+    # layer, with the chunk's active vector
+    args, pt = _paged_args(4, 128, False, 1, lanes=32, hkv=8, pps=128,
+                           layers=8, n_pages=4097)
     compile_("decode at the steady cell's shape",
              lambda *a: att.paged_decode_attention_kernel(
-                 *a[:5], None, None, a[5], page_tokens=pt),
+                 *a[:5], None, None, a[5], page_tokens=pt, layer=7),
              *on(one, args + [s((32,), jnp.bool_)]))
     # a tensor-parallel :predict at a flash-qualifying shape: the gate must
     # keep the bare Mosaic kernel out of the partitioned program (the TPU

@@ -51,11 +51,13 @@ def test_decode_chunk_program_carries_every_scope(model):
         cfg_key=cfg_key, chunk=2, page_tokens=pt, kernel=False,
     ))
     assert [s for s in SCOPES if not has(s)] == []
-    # the arena's slices and updates are told apart inside a layer, and the
-    # re-stack of the slices after the loop from both
-    for path in ("layer/kv_read", "layer/kv_write", "layer/attn", "layer/ffn",
-                 "kv_write/concatenate"):
+    # the reads of the arena (the reference's gather of the lanes' pages) and
+    # its update (one scatter of the new rows a layer) are told apart inside a
+    # layer; nothing re-stacks slices after the loop (PR 26: there are none)
+    for path in ("layer/attn/kv_read/gather", "layer/kv_write/scatter",
+                 "layer/attn", "layer/ffn"):
         assert has(path), path
+    assert not has("layer/kv_read")            # no layer's slice is taken out
 
 
 def test_slot_prefill_program_carries_every_scope(model):

@@ -8,6 +8,8 @@ kernel-off through the continuous engine, and the hardware-gated
 (max-abs-err + bandwidth-proxy timing at S in {4,16,32} lanes and at the
 benchmark's steady cell: 10 of 32 lanes active, ragged lengths)."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +18,7 @@ import pytest
 import tfservingcache_tpu.models.generation as generation
 import tfservingcache_tpu.ops.attention as att
 from tfservingcache_tpu.config import ServingConfig
-from tfservingcache_tpu.models.registry import export_artifact
+from tfservingcache_tpu.models.registry import build, export_artifact
 from tfservingcache_tpu.ops.attention import (
     dequantize_pages,
     paged_attention,
@@ -189,6 +191,58 @@ def test_dispatch_kernel_off_is_reference_path():
     # on CPU the TPU-shape gate also falls back to the reference
     on_cpu = np.asarray(paged_attention(*args, PT, kernel=True))
     assert (on_cpu == ref).all()
+
+
+# -- the arena where it lies (PR 26) -------------------------------------------
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "verify"])
+def test_kernel_reads_its_layer_of_the_whole_arena(kernel, quantized, layer):
+    """The kernels take the 5-D arena ``(layers, n_pages, hkv, pt, d)`` and a
+    static ``layer`` and index it themselves (a manual copy of
+    ``arena[layer, page]``, a BlockSpec with the layer squeezed), so that no
+    caller slices a layer out. Three layers of different pages: the kernel at
+    the first and the last equals the gather+einsum reference on THAT layer's
+    pages alone, and so does the reference on the whole arena."""
+    t_q = 1 if kernel == "decode" else 3
+    lanes, hkv, g, d, pps, layers = 4, 2, 2, 16, 4, 3
+    per_layer = [_arena(lanes, hkv * g, hkv, d, pps, PT, seed=40 + li)
+                 for li in range(layers)]
+    # verify writes draft rows up to pos + T - 1: keep them inside the table
+    pos = np.minimum(per_layer[0][4], pps * PT - t_q).astype(np.int32)
+    tables = np.arange(1, lanes * pps + 1, dtype=np.int32).reshape(lanes, pps)
+    q = np.random.default_rng(7).standard_normal(
+        (lanes, hkv * g, t_q, d)).astype(np.float32)
+    q, tables, pos = jnp.asarray(q), jnp.asarray(tables), jnp.asarray(pos)
+    arena = [jnp.stack([jnp.asarray(c[w]) for c in per_layer]) for w in (1, 2)]
+    scales = []
+    if quantized:
+        (kq, ks), (vq, vs) = (generation._quantize_kv_rows(a) for a in arena)
+        arena, scales = [kq, vq], [ks, vs]
+        layer_pages = [dequantize_pages(a[layer], sc[layer])
+                       for a, sc in zip(arena, scales)]
+    else:
+        layer_pages = [a[layer] for a in arena]
+    if kernel == "decode":
+        ref_fn, kern_fn, gate = (paged_decode_attention,
+                                 paged_decode_attention_kernel, paged_attention)
+    else:
+        ref_fn, kern_fn, gate = (paged_verify_attention,
+                                 paged_verify_attention_kernel,
+                                 att.paged_attention_verify)
+    want = np.asarray(ref_fn(q, *layer_pages, tables, pos, PT))
+    got = np.asarray(kern_fn(q, *arena, tables, pos, *scales,
+                             page_tokens=PT, interpret=True, layer=layer))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    other = np.asarray(kern_fn(q, *arena, tables, pos, *scales,
+                               page_tokens=PT, interpret=True, layer=1))
+    assert np.abs(other - want).max() > 1e-2          # the layer is not ignored
+    # the reference on the whole arena gathers the lanes' pages of that layer
+    # and dequantizes after the gather: the same numbers
+    whole = np.asarray(gate(q, *arena, tables, pos, PT, *scales,
+                            kernel=False, layer=layer))
+    np.testing.assert_allclose(whole, want, atol=1e-6, rtol=1e-6)
 
 
 # -- engine-level greedy parity ----------------------------------------------
@@ -443,3 +497,63 @@ def test_paged_verify_shapes_on_tpu(g, d, quantized):
     assert np.isfinite(err) and err < 3e-2, (
         f"verify kernel diverges at g={g} d={d} quantized={quantized}: {err}"
     )
+
+
+@pytest.mark.skipif(
+    jax.default_backend() != "tpu",
+    reason="needs real TPU (conftest forces CPU; run via tools/tpu_kernel_check.py)",
+)
+def test_decode_chunk_time_does_not_follow_the_arena_on_tpu():
+    """The invariant of PR 26 on the chip: a decode step writes its new rows
+    and reads its live pages, so its time does not depend on how large the
+    arena is. One decode chunk (8 steps) of the benchmark's Mistral cell
+    (``mistral7b-chat-steady``: 8 layers of 4096, 32 / 8 heads of 128, FFN
+    14336, vocab 32768, 32 lanes, 128 table slots, kernel on), the same four
+    live lanes of 600-1500 tokens, at ``kv_arena_pages`` 512 and 4096: the
+    step times agree within 10 % (before: 8 passes over a layer's slice a
+    step, so the larger arena cost about eight times more)."""
+    cfg = build("transformer_lm", {
+        "vocab_size": 32768, "d_model": 4096, "n_layers": 8, "n_heads": 32,
+        "n_kv_heads": 8, "d_ff": 14336, "max_seq": 2048, "rope_theta": 1e6,
+        "dtype": "bfloat16"}).config
+    shapes = jax.eval_shape(build("transformer_lm", cfg).init,
+                            jax.random.PRNGKey(0))
+    leaves, treedef = jax.tree_util.tree_flatten(shapes)
+    params = jax.tree_util.tree_unflatten(treedef, [
+        (0.02 * jax.random.normal(jax.random.PRNGKey(i), a.shape, jnp.bfloat16)
+         if a.ndim > 1 else jnp.ones(a.shape, jnp.bfloat16))
+        for i, a in enumerate(leaves)])
+    lanes, pps, pt, chunk = 32, 128, 16, 8
+    live = np.asarray([600, 900, 1200, 1500], np.int32)
+    tables = np.zeros((lanes, pps), np.int32)
+    nxt = 1
+    for lane, p in enumerate(live):
+        n = -(-(int(p) + chunk + 1) // pt)
+        tables[lane, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    assert nxt <= 512
+    pos = np.zeros((lanes,), np.int32)
+    pos[:len(live)] = live
+    active = np.arange(lanes) < len(live)
+    rngs = jax.random.split(jax.random.PRNGKey(1), chunk)
+    ms = {}
+    for n_pages in (512, 4096):
+        cache = generation.init_paged_cache(cfg, n_pages + 1, pt)
+        k, v = cache["k"], cache["v"]
+        tok = jnp.ones((lanes,), jnp.int32)
+        best = float("inf")
+        for _ in range(6):                   # the first call compiles
+            t0 = time.perf_counter()
+            k, v, _, _, _, toks, _ = generation._paged_decode_chunk_jit(
+                params, k, v, None, tables, tok, pos, active, rngs,
+                np.zeros((lanes,), np.float32), np.zeros((lanes,), np.int32),
+                cfg_key=tuple(sorted(cfg.items())), chunk=chunk,
+                page_tokens=pt, kernel=True)
+            jax.block_until_ready(toks)
+            best = min(best, time.perf_counter() - t0)
+        ms[n_pages] = best / chunk * 1e3
+        del k, v, cache
+    print(f"\n[decode chunk vs arena] Mistral cell shape, 4 live lanes "
+          f"({int(live.sum())} tokens): {ms[512]:.3f} ms a step at 512 pages, "
+          f"{ms[4096]:.3f} ms at 4096 pages", flush=True)
+    assert abs(ms[4096] - ms[512]) / ms[512] < 0.10, ms

@@ -446,120 +446,89 @@ def _quantize_kv_rows(x):
     return q, scale
 
 
+def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows):
+    """THE write into the paged arena, shared by every paged step: the new
+    rows ``k_rows`` / ``v_rows`` ``(S, T, n_kv, hd)`` of layer ``li`` go to
+    ``arena[li, pages, :, off]`` (``pages`` / ``off`` ``(S, T)``) as one
+    scatter on the whole 5-D arena. The arena is donated to the chunk
+    programs and carried by their scans, so the scatter updates it in
+    place: a step moves its new rows, never a layer's slice (there is no
+    ``cache["k"][li]`` and nothing is stacked back). ``li`` is a Python int
+    (the layer loop is unrolled).
+
+    The kv head is an INDEX of the scatter too, not a slice of its window:
+    the update is one ``hd`` row a (lane, position, head). With the heads in
+    the window (``.at[li, pages, :, off, :]``) the TPU compiler gives the
+    scatter a layout of its own, heads next to ``hd``, and converts the WHOLE
+    arena into it and back around every layer's write, because the paged
+    kernels read the arena in the layout it is stored in (PR 26, compiled
+    for the v5e: 20 arena-sized copies in the decode chunk; none this way).
+
+    An int8 arena (``k_scale`` present) quantizes each row here, with
+    per-row scales, so resident rows are never requantized. Lanes parked on
+    the trash page may collide: last-writer-wins junk that no live lane's
+    block table can reach. Returns the updated cache."""
+    with jax.named_scope("kv_write"):
+        heads = jnp.arange(k_rows.shape[2])[None, None, :]
+        at = (li, pages[:, :, None], heads, off[:, :, None])     # (S, T, n_kv)
+        new = {}
+        if "k_scale" in cache:
+            k_rows, k_s = _quantize_kv_rows(k_rows)
+            v_rows, v_s = _quantize_kv_rows(v_rows)
+            new["k_scale"] = cache["k_scale"].at[at].set(k_s)
+            new["v_scale"] = cache["v_scale"].at[at].set(v_s)
+        new["k"] = cache["k"].at[at].set(k_rows.astype(cache["k"].dtype))
+        new["v"] = cache["v"].at[at].set(v_rows.astype(cache["v"].dtype))
+        return new
+
+
 def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
                         page_tokens: int, kernel: bool = False, active=None,
                         moe_stats: list | None = None):
-    """One decode step (s_len=1 per lane) against the paged arena — the
-    block-table counterpart of ``_forward_cached_dyn``. Each lane writes its
-    new K/V at ``tables[lane, pos // page_tokens]`` offset ``pos %
-    page_tokens`` (clipped to the last table slot: overshoot past a lane's
-    reservation hits a zeroed table entry, i.e. the trash page), then
-    attends over its pages via ``paged_attention`` — the fused Pallas
-    kernel when ``kernel`` and the backend/shape gate admit it, else the
-    gather+einsum reference whose GQA/mask pipeline matches the dense path
-    operation-for-operation, so greedy decode is token-for-token identical.
-
-    An int8 arena (``cache["k_scale"]`` present) quantizes each lane's new
-    row at write time — per-row scales, so resident rows are never
-    requantized — and attention dequantizes on the read side.
-
-    ``active`` (the chunk's frozen ``(S,)`` vector, default all lanes) goes
-    to ``paged_attention`` as it is: the caller discards an inactive lane's
-    token, so the kernel reads no page for it, and an expert layer routes
-    it to no expert. Each expert layer's ``(experts_hit, expert_rows_max)``
-    is appended to ``moe_stats`` where the caller gives a list (a dense
-    model appends nothing)."""
-    from tfservingcache_tpu.ops.attention import paged_attention
-
-    dtype = jnp.dtype(cfg["dtype"])
-    s_lanes = tok.shape[0]
-    n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"]
-    head_dim = cfg["d_model"] // n_heads
-    pps = tables.shape[1]
-    positions = pos[:, None]                                     # (S, 1)
-    page = jnp.take_along_axis(
-        tables, jnp.clip(pos // page_tokens, 0, pps - 1)[:, None], axis=1
-    )[:, 0]                                                      # (S,)
-    # past-the-table writes go to the trash page EXPLICITLY: the clip above
-    # would otherwise hand back the lane's own last slot, which is a live
-    # reserved page when the lane's budget fills the whole table (a draft
-    # scan near max_seq under spec headroom capping can get here)
-    page = jnp.where(pos // page_tokens >= pps, 0, page)
-    off = pos % page_tokens
-    quantized = "k_scale" in cache
-
-    with jax.named_scope("embed"):
-        x = params["embed"][tok[:, None]].astype(dtype)          # (S, 1, d)
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    for li, layer in enumerate(params["layers"]):
-        with jax.named_scope("layer"):
-            with jax.named_scope("attn"):
-                attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
-                q = _rope_per_example(q, positions, cfg["rope_theta"])
-                k = _rope_per_example(k, positions, cfg["rope_theta"])
-            with jax.named_scope("kv_read"):
-                # this layer's slice of the arena
-                k_layer, v_layer = cache["k"][li], cache["v"][li]
-
-            # scatter each lane's single new row into its current page; lanes
-            # parked on the trash page may collide — last-writer-wins junk that
-            # no live lane's block table can reach
-            with jax.named_scope("kv_write"):
-                k_row, v_row = k[:, :, 0, :], v[:, :, 0, :]      # (S, n_kv, hd)
-                ks_arena = vs_arena = None
-                if quantized:
-                    k_row, k_s = _quantize_kv_rows(k_row)
-                    v_row, v_s = _quantize_kv_rows(v_row)
-                    ks_arena = cache["k_scale"][li].at[page, :, off].set(k_s)
-                    vs_arena = cache["v_scale"][li].at[page, :, off].set(v_s)
-                    new_ks.append(ks_arena)
-                    new_vs.append(vs_arena)
-                k_arena = k_layer.at[page, :, off, :].set(
-                    k_row.astype(cache["k"].dtype)
-                )
-                v_arena = v_layer.at[page, :, off, :].set(
-                    v_row.astype(cache["v"].dtype)
-                )
-                new_k.append(k_arena)
-                new_v.append(v_arena)
-
-            with jax.named_scope("attn"):
-                out = paged_attention(q, k_arena, v_arena, tables, pos, page_tokens,
-                                      k_scale=ks_arena, v_scale=vs_arena,
-                                      kernel=kernel, active=active)
-                out = out.reshape(s_lanes, n_heads, 1, head_dim).astype(x.dtype)
-                out = out.transpose(0, 2, 1, 3).reshape(s_lanes, 1, cfg["d_model"])
-                x = x + out @ attn["wo"]
-            x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
-                               moe_stats=moe_stats)
-    logits = _output_logits(params, x, dtype)
-    with jax.named_scope("kv_write"):
-        # the per-layer slices back into one arena
-        new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-        if quantized:
-            new_cache["k_scale"] = jnp.stack(new_ks)
-            new_cache["v_scale"] = jnp.stack(new_vs)
-    return logits, new_cache
+    """One decode step (s_len=1 per lane, ``tok`` ``(S,)``) against the
+    paged arena — the block-table counterpart of ``_forward_cached_dyn``,
+    and ``_paged_verify_step``'s T = 1 case: that is where it is written."""
+    return _paged_verify_step(
+        params, tok[:, None], cache, tables, pos, cfg, family, page_tokens,
+        kernel=kernel, active=active, moe_stats=moe_stats,
+    )
 
 
 def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
-                       page_tokens: int, kernel: bool = False):
-    """One multi-position forward (s_len=T per lane) against the paged
-    arena — the verify pass of in-engine speculative decoding. Lane ``s``'s
-    T tokens ``toks[s]`` sit at positions ``pos[s]..pos[s]+T-1``; each
-    writes its K/V row at ``tables[lane, p // page_tokens]`` offset
-    ``p % page_tokens`` (clipped to the last table slot — overshoot past
-    the reservation lands on the trash page, exactly like the decode
-    step), then all T queries attend in ONE ``paged_attention_verify``
-    call with per-position causal masks. With T == 1 the math degenerates
-    to ``_paged_forward_step`` operation-for-operation, which is what
-    keeps spec-on greedy decode token-for-token identical to spec-off.
+                       page_tokens: int, kernel: bool = False, active=None,
+                       moe_stats: list | None = None):
+    """One forward of T positions a lane against the paged arena: the decode
+    step (T = 1), the verify pass of in-engine speculative decoding and a
+    chunk of chunked prefill. Lane ``s``'s T tokens ``toks[s]`` sit at
+    positions ``pos[s]..pos[s]+T-1``; each writes its K/V row at
+    ``tables[lane, p // page_tokens]`` offset ``p % page_tokens`` (clipped
+    to the last table slot: overshoot past a lane's reservation hits a
+    zeroed table entry, i.e. the trash page) through ``_paged_write_rows``,
+    in place on the arena, then the queries attend over the lane's pages of
+    the arena where it lies: ``paged_attention`` at T = 1,
+    ``paged_attention_verify`` (per-position causal masks, all T queries in
+    ONE call) above it — the fused Pallas kernels when ``kernel`` and the
+    backend/shape gate admit them, else the gather+einsum reference whose
+    GQA/mask pipeline matches the dense path operation-for-operation, so
+    greedy decode is token-for-token identical, and spec-on identical to
+    spec-off. The attention's operand is the write's result, so the new
+    rows are read after they are written by data dependency.
 
-    An int8 arena quantizes each of the T new rows at write time with the
-    same per-row absmax discipline — rejected draft rows are quantization
-    junk above the accepted prefix, masked until overwritten."""
-    from tfservingcache_tpu.ops.attention import paged_attention_verify
+    An int8 arena (``cache["k_scale"]`` present) quantizes each new row at
+    write time and attention dequantizes on the read side — rejected draft
+    rows are quantization junk above the accepted prefix, masked until
+    overwritten.
+
+    ``active`` (T = 1 only: the chunk's frozen ``(S,)`` vector, default all
+    lanes) goes to ``paged_attention`` as it is: the caller discards an
+    inactive lane's token, so the kernel reads no page for it, and an expert
+    layer routes it to no expert. Each expert layer's ``(experts_hit,
+    expert_rows_max)`` is appended to ``moe_stats`` where the caller gives a
+    list (a dense model appends nothing)."""
+    from tfservingcache_tpu.ops.attention import (
+        paged_attention,
+        paged_attention_verify,
+    )
 
     dtype = jnp.dtype(cfg["dtype"])
     s_lanes, t_q = toks.shape
@@ -570,16 +539,15 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     pages = jnp.take_along_axis(
         tables, jnp.clip(positions // page_tokens, 0, pps - 1), axis=1
     )                                                            # (S, T)
-    # past-the-table positions redirect to the trash page explicitly — the
-    # clip alone would alias them onto the lane's own LAST slot, stomping
-    # visible history when the reservation fills the whole table
+    # past-the-table positions redirect to the trash page EXPLICITLY — the
+    # clip alone would alias them onto the lane's own LAST slot, which is a
+    # live reserved page when the lane's budget fills the whole table (a
+    # draft scan near max_seq under spec headroom capping can get here)
     pages = jnp.where(positions // page_tokens >= pps, 0, pages)
     off = positions % page_tokens
-    quantized = "k_scale" in cache
 
     with jax.named_scope("embed"):
         x = params["embed"][toks].astype(dtype)                  # (S, T, d)
-    new_k, new_v, new_ks, new_vs = [], [], [], []
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope("layer"):
             with jax.named_scope("attn"):
@@ -587,48 +555,26 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                 q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
                 q = _rope_per_example(q, positions, cfg["rope_theta"])
                 k = _rope_per_example(k, positions, cfg["rope_theta"])
-            with jax.named_scope("kv_read"):
-                k_layer, v_layer = cache["k"][li], cache["v"][li]
-
-            # scatter the T new rows per lane: advanced indices (S, T) at arena
-            # dims 0 and 2 straddle the head slice, so the updated block is
-            # (S, T, n_kv, hd) — the natural layout of the projection
-            with jax.named_scope("kv_write"):
-                k_rows = k.transpose(0, 2, 1, 3)                 # (S, T, n_kv, hd)
-                v_rows = v.transpose(0, 2, 1, 3)
-                ks_arena = vs_arena = None
-                if quantized:
-                    k_rows, k_s = _quantize_kv_rows(k_rows)
-                    v_rows, v_s = _quantize_kv_rows(v_rows)
-                    ks_arena = cache["k_scale"][li].at[pages, :, off].set(k_s)
-                    vs_arena = cache["v_scale"][li].at[pages, :, off].set(v_s)
-                    new_ks.append(ks_arena)
-                    new_vs.append(vs_arena)
-                k_arena = k_layer.at[pages, :, off, :].set(
-                    k_rows.astype(cache["k"].dtype)
-                )
-                v_arena = v_layer.at[pages, :, off, :].set(
-                    v_rows.astype(cache["v"].dtype)
-                )
-                new_k.append(k_arena)
-                new_v.append(v_arena)
-
+            cache = _paged_write_rows(
+                cache, li, pages, off,
+                k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+            )
             with jax.named_scope("attn"):
-                out = paged_attention_verify(
-                    q, k_arena, v_arena, tables, pos, page_tokens,
-                    k_scale=ks_arena, v_scale=vs_arena, kernel=kernel,
-                )
+                operands = (q, cache["k"], cache["v"], tables, pos,
+                            page_tokens, cache.get("k_scale"),
+                            cache.get("v_scale"))
+                if t_q == 1:
+                    out = paged_attention(*operands, kernel=kernel,
+                                          active=active, layer=li)
+                else:
+                    out = paged_attention_verify(*operands, kernel=kernel,
+                                                 layer=li)
                 out = out.reshape(s_lanes, n_heads, t_q, head_dim).astype(x.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(s_lanes, t_q, cfg["d_model"])
                 x = x + out @ attn["wo"]
-            x = x + _ffn_block(layer, x, cfg, dtype)
-    logits = _output_logits(params, x, dtype)
-    with jax.named_scope("kv_write"):
-        new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-        if quantized:
-            new_cache["k_scale"] = jnp.stack(new_ks)
-            new_cache["v_scale"] = jnp.stack(new_vs)
-    return logits, new_cache
+            x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
+                               moe_stats=moe_stats)
+    return _output_logits(params, x, dtype), cache
 
 
 @functools.partial(

@@ -532,21 +532,38 @@ def flash_attention_carry(
 # Paged-KV attention (continuous decode engine)
 # ---------------------------------------------------------------------------
 
+def _as_arena(pages: jax.Array, ndim: int = 5) -> jax.Array:
+    """The paged functions take the ARENA ``(layers, n_pages, Hkv,
+    page_tokens, D)`` (scales: without the last dimension) and a static
+    ``layer``, and index it where it lies: a caller never slices a layer
+    out, which XLA would materialise (268 MB a layer in the chat cells).
+    One layer's pages alone, one dimension fewer than ``ndim`` (4 for the
+    scales), are a one-layer arena."""
+    return pages if pages.ndim == ndim else pages[None]
+
+
 @jax.named_scope("kv_read")
 def paged_gather_kv(
-    pages: jax.Array, tables: jax.Array, page_tokens: int
+    pages: jax.Array, tables: jax.Array, page_tokens: int, layer: int = 0,
+    scale: jax.Array | None = None,
 ) -> jax.Array:
     """Assemble each lane's logical K or V row from the shared page arena.
 
-    ``pages`` is the arena ``(n_pages, Hkv, page_tokens, D)``; ``tables``
-    is the per-lane block table ``(S, pages_per_slot)`` of page indices.
-    Logical position ``p`` of lane ``s`` lives at
-    ``pages[tables[s, p // page_tokens], :, p % page_tokens]`` — the gather
-    lays pages out in block-table order, so the result
+    ``pages`` is the arena ``(layers, n_pages, Hkv, page_tokens, D)``
+    (``_as_arena``); ``tables`` is the per-lane block table
+    ``(S, pages_per_slot)`` of page indices. Logical position ``p`` of lane
+    ``s`` lives at
+    ``pages[layer, tables[s, p // page_tokens], :, p % page_tokens]`` — ONE
+    gather of the lanes' own pages out of the whole arena, laid out in
+    block-table order, so the result
     ``(S, Hkv, pages_per_slot * page_tokens, D)`` is positionally identical
     to a dense per-lane cache row and the dense causal mask applies as-is.
     A lane only ever gathers its OWN pages plus the shared trash page, so
-    no cross-lane bytes are touched even before masking.
+    no cross-lane bytes are touched even before masking. An int8 arena
+    (``scale``: its per-(page, head, token) f32 scales, the arena's shape
+    without D) is dequantized to f32 rows AFTER the gather, the lanes' pages
+    only: the REFERENCE dequant — the Pallas paged kernels apply the same
+    scales in VMEM to the block they just fetched.
 
     SILENT-JUNK HAZARD (documented + checked, ISSUE 14): a table entry of
     0 is the trash page — last-writer junk from every parked lane. Junk is
@@ -558,8 +575,12 @@ def paged_gather_kv(
     guarantee into an assertion at every chunk dispatch
     (model_runtime._check_trash_unreachable)."""
     s_lanes, pps = tables.shape
-    _, hkv, pt, d = pages.shape
-    gathered = pages[tables]                       # (S, PPS, Hkv, pt, D)
+    pages = _as_arena(pages)
+    _, _, hkv, pt, d = pages.shape
+    gathered = pages[layer, tables]                # (S, PPS, Hkv, pt, D)
+    if scale is not None:
+        gathered = dequantize_pages(
+            gathered, _as_arena(scale, 4)[layer, tables])
     return gathered.transpose(0, 2, 1, 3, 4).reshape(
         s_lanes, hkv, pps * pt, d
     )
@@ -572,14 +593,18 @@ def paged_decode_attention(
     tables: jax.Array,
     pos: jax.Array,
     page_tokens: int,
+    layer: int = 0,
+    k_scale: jax.Array | None = None,
+    v_scale: jax.Array | None = None,
 ) -> jax.Array:
     """Single-position attention over a paged KV arena — the decode-step
     counterpart of the dense slot read in ``_forward_cached_dyn``.
 
     Shapes: q ``(S, Hq, 1, D)`` (one query per lane, post-RoPE),
-    k_pages/v_pages ``(n_pages, Hkv, page_tokens, D)``, tables
-    ``(S, pages_per_slot)`` int32, pos ``(S,)`` int32 query positions.
-    Returns f32 ``(S, Hq, 1, D)``.
+    k_pages/v_pages the arena ``(layers, n_pages, Hkv, page_tokens, D)``
+    read at ``layer`` (``_as_arena``; int8 with ``k_scale``/``v_scale``),
+    tables ``(S, pages_per_slot)`` int32, pos ``(S,)`` int32 query
+    positions. Returns f32 ``(S, Hq, 1, D)``.
 
     The math mirrors the dense path operation-for-operation (GQA grouped
     K/V, dots in the stored dtype with f32 accumulation via
@@ -591,13 +616,13 @@ def paged_decode_attention(
     unreserved table entries and a lane's own not-yet-written positions —
     sit strictly above ``pos`` and are masked before the softmax."""
     s_lanes, hq, _, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     g = hq // hkv
-    kc = paged_gather_kv(k_pages, tables, page_tokens)   # (S, Hkv, L, D)
-    vc = paged_gather_kv(v_pages, tables, page_tokens)
-    qg = q.reshape(s_lanes, hkv, g, 1, d)
+    kc = paged_gather_kv(k_pages, tables, page_tokens, layer, k_scale)
+    vc = paged_gather_kv(v_pages, tables, page_tokens, layer, v_scale)
+    qg = q.reshape(s_lanes, hkv, g, 1, d)                # kc: (S, Hkv, L, D)
     s = jnp.einsum(
         "bkgqd,bkld->bkgql", qg, kc, preferred_element_type=jnp.float32
     ) / math.sqrt(d)
@@ -619,14 +644,18 @@ def paged_verify_attention(
     tables: jax.Array,
     pos: jax.Array,
     page_tokens: int,
+    layer: int = 0,
+    k_scale: jax.Array | None = None,
+    v_scale: jax.Array | None = None,
 ) -> jax.Array:
     """Multi-token-query attention over a paged KV arena — the verify pass
     of in-engine speculative decoding (ISSUE 16).
 
     Shapes: q ``(S, Hq, T, D)`` (T = spec_tokens + 1 query positions per
-    lane, post-RoPE), k_pages/v_pages ``(n_pages, Hkv, page_tokens, D)``,
-    tables ``(S, pages_per_slot)`` int32, pos ``(S,)`` int32 positions of
-    each lane's FIRST query token. Returns f32 ``(S, Hq, T, D)``.
+    lane, post-RoPE), k_pages/v_pages the arena read at ``layer`` as in
+    ``paged_decode_attention``, tables ``(S, pages_per_slot)`` int32, pos
+    ``(S,)`` int32 positions of each lane's FIRST query token. Returns f32
+    ``(S, Hq, T, D)``.
 
     Query index ``t`` of lane ``s`` sits at position ``pos[s] + t`` and
     attends with the causal mask ``k_pos <= pos[s] + t`` — with T == 1 this
@@ -638,13 +667,13 @@ def paged_verify_attention(
     ``pos..pos+T-1``; rows above the eventually-accepted prefix are junk a
     later round overwrites — same discipline as the solo verify chunk."""
     s_lanes, hq, t, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[-3]
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     g = hq // hkv
-    kc = paged_gather_kv(k_pages, tables, page_tokens)   # (S, Hkv, L, D)
-    vc = paged_gather_kv(v_pages, tables, page_tokens)
-    qg = q.reshape(s_lanes, hkv, g, t, d)
+    kc = paged_gather_kv(k_pages, tables, page_tokens, layer, k_scale)
+    vc = paged_gather_kv(v_pages, tables, page_tokens, layer, v_scale)
+    qg = q.reshape(s_lanes, hkv, g, t, d)                # kc: (S, Hkv, L, D)
     s = jnp.einsum(
         "bkgqd,bkld->bkgql", qg, kc, preferred_element_type=jnp.float32
     ) / math.sqrt(d)
@@ -660,13 +689,13 @@ def paged_verify_attention(
     return out.reshape(s_lanes, hq, t, d)
 
 
-@jax.named_scope("kv_read")
 def dequantize_pages(pages: jax.Array, scales: jax.Array) -> jax.Array:
-    """Expand an int8 page arena ``(n_pages, Hkv, page_tokens, D)`` against
-    its per-(page, head, token) f32 scales ``(n_pages, Hkv, page_tokens)``
-    back to f32 rows. This is the REFERENCE dequant — the Pallas paged
-    kernel performs the same multiply in VMEM on the one page it just
-    streamed, so the f32 arena never materializes in HBM on the fast path."""
+    """Expand int8 pages ``(..., page_tokens, D)`` against their
+    per-(page, head, token) f32 scales ``(..., page_tokens)`` back to f32
+    rows. This is the REFERENCE dequant, applied by ``paged_gather_kv`` to
+    the pages it gathered — the Pallas paged kernels perform the same
+    multiply in VMEM on the block they just streamed, so no f32 copy of an
+    arena ever materializes in HBM."""
     return pages.astype(jnp.float32) * scales[..., None]
 
 
@@ -699,14 +728,16 @@ PAGED_BLOCK_TOKENS = 128
 def _paged_decode_kernel(
     tables_ref, pos_ref, active_ref, q_ref, k_hbm, v_hbm, *rest,
     sm_scale: float, page_tokens: int, block_pages: int, quantized: bool,
+    layer: int,
 ):
     """One LANE of paged decode attention: a grid step per lane, and inside
     it a loop over the lane's LIVE pages only, ``block_pages`` at a time.
 
-    K and V stay in HBM. The arena's layer slice is page-major
-    ``(n_pages, hkv, pt, d)``, so one page with all its kv heads is one
-    contiguous run: each block is ``block_pages`` page copies for K and as
-    many for V (``make_async_copy`` into ``buf[slot, :, p]``, a destination
+    K and V stay in HBM, the WHOLE arena, read at the static ``layer``: no
+    operand is a slice XLA would have to materialise. The arena is
+    page-major ``(layers, n_pages, hkv, pt, d)``, so one page with all its
+    kv heads is one contiguous run: each block is ``block_pages`` page
+    copies for K and as many for V (``make_async_copy`` into ``buf[slot, :, p]``, a destination
     strided over heads so that every head's block is contiguous for the
     product), double-buffered — block n+1 lands while block n is multiplied.
     All heads of a block are computed in the step that fetched it, with the
@@ -752,9 +783,11 @@ def _paged_decode_kernel(
             ]
             copies += [
                 pltpu.make_async_copy(
-                    k_hbm.at[page], k_buf.at[slot, :, p], sem.at[0, slot]),
+                    k_hbm.at[layer, page], k_buf.at[slot, :, p],
+                    sem.at[0, slot]),
                 pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[slot, :, p], sem.at[1, slot]),
+                    v_hbm.at[layer, page], v_buf.at[slot, :, p],
+                    sem.at[1, slot]),
             ]
         return copies
 
@@ -820,23 +853,27 @@ def _paged_decode_kernel(
     ).astype(o_ref.dtype)
 
 
-def _lane_scale_rows(scale, tables, pos, block_tokens: int):
+@jax.named_scope("kv_read")
+def _lane_scale_rows(scale, tables, pos, block_tokens: int, layer: int):
     """Each lane's scale rows in token order, ``(S, hkv, 1, tokens)`` with
-    ``tokens`` rounded up to whole compute blocks: ``scale`` is
-    ``(n_pages, hkv, pt)``, a page's rows are 16 lanes wide, so XLA gathers
-    them (1/32 of the int8 KV bytes at head 128) where the pages themselves
-    are copied by the kernel. Rows above ``pos`` are zeroed: the kernel
-    multiplies masked scores and zero probabilities by them, and a dead
-    table slot's trash page may hold anything."""
+    ``tokens`` rounded up to whole compute blocks: ``scale`` is the arena's
+    ``(layers, n_pages, hkv, pt)``, a page's rows are 16 lanes wide, so XLA
+    gathers the lanes' own (``scale[layer, tables]``: 1/32 of the int8 KV
+    bytes at head 128) where the pages themselves are copied by the kernel.
+    Rows above ``pos`` are zeroed: the kernel multiplies masked scores and
+    zero probabilities by them, and a dead table slot's trash page may hold
+    anything."""
     s_lanes, pps = tables.shape
-    _, hkv, pt = scale.shape
-    rows = scale[tables].transpose(0, 2, 1, 3).reshape(s_lanes, hkv, pps * pt)
+    _, _, hkv, pt = scale.shape
+    rows = scale[layer, tables].transpose(0, 2, 1, 3).reshape(
+        s_lanes, hkv, pps * pt)
     rows = jnp.where(jnp.arange(pps * pt) <= pos[:, None, None], rows, 0.0)
     pad = -(pps * pt) % block_tokens
     return jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))[:, :, None, :]
 
 
-@functools.partial(jax.jit, static_argnames=("page_tokens", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("page_tokens", "interpret", "layer"))
 def paged_decode_attention_kernel(
     q: jax.Array,
     k_pages: jax.Array,
@@ -849,16 +886,17 @@ def paged_decode_attention_kernel(
     *,
     page_tokens: int,
     interpret: bool = False,
+    layer: int = 0,
 ) -> jax.Array:
     """Fused paged decode attention: same contract as
-    ``paged_decode_attention`` (q ``(S, Hq, 1, D)``, arena pages
-    ``(n_pages, Hkv, page_tokens, D)``, tables ``(S, pages_per_slot)``,
-    pos ``(S,)`` -> f32 ``(S, Hq, 1, D)``), but ONE pass over the LIVE KV
-    bytes: the grid is one step a lane, block tables, positions and the
-    ``active`` vector ride in as scalar-prefetch operands, and the kernel
-    copies each active lane's live pages straight out of the arena,
-    ``PAGED_BLOCK_TOKENS`` tokens a block (``_paged_decode_kernel``). With
-    ``k_scale``/``v_scale`` (``(n_pages, Hkv, page_tokens)`` f32) the arena
+    ``paged_decode_attention`` (q ``(S, Hq, 1, D)``, the arena
+    ``(layers, n_pages, Hkv, page_tokens, D)`` read at the static ``layer``,
+    tables ``(S, pages_per_slot)``, pos ``(S,)`` -> f32 ``(S, Hq, 1, D)``),
+    but ONE pass over the LIVE KV bytes: the grid is one step a lane, block
+    tables, positions and the ``active`` vector ride in as scalar-prefetch
+    operands, and the kernel copies each active lane's live pages straight
+    out of the arena where it lies, ``PAGED_BLOCK_TOKENS`` tokens a block (``_paged_decode_kernel``). With
+    ``k_scale``/``v_scale`` (the arena's shape without D, f32) the arena
     is int8 and dequantized in VMEM per fetched block. ``active`` (``(S,)``
     bool, default all true) marks lanes whose output the caller keeps: an
     inactive lane's row is zeros, whatever its ``pos`` and table say.
@@ -870,7 +908,8 @@ def paged_decode_attention_kernel(
     from jax.experimental.pallas import tpu as pltpu
 
     s_lanes, hq, _, d = q.shape
-    n_pages_arena, hkv, pt, _ = k_pages.shape
+    k_pages, v_pages = _as_arena(k_pages), _as_arena(v_pages)
+    _, _, hkv, pt, _ = k_pages.shape
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     if pt != page_tokens:
@@ -901,7 +940,8 @@ def paged_decode_attention_kernel(
     scratch_shapes = [page_buf, page_buf]
     if quantized:
         operands += [
-            _lane_scale_rows(sc, tables, pos, block_pages * pt)
+            _lane_scale_rows(_as_arena(sc, 4), tables, pos, block_pages * pt,
+                             layer)
             for sc in (k_scale, v_scale)
         ]
         in_specs += [
@@ -916,7 +956,7 @@ def paged_decode_attention_kernel(
 
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, page_tokens=page_tokens,
-        block_pages=block_pages, quantized=quantized,
+        block_pages=block_pages, quantized=quantized, layer=layer,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -980,7 +1020,7 @@ def _paged_kernel_traced(gate: str, kernel: bool, q: jax.Array,
     elif PAGED_KERNEL_INTERPRET:
         why = None
     else:
-        why = _kernel_refusal(q.shape[-1], q.shape[1], k_pages.shape[1],
+        why = _kernel_refusal(q.shape[-1], q.shape[1], k_pages.shape[-3],
                               head_multiple)
     shapes = (q.shape, k_pages.shape, (str(k_pages.dtype),))
     if why is None:
@@ -993,7 +1033,7 @@ def _paged_kernel_traced(gate: str, kernel: bool, q: jax.Array,
     return False
 
 
-def paged_attention(  # static-bounded: kernel, page_tokens, PAGED_KERNEL_INTERPRET -- kernel and the interpret flag are booleans (two programs max); page_tokens is one value per slot state (ServingConfig kv_page_tokens)
+def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL_INTERPRET -- kernel and the interpret flag are booleans (two programs max); page_tokens is one value per slot state (ServingConfig kv_page_tokens); layer is the caller's unrolled loop index, below the model's depth
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -1004,9 +1044,12 @@ def paged_attention(  # static-bounded: kernel, page_tokens, PAGED_KERNEL_INTERP
     v_scale: jax.Array | None = None,
     kernel: bool = True,
     active: jax.Array | None = None,
+    layer: int = 0,
 ) -> jax.Array:
-    """Paged decode dispatch, mirroring ``attention``'s gate: the fused
-    Pallas kernel on the TPU backend when shapes qualify (head_dim a
+    """Paged decode dispatch over the arena ``(layers, n_pages, Hkv,
+    page_tokens, D)`` at the static ``layer`` (``_as_arena``), mirroring
+    ``attention``'s gate: the fused Pallas kernel on the TPU backend when
+    shapes qualify (head_dim a
     multiple of 128: the kernel copies whole pages out of the arena itself,
     and Mosaic slices an HBM operand only in whole 128-lane tiles; GQA
     divisibility), the gather+einsum reference everywhere else.
@@ -1015,22 +1058,20 @@ def paged_attention(  # static-bounded: kernel, page_tokens, PAGED_KERNEL_INTERP
     and qualifying shapes there is no quiet way out: the kernel is traced,
     and a kernel that fails to lower or compile raises. An int8 arena
     (``k_scale`` present) is dequantized in-kernel on the fast path; the
-    reference materializes the dequantized pages first (same math, minus the
-    bandwidth win). ``active`` (``(S,)`` bool, default all true) names the
-    lanes whose rows the caller keeps: the kernel does no work for the
-    others and returns zeros there, the reference computes them like any
-    lane. The branch taken is recorded (``dispatch_tally``)."""
+    reference dequantizes the lanes' pages after it gathered them (same
+    math, minus the bandwidth win). ``active`` (``(S,)`` bool, default all
+    true) names the lanes whose rows the caller keeps: the kernel does no
+    work for the others and returns zeros there, the reference computes them
+    like any lane. The branch taken is recorded (``dispatch_tally``)."""
     if _paged_kernel_traced("paged_attention", kernel, q, k_pages,
                             head_multiple=128):
         return paged_decode_attention_kernel(
             q, k_pages, v_pages, tables, pos, k_scale, v_scale, active,
             page_tokens=page_tokens, interpret=PAGED_KERNEL_INTERPRET,
+            layer=layer,
         )
-    if k_scale is not None:
-        k_pages = dequantize_pages(k_pages, k_scale)
-        v_pages = dequantize_pages(v_pages, v_scale)
     return paged_decode_attention(q, k_pages, v_pages, tables, pos,
-                                  page_tokens)
+                                  page_tokens, layer, k_scale, v_scale)
 
 
 def _paged_verify_kernel(
@@ -1115,7 +1156,8 @@ def _paged_verify_kernel(
         ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("page_tokens", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("page_tokens", "interpret", "layer"))
 def paged_verify_attention_kernel(
     q: jax.Array,
     k_pages: jax.Array,
@@ -1127,11 +1169,13 @@ def paged_verify_attention_kernel(
     *,
     page_tokens: int,
     interpret: bool = False,
+    layer: int = 0,
 ) -> jax.Array:
     """Fused paged verify attention: same contract as
-    ``paged_verify_attention`` (q ``(S, Hq, T, D)``, arena pages, tables,
-    pos -> f32 ``(S, Hq, T, D)``) with one pass over the KV bytes. The T
-    query positions fold into the GQA group axis — blocks become
+    ``paged_verify_attention`` (q ``(S, Hq, T, D)``, the arena read at the
+    static ``layer`` through the index maps, a block's layer dimension
+    squeezed, tables, pos -> f32 ``(S, Hq, T, D)``) with one pass over the
+    KV bytes. The T query positions fold into the GQA group axis — blocks become
     ``(T*g, d)`` with row ``r`` at query offset ``r // g`` — so the grid is
     ``(lanes, kv_heads, pages_per_slot)``, tables and pos ride in as
     scalar-prefetch operands and T never becomes a grid dim.
@@ -1140,7 +1184,8 @@ def paged_verify_attention_kernel(
     from jax.experimental.pallas import tpu as pltpu
 
     s_lanes, hq, t_q, d = q.shape
-    n_pages_arena, hkv, pt, _ = k_pages.shape
+    k_pages, v_pages = _as_arena(k_pages), _as_arena(v_pages)
+    _, _, hkv, pt, _ = k_pages.shape
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     if pt != page_tokens:
@@ -1169,16 +1214,16 @@ def paged_verify_attention_kernel(
         # so the pipeline skips the re-fetch, and the trash page behind
         # unreserved entries is never touched
         jj = jnp.minimum(j, (ps[s] + t_q - 1) // page_tokens)
-        return (tbl[s, jj], h, 0, 0)
+        return (layer, tbl[s, jj], h, 0, 0)
 
     def scale_index(s, h, j, tbl, ps):
         jj = jnp.minimum(j, (ps[s] + t_q - 1) // page_tokens)
-        return (tbl[s, jj], 0, 0)
+        return (layer, tbl[s, jj], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, t_q * g, d), q_index),
-        pl.BlockSpec((1, 1, pt, d), kv_index),
-        pl.BlockSpec((1, 1, pt, d), kv_index),
+        pl.BlockSpec((None, 1, 1, pt, d), kv_index),
+        pl.BlockSpec((None, 1, 1, pt, d), kv_index),
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
@@ -1187,10 +1232,10 @@ def paged_verify_attention_kernel(
         # which a per-head (1, 1, pt) block is not; the kernel picks its
         # head's row (_head_scale_row)
         in_specs += [
-            pl.BlockSpec((1, hkv, pt), scale_index),
-            pl.BlockSpec((1, hkv, pt), scale_index),
+            pl.BlockSpec((None, 1, hkv, pt), scale_index),
+            pl.BlockSpec((None, 1, hkv, pt), scale_index),
         ]
-        operands += [k_scale, v_scale]
+        operands += [_as_arena(k_scale, 4), _as_arena(v_scale, 4)]
 
     kernel = functools.partial(
         _paged_verify_kernel, sm_scale=sm_scale, page_tokens=page_tokens,
@@ -1225,7 +1270,7 @@ def paged_verify_attention_kernel(
     )
 
 
-def paged_attention_verify(  # static-bounded: kernel, page_tokens, PAGED_KERNEL_INTERPRET -- kernel and the interpret flag are booleans (two programs max); page_tokens is one value per slot state (ServingConfig kv_page_tokens)
+def paged_attention_verify(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL_INTERPRET -- kernel and the interpret flag are booleans (two programs max); page_tokens is one value per slot state (ServingConfig kv_page_tokens); layer is the caller's unrolled loop index, below the model's depth
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -1235,19 +1280,18 @@ def paged_attention_verify(  # static-bounded: kernel, page_tokens, PAGED_KERNEL
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     kernel: bool = True,
+    layer: int = 0,
 ) -> jax.Array:
     """Multi-token-query (verify) dispatch with exactly ``paged_attention``'s
-    gate: fused Pallas kernel on the TPU backend when shapes qualify, the
-    gather+einsum reference elsewhere, ``kernel=False`` forcing the
-    reference unconditionally. Per-row acceptance downstream is traced
+    gate and arena operands: fused Pallas kernel on the TPU backend when
+    shapes qualify, the gather+einsum reference elsewhere, ``kernel=False``
+    forcing the reference unconditionally. Per-row acceptance downstream is traced
     data; only (config, spec_tokens) mints programs here."""
     if _paged_kernel_traced("paged_attention_verify", kernel, q, k_pages):
         return paged_verify_attention_kernel(
             q, k_pages, v_pages, tables, pos, k_scale, v_scale,
             page_tokens=page_tokens, interpret=PAGED_KERNEL_INTERPRET,
+            layer=layer,
         )
-    if k_scale is not None:
-        k_pages = dequantize_pages(k_pages, k_scale)
-        v_pages = dequantize_pages(v_pages, v_scale)
     return paged_verify_attention(q, k_pages, v_pages, tables, pos,
-                                  page_tokens)
+                                  page_tokens, layer, k_scale, v_scale)

@@ -40,7 +40,10 @@ def main() -> int:
     # group 1/2/4; compile + parity, and for decode at head 64 the refusal
     # by name with the gate's reference) and the kernel-vs-gather+einsum
     # timing at S in {4,16,32} full lanes and at the benchmark's steady
-    # cell, 10 of 32 lanes active (printed, not asserted).
+    # cell, 10 of 32 lanes active (printed, not asserted), the kernels on
+    # the whole 5-D arena at a layer, and one decode chunk of the Mistral
+    # cell's shape with the same live tokens at kv_arena_pages 512 and 4096
+    # (a step's time must not follow the arena's size: asserted within 10 %).
     # test_olmoe.py carries the grouped expert kernel's rows at
     # OLMoE-1B-7B's widths: the grouped product with 25 / 64 of 64 experts
     # hit by 1, 4 and 48 rows each and with 63 experts empty, and the whole
