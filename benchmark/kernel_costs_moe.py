@@ -16,6 +16,7 @@ worse than this by construction and its roofline share says by how much.
 from __future__ import annotations
 
 from kernel_costs import peaks, roofline  # noqa: F401  (one table, one rule)
+from measure import chunk_boundaries
 
 
 def grouped_experts(rows: float, experts_hit: float, hidden: int, width: int,
@@ -62,16 +63,8 @@ def traced_calls(run):
     lo, hi = run.trace_wall
     mc = run.program_config
     layers, top_k, n_exp = mc["n_layers"], mc["top_k"], mc["n_experts"]
-    calls = []
-    for s in run.steps:
-        if s["chunk"] <= 0 or not s.get("experts_hit"):
-            continue
-        end = s["t_wall"]
-        start = end - s["step_ms"] / 1e3
-        inside = min(end, hi) - max(start, lo)
-        if inside > 0:
-            calls.append((s["active"] * top_k, s["experts_hit"],
-                          inside / (end - start) * s["chunk"] * layers))
+    calls = [(s["active"] * top_k, s["experts_hit"], share * s["chunk"] * layers)
+             for s, _mid, share in chunk_boundaries(run) if s.get("experts_hit")]
     to_wall = run.before["t_wall"] - run.before["t"]
     for r in run.records:
         if r["token_t"] and lo <= r["token_t"][0] + to_wall <= hi:

@@ -9,6 +9,7 @@ harness then leaves the metric out), a number, or ``(number, samples)``.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 from typing import Any
 
@@ -133,18 +134,49 @@ def kernel_time(run):
     return (sum(v["seconds"] for v in hits), calls) if calls else None
 
 
-def live_tokens(run):
-    """(cached tokens attended to, lanes) at the traced span's middle."""
-    if not run.trace_wall:
-        return None
-    mid = sum(run.trace_wall) / 2 - (run.before["t_wall"] - run.before["t"])
-    tokens = lanes = 0
+def live_tokens(run, at: float) -> int:
+    """Cached tokens the kernel attends to at monotonic time ``at``: for
+    each request streaming then, its prompt plus the tokens the client had
+    received (they trail the engine's by less than a chunk)."""
+    tokens = 0
     for r in run.records:
         t = r["token_t"]
-        if t and t[0] <= mid and (len(t) < r["max_new"] or t[-1] >= mid):
-            tokens += r["prompt_len"] + sum(x <= mid for x in t)
-            lanes += 1
-    return (tokens, lanes) if lanes else None
+        if t and t[0] <= at and (len(t) < r["max_new"] or t[-1] >= at):
+            tokens += r["prompt_len"] + bisect.bisect_right(t, at)
+    return tokens
+
+
+def chunk_boundaries(run):
+    """The ring boundaries that ran a decode chunk and overlap the traced
+    span -> ``[(boundary, wall middle, share of it inside the span)]``
+    (``t_wall`` is a boundary's end, ``step_ms`` its length)."""
+    lo, hi = run.trace_wall
+    out = []
+    for s in run.steps:
+        if s["chunk"] <= 0:
+            continue
+        end = s["t_wall"]
+        start = end - s["step_ms"] / 1e3
+        inside = min(end, hi) - max(start, lo)
+        if inside > 0:
+            out.append((s, (start + end) / 2, inside / (end - start)))
+    return out
+
+
+def paged_decode_calls(run):
+    """The decode calls the traced span held -> ``[(live tokens, lanes,
+    calls)]``, one entry a boundary of ``chunk_boundaries``, or None where
+    nothing was traced. The tokens are the records' at the boundary's
+    middle, the lanes the ring's ``active``, the calls ``chunk x layers``
+    weighted by the boundary's share inside the span (as ``kernel_costs_moe.
+    traced_calls`` counts the expert kernel's)."""
+    if not run.trace_wall:
+        return None
+    to_mono = run.before["t"] - run.before["t_wall"]
+    layers = run.program_config["n_layers"]
+    return [(live_tokens(run, mid + to_mono), s["active"],
+             share * s["chunk"] * layers)
+            for s, mid, share in chunk_boundaries(run)]
 
 
 def samples(value: Any) -> tuple[float | None, int | None]:

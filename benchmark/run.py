@@ -276,6 +276,31 @@ def decode_predict(body: bytes):
     return np.asarray(out, np.float32)[0]
 
 
+def stop_trace(trace_dir: str) -> None:
+    """``jax.profiler.stop_trace`` without its export. The session's
+    ``stop()`` returns the capture (the XSpace ``stop_and_export`` would
+    write as ``.xplane.pb``); the export also renders a trace-viewer JSON of
+    every event that nothing here reads, which on a half-million-operation
+    span took 37 of stop_trace's 61 s (PERF.md section 6, PR 28). The
+    session is JAX's private state: where it is not as expected, the public
+    call does the same, slower."""
+    import jax
+    from jax._src import profiler as private
+
+    state = getattr(private, "_profile_state", None)
+    session = getattr(state, "profile_session", None)
+    if not hasattr(session, "stop"):
+        jax.profiler.stop_trace()
+        return
+    with state.lock:
+        capture = session.stop()
+        state.reset()
+    out = os.path.join(trace_dir, "plugins", "profile", "bench")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "bench.xplane.pb"), "wb") as f:
+        f.write(capture)
+
+
 class Driver:
     """The client thread's work. ``run`` holds what the run observed at the
     end, or ``error`` the reason there is nothing."""
@@ -294,6 +319,8 @@ class Driver:
         self.run = Run(cell=cell, config=config, program_config=mc,
                        server=server, device=stamp, seconds=float(args.seconds))
         self.run.setup_split = split
+        self.after_split: dict = {}     # a traced run's stages after the window
+        self.t_done = 0.0               # when the client had all it asks for
         self.correct = True
         self.notes: list[str] = []
         self.error: BaseException | None = None
@@ -449,7 +476,9 @@ class Driver:
             pass
         await asyncio.sleep(span)
         w1 = time.time()
-        await asyncio.to_thread(jax.profiler.stop_trace)
+        t = time.monotonic()
+        await asyncio.to_thread(stop_trace, self.trace_dir)
+        self.after_split["trace_stop_s"] = time.monotonic() - t
         self.run.trace_wall = (w0, w1)
 
     # -- the whole drive ------------------------------------------------------
@@ -488,6 +517,7 @@ class Driver:
             run.steps = await client.engine_steps()
             run.compiles_in_window = self.compiles.between(
                 run.t0, run.t0 + run.seconds)
+        self.t_done = time.monotonic()
         if self.first_digest is not None:
             bad = [r for r in run.records
                    if r["ok"] and r["digest"] != self.first_digest[r["tenant"]]]
@@ -565,8 +595,14 @@ def reduce_trace(driver: Driver) -> None:
     import trace_reduce
 
     path = trace_reduce.find_xplane(driver.trace_dir)
+    t0 = time.monotonic()
     rows = trace_reduce.load_xplane(path)
-    driver.run.trace = trace_reduce.reduce(rows, host_states(driver.run))
+    t1 = time.monotonic()
+    trace = driver.run.trace = trace_reduce.reduce(rows, host_states(driver.run))
+    driver.after_split.update(
+        trace_load_s=t1 - t0, trace_reduce_s=time.monotonic() - t1,
+        capture_bytes=os.path.getsize(path),
+        device_events=sum(k["calls"] for k in trace["kernels"].values()))
 
 
 def read_metrics(bench: dict, cell_name: str, kind: str, run,
@@ -649,6 +685,7 @@ def main(argv: list[str] | None = None) -> int:
         driver = Driver(args, cell, config, family, mc, server, rest_port, kept,
                         stamp, compiles, workdir, split)
         rc = serve_with_client(config_path, driver)
+        driver.after_split["shutdown_s"] = time.monotonic() - driver.t_done
         if driver.error is not None:
             raise BenchFailure(f"the client failed: {driver.error!r}")
         if rc != 0:
@@ -691,7 +728,9 @@ def main(argv: list[str] | None = None) -> int:
         say(f"peak device bytes: {peak}")
 
         kind = "per_layer" if args.trace else "end_to_end"
+        t_readers = time.monotonic()
         metrics = read_metrics(bench, args.workload, kind, run, rehearsal)
+        driver.after_split["readers_s"] = time.monotonic() - t_readers
         device = dict(stamp, memory_peak_bytes=peak)
         result = {"correct": bool(driver.correct and not failed),
                   "attempted": len(window), "failed": len(failed),
@@ -705,6 +744,12 @@ def main(argv: list[str] | None = None) -> int:
             say(f"longest single gaps: {json.dumps(run.trace['longest_gaps'][:5])}")
         for note in driver.notes:
             say(f"note: {note}")
+        if args.trace:
+            # what a traced run costs beyond an untraced one (README.md,
+            # "What a run costs"): the limit is on the whole process
+            driver.after_split["process_wall_s"] = time.monotonic() - T_START
+            say("after-window split: " + json.dumps(
+                {k: round(v, 3) for k, v in driver.after_split.items()}))
         print(json.dumps(result), flush=True)
         return 0
     except BenchFailure as e:

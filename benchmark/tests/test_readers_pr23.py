@@ -136,17 +136,30 @@ def test_pool_wait_mean_is_the_histograms_growth_over_the_window():
     assert reader("pool_wait_mean_ms")(make_run()) is None   # no such family
 
 
-def test_benchmark_json_gained_exactly_the_five_entries():
+LAYER = {"chunk_step_p50_ms": "model step models/generation.py",
+         "prefill_stall_p50_ms": "engine runtime/batcher.py",
+         "boundary_host_p50_ms": "engine runtime/batcher.py",
+         "protocol_self_p50_ms": "protocol protocol/rest.py local_backend.py",
+         "load_evict_p50_ms": "runtime load runtime/model_runtime.py"}
+ENTRIES = [(cell, name) for cell in sorted(NEW) for name in sorted(NEW[cell])]
+
+
+@pytest.mark.parametrize("cell, name", ENTRIES)
+def test_benchmark_json_holds_each_of_the_five_entries_once(cell, name):
+    """By name, not by place: later PRs append entries after these, and add
+    their own cells to a metric's list (OLMoE's to the three ring readers)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    by_name = {m["name"]: m for m in bench["per_layer"]}
-    tail = [m["name"] for m in bench["per_layer"][-5:]]
-    assert set(tail) == NEW["mistral7b-chat-steady"] | NEW["smollm2-tenants-churn"]
-    for cell, names in NEW.items():
-        for name in names:
-            assert by_name[name]["workloads"] == [cell]
-            assert by_name[name]["source"] == "program_span"
-    assert "pool_wait_mean_ms" not in by_name
+    found = [m for m in bench["per_layer"] if m["name"] == name]
+    assert len(found) == 1
+    entry = found[0]
+    assert cell in entry["workloads"]
+    other = next(c for c in NEW if c != cell)
+    assert other not in entry["workloads"]
+    assert (entry["layer"], entry["unit"], entry["source"]) == (
+        LAYER[name], "ms", "program_span")
+    assert entry["moves"] == ("tpot_p50_ms" if "chat" in cell else "cold_p50_s")
+    assert all(m["name"] != "pool_wait_mean_ms" for m in bench["per_layer"])
     assert os.path.exists(os.path.join(
         ROOT, "benchmark", "layer_metrics", "pool_wait_mean_ms.py"))
 

@@ -105,3 +105,71 @@ def test_every_seed_offers_the_same_work_in_another_order():
     plens = np.array([len(r.prompt) for r in a])
     assert plens.min() >= 8 and plens.max() <= 200 and abs(np.median(plens) - 48) <= 1
     assert a == traffic.compile_schedule(mix, 1, 1000, 20.0)
+
+
+ORDERED = dict(MIX, order={"base_seed": 3, "swap_ranks": 4})
+
+
+def _ranks(values):
+    return np.argsort(np.argsort(values, kind="stable"), kind="stable")
+
+
+def test_order_gives_every_seed_one_load_profile():
+    """With ``order`` the seed moves a value by fewer than ``swap_ranks``
+    ranks at any place in the arrival order: the same load, second by
+    second, from other requests."""
+    a = traffic.compile_schedule(ORDERED, 1, 1000, 20.0)
+    b = traffic.compile_schedule(ORDERED, 2**31 + 5, 1000, 20.0)
+    assert len(a) == len(b) == 400
+    for get in (lambda r: len(r.prompt), lambda r: r.max_new):
+        va, vb = np.array([get(r) for r in a]), np.array([get(r) for r in b])
+        assert sorted(va) == sorted(vb)
+        assert (va != vb).any()                        # another order ...
+        # ... of near-equal values: a quantile and its third neighbour
+        assert (np.abs(va - vb) <= np.maximum(2, 0.12 * va)).all()
+    assert (np.bincount([r.tenant for r in a]) == np.bincount([r.tenant for r in b])).all()
+    ta, tb = np.array([r.at_s for r in a]), np.array([r.at_s for r in b])
+    assert (ta != tb).any() and np.abs(ta - tb).max() < 0.5    # of 20 s
+    assert all(x.prompt != y.prompt for x, y in zip(a, b))     # token ids
+    assert a == traffic.compile_schedule(ORDERED, 1, 1000, 20.0)
+
+
+def test_order_swap_ranks_1_leaves_the_seed_the_tokens_only():
+    mix = dict(MIX, order={"base_seed": 3, "swap_ranks": 1})
+    a = traffic.compile_schedule(mix, 1, 1000, 10.0)
+    b = traffic.compile_schedule(mix, 2, 1000, 10.0)
+    assert [(r.at_s, len(r.prompt), r.max_new, r.tenant) for r in a] == \
+           [(r.at_s, len(r.prompt), r.max_new, r.tenant) for r in b]
+    assert [r.prompt for r in a] != [r.prompt for r in b]
+
+
+def test_order_base_seed_names_the_profile_and_absent_order_is_the_seeds():
+    a = traffic.compile_schedule(ORDERED, 1, 1000, 10.0)
+    b = traffic.compile_schedule(dict(MIX, order={"base_seed": 4, "swap_ranks": 4}),
+                                 1, 1000, 10.0)
+    assert sorted(r.max_new for r in a) == sorted(r.max_new for r in b)
+    ra, rb = _ranks([r.max_new for r in a]), _ranks([r.max_new for r in b])
+    assert np.abs(ra - rb).max() > 50                  # another profile
+    # without ``order`` two seeds differ as far as two base seeds do
+    c = traffic.compile_schedule(MIX, 1, 1000, 10.0)
+    d = traffic.compile_schedule(MIX, 2, 1000, 10.0)
+    assert np.abs(_ranks([r.max_new for r in c])
+                  - _ranks([r.max_new for r in d])).max() > 50
+
+
+def test_the_expert_cell_fixes_its_order_and_the_dense_cells_do_not():
+    cells = {}
+    for name in ("olmoe-chat-steady", "mistral7b-chat-steady", "smollm2-tenants-churn"):
+        with open(os.path.join(BENCH, "workloads", name + ".json")) as f:
+            cells[name] = json.load(f)
+    assert cells["olmoe-chat-steady"]["traffic"]["order"] == {"base_seed": 1, "swap_ranks": 4}
+    assert "order" not in cells["mistral7b-chat-steady"]["traffic"]
+    assert "order" not in cells["smollm2-tenants-churn"]["traffic"]
+    mix = cells["olmoe-chat-steady"]["traffic"]
+    a = traffic.compile_schedule(mix, 11, 50304, 51.0)
+    b = traffic.compile_schedule(mix, 2**31 + 12, 50304, 51.0)
+    assert len(a) == len(b) == 128
+    assert sum(len(r.prompt) for r in a) == sum(len(r.prompt) for r in b)
+    assert sum(r.max_new for r in a) == sum(r.max_new for r in b)
+    # the four longest gaps are 1.4-2.2 s: swapping them moves what follows
+    assert max(abs(x.at_s - y.at_s) for x, y in zip(a, b)) < 1.0

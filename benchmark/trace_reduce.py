@@ -22,17 +22,25 @@ The host's side comes from two places: a ``bench_wall_<ns>`` annotation the
 benchmark writes into the trace (it ties the trace's clock to the wall
 clock), and intervals on the wall clock that the caller passes in (engine
 boundaries from the ring, requests in flight from the client's records).
+
+Everything here is linear in the capture (a decode step is hundreds of small
+operations, a 4 s span half a million): a name is parsed once a distinct
+name, and idle gaps meet host states in one sweep in time order.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
+import gzip
 import os
+import sys
 from typing import Iterable
 
 Row = tuple[str, str, str, int, int]   # plane, line, name, start_ns, dur_ns
 
 DEVICE_PLANE = "/device:TPU:"
+HOST_PLANE = "/host:"             # TraceMe events: annotations live here
 OPS_LINE = "XLA Ops"
 WALL_MARK = "bench_wall_"
 # operations that only wrap others: counting them would count the children twice
@@ -48,19 +56,36 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load_xplane(path: str) -> list[Row]:
-    """Device operation rows and the benchmark's own annotations."""
+    """Device operation rows and the benchmark's own annotation: of a device
+    plane the ``XLA Ops`` line, of the host's planes nothing but the first
+    ``bench_wall_`` event (``run.py`` writes one a capture). Names are
+    interned: a capture has a few hundred distinct ones, 200 bytes each."""
     from jax.profiler import ProfileData
 
+    if path.endswith(".gz"):
+        with gzip.open(path, "rb") as f:
+            data = ProfileData.from_serialized_xspace(f.read())
+    else:
+        data = ProfileData.from_file(path)
     rows: list[Row] = []
-    for plane in ProfileData.from_file(path).planes:
-        device = plane.name.startswith(DEVICE_PLANE)
-        for line in plane.lines:
-            if device and line.name != OPS_LINE:
-                continue
-            for ev in line.events:
-                if device or ev.name.startswith(WALL_MARK):
-                    rows.append((plane.name, line.name, ev.name,
-                                 int(ev.start_ns), int(ev.duration_ns)))
+    marked = False
+    for plane in data.planes:
+        pname = plane.name
+        if pname.startswith(DEVICE_PLANE):
+            for line in plane.lines:
+                if line.name == OPS_LINE:
+                    rows += [(pname, OPS_LINE, sys.intern(ev.name),
+                              int(ev.start_ns), int(ev.duration_ns))
+                             for ev in line.events]
+        elif not marked and pname.startswith(HOST_PLANE):
+            for line in plane.lines:
+                mark = next((ev for ev in line.events
+                             if ev.name.startswith(WALL_MARK)), None)
+                if mark is not None:
+                    rows.append((pname, line.name, mark.name,
+                                 int(mark.start_ns), int(mark.duration_ns)))
+                    marked = True
+                    break
     return rows
 
 
@@ -78,9 +103,14 @@ def is_wrapper(name: str) -> bool:
 def device_ops(rows: Iterable[Row]) -> dict[str, list[tuple[str, int, int]]]:
     """{device plane: [(name, start_ns, dur_ns)]}, wrappers left out."""
     out: dict[str, list[tuple[str, int, int]]] = {}
+    wrapper: dict[str, bool] = {}
     for plane, line, name, start, dur in rows:
-        if (plane.startswith(DEVICE_PLANE) and line == OPS_LINE
-                and not is_wrapper(name)):
+        if line != OPS_LINE or not plane.startswith(DEVICE_PLANE):
+            continue
+        skip = wrapper.get(name)
+        if skip is None:
+            skip = wrapper[name] = is_wrapper(name)
+        if not skip:
             out.setdefault(plane, []).append((name, start, dur))
     for ops in out.values():
         ops.sort(key=lambda e: e[1])
@@ -134,6 +164,42 @@ def wall_offset_ns(rows: Iterable[Row]) -> int | None:
     return None
 
 
+def name_gaps(spans: list[tuple[float, float]],
+              host_states: list[tuple[str, float, float]]) -> list[str]:
+    """The label of each idle span ``(wall_start_s, wall_end_s)``; the spans
+    are in time order and do not overlap. A state names a span when it
+    covers at least half of it; of several, the earliest entry of
+    ``host_states`` wins; of none, ``"host: nothing recorded"``.
+
+    One sweep: the states wait sorted by their start, and ``live`` holds the
+    list positions, in rising order, of those that began before the span's
+    end and had not ended by the start of an earlier one. Only they can
+    overlap the span, so each is tried in list order with the arithmetic a
+    loop over all states would use."""
+    by_start = sorted(range(len(host_states)), key=lambda i: host_states[i][1])
+    live: list[int] = []
+    nxt = 0
+    out = []
+    for ws, we in spans:
+        while nxt < len(by_start) and host_states[by_start[nxt]][1] < we:
+            bisect.insort(live, by_start[nxt])
+            nxt += 1
+        label, ended = "host: nothing recorded", False
+        for i in live:
+            name, hs, he = host_states[i]
+            if he <= ws:
+                ended = True        # over before this span: before all later
+                continue
+            cover = min(we, he) - max(ws, hs)
+            if cover > 0.0 and cover >= 0.5 * (we - ws):
+                label = name
+                break
+        if ended:
+            live = [i for i in live if host_states[i][2] > ws]
+        out.append(label)
+    return out
+
+
 def reduce(rows: list[Row], host_states: list[tuple[str, float, float]] = (),
            top: int = 10) -> dict:
     """-> busy_s, window_s (averaged over the device planes), the top
@@ -151,25 +217,25 @@ def reduce(rows: list[Row], host_states: list[tuple[str, float, float]] = (),
     hi = max(max(s + d for _n, s, d in ops) for ops in per_dev.values())
     busy = [union_ns((s, s + d) for _n, s, d in ops) for ops in per_dev.values()]
     kernels: dict[str, list[float]] = {}
+    by_event_name: dict[str, list[float]] = {}
     for ops in per_dev.values():
         for name, _s, d in ops:
-            k = kernels.setdefault(kernel_name(name), [0.0, 0])
+            k = by_event_name.get(name)
+            if k is None:
+                k = by_event_name[name] = kernels.setdefault(
+                    kernel_name(name), [0.0, 0])
             k[0] += d / 1e9 / len(per_dev)
             k[1] += 1
     offset = wall_offset_ns(rows)
+    idle = gaps(next(iter(per_dev.values())), lo, hi)
+    if offset is None:
+        labels = ["host: nothing recorded"] * len(idle)
+    else:
+        labels = name_gaps([((s + offset) / 1e9, (e + offset) / 1e9)
+                            for s, e in idle], list(host_states))
     labelled: dict[str, float] = {}
     longest: list[tuple[float, str]] = []
-    first = next(iter(per_dev.values()))
-    for s, e in gaps(first, lo, hi):
-        label = "host: nothing recorded"
-        if offset is not None:
-            ws, we = (s + offset) / 1e9, (e + offset) / 1e9
-            best = 0.0
-            for name, hs, he in host_states:
-                cover = min(we, he) - max(ws, hs)
-                if cover > best and cover >= 0.5 * (we - ws):
-                    label, best = name, cover
-                    break
+    for (s, e), label in zip(idle, labels):
         labelled[label] = labelled.get(label, 0.0) + (e - s) / 1e9
         longest.append(((e - s) / 1e9, label))
     longest.sort(reverse=True)

@@ -14,6 +14,18 @@ two seeds offer the same tokens and requests and differ in who meets whom.
 Plain seeded draws offered 34.8k-41.7k prompt tokens from seed to seed at 41
 requests a window, more than any bound could absorb (PERF.md, PR 22).
 
+Who meets whom is work too where a step's time follows the lanes in flight
+(an expert model's step reads the experts its live lanes hit: 5.7 ms at one
+lane, 9.0 at five): with the order drawn whole from the seed, the same
+multiset read ``tpot_p50_ms`` 9.0-9.9 from seed to seed in
+``olmoe-chat-steady`` where one seed repeats to 0.7 % (PERF.md, PR 28). A mix
+may therefore fix its load profile with ``order``: the order of gaps, lengths
+and tenants is then drawn once from ``order.base_seed``, the same for every
+seed, and the run's seed permutes values only among ``order.swap_ranks``
+neighbouring quantiles (a few percent apart), so every seed offers the same
+load second by second and differs in which of near-equal requests stands
+where, in every token id and in the weights.
+
 A mix (the ``traffic`` object of ``benchmark/workloads/<cell>.json``):
 
     verb            "generate" | "predict"
@@ -32,6 +44,9 @@ A mix (the ``traffic`` object of ``benchmark/workloads/<cell>.json``):
                     starts every prompt of that tenant (0 = none)
     prompt_per_tenant      true = one prompt per tenant, drawn once (every
                     answer of a tenant can then be compared byte for byte)
+    order           absent = the seed draws the whole order | {"base_seed",
+                    "swap_ranks"}: one order for every seed, the seed swaps
+                    only values within ``swap_ranks`` neighbouring quantiles
 
 ``burst``, ``turns`` and ``shared_prefix_tokens`` are used by no cell yet:
 they are what the cells PERF.md keeps for later (`mistral7b-chat-bursty`,
@@ -76,35 +91,60 @@ def midpoints(n: int) -> np.ndarray:
     return (np.arange(n) + 0.5) / max(n, 1)
 
 
-def spread_choice(weights: np.ndarray, n: int,
-                  rng: np.random.Generator) -> np.ndarray:
+class Shuffler:
+    """Puts a distribution's quantiles (given in rank order) into arrival
+    order. Without ``order`` the seed's ``rng`` draws the whole permutation.
+    With ``order`` (see the module docstring) a generator made from
+    ``base_seed`` alone draws it, and ``rng`` only permutes the ranks inside
+    each run of ``swap_ranks`` neighbours."""
+
+    def __init__(self, rng: np.random.Generator, order: dict | None,
+                 n_tenants: int) -> None:
+        self.rng = rng
+        self.base = self.swap = None
+        if order is not None:
+            self.base = np.random.default_rng(
+                [int(order["base_seed"]), n_tenants, 0x7E])
+            self.swap = max(1, int(order.get("swap_ranks", 1)))
+
+    def __call__(self, values: np.ndarray) -> np.ndarray:
+        if self.base is None:
+            return self.rng.permutation(values)
+        ranks = np.arange(len(values))
+        for lo in range(0, len(values), self.swap):
+            ranks[lo:lo + self.swap] = self.rng.permutation(
+                ranks[lo:lo + self.swap])
+        return np.asarray(values)[ranks[self.base.permutation(len(values))]]
+
+
+def spread_choice(weights: np.ndarray, n: int, shuffle) -> np.ndarray:
     """``n`` indices whose counts are ``weights * n`` to the nearest whole
-    request, in an order ``rng`` draws."""
+    request, in an order ``shuffle`` draws (a ``Shuffler``, or a
+    generator's ``permutation``)."""
     edges = np.cumsum(weights / weights.sum())
-    return rng.permutation(np.searchsorted(edges, midpoints(n), side="right")
-                           .clip(0, len(weights) - 1))
+    return shuffle(np.searchsorted(edges, midpoints(n), side="right")
+                   .clip(0, len(weights) - 1))
 
 
-def draw_lengths(spec: dict[str, Any], n: int,
-                 rng: np.random.Generator) -> np.ndarray:
+def draw_lengths(spec: dict[str, Any], n: int, shuffle) -> np.ndarray:
     """``n`` lengths from a length spec (see the module docstring): the
-    distribution's own quantiles, in an order ``rng`` draws."""
+    distribution's own quantiles, in an order ``shuffle`` draws."""
     if "lognormal" in spec:
         p = spec["lognormal"]
         mu, sigma = np.log(float(p["median"])), float(p["sigma"])
         z = np.asarray([NormalDist().inv_cdf(u) for u in midpoints(n)])
-        raw = rng.permutation(np.exp(mu + sigma * z))
+        raw = shuffle(np.exp(mu + sigma * z))
         return np.clip(np.rint(raw), int(p["min"]), int(p["max"])).astype(int)
     if "choice" in spec:
         p = spec["choice"]
         lens = np.asarray(p["lens"], int)
         w = np.asarray(p.get("weights") or np.ones(len(lens)), np.float64)
-        return lens[spread_choice(w, n, rng)]
+        return lens[spread_choice(w, n, shuffle)]
     raise ValueError(f"length spec needs 'lognormal' or 'choice': {spec}")
 
 
 def _conv_starts(mix: dict[str, Any], horizon_s: float,
-                 rng: np.random.Generator) -> np.ndarray:
+                 shuffle) -> np.ndarray:
     """Arrival offsets of the conversations' first turns, up to the horizon."""
     arrival = mix.get("arrival", "poisson")
     rate = float(mix["rate_rps"])
@@ -117,7 +157,7 @@ def _conv_starts(mix: dict[str, Any], horizon_s: float,
         return (np.arange(n) // size) * gap
     # the exponential distribution's own n gaps, shuffled, and stretched so
     # that the last request is due just inside the horizon
-    gaps = rng.permutation(-np.log1p(-midpoints(n)) * horizon_s / n)
+    gaps = shuffle(-np.log1p(-midpoints(n)) * horizon_s / n)
     starts = np.cumsum(gaps) - gaps[:1] / 2
     return starts * min(1.0, horizon_s * (n - 0.5) / n / max(starts[-1], 1e-9))
 
@@ -129,14 +169,15 @@ def compile_schedule(mix: dict[str, Any], seed: int, vocab: int,
     n_tenants = int(mix.get("tenants", 1))
     rng = np.random.default_rng([int(seed), n_tenants, 0x7C])
     vocab = max(2, int(vocab))
-    starts = _conv_starts(mix, horizon_s, rng)
+    shuffle = Shuffler(rng, mix.get("order"), n_tenants)
+    starts = _conv_starts(mix, horizon_s, shuffle)
     n_conv = len(starts)
     weights = tenant_weights(n_tenants, float(mix.get("zipf_s", 0.0)))
-    tenants = spread_choice(weights, n_conv, rng)
+    tenants = spread_choice(weights, n_conv, shuffle)
     turns = max(1, int(mix.get("turns", 1)))
-    plens = draw_lengths(mix["prompt"], n_conv, rng)
+    plens = draw_lengths(mix["prompt"], n_conv, shuffle)
     verb = mix.get("verb", "generate")
-    olens = (draw_lengths(mix["output"], n_conv * turns, rng)
+    olens = (draw_lengths(mix["output"], n_conv * turns, shuffle)
              if verb == "generate" else np.zeros(n_conv * turns, int))
     shared = int(mix.get("shared_prefix_tokens", 0))
     per_tenant = bool(mix.get("prompt_per_tenant", False))
@@ -146,7 +187,7 @@ def compile_schedule(mix: dict[str, Any], seed: int, vocab: int,
     system = [tuple(int(t) for t in trng.integers(1, vocab, shared))
               for _ in range(n_tenants)]
     fixed = [tuple(int(t) for t in trng.integers(
-        1, vocab, int(draw_lengths(mix["prompt"], 1, trng)[0])))
+        1, vocab, int(draw_lengths(mix["prompt"], 1, trng.permutation)[0])))
         for _ in range(n_tenants)] if per_tenant else None
 
     out: list[Request] = []
