@@ -188,7 +188,7 @@ def test_one_module_places_the_compile_cache():
             setters += [os.path.join(dirpath, f) for f in files
                         if f.endswith(".py")]
     setters += [os.path.join(REPO, f)
-                for f in ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+                for f in ("chip_smoke.py", "__graft_entry__.py")]
     hits = [p for p in setters
             if re.search(r"""update\(\s*["']jax_compilation_cache_dir""",
                          open(p).read())]

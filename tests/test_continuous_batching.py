@@ -1,7 +1,9 @@
-"""Iteration-level continuous batching (`serving.generate_engine=continuous`):
-greedy parity with the solo decoder, deterministic-EOS waste accounting vs the
-coalescer, host dispatch overhead budget, and the Poisson admission soak."""
+"""Iteration-level continuous batching, the one `:generate` engine: greedy
+parity with the solo decoder, deterministic-EOS waste accounting, what a
+backend builds from defaults, host dispatch overhead budget, and the Poisson
+admission soak."""
 
+import json
 import time
 
 import numpy as np
@@ -9,10 +11,7 @@ import pytest
 
 from tfservingcache_tpu.config import ServingConfig
 from tfservingcache_tpu.models.registry import export_artifact
-from tfservingcache_tpu.runtime.batcher import (
-    ContinuousGenerateEngine,
-    GenerateCoalescer,
-)
+from tfservingcache_tpu.runtime.batcher import ContinuousGenerateEngine
 from tfservingcache_tpu.runtime.model_runtime import TPUModelRuntime
 from tfservingcache_tpu.types import Model, ModelId
 from tfservingcache_tpu.utils.metrics import Metrics
@@ -61,78 +60,49 @@ def test_greedy_parity_with_solo_decoder(tmp_path):
         rt.close()
 
 
-def test_deterministic_eos_waste_continuous_vs_coalesce(tmp_path):
-    """The metric the engine exists to improve: with a model whose greedy
-    rollout deterministically hits EOS early, chunk=1 continuous decode
-    records ZERO wasted steps (retirement at the exact step), while the
-    coalescer — which runs every row to the batch's bucketed max_new —
-    records the full post-EOS tail as waste."""
-    # probe the (deterministic) greedy rollout without EOS to pick an eos_id
-    # that provably appears early
+def _eos_probe(tmp_path):
+    """The (deterministic) greedy rollout of the toy model without an EOS, and
+    a token of it to declare as EOS: the first one at index >= 2 that did NOT
+    occur earlier in the rollout, so the engine's first EOS hit is at the
+    index returned (the toy repeats tokens: taking the third token blindly
+    picked one the rollout had already emitted at index 1)."""
     probe_rt, probe_mid = _load(tmp_path / "probe")
     try:
         prompt = np.array([[5, 17, 40]], np.int32)
-        roll = probe_rt.generate(probe_mid, prompt, max_new_tokens=8, seed=0)
+        roll = probe_rt.generate(probe_mid, prompt, max_new_tokens=8, seed=0)[0]
     finally:
         probe_rt.close()
-    eos = int(roll[0, 2])  # third emitted token becomes EOS -> useful=3
+    at = next(i for i in range(2, len(roll)) if roll[i] not in roll[:i])
+    return prompt, roll, int(roll[at]), at
 
+
+@pytest.mark.parametrize("chunk", [1, 4])
+def test_deterministic_eos_waste_bounded_by_chunk(tmp_path, chunk):
+    """The metric the engine exists to bound: with a model whose greedy
+    rollout deterministically hits EOS early, the row retires AT the EOS
+    step and is zero-padded after it; chunk=1 records ZERO wasted steps,
+    chunk=k at most k-1 (here exactly the rest of the chunk the EOS fell
+    into) — waste is bounded per retirement, not per batch drain."""
+    prompt, roll, eos, at = _eos_probe(tmp_path)
     metrics = Metrics()
     rt, mid = _load(
         tmp_path / "eos", config={**TINY, "eos_id": eos}, metrics=metrics
     )
     assert rt.eos_id_of(mid) == eos
-    wasted_cont = metrics.gen_wasted_steps.labels("continuous")
-    wasted_coal = metrics.gen_wasted_steps.labels("coalesce")
-    try:
-        eng = ContinuousGenerateEngine(rt, slots=2, chunk_tokens=1, metrics=metrics)
-        try:
-            out = eng.generate(mid, prompt, max_new_tokens=16)
-        finally:
-            eng.close()
-        # stopped AT the eos step: tokens after it stay zero-padded
-        assert int(out[0, 2]) == eos
-        assert (out[0, 3:] == 0).all()
-        assert wasted_cont._value.get() == 0
-
-        coal = GenerateCoalescer(rt, metrics=metrics)
-        out2 = coal.generate(mid, prompt, max_new_tokens=16)
-        assert out2.shape == (1, 16)
-        # bucketed batch ran all 16 steps; only 3 were useful
-        assert wasted_coal._value.get() == 16 - 3
-        # coalesce admission wait (HOL stall surface) observed for the row
-        count = [
-            s.value
-            for fam in metrics.gen_admission_wait.collect()
-            for s in fam.samples
-            if s.name.endswith("_count") and s.labels.get("engine") == "coalesce"
-        ]
-        assert count and count[0] >= 1
-    finally:
-        rt.close()
-
-
-def test_chunked_retirement_overshoot_bounded_by_chunk(tmp_path):
-    """With chunk>1 a row finishing mid-chunk wastes at most chunk-1 steps —
-    the whole point of iteration-level scheduling is that waste is bounded
-    per retirement, not per batch drain."""
-    probe_rt, probe_mid = _load(tmp_path / "probe")
-    try:
-        prompt = np.array([[5, 17, 40]], np.int32)
-        roll = probe_rt.generate(probe_mid, prompt, max_new_tokens=8, seed=0)
-    finally:
-        probe_rt.close()
-    eos = int(roll[0, 2])
-
-    metrics = Metrics()
-    rt, mid = _load(tmp_path / "eos", config={**TINY, "eos_id": eos}, metrics=metrics)
-    chunk = 4
-    eng = ContinuousGenerateEngine(rt, slots=2, chunk_tokens=chunk, metrics=metrics)
+    eng = ContinuousGenerateEngine(
+        rt, slots=2, chunk_tokens=chunk, metrics=metrics
+    )
     try:
         out = eng.generate(mid, prompt, max_new_tokens=16)
-        assert int(out[0, 2]) == eos
+        # stopped AT the eos step: the rollout up to it, zero-padded after
+        assert (out[0, : at + 1] == roll[: at + 1]).all()
+        assert int(out[0, at]) == eos
+        assert (out[0, at + 1:] == 0).all()
         wasted = metrics.gen_wasted_steps.labels("continuous")._value.get()
-        assert 0 <= wasted < chunk
+        assert 0 <= wasted <= chunk - 1
+        # token 0 is the prefill's; decode token d = at - 1 fell at place
+        # d % chunk of its chunk, whose remaining steps are the waste
+        assert wasted == chunk - 1 - (at - 1) % chunk
     finally:
         eng.close()
         rt.close()
@@ -153,7 +123,7 @@ def test_solo_fallbacks_and_close(tmp_path):
         with pytest.raises(RuntimeError_):
             eng.generate(mid, ids, max_new_tokens=4, temperature=-1.0)
         # prompt + budget beyond max_seq is rejected, not wedged
-        with pytest.raises(RuntimeError_):
+        with pytest.raises(ValueError, match="max_seq"):
             eng.generate(mid, np.ones((1, 60), np.int32), max_new_tokens=10)
     finally:
         eng.close()
@@ -258,7 +228,7 @@ def test_spec_draft_eviction_detaches_and_decodes_plain(spec_stack):
         rt.drop_slot_state(mid)
 
 
-def test_backend_selects_continuous_engine(tmp_path):
+def _backend(tmp_path, cfg=None, **kw):
     from tfservingcache_tpu.cache.disk_cache import ModelDiskCache
     from tfservingcache_tpu.cache.manager import CacheManager
     from tfservingcache_tpu.cache.providers.disk import DiskModelProvider
@@ -266,28 +236,167 @@ def test_backend_selects_continuous_engine(tmp_path):
 
     store = tmp_path / "store"
     export_artifact("transformer_lm", str(store), name="lm", version=1, config=TINY)
+    cfg = cfg or ServingConfig(platform="cpu")
     mgr = CacheManager(
         DiskModelProvider(str(store)),
         ModelDiskCache(str(tmp_path / "cache"), capacity_bytes=1 << 30),
-        TPUModelRuntime(ServingConfig(platform="cpu")),
+        TPUModelRuntime(cfg),
     )
-    backend = LocalServingBackend(mgr, generate_engine="continuous")
+    return LocalServingBackend(mgr, **kw), mgr
+
+
+def _threads(prefix="tpusc-cdecode"):
+    import threading
+
+    return [t for t in threading.enumerate() if t.name.startswith(prefix)]
+
+
+async def test_backend_builds_the_engine_and_nothing_else_until_generate(tmp_path):
+    """Every backend has the engine, and having it costs nothing: a server
+    that only answers :predict starts no scheduler thread and allocates no
+    arena; both appear at a model's first :generate."""
+    backend, mgr = _backend(tmp_path)
+    before = len(_threads())
     try:
         assert isinstance(backend._generator, ContinuousGenerateEngine)
+        resp = await backend.handle_rest(
+            "POST", "lm", None, "predict",
+            json.dumps({"inputs": {"input_ids": [[3, 5, 7, 9]]}}).encode(),
+        )
+        assert resp.status == 200, resp.body
+        assert backend._generator._scheds == {}
+        assert mgr.runtime._slot_states == {}
+        assert len(_threads()) == before
+        resp = await backend.handle_rest(
+            "POST", "lm", None, "generate",
+            json.dumps({"input_ids": [[3, 5, 7, 9]], "max_new_tokens": 4}).encode(),
+        )
+        assert resp.status == 200, resp.body
+        assert len(backend._generator._scheds) == 1
+        assert len(_threads()) == before + 1
     finally:
         backend.close()
         mgr.close()
     assert backend._generator._closed
 
 
-class _StubState:
-    def __init__(self, slots, max_seq=4096):
-        self.max_seq = max_seq
-        self.tok = np.zeros(slots, np.int32)
-        self.pos = np.zeros(slots, np.int32)
-        self.active = np.zeros(slots, bool)
-        self.temps = np.zeros(slots, np.float32)
-        self.topks = np.zeros(slots, np.int32)
+async def test_default_config_serves_generate_through_the_paged_engine(tmp_path):
+    """A deployment that sets nothing runs the path every chip number was
+    taken on: Config() defaults build the engine over 16-token pages, and an
+    unseeded :generate goes through it and returns the solo decoder's greedy
+    tokens."""
+    from tfservingcache_tpu.config import Config
+
+    serving = Config().serving
+    serving.platform = "cpu"
+    backend, mgr = _backend(
+        tmp_path, cfg=serving,
+        generate_slots=serving.generate_slots,
+        generate_chunk_tokens=serving.generate_chunk_tokens,
+        kv_page_tokens=serving.kv_page_tokens,
+        kv_arena_pages=serving.kv_arena_pages,
+    )
+    mid = ModelId("lm", 1)
+    prompt = [[3, 5, 7, 9, 11]]
+    try:
+        resp = await backend.handle_rest(
+            "POST", "lm", None, "generate",
+            json.dumps({"input_ids": prompt, "max_new_tokens": 6}).encode(),
+        )
+        assert resp.status == 200, resp.body
+        eng = backend._generator
+        assert eng.admitted == 1 and eng.chunks >= 1
+        st = mgr.runtime._slot_states[mid]
+        assert st.page_tokens == 16 == serving.kv_page_tokens
+        assert st.slots == serving.generate_slots == 8
+        # auto-sized arena: every lane can hold the longest request
+        assert st.arena_pages == 8 * (TINY["max_seq"] // 16)
+        assert st.k.shape[1] == st.arena_pages + 1 and st.k.shape[3] == 16
+        want = mgr.runtime.generate(
+            mid, np.asarray(prompt, np.int32), max_new_tokens=6, seed=0
+        )
+        assert json.loads(resp.body)["tokens"] == want.tolist()
+    finally:
+        backend.close()
+        mgr.close()
+
+
+def test_ignored_generate_engine_key_loads_warns_once_and_serves(tmp_path, caplog):
+    """The benchmark's chat configurations still carry
+    ``serving.generate_engine: continuous``: the loader ignores the key with
+    ONE warning that names it, and the server built from that file serves
+    :generate through the engine."""
+    import logging
+
+    import yaml
+
+    from tfservingcache_tpu.config import load_config
+
+    path = tmp_path / "server.yaml"
+    path.write_text(yaml.safe_dump({"serving": {
+        "platform": "cpu", "generate_engine": "continuous",
+        "generate_slots": 4, "kv_page_tokens": 8, "kv_arena_pages": 32,
+    }}))
+    with caplog.at_level(logging.WARNING, logger="tpusc.config"):
+        cfg = load_config(str(path))
+    warned = [r for r in caplog.records if "generate_engine" in r.getMessage()]
+    assert len(warned) == 1, [r.getMessage() for r in caplog.records]
+    assert "ignoring unknown config key" in warned[0].getMessage()
+    assert not hasattr(cfg.serving, "generate_engine")
+    assert cfg.serving.kv_page_tokens == 8
+    backend, mgr = _backend(
+        tmp_path, cfg=cfg.serving,
+        generate_slots=cfg.serving.generate_slots,
+        kv_page_tokens=cfg.serving.kv_page_tokens,
+        kv_arena_pages=cfg.serving.kv_arena_pages,
+    )
+    try:
+        mid = ModelId("lm", 1)
+        mgr.ensure_servable(mid)
+        out = backend._generator.generate(
+            mid, np.array([[3, 5, 7]], np.int32), max_new_tokens=4
+        )
+        assert out.shape == (1, 4)
+        st = mgr.runtime._slot_states[mid]
+        assert (st.page_tokens, st.arena_pages, st.slots) == (8, 32, 4)
+    finally:
+        backend.close()
+        mgr.close()
+
+
+@pytest.mark.parametrize("bad", [0, -16])
+def test_kv_page_tokens_below_one_is_refused_by_name(tmp_path, bad):
+    """There is no other KV layout to fall back to: a page size < 1 is a
+    configuration error, raised where the engine is built, naming the
+    option."""
+    with pytest.raises(ValueError, match="serving.kv_page_tokens"):
+        _backend(tmp_path, kv_page_tokens=bad)
+    # the runtime's own knob (an engine that defers to it) is held to the same
+    rt, mid = _load(tmp_path / "rt", kv_page_tokens=bad)
+    try:
+        with pytest.raises(ValueError, match="serving.kv_page_tokens"):
+            rt.slot_decode_state(mid, 2)
+    finally:
+        rt.close()
+
+
+def _stub_state(slots, max_seq=4096, page_tokens=16):
+    """A real SlotDecodeState with no device arrays: the scheduler reserves,
+    counts and recycles pages on every admission, so the stub keeps the
+    arena's host bookkeeping and nothing else."""
+    from tfservingcache_tpu.runtime.model_runtime import SlotDecodeState
+
+    pps = max_seq // page_tokens
+    return SlotDecodeState(
+        model_id=ModelId("stub", 1), cfg_key=(), family="stub", slots=slots,
+        max_seq=max_seq, k=None, v=None,
+        tok=np.zeros(slots, np.int32), pos=np.zeros(slots, np.int32),
+        active=np.zeros(slots, bool), temps=np.zeros(slots, np.float32),
+        topks=np.zeros(slots, np.int32),
+        page_tokens=page_tokens, arena_pages=slots * pps, pages_per_slot=pps,
+        block_tables=np.zeros((slots, pps), np.int32),
+        free_pages=list(range(1, slots * pps + 1)),
+    )
 
 
 class _StubRuntime:
@@ -297,7 +406,7 @@ class _StubRuntime:
     mesh = None
 
     def __init__(self, slots):
-        self._state = _StubState(slots)
+        self._state = _stub_state(slots)
 
     def engine_ready_of(self, _m):
         return True
@@ -313,9 +422,6 @@ class _StubRuntime:
 
     def slot_prefill(self, _m, prompt, temperature, top_k, seed):
         return 1, None, None, False
-
-    def slot_admit(self, state, idx, pk, pv):
-        pass
 
     def slot_decode_chunk(self, state, chunk):
         state.pos = state.pos + state.active.astype(np.int32) * chunk
@@ -347,8 +453,7 @@ def test_host_dispatch_overhead_under_1ms_per_chunk():
 def test_poisson_admission_soak(tmp_path):
     """Sustained 2x slot oversubscription under Poisson arrivals: every
     request completes, TTFT stays bounded, and the admission-wait histogram
-    fills — the long-haul version of the bench's continuous_batching
-    section."""
+    fills."""
     import threading
 
     metrics = Metrics()

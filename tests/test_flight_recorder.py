@@ -32,6 +32,7 @@ from tfservingcache_tpu.utils.flight_recorder import (
 )
 from tfservingcache_tpu.utils.metrics import Metrics
 from tfservingcache_tpu.utils.tracing import TRACER
+from tests.test_continuous_batching import _StubRuntime
 
 TINY = {
     "vocab_size": 97,
@@ -128,48 +129,6 @@ def test_record_overhead_under_50us():
     assert statistics.median(per_rec) < 50e-6, per_rec
 
 
-class _StubState:
-    def __init__(self, slots, max_seq=4096):
-        self.max_seq = max_seq
-        self.tok = np.zeros(slots, np.int32)
-        self.pos = np.zeros(slots, np.int32)
-        self.active = np.zeros(slots, bool)
-        self.temps = np.zeros(slots, np.float32)
-        self.topks = np.zeros(slots, np.int32)
-
-
-class _StubRuntime:
-    """Zero-cost model surface (test_continuous_batching.py): engine time
-    IS host scheduling + recording overhead."""
-
-    mesh = None
-
-    def __init__(self, slots):
-        self._state = _StubState(slots)
-
-    def engine_ready_of(self, _m):
-        return True
-
-    def eos_id_of(self, _m):
-        return None
-
-    def slot_decode_state(self, _m, _slots):
-        return self._state
-
-    def drop_slot_state(self, _m):
-        pass
-
-    def slot_prefill(self, _m, prompt, temperature, top_k, seed):
-        return 1, None, None, False
-
-    def slot_admit(self, state, idx, pk, pv):
-        pass
-
-    def slot_decode_chunk(self, state, chunk):
-        state.pos = state.pos + state.active.astype(np.int32) * chunk
-        return np.ones((state.tok.shape[0], chunk), np.int32)
-
-
 def test_stub_engine_records_every_chunk_within_budget():
     """With the ring enabled by default (no opt-in anywhere), the stub
     engine must both populate the per-model ring AND hold the existing
@@ -211,7 +170,7 @@ async def test_monitoring_engine_two_model_workload(tmp_path):
         ModelDiskCache(str(tmp_path / "cache"), capacity_bytes=1 << 30),
         runtime, metrics,
     )
-    backend = LocalServingBackend(manager, generate_engine="continuous")
+    backend = LocalServingBackend(manager)
     rest = RestServingServer(backend, metrics, require_version=False)
     rport = await rest.start(0, host="127.0.0.1")
     try:

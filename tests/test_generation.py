@@ -248,75 +248,104 @@ def _lm_stack(tmp_path, **serving_kw):
     return mgr, rt
 
 
-def test_generate_coalescer_merges_concurrent(tmp_path):
-    """Concurrent unseeded same-bucket :generate requests coalesce into ONE
-    device program; ragged prompts keep per-row lengths; greedy output
-    matches each request's solo run exactly."""
+def _engine_threads(eng, mid, reqs):
+    """Run every (ids, prompt_lengths, max_new) of ``reqs`` through
+    ``eng.generate`` on its own thread; -> results in order."""
     import threading
 
-    from tfservingcache_tpu.runtime.batcher import GenerateCoalescer
+    got: list = [None] * len(reqs)
+    errors: list = []
+
+    def call(i):
+        ids, pl, new = reqs[i]
+        try:
+            got[i] = eng.generate(mid, ids, prompt_lengths=pl, max_new_tokens=new)
+        except BaseException as e:  # noqa: BLE001
+            errors.append((i, e))
+
+    ts = [threading.Thread(target=call, args=(i,)) for i in range(len(reqs))]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in ts)
+    assert not errors, errors
+    return got
+
+
+def test_engine_shares_decode_steps_between_concurrent_requests(tmp_path):
+    """Concurrent unseeded :generate requests ride the SAME decode steps
+    (one admission boundary, every chunk computed for all three lanes);
+    ragged prompts keep per-row lengths; greedy output matches each
+    request's solo run exactly."""
+    from tfservingcache_tpu.lab import faults as lab_faults
+    from tfservingcache_tpu.runtime.batcher import ContinuousGenerateEngine
     from tfservingcache_tpu.types import ModelId
+    from tfservingcache_tpu.utils.flight_recorder import RECORDER
 
     mgr, rt = _lm_stack(tmp_path)
+    mid = ModelId("lm", 1)
+    mgr.ensure_servable(mid)
+    eng = ContinuousGenerateEngine(rt, slots=4, chunk_tokens=2)
     try:
-        mid = ModelId("lm", 1)
-        mgr.ensure_servable(mid)
-        gc = GenerateCoalescer(rt, max_inflight=1)
-        prompts = [
-            (np.array([[1, 2, 3, 0]], np.int32), [3]),   # ragged: true len 3
-            (np.array([[4, 5, 6, 7]], np.int32), None),
-            (np.array([[9, 9, 2, 1]], np.int32), None),
+        reqs = [
+            (np.array([[1, 2, 3, 0]], np.int32), [3], 4),   # ragged: true len 3
+            (np.array([[4, 5, 6, 7]], np.int32), None, 4),
+            (np.array([[9, 9, 2, 1]], np.int32), None, 4),
         ]
         solo = [
-            rt.generate(mid, ids, prompt_lengths=pl, max_new_tokens=4)
-            for ids, pl in prompts
+            rt.generate(mid, ids, prompt_lengths=pl, max_new_tokens=new)
+            for ids, pl, new in reqs
         ]
-        key = (mid, 4, 4, 0.0, 0)
-        gate = gc._gate(key)
-        results: list = [None] * 3
-        errors: list = []
-
-        def call(i):
-            ids, pl = prompts[i]
-            try:
-                results[i] = gc.generate(mid, ids, prompt_lengths=pl, max_new_tokens=4)
-            except BaseException as e:  # noqa: BLE001
-                errors.append(e)
-
-        with gate:  # simulate a busy device: all three join one batch
-            ts = [threading.Thread(target=call, args=(i,)) for i in range(3)]
-            for t in ts:
-                t.start()
-            import time
-
-            time.sleep(0.5)
-        for t in ts:
-            t.join()
-        assert not errors, errors
-        assert gc.batches == 1 and gc.batched_requests == 3
-        for got, want in zip(results, solo):
-            np.testing.assert_array_equal(got, want)  # greedy = deterministic
+        # a busy device: the scheduler sleeps through its first boundary, so
+        # all three are pending when it admits
+        lab_faults.arm([lab_faults.FaultSpec(
+            kind="freeze_scheduler", count=1, duration_s=1.0,
+        )])
+        RECORDER.clear()
+        got = _engine_threads(eng, mid, reqs)
+        for g, w in zip(got, solo):
+            np.testing.assert_array_equal(g, w)  # greedy = deterministic
+        assert eng.admitted == 3 and eng.peak_active == 3
+        steps = RECORDER.snapshot(tail=64)["models"][str(mid)]["steps"]
+        assert [s["admitted"] for s in steps if s["admitted"]] == [3]
+        chunks = [s for s in steps if s["chunk"] > 0]
+        assert chunks and all(s["active"] == 3 for s in chunks)
+        assert eng.chunks == len(chunks) < 3 * len(chunks)
     finally:
+        lab_faults.disarm()
+        eng.close()
         mgr.close()
 
 
-def test_generate_coalescer_seeded_runs_solo(tmp_path):
+def test_engine_seeded_runs_solo(tmp_path):
     """An explicit seed promises a reproducible solo sample stream — it must
-    bypass coalescing even under concurrent load."""
-    from tfservingcache_tpu.runtime.batcher import GenerateCoalescer
+    bypass the engine's shared steps, and differ by seed."""
+    from tfservingcache_tpu.runtime.batcher import ContinuousGenerateEngine
     from tfservingcache_tpu.types import ModelId
 
     mgr, rt = _lm_stack(tmp_path)
+    mid = ModelId("lm", 1)
+    mgr.ensure_servable(mid)
+    eng = ContinuousGenerateEngine(rt)
     try:
-        mid = ModelId("lm", 1)
-        mgr.ensure_servable(mid)
-        gc = GenerateCoalescer(rt)
         ids = np.array([[1, 2, 3]], np.int32)
-        a = gc.generate(mid, ids, max_new_tokens=4, temperature=0.9, seed=7)
-        b = gc.generate(mid, ids, max_new_tokens=4, temperature=0.9, seed=7)
+        a = eng.generate(mid, ids, max_new_tokens=8, temperature=0.9, seed=7)
+        b = eng.generate(mid, ids, max_new_tokens=8, temperature=0.9, seed=7)
         np.testing.assert_array_equal(a, b)
-        assert gc.batches == 0  # never entered the batching path
+        np.testing.assert_array_equal(
+            a, rt.generate(mid, ids, max_new_tokens=8, temperature=0.9, seed=7)
+        )
+        others = [
+            eng.generate(mid, ids, max_new_tokens=8, temperature=0.9, seed=s)
+            for s in (8, 9, 10)
+        ]
+        assert any((o != a).any() for o in others)
+        # never entered the engine: no scheduler, no arena, no admission
+        assert eng.admitted == 0 and eng._scheds == {}
+        assert mid not in rt._slot_states
     finally:
+        eng.close()
         mgr.close()
 
 
@@ -338,7 +367,10 @@ async def test_rest_generate_deadline_504(tmp_path, monkeypatch):
     monkeypatch.setattr(rt, "generate", slow_generate)
     backend = LocalServingBackend(mgr, batch_window_ms=0.0)
     try:
-        body = json.dumps({"input_ids": [[1, 2, 3]], "max_new_tokens": 2}).encode()
+        # seeded: the one kind of request that reaches runtime.generate
+        body = json.dumps(
+            {"input_ids": [[1, 2, 3]], "max_new_tokens": 2, "seed": 1}
+        ).encode()
         with pytest.raises(BackendError) as ei:
             await backend.handle_rest("POST", "lm", 1, "generate", body)
         assert ei.value.http_status == 504
@@ -347,54 +379,68 @@ async def test_rest_generate_deadline_504(tmp_path, monkeypatch):
         mgr.close()
 
 
-def test_generate_coalescer_concurrent_stress(tmp_path):
-    """Unsynchronized concurrent load: 24 requests from 8 threads with mixed
-    buckets/sampling keys all complete, greedy results match solo runs, and
-    at least one batch actually coalesced."""
+def test_engine_concurrent_stress(tmp_path):
+    """Unsynchronized concurrent load: 32 requests from 16 threads (more
+    than lanes, more than cores) with ragged prompts and mixed budgets all
+    complete, greedy results match solo runs, rows did share steps, and the
+    arena drains."""
+    import sys
     import threading
 
-    from tfservingcache_tpu.runtime.batcher import GenerateCoalescer
+    from tfservingcache_tpu.runtime.batcher import ContinuousGenerateEngine
     from tfservingcache_tpu.types import ModelId
 
     mgr, rt = _lm_stack(tmp_path)
+    mid = ModelId("lm", 1)
+    mgr.ensure_servable(mid)
+    eng = ContinuousGenerateEngine(rt, slots=4, chunk_tokens=2)
+    old_switch = sys.getswitchinterval()
     try:
-        mid = ModelId("lm", 1)
-        mgr.ensure_servable(mid)
-        # max_inflight=1: with pipelining slots free, 24 requests can
-        # drain without ever stacking enough to coalesce — flaky >=1
-        gc = GenerateCoalescer(rt, max_inflight=1)
         rng = np.random.default_rng(0)
         reqs = []
-        for i in range(24):
-            s = int(rng.integers(2, 5))            # buckets 2/4
-            ids = rng.integers(1, 97, (1, s)).astype(np.int32)
-            new = int(rng.choice([3, 4]))          # one new-token bucket
-            reqs.append((ids, new))
-        want = [rt.generate(mid, ids, max_new_tokens=new) for ids, new in reqs]
+        for _ in range(32):
+            width = int(rng.integers(2, 7))
+            L = int(rng.integers(1, width + 1))     # ragged: true len <= width
+            ids = np.zeros((1, width), np.int32)
+            ids[0, :L] = rng.integers(1, 97, L)
+            reqs.append((ids, [L], int(rng.choice([3, 4, 7]))))
+        want = [
+            rt.generate(mid, ids, prompt_lengths=pl, max_new_tokens=new)
+            for ids, pl, new in reqs
+        ]
         got: list = [None] * len(reqs)
         errors: list = []
 
         def worker(k: int) -> None:
-            for j in range(k, len(reqs), 8):
-                ids, new = reqs[j]
+            for j in range(k, len(reqs), 16):
+                ids, pl, new = reqs[j]
                 try:
-                    got[j] = gc.generate(mid, ids, max_new_tokens=new)
+                    got[j] = eng.generate(
+                        mid, ids, prompt_lengths=pl, max_new_tokens=new
+                    )
                 except BaseException as e:  # noqa: BLE001
                     errors.append((j, e))
 
-        ts = [threading.Thread(target=worker, args=(k,)) for k in range(8)]
+        sys.setswitchinterval(1e-5)
+        ts = [threading.Thread(target=worker, args=(k,)) for k in range(16)]
         for t in ts:
             t.start()
         for t in ts:
-            t.join()
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in ts)
         assert not errors, errors
-        # the stress is pointless if nothing ever coalesced: with 8 threads
-        # funneling 24 requests through per-key gates, at least one batch
-        # must have formed
-        assert gc.batches >= 1
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
+        assert eng.admitted == 32
+        # the stress is pointless if rows never shared a step: 16 threads
+        # over 4 lanes must have filled more than one
+        assert eng.peak_active >= 2
+        st = rt._slot_states[mid]
+        st.check_page_conservation()
+        assert len(st.free_pages) == st.arena_pages and not st.lane_pages
     finally:
+        sys.setswitchinterval(old_switch)
+        eng.close()
         mgr.close()
 
 

@@ -197,6 +197,38 @@ def test_mesh_fast_path_off_restores_lockstep_solo_dispatch(tmp_path):
         rt2.close()
 
 
+async def test_lockstep_runtime_gets_no_engine_and_still_answers_generate(tmp_path):
+    """A lockstep runtime's device-op stream must not depend on a host
+    scheduler thread: its backend builds no engine, and an unseeded REST
+    :generate goes alone through runtime.generate — same tokens as the
+    engine serves on one device."""
+    import json
+
+    store = _store(tmp_path)
+    rt1, mgr1 = _stack(tmp_path, store, "one")
+    rt2, mgr2 = _stack(tmp_path, store, "mesh", mesh=make_mesh({"model": 2}),
+                       mesh_fast_path=False)
+    assert rt2.mesh_lockstep is True
+    one = LocalServingBackend(mgr1, kv_page_tokens=PT)
+    lock = LocalServingBackend(mgr2, kv_page_tokens=PT)
+    body = json.dumps(
+        {"input_ids": [[5, 17, 40, 3, 9, 61, 2, 11]], "max_new_tokens": 8}
+    ).encode()
+    try:
+        assert isinstance(one._generator, ContinuousGenerateEngine)
+        assert lock._generator is None
+        want = await one.handle_rest("POST", "lm", None, "generate", body)
+        got = await lock.handle_rest("POST", "lm", None, "generate", body)
+        assert got.status == want.status == 200
+        assert json.loads(got.body) == json.loads(want.body)
+        assert MID in rt1._slot_states and MID not in rt2._slot_states
+    finally:
+        one.close()
+        lock.close()
+        rt1.close()
+        rt2.close()
+
+
 # -- cold load: pipelined vs serialized on the mesh ---------------------------
 
 def test_cold_load_pipelined_vs_serialized_parity_on_mesh(tmp_path):
@@ -365,7 +397,7 @@ async def test_monitoring_engine_reports_mesh_topology(tmp_path):
     store = _store(tmp_path)
     rt, mgr = _stack(tmp_path, store, "mesh", mesh=make_mesh({"model": 2}),
                      metrics=metrics)
-    backend = LocalServingBackend(mgr, generate_engine="continuous")
+    backend = LocalServingBackend(mgr)
     rest = RestServingServer(backend, metrics, require_version=False)
     rport = await rest.start(0, host="127.0.0.1")
     try:

@@ -434,7 +434,7 @@ def test_f_engine_answers_as_the_solo_path_and_a_dense_model_records_zero(tmp_pa
         dense_rt.close()
 
 
-def test_f_the_coalescer_and_the_engine_ask_the_modeldef_not_the_name():
+def test_f_the_engine_and_the_solo_decoder_ask_the_modeldef_not_the_name():
     assert build("moe_lm", MC).engine_ready and build("transformer_lm").engine_ready
     assert not build("half_plus_two").engine_ready
     with pytest.raises(ValueError, match="engine_ready"):

@@ -1,7 +1,7 @@
-"""Paged KV arena for the continuous decode engine
-(`serving.kv_page_tokens` > 0): dense-vs-paged greedy parity (ragged
-prompts, prefix-cache-hit admission, per-lane sampling params), page
-recycling under churn (free-list conservation, no cross-slot KV bleed),
+"""Paged KV arena of the continuous decode engine
+(`serving.kv_page_tokens`): greedy parity with the solo decoder (ragged
+prompts, prefix-cache-hit admission), per-lane sampling params across page
+sizes, page recycling under churn (free-list conservation, no cross-slot KV bleed),
 admission blocking — not failing — on arena exhaustion, and the
 slot-state first-admission once-guard."""
 
@@ -30,9 +30,9 @@ TINY = {
     "max_seq": 64,
 }
 
-# page size dividing max_seq: the gathered logical length equals the dense
-# slot row, so the attention reductions are shape-identical and greedy
-# parity is exact (see paged_decode_attention)
+# page size dividing max_seq: the gathered logical length equals the solo
+# decoder's cache row, so the attention reductions are shape-identical and
+# greedy parity is exact (see paged_decode_attention)
 PT = 8
 
 
@@ -112,75 +112,70 @@ def test_paged_attention_op_matches_dense_math():
 
 # -- engine-level greedy parity ----------------------------------------------
 
-def test_greedy_parity_paged_vs_dense(tmp_path):
+def test_greedy_parity_paged_vs_solo(tmp_path):
     """Token-for-token greedy parity on ragged prompts: the paged engine
-    must be indistinguishable from the dense engine AND the solo decoder."""
+    must be indistinguishable from the solo decoder."""
     ids, lens = _ragged_prompts()
-    rt_d, mid = _load(tmp_path / "dense")
-    eng_d = ContinuousGenerateEngine(rt_d, slots=4, chunk_tokens=4)
-    rt_p, _ = _load(tmp_path / "paged")
-    eng_p = ContinuousGenerateEngine(rt_p, slots=4, chunk_tokens=4,
-                                     page_tokens=PT, arena_pages=32)
+    rt, mid = _load(tmp_path)
+    eng = ContinuousGenerateEngine(rt, slots=4, chunk_tokens=4,
+                                   page_tokens=PT, arena_pages=32)
     try:
-        want = rt_d.generate(mid, ids, prompt_lengths=lens,
-                             max_new_tokens=8, seed=0)
-        dense = eng_d.generate(mid, ids, prompt_lengths=lens, max_new_tokens=8)
-        paged = eng_p.generate(mid, ids, prompt_lengths=lens, max_new_tokens=8)
-        assert (dense == want).all()
-        assert (paged == dense).all()
-        st = _slot_state(rt_p, mid)
-        assert st.paged and st.page_tokens == PT and st.arena_pages == 32
+        want = rt.generate(mid, ids, prompt_lengths=lens,
+                           max_new_tokens=8, seed=0)
+        paged = eng.generate(mid, ids, prompt_lengths=lens, max_new_tokens=8)
+        assert (paged == want).all()
+        st = _slot_state(rt, mid)
+        assert st.page_tokens == PT and st.arena_pages == 32
         _assert_arena_clean(st)
     finally:
-        eng_d.close()
-        eng_p.close()
-        rt_d.close()
-        rt_p.close()
+        eng.close()
+        rt.close()
 
 
 def test_greedy_parity_with_prefix_cache_hit(tmp_path):
     """Admission through a prefix-cache hit (the from-cache prefill variant)
-    must stay dense/paged parity-exact; both arms pre-populate the cache
-    identically via the solo path first."""
+    must emit what the solo decoder emits for the whole prompt, cold, on a
+    runtime with no prefix cache."""
     # long enough that the stored pow2-floor entry clears the cache's
     # 16-row storage minimum: 12 prompt + 8 completion -> 16 rows stored
     rng = np.random.default_rng(7)
     prefix = rng.integers(1, 96, size=(1, 12)).astype(np.int32)
 
-    outs, hits = [], []
-    for arm, kw in (("dense", {}), ("paged", {"page_tokens": PT,
-                                              "arena_pages": 24})):
-        metrics = Metrics()
-        rt, mid = _load(tmp_path / arm, metrics=metrics,
-                        prefix_cache_bytes=32 << 20)
-        eng = ContinuousGenerateEngine(rt, slots=2, chunk_tokens=4,
-                                       metrics=metrics, **kw)
-        try:
-            # populate: the cache stores the first 16 rows of prefix +
-            # greedy completion; a query extending THAT sequence hits
-            comp = rt.generate(mid, prefix, max_new_tokens=8, seed=0)
-            prompt = np.concatenate(
-                [prefix[0], comp[0, :4], [56]]
-            )[None, :].astype(np.int32)
-            before = metrics.registry.get_sample_value(
-                "tpusc_prefix_cache_hits_total") or 0
-            outs.append(eng.generate(mid, prompt, max_new_tokens=6))
-            after = metrics.registry.get_sample_value(
-                "tpusc_prefix_cache_hits_total") or 0
-            hits.append(after - before)
-        finally:
-            eng.close()
-            rt.close()
-    assert hits == [1, 1]  # both arms actually admitted through the hit path
-    assert (outs[0] == outs[1]).all()
+    metrics = Metrics()
+    rt, mid = _load(tmp_path / "paged", metrics=metrics,
+                    prefix_cache_bytes=32 << 20)
+    rt_cold, _ = _load(tmp_path / "cold")
+    eng = ContinuousGenerateEngine(rt, slots=2, chunk_tokens=4,
+                                   metrics=metrics, page_tokens=PT,
+                                   arena_pages=24)
+    try:
+        # populate: the cache stores the first 16 rows of prefix +
+        # greedy completion; a query extending THAT sequence hits
+        comp = rt.generate(mid, prefix, max_new_tokens=8, seed=0)
+        prompt = np.concatenate(
+            [prefix[0], comp[0, :4], [56]]
+        )[None, :].astype(np.int32)
+        want = rt_cold.generate(mid, prompt, max_new_tokens=6, seed=0)
+        before = metrics.registry.get_sample_value(
+            "tpusc_prefix_cache_hits_total") or 0
+        got = eng.generate(mid, prompt, max_new_tokens=6)
+        after = metrics.registry.get_sample_value(
+            "tpusc_prefix_cache_hits_total") or 0
+    finally:
+        eng.close()
+        rt.close()
+        rt_cold.close()
+    assert after - before == 1  # actually admitted through the hit path
+    assert (got == want).all()
 
 
 def test_per_lane_sampling_parity(tmp_path, monkeypatch):
-    """Lanes carrying different temperature/top_k must sample identically on
-    the dense and paged engines: prefill seeds are pinned (secrets.randbits
-    patched to a replayed counter), chunk rngs are already deterministic
-    (PRNGKey(chunk_counter)), and rows are submitted in one FIFO batch so
-    lane assignment matches arm-for-arm."""
+    """Lanes carrying different temperature/top_k must sample identically
+    whatever the arena's page size: prefill seeds are pinned
+    (secrets.randbits patched to a replayed counter), chunk rngs are already
+    deterministic (PRNGKey(chunk_counter)), and rows are submitted in one
+    FIFO batch so lane assignment matches arm-for-arm. The greedy lane also
+    equals the solo decoder."""
     ids, lens = _ragged_prompts(rows=3, width=7, seed=5)
     sampling = [(0.0, 0), (0.8, 5), (1.3, 3)]
 
@@ -203,38 +198,40 @@ def test_per_lane_sampling_parity(tmp_path, monkeypatch):
             for r in reqs:
                 assert r.done.wait(60.0)
                 assert r.error is None
-            return [list(r.tokens) for r in reqs]
+            solo = rt.generate(mid, ids[:1, : lens[0]], max_new_tokens=6, seed=0)
+            return [list(r.tokens) for r in reqs], solo[0].tolist()
         finally:
             eng.close()
             rt.close()
 
-    dense = run(tmp_path / "dense")
-    paged = run(tmp_path / "paged", page_tokens=PT, arena_pages=32)
-    assert dense == paged
+    small, solo = run(tmp_path / "pt8", page_tokens=PT, arena_pages=32)
+    large, _ = run(tmp_path / "pt16", page_tokens=2 * PT, arena_pages=16)
+    assert small == large
+    assert small[0] == solo
+    assert all(0 <= t < TINY["vocab_size"] for row in small for t in row)
 
 
 # -- recycling / admission gating --------------------------------------------
 
 def test_page_recycling_stress(tmp_path):
     """Churn far more requests than the arena holds at once: every row
-    completes with greedy parity to the dense engine (any cross-slot bleed
+    completes with greedy parity to the solo decoder (any cross-slot bleed
     would corrupt tokens), and afterwards the free-list holds every page
     exactly once."""
     ids, lens = _ragged_prompts(rows=16, width=7, seed=9)
-    rt_d, mid = _load(tmp_path / "dense")
-    eng_d = ContinuousGenerateEngine(rt_d, slots=4, chunk_tokens=4)
     metrics = Metrics()
-    rt_p, _ = _load(tmp_path / "paged", metrics=metrics)
+    rt, mid = _load(tmp_path, metrics=metrics)
     # 6 usable pages; each row needs 2 (prompt <= 7 + max_new 6 = 13 tokens)
     # -> at most 3 rows hold pages at once, 16 rows churn through
-    eng_p = ContinuousGenerateEngine(rt_p, slots=4, chunk_tokens=4,
-                                     metrics=metrics,
-                                     page_tokens=PT, arena_pages=6)
+    eng = ContinuousGenerateEngine(rt, slots=4, chunk_tokens=4,
+                                   metrics=metrics,
+                                   page_tokens=PT, arena_pages=6)
     try:
-        dense = eng_d.generate(mid, ids, prompt_lengths=lens, max_new_tokens=6)
-        paged = eng_p.generate(mid, ids, prompt_lengths=lens, max_new_tokens=6)
-        assert (paged == dense).all()
-        st = _slot_state(rt_p, mid)
+        want = rt.generate(mid, ids, prompt_lengths=lens, max_new_tokens=6,
+                           seed=0)
+        paged = eng.generate(mid, ids, prompt_lengths=lens, max_new_tokens=6)
+        assert (paged == want).all()
+        st = _slot_state(rt, mid)
         _assert_arena_clean(st)
         # occupancy gauges drained back to zero; waste observed per retirement
         assert metrics.registry.get_sample_value("tpusc_gen_kv_pages_used") == 0
@@ -243,10 +240,8 @@ def test_page_recycling_stress(tmp_path):
             "tpusc_gen_kv_page_waste_tokens_count")
         assert waste_n == 16
     finally:
-        eng_d.close()
-        eng_p.close()
-        rt_d.close()
-        rt_p.close()
+        eng.close()
+        rt.close()
 
 
 def test_admission_blocks_on_page_exhaustion(tmp_path):
@@ -293,14 +288,12 @@ def test_spec_round_census_under_recycling_stress(tmp_path, monkeypatch):
     exactly conserved: 16 rows churn through a 6-page arena with spec
     rounds enabled, the trash-unreachable guard armed on every chunk, and
     the drained free-lists must hold every page exactly once. Greedy output
-    stays byte-identical to the dense spec-less engine throughout."""
+    stays byte-identical to the solo decoder's throughout."""
     import tfservingcache_tpu.runtime.model_runtime as mr
 
     monkeypatch.setattr(mr, "_PAGECHECK", True)
     ids, lens = _ragged_prompts(rows=16, width=7, seed=9)
-    rt_d, mid = _load(tmp_path / "dense")
-    eng_d = ContinuousGenerateEngine(rt_d, slots=4, chunk_tokens=4)
-    rt_p, _ = _load(tmp_path / "paged")
+    rt_p, mid = _load(tmp_path / "paged")
     draft_cfg = dict(TINY, d_model=24, n_layers=1, n_heads=2, n_kv_heads=1,
                      d_ff=48)
     export_artifact("transformer_lm", str(tmp_path / "paged"), name="draft",
@@ -315,9 +308,10 @@ def test_spec_round_census_under_recycling_stress(tmp_path, monkeypatch):
                                      page_tokens=PT, arena_pages=6,
                                      spec_draft_model="draft", spec_tokens=2)
     try:
-        dense = eng_d.generate(mid, ids, prompt_lengths=lens, max_new_tokens=6)
+        want = rt_p.generate(mid, ids, prompt_lengths=lens, max_new_tokens=6,
+                             seed=0)
         paged = eng_p.generate(mid, ids, prompt_lengths=lens, max_new_tokens=6)
-        assert (paged == dense).all()
+        assert (paged == want).all()
         st = _slot_state(rt_p, mid)
         assert st.spec_draft is not None
         _assert_arena_clean(st)
@@ -325,9 +319,7 @@ def test_spec_round_census_under_recycling_stress(tmp_path, monkeypatch):
         st.check_page_conservation()
         st.spec_draft.check_page_conservation()
     finally:
-        eng_d.close()
         eng_p.close()
-        rt_d.close()
         rt_p.close()
 
 
@@ -335,18 +327,18 @@ def test_spec_round_census_under_recycling_stress(tmp_path, monkeypatch):
 
 def test_slot_state_allocated_once_under_race(tmp_path, monkeypatch):
     """Concurrent first admissions must allocate the (potentially
-    hundreds-of-MB) slot array exactly once: the per-model once-guard
+    gigabytes of) arena exactly once: the per-model once-guard
     serializes allocation, every thread gets the same state object."""
     rt, mid = _load(tmp_path)
     calls = []
-    real = generation.init_cache
+    real = generation.init_paged_cache
 
-    def slow_init(cfg, batch, max_len, mesh=None):
+    def slow_init(*args, **kw):
         calls.append(threading.get_ident())
         time.sleep(0.05)  # widen the race window the guard must close
-        return real(cfg, batch, max_len, mesh=mesh)
+        return real(*args, **kw)
 
-    monkeypatch.setattr(generation, "init_cache", slow_init)
+    monkeypatch.setattr(generation, "init_paged_cache", slow_init)
     states = [None] * 8
     barrier = threading.Barrier(8)
 

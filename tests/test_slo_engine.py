@@ -21,10 +21,7 @@ from tfservingcache_tpu.protocol import codec
 from tfservingcache_tpu.protocol.local_backend import LocalServingBackend
 from tfservingcache_tpu.protocol.protos import tf_serving_pb2 as sv
 from tfservingcache_tpu.protocol.rest import RestServingServer
-from tfservingcache_tpu.runtime.batcher import (
-    ContinuousGenerateEngine,
-    GenerateCoalescer,
-)
+from tfservingcache_tpu.runtime.batcher import ContinuousGenerateEngine
 from tfservingcache_tpu.runtime.model_runtime import TPUModelRuntime
 from tfservingcache_tpu.types import Model, ModelId
 from tfservingcache_tpu.utils.metrics import Metrics
@@ -61,7 +58,6 @@ def _backend(tmp_path, metrics=None, **kw):
         TPUModelRuntime(ServingConfig(platform="cpu"), metrics),
         metrics,
     )
-    kw.setdefault("generate_engine", "continuous")
     kw.setdefault("generate_slots", 4)
     kw.setdefault("generate_chunk_tokens", 2)
     return LocalServingBackend(manager, **kw), manager
@@ -511,18 +507,25 @@ async def test_router_conversation_affinity_pins_replica():
         await cluster.disconnect()
 
 
-# -------------------------------------------------------------- coalescer
+# ----------------------------------------------------------------- submit
 
 
-def test_coalescer_oversized_prompt_fails_at_submit(tmp_path):
-    """The coalescer must reject prompt + max_new > max_seq LOUDLY at
-    submit, not let the batch worker discover it after other rows have
-    coalesced in behind it."""
+def test_oversized_prompt_fails_at_submit(tmp_path):
+    """The engine must reject prompt + max_new > max_seq LOUDLY at submit,
+    in the caller's thread: before a scheduler exists, before the row
+    queues behind others for a lane, before any page is reserved."""
     rt, mid = _load(tmp_path)
-    coal = GenerateCoalescer(rt)
+    eng = ContinuousGenerateEngine(rt, slots=2, chunk_tokens=2)
     try:
         ids = np.arange(1, 61, dtype=np.int32)[None]  # 60 + 16 > 64
         with pytest.raises(ValueError, match="max_seq"):
-            coal.generate(mid, ids, max_new_tokens=16)
+            eng.generate(mid, ids, max_new_tokens=16)
+        assert eng._scheds == {} and eng.admitted == 0
+        assert mid not in rt._slot_states
+        # the TRUE length counts, not the padded width: 8 real tokens of a
+        # 60-wide row fit
+        out = eng.generate(mid, ids, prompt_lengths=[8], max_new_tokens=16)
+        assert out.shape == (1, 16) and eng.admitted == 1
     finally:
+        eng.close()
         rt.close()

@@ -158,7 +158,7 @@ async def test_streamed_generate_is_one_root_as_long_as_the_stream(tmp_path):
     from tfservingcache_tpu.protocol.rest import RestServingServer
 
     backend, manager = _lm_backend(
-        tmp_path, generate_engine="continuous", generate_slots=2,
+        tmp_path, generate_slots=2,
         generate_chunk_tokens=2,
     )
     rest = RestServingServer(backend, require_version=False)

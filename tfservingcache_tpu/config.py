@@ -40,9 +40,10 @@ class ServingConfig:
     # so the default is looser. Enforced by CacheManager.ensure_servable.
     load_timeout_s: float = 30.0
     platform: str = ""                     # "" = default jax backend; "cpu" forces CPU
-    # adaptive micro-batching (TF Serving --enable_batching equivalent,
-    # in-process now): 0 disables; concurrent same-shape requests within the
-    # window coalesce into one device call. Default 0 (OFF) per measured
+    # adaptive micro-batching of :predict (TF Serving --enable_batching
+    # equivalent, in-process now; :generate has its own engine below): 0
+    # disables; concurrent same-shape requests within the window coalesce
+    # into one device call. Default 0 (OFF) per measured
     # evidence: on the chip (r5, tpu_runs/) LM REST loses consistently with
     # batching (36-66 vs 100-105 QPS) as does r2's mnist REST (-31%); the
     # wins are protocol/family-specific and window-noisy (r5 full run:
@@ -51,8 +52,7 @@ class ServingConfig:
     # hold across families; off does. Enable per-deployment (set 1-2 ms)
     # only when profiling shows concurrent same-shape warm traffic whose
     # batched device call beats the window latency — e.g. many-client
-    # fan-in on one cheap-decode model (bench.py `batcher_qps` section
-    # measures exactly this pair).
+    # fan-in on one cheap-decode model.
     batch_window_ms: float = 0.0
     batch_max_size: int = 64
     # Prefix KV cache for :generate (runtime/prefix_cache.py): byte budget
@@ -70,29 +70,26 @@ class ServingConfig:
     cold_load_pipeline: bool = True
     # Mesh parity for the fast path (ISSUE 20): single-process mesh
     # runtimes run the same pipelined cold load, host warm tier, packed
-    # adoption, and continuous/paged :generate engine as single-chip
-    # runtimes, with params and KV arenas sharded per the family's
-    # partition rules. False restores the pre-parity behavior (serialized
-    # loads, coalesce generate) — the A/B lever the mesh_generate bench
-    # section flips. Multi-process (cross-host) groups ignore the knob and
-    # stay serialized/coalesced: their device-op stream is lockstep.
+    # adoption, and :generate engine as single-chip runtimes, with params
+    # and KV arenas sharded per the family's partition rules. False
+    # restores the pre-parity behavior (serialized loads, every :generate
+    # alone through runtime.generate). Multi-process (cross-host) groups
+    # ignore the knob and stay lockstep: their device-op stream must not
+    # depend on host thread timing.
     mesh_fast_path: bool = True
     # Host buffers the chunk assembler may run ahead of the H2D stream
     # (bounded queue depth; each slot holds up to one ~256 MB packed chunk).
     cold_pipeline_buffer_depth: int = 2
-    # :generate engine for the transformer_lm family. "coalesce" (default)
-    # keeps batch-formation-time coalescing (GenerateCoalescer): safe,
-    # proven, but a request arriving just after a batch launches waits for
-    # the whole fixed-length scan, and early-EOS rows burn padded steps
-    # until the batch drains. "continuous" enables the slotted
-    # iteration-level engine (runtime/batcher.py ContinuousGenerateEngine):
-    # a fixed slot array advanced by one compiled decode-chunk program,
-    # with admission at chunk boundaries and per-row retirement at EOS /
-    # max_new_tokens. Mesh/multi-process runtimes ignore "continuous" and
-    # take the coalesce path unconditionally (same rule as
-    # cold_load_pipeline: lockstep device-op streams must not depend on a
-    # host scheduler thread).
-    generate_engine: str = "coalesce"
+    # :generate has one engine (runtime/batcher.py ContinuousGenerateEngine):
+    # a fixed array of lanes advanced by one compiled decode-chunk program
+    # over a paged KV arena, with admission at chunk boundaries and per-row
+    # retirement at EOS / max_new_tokens. It serves single-device runtimes
+    # and single-process meshes (the arena shards over the KV heads). What
+    # it cannot take runs alone through runtime.generate's whole-request
+    # scan: requests with an explicit seed, models that are not
+    # ModelDef.engine_ready, malformed input, and lockstep runtimes
+    # (cross-process groups, or mesh_fast_path off: their device-op stream
+    # must not depend on a host scheduler thread).
     # Slot count S of the continuous engine's decode array: one compiled
     # program serves all S lanes; S bounds concurrent decodes per model.
     generate_slots: int = 8
@@ -101,8 +98,7 @@ class ServingConfig:
     # the cost of up to k-1 overshoot steps per finishing row (PERF.md
     # "Continuous batching" discusses the tradeoff).
     generate_chunk_tokens: int = 8
-    # Chunked prefill interleaving for generate_engine=continuous over a
-    # paged arena (ISSUE 19): 0 (default) prefills each admitted prompt in
+    # Chunked prefill interleaving (ISSUE 19): 0 (default) prefills each admitted prompt in
     # one dispatch — a 2k-token prompt monopolizes the engine for the whole
     # prefill, inflating every other lane's inter-token latency and TTFT.
     # > 0 splits cold-miss prefills into fixed chunks of this many tokens
@@ -112,22 +108,22 @@ class ServingConfig:
     # chunks. Prompts that fit one chunk, shared-prefix/resume hits, and
     # spec-draft engines take the single-dispatch path unchanged.
     prefill_chunk_tokens: int = 0
-    # Paged KV for the continuous engine. 0 (default) keeps the dense
-    # per-lane slot array (slots x max_seq rows reserved per lane). > 0
-    # replaces it with a shared page arena: fixed pages of kv_page_tokens
-    # tokens each, handed out by a free-list at admission (the row's full
-    # prompt + max_new budget is pre-reserved) and recycled at retirement,
-    # so HBM is sized by tokens in flight instead of worst case.
-    kv_page_tokens: int = 0
+    # Page size of the engine's KV arena, in tokens: fixed pages handed out
+    # by a free-list at admission (the row's full prompt + max_new budget is
+    # pre-reserved) and recycled at retirement, so HBM is sized by tokens in
+    # flight instead of worst case. Must be >= 1 (there is no other KV
+    # layout); 16 is the size the chip cells run and the paged kernel was
+    # checked at.
+    kv_page_tokens: int = 16
     # Usable arena pages (one extra trash page is always added). 0 = auto:
-    # generate_slots x ceil(max_seq / kv_page_tokens) — the dense-equivalent
-    # byte budget; shrink it to cap KV HBM, grow it (with generate_slots) to
-    # admit more concurrent rows at the same budget.
+    # generate_slots x ceil(max_seq / kv_page_tokens), every lane can hold
+    # the longest request; shrink it to cap KV HBM, grow it (with
+    # generate_slots) to admit more concurrent rows at the same budget.
     kv_arena_pages: int = 0
     # Cross-request shared-prefix KV over the paged arena
     # (runtime/prefix_cache.py PagePrefixIndex): byte budget of arena pages
-    # the radix prefix index may pin for reuse. 0 = off (default). > 0 (and
-    # kv_page_tokens > 0) makes admission map the longest page-aligned
+    # the radix prefix index may pin for reuse. 0 = off (default). > 0
+    # makes admission map the longest page-aligned
     # shared prompt prefix read-only into a new row's block table (refcount
     # bump, no prefill compute over the shared part, no page copy) and
     # reserve only the private suffix + max_new pages — N concurrent
@@ -139,22 +135,23 @@ class ServingConfig:
     # paged_attention): walk each lane's block table inside the kernel and
     # compute online-softmax attention straight from the page arena — one
     # pass over the KV bytes instead of paged_gather_kv's materialized
-    # pages[tables] round-trip. true (default) uses the kernel on TPU
-    # backends when shapes qualify (head_dim % 64 == 0, heads divisible by
+    # pages[tables] round-trip. true (default) uses the kernel on one TPU
+    # chip when shapes qualify (head_dim % 128 == 0 since PR 24: the kernel
+    # copies pages out of HBM in whole 128-lane tiles; heads divisible by
     # kv heads) and falls back to the gather+einsum reference everywhere
-    # else; false forces the reference path unconditionally — byte-for-byte
-    # the pre-kernel behavior, the A/B lever for parity tests and bench.
+    # else (CPU, a mesh, head 64); false forces the reference path
+    # unconditionally, the lever of the parity tests.
     kv_paged_kernel: bool = True
     # KV page arena element type. "" (default) stores pages in the model's
     # own dtype. "int8" quantizes pages symmetrically per (page, kv_head,
     # token) row with f32 scales riding beside the arena — rows dequantize
     # inside the decode kernel (or before the reference einsum), and the
     # auto-sized arena (kv_arena_pages == 0) grows to fill the SAME byte
-    # budget the dense arena would have used (~1.9x pages for bf16 models),
+    # budget the model-dtype arena would have used (~1.9x pages for bf16),
     # which is the capacity win. Page bookkeeping (reserve/CoW/census) is
     # count-based and identical under quantization.
     kv_arena_dtype: str = ""
-    # In-engine speculative decoding for generate_engine=continuous
+    # In-engine speculative decoding
     # (runtime/batcher.py): name of the DRAFT model — "name" (highest
     # resident version) or "name@version". "" = off (default). When set,
     # each continuous scheduler attaches the draft to its paged slot state
@@ -164,9 +161,8 @@ class ServingConfig:
     # accepts a variable-length prefix — greedy streams stay byte-identical
     # to spec-off. Admission reserves spec_tokens of extra page headroom
     # per row in BOTH arenas, so requests sized to the exact arena edge may
-    # need one more page than without spec. Mesh runtimes and dense
-    # (non-paged) states ignore the knob; lanes with temperature > 0 fall
-    # back to single-token emission inside the round.
+    # need one more page than without spec. Lanes with temperature > 0
+    # fall back to single-token emission inside the round.
     spec_draft_model: str = ""
     # Draft tokens proposed per verify round when spec_draft_model is set
     # (clamped to the pow2 bucket ladder {1, 2, 4, 8} at attach — bounds
@@ -175,7 +171,7 @@ class ServingConfig:
     # runtime's acceptance health gate (_spec_admit) auto-disables a pair
     # that sustains low acceptance and re-auditions it periodically.
     spec_tokens: int = 4
-    # Transparent crash recovery for generate_engine=continuous
+    # Transparent crash recovery of the engine
     # (runtime/batcher.py): on an engine-thread death (device failure,
     # mid-decode eviction, injected kill) the crashed scheduler's in-flight
     # and queued rows requeue into a fresh scheduler thread instead of
@@ -189,10 +185,10 @@ class ServingConfig:
     # fails on the next one (a poison prompt that deterministically crashes
     # the engine must not respawn scheduler threads forever).
     generate_max_recoveries: int = 2
-    # Conversation KV tier for generate_engine=continuous
+    # Conversation KV tier of the engine
     # (cache/conversation_kv.py): host-RAM byte budget for PARKED decode
     # state. A `:generate` request carrying a conversation_id parks its
-    # lane's live KV pages (raw arena dtype + int8 scales — half the dense
+    # lane's live KV pages (raw arena dtype + int8 scales — half the
     # bytes under kv_arena_dtype=int8) at retirement; the conversation's
     # next turn resumes with a suffix-only prefill over the re-imported
     # pages — O(new tokens) TTFT instead of a full-history re-prefill,

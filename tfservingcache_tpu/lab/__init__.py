@@ -10,7 +10,7 @@ Three pieces, deliberately decoupled from production wiring:
   engine, cache manager, peer-transfer receiver, and fleet status plane.
   Disarmed (the default) every hook is a passthrough; arming happens only
   through ``observability.lab_faults`` / the ``TPUSC_OBSERVABILITY_LAB_FAULTS``
-  env override or an explicit ``arm()`` in tests and bench;
+  env override or an explicit ``arm()`` in tests and drives;
 * ``scenario`` — runs one scenario x fault cell end-to-end and emits an
   SLO scorecard row (TTFT percentiles, tok/s, goodput, cold-miss rate,
   lost/recovered counts, page-conservation census, platform stamps).

@@ -1,19 +1,17 @@
 """Scenario x fault cell runner: replay a compiled schedule, emit an SLO
 scorecard row.
 
-``run_cell`` is deliberately harness-agnostic: the caller (bench.py's
-``scenario_lab`` section, or tests/test_scenario_lab.py) supplies a
+``run_cell`` is deliberately harness-agnostic: the caller
+(tests/test_scenario_lab.py, or a drive script) supplies a
 ``generate_fn(ScheduledRequest) -> dict`` closure over whatever stack it
 built, plus optional Metrics / census hooks. The runner owns only the
 open-loop replay (one thread per request, sleeping to its compiled arrival
 offset), fault arming, and the scorecard math — so the same cell definition
-runs against an engine-only stub stack in tests and the full
-manager+runtime stack in bench.
+runs against an engine-only stub stack and a full manager+runtime stack.
 
-Every scorecard row stamps ``kernel_active`` and ``platform`` (satellite
-fix for BENCH_r09: its kernel arm silently ran interpret-mode on CPU and
-the tok/s deltas were non-evidence — a matrix row without the stamp can no
-longer exist).
+Every scorecard row stamps ``kernel_active`` and ``platform``: a kernel
+arm once ran interpret-mode on CPU in silence and its tok/s deltas were
+non-evidence — a matrix row without the stamp can no longer exist.
 """
 
 from __future__ import annotations
@@ -50,8 +48,8 @@ SCORECARD_FIELDS = (
 def default_scenarios(
     tenants: tuple[str, ...] = ("lm",), requests: int = 16, max_new: int = 10,
 ) -> list[WorkloadSpec]:
-    """The standard 4-scenario row set (bench and the chaos suite share it
-    so BENCH_r11 cells and regression cells are the same workloads)."""
+    """The standard 4-scenario row set (the chaos suite's regression cells
+    and the committed BENCH_r11.json sample are these workloads)."""
     multi = tenants if len(tenants) > 1 else tenants * 2
     return [
         WorkloadSpec(

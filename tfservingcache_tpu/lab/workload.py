@@ -11,7 +11,7 @@ request stream, diff only the system under test.
 Arrival processes:
 
 * ``poisson``     — exponential inter-arrivals at ``rate_rps`` (the classic
-  open-loop load model; same idiom as bench.py's admission soak);
+  open-loop load model);
 * ``burst``       — groups of ``burst_size`` simultaneous arrivals spaced
   ``burst_gap_s`` apart (coordinated clients, cron fan-out);
 * ``flash_crowd`` — a poisson baseline with ``flash_share`` of all traffic
@@ -122,7 +122,7 @@ def compile_schedule(
 ) -> list[ScheduledRequest]:
     """Compile ``spec`` into a replayable schedule, sorted by arrival time.
     Token ids are drawn from [1, vocab) — 0 is reserved (pad in the toy LM
-    family, same convention as bench.py's prompt generators)."""
+    family)."""
     rng = np.random.default_rng([int(seed), spec.requests, len(spec.tenants)])
     vocab = max(2, int(vocab))
     n_conv = max(1, spec.requests // spec.turns)

@@ -213,7 +213,7 @@ def _generate_from_cache_jit(
 def _sample_per_row(logits, rng, temperature, top_k):
     """Per-row sampling params: logits (S, V), temperature (S,) f32,
     top_k (S,) i32 -> token ids (S,). The continuous engine packs unrelated
-    requests into one slot array, so each lane carries its own sampling
+    requests into one decode step, so each lane carries its own sampling
     config; the values stay TRACED for the same compile-DoS reason as
     ``_sample``. One categorical draw covers all rows (matches the batched
     stream structure)."""
@@ -316,74 +316,6 @@ def _sample_logits_jit(last, rng, temperature, top_k):
     the same prompt produce the same token under the same seed."""
     _, sub = jax.random.split(rng)
     return _sample(last, sub, temperature, top_k)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-@jax.named_scope("kv_write")
-def _slot_insert_jit(slot_k, slot_v, pk, pv, idx):
-    """Copy one admitted request's prefill K/V (layers, 1, n_kv, P_pad, hd)
-    into slot row ``idx`` of the slot array (layers, S, n_kv, max_seq, hd).
-    ``idx`` is traced, so one compile serves every slot; donation makes the
-    copy in-place instead of reallocating the (large) slot array. Rows
-    beyond P_pad keep a previous occupant's stale K/V — never visible: a
-    query at pos p sees only rows <= p, and the decode step writes row p
-    before attending (the same write-before-read argument as prefill
-    padding)."""
-    idx = idx.astype(jnp.int32)
-    k = jax.lax.dynamic_update_slice(
-        slot_k, pk.astype(slot_k.dtype), (0, idx, 0, 0, 0)
-    )
-    v = jax.lax.dynamic_update_slice(
-        slot_v, pv.astype(slot_v.dtype), (0, idx, 0, 0, 0)
-    )
-    return k, v
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg_key", "family", "chunk"),
-    donate_argnums=(1, 2),
-)
-def _decode_chunk_jit(
-    params,
-    slot_k,              # (layers, S, n_kv, max_seq, head_dim) — donated
-    slot_v,
-    tok,                 # (S,) last sampled token per slot
-    pos,                 # (S,) i32 write position per slot
-    active,              # (S,) bool — frozen for the whole chunk
-    rngs,                # (chunk, 2) uint32 — one PRNG key per step
-    temperature,         # (S,) f32 per-slot
-    top_k,               # (S,) i32 per-slot
-    *,
-    cfg_key,
-    family: str = "transformer_lm",
-    chunk: int,
-):
-    """Advance every ACTIVE slot by ``chunk`` decode steps in one compiled
-    program — the continuous engine's only steady-state dispatch. Inactive
-    lanes ride along: their token/pos are frozen (``where(active, ...)``)
-    so each step just rewrites the same K/V at the frozen pos — junk for
-    never-admitted slots, a no-op rewrite for retired ones — and the host
-    ignores their emitted tokens. Admission/retirement happen on the host
-    BETWEEN chunks; a row finishing mid-chunk keeps decoding from its own
-    EOS until the chunk ends (the < chunk overshoot the wasted-steps
-    counter measures)."""
-    cfg = dict(cfg_key)
-
-    def step(carry, rng):
-        k, v, tok, pos = carry
-        logits, cache = _forward_cached_dyn(
-            params, tok[:, None], {"k": k, "v": v}, pos, cfg, family
-        )
-        nxt = _sample_per_row(logits[:, 0], rng, temperature, top_k)
-        nxt = jnp.where(active, nxt, tok)
-        pos = pos + active.astype(jnp.int32)
-        return (cache["k"], cache["v"], nxt, pos), nxt
-
-    (slot_k, slot_v, tok, pos), toks = jax.lax.scan(
-        step, (slot_k, slot_v, tok, pos), rngs, length=chunk
-    )
-    return slot_k, slot_v, tok, pos, jnp.transpose(toks, (1, 0))  # (S, chunk)
 
 
 def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
@@ -598,8 +530,9 @@ def _paged_prefill_chunk_jit(params, arena_k, arena_v, scales, table_row,
     reservation), and per-position causal masks give each real query
     exact attention over every previously written chunk. Pad rows INSIDE
     the reservation hold junk at positions >= the prompt end — the same
-    write-before-read argument as the dense insert makes them invisible:
-    decode writes row p before any query attends to it. Returns the
+    write-before-read argument as prefill padding makes them invisible:
+    a query at pos p sees only rows <= p, and decode writes row p before
+    any query attends to it. Returns the
     updated arena plus the last REAL token's logits (f32), which the
     final chunk feeds through the split-then-sample helper for a first
     token bit-identical in discipline to the monolithic prefill."""
@@ -633,15 +566,16 @@ def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
     is the lane's FULL (pages_per_slot,) block-table row — entries beyond
     the reservation are 0, so prefill-pad rows past the reserved budget
     (P_pad is a pow2 bucket and can overshoot it) land in the trash page.
-    Junk pad rows inside the reservation are never visible for the same
-    write-before-read reason as the dense insert. ``base`` (traced i32) is
+    Junk pad rows inside the reservation are never visible: a query at pos
+    p sees only rows <= p, and the decode step writes row p before
+    attending. ``base`` (traced i32) is
     the shared-prefix boundary: rows < base belong to pages another lane /
     the prefix index owns READ-ONLY, so their scatter is redirected to the
     trash page — prefill stops at the shared boundary and only private
     pages are written. base=0 is the plain unshared insert. One compile
     per P_pad bucket, same bound as the prefill itself (base is data, not
     a signature). ``scales`` is the int8 arena's {"k", "v"} per-row scale
-    buffers (donated; None for a dense-dtype arena): prefill rows are
+    buffers (donated; None for an arena in the model's dtype): prefill rows are
     quantized here with the same per-row absmax discipline as the decode
     write, so a page is bit-identical whether filled by prefill or steps."""
     p_pad = pk.shape[3]
@@ -768,9 +702,15 @@ def _paged_decode_chunk_jit(
     page_tokens: int,
     kernel: bool = False,
 ):
-    """Paged counterpart of ``_decode_chunk_jit``: same scan, same frozen
-    inactive-lane convention, but K/V live in the shared page arena and
-    each lane reads through its block table. ``tables`` is traced (a tiny
+    """Advance every ACTIVE lane by ``chunk`` decode steps in one compiled
+    program — the continuous engine's only steady-state dispatch. K/V live
+    in the shared page arena and each lane reads through its block table.
+    Inactive lanes ride along: their token/pos are frozen
+    (``where(active, ...)``) and their writes land on the trash page; the
+    host ignores their emitted tokens. Admission/retirement happen on the
+    host BETWEEN chunks; a row finishing mid-chunk keeps decoding from its
+    own EOS until the chunk ends (the < chunk overshoot the wasted-steps
+    counter measures). ``tables`` is traced (a tiny
     (S, pages_per_slot) i32 H2D copy per chunk), so recycling pages never
     mints a new program; compiled-program count stays one per chunk size
     (x2 for the ``kernel`` boolean — the serving.kv_paged_kernel gate).

@@ -116,7 +116,7 @@ class ModelDef:
     # per-request layer state is K/V rows in the decoder-LM cache layout
     # (models/generation.py), and its step is row-invariant — a row's logits
     # do not depend on the rows beside it, so strangers can share a decode
-    # step. The engine, the arena and the coalescer ask this, not the name.
+    # step. The engine and the arena ask this, not the name.
     engine_ready: bool = False
 
 

@@ -70,10 +70,9 @@ class LocalServingBackend(ServingBackend):
         batch_window_ms: float = 0.0,
         batch_max_size: int = 64,
         batch_max_inflight: int = 4,
-        generate_engine: str = "coalesce",
         generate_slots: int = 8,
         generate_chunk_tokens: int = 8,
-        kv_page_tokens: int = 0,
+        kv_page_tokens: int = 16,
         kv_arena_pages: int = 0,
         kv_share_prefix_bytes: int = 0,
         kv_paged_kernel: bool = True,
@@ -88,49 +87,36 @@ class LocalServingBackend(ServingBackend):
         prefill_chunk_tokens: int = 0,
     ) -> None:
         self.manager = manager
-        # engine-level speculative decoding: the continuous scheduler needs
-        # the draft RESIDENT to attach it, and residency is the backend's
-        # job (the engine has no ensure_servable) — _rest_generate ensure-
-        # loads this name alongside the target when the continuous engine
-        # is in play (set below; "" everywhere else)
-        self._spec_draft_name = ""
         # JAX dispatch is effectively serialized per device; a few workers
         # keep fetch/compile of different models overlapping inference.
         self._pool = ThreadPoolExecutor(max_workers=max_workers, thread_name_prefix="tpusc-serve")
         self._pool_wait_observers: dict[str, Any] = {}   # what -> histogram child's observe
-        # batch_window_ms > 0 enables the continuous batcher (batches form
-        # while the device is busy — no timed window exists anymore, the
-        # knob is the on/off switch; see runtime/batcher.py)
+        # batch_window_ms > 0 enables the :predict micro-batcher (batches
+        # form while the device is busy — no timed window exists anymore,
+        # the knob is the on/off switch; see runtime/batcher.py)
         if batch_window_ms > 0:
-            from tfservingcache_tpu.runtime.batcher import (
-                GenerateCoalescer,
-                MicroBatcher,
-            )
+            from tfservingcache_tpu.runtime.batcher import MicroBatcher
 
             self._predictor = MicroBatcher(
                 manager.runtime, max_batch=batch_max_size,
                 metrics=manager.metrics, max_inflight=batch_max_inflight,
             )
-            # concurrent :generate requests with matching buckets + sampling
-            # params coalesce into one prefill+decode program; generate runs
-            # for seconds, so its in-flight bound caps at 2 — but it still
-            # honors a stricter batch_max_inflight (1 = strict serialization)
-            self._generator = GenerateCoalescer(
-                manager.runtime, max_batch=min(batch_max_size, 32),
-                metrics=manager.metrics,
-                max_inflight=min(2, batch_max_inflight),
-            )
         else:
             self._predictor = manager.runtime
-            self._generator = None
-        # serving.generate_engine=continuous replaces whichever generator the
-        # batching knob picked with the slotted continuous-decode engine
-        # (step-boundary admission / early retirement; runtime/batcher.py).
-        # Only LOCKSTEP runtimes (cross-process groups, or meshes with
-        # serving.mesh_fast_path off) keep the coalescer now: a
-        # single-process mesh runs the engine on its KV-head-sharded arena
-        # (ISSUE 20), same rule as serving.cold_load_pipeline.
-        if generate_engine == "continuous" and not getattr(
+        # :generate has one engine (step-boundary admission / early
+        # retirement over the paged arena; runtime/batcher.py). Building it
+        # is free: a scheduler thread and an arena exist only from a model's
+        # first :generate. LOCKSTEP runtimes (cross-process groups, or
+        # meshes with serving.mesh_fast_path off) get none: their device-op
+        # stream must not depend on a host scheduler thread, so every
+        # request goes alone through runtime.generate.
+        self._generator = None
+        # engine-level speculative decoding: the scheduler needs the draft
+        # RESIDENT to attach it, and residency is the backend's job (the
+        # engine has no ensure_servable) — _prepare_generate's run()
+        # ensure-loads this name alongside the target
+        self._spec_draft_name = ""
+        if not getattr(
             manager.runtime, "mesh_lockstep",
             getattr(manager.runtime, "mesh", None) is not None,
         ):
@@ -205,7 +191,7 @@ class LocalServingBackend(ServingBackend):
             # both spellings: asyncio.TimeoutError is the builtin only since
             # 3.11, and with the deadline disabled this branch can still fire
             # via a builtin TimeoutError escaping the job (e.g. the generate
-            # coalescer's follower wait, a socket timeout in a provider)
+            # engine's wait for its rows, a socket timeout in a provider)
             bound = f"{timeout:.1f}s" if timeout else "an internal"
             raise BackendError(
                 f"{what} for {model_id} exceeded {bound} deadline",
@@ -739,7 +725,7 @@ class LocalServingBackend(ServingBackend):
             )
 
         # speculative decoding: resolve + ensure the draft alongside the
-        # target; such requests bypass the coalescer (their device program
+        # target; such requests bypass the engine (their device program
         # depends on the draft pairing, not just the request shape)
         draft_mid = None
         draft_spec = payload.get("draft_model")
@@ -780,8 +766,8 @@ class LocalServingBackend(ServingBackend):
 
         # SLO class (ISSUE 19): admission ordering + preemption rights in
         # the continuous engine; validated here so bad classes answer 400
-        # on every surface (the coalescer/solo paths accept-and-ignore it,
-        # priority has no meaning without a shared scheduler to contend on)
+        # on every surface (the solo path accepts and ignores it: priority
+        # has no meaning without a shared scheduler to contend on)
         priority = payload.get("priority", "normal")
         if isinstance(priority, bytes):
             priority = priority.decode("utf-8", "replace")
@@ -832,20 +818,12 @@ class LocalServingBackend(ServingBackend):
                 arr = np.asarray(ids, np.int32)
                 if gen is not None and draft_mid is None:
                     gkw = dict(kwargs)
-                    if conv_id is not None and getattr(
-                        gen, "conversation_tier", None
-                    ) is not None:
-                        # only the continuous engine understands the kwarg
-                        # (and only with the tier enabled) — the coalescer
-                        # keeps its narrower signature
+                    if conv_id is not None and gen.conversation_tier is not None:
                         gkw["conversation_id"] = conv_id
-                    if hasattr(gen, "prefill_chunk_tokens"):
-                        # continuous engine only: the coalescer has neither
-                        # priority classes nor a live token callback
-                        if priority != "normal":
-                            gkw["priority"] = priority
-                        if on_token is not None:
-                            gkw["on_token"] = on_token
+                    if priority != "normal":
+                        gkw["priority"] = priority
+                    if on_token is not None:
+                        gkw["on_token"] = on_token
                     try:
                         return gen.generate(
                             model_id, arr,
@@ -889,34 +867,34 @@ class LocalServingBackend(ServingBackend):
         Response: {"tokens": [[...]]}.
 
         "conversation_id" opts the request into the conversation KV tier
-        (serving.conversation_kv_bytes > 0, continuous engine only): the
+        (serving.conversation_kv_bytes > 0): the
         request's decode state parks under the id at retirement and the
         conversation's next turn resumes with a suffix-only prefill.
         Ignored (today's behavior exactly) when the tier is off or the
         request falls to the solo path.
 
-        "priority" (default "normal") orders continuous-engine admission by
+        "priority" (default "normal") orders the engine's admission by
         class and lets a "high" arrival preempt a lower-class decoding lane
-        when the page arena is full (ISSUE 19). Other engines accept and
-        ignore it — without a shared scheduler there is nothing to contend.
+        when the page arena is full (ISSUE 19). The solo path accepts and
+        ignores it — without a shared scheduler there is nothing to contend.
 
         ``?stream=true`` (single-row requests only) switches the response to
         Server-Sent Events over chunked transfer: one ``{"token": N}`` frame
         per generated token as it is sampled, then a terminal
         ``{"done": true, "tokens": [[...]]}`` frame carrying the same padded
-        matrix the buffered response would have returned. Engines without a
-        live token callback (coalescer, solo runtime) replay the finished
-        row as frames — same wire shape, no early delivery.
+        matrix the buffered response would have returned. The solo path has
+        no live token callback and replays the finished row as frames —
+        same wire shape, no early delivery.
 
         Omitting "seed" draws fresh entropy per request (distinct samples) and
-        lets concurrent same-shape requests coalesce into one device program;
-        pass an explicit seed for reproducible (solo) completions.
+        lets concurrent requests share the engine's decode steps; pass an
+        explicit seed for reproducible (solo) completions.
 
         "draft_model" enables greedy speculative decoding (temperature must
         be 0): the draft proposes spec_tokens tokens per round, the target
         verifies them in one chunked forward — output is bit-identical to
         the target's own greedy decode. Speculative requests run solo
-        (never coalesced).
+        (never through the engine).
 
         The whole buffered request — cold load AND the generate program — is
         deadline-bounded by the manager's ``load_timeout_s``: a hung or

@@ -22,8 +22,10 @@ The accumulation window is therefore exactly the device's own busy time:
     window-length tuning (the classic latency/throughput knob dissolves).
 
 Whether coalescing wins over independent dispatch is an empirical, shape-
-dependent question — bench.py measures warm QPS batcher on vs off with
-varied payloads.
+dependent question (config.py, ``batch_window_ms``: off by default).
+
+``:generate`` does not come through the gate: ``ContinuousGenerateEngine``
+below batches it at every decode-chunk boundary over the paged KV arena.
 
 Calls are thread-blocking by design — they arrive on the protocol backend's
 executor threads (protocol/local_backend.py), never on the event loop.
@@ -58,8 +60,9 @@ from tfservingcache_tpu.utils.tracing import TRACER, current_ids, host_span
 log = get_logger("runtime.batcher")
 
 
-# the coalescer predicts which runtime compile bucket a request lands in —
-# it must be the runtime's own bucketing function, not a copy that can drift
+# chunk and prefill-chunk sizes clamp to the runtime's compile buckets — its
+# own bucketing function, not a copy that can drift
+from tfservingcache_tpu.runtime.model_runtime import check_page_tokens
 from tfservingcache_tpu.runtime.model_runtime import next_bucket as _next_bucket
 
 
@@ -98,8 +101,8 @@ class _Gate:
 
 @lockchecked
 class _GateMap:
-    """Per-key device gates with bounded growth (shared by MicroBatcher and
-    GenerateCoalescer): bound how many batches per key are in flight so
+    """MicroBatcher's per-key device gates with bounded growth: bound how
+    many batches per key are in flight so
     arrivals during a saturated device accumulate into the next batch.
     Pruning keeps only in-use gates; losing an idle gate only costs a
     coalescing opportunity (or briefly exceeds the in-flight bound), never
@@ -367,326 +370,6 @@ class MicroBatcher:
             }
 
 
-@dataclass
-class _GenSlot:
-    ids: np.ndarray                       # (rows, s_i) int32 prompts
-    lengths: np.ndarray                   # (rows,) true prompt lengths
-    max_new: int
-    enqueue_t: float = field(default_factory=time.monotonic)
-    done: threading.Event = field(default_factory=threading.Event)
-    result: np.ndarray | None = None
-    error: BaseException | None = None
-
-
-@dataclass
-class _GenPending:
-    slots: list[_GenSlot] = field(default_factory=list)
-    rows: int = 0
-    closed: bool = False
-
-
-@lockchecked
-class GenerateCoalescer:
-    """Continuous batching for ``:generate`` — the verb LM clients actually
-    call (VERDICT r2 next-round #8). Same gate design as MicroBatcher: the
-    accumulation window is the device's own busy time, so sequential traffic
-    pays nothing and saturating traffic coalesces into one prefill+decode
-    program per batch.
-
-    Coalescing key: (model, prompt-seq bucket, new-token bucket, temperature,
-    top_k) — the runtime pads to the same buckets, so joiners share one
-    compiled program; sampling params must match because one program invokes
-    one (traced) temperature/top_k for every row. Requests with an explicit
-    ``seed`` NEVER coalesce: their contract is a reproducible solo sample
-    stream, which a shared batch draw would silently break.
-    """
-
-    _tpusc_guarded = {"_pending": "_lock"}
-
-    def __init__(
-        self,
-        runtime: BaseRuntime,
-        max_batch: int = 32,
-        wait_timeout_s: float = 600.0,
-        metrics=None,
-        max_inflight: int = 2,
-    ) -> None:
-        self.runtime = runtime
-        self.max_batch = max_batch
-        self.wait_timeout_s = wait_timeout_s
-        self.metrics = metrics
-        self._lock = threading.Lock()
-        self._pending: dict[tuple, _GenPending] = {}
-        # generate programs run for seconds: 2 in flight overlaps host prep
-        # with device decode without piling long jobs behind each other
-        self._gates = _GateMap(limit=max_inflight)
-        self.batches = 0
-        self.batched_requests = 0
-
-    def _gate(self, key: tuple) -> _Gate:
-        return self._gates.get(key)
-
-    def generate(
-        self,
-        model_id: ModelId,
-        input_ids: np.ndarray,
-        prompt_lengths: list[int] | None = None,
-        max_new_tokens: int = 32,
-        temperature: float = 0.0,
-        top_k: int = 0,
-        seed: int | None = None,
-    ) -> np.ndarray:
-        ids = np.asarray(input_ids, np.int32)
-        ready = getattr(self.runtime, "engine_ready_of", lambda _m: False)(model_id)
-        if ids.ndim == 2 and ready:
-            # oversized prompts must fail loudly AT SUBMIT (mirroring the
-            # continuous engine): before this check they joined a pending
-            # batch, the leader's drain raised for everyone, and joiners
-            # saw only an opaque timeout after wait_timeout_s
-            max_seq = getattr(
-                self.runtime, "max_seq_of", lambda _m: None
-            )(model_id)
-            if max_seq is not None and ids.shape[1] + max_new_tokens > max_seq:
-                raise ValueError(
-                    f"prompt {ids.shape[1]} + max_new_tokens "
-                    f"{max_new_tokens} exceeds max_seq {max_seq}"
-                )
-        if (
-            seed is not None
-            or ids.ndim != 2
-            or ids.shape[0] >= self.max_batch
-            or not ready
-        ):
-            # seeded = reproducible solo; malformed shapes fall through so the
-            # runtime raises its own clean error; a family co-batches only if
-            # its ModelDef declares a row-invariant step (engine_ready): one
-            # whose answers depend on the rows beside them would let
-            # co-batched strangers change THIS request's tokens
-            return self.runtime.generate(
-                model_id, ids, prompt_lengths=prompt_lengths,
-                max_new_tokens=max_new_tokens, temperature=temperature,
-                top_k=top_k, seed=seed if seed is not None else secrets.randbits(31),
-            )
-        rows, s = ids.shape
-        if prompt_lengths is None:
-            lengths = np.full((rows,), s, np.int32)
-        else:
-            lengths = np.asarray(prompt_lengths, np.int32)
-            if lengths.shape != (rows,) or (lengths < 1).any() or (lengths > s).any():
-                # invalid per-request params must fail ONLY this request: run
-                # solo so the runtime's clean error can't poison a batch of
-                # innocent coalesced callers
-                return self.runtime.generate(
-                    model_id, ids, prompt_lengths=prompt_lengths,
-                    max_new_tokens=max_new_tokens, temperature=temperature,
-                    top_k=top_k, seed=secrets.randbits(31),
-                )
-        key = (
-            model_id, _next_bucket(s), _next_bucket(max_new_tokens),
-            float(temperature), int(top_k),
-        )
-        slot = _GenSlot(ids=ids, lengths=lengths, max_new=max_new_tokens)
-        with self._lock:
-            pend = self._pending.get(key)
-            if pend is not None and pend.rows + rows > self.max_batch:
-                pend.closed = True
-                self._pending.pop(key, None)
-                pend = None
-            leader = pend is None
-            if leader:
-                pend = _GenPending()
-                self._pending[key] = pend
-            pend.slots.append(slot)
-            pend.rows += rows
-            if pend.rows >= self.max_batch:
-                pend.closed = True
-                self._pending.pop(key, None)
-        if self.metrics is not None:
-            self.metrics.batcher_queue_depth.labels("generate").inc()
-
-        if not leader:
-            if not slot.done.wait(self.wait_timeout_s):
-                raise TimeoutError(f"batched generate for {model_id} timed out")
-            if slot.error is not None:
-                raise slot.error
-            assert slot.result is not None
-            return slot.result
-
-        with self._gate(key):
-            with self._lock:
-                if not pend.closed:
-                    pend.closed = True
-                    self._pending.pop(key, None)
-            slots = pend.slots
-            if self.metrics is not None:
-                self.metrics.batcher_queue_depth.labels("generate").dec(len(slots))
-                # head-of-line stall, on the SAME metric the continuous
-                # engine records its slot wait: decoding starts on every
-                # joiner's behalf the moment its leader holds the gate
-                now = time.monotonic()
-                for sl in slots:
-                    self.metrics.gen_admission_wait.labels("coalesce").observe(
-                        max(0.0, now - sl.enqueue_t)
-                    )
-            try:
-                if len(slots) == 1:
-                    dev_t0 = time.monotonic()
-                    out = self.runtime.generate(
-                        model_id, slot.ids, prompt_lengths=list(slot.lengths),
-                        max_new_tokens=slot.max_new, temperature=temperature,
-                        top_k=top_k, seed=secrets.randbits(31),
-                    )
-                    dev_t1 = time.monotonic()
-                    slot.result = out
-                    wasted = self._observe_waste(model_id, [slot], slot.max_new)
-                    self._finish_drain(
-                        model_id, [slot], slot.max_new, dev_t0, dev_t1, wasted
-                    )
-                    return out
-                with TRACER.span(
-                    "generate_coalesce", model=str(model_id),
-                    requests=len(slots), rows=pend.rows,
-                ):
-                    s_max = max(sl.ids.shape[1] for sl in slots)
-                    cat = np.concatenate(
-                        [
-                            np.pad(sl.ids, ((0, 0), (0, s_max - sl.ids.shape[1])))
-                            for sl in slots
-                        ]
-                    )
-                    cat_len = np.concatenate([sl.lengths for sl in slots])
-                    dev_t0 = time.monotonic()
-                    toks = self.runtime.generate(
-                        model_id, cat, prompt_lengths=list(cat_len),
-                        max_new_tokens=max(sl.max_new for sl in slots),
-                        temperature=temperature, top_k=top_k,
-                        seed=secrets.randbits(31),
-                    )
-                    dev_t1 = time.monotonic()
-                    self.batches += 1
-                    self.batched_requests += len(slots)
-                    if self.metrics is not None:
-                        self.metrics.coalesced_batches.labels("generate").inc()
-                        self.metrics.coalesced_requests.labels("generate").inc(len(slots))
-                    lo = 0
-                    for sl in slots:
-                        hi = lo + sl.ids.shape[0]
-                        sl.result = toks[lo:hi, : sl.max_new]
-                        lo = hi
-                    wasted = self._observe_waste(
-                        model_id, slots, max(sl.max_new for sl in slots)
-                    )
-                    self._finish_drain(
-                        model_id, slots, max(sl.max_new for sl in slots),
-                        dev_t0, dev_t1, wasted,
-                    )
-                assert slot.result is not None
-                return slot.result
-            except BaseException as e:
-                for sl in slots:
-                    if sl is not slot and sl.result is None and sl.error is None:
-                        sl.error = e
-                        sl.done.set()
-                raise
-            finally:
-                for sl in slots:
-                    if sl is not slot:
-                        sl.done.set()
-
-    def _observe_waste(
-        self, model_id: ModelId, slots: list[_GenSlot], batch_max_new: int
-    ) -> int:
-        """Post-hoc padded-step accounting: the batch's scan computed
-        ``next_bucket(batch_max_new)`` decode steps for EVERY row, so a row
-        that hit EOS (when the model declares one) or whose own max_new was
-        below the batch's kept burning steps until the drain. An estimate —
-        the runtime falls back to exact sizes on bucket overshoot — but the
-        comparison the metric exists for (coalesce vs continuous on one
-        workload) uses models/workloads where the bucket estimate is exact.
-        Returns the wasted-step count (the flight ring records it too)."""
-        eos = getattr(self.runtime, "eos_id_of", lambda _m: None)(model_id)
-        steps = _next_bucket(batch_max_new)
-        wasted = 0
-        for sl in slots:
-            if sl.result is None:
-                continue
-            for row in np.asarray(sl.result):
-                useful = row.shape[0]
-                if eos is not None:
-                    hits = np.flatnonzero(row == eos)
-                    if hits.size:
-                        useful = int(hits[0]) + 1
-                wasted += steps - useful
-        if wasted > 0 and self.metrics is not None:
-            self.metrics.gen_wasted_steps.labels("coalesce").inc(wasted)
-        return wasted
-
-    def _finish_drain(
-        self,
-        model_id: ModelId,
-        slots: list[_GenSlot],
-        batch_max_new: int,
-        dev_t0: float,
-        dev_t1: float,
-        wasted: int,
-    ) -> None:
-        """Flight-ring entry + phase clocks for one batch drain. The
-        coalescer's analogue of the continuous engine's chunk boundary:
-        every member admits at gate acquisition and retires at the drain,
-        so admitted == retired == the batch size. Phases: queue = gate
-        stall (the same value gen_admission_wait observed), decode = the
-        batched device call (prefill is not separable from decode inside
-        the fused generate program), respond = scatter back to rows."""
-        end_t = time.monotonic()
-        rows = sum(sl.ids.shape[0] for sl in slots)
-        # cost ledger: the batched device call's wall time lands on this
-        # tenant as decode (prefill is fused into the generate program and
-        # not separable); tokens_out excludes the padded-step waste.
-        LEDGER.note_step(
-            str(model_id), "coalesce",
-            decode_s=max(0.0, dev_t1 - dev_t0),
-            tokens_in=sum(
-                sl.ids.shape[0] * sl.ids.shape[1] for sl in slots
-            ),
-            tokens_out=max(0, rows * _next_bucket(batch_max_new) - wasted),
-        )
-        RECORDER.record(
-            str(model_id), "coalesce",
-            step_ms=(dev_t1 - dev_t0) * 1e3,
-            chunk=_next_bucket(batch_max_new),
-            active=rows, admitted=len(slots), retired=len(slots),
-            wasted=wasted,
-        )
-        ids_ctx = current_ids()
-        for sl in slots:
-            phases = {
-                "queue": max(0.0, dev_t0 - sl.enqueue_t),
-                "decode": dev_t1 - dev_t0,
-                "respond": max(0.0, end_t - dev_t1),
-            }
-            if self.metrics is not None:
-                for ph, v in phases.items():
-                    # the coalescer predates priority classes: everything
-                    # it serves is class=normal
-                    self.metrics.observe_phase(ph, "coalesce", "normal", v)
-            RECORDER.note_phases(
-                str(model_id), "coalesce", phases,
-                trace_id=ids_ctx[0] if ids_ctx else None,
-            )
-        TRACER.annotate_root(
-            priority="normal",  # the coalescer has no priority classes
-            # first token materializes when the whole batch lands
-            ttft_ms=round(
-                max(0.0, dev_t1 - min(sl.enqueue_t for sl in slots)) * 1e3, 3
-            ),
-            phase_queue_ms=round(
-                max(0.0, dev_t0 - min(sl.enqueue_t for sl in slots)) * 1e3, 3
-            ),
-            phase_decode_ms=round((dev_t1 - dev_t0) * 1e3, 3),
-            phase_respond_ms=round(max(0.0, end_t - dev_t1) * 1e3, 3),
-        )
-
-
 # priority classes for the continuous engine's SLO-aware admission
 # (REST/gRPC `priority`, default normal): rank order is what admission and
 # preemption compare — smaller rank wins pages
@@ -770,7 +453,7 @@ class _ContinuousScheduler:
         self.pending: collections.deque[_ContinuousReq] = collections.deque()
         self.stopped = False
         # speculative decoding (ISSUE 16): set when the configured draft
-        # pair turned out structurally incompatible (family/vocab/dense) —
+        # pair turned out structurally incompatible (family/vocab) —
         # permanent for this scheduler, so the warning logs once and every
         # later boundary decodes plain without re-raising. Scheduler-thread
         # only, like `lanes`/`state`.
@@ -879,8 +562,6 @@ class _ContinuousScheduler:
                 state.spec_tokens = 0
             return
         if self._spec_broken or state is None:
-            return
-        if not getattr(state, "paged", False):
             return
         if not hasattr(rt, "slot_attach_draft"):
             return
@@ -1072,161 +753,159 @@ class _ContinuousScheduler:
                     kind = None
                     resume = None   # (parked, covered, n_pages) when resuming
                     share = getattr(state, "prefix_index", None) is not None
-                    if getattr(state, "paged", False):
-                        # admission is gated on free PAGES, not just free lanes:
-                        # the row's whole prompt + max_new budget is reserved up
-                        # front so a mid-decode row can never starve for a page.
-                        # With a draft attached the budget grows by spec_tokens
-                        # of headroom — a verify round started one token short
-                        # of max_new still writes K/V rows at pos..pos+spec, and
-                        # those writes must land on pages this row owns (never
-                        # shared/trash), so the overshoot is reserved up front
-                        # and handed back through release_pages at retirement.
-                        headroom = state.spec_tokens if d_st is not None else 0
-                        budget = min(p + remaining + headroom,
-                                     state.pages_per_slot * state.page_tokens)
-                        need = state.pages_needed(budget)
-                        if need > state.arena_pages:
-                            req.error = RuntimeError_(
-                                f"request needs {need} KV pages "
-                                f"({budget} tokens) but the arena has only "
-                                f"{state.arena_pages}"
-                            )
-                            req.done.set()
-                            continue
-                        idx = free[-1]  # the lane free.pop() will hand out below
-                        shared_pages = ()
-                        cow_headroom = 0
-                        if req.preempt_parked is not None and \
-                                hasattr(rt, "plan_conversation_resume"):
-                            # preempted row coming back: its own parked pages
-                            # beat both the conversation tier and the radix
-                            # index — they cover prompt + every emitted token,
-                            # so the resume prefill is O(1) (the single row the
-                            # park could not cover)
+                    # admission is gated on free PAGES, not just free lanes:
+                    # the row's whole prompt + max_new budget is reserved up
+                    # front so a mid-decode row can never starve for a page.
+                    # With a draft attached the budget grows by spec_tokens
+                    # of headroom — a verify round started one token short
+                    # of max_new still writes K/V rows at pos..pos+spec, and
+                    # those writes must land on pages this row owns (never
+                    # shared/trash), so the overshoot is reserved up front
+                    # and handed back through release_pages at retirement.
+                    headroom = state.spec_tokens if d_st is not None else 0
+                    budget = min(p + remaining + headroom,
+                                 state.pages_per_slot * state.page_tokens)
+                    need = state.pages_needed(budget)
+                    if need > state.arena_pages:
+                        req.error = RuntimeError_(
+                            f"request needs {need} KV pages "
+                            f"({budget} tokens) but the arena has only "
+                            f"{state.arena_pages}"
+                        )
+                        req.done.set()
+                        continue
+                    idx = free[-1]  # the lane free.pop() will hand out below
+                    shared_pages = ()
+                    cow_headroom = 0
+                    if req.preempt_parked is not None and \
+                            hasattr(rt, "plan_conversation_resume"):
+                        # preempted row coming back: its own parked pages
+                        # beat both the conversation tier and the radix
+                        # index — they cover prompt + every emitted token,
+                        # so the resume prefill is O(1) (the single row the
+                        # park could not cover)
+                        rplan = rt.plan_conversation_resume(
+                            state, prompt, req.preempt_parked
+                        )
+                        if rplan is not None:
+                            resume = (req.preempt_parked, rplan[0], rplan[1])
+                    if resume is None and req.conversation_id and \
+                            eng.conversation_tier is not None and \
+                            hasattr(rt, "plan_conversation_resume"):
+                        # resume beats cold prefill AND the shared-prefix
+                        # plan: parked pages cover the whole history (prompt
+                        # + prior turns' emitted tokens), where the radix
+                        # index at best covers what is still arena-resident.
+                        # The lookup PEEKS, so a lane that crashes mid-decode
+                        # can resume again from the same ancestor.
+                        parked, _outcome = eng.conversation_tier.get(
+                            req.conversation_id, str(self.model_id)
+                        )
+                        if parked is not None:
                             rplan = rt.plan_conversation_resume(
-                                state, prompt, req.preempt_parked
+                                state, prompt, parked
                             )
                             if rplan is not None:
-                                resume = (req.preempt_parked, rplan[0], rplan[1])
-                        if resume is None and req.conversation_id and \
-                                eng.conversation_tier is not None and \
-                                hasattr(rt, "plan_conversation_resume"):
-                            # resume beats cold prefill AND the shared-prefix
-                            # plan: parked pages cover the whole history (prompt
-                            # + prior turns' emitted tokens), where the radix
-                            # index at best covers what is still arena-resident.
-                            # The lookup PEEKS, so a lane that crashes mid-decode
-                            # can resume again from the same ancestor.
-                            parked, _outcome = eng.conversation_tier.get(
-                                req.conversation_id, str(self.model_id)
+                                resume = (parked, rplan[0], rplan[1])
+                    if share and resume is None:
+                        plan = rt.shared_prefix_plan(state, prompt)
+                        if plan is not None:
+                            # map the indexed prefix read-only; reserve only
+                            # the private remainder. An exact hit with a
+                            # mid-page tail also needs one CoW page in hand
+                            # — its first decode write lands in the shared
+                            # boundary page.
+                            shared_pages = plan.mapped_pages()
+                            if plan.kind == "exact" and plan.tail_len > 0:
+                                cow_headroom = 1
+                    ok = state.reserve_pages(
+                        idx, budget, shared_pages, cow_headroom
+                    )
+                    if not ok and share:
+                        # page pressure: cold index-only prefix pages must
+                        # lose the fight to a live admission (protecting the
+                        # plan's own mapped pages), else sharing would turn
+                        # the blocks-never-fails queue into a deadlock
+                        want = (max(0, need - len(shared_pages)) + cow_headroom
+                                - len(state.free_pages))
+                        if want > 0 and rt.reclaim_prefix_pages(
+                            state, want, shared_pages
+                        ):
+                            ok = state.reserve_pages(
+                                idx, budget, shared_pages, cow_headroom
                             )
-                            if parked is not None:
-                                rplan = rt.plan_conversation_resume(
-                                    state, prompt, parked
-                                )
-                                if rplan is not None:
-                                    resume = (parked, rplan[0], rplan[1])
-                        if share and resume is None:
-                            plan = rt.shared_prefix_plan(state, prompt)
-                            if plan is not None:
-                                # map the indexed prefix read-only; reserve only
-                                # the private remainder. An exact hit with a
-                                # mid-page tail also needs one CoW page in hand
-                                # — its first decode write lands in the shared
-                                # boundary page.
-                                shared_pages = plan.mapped_pages()
-                                if plan.kind == "exact" and plan.tail_len > 0:
-                                    cow_headroom = 1
-                        ok = state.reserve_pages(
-                            idx, budget, shared_pages, cow_headroom
+                    if ok and d_st is not None:
+                        # the draft arena mirrors the reservation (its rows
+                        # for pos..pos+spec are written every round). No
+                        # shared pages: the draft state has no prefix index,
+                        # every draft page is private by construction. The
+                        # cap keeps a shorter draft max_seq from deadlocking
+                        # (the auto-sized draft arena always covers slots x
+                        # pages_per_slot, so a capped reservation succeeds
+                        # whenever the lane itself is free).
+                        d_budget = min(
+                            budget, d_st.pages_per_slot * d_st.page_tokens
                         )
-                        if not ok and share:
-                            # page pressure: cold index-only prefix pages must
-                            # lose the fight to a live admission (protecting the
-                            # plan's own mapped pages), else sharing would turn
-                            # the blocks-never-fails queue into a deadlock
-                            want = (max(0, need - len(shared_pages)) + cow_headroom
-                                    - len(state.free_pages))
-                            if want > 0 and rt.reclaim_prefix_pages(
-                                state, want, shared_pages
+                        if not d_st.reserve_pages(idx, d_budget):
+                            state.release_pages(idx)
+                            ok = False
+                    if not ok and hasattr(rt, "park_lane"):
+                        # priority preemption (ISSUE 19): a higher-class
+                        # arrival that still can't reserve parks the
+                        # lowest-class decoding lane's KV (pages are COPIES
+                        # through the PR 18 codec, so the conservation
+                        # census stays exact), requeues it for an
+                        # O(new tokens) parked-KV resume, and retries the
+                        # reservation. One victim may not free enough —
+                        # keep hunting until the reserve succeeds or no
+                        # preemptible lane remains.
+                        while not ok:
+                            vidx = self._pick_victim(lanes, req)
+                            if vidx is None or not self._preempt(
+                                rt, state, lanes, vidx
                             ):
-                                ok = state.reserve_pages(
-                                    idx, budget, shared_pages, cow_headroom
-                                )
-                        if ok and d_st is not None:
-                            # the draft arena mirrors the reservation (its rows
-                            # for pos..pos+spec are written every round). No
-                            # shared pages: the draft state has no prefix index,
-                            # every draft page is private by construction. The
-                            # cap keeps a shorter draft max_seq from deadlocking
-                            # (the auto-sized draft arena always covers slots x
-                            # pages_per_slot, so a capped reservation succeeds
-                            # whenever the lane itself is free).
-                            d_budget = min(
-                                budget, d_st.pages_per_slot * d_st.page_tokens
+                                break
+                            # the victim's lane frees too — at the FRONT of
+                            # the free list, so free[-1] (the lane reserved
+                            # as `idx` above) is untouched
+                            free.insert(0, vidx)
+                            ok = state.reserve_pages(
+                                idx, budget, shared_pages, cow_headroom
                             )
-                            if not d_st.reserve_pages(idx, d_budget):
-                                state.release_pages(idx)
-                                ok = False
-                        if not ok and hasattr(rt, "park_lane"):
-                            # priority preemption (ISSUE 19): a higher-class
-                            # arrival that still can't reserve parks the
-                            # lowest-class decoding lane's KV (pages are COPIES
-                            # through the PR 18 codec, so the conservation
-                            # census stays exact), requeues it for an
-                            # O(new tokens) parked-KV resume, and retries the
-                            # reservation. One victim may not free enough —
-                            # keep hunting until the reserve succeeds or no
-                            # preemptible lane remains.
-                            while not ok:
-                                vidx = self._pick_victim(lanes, req)
-                                if vidx is None or not self._preempt(
-                                    rt, state, lanes, vidx
-                                ):
+                            if ok and d_st is not None:
+                                d_budget = min(
+                                    budget,
+                                    d_st.pages_per_slot * d_st.page_tokens,
+                                )
+                                if not d_st.reserve_pages(idx, d_budget):
+                                    state.release_pages(idx)
+                                    ok = False
                                     break
-                                # the victim's lane frees too — at the FRONT of
-                                # the free list, so free[-1] (the lane reserved
-                                # as `idx` above) is untouched
-                                free.insert(0, vidx)
-                                ok = state.reserve_pages(
-                                    idx, budget, shared_pages, cow_headroom
-                                )
-                                if ok and d_st is not None:
-                                    d_budget = min(
-                                        budget,
-                                        d_st.pages_per_slot * d_st.page_tokens,
-                                    )
-                                    if not d_st.reserve_pages(idx, d_budget):
-                                        state.release_pages(idx)
-                                        ok = False
-                                        break
-                        if not ok:
-                            # arena exhausted: the queue BLOCKS, never fails —
-                            # the row goes back to the FRONT (FIFO preserved)
-                            # and retirements below recycle pages for the next
-                            # chunk boundary's retry. Can't deadlock: with no
-                            # active lanes every page is free or reclaimable
-                            # from the prefix index, and need <= arena_pages
-                            # was checked above.
-                            with self.cv:
-                                self.pending.appendleft(req)
-                                if eng.metrics is not None:
-                                    eng.metrics.batcher_queue_depth.labels(
-                                        "generate"
-                                    ).inc()
-                            RECORDER.dump(
-                                "page_exhaustion", model=str(self.model_id),
-                                needed_pages=need, free_pages=len(state.free_pages),
-                                arena_pages=state.arena_pages,
-                            )
-                            break
-                        reserved_idx = idx
+                    if not ok:
+                        # arena exhausted: the queue BLOCKS, never fails —
+                        # the row goes back to the FRONT (FIFO preserved)
+                        # and retirements below recycle pages for the next
+                        # chunk boundary's retry. Can't deadlock: with no
+                        # active lanes every page is free or reclaimable
+                        # from the prefix index, and need <= arena_pages
+                        # was checked above.
+                        with self.cv:
+                            self.pending.appendleft(req)
+                            if eng.metrics is not None:
+                                eng.metrics.batcher_queue_depth.labels(
+                                    "generate"
+                                ).inc()
+                        RECORDER.dump(
+                            "page_exhaustion", model=str(self.model_id),
+                            needed_pages=need, free_pages=len(state.free_pages),
+                            arena_pages=state.arena_pages,
+                        )
+                        break
+                    reserved_idx = idx
                     pf0 = time.monotonic()
                     seed = secrets.randbits(31)
                     if (
-                        reserved_idx is not None
-                        and eng.prefill_chunk_tokens > 0
+                        eng.prefill_chunk_tokens > 0
                         and resume is None and plan is None and d_st is None
                         and p > eng.prefill_chunk_tokens
                         and hasattr(rt, "slot_prefill_chunk")
@@ -1262,7 +941,7 @@ class _ContinuousScheduler:
                             ).observe(max(0.0, now - req.enqueue_t))
                         continue
                     with host_span("prefill"):
-                        if resume is not None and reserved_idx is not None:
+                        if resume is not None:
                             # O(new tokens) turn resume: parked pages re-import into
                             # the lane's private reservation, only the suffix past
                             # the common history prefix runs through prefill
@@ -1286,7 +965,7 @@ class _ContinuousScheduler:
                                 req.top_k, seed=seed,
                             )
                             last = None
-                        if d_st is not None and reserved_idx is not None:
+                        if d_st is not None:
                             # greedy draft prefill (temperature 0, sampled token
                             # ignored — only the draft's K/V rows matter). Runs even
                             # on an exact target prefix hit: the draft arena has no
@@ -1345,8 +1024,7 @@ class _ContinuousScheduler:
                     )
                 if (eos is not None and int(tok) == eos) or remaining <= 1:
                     # done at prefill: the lane was never consumed
-                    if reserved_idx is not None:
-                        self._retire_pages(state, reserved_idx, req)
+                    self._retire_pages(state, reserved_idx, req)
                     req.finish_t = now
                     req.done.set()
                     retired_n += 1
@@ -1447,8 +1125,7 @@ class _ContinuousScheduler:
             )
         )
         spec_span = state.spec_tokens if use_spec else 0
-        if getattr(state, "paged", False) and \
-                getattr(state, "page_refs", None) is not None:
+        if getattr(state, "page_refs", None) is not None:
             # copy-on-write safety net: no lane may write into a page it
             # doesn't solely own. Admission already CoW'd the only shareable
             # write target (the exact-hit boundary page) and a chunk only
@@ -1514,14 +1191,12 @@ class _ContinuousScheduler:
                     if (eos is not None and t == eos) or len(req.tokens) >= req.max_new:
                         # retire NOW: steps the chunk computed past this point
                         # were for a finished request — the waste continuous
-                        # batching exists to bound (< chunk, vs batch-drain
-                        # padding under coalesce). Under spec this also drops
-                        # accepted tokens past a mid-round EOS.
+                        # batching exists to bound (< chunk). Under spec this
+                        # also drops accepted tokens past a mid-round EOS.
                         wasted += n_emit - (j + 1)
                         state.active[idx] = False
                         lanes[idx] = None
-                        if getattr(state, "paged", False):
-                            self._retire_pages(state, idx, req)
+                        self._retire_pages(state, idx, req)
                         req.finish_t = now
                         req.done.set()
                         retired_n += 1
@@ -1581,10 +1256,7 @@ class _ContinuousScheduler:
                 eng.metrics.moe_assignments.labels(label).inc(
                     active * chunk * dict(state.cfg_key).get("top_k", 1))
                 eng.metrics.moe_expert_rows.labels(label).observe(moe_stats[1])
-        paged = state is not None and getattr(state, "paged", False)
-        shared = 0
-        if paged and hasattr(state, "page_stats"):
-            shared = state.page_stats()["shared"]
+        shared = state.page_stats()["shared"]
         now = time.monotonic()
         # cost ledger: the whole boundary's wall time lands on this tenant
         # (each scheduler thread is single-model); the prefill clock sum is
@@ -1607,10 +1279,8 @@ class _ContinuousScheduler:
             str(self.model_id), "continuous",
             step_ms=(now - step_t0) * 1e3,
             chunk=chunk, active=active, admitted=admitted, retired=retired,
-            pages_used=(
-                state.arena_pages - len(state.free_pages) if paged else 0
-            ),
-            pages_free=len(state.free_pages) if paged else 0,
+            pages_used=state.arena_pages - len(state.free_pages),
+            pages_free=len(state.free_pages),
             wasted=wasted, queue_depth=depth, oldest_wait_ms=wait_ms,
             pages_shared=shared, prefix_hits=prefix_hits,
             drafted=drafted, accepted=accepted,
@@ -1632,7 +1302,6 @@ class _ContinuousScheduler:
         if (
             req.conversation_id
             and eng.conversation_tier is not None
-            and getattr(state, "paged", False)
             and hasattr(eng.runtime, "park_lane")
         ):
             # park BEFORE release: export needs the lane's page mapping.
@@ -1770,7 +1439,7 @@ class _ContinuousScheduler:
         requeue the row (priority preemption). The parked pages are COPIES:
         release_pages hands the originals back through the normal free
         list, so the conservation census never sees a discrepancy. Returns
-        False when the lane can't be parked (dense state, codec mismatch) —
+        False when the lane can't be parked (nothing valid to export yet) —
         the caller stops hunting victims then."""
         eng = self.engine
         victim = lanes[vidx]
@@ -1823,43 +1492,36 @@ class _ContinuousScheduler:
         return True
 
     def _update_page_gauge(self, state) -> None:
-        if state is not None and getattr(state, "paged", False):
-            if hasattr(state, "page_stats"):
-                # DISTINCT pages only: a prefix page mapped by N lanes
-                # counts once, and index-only ("cached") pages are excluded
-                # — they are reclaimable on demand, so counting them would
-                # under-report admission headroom (NodeStatus routes on it)
-                ps = state.page_stats()
-                used, shared = ps["shared"] + ps["private"], ps["shared"]
-            else:
-                used = state.arena_pages - len(state.free_pages)
-                shared = 0
+        if state is not None:
+            # DISTINCT pages only: a prefix page mapped by N lanes counts
+            # once, and index-only ("cached") pages are excluded — they are
+            # reclaimable on demand, so counting them would under-report
+            # admission headroom (NodeStatus routes on it)
+            ps = state.page_stats()
             self.engine._set_pages(
-                self.model_id, used, state.arena_pages, shared
+                self.model_id, ps["shared"] + ps["private"],
+                state.arena_pages, ps["shared"],
             )
 
 
 @lockchecked
 class ContinuousGenerateEngine:
-    """Iteration-level continuous batching for ``:generate`` — the vLLM-/
-    DeepServe-style alternative to GenerateCoalescer, selected via
-    ``serving.generate_engine=continuous``.
+    """Iteration-level continuous batching for ``:generate`` (vLLM-/
+    DeepServe-style), the one batching engine of this program.
 
-    Where the coalescer decides membership once at batch-formation time
-    (a request arriving 50 ms after launch waits out the whole fixed-length
-    scan, and early-EOS rows burn padded steps until the drain), this
-    engine keeps a fixed-capacity slot array per model (static shapes — one
-    compiled decode-chunk program regardless of which lanes are live) and
-    makes both decisions at every chunk boundary: pending rows admit into
-    free lanes (prompt prefilled via the prefix-cache-aware slot prefill),
-    finished rows retire immediately.
+    It keeps a fixed number of lanes per model over a paged KV arena
+    (static shapes — one compiled decode-chunk program regardless of which
+    lanes are live) and decides membership at every chunk boundary: pending
+    rows admit into free lanes once their pages are reserved (prompt
+    prefilled via the prefix-cache-aware slot prefill), finished rows
+    retire immediately and hand their pages back.
 
-    Scope mirrors the coalescer's exclusions: explicitly seeded requests
-    (reproducible solo stream), families not engine_ready, malformed
-    params, and LOCKSTEP mesh runtimes (``runtime.mesh_lockstep`` — a
-    cross-process group's device-op stream must not depend on a host
-    scheduler thread) all fall through to ``runtime.generate``. A
-    single-process mesh runs here on its KV-head-sharded arena (ISSUE 20),
+    What it cannot take falls through to ``runtime.generate``, the solo
+    decoder: explicitly seeded requests (a reproducible stream of their
+    own), families not engine_ready, malformed params, and LOCKSTEP mesh
+    runtimes (``runtime.mesh_lockstep`` — a cross-process group's device-op
+    stream must not depend on a host scheduler thread). A single-process
+    mesh runs here on its KV-head-sharded arena (ISSUE 20),
     greedy-parity-pinned against the single-device path by
     tests/test_mesh_parity.py.
     """
@@ -1899,11 +1561,13 @@ class ContinuousGenerateEngine:
         self.chunk_tokens = max(1, int(chunk_tokens))
         self.wait_timeout_s = wait_timeout_s
         self.metrics = metrics
-        # paged-KV knobs forwarded to slot_decode_state: None = defer to the
+        # arena knobs forwarded to slot_decode_state: None = defer to the
         # runtime's ServingConfig (kv_page_tokens / kv_arena_pages /
-        # kv_share_prefix_bytes), 0 = explicit dense / sharing off, > 0 =
-        # paged with this page size / arena size / prefix-index byte budget
-        self.page_tokens = None if page_tokens is None else int(page_tokens)
+        # kv_share_prefix_bytes); page size >= 1, arena_pages 0 = auto-size,
+        # share_prefix_bytes 0 = sharing off
+        self.page_tokens = (
+            None if page_tokens is None else check_page_tokens(page_tokens)
+        )
         self.arena_pages = None if arena_pages is None else int(arena_pages)
         self.share_prefix_bytes = (
             None if share_prefix_bytes is None else int(share_prefix_bytes)
@@ -1991,10 +1655,10 @@ class ContinuousGenerateEngine:
         # engine-monotonic submit sequence — the FIFO half of admission's
         # (rank, seq) order; preserved across preemption/crash requeues
         self._seq = 0
-        # observability (tests + bench)
+        # observability (tests + drives)
         self.admitted = 0
         self.chunks = 0
-        self.peak_active = 0  # high-water concurrent lanes (bench headline)
+        self.peak_active = 0  # high-water concurrent lanes
 
     def _set_active(self, model_id: ModelId, n: int) -> None:
         with self._lock:
@@ -2104,13 +1768,12 @@ class ContinuousGenerateEngine:
         priority: str = "normal",
         on_token: Callable[[int], None] | None = None,
     ) -> np.ndarray:
-        """Drop-in for GenerateCoalescer.generate: (rows, max_new_tokens)
-        int32. A row that hit EOS early is zero-padded after it (the solo
-        path has no EOS concept and always fills max_new_tokens — identical
-        when the model declares no eos_id). ``return_stats`` additionally
-        returns per-row timing dicts (ttft_s, admission_wait_s, tokens,
-        prefill_tokens, priority, preemptions) — the bench's streaming-TTFT
-        surface.
+        """-> (rows, max_new_tokens) int32. A row that hit EOS early is
+        zero-padded after it (the solo path has no EOS concept and always
+        fills max_new_tokens — identical when the model declares no
+        eos_id). ``return_stats`` additionally returns per-row timing dicts
+        (ttft_s, admission_wait_s, tokens, prefill_tokens, priority,
+        preemptions) — the streaming-TTFT surface of drives and tests.
 
         ``priority`` ("high" | "normal" | "low") orders admission by class
         then FIFO and arms preemption: a high-class arrival finding no free
@@ -2138,9 +1801,8 @@ class ContinuousGenerateEngine:
         ids = np.asarray(input_ids, np.int32)
         ready = getattr(self.runtime, "engine_ready_of", lambda _m: False)(model_id)
         # mesh_lockstep (ISSUE 20): only CROSS-PROCESS groups (or meshes
-        # with serving.mesh_fast_path off) fall back to the solo/coalesce
-        # path now — a single-process mesh runs the continuous paged engine
-        # on its sharded arena
+        # with serving.mesh_fast_path off) fall back to the solo path — a
+        # single-process mesh runs the engine on its sharded arena
         solo = (
             seed is not None
             or getattr(
@@ -2171,6 +1833,16 @@ class ContinuousGenerateEngine:
                 or top_k < 0
             ):
                 solo = True
+        if not solo:
+            # an oversized prompt fails loudly AT SUBMIT, in the caller's
+            # thread, not after it queued behind other rows for a lane
+            max_seq = getattr(self.runtime, "max_seq_of", lambda _m: None)(model_id)
+            longest = int(lengths.max())
+            if max_seq is not None and longest + max_new_tokens > max_seq:
+                raise ValueError(
+                    f"prompt {longest} + max_new_tokens {max_new_tokens} "
+                    f"exceeds max_seq {max_seq}"
+                )
         if solo:
             out = self.runtime.generate(
                 model_id, ids, prompt_lengths=prompt_lengths,

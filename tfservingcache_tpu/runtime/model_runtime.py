@@ -53,6 +53,18 @@ def next_bucket(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def check_page_tokens(n: Any) -> int:
+    """``serving.kv_page_tokens`` as an int, refused below 1: the paged arena
+    is the only KV layout, so there is nothing a 0 could fall back to."""
+    n = int(n)
+    if n < 1:
+        raise ValueError(
+            "serving.kv_page_tokens must be >= 1 (the KV arena's page size "
+            f"in tokens), got {n}"
+        )
+    return n
+
+
 # Speculative-decoding worst case (VERDICT r5 #6): at acceptance ~0 every
 # verify round still pays spec_tokens draft forwards + one chunked target
 # forward to emit ONE token — strictly more target work per token than plain
@@ -648,13 +660,10 @@ class LoadedModel:
 
 @dataclass
 class SlotDecodeState:
-    """Device + host state of one model's continuous-decode slot array
-    (runtime/batcher.py ContinuousGenerateEngine). Dense mode
-    (``page_tokens == 0``): the K/V arrays are (layers, S, n_kv, max_seq,
-    head_dim) — one lane per slot, advanced by ``_decode_chunk_jit`` and
-    surgically written by admission inserts. Paged mode: ``k``/``v`` hold
-    the shared page arena (layers, arena_pages + 1, n_kv, page_tokens, hd)
-    — page 0 is the trash page — and each lane reads/writes through its
+    """Device + host state of one model's continuous-decode lanes
+    (runtime/batcher.py ContinuousGenerateEngine). ``k``/``v`` hold the
+    shared page arena (layers, arena_pages + 1, n_kv, page_tokens, hd) —
+    page 0 is the trash page — and each lane reads/writes through its
     ``block_tables`` row; the free-list hands pages out at admission and
     recycles them at retirement. The host mirrors (tok/pos/active/temps/
     topks, block tables, free-list) are owned by the engine's scheduler
@@ -665,7 +674,7 @@ class SlotDecodeState:
     family: str
     slots: int
     max_seq: int
-    k: Any                           # device slot array OR paged arena
+    k: Any                           # device page arena
     v: Any
     tok: np.ndarray                  # (S,) i32 — last sampled token per lane
     pos: np.ndarray                  # (S,) i32 — next write position
@@ -676,13 +685,12 @@ class SlotDecodeState:
     # the last decode chunk's routing stats of a model with expert layers:
     # (experts_hit, expert_rows_max), chunk means; None for a dense model
     moe_stats: tuple | None = None
-    # -- paged-arena bookkeeping (scheduler-thread-owned; page_tokens == 0
-    # means dense mode and none of these are consulted) --
-    page_tokens: int = 0
+    # -- arena bookkeeping (scheduler-thread-owned) --
+    page_tokens: int = 0             # tokens a page; >= 1 in a built state
     arena_pages: int = 0             # usable pages (excludes trash page 0)
     pages_per_slot: int = 0          # ceil(max_seq / page_tokens)
     # int8 arena (serving.kv_arena_dtype): per-row f32 scale buffers riding
-    # with the page payload ({"k","v"} device arrays, None for dense dtype).
+    # with the page payload ({"k","v"} device arrays, None for model dtype).
     # All page bookkeeping above is PAGE-COUNT based, so quantization never
     # touches reserve/release/CoW/census semantics — scales just travel with
     # every page write/copy.
@@ -713,10 +721,6 @@ class SlotDecodeState:
     spec_draft_id: Any = None        # ModelId of the attached draft
     spec_draft: Any = None           # the draft's SlotDecodeState
     spec_tokens: int = 0             # draft proposals per verify round
-
-    @property
-    def paged(self) -> bool:
-        return self.page_tokens > 0
 
     def pages_needed(self, tokens: int) -> int:
         return -(-int(tokens) // self.page_tokens)
@@ -822,8 +826,6 @@ class SlotDecodeState:
         agreeing with the actual lane + index reference census — i.e. no
         page is leaked and none is double-booked. Test/bench hook (cheap:
         O(arena), host-only)."""
-        if not self.paged:
-            return
         census = np.zeros(self.arena_pages + 1, np.int64)
         for pages in self.lane_pages.values():
             for pg in pages:
@@ -1501,7 +1503,7 @@ class TPUModelRuntime(BaseRuntime):
         """True when this runtime's device-op stream must stay LOCKSTEP — a
         pure function of the request sequence, never of host thread timing
         or per-process residency — which is what actually forces the
-        serialized-load/coalesce-generate fallbacks. Before ISSUE 20 every
+        serialized-load/solo-generate fallbacks. Before ISSUE 20 every
         mesh runtime was lockstep; now only cross-process groups are (each
         follower must replay the leader's exact op stream), plus any mesh
         with ``serving.mesh_fast_path`` off (the A/B lever). Consumers:
@@ -2027,11 +2029,10 @@ class TPUModelRuntime(BaseRuntime):
     ) -> SlotDecodeState:
         """Create-or-get the model's slot state. One compiled decode-chunk
         program serves all ``slots`` lanes. ``page_tokens`` / ``arena_pages``
-        default to the runtime's ServingConfig knobs; ``page_tokens == 0``
-        keeps the dense (layers, slots, n_kv, max_seq, head_dim) slot array,
-        ``> 0`` allocates the paged arena instead (``arena_pages == 0`` auto-
-        sizes to slots x ceil(max_seq/page_tokens) — the dense-equivalent
-        byte budget; with ``arena_dtype == "int8"`` the page count grows to
+        default to the runtime's ServingConfig knobs: the arena holds pages
+        of ``page_tokens`` tokens (``arena_pages == 0`` auto-sizes to slots
+        x ceil(max_seq/page_tokens), every lane can hold the longest
+        request; with ``arena_dtype == "int8"`` the page count grows to
         fill the SAME byte budget, which is where the capacity win comes
         from). An existing state always wins; later callers' knobs
         are ignored, same as ``slots``.
@@ -2040,7 +2041,7 @@ class TPUModelRuntime(BaseRuntime):
         ``_slot_lock``: the array can be hundreds of MB (seconds of HBM
         traffic) and the map lock is taken by eviction/reset paths. The
         guard closes the first-admission race where two concurrent first
-        requests each allocated a full slot array and one was thrown away.
+        requests each allocated a full arena and one was thrown away.
         """
         loaded = self._resident.get(model_id)
         if loaded is None:
@@ -2084,13 +2085,11 @@ class TPUModelRuntime(BaseRuntime):
         arena_dtype: str | None = None,
         paged_kernel: bool | None = None,
     ) -> SlotDecodeState:
-        from tfservingcache_tpu.models.generation import (
-            init_cache,
-            init_paged_cache,
-        )
+        from tfservingcache_tpu.models.generation import init_paged_cache
 
         if page_tokens is None:
-            page_tokens = int(getattr(self.cfg, "kv_page_tokens", 0))
+            page_tokens = getattr(self.cfg, "kv_page_tokens", 16)
+        page_tokens = check_page_tokens(page_tokens)
         if arena_pages is None:
             arena_pages = int(getattr(self.cfg, "kv_arena_pages", 0))
         if share_prefix_bytes is None:
@@ -2107,12 +2106,50 @@ class TPUModelRuntime(BaseRuntime):
         if self.mesh is not None:
             paged_kernel = False
         # Sharded arena (ISSUE 20): pages partition over the KV-head axis on
-        # a fast-path mesh; a lockstep runtime never builds slot state (the
-        # batcher routes it to coalesce), but keep it dense-host-identical
+        # a fast-path mesh; a lockstep runtime never builds slot state (its
+        # requests go to runtime.generate), so its arena would be unsharded
         arena_mesh = None if self.mesh_lockstep else self.mesh
         cfg = loaded.model_def.config
         max_seq = int(cfg["max_seq"])
-        common = dict(
+        pps = -(-max_seq // page_tokens)
+        usable = int(arena_pages) if arena_pages else slots * pps
+        if not arena_pages and arena_dtype == "int8":
+            # Byte-matched auto-size: int8 pages are smaller (1-byte
+            # payload + 4-byte f32 scale per row vs the model dtype's
+            # itemsize), so the SAME byte budget holds more pages — that
+            # growth IS the int8 capacity win. Explicit kv_arena_pages is
+            # honored verbatim.
+            import jax.numpy as jnp
+
+            hd = int(cfg["d_model"]) // int(cfg["n_heads"])
+            model_item = jnp.dtype(
+                cfg.get("dtype", "bfloat16")
+            ).itemsize
+            usable = max(
+                usable, (usable * hd * model_item) // (hd + 4)
+            )
+        # +1: page 0 is the trash page, permanently reserved
+        cache = init_paged_cache(
+            cfg, usable + 1, page_tokens, arena_dtype, mesh=arena_mesh
+        )
+        scales = None
+        if "k_scale" in cache:
+            scales = {"k": cache["k_scale"], "v": cache["v_scale"]}
+        prefix_index = None
+        if share_prefix_bytes and share_prefix_bytes > 0:
+            from tfservingcache_tpu.runtime.prefix_cache import (
+                PagePrefixIndex,
+            )
+
+            page_nbytes = sum(
+                int(a.nbytes)
+                for a in (cache["k"], cache["v"],
+                          *(scales.values() if scales else ()))
+            ) // (usable + 1)
+            prefix_index = PagePrefixIndex(
+                page_tokens, page_nbytes, int(share_prefix_bytes)
+            )
+        st = SlotDecodeState(
             model_id=model_id,
             cfg_key=tuple(sorted((k, v) for k, v in cfg.items())),
             family=loaded.model_def.family,
@@ -2123,77 +2160,29 @@ class TPUModelRuntime(BaseRuntime):
             active=np.zeros((slots,), bool),
             temps=np.zeros((slots,), np.float32),
             topks=np.zeros((slots,), np.int32),
+            k=cache["k"],
+            v=cache["v"],
+            scales=scales,
+            arena_dtype=arena_dtype,
+            kernel=bool(paged_kernel),
+            page_tokens=page_tokens,
+            arena_pages=usable,
+            pages_per_slot=pps,
+            block_tables=np.zeros((slots, pps), np.int32),
+            free_pages=list(range(1, usable + 1)),
+            page_refs=np.zeros((usable + 1,), np.int32),
+            prefix_index=prefix_index,
         )
-        if page_tokens and page_tokens > 0:
-            page_tokens = int(page_tokens)
-            pps = -(-max_seq // page_tokens)
-            usable = int(arena_pages) if arena_pages else slots * pps
-            if not arena_pages and arena_dtype == "int8":
-                # Byte-matched auto-size: int8 pages are smaller (1-byte
-                # payload + 4-byte f32 scale per row vs the dense itemsize),
-                # so the SAME byte budget holds more pages — that growth IS
-                # the int8 capacity win. Explicit kv_arena_pages is honored
-                # verbatim (bench arms pass matched budgets themselves).
-                import jax.numpy as jnp
-
-                hd = int(cfg["d_model"]) // int(cfg["n_heads"])
-                dense_item = jnp.dtype(
-                    cfg.get("dtype", "bfloat16")
-                ).itemsize
-                usable = max(
-                    usable, (usable * hd * dense_item) // (hd + 4)
-                )
-            # +1: page 0 is the trash page, permanently reserved
-            cache = init_paged_cache(
-                cfg, usable + 1, page_tokens, arena_dtype, mesh=arena_mesh
-            )
-            scales = None
-            if "k_scale" in cache:
-                scales = {"k": cache["k_scale"], "v": cache["v_scale"]}
-            prefix_index = None
-            if share_prefix_bytes and share_prefix_bytes > 0:
-                from tfservingcache_tpu.runtime.prefix_cache import (
-                    PagePrefixIndex,
-                )
-
-                page_nbytes = sum(
-                    int(a.nbytes)
-                    for a in (cache["k"], cache["v"],
-                              *(scales.values() if scales else ()))
-                ) // (usable + 1)
-                prefix_index = PagePrefixIndex(
-                    page_tokens, page_nbytes, int(share_prefix_bytes)
-                )
-            st = SlotDecodeState(
-                k=cache["k"],
-                v=cache["v"],
-                scales=scales,
-                arena_dtype=arena_dtype,
-                kernel=bool(paged_kernel),
-                page_tokens=page_tokens,
-                arena_pages=usable,
-                pages_per_slot=pps,
-                block_tables=np.zeros((slots, pps), np.int32),
-                free_pages=list(range(1, usable + 1)),
-                page_refs=np.zeros((usable + 1,), np.int32),
-                prefix_index=prefix_index,
-                **common,
-            )
-            self._note_arena_bytes(st)
-            return st
-        cache = init_cache(cfg, slots, max_seq, mesh=arena_mesh)
-        return SlotDecodeState(
-            k=cache["k"], v=cache["v"],
-            kernel=bool(paged_kernel), **common,
-        )
+        self._note_arena_bytes(st)
+        return st
 
     def _note_arena_bytes(self, state: SlotDecodeState) -> None:
         """Publish ``tpusc_gen_kv_arena_bytes{dtype}`` for a freshly built
-        paged arena. Gauge semantics are "bytes currently allocated with
+        arena. Gauge semantics are "bytes currently allocated with
         this dtype label"; drop paths zero the label rather than tracking a
         cross-model sum (one continuous-decode model per runtime in
         practice — the engine keys slot state by model_id)."""
-        if self.metrics is None or not state.page_tokens:
+        if self.metrics is None:
             return
 
         def actual(arr: Any) -> int:
@@ -2212,9 +2201,8 @@ class TPUModelRuntime(BaseRuntime):
         self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(nbytes)
 
     def mesh_topology(self) -> dict | None:
-        """Structural stamp for /monitoring/engine and bench artifacts
-        (same rule as ``kernel_active``/``platform`` from BENCH_r09): a
-        number without its topology is unreadable later. None off-mesh."""
+        """Structural stamp for /monitoring/engine: a number without its
+        topology is unreadable later. None off-mesh."""
         if self.mesh is None:
             return None
         return {
@@ -2226,7 +2214,7 @@ class TPUModelRuntime(BaseRuntime):
     def drop_slot_state(self, model_id: ModelId) -> None:
         with self._slot_lock:
             st = self._slot_states.pop(model_id, None)
-        if st is not None and st.page_tokens and self.metrics is not None:
+        if st is not None and self.metrics is not None:
             label = st.arena_dtype or str(st.k.dtype)
             self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(0)
 
@@ -2593,15 +2581,13 @@ class TPUModelRuntime(BaseRuntime):
         Read-only on the arena: the caller still release_pages() the lane
         normally, so the conservation census never sees a parked page as a
         new reference source. None when the lane has nothing parkable
-        (dense state, empty history, or a lane whose reservation no longer
-        covers it — a crash-recovery race, not an error)."""
+        (empty history, or a lane whose reservation no longer covers it —
+        a crash-recovery race, not an error)."""
         import jax
 
         from tfservingcache_tpu.cache.conversation_kv import ParkedConversation
         from tfservingcache_tpu.models.generation import _pages_export_jit
 
-        if not state.paged:
-            return None
         history = np.asarray(history, np.int32).reshape(-1)
         if history.shape[0] <= 0:
             return None
@@ -2639,7 +2625,7 @@ class TPUModelRuntime(BaseRuntime):
         the lane (mirroring shared_prefix_plan's trim). None when nothing
         is resumable — wrong page size / arena layout / dtype, divergent
         first token, or the trim shed everything."""
-        if parked is None or not state.paged:
+        if parked is None:
             return None
         if int(parked.page_tokens) != state.page_tokens:
             return None
@@ -2738,29 +2724,21 @@ class TPUModelRuntime(BaseRuntime):
     @_mesh_serialized
     def slot_admit(self, state: SlotDecodeState, idx: int, pk: Any, pv: Any,
                    base_tokens: int = 0) -> None:
-        """Copy an admitted request's prefill K/V into slot lane ``idx``
+        """Copy an admitted request's prefill K/V into lane ``idx``'s pages
         (in-place via donation). The caller (scheduler thread) owns the host
-        mirrors and sets tok/pos/active/temps/topks itself; for a paged
-        state it must have reserved the lane's pages (reserve_pages) first —
-        the insert scatters through the lane's block-table row.
+        mirrors and sets tok/pos/active/temps/topks itself, and must have
+        reserved the lane's pages (reserve_pages) first — the insert
+        scatters through the lane's block-table row.
         ``base_tokens`` is the shared-prefix boundary: prefill rows below it
         belong to read-only shared pages and are redirected to the trash
         page (the suffix prefill only produced junk there anyway)."""
-        from tfservingcache_tpu.models.generation import (
-            _paged_insert_jit,
-            _slot_insert_jit,
-        )
+        from tfservingcache_tpu.models.generation import _paged_insert_jit
 
-        if state.paged:
-            state.k, state.v, state.scales = _paged_insert_jit(
-                state.k, state.v, state.scales, pk, pv,
-                np.asarray(state.block_tables[idx], np.int32),
-                np.int32(base_tokens),
-                page_tokens=state.page_tokens,
-            )
-            return
-        state.k, state.v = _slot_insert_jit(
-            state.k, state.v, pk, pv, np.int32(idx)
+        state.k, state.v, state.scales = _paged_insert_jit(
+            state.k, state.v, state.scales, pk, pv,
+            np.asarray(state.block_tables[idx], np.int32),
+            np.int32(base_tokens),
+            page_tokens=state.page_tokens,
         )
 
     @_mesh_serialized
@@ -2773,7 +2751,6 @@ class TPUModelRuntime(BaseRuntime):
         import jax
 
         from tfservingcache_tpu.models.generation import (
-            _decode_chunk_jit,
             _paged_decode_chunk_jit,
         )
 
@@ -2784,26 +2761,17 @@ class TPUModelRuntime(BaseRuntime):
         rngs = jax.random.split(
             jax.random.PRNGKey(state.chunk_counter), chunk
         )
-        if state.paged:
-            if _PAGECHECK:
-                _check_trash_unreachable(state)
-            (state.k, state.v, state.scales, tok, pos,
-             toks, stats) = _paged_decode_chunk_jit(
-                loaded.params, state.k, state.v, state.scales,
-                np.asarray(state.block_tables, np.int32),
-                state.tok, state.pos, state.active, rngs,
-                state.temps, state.topks,
-                cfg_key=state.cfg_key, family=state.family, chunk=chunk,
-                page_tokens=state.page_tokens, kernel=state.kernel,
-            )
-        else:
-            stats = None
-            state.k, state.v, tok, pos, toks = _decode_chunk_jit(
-                loaded.params, state.k, state.v,
-                state.tok, state.pos, state.active, rngs,
-                state.temps, state.topks,
-                cfg_key=state.cfg_key, family=state.family, chunk=chunk,
-            )
+        if _PAGECHECK:
+            _check_trash_unreachable(state)
+        (state.k, state.v, state.scales, tok, pos,
+         toks, stats) = _paged_decode_chunk_jit(
+            loaded.params, state.k, state.v, state.scales,
+            np.asarray(state.block_tables, np.int32),
+            state.tok, state.pos, state.active, rngs,
+            state.temps, state.topks,
+            cfg_key=state.cfg_key, family=state.family, chunk=chunk,
+            page_tokens=state.page_tokens, kernel=state.kernel,
+        )
         # np.array (not asarray): device_get hands back READ-ONLY views and
         # the scheduler writes these mirrors at the next admission
         # one fetch: an expert model's two routing numbers ride with the tokens
@@ -2824,17 +2792,12 @@ class TPUModelRuntime(BaseRuntime):
         target's — and pins it on ``state.spec_draft`` so its lifecycle is
         the target state's (dropped together; NOT registered in
         ``_slot_states``). Idempotent for the same draft. The draft must be
-        resident, share the target's vocabulary, and be a transformer_lm;
-        the target state must be paged (the private-page discipline is what
-        makes ragged rollback free). ``spec_tokens`` is clamped to the same
+        resident, share the target's vocabulary, and be engine_ready (the
+        arena's private-page discipline is what makes ragged rollback
+        free). ``spec_tokens`` is clamped to the same
         {1,2,4,8} jit-signature buckets as the solo path."""
         if state.spec_draft is not None and state.spec_draft_id == draft_id:
             return state.spec_draft
-        if not state.paged:
-            raise RuntimeError_(
-                "in-engine speculation requires a paged slot state "
-                "(serving.kv_page_tokens > 0)"
-            )
         loaded = self._resident.get(state.model_id)
         draft = self._resident.get(draft_id)
         if loaded is None or draft is None:
@@ -3383,8 +3346,8 @@ class TPUModelRuntime(BaseRuntime):
     def engine_ready_of(self, model_id: ModelId) -> bool:
         """Whether a resident model's family declares itself engine-ready
         (``ModelDef.engine_ready``: KV pages its only layer state, a
-        row-invariant step) — what the coalescer and the continuous engine
-        ask before they co-batch it. False when not loaded."""
+        row-invariant step) — what the continuous engine asks before it
+        co-batches it. False when not loaded."""
         loaded = self._resident.get(model_id, touch=False)
         return loaded is not None and loaded.model_def.engine_ready
 

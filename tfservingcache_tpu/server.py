@@ -185,7 +185,6 @@ class CacheNode:
                 manager,
                 batch_window_ms=cfg.serving.batch_window_ms,
                 batch_max_size=cfg.serving.batch_max_size,
-                generate_engine=cfg.serving.generate_engine,
                 generate_slots=cfg.serving.generate_slots,
                 generate_chunk_tokens=cfg.serving.generate_chunk_tokens,
                 kv_page_tokens=cfg.serving.kv_page_tokens,

@@ -5,11 +5,11 @@ this module makes the TENANTS observable — who is spending the HBM, the KV
 arena pages, the decode steps, and the peer wire. Every tier feeds the same
 per-tenant (``name@version``) ledger of monotonic resource integrals:
 
-- **Engine steps** (runtime/batcher.py): each chunk boundary / batch drain
-  lands its prefill and decode step-seconds plus tokens in/out on the one
-  tenant the dispatch served (each scheduler thread and each coalesced
-  batch is single-model by construction, so there is no cross-tenant
-  apportionment ambiguity at a boundary).
+- **Engine steps** (runtime/batcher.py): each chunk boundary lands its
+  prefill and decode step-seconds plus tokens in/out on the one tenant
+  the dispatch served (each scheduler thread is single-model by
+  construction, so there is no cross-tenant apportionment ambiguity at a
+  boundary).
 - **KV pages** (runtime/batcher.py page gauge sites): page-seconds as the
   integral of DISTINCT pages held over time — a shared-prefix page mapped
   by N lanes of the tenant counts once, matching ``page_stats()``'s
@@ -173,7 +173,7 @@ class TenantLedger:
         tokens_out: int = 0,
         queue_depth: int = 0,
     ) -> None:
-        """One engine chunk boundary / batch drain for ``tenant``. Also
+        """One engine chunk boundary for ``tenant``. Also
         advances the noisy-neighbor window; the dump (if any) fires outside
         the lock so file IO never blocks a scheduler thread's next admit."""
         if not self.enabled:

@@ -1,7 +1,7 @@
 """Where this process keeps JAX's persistent compilation cache.
 
 One rule, applied once at process start by the entry points (``tpuserve
-serve`` / ``warm``, ``bench.py``, ``chip_smoke.py`` through ``serve``):
+serve`` / ``warm``, ``chip_smoke.py`` through ``serve``):
 
   1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and nothing in
      this repo calls ``jax.config.update("jax_compilation_cache_dir", ...)``

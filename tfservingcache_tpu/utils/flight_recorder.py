@@ -6,9 +6,8 @@ traces say which request, but neither says what the ENGINE was doing —
 queue depth, lane occupancy, page pressure, wasted steps — at the moment
 it went wrong. This module is the box's flight recorder:
 
-- **Step ring**: one fixed-size record per dispatched decode chunk (and
-  per coalescer batch drain) into a per-model ring buffer, ~4096 entries
-  by default. Writes are lock-free on the hot path: a preallocated list,
+- **Step ring**: one fixed-size record per chunk boundary into a
+  per-model ring buffer, ~4096 entries by default. Writes are lock-free on the hot path: a preallocated list,
   an ``itertools.count`` (atomic under the GIL) for slot assignment, and
   one tuple build — tens of microseconds, guarded by
   tests/test_flight_recorder.py (< 50 us/step).
@@ -59,13 +58,13 @@ log = get_logger("flight_recorder")
 # tools/engine_dump.py.
 STEP_FIELDS = (
     "t_wall",          # epoch seconds at record time
-    "engine",          # "continuous" | "coalesce"
-    "step_ms",         # wall time of this chunk boundary / batch drain
+    "engine",          # "continuous" (the one engine; the field stays)
+    "step_ms",         # wall time of this chunk boundary
     "chunk",           # decode steps computed per lane this dispatch
     "active",          # lanes (rows) the dispatch computed for
     "admitted",        # rows admitted at this boundary
     "retired",         # rows retired at this boundary
-    "pages_used",      # KV arena pages reserved after this step (0 = dense)
+    "pages_used",      # KV arena pages reserved after this step
     "pages_free",      # KV arena pages free after this step
     "wasted",          # steps computed for already-finished rows this step
     "queue_depth",     # rows still waiting for admission
@@ -117,8 +116,8 @@ def _step_dict(e: tuple) -> dict[str, Any]:
 
 class _Ring:
     """Lock-free fixed-size ring of step tuples: one writer-side atomic
-    counter hands out slots, so concurrent writers (coalescer leaders of
-    the same model) never block each other; a torn read during snapshot
+    counter hands out slots, so concurrent writers (a scheduler and its
+    respawned successor) never block each other; a torn read during snapshot
     costs at most one misordered diagnostic row, never a crash."""
 
     def __init__(self, entries: int) -> None:
@@ -341,7 +340,7 @@ class FlightRecorder:
             if e[11] > max_wait:
                 max_wait = e[11]
             # appended fields may be absent in entries deserialized from old
-            # dumps — treat short tuples as zero, same as a dense engine
+            # dumps — treat short tuples as zero
             if len(e) > 12 and e[12] > max_shared:
                 max_shared = e[12]
             if len(e) > 13:

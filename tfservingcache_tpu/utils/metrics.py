@@ -163,8 +163,10 @@ class Metrics:
             "/monitoring/engine scrape (reset-on-scrape)",
             registry=r,
         )
-        # continuous batching observability: how often requests coalesce and
-        # how many ride each device call (kind = predict | generate)
+        # the :predict micro-batcher (runtime/batcher.py MicroBatcher): how
+        # often requests coalesce and how many ride each device call. The
+        # kind label keeps its place; "predict" is its one value since the
+        # :generate coalescer went
         self.coalesced_batches = Counter(
             "tpusc_coalesced_batches", "Multi-request device calls",
             ["kind"], registry=r,
@@ -174,10 +176,9 @@ class Metrics:
             ["kind"], registry=r,
         )
         # iteration-level continuous batching (runtime/batcher.py
-        # ContinuousGenerateEngine). The engine label makes coalesce vs
-        # continuous comparable on the SAME metric: the coalescer records
-        # its head-of-line gate stall and post-hoc padded-step waste under
-        # engine="coalesce".
+        # ContinuousGenerateEngine). The engine label keeps its place on
+        # every family below; "continuous" is its one value since the
+        # coalescer went (dashboards and the benchmark's readers key on it).
         # model label gated on the metrics.model_labels flag (same
         # cardinality rule as the cache counters): off = one "all_models"
         # series summed across models, on = per-model lane occupancy, so a
@@ -194,15 +195,15 @@ class Metrics:
         self.gen_wasted_steps = Counter(
             "tpusc_gen_wasted_steps",
             "Decode steps computed for a row AFTER its request already "
-            "finished (EOS or its own max_new_tokens): batch-drain padding "
-            "under coalesce, chunk overshoot (< chunk size) under continuous",
+            "finished (EOS or its own max_new_tokens): the chunk overshoot, "
+            "fewer than chunk size a retirement (engine=continuous)",
             ["engine"], registry=r,
         )
         self.gen_admission_wait = Histogram(
             "tpusc_gen_admission_wait_seconds",
             "Time a generate request waited before decoding began on its "
-            "behalf: slot-free wait under continuous, in-flight gate stall "
-            "under coalesce",
+            "behalf: the wait for a free lane and its pages "
+            "(engine=continuous)",
             ["engine"], registry=r,
             buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1, .25,
                      .5, 1, 2.5, 5, 10),
@@ -281,7 +282,7 @@ class Metrics:
             "tpusc_request_phase_seconds",
             "Per-request latency attribution by phase "
             "(phase=queue|prefill|decode|respond, "
-            "engine=continuous|coalesce; class=high|normal|low "
+            "engine=continuous; class=high|normal|low "
             "when model_labels is on)",
             phase_labels, registry=r,
             buckets=(.0005, .001, .0025, .005, .01, .025, .05, .1, .25,

@@ -1,8 +1,11 @@
 #!/usr/bin/env python3
-"""Terminal renderer for scenario-lab SLO scorecards (BENCH_r*.json).
+"""Terminal renderer for scenario-lab SLO scorecards.
 
-Reads a bench artifact carrying a ``scenario_lab`` section (or a bare
-section dict) and renders the scenario x fault matrix the way an on-call
+Reads a JSON artifact carrying a ``scenario_lab`` section (or a bare
+section dict: ``{"scenarios", "faults", "matrix": [scorecard rows of
+lab.scenario.run_cell]}``; tests/test_scenario_lab.py builds one, the
+committed BENCH_r11.json and BENCH_r13.json are two CPU-harness samples
+from before bench.py went) and renders the scenario x fault matrix the way an on-call
 reads a chaos drill: one row per scenario, one column per fault kind, the
 chosen metric in each cell. A second table lists every cell's full
 scorecard row (the SCORECARD_FIELDS schema from lab/scenario.py), with
@@ -49,7 +52,7 @@ _CLASS_ORDER = {"high": 0, "normal": 1, "low": 2}
 
 
 def _section(doc: dict) -> dict:
-    """Accept a full bench artifact, its ``parsed`` envelope, or a bare
+    """Accept a full artifact, its ``parsed`` envelope, or a bare
     scenario_lab section."""
     for key in ("parsed", "detail"):
         if isinstance(doc.get(key), dict):
@@ -59,7 +62,8 @@ def _section(doc: dict) -> dict:
     if "matrix" not in doc:
         raise SystemExit(
             "no scenario_lab matrix in this artifact "
-            "(run `python bench.py --only scenario_lab` first)"
+            "(it wants lab.scenario.run_cell scorecard rows under "
+            "scenario_lab.matrix)"
         )
     return doc
 
@@ -143,8 +147,8 @@ def _unwrap(doc: dict) -> dict:
 def _classes_from_traces(traces: list) -> dict:
     """Per-class TTFT map from a ``/monitoring/traces`` dump: generate
     trace roots carry ``priority`` and ``ttft_ms`` attrs (stamped by the
-    batcher engines), so the live trace ring yields the same pivot the
-    bench arms record — the cross-check that the class-labeled
+    engine), so the live trace ring yields the same pivot a recorded
+    artifact holds — the cross-check that the class-labeled
     ``tpusc_request_phase_seconds`` histogram and the traces agree."""
     samples: dict[str, list] = {}
     for t in traces:
@@ -165,8 +169,8 @@ def _classes_from_traces(traces: list) -> dict:
 
 def render_classes(doc: dict, out=None) -> None:
     """Per-priority-class TTFT pivot (ISSUE 19): one row per cell that
-    recorded ``ttft_ms_by_class`` (the slo_engine bench arms, plus any
-    scenario-lab cell that tagged its requests), one column per class.
+    recorded ``ttft_ms_by_class`` (an artifact's ``slo_engine`` arms, plus
+    any scenario-lab cell that tagged its requests), one column per class.
     Each cell shows ``p95 (n=count)`` — the SLO the class actually got,
     not the population blend the headline p95 hides it in. A
     ``/monitoring/traces`` dump (``{"traces": [...]}``) works too: the
@@ -197,7 +201,7 @@ def render_classes(doc: dict, out=None) -> None:
     if not rows:
         raise SystemExit(
             "no per-class TTFT data in this artifact "
-            "(run `python bench.py --only slo_engine` first, or dump "
+            "(it wants ttft_ms_by_class rows, or a dump of "
             "/monitoring/traces)"
         )
     classes = sorted(
@@ -220,7 +224,7 @@ def render_classes(doc: dict, out=None) -> None:
 
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
-        description="render scenario-lab SLO scorecards from a bench artifact"
+        description="render scenario-lab SLO scorecards from a JSON artifact"
     )
     ap.add_argument("artifact", help="BENCH_r*.json (or a bare section dump)")
     ap.add_argument("--metric", default="p95_ttft_ms", choices=METRICS,
