@@ -132,6 +132,52 @@ def test_solo_fallbacks_and_close(tmp_path):
         eng.generate(mid, np.ones((1, 4), np.int32))
 
 
+def test_sample_steps_counter_follows_the_live_lanes(tmp_path):
+    """`tpusc_gen_sample_steps_total{path}`: every decode step counts once,
+    under what its LIVE lanes asked the sampler for. A retired sampled lane
+    keeps its temperature / top_k in the state's mirrors; the next all-greedy
+    chunk must still count (and run) `greedy`."""
+    from tfservingcache_tpu.utils.flight_recorder import RECORDER
+
+    metrics = Metrics()
+    rt, mid = _load(tmp_path, name="paths", metrics=metrics)
+    eng = ContinuousGenerateEngine(rt, slots=4, chunk_tokens=4, metrics=metrics)
+    ids = np.array([[5, 17, 40, 3]], np.int32)
+
+    def counts():
+        return {p: metrics.gen_sample_steps.labels(p)._value.get()
+                for p in ("greedy", "sample", "topk")}
+
+    def ring_steps():
+        snap = RECORDER.snapshot(tail=RECORDER.ring_entries)
+        return sum(s["chunk"] for s in snap["models"][str(mid)]["steps"])
+
+    try:
+        greedy = eng.generate(mid, ids, max_new_tokens=9)
+        eng.generate(mid, np.repeat(ids, 3, axis=0), max_new_tokens=5)
+        assert counts() == {"greedy": ring_steps(), "sample": 0, "topk": 0}
+
+        at = counts()
+        eng.generate(mid, ids, max_new_tokens=9, temperature=0.8)
+        assert counts() == {**at, "sample": 8}
+        eng.generate(mid, ids, max_new_tokens=9, temperature=0.8, top_k=5)
+        assert counts() == {**at, "sample": 8, "topk": 8}
+        # top_k at or over the vocabulary filters nothing: no sort, `sample`
+        eng.generate(mid, ids, max_new_tokens=5, temperature=0.8,
+                     top_k=TINY["vocab_size"])
+        assert counts() == {**at, "sample": 12, "topk": 8}
+
+        # the stale lane: the top_k request's lane is free, its values stay
+        st = rt.slot_decode_state(mid, 4)
+        assert not st.active.any() and (st.temps > 0).any() and (st.topks > 0).any()
+        assert (eng.generate(mid, ids, max_new_tokens=9) == greedy).all()
+        assert counts() == {"greedy": at["greedy"] + 8, "sample": 12, "topk": 8}
+        assert sum(counts().values()) == ring_steps()
+    finally:
+        eng.close()
+        rt.close()
+
+
 # -- in-engine speculative decoding (ISSUE 16) --------------------------------
 
 DRAFT_TINY = dict(TINY, d_model=24, n_layers=1, n_heads=2, n_kv_heads=1,
@@ -388,7 +434,8 @@ def _stub_state(slots, max_seq=4096, page_tokens=16):
 
     pps = max_seq // page_tokens
     return SlotDecodeState(
-        model_id=ModelId("stub", 1), cfg_key=(), family="stub", slots=slots,
+        model_id=ModelId("stub", 1), cfg_key=(("vocab_size", 97),), family="stub",
+        slots=slots,
         max_seq=max_seq, k=None, v=None,
         tok=np.zeros(slots, np.int32), pos=np.zeros(slots, np.int32),
         active=np.zeros(slots, bool), temps=np.zeros(slots, np.float32),

@@ -571,6 +571,7 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_moe_expert_rows",
     "tpusc_gen_prefix_hits",
     "tpusc_gen_oldest_queued_age_seconds",
+    "tpusc_gen_sample_steps",
     "tpusc_gen_stream_frames",
     "tpusc_gen_slots_active",
     "tpusc_gen_wasted_steps",

@@ -27,6 +27,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tfservingcache_tpu.models.moe_lm import _moe_block
 from tfservingcache_tpu.models.transformer_lm import (
@@ -64,28 +65,29 @@ def init_cache(cfg: dict, batch: int, max_len: int, mesh=None) -> dict:
     return cache
 
 
-@jax.named_scope("sample")
 def _sample(logits, rng, temperature, top_k):
-    """logits (B, V) -> token ids (B,).
+    """logits (B, V) -> token ids (B,): ``_sample_per_row`` with one
+    ``temperature`` and one ``top_k`` for every row (the prefill's first
+    token, the solo decoder's scan), so it pays what that sampler pays: an
+    ``argmax`` alone where ``temperature <= 0``, the categorical draw where
+    it is positive, the full-vocabulary sort only with a ``top_k`` inside
+    (0, vocab).
 
     ``temperature`` and ``top_k`` are TRACED scalars, not compile-time
     constants: both arrive straight from the unauthenticated ``:generate``
     request body, and a static argname would mint (and cache forever) a fresh
     XLA compile of the whole prefill+scan program per novel value — a
-    compile-DoS vector. One compiled program now serves every sampling
-    config: temperature<=0 selects greedy, top_k<=0 (or >= vocab) disables
-    top-k filtering, all via in-graph selects.
+    compile-DoS vector. One compiled program serves every sampling config:
+    temperature<=0 selects greedy, top_k<=0 (or >= vocab) disables top-k
+    filtering, both decided on the device (``lax.cond``), never by a static
+    flag or a second program.
     """
-    v = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    k = jnp.clip(jnp.asarray(top_k, jnp.int32), 0, v)
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    kth = sorted_desc[:, jnp.clip(k - 1, 0, v - 1)][:, None]
-    thresh = jnp.where((k > 0) & (k < v), kth, -jnp.inf)
-    filt = jnp.where(logits < thresh, -1e30, logits)
-    temp = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
-    sampled = jax.random.categorical(rng, filt / temp, axis=-1).astype(jnp.int32)
-    return jnp.where(jnp.asarray(temperature, jnp.float32) <= 0.0, greedy, sampled)
+    rows = logits.shape[:1]
+    return _sample_per_row(
+        logits, rng,
+        jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), rows),
+        jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), rows),
+    )
 
 
 def _decode_scan(params, cache, first_tok, start_pos, rng, temperature,
@@ -209,26 +211,75 @@ def _generate_from_cache_jit(
     return toks
 
 
+def _sampling_lanes(temperature, top_k, vocab: int, active=None):
+    """What a step's lanes ask of the sampler, as two (S,) masks: the rows
+    that draw (exactly those the sampler's last select does not hand to
+    ``argmax``) and, among them, the rows whose ``top_k`` filters. Operators
+    only, so the device (traced arrays, ``_sample_per_row``) and the host
+    (the engine's numpy mirrors, ``sample_path``) run the same lines.
+    ``active`` leaves out lanes nobody reads: a retired lane keeps its last
+    ``temperature`` / ``top_k`` until the next admission overwrites them."""
+    samples = ~(temperature <= 0.0)
+    if active is not None:
+        samples = samples & active
+    return samples, samples & (top_k > 0) & (top_k < vocab)
+
+
+def sample_path(active, temps, topks, vocab: int) -> str:
+    """The path ``_sample_per_row`` takes for these lanes, worked out on the
+    host from the mirrors a chunk is dispatched with: ``"greedy"``,
+    ``"sample"`` or ``"topk"`` (the ``tpusc_gen_sample_steps_total`` label)."""
+    samples, wants_k = _sampling_lanes(
+        np.asarray(temps), np.asarray(topks), vocab, np.asarray(active, bool))
+    if not samples.any():
+        return "greedy"
+    return "topk" if wants_k.any() else "sample"
+
+
 @jax.named_scope("sample")
-def _sample_per_row(logits, rng, temperature, top_k):
+def _sample_per_row(logits, rng, temperature, top_k, active=None):
     """Per-row sampling params: logits (S, V), temperature (S,) f32,
     top_k (S,) i32 -> token ids (S,). The continuous engine packs unrelated
     requests into one decode step, so each lane carries its own sampling
     config; the values stay TRACED for the same compile-DoS reason as
-    ``_sample``. One categorical draw covers all rows (matches the batched
-    stream structure)."""
+    ``_sample``.
+
+    A step pays for what its lanes ask (``_sampling_lanes``; ``active``
+    (S,) bool, where given, names the lanes somebody reads), chosen on the
+    device inside the one program:
+
+    - no lane draws (``greedy``): the ``argmax`` and nothing else;
+    - some lane draws, none filters (``sample``): one categorical draw over
+      all rows (matches the batched stream structure), no sort;
+    - some drawing lane sets a ``top_k`` inside (0, vocab) (``topk``): the
+      full-vocabulary sort for every row's threshold, then the draw.
+
+    A row's token is the same on every path that may serve it: one lane
+    that draws makes all S rows pay for the draw, as before."""
     v = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     k = jnp.clip(top_k.astype(jnp.int32), 0, v)
-    sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(
-        sorted_desc, jnp.clip(k - 1, 0, v - 1)[:, None], axis=-1
-    )
-    thresh = jnp.where(((k > 0) & (k < v))[:, None], kth, -jnp.inf)
-    filt = jnp.where(logits < thresh, -1e30, logits)
-    temp = jnp.maximum(temperature.astype(jnp.float32), 1e-6)[:, None]
-    sampled = jax.random.categorical(rng, filt / temp, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    samples, wants_k = _sampling_lanes(temperature, k, v, active)
+
+    def threshold_by_sort():
+        sorted_desc = jnp.sort(logits, axis=-1)[:, ::-1]
+        kth = jnp.take_along_axis(
+            sorted_desc, jnp.clip(k - 1, 0, v - 1)[:, None], axis=-1
+        )
+        return jnp.where(((k > 0) & (k < v))[:, None], kth, -jnp.inf)
+
+    def draw():
+        thresh = jax.lax.cond(
+            jnp.any(wants_k), threshold_by_sort,
+            lambda: jnp.full((logits.shape[0], 1), -jnp.inf, logits.dtype),
+        )
+        filt = jnp.where(logits < thresh, -1e30, logits)
+        temp = jnp.maximum(temperature.astype(jnp.float32), 1e-6)[:, None]
+        sampled = jax.random.categorical(
+            rng, filt / temp, axis=-1).astype(jnp.int32)
+        return jnp.where(temperature <= 0.0, greedy, sampled)
+
+    return jax.lax.cond(jnp.any(samples), draw, lambda: greedy)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg_key", "family"))
@@ -730,7 +781,7 @@ def _paged_decode_chunk_jit(
             params, tok, cache, tables, pos, cfg, family,
             page_tokens, kernel=kernel, active=active, moe_stats=layer_stats,
         )
-        nxt = _sample_per_row(logits[:, 0], rng, temperature, top_k)
+        nxt = _sample_per_row(logits[:, 0], rng, temperature, top_k, active)
         nxt = jnp.where(active, nxt, tok)
         pos = pos + active.astype(jnp.int32)
         # (2,): the step's mean over its expert layers; nothing for a dense model

@@ -387,7 +387,7 @@ def _paged_spec_round_jit(  # static-bounded: cfg_t_key, cfg_d_key, family_t, fa
     # and emit one token sampled from the position-0 logits — identical
     # math to the plain chunk's _sample_per_row step
     greedy_row = temperature <= 0.0
-    e0 = _sample_per_row(logits_t[:, 0], rng, temperature, top_k)
+    e0 = _sample_per_row(logits_t[:, 0], rng, temperature, top_k, active)
     a = jnp.where(greedy_row, a, 0)
     toks = g.at[:, 0].set(jnp.where(greedy_row, g[:, 0], e0))
     accept = jnp.where(active, a + 1, 0)                    # emitted count

@@ -1146,6 +1146,16 @@ class _ContinuousScheduler:
                     pg = int(state.block_tables[cidx, slot])
                     if pg and int(state.page_refs[pg]) > 1:
                         rt.slot_cow(state, cidx, slot)
+        # what the sampler will pay for, from the mirrors this dispatch takes
+        # (the emission loop below clears ``active`` for the rows it retires)
+        path = None
+        if eng.metrics is not None:
+            from tfservingcache_tpu.models.generation import sample_path
+
+            path = sample_path(
+                state.active, state.temps, state.topks,
+                dict(state.cfg_key)["vocab_size"],
+            )
         chunk_t0 = time.monotonic()
         with host_span("decode_chunk"):
             accept = None
@@ -1202,8 +1212,10 @@ class _ContinuousScheduler:
                         retired_n += 1
                         break
         emit_s = time.monotonic() - now
-        if wasted and eng.metrics is not None:
-            eng.metrics.gen_wasted_steps.labels("continuous").inc(wasted)
+        if eng.metrics is not None:
+            eng.metrics.gen_sample_steps.labels(path).inc(chunk)
+            if wasted:
+                eng.metrics.gen_wasted_steps.labels("continuous").inc(wasted)
         if accept is not None and hasattr(rt, "_spec_observe"):
             # acceptance health + cumulative counters: one verify round per
             # active lane this boundary

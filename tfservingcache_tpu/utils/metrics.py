@@ -199,6 +199,15 @@ class Metrics:
             "fewer than chunk size a retirement (engine=continuous)",
             ["engine"], registry=r,
         )
+        self.gen_sample_steps = Counter(
+            "tpusc_gen_sample_steps",
+            "Decode steps of the engine (the flight recorder's chunk) by "
+            "what the step's live lanes asked of the sampler: greedy = an "
+            "argmax alone, sample = the categorical draw over every row, "
+            "topk = the full-vocabulary sort before the draw; one lane that "
+            "draws puts the whole step on its path",
+            ["path"], registry=r,
+        )
         self.gen_admission_wait = Histogram(
             "tpusc_gen_admission_wait_seconds",
             "Time a generate request waited before decoding began on its "
