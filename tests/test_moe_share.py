@@ -1,0 +1,155 @@
+"""One chip's share of an expert layer (``ops.moe.moe_experts``' ``held``), at
+a small size on the CPU.
+
+The deployment a share stands for divides a layer's experts between chips;
+each chip routes over ALL of them, computes the answers of the experts it
+holds and leaves the rest out. What ties the share to the model:
+
+  * the routed parts that the four shares give, plus what every chip computes
+    alike (the shared expert) counted ONCE, add up to what the uncut layer
+    gives (the benchmark's reference, holding every expert);
+  * a held range that is the whole layer is today's ``moe_experts`` bit for
+    bit: OLMoE's path does not change;
+  * an assignment to an expert held elsewhere reads no weight: ``experts_hit``
+    and ``expert_rows_max`` count held experts, ``expert_rows_local`` the
+    assignments that landed here.
+
+Float32 throughout; the tolerance against the reference (1e-5 of answers of
+size 1) is the order of float32 sums.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tfservingcache_tpu.models.moe_lm import _moe_block
+from tfservingcache_tpu.ops import moe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+T, D, FF, E, K = 29, 32, 16, 8, 3
+
+
+def _layer(seed=0, shared=True):
+    rng = np.random.default_rng(seed)
+    w = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape) / np.sqrt(shape[-2]), jnp.float32)
+    layer = {"router": w(D, E), "bias": jnp.asarray(0.3 * rng.standard_normal(E), jnp.float32),
+             "w1": w(E, D, FF), "w3": w(E, D, FF), "w2": w(E, FF, D)}
+    if shared:
+        layer["shared"] = {"w1": w(D, FF), "w3": w(D, FF), "w2": w(FF, D)}
+    return layer, jnp.asarray(rng.standard_normal((T, D)), jnp.float32)
+
+
+def _share(layer, first, count):
+    cut = {k: v for k, v in layer.items() if k in ("router", "bias")}
+    cut.update({w: layer[w][first:first + count] for w in ("w1", "w2", "w3")})
+    return cut
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["ragged_dot", "kernel"])
+def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
+        monkeypatch, interpret):
+    """Through ``_moe_block`` (norm, routed share, shared expert): the sum of
+    the four shares' answers minus three of the four shared-expert answers is
+    the reference's whole layer (every expert held, benchmark/families/
+    mla_moe.py's ``gates`` and ``add_experts``)."""
+    monkeypatch.setattr(moe, "MOE_KERNEL_INTERPRET", interpret)
+    layer, x = _layer(1)
+    cfg = {"top_k": K, "norm_topk_prob": True, "route_score": "sigmoid",
+           "route_scale": 2.5, "rms_eps": 1e-6, "n_experts": E}
+    ln2 = jnp.asarray(1.0 + 0.1 * np.random.default_rng(2).standard_normal(D),
+                      jnp.float32)
+    parts, locals_ = [], []
+    for first in range(0, E, 2):
+        y, stats = _moe_block(
+            {"moe": dict(_share(layer, first, 2), shared=layer["shared"]),
+             "ln2": ln2}, x[None],
+            dict(cfg, n_experts_held=2, expert_first=first), jnp.float32)
+        parts.append(np.asarray(y[0], np.float64))
+        locals_.append(float(stats["expert_rows_local"]))
+        assert float(stats["experts_hit"]) <= 2
+    assert sum(locals_) == T * K            # every assignment landed on one chip
+    only_shared, _ = _moe_block(
+        {"moe": dict(_share(layer, 0, 2), shared=layer["shared"]), "ln2": ln2},
+        x[None], dict(cfg, n_experts_held=2, expert_first=0), jnp.float32,
+        row_mask=jnp.zeros((T,), bool))     # no row routed: the shared expert alone
+    total = sum(parts) - 3 * np.asarray(only_shared[0], np.float64)
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_family_mla_moe", os.path.join(ROOT, "benchmark", "families", "mla_moe.py"))
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    mc = {"n_heads": 2, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 16,
+          "kv_lora_rank": 8, "rms_eps": 1e-6, "top_k": K, "expert_first": 0,
+          "n_experts_held": E, "norm_topk_prob": True, "route_scale": 2.5,
+          "rope_theta": 10000.0, "rope_factor": 4.0, "rope_beta_fast": 32.0,
+          "rope_beta_slow": 1.0, "rope_original_max": 32,
+          "rope_mscale_all_dim": 1.0, "llama4_beta": 0.1}
+    *_, gates, add_experts, _head = family._fns(tuple(sorted(mc.items())))
+    with jax.default_matmul_precision("highest"):
+        z, y, weight = gates(x, ln2, layer["router"], layer["bias"], layer["shared"])
+        want = add_experts(y, z, weight, layer["w1"], layer["w3"], layer["w2"]) - x
+    np.testing.assert_allclose(total, np.asarray(want), atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_a_held_range_that_is_the_whole_layer_is_todays_path_bit_for_bit(masked):
+    """``held=(0, E)`` against ``held=None`` (OLMoE's call): the same bytes,
+    the same stats."""
+    layer, x = _layer(3, shared=False)
+    layer.pop("bias")
+    mask = jnp.asarray(np.arange(T) % 3 != 0) if masked else None
+    y0, s0 = moe.moe_experts(x, layer, K, row_mask=mask)
+    y1, s1 = moe.moe_experts(x, layer, K, row_mask=mask, held=(0, E))
+    assert np.array_equal(np.asarray(y0), np.asarray(y1))
+    for name in ("experts_hit", "expert_rows_max", "expert_rows_local"):
+        assert float(s0[name]) == float(s1[name])
+    assert float(s0["expert_rows_local"]) == (int(mask.sum()) if masked else T) * K
+    # what a training loss reads: the router's choices over the full width, a
+    # masked row's set to ``E`` (no expert), with a share or without
+    want = np.asarray(moe.route(x, layer["router"], K, False)[1])
+    if masked:
+        want = np.where(np.asarray(mask)[:, None], want, E)
+    for s in (s0, s1, moe.moe_experts(x, _share(layer, 2, 2), K, row_mask=mask,
+                                      held=(2, 2))[1]):
+        assert np.array_equal(np.asarray(s["experts"]), want)
+    # OLMoE's call traces the same program with the share spelled out: the
+    # held range adds no equation to the output's computation
+    trace = lambda **kw: str(jax.make_jaxpr(  # noqa: E731
+        lambda x: moe.moe_experts(x, layer, K, **kw)[0])(x))
+    assert trace().count("\n") <= trace(held=(0, E)).count("\n")
+
+
+def test_assignments_to_experts_held_elsewhere_read_no_weight(monkeypatch):
+    """The kernel's grid visits (row tile, expert) pairs that hold rows: with
+    experts 2..3 held, only assignments to them are rows, the answer is the
+    held assignments' by hand, and the stats count held experts."""
+    monkeypatch.setattr(moe, "MOE_KERNEL_INTERPRET", True)
+    layer, x = _layer(4, shared=False)
+    gates, idx, _ = moe.route(x, layer["router"], K, True, "sigmoid", layer["bias"])
+    idx = np.asarray(idx)
+    held = _share(layer, 2, 2)
+    y, stats = moe.moe_experts(x, held, K, norm_topk=True, score="sigmoid",
+                               held=(2, 2))
+    here = (idx >= 2) & (idx < 4)
+    assert float(stats["expert_rows_local"]) == here.sum()
+    assert float(stats["experts_hit"]) == len(set(idx[here].tolist()))
+    assert float(stats["expert_rows_max"]) == max(
+        (idx == e).sum() for e in (2, 3))
+    # by hand: each token's held assignments only
+    want = np.zeros((T, D))
+    for t in range(T):
+        for g, e in zip(np.asarray(gates)[t], idx[t]):
+            if 2 <= e < 4:
+                h = jax.nn.silu(x[t] @ layer["w1"][e]) * (x[t] @ layer["w3"][e])
+                want[t] += float(g) * np.asarray(h @ layer["w2"][e])
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5, rtol=0)
+    # a token none of whose experts is here answers exactly zero
+    none_here = ~here.any(axis=1)
+    assert none_here.any() and not np.asarray(y)[none_here].any()
+    with pytest.raises(ValueError, match="the weights hold 2 experts"):
+        moe.moe_experts(x, held, K, held=(0, 4))

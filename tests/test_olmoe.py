@@ -195,8 +195,9 @@ def test_b_prefill_then_paged_decode_matches_the_reference_at_every_position():
             MC, tree, [prompt.tolist() + chain], last=1)[0][0]
         chain.append(int(np.argmax(ref)))
     assert np.asarray(toks)[0].tolist() == chain[1:]
-    hit, rows_max = np.asarray(stats)
-    assert hit == 2.0 and rows_max == 1.0       # one live row, two experts
+    hit, rows_max, local = np.asarray(stats)
+    # one live row, two experts, both held here (the chip holds every expert)
+    assert hit == 2.0 and rows_max == 1.0 and local == 2.0
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
@@ -467,7 +468,8 @@ def test_ring_routing_fields_sit_at_the_end_and_older_dumps_render(tmp_path, cap
     """Appended, never inserted (the 19 older names keep their positions);
     a 19-field tuple from a dump written before them goes through the zip
     fallback and ``tools/engine_dump.py`` prints it without them."""
-    assert STEP_FIELDS[-2:] == ("experts_hit", "expert_rows_max")
+    assert STEP_FIELDS[19:] == ("experts_hit", "expert_rows_max",
+                                "expert_rows_local")
     assert STEP_FIELDS[16:19] == ("prefill_ms", "chunk_ms", "emit_ms")
     fr = FlightRecorder(flight_dir=str(tmp_path))
     old = (time.time(), "continuous", 1.5, 8, 4, 1, 1, 3, 5, 2, 1, 2.0, 1, 1, 0, 0,
@@ -548,8 +550,8 @@ def test_g_dense_model_traces_the_jaxpr_it_traced_before(monkeypatch):
         v = (h @ attn["wv"]).reshape(b, s, n_kv, hd).transpose(0, 2, 1, 3)
         return q, k, v
 
-    def inline_head(params, x, dtype):
-        x = dense_lm._rmsnorm(x, params["ln_f"])
+    def inline_head(params, x, dtype, eps=1e-5):
+        x = dense_lm._rmsnorm(x, params["ln_f"], eps)
         return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
 
     monkeypatch.setattr(generation, "_qkv", inline_qkv)

@@ -70,7 +70,7 @@ class ParkedConversation:
     model_id: str
     history: np.ndarray                 # (tokens,) int32
     pages_k: np.ndarray
-    pages_v: np.ndarray
+    pages_v: np.ndarray | None
     k_scale: np.ndarray | None
     v_scale: np.ndarray | None
     page_tokens: int
@@ -103,8 +103,9 @@ def pack_parked(parked: ParkedConversation) -> bytes:
     arrays: list[tuple[str, np.ndarray]] = [
         ("history", parked.history),
         ("pages_k", parked.pages_k),
-        ("pages_v", parked.pages_v),
     ]
+    if parked.pages_v is not None:      # None: a one-sided (latent) arena
+        arrays.append(("pages_v", parked.pages_v))
     if parked.k_scale is not None:
         arrays.append(("k_scale", parked.k_scale))
     if parked.v_scale is not None:
@@ -151,7 +152,7 @@ def unpack_parked(blob: bytes | memoryview) -> ParkedConversation:
         model_id=header["model"],
         history=out["history"],
         pages_k=out["pages_k"],
-        pages_v=out["pages_v"],
+        pages_v=out.get("pages_v"),
         k_scale=out.get("k_scale"),
         v_scale=out.get("v_scale"),
         page_tokens=int(header["page_tokens"]),

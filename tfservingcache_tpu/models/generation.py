@@ -29,7 +29,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tfservingcache_tpu.models.mla_moe_lm import (
+    absorbed_output,
+    absorbed_query,
+    dense_absorbed_attention,
+    expanded_attention,
+    latent_project,
+    softmax_scale,
+)
 from tfservingcache_tpu.models.moe_lm import _moe_block
+from tfservingcache_tpu.models.registry import (
+    CacheRow,
+    kv_cache_row,
+    static_config,
+)
 from tfservingcache_tpu.models.transformer_lm import (
     _output_logits,
     _qkv,
@@ -46,18 +59,22 @@ warnings.filterwarnings(
 )
 
 
+def _cache_row(cfg) -> CacheRow:
+    """The row a program's config carries: ``registry.static_config`` puts the
+    ModelDef's ``cache_row`` under that key. A config made by hand with no row
+    (tests of the K/V families) means the decoder-LM K/V row."""
+    return cfg.get("cache_row") or kv_cache_row(cfg)
+
+
 def init_cache(cfg: dict, batch: int, max_len: int, mesh=None) -> dict:
     """Preallocated per-layer K/V buffers. bf16 storage halves HBM traffic;
     attention still accumulates in f32. ``mesh`` commits the buffers to
     KV-head shardings (parallel/sharding.kv_arena_shardings) so the slot
     jits compile partitioned programs from day one."""
-    n_kv = cfg["n_kv_heads"]
-    head_dim = cfg["d_model"] // cfg["n_heads"]
+    row = _cache_row(cfg)
     dtype = jnp.dtype(cfg["dtype"])
-    cache = {
-        "k": jnp.zeros((cfg["n_layers"], batch, n_kv, max_len, head_dim), dtype),
-        "v": jnp.zeros((cfg["n_layers"], batch, n_kv, max_len, head_dim), dtype),
-    }
+    shape = (cfg["n_layers"], batch, row.heads, max_len, row.width)
+    cache = {side: jnp.zeros(shape, dtype) for side in "kv"[:row.sides]}
     if mesh is not None:
         from tfservingcache_tpu.parallel.sharding import shard_kv_arena
 
@@ -137,11 +154,7 @@ def _generate_jit(
     # prefill the (right-padded) prompt block — the start_pos = 0 case of the
     # per-example forward; padding positions write junk K/V but the per-step
     # mask keeps them invisible until overwritten
-    logits, cache = _forward_cached_dyn(
-        params, input_ids, cache, jnp.zeros((b,), jnp.int32), cfg, family
-    )
-    # last REAL prompt token's logits seed the first sampled token
-    last = jnp.take_along_axis(logits, (prompt_len - 1)[:, None, None], axis=1)[:, 0]
+    last, cache = _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family)
     rng, sub = jax.random.split(rng)
     tok = _sample(last, sub, temperature, top_k)
 
@@ -150,7 +163,7 @@ def _generate_jit(
         max_new_tokens,
     )
     if return_cache:
-        return toks, cache["k"], cache["v"]
+        return toks, cache["k"], cache.get("v")
     return toks
 
 
@@ -183,15 +196,7 @@ def _generate_from_cache_jit(
     b, s_pad = suffix_ids.shape
     l_pad = cached_k.shape[3]
     max_len = l_pad + s_pad + max_new_tokens
-    cache = init_cache(cfg, b, max_len)
-    cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], cached_k.astype(cache["k"].dtype), (0, 0, 0, 0, 0)
-        ),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], cached_v.astype(cache["v"].dtype), (0, 0, 0, 0, 0)
-        ),
-    }
+    cache = _with_prefix(init_cache(cfg, b, max_len), cached_k, cached_v)
     start = cached_len.astype(jnp.int32)                  # (1,)
     logits, cache = _forward_cached_dyn(
         params, suffix_ids, cache, start, cfg, family
@@ -207,8 +212,37 @@ def _generate_from_cache_jit(
         cfg, family, max_new_tokens,
     )
     if return_cache:
-        return toks, cache["k"], cache["v"]
+        return toks, cache["k"], cache.get("v")
     return toks
+
+
+def _with_prefix(cache: dict, cached_k, cached_v) -> dict:
+    """``cache`` with a cached prefix's rows copied in from position 0, each
+    side the cache has (a one-sided cache's ``cached_v`` is None)."""
+    return {
+        side: jax.lax.dynamic_update_slice(
+            cache[side], rows.astype(cache[side].dtype), (0, 0, 0, 0, 0))
+        for side, rows in (("k", cached_k), ("v", cached_v)) if side in cache
+    }
+
+
+def _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family):
+    """The forward of whole (right-padded) prompts into a fresh cache, the
+    start_pos = 0 case of ``_forward_cached_dyn`` -> (the last REAL prompt
+    token's logits ``(B, V)`` f32, the cache). Padding positions write junk
+    rows the per-step mask keeps invisible until overwritten. A latent family
+    projects that one position through the head and no other (a long
+    prompt's ``S_pad x V`` float32 logits are a gigabyte at 8192 x 32768)."""
+    b = input_ids.shape[0]
+    latent = _cache_row(cfg).sides == 1
+    logits, cache = _forward_cached_dyn(
+        params, input_ids, cache, jnp.zeros((b,), jnp.int32), cfg, family,
+        fresh=True, logits_at=prompt_len - 1 if latent else None,
+    )
+    if latent:
+        return logits[:, 0], cache
+    return jnp.take_along_axis(
+        logits, (prompt_len - 1)[:, None, None], axis=1)[:, 0], cache
 
 
 def _sampling_lanes(temperature, top_k, vocab: int, active=None):
@@ -305,14 +339,11 @@ def _slot_prefill_jit(
     skips prefill compute entirely."""
     cfg = dict(cfg_key)
     b, s_max = input_ids.shape
-    cache = init_cache(cfg, b, s_max)
-    logits, cache = _forward_cached_dyn(
-        params, input_ids, cache, jnp.zeros((b,), jnp.int32), cfg, family
-    )
-    last = jnp.take_along_axis(logits, (prompt_len - 1)[:, None, None], axis=1)[:, 0]
+    last, cache = _prefill_fresh(
+        params, input_ids, prompt_len, init_cache(cfg, b, s_max), cfg, family)
     _, sub = jax.random.split(rng)
     tok = _sample(last, sub, temperature, top_k)
-    return tok, cache["k"], cache["v"], last
+    return tok, cache["k"], cache.get("v"), last
 
 
 @functools.partial(jax.jit, static_argnames=("cfg_key", "family"))
@@ -337,15 +368,7 @@ def _slot_prefill_from_cache_jit(
     cfg = dict(cfg_key)
     b, s_pad = suffix_ids.shape
     l_pad = cached_k.shape[3]
-    cache = init_cache(cfg, b, l_pad + s_pad)
-    cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], cached_k.astype(cache["k"].dtype), (0, 0, 0, 0, 0)
-        ),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], cached_v.astype(cache["v"].dtype), (0, 0, 0, 0, 0)
-        ),
-    }
+    cache = _with_prefix(init_cache(cfg, b, l_pad + s_pad), cached_k, cached_v)
     start = cached_len.astype(jnp.int32)
     logits, cache = _forward_cached_dyn(
         params, suffix_ids, cache, start, cfg, family
@@ -355,7 +378,7 @@ def _slot_prefill_from_cache_jit(
     )[:, 0]
     _, sub = jax.random.split(rng)
     tok = _sample(last, sub, temperature, top_k)
-    return tok, cache["k"], cache["v"], last
+    return tok, cache["k"], cache.get("v"), last
 
 
 @jax.jit
@@ -370,7 +393,8 @@ def _sample_logits_jit(last, rng, temperature, top_k):
 
 
 def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
-                     arena_dtype: str = "", mesh=None) -> dict:
+                     arena_dtype: str = "", mesh=None,
+                     row: CacheRow | None = None) -> dict:
     """Preallocated paged KV arena shared by every lane of one model's
     continuous-decode state: fixed-size pages instead of per-lane
     ``max_seq`` rows, so HBM is sized by tokens in flight, not worst case.
@@ -394,11 +418,19 @@ def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
     and the free-list stay
     host-side, so reserve/CoW/publish/census run unchanged on the sharded
     arena; every jit that donates the arena round-trips the committed
-    layout, keeping donation effective."""
-    n_kv = cfg["n_kv_heads"]
-    head_dim = cfg["d_model"] // cfg["n_heads"]
+    layout, keeping donation effective.
+
+    ``row`` (the family's ``ModelDef.cache_row``; a config with no row means
+    the decoder-LM K/V row, ``_cache_row``) says what a page holds: ``sides``
+    arrays of ``(heads, page_tokens, width)`` tiles. A latent family's arena
+    is ONE side, ``k``, of one shared row a token; there is no ``v``."""
+    row = row or _cache_row(cfg)
     dtype = jnp.dtype(cfg["dtype"])
-    shape = (cfg["n_layers"], n_pages, n_kv, page_tokens, head_dim)
+    shape = (cfg["n_layers"], n_pages, row.heads, page_tokens, row.width)
+    if row.sides == 1:
+        if arena_dtype == "int8":
+            raise ValueError("a latent (one-sided) arena has no int8 form")
+        return {"k": jnp.zeros(shape, jnp.dtype(arena_dtype or dtype))}
     if arena_dtype == "int8":
         sshape = shape[:-1]
         cache = {
@@ -450,10 +482,13 @@ def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows):
     An int8 arena (``k_scale`` present) quantizes each row here, with
     per-row scales, so resident rows are never requantized. Lanes parked on
     the trash page may collide: last-writer-wins junk that no live lane's
-    block table can reach. Returns the updated cache."""
+    block table can reach. A one-sided arena (latent rows) has no ``v``:
+    ``v_rows`` is None. Returns the updated cache."""
     with jax.named_scope("kv_write"):
         heads = jnp.arange(k_rows.shape[2])[None, None, :]
         at = (li, pages[:, :, None], heads, off[:, :, None])     # (S, T, n_kv)
+        if v_rows is None:
+            return {"k": cache["k"].at[at].set(k_rows.astype(cache["k"].dtype))}
         new = {}
         if "k_scale" in cache:
             k_rows, k_s = _quantize_kv_rows(k_rows)
@@ -506,17 +541,22 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     lanes) goes to ``paged_attention`` as it is: the caller discards an
     inactive lane's token, so the kernel reads no page for it, and an expert
     layer routes it to no expert. Each expert layer's ``(experts_hit,
-    expert_rows_max)`` is appended to ``moe_stats`` where the caller gives a
-    list (a dense model appends nothing)."""
+    expert_rows_max, expert_rows_local)`` is appended to ``moe_stats`` where
+    the caller gives a list (a dense model appends nothing).
+
+    A latent family (one-sided arena) writes its ONE row a token and attends
+    in the absorbed form, ``paged_latent_attention``: the fused kernel at
+    T = 1, the gather + einsum reference above it."""
     from tfservingcache_tpu.ops.attention import (
         paged_attention,
         paged_attention_verify,
+        paged_latent_attention,
     )
 
     dtype = jnp.dtype(cfg["dtype"])
     s_lanes, t_q = toks.shape
-    n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"]
-    head_dim = cfg["d_model"] // n_heads
+    row = _cache_row(cfg)
+    eps = cfg.get("rms_eps", 1e-5)
     pps = tables.shape[1]
     positions = pos[:, None] + jnp.arange(t_q)[None, :]          # (S, T)
     pages = jnp.take_along_axis(
@@ -535,29 +575,40 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
         with jax.named_scope("layer"):
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
-                q = _rope_per_example(q, positions, cfg["rope_theta"])
-                k = _rope_per_example(k, positions, cfg["rope_theta"])
-            cache = _paged_write_rows(
-                cache, li, pages, off,
-                k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-            )
-            with jax.named_scope("attn"):
-                operands = (q, cache["k"], cache["v"], tables, pos,
-                            page_tokens, cache.get("k_scale"),
-                            cache.get("v_scale"))
-                if t_q == 1:
-                    out = paged_attention(*operands, kernel=kernel,
-                                          active=active, layer=li)
+                a = _rmsnorm(x, layer["ln1"], eps)
+                if row.sides == 1:
+                    q_n, q_r, k = latent_project(attn, a, positions, cfg)
+                    q = absorbed_query(attn, q_n, q_r, cfg)
+                    k, v = k[:, :, None], None                   # (S, T, 1, W)
                 else:
-                    out = paged_attention_verify(*operands, kernel=kernel,
-                                                 layer=li)
-                out = out.reshape(s_lanes, n_heads, t_q, head_dim).astype(x.dtype)
-                out = out.transpose(0, 2, 1, 3).reshape(s_lanes, t_q, cfg["d_model"])
-                x = x + out @ attn["wo"]
+                    q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"])
+                    q = _rope_per_example(q, positions, cfg["rope_theta"])
+                    k = _rope_per_example(k, positions, cfg["rope_theta"])
+                    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            cache = _paged_write_rows(cache, li, pages, off, k, v)
+            with jax.named_scope("attn"):
+                if row.sides == 1:
+                    out = paged_latent_attention(
+                        q, cache["k"], tables, pos, page_tokens,
+                        row.value_width, softmax_scale(cfg), kernel=kernel,
+                        active=active if t_q == 1 else None, layer=li)
+                    x = x + absorbed_output(attn, out, cfg, dtype)
+                else:
+                    operands = (q, cache["k"], cache["v"], tables, pos,
+                                page_tokens, cache.get("k_scale"),
+                                cache.get("v_scale"))
+                    if t_q == 1:
+                        out = paged_attention(*operands, kernel=kernel,
+                                              active=active, layer=li)
+                    else:
+                        out = paged_attention_verify(*operands, kernel=kernel,
+                                                     layer=li)
+                    out = out.reshape(s_lanes, cfg["n_heads"], t_q, row.width)
+                    out = out.astype(x.dtype).transpose(0, 2, 1, 3)
+                    x = x + out.reshape(s_lanes, t_q, cfg["d_model"]) @ attn["wo"]
             x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
                                moe_stats=moe_stats)
-    return _output_logits(params, x, dtype), cache
+    return _output_logits(params, x, dtype, eps), cache
 
 
 @functools.partial(
@@ -588,21 +639,39 @@ def _paged_prefill_chunk_jit(params, arena_k, arena_v, scales, table_row,
     final chunk feeds through the split-then-sample helper for a first
     token bit-identical in discipline to the monolithic prefill."""
     cfg = dict(cfg_key)
-    cache = {"k": arena_k, "v": arena_v}
-    if scales is not None:
-        cache["k_scale"] = scales["k"]
-        cache["v_scale"] = scales["v"]
+    cache = _arena_cache(arena_k, arena_v, scales)
     logits, cache = _paged_verify_step(
         params, toks, cache, table_row, start, cfg, family, page_tokens,
         kernel=kernel,
     )
     idx = jnp.clip(real_len - 1, 0, toks.shape[1] - 1)
     last = jnp.take_along_axis(logits, idx[:, None, None], axis=1)[:, 0]
-    out_scales = (
-        {"k": cache["k_scale"], "v": cache["v_scale"]}
-        if "k_scale" in cache else None
-    )
-    return cache["k"], cache["v"], out_scales, last
+    return (*_cache_arena(cache), last)
+
+
+def _arena_cache(arena_k, arena_v, scales) -> dict:
+    """The arena as the jits take it (``k``, ``v`` or None for a one-sided
+    arena, the int8 arena's ``scales`` or None) -> the cache dict the steps
+    carry."""
+    cache = {"k": arena_k}
+    if arena_v is not None:
+        cache["v"] = arena_v
+    if scales is not None:
+        cache["k_scale"] = scales["k"]
+        cache["v_scale"] = scales["v"]
+    return cache
+
+
+def _cache_arena(cache: dict) -> tuple:
+    """``_arena_cache``'s inverse -> (k, v | None, scales | None)."""
+    scales = ({"k": cache["k_scale"], "v": cache["v_scale"]}
+              if "k_scale" in cache else None)
+    return cache["k"], cache.get("v"), scales
+
+
+# ``fn`` over every side of an arena that exists: a one-sided (latent) arena's
+# ``v`` is None, an empty pytree, and stays None
+_each_side = jax.tree_util.tree_map
 
 
 @functools.partial(
@@ -638,6 +707,15 @@ def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
     # (layers, 1, n_kv, P_pad, hd) -> (P_pad, layers, n_kv, hd): the two
     # advanced indices below are non-adjacent, so their broadcast dim moves
     # to the front of the updated slice
+    if arena_v is None:
+        # one-sided (latent) arena, one head: layer, page, head and offset are
+        # all INDICES of the scatter and its window is one row, as in
+        # ``_paged_write_rows`` (with the layers and the head in the window
+        # the TPU compiler converts the WHOLE arena to a layout of the
+        # scatter's own and back, two arena-sized copies an admission)
+        layers = jnp.arange(arena_k.shape[0])[:, None]
+        return (arena_k.at[layers, pages[None, :], 0, offs[None, :]].set(
+            pk[:, 0, 0].astype(arena_k.dtype)), None, None)
     kv = pk[:, 0].transpose(2, 0, 1, 3)
     vv = pv[:, 0].transpose(2, 0, 1, 3)
     if scales is not None:
@@ -663,15 +741,14 @@ def _paged_gather_prefix_jit(arena_k, arena_v, scales, pages):
     (``scales`` not None) is dequantized here: the suffix prefill runs on
     dense f32 rows either way."""
     # arena: (layers, n_pages, n_kv, page_tokens, hd); pages: (n,) i32
-    k = arena_k[:, pages]                       # (L, n, n_kv, pt, hd)
-    v = arena_v[:, pages]
+    k, v = _each_side(lambda a: a[:, pages], (arena_k, arena_v))  # (L, n, n_kv, pt, hd)
     if scales is not None:
         k = k.astype(jnp.float32) * scales["k"][:, pages][..., None]
         v = v.astype(jnp.float32) * scales["v"][:, pages][..., None]
     layers, n, n_kv, pt, hd = k.shape
-    k = k.swapaxes(1, 2).reshape(layers, n_kv, n * pt, hd)[:, None]
-    v = v.swapaxes(1, 2).reshape(layers, n_kv, n * pt, hd)[:, None]
-    return k, v
+    return _each_side(
+        lambda a: a.swapaxes(1, 2).reshape(layers, n_kv, n * pt, hd)[:, None],
+        (k, v))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -683,8 +760,8 @@ def _page_copy_jit(arena_k, arena_v, scales, src, dst):
     compiled program — the decode-chunk program count is untouched. An int8
     arena's per-row scales (``scales`` {"k","v"}, donated) travel with the
     page bytes — a CoW'd or published page stays bit-identical."""
-    arena_k = arena_k.at[:, dst].set(arena_k[:, src])
-    arena_v = arena_v.at[:, dst].set(arena_v[:, src])
+    arena_k, arena_v = _each_side(
+        lambda a: a.at[:, dst].set(a[:, src]), (arena_k, arena_v))
     if scales is not None:
         scales = {
             "k": scales["k"].at[:, dst].set(scales["k"][:, src]),
@@ -703,8 +780,7 @@ def _pages_export_jit(arena_k, arena_v, scales, pages):
     the parked bytes must re-import bit-identical, and int8 + scales is
     half the host/disk footprint of dense rows. One compile per distinct
     page count, bounded by pages_per_slot."""
-    k = arena_k[:, pages]
-    v = arena_v[:, pages]
+    k, v = _each_side(lambda a: a[:, pages], (arena_k, arena_v))
     if scales is None:
         return k, v, None
     return k, v, {"k": scales["k"][:, pages], "v": scales["v"][:, pages]}
@@ -720,7 +796,8 @@ def _pages_import_jit(arena_k, arena_v, scales, pages, pk, pv, pscales):
     lane that never retired. One compile per page count, same bound as the
     export."""
     arena_k = arena_k.at[:, pages].set(pk.astype(arena_k.dtype))
-    arena_v = arena_v.at[:, pages].set(pv.astype(arena_v.dtype))
+    if arena_v is not None:
+        arena_v = arena_v.at[:, pages].set(pv.astype(arena_v.dtype))
     if scales is not None:
         scales = {
             "k": scales["k"].at[:, pages].set(pscales["k"]),
@@ -767,12 +844,11 @@ def _paged_decode_chunk_jit(
     (x2 for the ``kernel`` boolean — the serving.kv_paged_kernel gate).
 
     The last output is the chunk's routing stats for a model with expert
-    layers: float32 ``(experts_hit, expert_rows_max)``, each a mean over the
-    chunk's steps and layers, computed by the program and fetched with the
-    tokens; ``None`` (no output at all) for a dense model, whose program is
-    therefore the one it was."""
+    layers: float32 ``(experts_hit, expert_rows_max, expert_rows_local)``,
+    each a mean over the chunk's steps and layers, computed by the program
+    and fetched with the tokens; ``None`` (no output at all) for a dense
+    model, whose program is therefore the one it was."""
     cfg = dict(cfg_key)
-    quantized = scales is not None
 
     def step(carry, rng):
         cache, tok, pos = carry
@@ -784,24 +860,23 @@ def _paged_decode_chunk_jit(
         nxt = _sample_per_row(logits[:, 0], rng, temperature, top_k, active)
         nxt = jnp.where(active, nxt, tok)
         pos = pos + active.astype(jnp.int32)
-        # (2,): the step's mean over its expert layers; nothing for a dense model
+        # (3,): the step's mean over its expert layers; nothing for a dense model
         stats = jnp.mean(jnp.stack(layer_stats), axis=0) if layer_stats else None
         return (cache, nxt, pos), (nxt, stats)
 
-    cache = {"k": arena_k, "v": arena_v}
-    if quantized:
-        cache["k_scale"] = scales["k"]
-        cache["v_scale"] = scales["v"]
     (cache, tok, pos), (toks, stats) = jax.lax.scan(
-        step, (cache, tok, pos), rngs, length=chunk
-    )
-    scales = (
-        {"k": cache["k_scale"], "v": cache["v_scale"]} if quantized else None
+        step, (_arena_cache(arena_k, arena_v, scales), tok, pos), rngs,
+        length=chunk
     )
     if stats is not None:
         stats = jnp.mean(stats, axis=0)
-    return (cache["k"], cache["v"], scales, tok, pos,
-            jnp.transpose(toks, (1, 0)), stats)  # (S, chunk), (2,) | None
+    return (*_cache_arena(cache), tok, pos,
+            jnp.transpose(toks, (1, 0)), stats)  # (S, chunk), (3,) | None
+
+
+# what a decode chunk reports of its expert layers, in the order of its last
+# output (``ops.moe.moe_experts``' stats; the ring's fields of the same names)
+MOE_STATS = ("experts_hit", "expert_rows_max", "expert_rows_local")
 
 
 def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
@@ -811,13 +886,12 @@ def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
     holds: ``moe`` = the routed expert layer, else the dense SwiGLU ``mlp``.
     ``row_mask`` (one flag a row of ``x`` flattened) marks rows whose answer
     nobody reads: the expert layer routes them nowhere. An expert layer's
-    routing stats (``experts_hit``, ``expert_rows_max``) are appended to
-    ``moe_stats`` where the caller gives a list."""
+    routing stats (``MOE_STATS``) are appended to ``moe_stats`` where the
+    caller gives a list."""
     if "moe" in layer:
         y, stats = _moe_block(layer, x, cfg, dtype, row_mask=row_mask)
         if moe_stats is not None:
-            moe_stats.append(
-                jnp.stack([stats["experts_hit"], stats["expert_rows_max"]]))
+            moe_stats.append(jnp.stack([stats[name] for name in MOE_STATS]))
         return y
     with jax.named_scope("ffn"):
         h = _rmsnorm(x, layer["ln2"])
@@ -825,21 +899,58 @@ def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
         return (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
 
 
+def _latent_cached_layer(attn, a, rows_layer, start_pos, positions, cfg,
+                         fresh: bool):
+    """The attention half of a latent layer against a DENSE cache: the new
+    rows written at each example's ``start_pos`` -> (residual delta, the
+    layer's rows ``(B, 1, L, W)``). ``fresh`` (every ``start_pos`` is 0 and
+    the cache holds nothing) attends among the tokens at hand in the expanded
+    form, through ``ops.attention.attention``; otherwise the absorbed form
+    reads the whole cache."""
+    q_n, q_r, rows = latent_project(attn, a, positions, cfg)
+    with jax.named_scope("kv_write"):
+        rows_layer = jax.vmap(
+            lambda c, new, p: jax.lax.dynamic_update_slice(c, new[None], (0, p, 0))
+        )(rows_layer, rows.astype(rows_layer.dtype), start_pos)
+    if fresh:
+        return expanded_attention(attn, q_n, q_r, rows, cfg), rows_layer
+    out = dense_absorbed_attention(
+        absorbed_query(attn, q_n, q_r, cfg), rows_layer[:, 0], positions, cfg)
+    return absorbed_output(attn, out, cfg, a.dtype), rows_layer
+
+
 def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
-                        family: str = "transformer_lm"):
+                        family: str = "transformer_lm", fresh: bool = False,
+                        logits_at=None):
     """Like _forward_cached but with PER-EXAMPLE start positions (B,) —
-    needed because prompts in one batch have different true lengths."""
+    needed because prompts in one batch have different true lengths.
+    ``fresh`` promises every start is 0 and the cache empty (a latent family
+    then takes its expanded form; the K/V families compute the same either
+    way). ``logits_at (B,)`` projects that one position of each example
+    through the head -> logits ``(B, 1, V)``; None = every position."""
     dtype = jnp.dtype(cfg["dtype"])
     b, s_len = input_ids.shape
-    n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"]
-    head_dim = cfg["d_model"] // n_heads
     positions = start_pos[:, None] + jnp.arange(s_len)[None, :]   # (B, S)
+    latent = _cache_row(cfg).sides == 1
+    eps = cfg.get("rms_eps", 1e-5)
 
     with jax.named_scope("embed"):
         x = params["embed"][input_ids].astype(dtype)
     new_k, new_v = [], []
+    n_heads, n_kv = cfg["n_heads"], cfg.get("n_kv_heads")
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope("layer"):
+            if latent:
+                with jax.named_scope("attn"):
+                    attn = jax.tree_util.tree_map(
+                        lambda w: w.astype(dtype), layer["attn"])
+                    out, rows = _latent_cached_layer(
+                        attn, _rmsnorm(x, layer["ln1"], eps), cache["k"][li],
+                        start_pos, positions, cfg, fresh)
+                    new_k.append(rows)
+                x = x + out
+                x = x + _ffn_block(layer, x, cfg, dtype)
+                continue
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
                 q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
@@ -889,9 +1000,13 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
                 out = out.transpose(0, 2, 1, 3).reshape(b, s_len, cfg["d_model"])
                 x = x + out @ attn["wo"]
             x = x + _ffn_block(layer, x, cfg, dtype)
-    logits = _output_logits(params, x, dtype)
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
+    logits = _output_logits(params, x, dtype, eps)
     with jax.named_scope("kv_write"):
-        new_cache = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+        new_cache = {"k": jnp.stack(new_k)}
+        if new_v:
+            new_cache["v"] = jnp.stack(new_v)
     return logits, new_cache
 
 
@@ -944,7 +1059,7 @@ def generate(  # static-bounded: cfg_key, max_new_tokens, return_cache -- cfg_ke
         )
     if rng is None:
         rng = jax.random.PRNGKey(0)
-    cfg_key = tuple(sorted((k, v) for k, v in cfg.items()))
+    cfg_key = static_config(model_def)
     return _generate_jit(
         params,
         input_ids,
@@ -982,7 +1097,7 @@ def generate_from_cache(  # static-bounded: cfg_key, max_new_tokens, return_cach
     if rng is None:
         rng = jax.random.PRNGKey(0)
     cfg = model_def.config
-    cfg_key = tuple(sorted((k, v) for k, v in cfg.items()))
+    cfg_key = static_config(model_def)
     return _generate_from_cache_jit(
         params,
         jnp.asarray(suffix_ids, jnp.int32),

@@ -33,7 +33,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from tfservingcache_tpu.models.registry import ModelDef, TensorSpec, register
+from tfservingcache_tpu.models.registry import (
+    ModelDef,
+    TensorSpec,
+    kv_cache_row,
+    register,
+)
 from tfservingcache_tpu.models.transformer_lm import (
     _attention_block,
     _output_logits,
@@ -65,14 +70,29 @@ def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
                partitioned: bool = False) -> tuple[jax.Array, dict]:
     """The expert half of a layer over the residual stream ``x (B, S, D)``
     BEFORE its norm -> (residual delta, the layer's routing stats).
-    ``row_mask (B*S,)`` marks rows whose answer nobody reads."""
+    ``row_mask (B*S,)`` marks rows whose answer nobody reads. What the config
+    may add to the plain top-k layer: the router's ``route_score`` /
+    ``route_scale``, the chip's share ``n_experts_held`` experts from
+    ``expert_first`` (``ops.moe.moe_experts``' ``held``), and, where the layer
+    holds ``moe/shared``, a dense SwiGLU expert every token takes, computed
+    once beside the routed ones."""
     b, s, d = x.shape
-    moe = {"router": layer["moe"]["router"]}            # routing stays f32
+    moe = {w: layer["moe"][w] for w in ("router", "bias")
+           if w in layer["moe"]}                        # routing stays f32
     moe.update((w, layer["moe"][w].astype(dtype)) for w in ("w1", "w2", "w3"))
+    held = ((int(cfg.get("expert_first", 0)), int(cfg["n_experts_held"]))
+            if "n_experts_held" in cfg else None)
+    z = _rmsnorm(x, layer["ln2"], cfg.get("rms_eps", 1e-5)).reshape(b * s, d)
     y, stats = moe_experts(
-        _rmsnorm(x, layer["ln2"]).reshape(b * s, d), moe, int(cfg["top_k"]),
+        z, moe, int(cfg["top_k"]),
         norm_topk=bool(cfg["norm_topk_prob"]), row_mask=row_mask,
-        partitioned=partitioned)
+        partitioned=partitioned, score=cfg.get("route_score", "softmax"),
+        route_scale=float(cfg.get("route_scale", 1.0)), held=held)
+    if "shared" in layer["moe"]:
+        with jax.named_scope("shared"):
+            sh = jax.tree_util.tree_map(lambda w: w.astype(dtype),
+                                        layer["moe"]["shared"])
+            y = y + (jax.nn.silu(z @ sh["w1"]) * (z @ sh["w3"])) @ sh["w2"]
     return y.reshape(b, s, d), stats
 
 
@@ -212,4 +232,5 @@ def build(config: dict) -> ModelDef:
         bind_mesh=make_apply,
         # no capacity, no dropped token: a row's answer is its own
         engine_ready=True,
+        cache_row=kv_cache_row(cfg),
     )

@@ -61,6 +61,38 @@ class TensorSpec:
         return np.dtype(self.dtype)
 
 
+@dataclass(frozen=True)
+class CacheRow:
+    """What one token leaves in one layer's cache, the unit the dense cache
+    and the paged arena (models/generation.py) are made of: ``sides`` arrays
+    of ``(heads, width)`` rows. A decoder with K and V rows has two sides of
+    ``(n_kv_heads, head_dim)``; latent attention has ONE side of one shared
+    row a token (``[c_kv | rope(k_r)]``, stored ``width`` wide), which every
+    query head reads as its key and, in its first ``value_width`` columns, as
+    its value."""
+
+    sides: int
+    heads: int
+    width: int
+    value_width: int = 0     # latent rows only: the value is a prefix of the key
+
+
+def kv_cache_row(cfg: Mapping[str, Any]) -> CacheRow:
+    """The decoder-LM families' row: K and V of ``(n_kv_heads, d_model /
+    n_heads)`` — the one place that derives it."""
+    return CacheRow(2, int(cfg["n_kv_heads"]),
+                    int(cfg["d_model"]) // int(cfg["n_heads"]))
+
+
+def latent_cache_row(cfg: Mapping[str, Any]) -> CacheRow:
+    """The latent-attention row ``[c_kv | rope(k_r)]``: ``kv_lora_rank +
+    qk_rope_head_dim`` columns, stored padded with zeros to a whole number of
+    128-lane tiles (Mosaic slices an HBM operand in whole tiles only; the pad
+    multiplies zero query columns), the value its first ``kv_lora_rank``."""
+    used = int(cfg["kv_lora_rank"]) + int(cfg["qk_rope_head_dim"])
+    return CacheRow(1, 1, -(-used // 128) * 128, int(cfg["kv_lora_rank"]))
+
+
 @dataclass
 class ModelDef:
     """A built, servable model family instance.
@@ -113,11 +145,27 @@ class ModelDef:
     # (partition_rules) and XLA inserts the collectives.
     bind_mesh: Callable[[Any], Callable[[Any, Mapping[str, Any]], dict[str, Any]]] | None = None
     # the family declares that the continuous engine may serve it: its only
-    # per-request layer state is K/V rows in the decoder-LM cache layout
-    # (models/generation.py), and its step is row-invariant — a row's logits
-    # do not depend on the rows beside it, so strangers can share a decode
-    # step. The engine and the arena ask this, not the name.
+    # per-request layer state is the rows ``cache_row`` describes, one a
+    # token a layer (models/generation.py), and its step is row-invariant — a
+    # row's logits do not depend on the rows beside it, so strangers can
+    # share a decode step. The engine and the arena ask this, not the name.
     engine_ready: bool = False
+    # what a cache row is (``CacheRow``: sides, heads, width): the dense cache,
+    # the paged arena and its byte accounting are built from it. Every
+    # ``engine_ready`` family declares one.
+    cache_row: CacheRow | None = None
+
+
+def static_config(model: ModelDef) -> tuple:
+    """The hashable form of a family's config that the programs of
+    models/generation.py are specialised on (their static ``cfg_key``): the
+    config's items, sorted, and under ``cache_row`` the row the ModelDef
+    declares. The one place a row enters shared code: nothing there derives
+    it from a config key of some family."""
+    items = dict(model.config)
+    if model.cache_row is not None:
+        items["cache_row"] = model.cache_row
+    return tuple(sorted(items.items()))
 
 
 _REGISTRY: dict[str, Callable[[dict[str, Any]], ModelDef]] = {}
@@ -169,6 +217,7 @@ def build(family: str, config: dict[str, Any] | None = None) -> ModelDef:
 
 _BUILTIN_MODULES = (
     "half_plus_two", "mnist_cnn", "bert", "resnet", "transformer_lm", "t5", "moe_lm",
+    "mla_moe_lm",
 )
 
 

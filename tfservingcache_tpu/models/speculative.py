@@ -39,6 +39,7 @@ from tfservingcache_tpu.models.generation import (
     _sample_per_row,
     init_cache,
 )
+from tfservingcache_tpu.models.registry import static_config
 
 
 def _greedy(logits) -> jax.Array:
@@ -465,10 +466,9 @@ def speculative_generate(
             f"prompt {s} + max_new_tokens {max_new_tokens} exceeds max_seq "
             f"{model_def_t.config['max_seq']}"
         )
-    key = lambda cfg: tuple(sorted((k, v) for k, v in cfg.items()))
     common = dict(
-        cfg_t_key=key(model_def_t.config),
-        cfg_d_key=key(model_def_d.config),
+        cfg_t_key=static_config(model_def_t),
+        cfg_d_key=static_config(model_def_d),
         max_new_tokens=max_new_tokens,
         spec_tokens=spec_tokens,
         family_t=model_def_t.family,

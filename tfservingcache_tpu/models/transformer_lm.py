@@ -23,7 +23,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from tfservingcache_tpu.models.registry import ModelDef, TensorSpec, register
+from tfservingcache_tpu.models.registry import (
+    ModelDef,
+    TensorSpec,
+    kv_cache_row,
+    register,
+)
 from tfservingcache_tpu.ops.attention import attention
 
 DEFAULT_CONFIG: dict[str, Any] = {
@@ -96,11 +101,12 @@ def _qkv(attn: dict, h: jax.Array, n_heads: int, n_kv: int):
 
 
 @jax.named_scope("lm_head")
-def _output_logits(params: dict, x: jax.Array, dtype) -> jax.Array:
+def _output_logits(params: dict, x: jax.Array, dtype,
+                   eps: float = 1e-5) -> jax.Array:
     """Final norm and output head -> float32 logits (a stable softmax/argmax
     downstream). An ``lm_head (d, vocab)`` leaf is the untied head; without
     it the head is the embedding."""
-    x = _rmsnorm(x, params["ln_f"])
+    x = _rmsnorm(x, params["ln_f"], eps)
     if "lm_head" in params:
         return (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
     return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
@@ -286,4 +292,5 @@ def build(config: dict) -> ModelDef:
         # kernel out of a partitioned program (_attention_block)
         bind_mesh=make_apply,
         engine_ready=True,
+        cache_row=kv_cache_row(cfg),
     )

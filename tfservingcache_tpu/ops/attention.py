@@ -57,7 +57,8 @@ def _record_dispatch(gate: str, branch: str, reason: str, *shapes: tuple) -> Non
 def dispatch_tally() -> dict[tuple[str, str, str], int]:
     """Snapshot of ``{(gate, branch, reason): traces}`` since process start.
     Gates: ``attention``, ``paged_attention``, ``paged_attention_verify``,
-    ``ring_attention``; branch is ``"kernel"`` or ``"reference"``."""
+    ``paged_latent_attention``, ``ring_attention``, ``moe_experts``; branch
+    is ``"kernel"`` or ``"reference"``."""
     with _DISPATCH_LOCK:
         return dict(_DISPATCH_TALLY)
 
@@ -1072,6 +1073,261 @@ def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL
         )
     return paged_decode_attention(q, k_pages, v_pages, tables, pos,
                                   page_tokens, layer, k_scale, v_scale)
+
+
+# ---------------------------------------------------------------------------
+# Paged LATENT attention (MLA's absorbed form over one shared row a token)
+# ---------------------------------------------------------------------------
+
+# Tokens one compute block of the latent kernel covers. A latent page is one
+# (page_tokens, width) tile of one shared row a token, 12 KiB at 16 x 384
+# bf16, where a K/V page is a tile a kv head: two blocks of PAGED_BLOCK_TOKENS
+# in flight are 0.2 MB, far under what the HBM moves in a copy's latency, so
+# the block is wide (tests/test_mla_moe.py's on-chip rows time the choices).
+LATENT_BLOCK_TOKENS = 2048
+# Page copies issued (unrolled) a round of the kernel's copy loop.
+LATENT_COPY_GROUP = 8
+
+
+def paged_latent_attention_reference(
+    q: jax.Array, pages: jax.Array, tables: jax.Array, pos: jax.Array,
+    page_tokens: int, value_width: int, sm_scale: float, layer: int = 0,
+) -> jax.Array:
+    """Absorbed latent attention over the paged arena, gather + einsum: the
+    reference of ``paged_latent_decode_attention_kernel`` and the path of
+    every T > 1 forward (verify, chunked prefill).
+
+    q ``(S, H, T, W)``: each head's query against the latent row (the
+    absorbed ``q_n W_kvb,K`` beside ``rope(q_r)``, zero in the row's pad
+    columns); ``pages`` the one-sided arena ``(layers, n_pages, 1,
+    page_tokens, W)`` read at ``layer``; query ``t`` of lane ``s`` sits at
+    ``pos[s] + t`` and sees rows at or below it. All H heads read the SAME
+    row (group H over one kv head); a row's first ``value_width`` columns are
+    its value. Returns f32 ``(S, H, T, value_width)``."""
+    rows = paged_gather_kv(pages, tables, page_tokens, layer)[:, 0]  # (S, L, W)
+    t = q.shape[2]
+    s = jnp.einsum("shtw,slw->shtl", q, rows,
+                   preferred_element_type=jnp.float32) * sm_scale
+    q_pos = pos[:, None] + jnp.arange(t)[None, :]                    # (S, T)
+    mask = jnp.arange(rows.shape[1])[None, None, :] <= q_pos[:, :, None]
+    s = jnp.where(mask[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("shtl,slc->shtc", p.astype(rows.dtype),
+                      rows[..., :value_width],
+                      preferred_element_type=jnp.float32)
+
+
+def _paged_latent_decode_kernel(
+    tables_ref, pos_ref, active_ref, q_ref, kv_hbm, o_ref, buf, sem, acc_s,
+    m_s, l_s, *, sm_scale: float, page_tokens: int, block_pages: int,
+    value_width: int, layer: int,
+):
+    """One LANE of latent decode attention, after ``_paged_decode_kernel``'s
+    plan: a grid step a lane, a double-buffered loop over the lane's LIVE
+    pages copied out of the arena in HBM by the kernel itself, nothing for an
+    inactive lane. What differs is the row: ONE ``(page_tokens, W)`` tile a
+    page, shared by all H heads, so the score product is ``(H, W) x (tokens,
+    W)^T`` and the value product takes the same block's first ``value_width``
+    columns. A page is 12 KiB where a K/V page with its heads is 32 or 64, so
+    a block is many pages (``LATENT_BLOCK_TOKENS``) and its copies are issued
+    by a loop over the block's LIVE groups of pages only: the last block's
+    dead groups are copied by nobody, keep what an earlier block (or the
+    zeroing at the first lane) left there, finite, and are masked out of the
+    scores."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lane = pl.program_id(0)
+    pos = pos_ref[lane]
+    block_tokens = block_pages * page_tokens
+    n_live = jnp.minimum(pos // page_tokens + 1, tables_ref.shape[1])
+    n_blocks = jnp.where(active_ref[lane] != 0,
+                         pl.cdiv(n_live, block_pages), 0)
+
+    @pl.when(lane == 0)
+    def _finite():
+        buf[...] = jnp.zeros_like(buf)
+
+    def groups_in(blk):
+        """Groups of ``LATENT_COPY_GROUP`` pages of block ``blk`` that hold a
+        live page."""
+        return pl.cdiv(jnp.minimum(n_live - blk * block_pages, block_pages),
+                       LATENT_COPY_GROUP)
+
+    def start_block(blk, slot):
+        # a loop over the block's live GROUPS, a group's copies unrolled:
+        # issuing a copy costs the scalar core about 50 ns, a loop round a
+        # page more. The last group's slots past the lane's live pages
+        # re-fetch its last live page (at most a group less one of them).
+        def group(g, carry):
+            for j in range(LATENT_COPY_GROUP):
+                p = g * LATENT_COPY_GROUP + j
+                page = tables_ref[
+                    lane, jnp.minimum(blk * block_pages + p, n_live - 1)]
+                pltpu.make_async_copy(
+                    kv_hbm.at[layer, page, 0], buf.at[slot, p],
+                    sem.at[slot]).start()
+            return carry
+        jax.lax.fori_loop(0, groups_in(blk), group, None)
+
+    def wait_block(blk, slot):
+        # the semaphore counts bytes: one wait a group, for a group's bytes
+        def group(g, carry):
+            at = pl.ds(g * LATENT_COPY_GROUP, LATENT_COPY_GROUP)
+            pltpu.make_async_copy(
+                kv_hbm.at[layer, pl.ds(0, LATENT_COPY_GROUP), 0],
+                buf.at[slot, at], sem.at[slot]).wait()
+            return carry
+        jax.lax.fori_loop(0, groups_in(blk), group, None)
+
+    acc_s[...] = jnp.zeros_like(acc_s)
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        start_block(0, 0)
+
+    def block_step(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            start_block(blk + 1, 1 - slot)
+
+        wait_block(blk, slot)
+        q = q_ref[0]                                        # (H, W)
+        kv = buf[slot].reshape(block_tokens, q.shape[-1])   # (T, W)
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale                                        # (H, T) f32
+        k_pos = blk * block_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos <= pos, s, NEG_INF)
+        m_prev = m_s[:, :1]
+        l_prev = l_s[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(kv.dtype), kv[:, :value_width],
+                     preferred_element_type=jnp.float32)    # (H, value_width)
+        acc_s[...] = acc_s[...] * alpha + pv
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+        l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block_step, None)
+    o_ref[0] = (acc_s[...] / jnp.maximum(l_s[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("page_tokens", "value_width", "sm_scale",
+                              "interpret", "layer"))
+def paged_latent_decode_attention_kernel(
+    q: jax.Array, pages: jax.Array, tables: jax.Array, pos: jax.Array,
+    active: jax.Array | None = None, *, page_tokens: int, value_width: int,
+    sm_scale: float, interpret: bool = False, layer: int = 0,
+) -> jax.Array:
+    """Fused latent decode attention: ``paged_latent_attention_reference``'s
+    contract at T = 1 (q ``(S, H, 1, W)``, the whole one-sided arena
+    ``(layers, n_pages, 1, page_tokens, W)`` and a static ``layer`` -> f32
+    ``(S, H, 1, value_width)``) in ONE pass over the live rows, which no
+    reduction over heads ever repeats: the arena is read once a lane for all
+    H heads. ``active`` as in ``paged_decode_attention_kernel``: an inactive
+    lane copies nothing and answers zeros. Tables, positions and ``active``
+    are traced data (SMEM)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_lanes, h, _, w = q.shape
+    pages = _as_arena(pages)
+    if pages.shape[2:] != (1, page_tokens, w):
+        raise ValueError(f"latent arena {pages.shape} is not (layers, pages, "
+                         f"1, {page_tokens}, {w})")
+    if (w % 128 or value_width % 128) and not interpret:
+        raise ValueError(f"row width {w} / value width {value_width} not a "
+                         "multiple of 128")
+    if pages.shape[1] < LATENT_COPY_GROUP:
+        raise ValueError(f"latent arena of {pages.shape[1]} pages: the kernel "
+                         f"waits for {LATENT_COPY_GROUP} page copies at a time")
+    block_pages = -(-max(1, LATENT_BLOCK_TOKENS // page_tokens)
+                    // LATENT_COPY_GROUP) * LATENT_COPY_GROUP
+    if active is None:
+        active = jnp.ones((s_lanes,), jnp.int32)
+
+    def lane_index(s, tbl, ps, act):
+        return (s, 0, 0)
+
+    kernel = functools.partial(
+        _paged_latent_decode_kernel, sm_scale=sm_scale, page_tokens=page_tokens,
+        block_pages=block_pages, value_width=value_width, layer=layer)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s_lanes,),
+        in_specs=[pl.BlockSpec((1, h, w), lane_index),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, h, value_width), lane_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_pages, page_tokens, w), pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((h, value_width), jnp.float32),      # acc
+            pltpu.VMEM((h, 128), jnp.float32),              # m (lane-bcast)
+            pltpu.VMEM((h, 128), jnp.float32),              # l (lane-bcast)
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_lanes, h, value_width), jnp.float32),
+        interpret=interpret,
+        # "arbitrary": the lanes run in order on one core, so the first lane's
+        # zeroing of the page buffers holds for all of them
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_latent_decode_kernel",
+    )(tables.astype(jnp.int32), pos.astype(jnp.int32),
+      active.astype(jnp.int32), q[:, :, 0], pages)
+    return out[:, :, None]
+
+
+def paged_latent_attention(  # static-bounded: kernel, page_tokens, value_width, sm_scale, layer, PAGED_KERNEL_INTERPRET -- kernel and the interpret flag are booleans; page_tokens is one value per slot state; value_width and sm_scale are one value per model config; layer is the caller's unrolled loop index
+    q: jax.Array, pages: jax.Array, tables: jax.Array, pos: jax.Array,
+    page_tokens: int, value_width: int, sm_scale: float, kernel: bool = True,
+    active: jax.Array | None = None, layer: int = 0,
+) -> jax.Array:
+    """Latent paged attention dispatch, mirroring ``paged_attention``: the
+    fused kernel for a decode step (T = 1) on the TPU when the row and value
+    widths are whole 128-lane tiles, the gather + einsum reference everywhere
+    else (``kernel=False``, another backend, T > 1: the verify and
+    chunked-prefill forwards). The branch taken is recorded under
+    ``('paged_latent_attention', 'kernel'|'reference', reason)``."""
+    if not kernel:
+        why = "kernel=False"
+    elif q.shape[2] != 1:
+        why = f"T={q.shape[2]} > 1 (the kernel is the decode step's)"
+    elif PAGED_KERNEL_INTERPRET:
+        why = None
+    elif jax.default_backend() != "tpu":
+        why = f"backend={jax.default_backend()}"
+    elif q.shape[-1] % 128 or value_width % 128:
+        why = (f"row width {q.shape[-1]} or value width {value_width} not a "
+               "multiple of 128")
+    else:
+        why = None
+    shapes = (q.shape, pages.shape, (str(pages.dtype),))
+    if why is None:
+        _record_dispatch(
+            "paged_latent_attention", "kernel",
+            "interpret" if PAGED_KERNEL_INTERPRET else "pallas", *shapes)
+        return paged_latent_decode_attention_kernel(
+            q, pages, tables, pos, active, page_tokens=page_tokens,
+            value_width=value_width, sm_scale=sm_scale,
+            interpret=PAGED_KERNEL_INTERPRET, layer=layer)
+    _record_dispatch("paged_latent_attention", "reference", why, *shapes)
+    return paged_latent_attention_reference(
+        q, pages, tables, pos, page_tokens, value_width, sm_scale, layer)
 
 
 def _paged_verify_kernel(

@@ -9,10 +9,12 @@ own router logits, its own ``top_k`` products summed in rank order). A row
 can therefore share a decode step with strangers.
 
   route    router product in float32 at HIGHEST precision (a float32 matmul
-           on the TPU is one bf16 pass otherwise), softmax over all experts,
-           ``jax.lax.top_k`` (ties as it breaks them), gates = the softmax
-           values themselves unless ``norm_topk``; the ``tokens x top_k``
-           assignments sorted by expert (stable), group sizes counted.
+           on the TPU is one bf16 pass otherwise), scores over all experts
+           (``softmax``, or ``sigmoid`` with an optional selection ``bias``
+           that picks the experts and does not enter their weight),
+           ``jax.lax.top_k`` (ties as it breaks them), gates = the scores
+           themselves unless ``norm_topk``, times ``scale``; the ``tokens x
+           top_k`` assignments sorted by expert (stable), group sizes counted.
   experts  the sorted rows gathered, ``silu(x W1_e) * (x W3_e)`` then
            ``h W2_e`` as two grouped products (bf16 operands, float32
            accumulation), unsorted, weighted by the gates and summed.
@@ -29,6 +31,13 @@ which was traced under ``('moe_experts', 'kernel'|'reference', reason)`` in
 ``row_mask`` marks rows whose answer nobody reads (the engine's inactive
 lanes): their assignments go to no expert, so they hit none and read none,
 and their output is zero.
+
+``held = (first, count)`` says which experts this chip holds of a layer that
+several chips share (expert parallelism): the weights are ``(count, d, ff)``,
+the router keeps its full width, and an assignment to an expert held
+elsewhere takes the road a masked row takes, to no expert. What comes back is
+the held experts' part of the layer's answer; on one chip there is no
+exchange, and nothing here stands in for the absent chips.
 """
 
 from __future__ import annotations
@@ -52,15 +61,29 @@ TN = 512
 VMEM_LIMIT = 64 << 20
 
 
-def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool = False):
+def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool = False,
+          score: str = "softmax", bias: jax.Array | None = None,
+          scale: float = 1.0):
     """``x (t, d)`` -> (gates ``(t, k)`` float32, experts ``(t, k)`` int32,
-    softmax ``(t, e)`` float32)."""
+    scores ``(t, e)`` float32). ``score`` is the function over the router's
+    logits, ``softmax`` or ``sigmoid``; ``bias (e,)`` is added to the scores
+    for the SELECTION only (a gate is the unbiased score); ``scale``
+    multiplies the gates after ``norm_topk``."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, top_k)
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"route score {score!r}: softmax or sigmoid")
+    probs = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+             else jax.nn.sigmoid(logits))
+    if bias is None:
+        gates, idx = jax.lax.top_k(probs, top_k)
+    else:
+        _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(probs, idx, axis=-1)
     if norm_topk:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if scale != 1.0:
+        gates = gates * scale
     return gates, idx.astype(jnp.int32), probs
 
 
@@ -149,19 +172,33 @@ def moe_grouped_matmul(lhs, rhs, group_sizes, rhs_up=None, *, tm: int,
 
 
 def moe_experts(x: jax.Array, moe: dict, top_k: int, *, norm_topk: bool = False,
-                row_mask: jax.Array | None = None, partitioned: bool = False):
-    """``x (t, d)`` in the compute dtype, ``moe`` = ``router (d, e)`` float32
-    and ``w1``, ``w3`` ``(e, d, ff)``, ``w2`` ``(e, ff, d)`` in the compute
-    dtype -> (``y (t, d)``, stats). ``stats`` holds ``experts_hit`` and
-    ``expert_rows_max`` (float32 scalars: distinct experts with rows, and the
-    most rows one expert took) and ``probs`` / ``experts`` for a training
-    loss."""
+                row_mask: jax.Array | None = None, partitioned: bool = False,
+                score: str = "softmax", route_scale: float = 1.0,
+                held: tuple[int, int] | None = None):
+    """``x (t, d)`` in the compute dtype, ``moe`` = ``router (d, E)`` float32
+    (and ``bias (E,)`` where the selection has one) and ``w1``, ``w3``
+    ``(e, d, ff)``, ``w2`` ``(e, ff, d)`` in the compute dtype -> (``y (t,
+    d)``, stats). ``e`` is the experts held here: all ``E`` of them, or
+    ``held = (first, count)`` of a layer shared between chips. ``stats`` holds
+    ``experts_hit``, ``expert_rows_max`` and ``expert_rows_local`` (float32
+    scalars: distinct held experts with rows, the most rows one of them took,
+    and the assignments that landed on a held expert) and ``probs`` /
+    ``experts`` (over all ``E``; a ``row_mask``ed row's are ``E``, no expert)
+    for a training loss."""
     t, d = x.shape
-    e = moe["router"].shape[1]
+    e = moe["w1"].shape[0]
     with jax.named_scope("route"):
-        gates, idx, probs = route(x, moe["router"], top_k, norm_topk)
-        if row_mask is not None:
-            idx = jnp.where(row_mask[:, None], idx, e)   # to no expert
+        gates, routed, probs = route(x, moe["router"], top_k, norm_topk, score,
+                                     moe.get("bias"), route_scale)
+        if row_mask is not None:   # to no expert: one past the router's width
+            routed = jnp.where(row_mask[:, None], routed, probs.shape[-1])
+        idx = routed
+        if held is not None:
+            first, count = held
+            if count != e:
+                raise ValueError(f"held {held}: the weights hold {e} experts")
+            idx = idx - first
+            idx = jnp.where((idx >= 0) & (idx < e), idx, e)   # held elsewhere
         a = t * top_k
         why = _kernel_refusal(moe["w1"], partitioned)
         tm = DECODE_TM if a <= DECODE_ROWS else PREFILL_TM
@@ -194,6 +231,7 @@ def moe_experts(x: jax.Array, moe: dict, top_k: int, *, norm_topk: bool = False,
     stats = {
         "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.float32),
         "expert_rows_max": jnp.max(group_sizes).astype(jnp.float32),
-        "probs": probs, "experts": idx,
+        "expert_rows_local": jnp.sum(group_sizes).astype(jnp.float32),
+        "probs": probs, "experts": routed,
     }
     return y.astype(x.dtype), stats

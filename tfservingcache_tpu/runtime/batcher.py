@@ -1259,7 +1259,7 @@ class _ContinuousScheduler:
             )
         # an expert model's routing numbers came back with the chunk's
         # tokens (plain chunks only: a spec round runs the verify step)
-        moe_stats = (0.0, 0.0)
+        moe_stats = (0.0, 0.0, 0.0)
         if chunk and drafted == 0 and getattr(state, "moe_stats", None):
             moe_stats = state.moe_stats
             if eng.metrics is not None:
@@ -1299,6 +1299,7 @@ class _ContinuousScheduler:
             prefill_ms=prefill_s * 1e3, chunk_ms=chunk_s * 1e3,
             emit_ms=emit_s * 1e3,
             experts_hit=moe_stats[0], expert_rows_max=moe_stats[1],
+            expert_rows_local=moe_stats[2],
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

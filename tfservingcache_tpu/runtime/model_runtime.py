@@ -33,7 +33,12 @@ import numpy as np
 from tfservingcache_tpu.cache.lru import LRUEntry
 from tfservingcache_tpu.native import make_lru_cache
 from tfservingcache_tpu.config import ServingConfig
-from tfservingcache_tpu.models.registry import ModelDef, TensorSpec, load_artifact
+from tfservingcache_tpu.models.registry import (
+    ModelDef,
+    TensorSpec,
+    load_artifact,
+    static_config,
+)
 from tfservingcache_tpu.runtime.base import BaseRuntime, ModelNotLoadedError, RuntimeError_
 from tfservingcache_tpu.types import Model, ModelId, ModelState
 from tfservingcache_tpu.utils.accounting import LEDGER
@@ -675,7 +680,7 @@ class SlotDecodeState:
     slots: int
     max_seq: int
     k: Any                           # device page arena
-    v: Any
+    v: Any                           # None for a one-sided (latent) arena
     tok: np.ndarray                  # (S,) i32 — last sampled token per lane
     pos: np.ndarray                  # (S,) i32 — next write position
     active: np.ndarray               # (S,) bool
@@ -683,7 +688,8 @@ class SlotDecodeState:
     topks: np.ndarray                # (S,) i32 per-lane top_k
     chunk_counter: int = 0           # host-side PRNG stream for chunk keys
     # the last decode chunk's routing stats of a model with expert layers:
-    # (experts_hit, expert_rows_max), chunk means; None for a dense model
+    # (experts_hit, expert_rows_max, expert_rows_local: generation.MOE_STATS),
+    # chunk means; None for a dense model
     moe_stats: tuple | None = None
     # -- arena bookkeeping (scheduler-thread-owned) --
     page_tokens: int = 0             # tokens a page; >= 1 in a built state
@@ -1862,6 +1868,8 @@ class TPUModelRuntime(BaseRuntime):
                 f"{loaded.model_def.family!r}"
             )
         self._refuse_experts_on_mesh(loaded)
+        self._refuse_latent(
+            loaded, "a draft_model" if draft_model_id is not None else None)
         draft = None
         if draft_model_id is not None:
             if temperature > 0.0:
@@ -2053,6 +2061,7 @@ class TPUModelRuntime(BaseRuntime):
                 f"{loaded.model_def.family!r}"
             )
         self._refuse_experts_on_mesh(loaded)
+        self._refuse_latent(loaded)
         with self._slot_lock:
             st = self._slot_states.get(model_id)
             if st is not None:
@@ -2100,6 +2109,8 @@ class TPUModelRuntime(BaseRuntime):
             arena_dtype = str(getattr(self.cfg, "kv_arena_dtype", "") or "")
         if paged_kernel is None:
             paged_kernel = bool(getattr(self.cfg, "kv_paged_kernel", True))
+        if arena_dtype == "int8":
+            self._refuse_latent(loaded, "the int8 arena (kv_arena_dtype)")
         # The fused Pallas decode kernel is single-chip-only (it indexes the
         # whole KV-head axis locally); on a mesh the gather+einsum reference
         # serves the sharded arena, pinned bitwise by tests/test_mesh_parity
@@ -2130,7 +2141,8 @@ class TPUModelRuntime(BaseRuntime):
             )
         # +1: page 0 is the trash page, permanently reserved
         cache = init_paged_cache(
-            cfg, usable + 1, page_tokens, arena_dtype, mesh=arena_mesh
+            cfg, usable + 1, page_tokens, arena_dtype, mesh=arena_mesh,
+            row=loaded.model_def.cache_row,
         )
         scales = None
         if "k_scale" in cache:
@@ -2143,15 +2155,14 @@ class TPUModelRuntime(BaseRuntime):
 
             page_nbytes = sum(
                 int(a.nbytes)
-                for a in (cache["k"], cache["v"],
-                          *(scales.values() if scales else ()))
+                for a in cache.values()
             ) // (usable + 1)
             prefix_index = PagePrefixIndex(
                 page_tokens, page_nbytes, int(share_prefix_bytes)
             )
         st = SlotDecodeState(
             model_id=model_id,
-            cfg_key=tuple(sorted((k, v) for k, v in cfg.items())),
+            cfg_key=static_config(loaded.model_def),
             family=loaded.model_def.family,
             slots=slots,
             max_seq=max_seq,
@@ -2161,7 +2172,7 @@ class TPUModelRuntime(BaseRuntime):
             temps=np.zeros((slots,), np.float32),
             topks=np.zeros((slots,), np.int32),
             k=cache["k"],
-            v=cache["v"],
+            v=cache.get("v"),
             scales=scales,
             arena_dtype=arena_dtype,
             kernel=bool(paged_kernel),
@@ -2195,7 +2206,7 @@ class TPUModelRuntime(BaseRuntime):
             return int(arr.nbytes)
 
         label = state.arena_dtype or str(state.k.dtype)
-        nbytes = actual(state.k) + actual(state.v)
+        nbytes = actual(state.k) + (actual(state.v) if state.v is not None else 0)
         if state.scales is not None:
             nbytes += sum(actual(a) for a in state.scales.values())
         self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(nbytes)
@@ -2261,7 +2272,7 @@ class TPUModelRuntime(BaseRuntime):
         if loaded is None:
             raise ModelNotLoadedError(f"model {model_id} is not loaded")
         cfg = loaded.model_def.config
-        cfg_key = tuple(sorted((k, v) for k, v in cfg.items()))
+        cfg_key = static_config(loaded.model_def)
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         p = prompt.shape[0]
         max_seq = int(cfg["max_seq"])
@@ -2332,7 +2343,7 @@ class TPUModelRuntime(BaseRuntime):
         if loaded is None:
             raise ModelNotLoadedError(f"model {model_id} is not loaded")
         cfg = loaded.model_def.config
-        cfg_key = tuple(sorted((k, v) for k, v in cfg.items()))
+        cfg_key = static_config(loaded.model_def)
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         t = tokens.shape[0]
         if not 0 < t <= chunk_size:
@@ -2453,7 +2464,7 @@ class TPUModelRuntime(BaseRuntime):
             )
             return int(np.asarray(tok)[0]), None, None, "exact", plan.logits
         cfg = loaded.model_def.config
-        cfg_key = tuple(sorted((k, v) for k, v in cfg.items()))
+        cfg_key = static_config(loaded.model_def)
         covered = plan.covered
         ck, cv = _paged_gather_prefix_jit(
             state.k, state.v, state.scales, np.asarray(plan.pages, np.int32)
@@ -2605,7 +2616,7 @@ class TPUModelRuntime(BaseRuntime):
             model_id=str(state.model_id),
             history=history.copy(),
             pages_k=np.asarray(jax.device_get(k)),
-            pages_v=np.asarray(jax.device_get(v)),
+            pages_v=None if v is None else np.asarray(jax.device_get(v)),
             k_scale=ks,
             v_scale=vs,
             page_tokens=state.page_tokens,
@@ -2689,12 +2700,13 @@ class TPUModelRuntime(BaseRuntime):
         if loaded is None:
             raise ModelNotLoadedError(f"model {model_id} is not loaded")
         cfg = loaded.model_def.config
-        cfg_key = tuple(sorted((k, v) for k, v in cfg.items()))
+        cfg_key = static_config(loaded.model_def)
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         p = prompt.shape[0]
         pages = np.asarray(state.lane_pages[lane][:n_pages], np.int32)
         pk_pg = np.ascontiguousarray(parked.pages_k[:, :n_pages])
-        pv_pg = np.ascontiguousarray(parked.pages_v[:, :n_pages])
+        pv_pg = (None if parked.pages_v is None else
+                 np.ascontiguousarray(parked.pages_v[:, :n_pages]))
         pscales = None
         if state.scales is not None:
             pscales = {
@@ -2774,12 +2786,12 @@ class TPUModelRuntime(BaseRuntime):
         )
         # np.array (not asarray): device_get hands back READ-ONLY views and
         # the scheduler writes these mirrors at the next admission
-        # one fetch: an expert model's two routing numbers ride with the tokens
+        # one fetch: an expert model's routing numbers ride with the tokens
         tok, pos, toks, stats = jax.device_get((tok, pos, toks, stats))
         state.tok = np.array(tok, dtype=np.int32)
         state.pos = np.array(pos, dtype=np.int32)
-        state.moe_stats = None if stats is None else (
-            float(stats[0]), float(stats[1]))
+        state.moe_stats = None if stats is None else tuple(
+            float(x) for x in stats)
         return np.asarray(toks)
 
     @_mesh_serialized
@@ -2809,6 +2821,8 @@ class TPUModelRuntime(BaseRuntime):
                 "(transformer_lm, moe_lm: ModelDef.engine_ready) only, not "
                 f"{draft.model_def.family!r}"
             )
+        for half in (loaded, draft):
+            self._refuse_latent(half, "a draft_model")
         if (draft.model_def.config["vocab_size"]
                 != loaded.model_def.config["vocab_size"]):
             raise RuntimeError_(
@@ -3364,6 +3378,23 @@ class TPUModelRuntime(BaseRuntime):
                 "generate of an expert model on a chip group is not supported: "
                 "the grouped expert kernel (ops/moe.py) is single-chip"
             )
+
+    def _refuse_latent(self, loaded: LoadedModel, what: str | None = None) -> None:
+        """What a latent-attention family (a one-sided ``cache_row``) cannot
+        do yet is refused by name, never answered wrongly: generation on a
+        chip group always (its weights are already one chip's share of a
+        layer), and ``what`` where the caller is about to use it: the int8
+        arena (no quantized form of the latent row), a ``draft_model`` (the
+        speculative programs hold K and V sides)."""
+        row = loaded.model_def.cache_row
+        if row is None or row.sides != 1:
+            return
+        if self.mesh is not None:
+            what = "generation on a chip-group mesh"
+        if what:
+            raise RuntimeError_(
+                f"{loaded.model_def.family} (latent attention) does not "
+                f"support {what}")
 
     def signature(self, model_id: ModelId):
         loaded = self._resident.get(model_id, touch=False)

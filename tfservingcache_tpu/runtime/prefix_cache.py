@@ -114,7 +114,8 @@ class PrefixCache:
     def insert(self, model_id: ModelId, tokens: np.ndarray, k, v,
                valid_len: int) -> None:
         tokens = np.asarray(tokens, np.int32)[:valid_len]
-        nbytes = int(k.nbytes) + int(v.nbytes)
+        # v is None for a one-sided (latent) cache
+        nbytes = int(k.nbytes) + (0 if v is None else int(v.nbytes))
         if nbytes > self.capacity_bytes:
             return  # one entry over budget: don't thrash the whole cache
         tok_bytes = tokens.tobytes()

@@ -88,6 +88,10 @@ STEP_FIELDS = (
     # ran no chunk
     "experts_hit",      # distinct experts a layer a step routed to (chunk mean)
     "expert_rows_max",  # most rows one expert took in a step (chunk mean)
+    # appended field (ISSUE 31 the chip's share of a layer's experts): the
+    # two above count HELD experts; of a step's active x top_k assignments
+    # these landed on one (all of them where the chip holds every expert)
+    "expert_rows_local",  # assignments a step a layer to a held expert (chunk mean)
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -100,7 +104,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 21:
+    if len(e) == 22:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -110,6 +114,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "drafted": e[14], "accepted": e[15],
             "prefill_ms": e[16], "chunk_ms": e[17], "emit_ms": e[18],
             "experts_hit": e[19], "expert_rows_max": e[20],
+            "expert_rows_local": e[21],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -267,6 +272,7 @@ class FlightRecorder:
         emit_ms: float = 0.0,
         experts_hit: float = 0.0,
         expert_rows_max: float = 0.0,
+        expert_rows_local: float = 0.0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -275,6 +281,7 @@ class FlightRecorder:
             drafted, accepted,
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
             round(experts_hit, 3), round(expert_rows_max, 3),
+            round(expert_rows_local, 3),
         ))
 
     def note_phases(

@@ -70,6 +70,8 @@ def _fmt_step(s: dict) -> str:
     if s.get("experts_hit"):
         split += (f"experts={s['experts_hit']:.1f} "
                   f"rows_max={s.get('expert_rows_max', 0):.1f} ")
+        if "expert_rows_local" in s:    # ISSUE 31: assignments that landed here
+            split += f"rows_local={s['expert_rows_local']:.1f} "
     return (
         f"  {s.get('engine', '?'):<10} step={s.get('step_ms', 0):>8.2f}ms {split}"
         f"chunk={s.get('chunk', 0):>3} active={s.get('active', 0):>3} "

@@ -50,11 +50,17 @@ def main() -> int:
     # layer at a decode step of 4 / 8 / 32 live lanes and prefills of 512 /
     # 2048 tokens, kernel against jax.lax.ragged_dot (ms, GB/s of the routed
     # experts' weights).
+    # test_mla_moe.py carries the latent (MLA) decode kernel's rows at
+    # Mistral-Small-4's shapes (32 heads over one 384-wide row, 16-token
+    # pages, an arena of 16384 pages x 6 layers): parity with the gather +
+    # einsum reference, ms, the share of the 640 B-a-token roofline, the same
+    # attention with the row stored as two arrays, and the block sizes.
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
         os.path.join(REPO, "tests", "test_paged_kernel.py"),
         os.path.join(REPO, "tests", "test_olmoe.py"),
+        os.path.join(REPO, "tests", "test_mla_moe.py"),
         "-v", "-rs", "-s", "--no-header",
         *extra,
     ]
