@@ -12,13 +12,28 @@ once a layer (``cache["k"][li]``, ``.at[page, :, off].set`` on the slice,
   slice of an arena buffer, or has as many elements as one or more;
 - arena-shaped results only from ``scatter`` (``_paged_write_rows``), one a
   layer for each of ``k``, ``v`` and, on an int8 arena, the two scale buffers
-  (the programs that carry the arena, ``pjit`` and ``scan``, pass it through).
+  (the programs that carry the arena, ``pjit``, ``scan`` and ``while``, pass
+  it through). Since PR 32 a decode chunk's write takes the rows of its live
+  lanes: a loop a layer over groups of ``_WRITE_GROUP`` lanes, the arena its
+  carry, each trip the same scatters on fewer lanes. The walk descends into
+  the loop's body; a prefill chunk writes every row through no loop.
 
 The toy arena has far more pages than lanes x pages a lane, so what the CPU
 reference legitimately gathers (the lanes' own pages) is small beside a layer.
+
+One case more compiles the decode chunk for a v5e that libtpu describes with
+no chip attached: what the TPU compiler makes of the form is the finding of
+PR 26 (a careless scatter brought arena-sized layout copies), so the compiled
+program holds no layer- or arena-sized result but the scatters', and its
+temporaries stay those of the program that writes every lane.
 """
 
 import functools
+import json
+import os
+import re
+import subprocess
+import sys
 
 import jax
 import jax.extend
@@ -28,7 +43,7 @@ import pytest
 import tfservingcache_tpu.models.generation as generation
 from tfservingcache_tpu.models.registry import build
 
-LANES, PPS, PT, N_PAGES, CHUNK = 2, 4, 4, 256, 2
+LANES, PPS, PT, N_PAGES, CHUNK = 8, 4, 4, 320, 2
 DENSE = ("transformer_lm", {
     "vocab_size": 97, "d_model": 48, "n_layers": 2, "n_heads": 4,
     "n_kv_heads": 2, "d_ff": 96, "max_seq": 64})
@@ -115,6 +130,148 @@ def test_no_layer_slice_leaves_the_arena_and_none_is_stacked_back(
                     # only a program that carries the arena may hand it on
                     assert list(_sub_jaxprs(eqn)), (name, aval)
     # one scatter a layer for k and for v (the same shape and dtype), and on
-    # an int8 arena as many again for their scales
+    # an int8 arena as many again for their scales; in a decode chunk they sit
+    # in the body of the layer's loop over the live lanes
+    assert LANES > generation._WRITE_GROUP
     assert scatters == {key: 2 * cfg["n_layers"] for key in buffers}, scatters
+    loops = sum(eqn.primitive.name == "while" for eqn in _equations(jaxpr))
+    assert loops == (cfg["n_layers"] if which == "decode_chunk" else 0)
     assert len(buffers) == (2 if arena_dtype == "int8" else 1)
+
+
+# -- what the TPU compiler makes of it, without a chip -------------------------
+
+_COMPILE_ONLY_ENV = {
+    # what libtpu asks its environment when no TPU VM metadata answers
+    "TPU_SKIP_MDS_QUERY": "1",
+    "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+    "TPU_WORKER_HOSTNAMES": "localhost",
+    "TPU_LOG_DIR": "disabled",
+    "JAX_PLATFORMS": "cpu",
+}
+# results that only hand a buffer on
+_CARRIERS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             "conditional", "call", "optimization-barrier"}
+
+
+def _large_results(hlo: str, elems: int) -> dict:
+    """{operation: count} over the instructions of a compiled module whose
+    result has at least ``elems`` elements; a fusion is named by the root of
+    the computation it calls (``fusion:scatter``)."""
+    roots = {}
+    current = None
+    for line in hlo.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            current = head.group(1)
+        root = re.match(r"\s*ROOT %?[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+        if root and current:
+            roots[current] = root.group(1)
+    found = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([0-9,]*)\]\S* ([\w\-]+)\(",
+                     line)
+        if not m or m.group(3) in _CARRIERS:
+            continue
+        size = 1
+        for dim in filter(None, m.group(2).split(",")):
+            size *= int(dim)
+        if size < elems:
+            continue
+        op = m.group(3)
+        if op == "fusion":
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            op = "fusion:" + roots.get(called.group(1) if called else "", "?")
+        found[op] = found.get(op, 0) + 1
+    return found
+
+
+def _compile_for_v5e_main():
+    """Child-process body of the test below: the decode chunk of a dense model
+    at 32 lanes over an arena far larger than anything else it touches,
+    compiled for a described v5e, as the tree writes it and with every lane's
+    rows written (the parent's write: no order handed down). Prints what it
+    found, or NO_TOPOLOGY."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from tfservingcache_tpu.models.registry import static_config
+
+    try:
+        topo = topologies.get_topology_desc(
+            topology_name="v5e:2x2", platform="tpu",
+            chip_config_name="default", chips_per_host_bounds=(2, 2, 1),
+            num_slices=1)
+    except Exception as e:  # noqa: BLE001 - reported; the parent skips
+        print("NO_TOPOLOGY", type(e).__name__, e)
+        return
+    one = SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", False)
+    # the gates ask the backend, "cpu" in this process: answer for the chip
+    jax.default_backend = lambda: "tpu"
+    lanes, pt, chunk, n_pages = 32, 16, 8, 1025
+    md = build("transformer_lm", {
+        "vocab_size": 4096, "d_model": 1024, "n_layers": 2, "n_heads": 8,
+        "n_kv_heads": 8, "d_ff": 2048, "max_seq": 2048, "dtype": "bfloat16"})
+    cfg = md.config
+    cache = jax.eval_shape(
+        lambda: generation.init_paged_cache(cfg, n_pages, pt))
+    params = jax.eval_shape(md.init, jax.random.PRNGKey(0))
+    s = jax.ShapeDtypeStruct
+    lane = s((lanes,), jnp.int32)
+    args = (params, cache["k"], cache["v"], None,
+            s((lanes, cfg["max_seq"] // pt), jnp.int32), lane, lane,
+            s((lanes,), jnp.bool_), s((chunk, 2), jnp.uint32),
+            s((lanes,), jnp.float32), lane)
+    args = jax.tree_util.tree_map(
+        lambda a: s(a.shape, a.dtype, sharding=one), args)
+    layer_elems = cache["k"].size // cfg["n_layers"]
+    live_lanes = generation._live_lanes
+    for name, form in (("live", live_lanes), ("every", lambda active: None)):
+        generation._live_lanes = form
+        generation._paged_decode_chunk_jit.clear_cache()
+        compiled = generation._paged_decode_chunk_jit.lower(
+            *args, cfg_key=static_config(md), family="transformer_lm",
+            chunk=chunk, page_tokens=pt, kernel=True).compile()
+        hlo = compiled.as_text()
+        print("COMPILED", name,
+              "temp", compiled.memory_analysis().temp_size_in_bytes,
+              "kernel", int("paged_decode_attention_kernel" in hlo),
+              "loops", len(re.findall(r" while\(", hlo)),
+              "large", json.dumps(_large_results(hlo, layer_elems)))
+    print("LAYER_BYTES", layer_elems * 2)
+
+
+def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy():
+    """The decode chunk compiled for a v5e, off the chip: every layer- or
+    arena-sized result is a scatter's (in place on the donated arena), inside
+    the write's loops too: no ``copy``, no ``transpose``, no layout conversion
+    around a loop; and the program's temporaries are those of the program that
+    writes every lane, but for the gathered rows (far under one layer of the
+    arena). Skipped where libtpu cannot describe the topology."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
+    env.update(_COMPILE_ONLY_ENV)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, 'tests'); import test_arena_in_place;"
+         " test_arena_in_place._compile_for_v5e_main()"],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=600)
+    if "NO_TOPOLOGY" in r.stdout:
+        pytest.skip("libtpu compile-only topology unavailable: "
+                    + r.stdout.strip()[-300:])
+    found = dict(re.findall(r"COMPILED (\w+) (.*)", r.stdout))
+    assert set(found) == {"live", "every"}, (r.stdout[-3000:], r.stderr[-3000:])
+    temp, loops = {}, {}
+    layer_bytes = int(re.search(r"LAYER_BYTES (\d+)", r.stdout).group(1))
+    for name, line in found.items():
+        m = re.match(r"temp (\d+) kernel (\d) loops (\d+) large (.*)", line)
+        temp[name] = int(m.group(1))
+        assert m.group(2) == "1", ("the paged kernel was not traced", line)
+        large = json.loads(m.group(4))
+        assert large and set(large) <= {"scatter", "fusion:scatter"}, (name, large)
+        loops[name] = int(m.group(3))
+    # the form under test was compiled: loops beside the chunk's scan
+    assert loops["live"] > loops["every"], loops
+    assert temp["live"] - temp["every"] < layer_bytes // 8, (temp, layer_bytes)

@@ -344,7 +344,7 @@ def test_d_engine_answers_as_the_solo_decoder(tmp_path, variant):
     np.testing.assert_array_equal(both[0], solo[0])
     np.testing.assert_array_equal(both[1], solo[1])
     np.testing.assert_array_equal(again[0], solo[0])
-    assert STEP_FIELDS[-1] == "expert_rows_local"
+    assert STEP_FIELDS[21] == "expert_rows_local"
     steps = [s for s in _ring(mid) if s["chunk"] > 0 and s["active"] == 2]
     assert steps
     for s in steps:

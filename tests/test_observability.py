@@ -565,6 +565,7 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_gen_kv_pages_total",
     "tpusc_gen_kv_pages_used",
     "tpusc_gen_kv_pages_used_peak",
+    "tpusc_gen_kv_write_steps",
     "tpusc_gen_preemptions",
     "tpusc_gen_prefill_chunks",
     "tpusc_moe_assignments",

@@ -499,6 +499,17 @@ def test_paged_verify_shapes_on_tpu(g, d, quantized):
     )
 
 
+def _random_bf16_params(family, cfg):
+    """A family's leaves at ``cfg``'s widths, made on the device: normal x 0.02
+    for matrices, ones for gains, all bfloat16 (the on-chip rows' weights)."""
+    shapes = jax.eval_shape(build(family, cfg).init, jax.random.PRNGKey(0))
+    leaves, treedef = jax.tree_util.tree_flatten(shapes)
+    return jax.tree_util.tree_unflatten(treedef, [
+        (0.02 * jax.random.normal(jax.random.PRNGKey(i), a.shape, jnp.bfloat16)
+         if a.ndim > 1 else jnp.ones(a.shape, jnp.bfloat16))
+        for i, a in enumerate(leaves)])
+
+
 @pytest.mark.skipif(
     jax.default_backend() != "tpu",
     reason="needs real TPU (conftest forces CPU; run via tools/tpu_kernel_check.py)",
@@ -516,13 +527,7 @@ def test_decode_chunk_time_does_not_follow_the_arena_on_tpu():
         "vocab_size": 32768, "d_model": 4096, "n_layers": 8, "n_heads": 32,
         "n_kv_heads": 8, "d_ff": 14336, "max_seq": 2048, "rope_theta": 1e6,
         "dtype": "bfloat16"}).config
-    shapes = jax.eval_shape(build("transformer_lm", cfg).init,
-                            jax.random.PRNGKey(0))
-    leaves, treedef = jax.tree_util.tree_flatten(shapes)
-    params = jax.tree_util.tree_unflatten(treedef, [
-        (0.02 * jax.random.normal(jax.random.PRNGKey(i), a.shape, jnp.bfloat16)
-         if a.ndim > 1 else jnp.ones(a.shape, jnp.bfloat16))
-        for i, a in enumerate(leaves)])
+    params = _random_bf16_params("transformer_lm", cfg)
     lanes, pps, pt, chunk = 32, 128, 16, 8
     live = np.asarray([600, 900, 1200, 1500], np.int32)
     tables = np.zeros((lanes, pps), np.int32)
@@ -557,3 +562,72 @@ def test_decode_chunk_time_does_not_follow_the_arena_on_tpu():
           f"({int(live.sum())} tokens): {ms[512]:.3f} ms a step at 512 pages, "
           f"{ms[4096]:.3f} ms at 4096 pages", flush=True)
     assert abs(ms[4096] - ms[512]) / ms[512] < 0.10, ms
+
+
+@pytest.mark.skipif(
+    jax.default_backend() != "tpu",
+    reason="needs real TPU (conftest forces CPU; run via tools/tpu_kernel_check.py)",
+)
+def test_decode_chunk_write_follows_the_live_lanes_on_tpu(monkeypatch):
+    """PR 32 on the chip: the KV write of a decode chunk takes the rows of its
+    live lanes, not of the 32 the engine was built with. One decode chunk (8
+    steps) of the benchmark's OLMoE cell (``olmoe-chat-steady``: 8 layers of
+    2048, 16 / 16 heads of 128, 64 experts of 1024 of which a token takes 8,
+    vocab 50304, 32 lanes, a 2048-page arena, kernel on) at 2 live lanes of 32
+    and at 32 of 32, as the tree writes and with every lane's rows written
+    (the parent's write: no order handed down), the arena's content the same
+    for both. At 2 lanes the step must come out at least 0.3 ms shorter (512
+    rows a side a layer less, 95 ns each: 0.7 ms; measured 3.920 -> 3.321); at
+    32 lanes it may cost the loop's eight trips a layer (measured 10.741 ->
+    11.009; asserted within 6 %)."""
+    cfg = build("moe_lm", {
+        "vocab_size": 50304, "d_model": 2048, "n_layers": 8, "n_heads": 16,
+        "n_kv_heads": 16, "d_ff": 1024, "n_experts": 64, "top_k": 8,
+        "norm_topk_prob": False, "qk_norm": True, "tie_embeddings": False,
+        "max_seq": 4096, "rope_theta": 1e4, "dtype": "bfloat16"}).config
+    params = _random_bf16_params("moe_lm", cfg)
+    lanes, pt, chunk, n_pages = 32, 16, 8, 2048
+    pps = cfg["max_seq"] // pt
+    rngs = jax.random.split(jax.random.PRNGKey(1), chunk)
+    live_lanes = generation._live_lanes
+    ms = {}
+    for write in ("live", "every"):
+        monkeypatch.setattr(generation, "_live_lanes",
+                            live_lanes if write == "live" else lambda active: None)
+        generation._paged_decode_chunk_jit.clear_cache()
+        for live in (2, 32):
+            idx = np.sort(np.random.default_rng(live).permutation(lanes)[:live])
+            tables = np.zeros((lanes, pps), np.int32)
+            pos = np.zeros((lanes,), np.int32)
+            nxt = 1
+            for lane in idx:
+                pos[lane] = 400 + 11 * int(lane)
+                n = -(-(int(pos[lane]) + chunk + 1) // pt)
+                tables[lane, :n] = np.arange(nxt, nxt + n)
+                nxt += n
+            assert nxt <= n_pages
+            active = np.zeros((lanes,), bool)
+            active[idx] = True
+            # a fresh arena a row: what the experts hit follows its content
+            cache = generation.init_paged_cache(cfg, n_pages + 1, pt)
+            k, v = cache["k"], cache["v"]
+            tok = jnp.ones((lanes,), jnp.int32)
+            best = float("inf")
+            for _ in range(6):                   # the first call compiles
+                t0 = time.perf_counter()
+                k, v, _, _, _, toks, _ = generation._paged_decode_chunk_jit(
+                    params, k, v, None, tables, tok, pos, active, rngs,
+                    np.zeros((lanes,), np.float32), np.zeros((lanes,), np.int32),
+                    cfg_key=tuple(sorted(cfg.items())), family="moe_lm",
+                    chunk=chunk, page_tokens=pt, kernel=True)
+                jax.block_until_ready(toks)
+                best = min(best, time.perf_counter() - t0)
+            ms[write, live] = best / chunk * 1e3
+            del k, v, cache
+    generation._paged_decode_chunk_jit.clear_cache()
+    print(f"\n[decode chunk vs live lanes] OLMoE cell shape, ms a step: "
+          f"2 live of 32: {ms['live', 2]:.3f} (every lane's rows written: "
+          f"{ms['every', 2]:.3f}); 32 of 32: {ms['live', 32]:.3f} "
+          f"({ms['every', 32]:.3f})", flush=True)
+    assert ms["every", 2] - ms["live", 2] > 0.3, ms
+    assert ms["live", 32] < 1.06 * ms["every", 32], ms

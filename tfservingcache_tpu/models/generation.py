@@ -461,15 +461,47 @@ def _quantize_kv_rows(x):
     return q, scale
 
 
-def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows):
+# Lanes a trip of a decode chunk's KV write (``_paged_write_rows``). Set on the
+# chip (v5e, PR 32): a row of the scatter costs 95 ns and a trip of the loop
+# about 5 us, i.e. 50 rows, two to three lanes of 16 to 32 rows (8 KV heads or
+# 16, two sides). The engine runs 2 to 4 live lanes, which 4 writes in one
+# trip; 32 live lanes take 8 trips a layer, 2.5 % of an OLMoE step at full load
+# over one scatter of all rows (11.01 against 10.74 ms).
+_WRITE_GROUP = 4
+
+
+def _write_trips(count):
+    """Trips of the write's loop for ``count`` live lanes. Operators only: the
+    device (a traced scalar) and the host (``kv_write_lanes``) run this line."""
+    return (count + _WRITE_GROUP - 1) // _WRITE_GROUP
+
+
+def kv_write_lanes(active) -> int:
+    """The lanes whose rows a decode chunk's KV write takes a step, worked out
+    on the host from the ``active`` mirror the chunk is dispatched with (the
+    ring's ``write_lanes``, the ``tpusc_gen_kv_write_steps_total`` label)."""
+    active = np.asarray(active, bool)
+    if active.size <= _WRITE_GROUP:
+        return active.size
+    return min(active.size, int(_write_trips(int(active.sum()))) * _WRITE_GROUP)
+
+
+def _live_lanes(active):
+    """``(order, count)`` for ``_paged_write_rows``: the lanes, the live ones
+    first (stable), and how many are live."""
+    return (jnp.argsort(~active, stable=True).astype(jnp.int32),
+            jnp.sum(active, dtype=jnp.int32))
+
+
+def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows, live=None):
     """THE write into the paged arena, shared by every paged step: the new
     rows ``k_rows`` / ``v_rows`` ``(S, T, n_kv, hd)`` of layer ``li`` go to
-    ``arena[li, pages, :, off]`` (``pages`` / ``off`` ``(S, T)``) as one
-    scatter on the whole 5-D arena. The arena is donated to the chunk
-    programs and carried by their scans, so the scatter updates it in
-    place: a step moves its new rows, never a layer's slice (there is no
-    ``cache["k"][li]`` and nothing is stacked back). ``li`` is a Python int
-    (the layer loop is unrolled).
+    ``arena[li, pages, :, off]`` (``pages`` / ``off`` ``(S, T)``) as a scatter
+    on the whole 5-D arena. The arena is donated to the chunk programs and
+    carried by their scans, so the scatter updates it in place: a step moves
+    its new rows, never a layer's slice (there is no ``cache["k"][li]`` and
+    nothing is stacked back). ``li`` is a Python int (the layer loop is
+    unrolled).
 
     The kv head is an INDEX of the scatter too, not a slice of its window:
     the update is one ``hd`` row a (lane, position, head). With the heads in
@@ -479,12 +511,27 @@ def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows):
     kernels read the arena in the layout it is stored in (PR 26, compiled
     for the v5e: 20 arena-sized copies in the decode chunk; none this way).
 
+    That scatter is sequential in its rows (95 ns each on a v5e), so a decode
+    chunk hands down ``live`` (``_live_lanes`` of its frozen ``active``): the
+    write then takes ``_WRITE_GROUP`` lanes of the order a trip of a loop
+    whose trip count the device works out from the live count, and stops
+    after the last live lane. The loop carries the arena as the chunk's scan
+    does; each trip is the same scatter on fewer lanes. The slots of the last
+    trip past the live count are inactive lanes, whose rows are the junk they
+    always were: nothing is masked, every live lane's rows are written, and
+    writes of inactive lanes are only left out. A caller that means every lane (a
+    verify pass, a chunk of chunked prefill, speculation: no ``live``) gets
+    the one scatter of all rows. (A ``lax.switch`` over whole-rung scatters
+    is as fast for K/V arenas, but around a one-sided arena the v5e compiler
+    copies the arena in and out of the conditional: PR 32.)
+
     An int8 arena (``k_scale`` present) quantizes each row here, with
     per-row scales, so resident rows are never requantized. Lanes parked on
     the trash page may collide: last-writer-wins junk that no live lane's
     block table can reach. A one-sided arena (latent rows) has no ``v``:
     ``v_rows`` is None. Returns the updated cache."""
-    with jax.named_scope("kv_write"):
+
+    def write(cache, pages, off, k_rows, v_rows):
         heads = jnp.arange(k_rows.shape[2])[None, None, :]
         at = (li, pages[:, :, None], heads, off[:, :, None])     # (S, T, n_kv)
         if v_rows is None:
@@ -499,22 +546,37 @@ def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows):
         new["v"] = cache["v"].at[at].set(v_rows.astype(cache["v"].dtype))
         return new
 
+    rows = (pages, off, k_rows, v_rows)
+    with jax.named_scope("kv_write"):
+        if live is None or k_rows.shape[0] <= _WRITE_GROUP:
+            return write(cache, *rows)
+        order, count = live
+
+        def trip(i, cache):
+            # past the end the slice is clamped: lanes written twice, alike
+            take = jax.lax.dynamic_slice(
+                order, (i * _WRITE_GROUP,), (_WRITE_GROUP,))
+            return write(
+                cache, *jax.tree_util.tree_map(lambda a: a[take], rows))
+
+        return jax.lax.fori_loop(0, _write_trips(count), trip, cache)
+
 
 def _paged_forward_step(params, tok, cache, tables, pos, cfg, family,
                         page_tokens: int, kernel: bool = False, active=None,
-                        moe_stats: list | None = None):
+                        moe_stats: list | None = None, live=None):
     """One decode step (s_len=1 per lane, ``tok`` ``(S,)``) against the
     paged arena — the block-table counterpart of ``_forward_cached_dyn``,
     and ``_paged_verify_step``'s T = 1 case: that is where it is written."""
     return _paged_verify_step(
         params, tok[:, None], cache, tables, pos, cfg, family, page_tokens,
-        kernel=kernel, active=active, moe_stats=moe_stats,
+        kernel=kernel, active=active, moe_stats=moe_stats, live=live,
     )
 
 
 def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                        page_tokens: int, kernel: bool = False, active=None,
-                       moe_stats: list | None = None):
+                       moe_stats: list | None = None, live=None):
     """One forward of T positions a lane against the paged arena: the decode
     step (T = 1), the verify pass of in-engine speculative decoding and a
     chunk of chunked prefill. Lane ``s``'s T tokens ``toks[s]`` sit at
@@ -542,7 +604,9 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     inactive lane's token, so the kernel reads no page for it, and an expert
     layer routes it to no expert. Each expert layer's ``(experts_hit,
     expert_rows_max, expert_rows_local)`` is appended to ``moe_stats`` where
-    the caller gives a list (a dense model appends nothing).
+    the caller gives a list (a dense model appends nothing). ``live``
+    (``_live_lanes(active)``, worked out once a chunk) lets the write follow
+    the live lanes; without it every lane's rows are written.
 
     A latent family (one-sided arena) writes its ONE row a token and attends
     in the absorbed form, ``paged_latent_attention``: the fused kernel at
@@ -585,7 +649,7 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                     q = _rope_per_example(q, positions, cfg["rope_theta"])
                     k = _rope_per_example(k, positions, cfg["rope_theta"])
                     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            cache = _paged_write_rows(cache, li, pages, off, k, v)
+            cache = _paged_write_rows(cache, li, pages, off, k, v, live)
             with jax.named_scope("attn"):
                 if row.sides == 1:
                     out = paged_latent_attention(
@@ -834,8 +898,9 @@ def _paged_decode_chunk_jit(
     program — the continuous engine's only steady-state dispatch. K/V live
     in the shared page arena and each lane reads through its block table.
     Inactive lanes ride along: their token/pos are frozen
-    (``where(active, ...)``) and their writes land on the trash page; the
-    host ignores their emitted tokens. Admission/retirement happen on the
+    (``where(active, ...)``) and their writes, those the write does not leave
+    out (``_paged_write_rows`` follows the live lanes), land on the trash
+    page; the host ignores their emitted tokens. Admission/retirement happen on the
     host BETWEEN chunks; a row finishing mid-chunk keeps decoding from its
     own EOS until the chunk ends (the < chunk overshoot the wasted-steps
     counter measures). ``tables`` is traced (a tiny
@@ -849,6 +914,7 @@ def _paged_decode_chunk_jit(
     and fetched with the tokens; ``None`` (no output at all) for a dense
     model, whose program is therefore the one it was."""
     cfg = dict(cfg_key)
+    live = _live_lanes(active)       # once a chunk: ``active`` is frozen
 
     def step(carry, rng):
         cache, tok, pos = carry
@@ -856,6 +922,7 @@ def _paged_decode_chunk_jit(
         logits, cache = _paged_forward_step(
             params, tok, cache, tables, pos, cfg, family,
             page_tokens, kernel=kernel, active=active, moe_stats=layer_stats,
+            live=live,
         )
         nxt = _sample_per_row(logits[:, 0], rng, temperature, top_k, active)
         nxt = jnp.where(active, nxt, tok)

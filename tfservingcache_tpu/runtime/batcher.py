@@ -1146,16 +1146,21 @@ class _ContinuousScheduler:
                     pg = int(state.block_tables[cidx, slot])
                     if pg and int(state.page_refs[pg]) > 1:
                         rt.slot_cow(state, cidx, slot)
-        # what the sampler will pay for, from the mirrors this dispatch takes
-        # (the emission loop below clears ``active`` for the rows it retires)
+        # what the sampler and the KV write will pay for, from the mirrors
+        # this dispatch takes (the emission loop below clears ``active`` for
+        # the rows it retires)
+        from tfservingcache_tpu.models.generation import (
+            kv_write_lanes,
+            sample_path,
+        )
+
         path = None
         if eng.metrics is not None:
-            from tfservingcache_tpu.models.generation import sample_path
-
             path = sample_path(
                 state.active, state.temps, state.topks,
                 dict(state.cfg_key)["vocab_size"],
             )
+        write_lanes = kv_write_lanes(state.active)
         chunk_t0 = time.monotonic()
         with host_span("decode_chunk"):
             accept = None
@@ -1182,6 +1187,8 @@ class _ContinuousScheduler:
                 # ring/ledger semantics: a spec round can emit up to spec+1
                 # tokens per lane in one dispatch — that is its "chunk"
                 chunk = state.spec_tokens + 1
+                # the draft scan and the verify pass write every lane's rows
+                write_lanes = state.slots
         eng.chunks += 1
         now = time.monotonic()
         wasted = 0
@@ -1214,6 +1221,7 @@ class _ContinuousScheduler:
         emit_s = time.monotonic() - now
         if eng.metrics is not None:
             eng.metrics.gen_sample_steps.labels(path).inc(chunk)
+            eng.metrics.gen_kv_write_steps.labels(str(write_lanes)).inc(chunk)
             if wasted:
                 eng.metrics.gen_wasted_steps.labels("continuous").inc(wasted)
         if accept is not None and hasattr(rt, "_spec_observe"):
@@ -1230,7 +1238,7 @@ class _ContinuousScheduler:
             prefix_hits_n, prefill_s_sum, tokens_in_n,
             drafted=drafted, accepted=accepted,
             emitted=accepted if accept is not None else None,
-            chunk_s=now - chunk_t0, emit_s=emit_s,
+            chunk_s=now - chunk_t0, emit_s=emit_s, write_lanes=write_lanes,
         )
         return state
 
@@ -1238,6 +1246,7 @@ class _ContinuousScheduler:
         self, state, chunk, active, admitted, retired, wasted, step_t0,
         prefix_hits=0, prefill_s=0.0, tokens_in=0,
         drafted=0, accepted=0, emitted=None, chunk_s=0.0, emit_s=0.0,
+        write_lanes=0,
     ) -> None:
         """One flight-recorder ring entry per chunk boundary (``step_ms``
         split into the prefill clocks ``_step`` already keeps, the decode
@@ -1299,7 +1308,7 @@ class _ContinuousScheduler:
             prefill_ms=prefill_s * 1e3, chunk_ms=chunk_s * 1e3,
             emit_ms=emit_s * 1e3,
             experts_hit=moe_stats[0], expert_rows_max=moe_stats[1],
-            expert_rows_local=moe_stats[2],
+            expert_rows_local=moe_stats[2], write_lanes=write_lanes,
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

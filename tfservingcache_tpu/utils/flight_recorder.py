@@ -92,6 +92,12 @@ STEP_FIELDS = (
     # two above count HELD experts; of a step's active x top_k assignments
     # these landed on one (all of them where the chip holds every expert)
     "expert_rows_local",  # assignments a step a layer to a held expert (chunk mean)
+    # appended field (ISSUE 32 the KV write follows the live lanes): the
+    # lanes whose rows each step of this chunk wrote into the arena, the live
+    # lanes rounded up to whole trips of the write's loop
+    # (generation.kv_write_lanes; the built width for a spec round; 0 for a
+    # boundary that ran no chunk)
+    "write_lanes",
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -104,7 +110,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 22:
+    if len(e) == 23:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -114,7 +120,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "drafted": e[14], "accepted": e[15],
             "prefill_ms": e[16], "chunk_ms": e[17], "emit_ms": e[18],
             "experts_hit": e[19], "expert_rows_max": e[20],
-            "expert_rows_local": e[21],
+            "expert_rows_local": e[21], "write_lanes": e[22],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -273,6 +279,7 @@ class FlightRecorder:
         experts_hit: float = 0.0,
         expert_rows_max: float = 0.0,
         expert_rows_local: float = 0.0,
+        write_lanes: int = 0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -281,7 +288,7 @@ class FlightRecorder:
             drafted, accepted,
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
             round(experts_hit, 3), round(expert_rows_max, 3),
-            round(expert_rows_local, 3),
+            round(expert_rows_local, 3), write_lanes,
         ))
 
     def note_phases(

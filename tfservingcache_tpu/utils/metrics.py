@@ -208,6 +208,14 @@ class Metrics:
             "draws puts the whole step on its path",
             ["path"], registry=r,
         )
+        self.gen_kv_write_steps = Counter(
+            "tpusc_gen_kv_write_steps",
+            "Decode steps of the engine (the flight recorder's chunk) by the "
+            "lanes whose KV rows the step wrote into the arena: the chunk's "
+            "live lanes rounded up to whole trips of the write's loop, four "
+            "lanes a trip (the engine's built width for a speculation round)",
+            ["lanes"], registry=r,
+        )
         self.gen_admission_wait = Histogram(
             "tpusc_gen_admission_wait_seconds",
             "Time a generate request waited before decoding began on its "

@@ -72,6 +72,8 @@ def _fmt_step(s: dict) -> str:
                   f"rows_max={s.get('expert_rows_max', 0):.1f} ")
         if "expert_rows_local" in s:    # ISSUE 31: assignments that landed here
             split += f"rows_local={s['expert_rows_local']:.1f} "
+    if s.get("write_lanes"):    # ISSUE 32: the lanes whose KV rows a step wrote
+        split += f"write_lanes={s['write_lanes']} "
     return (
         f"  {s.get('engine', '?'):<10} step={s.get('step_ms', 0):>8.2f}ms {split}"
         f"chunk={s.get('chunk', 0):>3} active={s.get('active', 0):>3} "

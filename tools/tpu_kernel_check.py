@@ -43,7 +43,13 @@ def main() -> int:
     # cell, 10 of 32 lanes active (printed, not asserted), the kernels on
     # the whole 5-D arena at a layer, and one decode chunk of the Mistral
     # cell's shape with the same live tokens at kv_arena_pages 512 and 4096
-    # (a step's time must not follow the arena's size: asserted within 10 %).
+    # (a step's time must not follow the arena's size: asserted within 10 %),
+    # and one of the OLMoE cell's shape at 2 live lanes of 32 against 32 of 32,
+    # as the tree writes its KV rows and with every lane's rows written (PR 32:
+    # the write follows the live lanes; at 2 lanes at least 0.3 ms a step less,
+    # at 32 within 6 %). `layer/kv_write` a step of a chat cell comes from a
+    # kept capture of a traced benchmark run read by tools/trace_scopes.py
+    # (PERF.md section 5 has parent beside change).
     # test_olmoe.py carries the grouped expert kernel's rows at
     # OLMoE-1B-7B's widths: the grouped product with 25 / 64 of 64 experts
     # hit by 1, 4 and 48 rows each and with 63 experts empty, and the whole
