@@ -203,7 +203,7 @@ def _paged_setup(tree, prompt, lanes=4, lane=1, pages=24):
     p_pad = 16
     ids = np.zeros((1, p_pad), np.int32)
     ids[0, :len(prompt)] = prompt
-    tok, pk, pv, last = generation._slot_prefill_jit(
+    tok, pk, pv, last, _lane = generation._slot_prefill_jit(
         tree, ids, np.asarray([len(prompt)], np.int32), jax.random.PRNGKey(0),
         np.float32(0.0), np.int32(0), cfg_key=tuple(sorted(cfg.items())),
         family="mla_moe_lm")
@@ -258,7 +258,7 @@ def test_b_prefill_then_paged_decode_matches_the_reference_at_every_position(
     tok = np.zeros((4,), np.int32)
     tok[lane] = first
     rngs = jax.random.split(jax.random.PRNGKey(1), 4)
-    k, v, scales, *_, toks, stats = generation._paged_decode_chunk_jit(
+    k, v, scales, *_, toks, stats, _lane = generation._paged_decode_chunk_jit(
         dev, cache["k"], None, None, tables, tok, pos, active, rngs,
         np.zeros((4,), np.float32), np.zeros((4,), np.int32),
         cfg_key=tuple(sorted(cfg.items())), family="mla_moe_lm", chunk=4,

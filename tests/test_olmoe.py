@@ -142,7 +142,7 @@ def _paged_setup(tree, prompt, lanes=4, lane=0, pages=24):
     p_pad = 16
     ids = np.zeros((1, p_pad), np.int32)
     ids[0, :len(prompt)] = prompt
-    tok, pk, pv, last = generation._slot_prefill_jit(
+    tok, pk, pv, last, _lane = generation._slot_prefill_jit(
         tree, ids, np.asarray([len(prompt)], np.int32), jax.random.PRNGKey(0),
         np.float32(0.0), np.int32(0), cfg_key=cfg_key, family="moe_lm")
     cache = generation.init_paged_cache(cfg, pages, PT)
@@ -184,7 +184,7 @@ def test_b_prefill_then_paged_decode_matches_the_reference_at_every_position():
     tok = np.zeros((4,), np.int32)
     tok[0] = first
     rngs = jax.random.split(jax.random.PRNGKey(1), 4)
-    *_, toks, stats = generation._paged_decode_chunk_jit(
+    *_, toks, stats, _lane = generation._paged_decode_chunk_jit(
         dev, cache["k"], cache["v"], None, tables, tok, pos, active, rngs,
         np.zeros((4,), np.float32), np.zeros((4,), np.int32),
         cfg_key=tuple(sorted(cfg.items())), family="moe_lm", chunk=4,

@@ -549,7 +549,7 @@ def test_decode_chunk_time_does_not_follow_the_arena_on_tpu():
         best = float("inf")
         for _ in range(6):                   # the first call compiles
             t0 = time.perf_counter()
-            k, v, _, _, _, toks, _ = generation._paged_decode_chunk_jit(
+            k, v, _, _, _, toks, _, _ = generation._paged_decode_chunk_jit(
                 params, k, v, None, tables, tok, pos, active, rngs,
                 np.zeros((lanes,), np.float32), np.zeros((lanes,), np.int32),
                 cfg_key=tuple(sorted(cfg.items())), chunk=chunk,
@@ -615,7 +615,7 @@ def test_decode_chunk_write_follows_the_live_lanes_on_tpu(monkeypatch):
             best = float("inf")
             for _ in range(6):                   # the first call compiles
                 t0 = time.perf_counter()
-                k, v, _, _, _, toks, _ = generation._paged_decode_chunk_jit(
+                k, v, _, _, _, toks, _, _ = generation._paged_decode_chunk_jit(
                     params, k, v, None, tables, tok, pos, active, rngs,
                     np.zeros((lanes,), np.float32), np.zeros((lanes,), np.int32),
                     cfg_key=tuple(sorted(cfg.items())), family="moe_lm",

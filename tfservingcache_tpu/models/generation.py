@@ -1,6 +1,15 @@
 """KV-cached autoregressive generation for the decoder-LM families
 (transformer_lm, moe_lm — both share the attention/cache layout; the FFN
-half is pluggable: dense silu-gate MLP vs routed expert block).
+half is pluggable: dense silu-gate MLP vs routed expert block; mla_moe_lm
+with its one-sided latent row; hybrid_lm, whose convolution layers keep a
+fixed state a request beside the rows its attention layers keep).
+
+What a layer keeps is the ModelDef's to say (``registry.static_config`` puts
+``cache_row`` and, for a model of several kinds, ``layer_state`` into the
+programs' config): a layer with a ``CacheRow`` has a layer of the cache or
+the arena (``_layer_slots``: the arena has as many layers as the model has
+such layers), a layer with a ``LaneState`` has a slice of the ``lane`` array
+that rides in the same cache dict, ``(lane layers, lanes, rows, width)``.
 
 No reference counterpart (the reference proxies opaque Predict calls —
 SURVEY.md §5); generation is where a TPU-native LM server must not re-run
@@ -29,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tfservingcache_tpu.models.hybrid_lm import conv_operator
 from tfservingcache_tpu.models.mla_moe_lm import (
     absorbed_output,
     absorbed_query,
@@ -40,6 +50,7 @@ from tfservingcache_tpu.models.mla_moe_lm import (
 from tfservingcache_tpu.models.moe_lm import _moe_block
 from tfservingcache_tpu.models.registry import (
     CacheRow,
+    LaneState,
     kv_cache_row,
     static_config,
 )
@@ -66,15 +77,68 @@ def _cache_row(cfg) -> CacheRow:
     return cfg.get("cache_row") or kv_cache_row(cfg)
 
 
+def _layer_slots(cfg) -> list[tuple[bool, int]]:
+    """For each layer of the model ``(keeps a lane state, its index)``: the
+    index into the ``lane`` array for a layer with a ``LaneState``, into the
+    cache's or the arena's layers for a layer with a ``CacheRow``. A config
+    with no ``layer_state`` (every family of one kind) has a cache layer a
+    model layer, in order."""
+    kinds = cfg.get("layer_state") or (None,) * int(cfg["n_layers"])
+    out, rows, lanes = [], 0, 0
+    for kind in kinds:
+        if isinstance(kind, LaneState):
+            out.append((True, lanes))
+            lanes += 1
+        else:
+            out.append((False, rows))
+            rows += 1
+    return out
+
+
+def _row_layers(cfg) -> int:
+    """The model's layers that keep rows: the cache's and the arena's layers."""
+    return sum(not lane for lane, _ in _layer_slots(cfg))
+
+
+def _lane_layer(layer: dict, x, state, real_len, dtype, eps):
+    """A layer that keeps a lane state, its operator half, for both cached
+    loops: the residual stream ``x`` BEFORE its norm and the layer's slice
+    ``state (B, rows, width)`` -> (residual delta, the slice after
+    ``real_len`` of the tokens at hand; ``conv_operator``)."""
+    with jax.named_scope("conv"):
+        conv = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["conv"])
+        return conv_operator(conv, _rmsnorm(x, layer["ln1"], eps), state,
+                             real_len)
+
+
+def init_lane_state(cfg: dict, lanes: int):
+    """Zeros ``(lane layers, lanes, rows, width)`` in the model's dtype: what
+    the layers with a ``LaneState`` keep, a slice a lane (a request's
+    beginning is zeros); None for a model with no such layer."""
+    kinds = [k for k in cfg.get("layer_state") or ()
+             if isinstance(k, LaneState)]
+    if not kinds:
+        return None
+    if len(set(kinds)) != 1:
+        raise ValueError(f"lane states of several shapes: {set(kinds)}")
+    return jnp.zeros((len(kinds), lanes, kinds[0].rows, kinds[0].width),
+                     jnp.dtype(cfg["dtype"]))
+
+
 def init_cache(cfg: dict, batch: int, max_len: int, mesh=None) -> dict:
     """Preallocated per-layer K/V buffers. bf16 storage halves HBM traffic;
     attention still accumulates in f32. ``mesh`` commits the buffers to
     KV-head shardings (parallel/sharding.kv_arena_shardings) so the slot
-    jits compile partitioned programs from day one."""
+    jits compile partitioned programs from day one. A model with lane-state
+    layers gets ``lane`` beside the sides, and cache layers for its other
+    layers only."""
     row = _cache_row(cfg)
     dtype = jnp.dtype(cfg["dtype"])
-    shape = (cfg["n_layers"], batch, row.heads, max_len, row.width)
+    shape = (_row_layers(cfg), batch, row.heads, max_len, row.width)
     cache = {side: jnp.zeros(shape, dtype) for side in "kv"[:row.sides]}
+    lane = init_lane_state(cfg, batch)
+    if lane is not None:
+        cache["lane"] = lane
     if mesh is not None:
         from tfservingcache_tpu.parallel.sharding import shard_kv_arena
 
@@ -230,7 +294,8 @@ def _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family):
     """The forward of whole (right-padded) prompts into a fresh cache, the
     start_pos = 0 case of ``_forward_cached_dyn`` -> (the last REAL prompt
     token's logits ``(B, V)`` f32, the cache). Padding positions write junk
-    rows the per-step mask keeps invisible until overwritten. A latent family
+    rows the per-step mask keeps invisible until overwritten; a lane state is
+    the one AT ``prompt_len``, which no pad token has touched. A latent family
     projects that one position through the head and no other (a long
     prompt's ``S_pad x V`` float32 logits are a gigabyte at 8192 x 32768)."""
     b = input_ids.shape[0]
@@ -238,6 +303,7 @@ def _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family):
     logits, cache = _forward_cached_dyn(
         params, input_ids, cache, jnp.zeros((b,), jnp.int32), cfg, family,
         fresh=True, logits_at=prompt_len - 1 if latent else None,
+        real_len=prompt_len,
     )
     if latent:
         return logits[:, 0], cache
@@ -330,7 +396,8 @@ def _slot_prefill_jit(
 ):
     """Prefill ONE prompt into a fresh (1, S_pad)-row cache and sample the
     request's first token — the admission half of the continuous engine.
-    Returns (first_tok (1,), k, v, last_logits (1, V) f32); the first
+    Returns (first_tok (1,), k, v, last_logits (1, V) f32, the lane state at
+    ``prompt_len`` ``(lane layers, 1, rows, width)`` or None); the first
     token's own K/V is NOT yet in the cache (it sits at pos=prompt_len,
     written by the first decode-chunk step — the same convention as
     ``_decode_scan``'s first_tok). The last-position logits ride along so
@@ -343,7 +410,7 @@ def _slot_prefill_jit(
         params, input_ids, prompt_len, init_cache(cfg, b, s_max), cfg, family)
     _, sub = jax.random.split(rng)
     tok = _sample(last, sub, temperature, top_k)
-    return tok, cache["k"], cache.get("v"), last
+    return tok, cache["k"], cache.get("v"), last, cache.get("lane")
 
 
 @functools.partial(jax.jit, static_argnames=("cfg_key", "family"))
@@ -423,10 +490,14 @@ def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
     ``row`` (the family's ``ModelDef.cache_row``; a config with no row means
     the decoder-LM K/V row, ``_cache_row``) says what a page holds: ``sides``
     arrays of ``(heads, page_tokens, width)`` tiles. A latent family's arena
-    is ONE side, ``k``, of one shared row a token; there is no ``v``."""
+    is ONE side, ``k``, of one shared row a token; there is no ``v``. The
+    arena's first axis counts the model's layers that keep rows: ``cfg``
+    carries ``layer_state`` where some layers keep a lane state instead
+    (``static_config``), and those have no layer here."""
     row = row or _cache_row(cfg)
     dtype = jnp.dtype(cfg["dtype"])
-    shape = (cfg["n_layers"], n_pages, row.heads, page_tokens, row.width)
+    # a layer of the arena a model layer that HAS pages (``_layer_slots``)
+    shape = (_row_layers(cfg), n_pages, row.heads, page_tokens, row.width)
     if row.sides == 1:
         if arena_dtype == "int8":
             raise ValueError("a latent (one-sided) arena has no int8 form")
@@ -535,8 +606,9 @@ def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows, live=None):
         heads = jnp.arange(k_rows.shape[2])[None, None, :]
         at = (li, pages[:, :, None], heads, off[:, :, None])     # (S, T, n_kv)
         if v_rows is None:
-            return {"k": cache["k"].at[at].set(k_rows.astype(cache["k"].dtype))}
-        new = {}
+            return {**cache,
+                    "k": cache["k"].at[at].set(k_rows.astype(cache["k"].dtype))}
+        new = dict(cache)       # every side below; a ``lane`` state rides along
         if "k_scale" in cache:
             k_rows, k_s = _quantize_kv_rows(k_rows)
             v_rows, v_s = _quantize_kv_rows(v_rows)
@@ -610,7 +682,14 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
 
     A latent family (one-sided arena) writes its ONE row a token and attends
     in the absorbed form, ``paged_latent_attention``: the fused kernel at
-    T = 1, the gather + einsum reference above it."""
+    T = 1, the gather + einsum reference above it.
+
+    A layer that keeps a ``LaneState`` (``_layer_slots``) touches no page: its
+    operator maps the lane's slice of ``cache["lane"]`` and the step's token
+    to the slice after (``conv_operator``), and an inactive lane's slice
+    stays bit for bit. Only T = 1 carries it: a verify pass or a prefill
+    chunk of such a model is refused by name at trace time (the runtime
+    refuses them before, ``_refuse_lane_state``)."""
     from tfservingcache_tpu.ops.attention import (
         paged_attention,
         paged_attention_verify,
@@ -633,10 +712,27 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     pages = jnp.where(positions // page_tokens >= pps, 0, pages)
     off = positions % page_tokens
 
+    slots = _layer_slots(cfg)
+    if t_q != 1 and any(lane for lane, _ in slots):
+        raise ValueError(
+            f"{family}: a forward of {t_q} positions a lane over the paged "
+            "arena (a speculative verify pass, a prefill chunk) does not "
+            "carry a lane state")
+    # a lane nobody reads keeps its state: it takes 0 of the step's 1 token
+    took = None if active is None else active.astype(jnp.int32)
+
     with jax.named_scope("embed"):
         x = params["embed"][toks].astype(dtype)                  # (S, T, d)
-    for li, layer in enumerate(params["layers"]):
+    for layer, (lane, li) in zip(params["layers"], slots):
         with jax.named_scope("layer"):
+            if lane:
+                out, after = _lane_layer(
+                    layer, x, cache["lane"][li], took, dtype, eps)
+                cache = {**cache, "lane": cache["lane"].at[li].set(after)}
+                x = x + out
+                x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
+                                   moe_stats=moe_stats)
+                continue
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
                 a = _rmsnorm(x, layer["ln1"], eps)
@@ -713,11 +809,14 @@ def _paged_prefill_chunk_jit(params, arena_k, arena_v, scales, table_row,
     return (*_cache_arena(cache), last)
 
 
-def _arena_cache(arena_k, arena_v, scales) -> dict:
+def _arena_cache(arena_k, arena_v, scales, lane_state=None) -> dict:
     """The arena as the jits take it (``k``, ``v`` or None for a one-sided
-    arena, the int8 arena's ``scales`` or None) -> the cache dict the steps
-    carry."""
+    arena, the int8 arena's ``scales`` or None) and the lane states beside
+    it (None for a model whose layers all keep rows) -> the cache dict the
+    steps carry."""
     cache = {"k": arena_k}
+    if lane_state is not None:
+        cache["lane"] = lane_state
     if arena_v is not None:
         cache["v"] = arena_v
     if scales is not None:
@@ -727,7 +826,8 @@ def _arena_cache(arena_k, arena_v, scales) -> dict:
 
 
 def _cache_arena(cache: dict) -> tuple:
-    """``_arena_cache``'s inverse -> (k, v | None, scales | None)."""
+    """``_arena_cache``'s inverse -> (k, v | None, scales | None); a lane
+    state is ``cache.get("lane")``."""
     scales = ({"k": cache["k_scale"], "v": cache["v_scale"]}
               if "k_scale" in cache else None)
     return cache["k"], cache.get("v"), scales
@@ -873,7 +973,7 @@ def _pages_import_jit(arena_k, arena_v, scales, pages, pk, pv, pscales):
 @functools.partial(
     jax.jit,
     static_argnames=("cfg_key", "family", "chunk", "page_tokens", "kernel"),
-    donate_argnums=(1, 2, 3),
+    donate_argnums=(1, 2, 3, 11),
 )
 def _paged_decode_chunk_jit(
     params,
@@ -887,6 +987,7 @@ def _paged_decode_chunk_jit(
     rngs,                # (chunk, 2) uint32 — one PRNG key per step
     temperature,         # (S,) f32 per-lane
     top_k,               # (S,) i32 per-lane
+    lane_state=None,     # (lane layers, S, rows, width) | None — donated
     *,
     cfg_key,
     family: str = "transformer_lm",
@@ -908,11 +1009,14 @@ def _paged_decode_chunk_jit(
     mints a new program; compiled-program count stays one per chunk size
     (x2 for the ``kernel`` boolean — the serving.kv_paged_kernel gate).
 
-    The last output is the chunk's routing stats for a model with expert
-    layers: float32 ``(experts_hit, expert_rows_max, expert_rows_local)``,
-    each a mean over the chunk's steps and layers, computed by the program
-    and fetched with the tokens; ``None`` (no output at all) for a dense
-    model, whose program is therefore the one it was."""
+    The output before the last is the chunk's routing stats for a model with
+    expert layers: float32 ``(experts_hit, expert_rows_max,
+    expert_rows_local)``, each a mean over the chunk's steps and the layers
+    that hold experts, computed by the program and fetched with the tokens;
+    ``None`` (no output at all) for a dense model, whose program is therefore
+    the one it was. The last is ``lane_state`` after the chunk (donated like
+    the arena and carried by the same scan; an inactive lane's slice comes
+    back bit for bit), ``None`` in and out for a model that keeps rows only."""
     cfg = dict(cfg_key)
     live = _live_lanes(active)       # once a chunk: ``active`` is frozen
 
@@ -932,13 +1036,23 @@ def _paged_decode_chunk_jit(
         return (cache, nxt, pos), (nxt, stats)
 
     (cache, tok, pos), (toks, stats) = jax.lax.scan(
-        step, (_arena_cache(arena_k, arena_v, scales), tok, pos), rngs,
-        length=chunk
+        step, (_arena_cache(arena_k, arena_v, scales, lane_state), tok, pos),
+        rngs, length=chunk
     )
     if stats is not None:
         stats = jnp.mean(stats, axis=0)
     return (*_cache_arena(cache), tok, pos,
-            jnp.transpose(toks, (1, 0)), stats)  # (S, chunk), (3,) | None
+            jnp.transpose(toks, (1, 0)), stats,  # (S, chunk), (3,) | None
+            cache.get("lane"))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("state_insert")
+def _lane_insert_jit(lane_state, new, lane):
+    """An admitted request's lane state ``new (lane layers, 1, rows, width)``
+    (``_slot_prefill_jit``'s last output) into slice ``lane`` of the state
+    array, in place (donated). ``lane`` is traced: one program a model."""
+    return lane_state.at[:, lane].set(new[:, 0].astype(lane_state.dtype))
 
 
 # what a decode chunk reports of its expert layers, in the order of its last
@@ -988,13 +1102,16 @@ def _latent_cached_layer(attn, a, rows_layer, start_pos, positions, cfg,
 
 def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
                         family: str = "transformer_lm", fresh: bool = False,
-                        logits_at=None):
+                        logits_at=None, real_len=None):
     """Like _forward_cached but with PER-EXAMPLE start positions (B,) —
     needed because prompts in one batch have different true lengths.
     ``fresh`` promises every start is 0 and the cache empty (a latent family
     then takes its expanded form; the K/V families compute the same either
     way). ``logits_at (B,)`` projects that one position of each example
-    through the head -> logits ``(B, 1, V)``; None = every position."""
+    through the head -> logits ``(B, 1, V)``; None = every position.
+    ``real_len (B,)`` is how many of the tokens at hand are real (None = all):
+    a layer that keeps a lane state (``cache["lane"]``) leaves the state after
+    that many, not after a right-padded prompt's pad tokens."""
     dtype = jnp.dtype(cfg["dtype"])
     b, s_len = input_ids.shape
     positions = start_pos[:, None] + jnp.arange(s_len)[None, :]   # (B, S)
@@ -1003,10 +1120,17 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
 
     with jax.named_scope("embed"):
         x = params["embed"][input_ids].astype(dtype)
-    new_k, new_v = [], []
+    new_k, new_v, new_lane = [], [], []
     n_heads, n_kv = cfg["n_heads"], cfg.get("n_kv_heads")
-    for li, layer in enumerate(params["layers"]):
+    for layer, (lane, li) in zip(params["layers"], _layer_slots(cfg)):
         with jax.named_scope("layer"):
+            if lane:
+                out, after = _lane_layer(
+                    layer, x, cache["lane"][li], real_len, dtype, eps)
+                new_lane.append(after)
+                x = x + out
+                x = x + _ffn_block(layer, x, cfg, dtype)
+                continue
             if latent:
                 with jax.named_scope("attn"):
                     attn = jax.tree_util.tree_map(
@@ -1074,6 +1198,8 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
         new_cache = {"k": jnp.stack(new_k)}
         if new_v:
             new_cache["v"] = jnp.stack(new_v)
+    if new_lane:
+        new_cache["lane"] = jnp.stack(new_lane).astype(cache["lane"].dtype)
     return logits, new_cache
 
 

@@ -72,7 +72,7 @@ def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
     BEFORE its norm -> (residual delta, the layer's routing stats).
     ``row_mask (B*S,)`` marks rows whose answer nobody reads. What the config
     may add to the plain top-k layer: the router's ``route_score`` /
-    ``route_scale``, the chip's share ``n_experts_held`` experts from
+    ``route_scale`` / ``route_norm_eps``, the chip's share ``n_experts_held`` experts from
     ``expert_first`` (``ops.moe.moe_experts``' ``held``), and, where the layer
     holds ``moe/shared``, a dense SwiGLU expert every token takes, computed
     once beside the routed ones."""
@@ -87,7 +87,8 @@ def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
         z, moe, int(cfg["top_k"]),
         norm_topk=bool(cfg["norm_topk_prob"]), row_mask=row_mask,
         partitioned=partitioned, score=cfg.get("route_score", "softmax"),
-        route_scale=float(cfg.get("route_scale", 1.0)), held=held)
+        route_scale=float(cfg.get("route_scale", 1.0)), held=held,
+        norm_eps=float(cfg.get("route_norm_eps", 0.0)))
     if "shared" in layer["moe"]:
         with jax.named_scope("shared"):
             sh = jax.tree_util.tree_map(lambda w: w.astype(dtype),

@@ -77,6 +77,18 @@ class CacheRow:
     value_width: int = 0     # latent rows only: the value is a prefix of the key
 
 
+@dataclass(frozen=True)
+class LaneState:
+    """What a request keeps in a layer whose state does NOT grow with its
+    length: ``rows`` rows of ``width`` in the model's dtype, fixed for the
+    life of the request (a gated short convolution keeps its last
+    ``kernel - 1`` inputs). It lives beside the paged arena, one slice a lane
+    (``SlotDecodeState.lane_state``), not in pages."""
+
+    rows: int
+    width: int
+
+
 def kv_cache_row(cfg: Mapping[str, Any]) -> CacheRow:
     """The decoder-LM families' row: K and V of ``(n_kv_heads, d_model /
     n_heads)`` — the one place that derives it."""
@@ -144,9 +156,10 @@ class ModelDef:
     # group. Plain TP families leave it None — their sharding is declarative
     # (partition_rules) and XLA inserts the collectives.
     bind_mesh: Callable[[Any], Callable[[Any, Mapping[str, Any]], dict[str, Any]]] | None = None
-    # the family declares that the continuous engine may serve it: its only
-    # per-request layer state is the rows ``cache_row`` describes, one a
-    # token a layer (models/generation.py), and its step is row-invariant — a
+    # the family declares that the continuous engine may serve it: every
+    # layer's per-request state is one of the kinds ``layer_state`` declares
+    # (the rows ``cache_row`` describes, one a token, in the paged arena; or a
+    # fixed ``LaneState`` a lane beside it), and its step is row-invariant — a
     # row's logits do not depend on the rows beside it, so strangers can
     # share a decode step. The engine and the arena ask this, not the name.
     engine_ready: bool = False
@@ -154,18 +167,39 @@ class ModelDef:
     # the paged arena and its byte accounting are built from it. Every
     # ``engine_ready`` family declares one.
     cache_row: CacheRow | None = None
+    # what each layer keeps of a request, one entry a layer: the model's
+    # ``cache_row`` (the layer has pages in the arena) or a ``LaneState`` (a
+    # fixed state a lane). A family whose layers are all of one kind declares
+    # ``cache_row`` alone and gets ``(cache_row,) * n_layers``.
+    layer_state: tuple = ()
+
+    def __post_init__(self) -> None:
+        if not self.layer_state and self.cache_row is not None:
+            self.layer_state = (self.cache_row,) * int(self.config["n_layers"])
 
 
 def static_config(model: ModelDef) -> tuple:
     """The hashable form of a family's config that the programs of
     models/generation.py are specialised on (their static ``cfg_key``): the
-    config's items, sorted, and under ``cache_row`` the row the ModelDef
-    declares. The one place a row enters shared code: nothing there derives
-    it from a config key of some family."""
-    items = dict(model.config)
+    config's items, sorted, under ``cache_row`` the row the ModelDef declares
+    and, for a model whose layers are not all of that kind, under
+    ``layer_state`` what each layer keeps. The one place a row or a layer's
+    kind enters shared code: nothing there derives either from a config key
+    of some family. (A model of one kind carries no ``layer_state`` item, so
+    its programs' key is the one it was.)"""
+    items = {k: tuple(v) if isinstance(v, list) else v
+             for k, v in model.config.items()}
     if model.cache_row is not None:
         items["cache_row"] = model.cache_row
+    if lane_layers(model.layer_state):
+        items["layer_state"] = tuple(model.layer_state)
     return tuple(sorted(items.items()))
+
+
+def lane_layers(layer_state) -> tuple[int, ...]:
+    """The layers (model indices) that keep a fixed ``LaneState``."""
+    return tuple(i for i, s in enumerate(layer_state)
+                 if isinstance(s, LaneState))
 
 
 _REGISTRY: dict[str, Callable[[dict[str, Any]], ModelDef]] = {}
@@ -217,7 +251,7 @@ def build(family: str, config: dict[str, Any] | None = None) -> ModelDef:
 
 _BUILTIN_MODULES = (
     "half_plus_two", "mnist_cnn", "bert", "resnet", "transformer_lm", "t5", "moe_lm",
-    "mla_moe_lm",
+    "mla_moe_lm", "hybrid_lm",
 )
 
 

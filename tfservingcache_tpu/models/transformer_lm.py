@@ -86,15 +86,22 @@ def _qkv(attn: dict, h: jax.Array, n_heads: int, n_kv: int):
     forward (here and the four in models/generation.py). ``q_norm`` /
     ``k_norm`` leaves in ``attn`` switch on QK-norm as OLMoE has it: an
     RMSNorm with a learned gain over the WHOLE query and key projection,
-    before the heads are split. A layer without the leaves traces the three
-    products and nothing else."""
+    before the heads are split. A gain of ONE head's length is the per-head
+    form (an RMSNorm over each head's columns, the gain shared by the heads
+    of a side): the leaf's shape says which. A layer without the leaves
+    traces the three products and nothing else."""
     b, s, _ = h.shape
 
     def proj(w, n, gain=None):
         t = h @ attn[w]
-        if gain in attn:
+        hd = t.shape[-1] // n
+        per_head = gain in attn and attn[gain].shape[-1] == hd
+        if gain in attn and not per_head:
             t = _rmsnorm(t, attn[gain])
-        return t.reshape(b, s, n, t.shape[-1] // n).transpose(0, 2, 1, 3)
+        t = t.reshape(b, s, n, hd)
+        if per_head:
+            t = _rmsnorm(t, attn[gain])
+        return t.transpose(0, 2, 1, 3)
 
     return (proj("wq", n_heads, "q_norm"), proj("wk", n_kv, "k_norm"),
             proj("wv", n_kv))

@@ -13,8 +13,10 @@ can therefore share a decode step with strangers.
            (``softmax``, or ``sigmoid`` with an optional selection ``bias``
            that picks the experts and does not enter their weight),
            ``jax.lax.top_k`` (ties as it breaks them), gates = the scores
-           themselves unless ``norm_topk``, times ``scale``; the ``tokens x
-           top_k`` assignments sorted by expert (stable), group sizes counted.
+           themselves unless ``norm_topk`` (over their sum plus
+           ``norm_eps``, 0 unless a model states one), times ``scale``; the
+           ``tokens x top_k`` assignments sorted by expert (stable), group
+           sizes counted.
   experts  the sorted rows gathered, ``silu(x W1_e) * (x W3_e)`` then
            ``h W2_e`` as two grouped products (bf16 operands, float32
            accumulation), unsorted, weighted by the gates and summed.
@@ -63,12 +65,14 @@ VMEM_LIMIT = 64 << 20
 
 def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool = False,
           score: str = "softmax", bias: jax.Array | None = None,
-          scale: float = 1.0):
+          scale: float = 1.0, norm_eps: float = 0.0):
     """``x (t, d)`` -> (gates ``(t, k)`` float32, experts ``(t, k)`` int32,
     scores ``(t, e)`` float32). ``score`` is the function over the router's
     logits, ``softmax`` or ``sigmoid``; ``bias (e,)`` is added to the scores
     for the SELECTION only (a gate is the unbiased score); ``scale``
-    multiplies the gates after ``norm_topk``."""
+    multiplies the gates after ``norm_topk``, whose denominator is the sum of
+    the chosen scores plus ``norm_eps`` (0, the bare sum, unless a model's
+    published router states a term)."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if score not in ("softmax", "sigmoid"):
@@ -81,7 +85,8 @@ def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool = False,
         _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
         gates = jnp.take_along_axis(probs, idx, axis=-1)
     if norm_topk:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total + norm_eps if norm_eps else total)
     if scale != 1.0:
         gates = gates * scale
     return gates, idx.astype(jnp.int32), probs
@@ -127,7 +132,9 @@ def moe_grouped_matmul(lhs, rhs, group_sizes, rhs_up=None, *, tm: int,
 
     m, k = lhs.shape
     n = rhs.shape[2]
-    tn = min(TN, n)
+    # the widest column tile of whole 128-lane tiles up to TN that divides n
+    # (1792 = 7 x 256 takes 256; 1024, 2048 and 4096 take TN as they did)
+    tn = next((t for t in range(min(TN, n), 0, -128) if n % t == 0), min(TN, n))
     if m % tm or n % tn:
         raise ValueError(f"rows {m} / columns {n} not a multiple of the tile "
                          f"({tm}, {tn})")
@@ -174,7 +181,7 @@ def moe_grouped_matmul(lhs, rhs, group_sizes, rhs_up=None, *, tm: int,
 def moe_experts(x: jax.Array, moe: dict, top_k: int, *, norm_topk: bool = False,
                 row_mask: jax.Array | None = None, partitioned: bool = False,
                 score: str = "softmax", route_scale: float = 1.0,
-                held: tuple[int, int] | None = None):
+                held: tuple[int, int] | None = None, norm_eps: float = 0.0):
     """``x (t, d)`` in the compute dtype, ``moe`` = ``router (d, E)`` float32
     (and ``bias (E,)`` where the selection has one) and ``w1``, ``w3``
     ``(e, d, ff)``, ``w2`` ``(e, ff, d)`` in the compute dtype -> (``y (t,
@@ -189,7 +196,7 @@ def moe_experts(x: jax.Array, moe: dict, top_k: int, *, norm_topk: bool = False,
     e = moe["w1"].shape[0]
     with jax.named_scope("route"):
         gates, routed, probs = route(x, moe["router"], top_k, norm_topk, score,
-                                     moe.get("bias"), route_scale)
+                                     moe.get("bias"), route_scale, norm_eps)
         if row_mask is not None:   # to no expert: one past the router's width
             routed = jnp.where(row_mask[:, None], routed, probs.shape[-1])
         idx = routed

@@ -26,7 +26,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Mapping
+from typing import Any, Mapping, NamedTuple
 
 import numpy as np
 
@@ -36,6 +36,7 @@ from tfservingcache_tpu.config import ServingConfig
 from tfservingcache_tpu.models.registry import (
     ModelDef,
     TensorSpec,
+    lane_layers,
     load_artifact,
     static_config,
 )
@@ -46,7 +47,7 @@ from tfservingcache_tpu.utils.flight_recorder import RECORDER
 from tfservingcache_tpu.utils.lockcheck import lockchecked
 from tfservingcache_tpu.utils.logging import get_logger
 from tfservingcache_tpu.utils.metrics import Metrics
-from tfservingcache_tpu.utils.tracing import TRACER, current_span
+from tfservingcache_tpu.utils.tracing import TRACER, current_span, host_span
 
 log = get_logger("runtime")
 
@@ -663,6 +664,15 @@ class LoadedModel:
     load_lock: threading.Lock = field(default_factory=threading.Lock)
 
 
+class PrefillRows(NamedTuple):
+    """The ``pk`` a prefill of a model with lane-state layers hands to
+    ``slot_admit`` (opaque to the engine between the two): its K rows for the
+    arena and the lane state at ``prompt_len`` for the admitted lane."""
+
+    k: Any
+    lane: Any
+
+
 @dataclass
 class SlotDecodeState:
     """Device + host state of one model's continuous-decode lanes
@@ -672,7 +682,10 @@ class SlotDecodeState:
     ``block_tables`` row; the free-list hands pages out at admission and
     recycles them at retirement. The host mirrors (tok/pos/active/temps/
     topks, block tables, free-list) are owned by the engine's scheduler
-    thread; the runtime only reads them to build chunk inputs."""
+    thread; the runtime only reads them to build chunk inputs.
+    ``lane_state`` holds what the model's layers with a fixed state keep
+    (``registry.LaneState``), one slice a lane beside the arena; an admission
+    overwrites its lane's slice, so retirement needs no device work."""
 
     model_id: ModelId
     cfg_key: tuple
@@ -691,6 +704,9 @@ class SlotDecodeState:
     # (experts_hit, expert_rows_max, expert_rows_local: generation.MOE_STATS),
     # chunk means; None for a dense model
     moe_stats: tuple | None = None
+    # device array (lane layers, slots, rows, width) in the model's dtype;
+    # None for a model whose layers all keep rows in the arena
+    lane_state: Any = None
     # -- arena bookkeeping (scheduler-thread-owned) --
     page_tokens: int = 0             # tokens a page; >= 1 in a built state
     arena_pages: int = 0             # usable pages (excludes trash page 0)
@@ -1870,6 +1886,8 @@ class TPUModelRuntime(BaseRuntime):
         self._refuse_experts_on_mesh(loaded)
         self._refuse_latent(
             loaded, "a draft_model" if draft_model_id is not None else None)
+        self._refuse_lane_state(
+            loaded, "a draft_model" if draft_model_id is not None else None)
         draft = None
         if draft_model_id is not None:
             if temperature > 0.0:
@@ -1944,8 +1962,11 @@ class TPUModelRuntime(BaseRuntime):
                 # pair re-auditions
                 TRACER.annotate(spec_gated=True)
                 draft = None
+            # a cached prefix is K/V rows: a model with lane-state layers
+            # would continue from it without its state, so it skips the cache
             prefix_capable = (
                 self._prefix_cache is not None and ids.shape[0] == 1
+                and not lane_layers(loaded.model_def.layer_state)
             )
             if prefix_rows is not None:
                 if prefix_rows < 0:
@@ -2094,7 +2115,10 @@ class TPUModelRuntime(BaseRuntime):
         arena_dtype: str | None = None,
         paged_kernel: bool | None = None,
     ) -> SlotDecodeState:
-        from tfservingcache_tpu.models.generation import init_paged_cache
+        from tfservingcache_tpu.models.generation import (
+            init_lane_state,
+            init_paged_cache,
+        )
 
         if page_tokens is None:
             page_tokens = getattr(self.cfg, "kv_page_tokens", 16)
@@ -2111,6 +2135,19 @@ class TPUModelRuntime(BaseRuntime):
             paged_kernel = bool(getattr(self.cfg, "kv_paged_kernel", True))
         if arena_dtype == "int8":
             self._refuse_latent(loaded, "the int8 arena (kv_arena_dtype)")
+        # a mesh, and the serving options whose machinery moves K/V pages and
+        # would leave a lane state behind: refused HERE, once, by the name of
+        # the first that is set, so such a model's first :generate says so
+        self._refuse_lane_state(loaded, next((what for option, what in (
+            (arena_dtype == "int8", "the int8 arena (kv_arena_dtype)"),
+            (share_prefix_bytes, "shared-prefix KV (kv_share_prefix_bytes)"),
+            (getattr(self.cfg, "conversation_kv_bytes", 0),
+             "conversation park/resume (conversation_kv_bytes)"),
+            (getattr(self.cfg, "spec_draft_model", ""),
+             "in-engine speculation (spec_draft_model)"),
+            (getattr(self.cfg, "prefill_chunk_tokens", 0),
+             "chunked prefill (prefill_chunk_tokens)"),
+        ) if option), None))
         # The fused Pallas decode kernel is single-chip-only (it indexes the
         # whole KV-head axis locally); on a mesh the gather+einsum reference
         # serves the sharded arena, pinned bitwise by tests/test_mesh_parity
@@ -2120,7 +2157,9 @@ class TPUModelRuntime(BaseRuntime):
         # a fast-path mesh; a lockstep runtime never builds slot state (its
         # requests go to runtime.generate), so its arena would be unsharded
         arena_mesh = None if self.mesh_lockstep else self.mesh
-        cfg = loaded.model_def.config
+        # the programs' config: the family's own plus what the ModelDef
+        # declares its layers keep (the arena has a layer a layer with rows)
+        cfg = dict(static_config(loaded.model_def))
         max_seq = int(cfg["max_seq"])
         pps = -(-max_seq // page_tokens)
         usable = int(arena_pages) if arena_pages else slots * pps
@@ -2173,6 +2212,7 @@ class TPUModelRuntime(BaseRuntime):
             topks=np.zeros((slots,), np.int32),
             k=cache["k"],
             v=cache.get("v"),
+            lane_state=init_lane_state(cfg, slots),
             scales=scales,
             arena_dtype=arena_dtype,
             kernel=bool(paged_kernel),
@@ -2210,6 +2250,10 @@ class TPUModelRuntime(BaseRuntime):
         if state.scales is not None:
             nbytes += sum(actual(a) for a in state.scales.values())
         self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(nbytes)
+        self.metrics.lane_state_bytes.labels(
+            self.metrics.model_label(state.model_id.name,
+                                     state.model_id.version)
+        ).set(0 if state.lane_state is None else actual(state.lane_state))
 
     def mesh_topology(self) -> dict | None:
         """Structural stamp for /monitoring/engine: a number without its
@@ -2228,6 +2272,8 @@ class TPUModelRuntime(BaseRuntime):
         if st is not None and self.metrics is not None:
             label = st.arena_dtype or str(st.k.dtype)
             self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(0)
+            self.metrics.lane_state_bytes.labels(self.metrics.model_label(
+                st.model_id.name, st.model_id.version)).set(0)
 
     @_mesh_serialized
     def slot_prefill(
@@ -2243,7 +2289,8 @@ class TPUModelRuntime(BaseRuntime):
         one exists — reuse ONLY; the continuous engine never inserts back,
         its completions live in the slot array, not in cache entries) and
         sample the request's first token. -> (first_token, k, v, prefix_hit)
-        with k/v ready for ``slot_admit``."""
+        with k/v ready for ``slot_admit`` (for a model with lane-state
+        layers ``k`` is a ``PrefillRows``: the rows and the lane state)."""
         tok, pk, pv, hit, _last = self._slot_prefill_impl(
             model_id, prompt, temperature, top_k, seed
         )
@@ -2281,7 +2328,8 @@ class TPUModelRuntime(BaseRuntime):
         tk = np.int32(top_k)
 
         hit = None
-        if self._prefix_cache is not None:
+        if self._prefix_cache is not None and not lane_layers(
+                loaded.model_def.layer_state):
             hit = self._prefix_cache.lookup(model_id, prompt)
             if hit is not None:
                 s_pad = next_bucket(p - hit.valid_len)
@@ -2306,11 +2354,13 @@ class TPUModelRuntime(BaseRuntime):
                 s_pad = p  # bucket overshoot: exact size (same rule as generate)
             ids = np.zeros((1, s_pad), np.int32)
             ids[0, :p] = prompt
-            tok, pk, pv, last = _slot_prefill_jit(
+            tok, pk, pv, last, lane = _slot_prefill_jit(
                 loaded.params, ids, np.asarray([p], np.int32),
                 rng, temp, tk, cfg_key=cfg_key,
                 family=loaded.model_def.family,
             )
+            if lane is not None:
+                pk = PrefillRows(pk, lane)
         return int(np.asarray(tok)[0]), pk, pv, hit is not None, last
 
     # -- chunked prefill over the paged arena (ISSUE 19) ---------------------
@@ -2342,6 +2392,8 @@ class TPUModelRuntime(BaseRuntime):
         loaded = self._resident.get(model_id)
         if loaded is None:
             raise ModelNotLoadedError(f"model {model_id} is not loaded")
+        self._refuse_lane_state(
+            loaded, "chunked prefill (prefill_chunk_tokens)")
         cfg = loaded.model_def.config
         cfg_key = static_config(loaded.model_def)
         tokens = np.asarray(tokens, np.int32).reshape(-1)
@@ -2599,6 +2651,10 @@ class TPUModelRuntime(BaseRuntime):
         from tfservingcache_tpu.cache.conversation_kv import ParkedConversation
         from tfservingcache_tpu.models.generation import _pages_export_jit
 
+        loaded = self._resident.get(state.model_id, touch=False)
+        if loaded is not None:      # parked pages would return without the state
+            self._refuse_lane_state(
+                loaded, "conversation park/resume (conversation_kv_bytes)")
         history = np.asarray(history, np.int32).reshape(-1)
         if history.shape[0] <= 0:
             return None
@@ -2743,9 +2799,30 @@ class TPUModelRuntime(BaseRuntime):
         scatters through the lane's block-table row.
         ``base_tokens`` is the shared-prefix boundary: prefill rows below it
         belong to read-only shared pages and are redirected to the trash
-        page (the suffix prefill only produced junk there anyway)."""
-        from tfservingcache_tpu.models.generation import _paged_insert_jit
+        page (the suffix prefill only produced junk there anyway).
+        A ``PrefillRows`` also carries the request's lane state, which goes
+        into slice ``idx`` of ``state.lane_state`` in a dispatch beside the
+        page insert (span ``state_insert``): a reused lane starts from its own
+        request's state, whatever its predecessor left."""
+        from tfservingcache_tpu.models.generation import (
+            _lane_insert_jit,
+            _paged_insert_jit,
+        )
 
+        if isinstance(pk, PrefillRows):
+            # a child span where a trace is open; on the engine's thread (no
+            # trace open: a span there would be a root of its own an
+            # admission) the profiler's ``tpusc.state_insert`` annotation alone
+            span = (functools.partial(TRACER.span, lane=int(idx))
+                    if current_span() is not None else host_span)
+            with span("state_insert"):
+                state.lane_state = _lane_insert_jit(
+                    state.lane_state, pk.lane, np.int32(idx))
+            pk = pk.k
+        elif state.lane_state is not None:
+            raise RuntimeError_(
+                f"{state.family}: an admission without its lane state (a "
+                "prefill that continued from cached rows?)")
         state.k, state.v, state.scales = _paged_insert_jit(
             state.k, state.v, state.scales, pk, pv,
             np.asarray(state.block_tables[idx], np.int32),
@@ -2776,11 +2853,11 @@ class TPUModelRuntime(BaseRuntime):
         if _PAGECHECK:
             _check_trash_unreachable(state)
         (state.k, state.v, state.scales, tok, pos,
-         toks, stats) = _paged_decode_chunk_jit(
+         toks, stats, state.lane_state) = _paged_decode_chunk_jit(
             loaded.params, state.k, state.v, state.scales,
             np.asarray(state.block_tables, np.int32),
             state.tok, state.pos, state.active, rngs,
-            state.temps, state.topks,
+            state.temps, state.topks, state.lane_state,
             cfg_key=state.cfg_key, family=state.family, chunk=chunk,
             page_tokens=state.page_tokens, kernel=state.kernel,
         )
@@ -2823,6 +2900,8 @@ class TPUModelRuntime(BaseRuntime):
             )
         for half in (loaded, draft):
             self._refuse_latent(half, "a draft_model")
+            self._refuse_lane_state(
+                half, "in-engine speculation (spec_draft_model)")
         if (draft.model_def.config["vocab_size"]
                 != loaded.model_def.config["vocab_size"]):
             raise RuntimeError_(
@@ -3359,9 +3438,10 @@ class TPUModelRuntime(BaseRuntime):
 
     def engine_ready_of(self, model_id: ModelId) -> bool:
         """Whether a resident model's family declares itself engine-ready
-        (``ModelDef.engine_ready``: KV pages its only layer state, a
-        row-invariant step) — what the continuous engine asks before it
-        co-batches it. False when not loaded."""
+        (``ModelDef.engine_ready``: every layer's state one of the kinds its
+        ``layer_state`` declares, rows in the paged arena or a fixed state a
+        lane, and a row-invariant step) — what the continuous engine asks
+        before it co-batches it. False when not loaded."""
         loaded = self._resident.get(model_id, touch=False)
         return loaded is not None and loaded.model_def.engine_ready
 
@@ -3394,6 +3474,30 @@ class TPUModelRuntime(BaseRuntime):
         if what:
             raise RuntimeError_(
                 f"{loaded.model_def.family} (latent attention) does not "
+                f"support {what}")
+
+    def _refuse_lane_state(self, loaded: LoadedModel,
+                           what: str | None = None) -> None:
+        """What a model with lane-state layers (``registry.LaneState``: a
+        fixed state a request beside its pages) cannot do yet is refused by
+        name, never answered wrongly: generation on a chip-group mesh always
+        (the state array is not partitioned), and ``what`` where the caller is
+        about to use it. Everything refused moves or reuses K/V PAGES and
+        would leave the lane's state behind: shared-prefix hits, conversation
+        park/resume, a ``draft_model``, chunked prefill, the int8 arena.
+
+        One site an entrance: the serving options where the slot state is
+        built; a request's ``draft_model`` in ``generate``; and the three
+        methods an engine reaches with the runtime's option unset
+        (``park_lane`` by priority preemption, ``slot_prefill_chunk`` and
+        ``slot_attach_draft`` by the engine's own constructor arguments)."""
+        if not lane_layers(loaded.model_def.layer_state):
+            return
+        if self.mesh is not None:
+            what = "generation on a chip-group mesh"
+        if what:
+            raise RuntimeError_(
+                f"{loaded.model_def.family} (lane-state layers) does not "
                 f"support {what}")
 
     def signature(self, model_id: ModelId):

@@ -388,6 +388,14 @@ class Metrics:
             "(protocol = sse | grpc)",
             ["protocol"], registry=r,
         )
+        self.lane_state_bytes = Gauge(
+            "tpusc_lane_state_bytes",
+            "Device bytes of the fixed per-lane state a model's lane-state "
+            "layers keep beside the paged KV arena (a gated short "
+            "convolution's last rows; 0 for a model whose layers all keep "
+            "pages)",
+            ["model"], registry=r,
+        )
         self.gen_kv_arena_bytes = Gauge(
             "tpusc_gen_kv_arena_bytes",
             "Device bytes allocated to the paged KV arena (pages plus, "
