@@ -25,7 +25,10 @@ One case more compiles the decode chunk for a v5e that libtpu describes with
 no chip attached: what the TPU compiler makes of the form is the finding of
 PR 26 (a careless scatter brought arena-sized layout copies), so the compiled
 program holds no layer- or arena-sized result but the scatters', and its
-temporaries stay those of the program that writes every lane.
+temporaries stay those of the program that writes every lane. It runs for a
+head-128 decoder and, since PR 34, for the LFM2 cell's attention (8 KV heads
+of 64, stored two a 128-lane row): the kernel is in the program and the arena
+is row-major wherever it appears.
 """
 
 import functools
@@ -186,9 +189,32 @@ def _large_results(hlo: str, elems: int) -> dict:
     return found
 
 
-def _compile_for_v5e_main():
-    """Child-process body of the test below: the decode chunk of a dense model
-    at 32 lanes over an arena far larger than anything else it touches,
+def _compile_only_models():
+    """{case: (family, config, lanes' pages)} of the compile-only test. ``dense``
+    is a head-128 decoder (the chat cells' arena); ``hybrid_head64`` has the
+    LFM2 cell's attention (``lfm2-longgen-steady``: hidden 2048, 32 / 8 heads of
+    64, gated convolutions beside it, experts behind two dense layers; the
+    experts small, they are not what is compiled here) over the cell's arena of
+    8193 pages, which 32 lanes of 4096 positions address whole."""
+    return {
+        "dense": ("transformer_lm", {
+            "vocab_size": 4096, "d_model": 1024, "n_layers": 2, "n_heads": 8,
+            "n_kv_heads": 8, "d_ff": 2048, "max_seq": 2048,
+            "dtype": "bfloat16"}, 1025),
+        "hybrid_head64": ("hybrid_lm", {
+            "vocab_size": 4096, "d_model": 2048, "n_layers": 4,
+            "layer_types": ["conv", "conv", "full_attention", "full_attention"],
+            "conv_kernel": 3, "n_heads": 32, "n_kv_heads": 8,
+            "n_dense_layers": 2, "d_ff_dense": 1024, "d_ff": 256,
+            "n_experts": 8, "top_k": 4, "norm_topk_prob": True,
+            "route_score": "sigmoid", "route_norm_eps": 1e-6, "max_seq": 4096,
+            "rope_theta": 1e6, "dtype": "bfloat16"}, 8193),
+    }
+
+
+def _compile_for_v5e_main(case="dense"):
+    """Child-process body of the test below: the decode chunk of ``case``'s
+    model at 32 lanes over an arena far larger than anything else it touches,
     compiled for a described v5e, as the tree writes it and with every lane's
     rows written (the parent's write: no order handed down). Prints what it
     found, or NO_TOPOLOGY."""
@@ -209,46 +235,62 @@ def _compile_for_v5e_main():
     jax.config.update("jax_enable_compilation_cache", False)
     # the gates ask the backend, "cpu" in this process: answer for the chip
     jax.default_backend = lambda: "tpu"
-    lanes, pt, chunk, n_pages = 32, 16, 8, 1025
-    md = build("transformer_lm", {
-        "vocab_size": 4096, "d_model": 1024, "n_layers": 2, "n_heads": 8,
-        "n_kv_heads": 8, "d_ff": 2048, "max_seq": 2048, "dtype": "bfloat16"})
-    cfg = md.config
+    family, config, n_pages = _compile_only_models()[case]
+    lanes, pt, chunk = 32, 16, 8
+    md = build(family, config)
+    cfg = dict(static_config(md))
     cache = jax.eval_shape(
-        lambda: generation.init_paged_cache(cfg, n_pages, pt))
-    params = jax.eval_shape(md.init, jax.random.PRNGKey(0))
+        lambda: generation.init_paged_cache(cfg, n_pages, pt, row=md.cache_row))
     s = jax.ShapeDtypeStruct
+    # weights as a server holds them: in the model's dtype
+    params = jax.tree_util.tree_map(
+        lambda a: s(a.shape, jnp.bfloat16),
+        jax.eval_shape(md.init, jax.random.PRNGKey(0)))
     lane = s((lanes,), jnp.int32)
     args = (params, cache["k"], cache["v"], None,
             s((lanes, cfg["max_seq"] // pt), jnp.int32), lane, lane,
             s((lanes,), jnp.bool_), s((chunk, 2), jnp.uint32),
-            s((lanes,), jnp.float32), lane)
+            s((lanes,), jnp.float32), lane,
+            jax.eval_shape(lambda: generation.init_lane_state(cfg, lanes)))
     args = jax.tree_util.tree_map(
         lambda a: s(a.shape, a.dtype, sharding=one), args)
-    layer_elems = cache["k"].size // cfg["n_layers"]
+    layer_elems = cache["k"].size // cache["k"].shape[0]
     live_lanes = generation._live_lanes
     for name, form in (("live", live_lanes), ("every", lambda active: None)):
         generation._live_lanes = form
         generation._paged_decode_chunk_jit.clear_cache()
         compiled = generation._paged_decode_chunk_jit.lower(
-            *args, cfg_key=static_config(md), family="transformer_lm",
+            *args, cfg_key=static_config(md), family=family,
             chunk=chunk, page_tokens=pt, kernel=True).compile()
         hlo = compiled.as_text()
+        layouts = set(re.findall(
+            r"\[%s\](\{[0-9,]*)" % ",".join(map(str, cache["k"].shape)), hlo))
         print("COMPILED", name,
               "temp", compiled.memory_analysis().temp_size_in_bytes,
               "kernel", int("paged_decode_attention_kernel" in hlo),
               "loops", len(re.findall(r" while\(", hlo)),
-              "large", json.dumps(_large_results(hlo, layer_elems)))
-    print("LAYER_BYTES", layer_elems * 2)
+              "large", json.dumps(_large_results(hlo, layer_elems)),
+              "layouts", json.dumps(sorted(layouts)))
+    print("LAYER_BYTES", layer_elems * 2, "ARENA", json.dumps(cache["k"].shape))
 
 
-def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy():
+@pytest.mark.parametrize("case", ["dense", "hybrid_head64"])
+def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy(case):
     """The decode chunk compiled for a v5e, off the chip: every layer- or
     arena-sized result is a scatter's (in place on the donated arena), inside
     the write's loops too: no ``copy``, no ``transpose``, no layout conversion
     around a loop; and the program's temporaries are those of the program that
     writes every lane, but for the gathered rows (far under one layer of the
-    arena). Skipped where libtpu cannot describe the topology."""
+    arena). Skipped where libtpu cannot describe the topology.
+
+    ``hybrid_head64`` (PR 34): the LFM2 cell's attention, 8 KV heads of 64.
+    Its arena is stored two heads a 128-lane row, so the paged kernel is in
+    the program (the parent's gate refused a head of 64), the device keeps the
+    arena row-major wherever it appears (the parent's ``(…, 8, 16, 64)`` arena
+    was kept with the PAGES minor and converted whole, in and out, every
+    chunk), and the temporaries are far under one layer of the arena (the
+    parent's gather of every table slot of every lane was a layer a side:
+    2.2 GB over the cell's 3 layers)."""
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
     env.update(_COMPILE_ONLY_ENV)
@@ -256,7 +298,7 @@ def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy():
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys; sys.path.insert(0, 'tests'); import test_arena_in_place;"
-         " test_arena_in_place._compile_for_v5e_main()"],
+         f" test_arena_in_place._compile_for_v5e_main({case!r})"],
         cwd=repo, env=env, capture_output=True, text=True, timeout=600)
     if "NO_TOPOLOGY" in r.stdout:
         pytest.skip("libtpu compile-only topology unavailable: "
@@ -264,14 +306,21 @@ def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy():
     found = dict(re.findall(r"COMPILED (\w+) (.*)", r.stdout))
     assert set(found) == {"live", "every"}, (r.stdout[-3000:], r.stderr[-3000:])
     temp, loops = {}, {}
-    layer_bytes = int(re.search(r"LAYER_BYTES (\d+)", r.stdout).group(1))
+    sizes = re.search(r"LAYER_BYTES (\d+) ARENA (.*)", r.stdout)
+    layer_bytes, arena = int(sizes.group(1)), json.loads(sizes.group(2))
     for name, line in found.items():
-        m = re.match(r"temp (\d+) kernel (\d) loops (\d+) large (.*)", line)
+        m = re.match(
+            r"temp (\d+) kernel (\d) loops (\d+) large (.*) layouts (.*)", line)
         temp[name] = int(m.group(1))
         assert m.group(2) == "1", ("the paged kernel was not traced", line)
         large = json.loads(m.group(4))
         assert large and set(large) <= {"scatter", "fusion:scatter"}, (name, large)
         loops[name] = int(m.group(3))
+        # row-major wherever the compiled program names the arena's shape
+        assert json.loads(m.group(5)) == ["{4,3,2,1,0"], (name, line)
     # the form under test was compiled: loops beside the chunk's scan
     assert loops["live"] > loops["every"], loops
     assert temp["live"] - temp["every"] < layer_bytes // 8, (temp, layer_bytes)
+    if case == "hybrid_head64":
+        assert arena == [2, 8193, 4, 16, 128]
+        assert temp["live"] < layer_bytes // 4, (temp, layer_bytes)

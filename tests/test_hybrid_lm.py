@@ -40,6 +40,7 @@ import pytest
 
 import tfservingcache_tpu.models.generation as generation
 import tfservingcache_tpu.models.hybrid_lm as hybrid
+import tfservingcache_tpu.ops.attention as att
 from tfservingcache_tpu.config import ServingConfig
 from tfservingcache_tpu.models.registry import (
     CacheRow,
@@ -189,8 +190,8 @@ def test_a_gate_denominator_term_is_the_models_and_defaults_to_the_bare_sum():
 
 # -- (b) prefill, then decode through the arena and the lane state ------------
 
-def _prefill(dev, prompt, p_pad=16):
-    model = build("hybrid_lm", MC)
+def _prefill(dev, prompt, p_pad=16, mc=MC):
+    model = build("hybrid_lm", mc)
     ids = np.zeros((1, p_pad), np.int32)
     ids[0, :len(prompt)] = prompt
     tok, pk, pv, last, lane = generation._slot_prefill_jit(
@@ -200,18 +201,21 @@ def _prefill(dev, prompt, p_pad=16):
     return int(tok[0]), pk, pv, np.asarray(last)[0], lane
 
 
-def _paged_setup(dev, prompt, lane=1, pages=24):
+def _paged_setup(dev, prompt, lane=1, pages=24, mc=MC):
     """Prefill ``prompt`` and admit it into lane ``lane`` of a fresh arena and
     a fresh lane-state array -> (cfg, cache with ``lane``, tables, pos, first
-    token, the last prompt position's logits)."""
-    model = build("hybrid_lm", MC)
+    token, the last prompt position's logits). ``MC64`` (2 KV heads of 64)
+    gets the packed arena: one 128-wide row a token for the pair."""
+    model = build("hybrid_lm", mc)
     cfg = dict(static_config(model))
-    tok, pk, pv, last, state = _prefill(dev, prompt)
-    assert pk.shape == pv.shape == (N_ATTN, 1, 2, 16, 16)
-    assert state.shape == (N_CONV, 1, 2, 64)
+    head = mc["d_model"] // mc["n_heads"]
+    tok, pk, pv, last, state = _prefill(dev, prompt, mc=mc)
+    assert pk.shape == pv.shape == (N_ATTN, 1, 2, 16, head)
+    assert state.shape == (N_CONV, 1, 2, mc["d_model"])
     cache = generation.init_paged_cache(cfg, pages, PT, row=model.cache_row)
-    assert cache["k"].shape == (N_ATTN, pages, 2, PT, 16)
-    pps = MC["max_seq"] // PT
+    assert cache["k"].shape == (
+        (N_ATTN, pages, 1, PT, 128) if head == 64 else (N_ATTN, pages, 2, PT, 16))
+    pps = mc["max_seq"] // PT
     tables = np.zeros((LANES, pps), np.int32)
     tables[lane, :5] = 1 + 5 * lane + np.arange(5)       # 40 tokens a lane
     k, v, _ = generation._paged_insert_jit(
@@ -251,6 +255,82 @@ def test_b_prefill_then_paged_decode_matches_the_reference_at_every_position(
     # the lanes nobody read kept the zeros they were built with
     others = np.asarray(cache["lane"])[:, ~active]
     assert not others.any()
+
+
+# the same model with 2 KV heads of 64: its arena packs them into one row
+MC64 = FAMILY.program_config(dict(
+    PUBLISHED, hidden_size=256,
+    assumed={"head_dim": {"value": 64}, "gate_norm_eps": {"value": 1e-6}}))
+
+
+@pytest.fixture
+def interpret_kernel(monkeypatch):
+    generation._paged_decode_chunk_jit.clear_cache()
+    monkeypatch.setattr(att, "PAGED_KERNEL_INTERPRET", True)
+    yield
+    generation._paged_decode_chunk_jit.clear_cache()
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["reference", "kernel"])
+def test_b_head_64_prefill_then_decode_over_the_packed_arena_matches_the_reference(
+        kernel, interpret_kernel):
+    """(b) again at a head of 64: the arena stores the two KV heads in one
+    128-wide row (``init_paged_cache``), the insert and the step's write pack
+    their rows, and 20 decode steps read them back through the gather + einsum
+    reference (it unpacks what it gathered) and through the decode kernel on
+    the packed pages (its interpreter), each 1e-4 from the float32 reference
+    at every position."""
+    tree = _tree(2, MC64)
+    rng = np.random.default_rng(3)
+    prompt = rng.integers(1, MC64["vocab_size"], 11)
+    forced = rng.integers(1, MC64["vocab_size"], 20)
+    want = _reference(tree, np.concatenate([prompt, forced]), MC64)
+    dev = jax.tree_util.tree_map(jnp.asarray, tree)
+    lane = 1
+    cfg, cache, tables, pos, _tok, last = _paged_setup(dev, prompt, lane=lane,
+                                                       mc=MC64)
+    np.testing.assert_allclose(last, want[len(prompt) - 1], atol=1e-4, rtol=0)
+    active = np.arange(LANES) == lane
+    gate = ("paged_attention",
+            *(("kernel", "interpret") if kernel else ("reference", "kernel=False")))
+    before = att.dispatch_tally().get(gate, 0)
+    step = jax.jit(lambda cache, tok, pos: generation._paged_forward_step(
+        dev, tok, cache, tables, pos, cfg, "hybrid_lm", PT, active=active,
+        kernel=kernel))
+    tok = np.zeros((LANES,), np.int32)
+    for j, t in enumerate(forced):
+        tok[lane] = t
+        logits, cache = step(cache, tok, pos)
+        np.testing.assert_allclose(np.asarray(logits)[lane, 0],
+                                   want[len(prompt) + j], atol=1e-4, rtol=0)
+        pos[lane] += 1
+    assert cache["k"].shape[-1] == 128
+    assert att.dispatch_tally().get(gate, 0) == before + N_ATTN
+
+
+def test_b_head_64_decode_chunk_through_the_kernel_emits_the_references_tokens(
+        interpret_kernel):
+    tree = _tree(4, MC64)
+    dev = jax.tree_util.tree_map(jnp.asarray, tree)
+    prompt = np.random.default_rng(5).integers(1, MC64["vocab_size"], 13)
+    lane = 2
+    cfg, cache, tables, pos, first, _ = _paged_setup(dev, prompt, lane=lane,
+                                                     mc=MC64)
+    active = np.arange(LANES) == lane
+    tok = np.zeros((LANES,), np.int32)
+    tok[lane] = first
+    k, _, _, _, _, toks, _, _ = generation._paged_decode_chunk_jit(
+        dev, cache["k"], cache["v"], None, tables, tok, pos, active,
+        jax.random.split(jax.random.PRNGKey(1), 4),
+        np.zeros((LANES,), np.float32), np.zeros((LANES,), np.int32),
+        cache["lane"], cfg_key=tuple(sorted(cfg.items())), family="hybrid_lm",
+        chunk=4, page_tokens=PT, kernel=True)
+    assert k.shape == cache["k"].shape == (N_ATTN, 24, 1, PT, 128)
+    chain = [first]
+    for _ in range(4):
+        ref = FAMILY.logits_many(MC64, tree, [prompt.tolist() + chain], last=1)[0][0]
+        chain.append(int(np.argmax(ref)))
+    assert np.asarray(toks)[lane].tolist() == chain[1:]
 
 
 def test_b_a_state_read_at_the_buckets_end_is_caught():
@@ -402,7 +482,8 @@ def test_d_the_benchmark_configurations_arena_has_a_layer_a_layer_with_pages():
     """LFM2-8B-A1B as the cell runs it: 14 layers, 3 with pages. The arena of
     8192 pages of 16 tokens is 3 x 8193 x 8 x 16 x 64 x 2 sides x 2 B = 0.81
     GB, not the 3.76 GB fourteen layers would take; the lane state is 2.9 MB.
-    (At ISSUE 33's fallback of 10 layers: 2 layers with pages.)"""
+    Its 8 KV heads of 64 are stored two a 128-lane row (PR 34): the same
+    bytes. (At ISSUE 33's fallback of 10 layers: 2 layers with pages.)"""
     with open(os.path.join(ROOT, "benchmark", "configs", "lfm2-8b-a1b.json")) as f:
         config = json.load(f)
     model = build("hybrid_lm", FAMILY.program_config(config))
@@ -415,7 +496,7 @@ def test_d_the_benchmark_configurations_arena_has_a_layer_a_layer_with_pages():
     pages = serving["kv_arena_pages"]
     arena = jax.eval_shape(lambda: generation.init_paged_cache(
         cfg, pages + 1, serving["kv_page_tokens"], row=model.cache_row))
-    assert arena["k"].shape == (3, 8193, 8, 16, 64)
+    assert arena["k"].shape == arena["v"].shape == (3, 8193, 4, 16, 128)
     nbytes = sum(a.size * a.dtype.itemsize for a in arena.values())
     assert nbytes == 3 * 8193 * 8 * 16 * 64 * 2 * 2 and 0.80e9 < nbytes < 0.81e9
     lanes = jax.eval_shape(lambda: generation.init_lane_state(
@@ -601,15 +682,26 @@ ACCEPTED = {
         "norm_topk_prob": True, "qk_norm": True, "tie_embeddings": False,
         "max_seq": 64, "rope_theta": 10000.0, "dtype": "float32"}),
     "latent": ("mla_moe_lm", None),
+    # the shapes the cells run: heads of 128 (Mistral, OLMoE) and an odd
+    # number of KV heads of 64 (SmolLM2): neither arena packs
+    "head128": ("transformer_lm", {
+        "vocab_size": 97, "d_model": 256, "n_layers": 2, "n_heads": 2,
+        "n_kv_heads": 2, "d_ff": 96, "max_seq": 64}),
+    "odd_head64": ("transformer_lm", {
+        "vocab_size": 97, "d_model": 320, "n_layers": 2, "n_heads": 5,
+        "n_kv_heads": 5, "d_ff": 96, "max_seq": 64}),
 }
 
 
 @pytest.mark.parametrize("which", sorted(ACCEPTED))
-def test_g_accepted_families_decode_chunk_is_traced_as_before(which):
+def test_g_accepted_families_decode_chunk_is_traced_as_before(which, monkeypatch):
     """The operand the lane state takes is an empty pytree for a family of one
     kind: its decode chunk has the parent's operands and results (the arena's
     sides donated, nothing beside them), no ``dynamic_update_slice`` a layer
-    for a state, no gate denominator term, and its arena a layer a layer."""
+    for a state, no gate denominator term, and its arena a layer a layer, of
+    the parent's shape: a tile a KV head, ``row.width`` wide (PR 34 packs a
+    two-sided head-64 row with an even number of heads, and none of these);
+    the program is the one traced with the packing taken out."""
     family, config = ACCEPTED[which]
     model = build(family, config)
     cfg = dict(static_config(model))
@@ -617,6 +709,9 @@ def test_g_accepted_families_decode_chunk_is_traced_as_before(which):
     cache = jax.eval_shape(lambda: generation.init_paged_cache(
         cfg, 40, 4, row=model.cache_row))
     assert cache["k"].shape[0] == cfg["n_layers"]
+    row = model.cache_row
+    assert cache["k"].shape == (cfg["n_layers"], 40, row.heads, 4, row.width)
+    assert ("v" in cache) == (row.sides == 2)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     args = (params, cache["k"], cache.get("v"), None, i32(lanes, pps), i32(lanes),
@@ -637,5 +732,11 @@ def test_g_accepted_families_decode_chunk_is_traced_as_before(which):
     sides = [a for a in (cache["k"], cache.get("v")) if a is not None]
     n_out = len(sides) + 3 + (0 if out[6] is None else 1)
     assert len(traced.jaxpr.outvars) == n_out
+    # the parent's program: what is traced when nothing can pack or unpack
+    monkeypatch.setattr(generation, "pack_rows", lambda rows, arena: rows)
+    monkeypatch.setattr(att, "unpack_pages", lambda pages, head_dim: pages)
+    generation._paged_decode_chunk_jit.clear_cache()
+    assert str(jax.make_jaxpr(fn)(*args)) == str(traced)
+    generation._paged_decode_chunk_jit.clear_cache()
     text = str(traced)
     assert "1e-06" not in text

@@ -245,6 +245,220 @@ def test_kernel_reads_its_layer_of_the_whole_arena(kernel, quantized, layer):
     np.testing.assert_allclose(whole, want, atol=1e-6, rtol=1e-6)
 
 
+# -- a head of 64, two KV heads a stored row (PR 34) ---------------------------
+
+HEAD64 = 64
+
+
+def _pack(pages):
+    """``(..., hkv, pt, 64)`` pages as a packed arena stores them,
+    ``(..., hkv // 2, pt, 128)``: row ``t`` of pair ``j`` is ``[row(head 2j, t) |
+    row(head 2j + 1, t)]``, written out by hand (not through ``pack_rows``)."""
+    return np.concatenate([pages[..., 0::2, :, :], pages[..., 1::2, :, :]], -1)
+
+
+def _head64_cfg(hkv, g=1, layers=2, dtype="float32"):
+    return {"n_layers": layers, "n_kv_heads": hkv, "n_heads": hkv * g,
+            "d_model": hkv * g * HEAD64, "dtype": dtype}
+
+
+@pytest.mark.parametrize("layout", ["ragged", "edges"])
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("hkv", [2, 8])
+def test_packed_kernel_matches_reference_interpret(hkv, g, layout):
+    """The decode kernel over a PACKED arena (head 64, two KV heads a 128-lane
+    row) through the interpreter, against the gather+einsum reference on the
+    same pages unpacked: ragged positions; ``_edge_case``'s block boundaries,
+    a lane whose last block is partly dead, inactive lanes (rows of zeros) and
+    an arena poisoned wherever no live lane reads. The reference over the
+    packed arena (it unpacks what it gathered) equals the reference over the
+    unpacked one bit for bit."""
+    pt = 16
+    seed = 11 * hkv + g
+    if layout == "ragged":
+        q, kp, vp, tables, pos = _arena(
+            lanes=5, hq=hkv * g, hkv=hkv, d=HEAD64, pps=12, pt=pt, seed=seed)
+        active = poison = None
+    else:
+        q, kp, vp, tables, pos, active, poison = _edge_case(
+            hkv * g, hkv, HEAD64, pt, seed)
+    q, tables, pos = jnp.asarray(q), jnp.asarray(tables), jnp.asarray(pos)
+    want = np.asarray(paged_decode_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), tables, pos, pt))
+    packed = [jnp.asarray(_pack(kp)), jnp.asarray(_pack(vp))]
+    assert packed[0].shape == (kp.shape[0], hkv // 2, pt, 2 * HEAD64)
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(q, *packed, tables, pos, pt)), want)
+    if poison is not None:
+        bad = jnp.asarray(poison)[:, None, None, None]
+        packed = [jnp.where(bad, np.nan, x) for x in packed]
+    got = np.asarray(paged_decode_attention_kernel(
+        q, *packed, tables, pos,
+        active=None if active is None else jnp.asarray(active),
+        page_tokens=pt, interpret=True))
+    assert got.shape == want.shape == (q.shape[0], hkv * g, 1, HEAD64)
+    if active is not None:
+        assert (got[~active] == 0).all()
+        got, want = got[active], want[active]
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("writer", ["write_rows", "write_rows_live", "insert"])
+@pytest.mark.parametrize("hkv", [2, 8])
+def test_rows_written_into_a_packed_arena_come_back_as_the_unpacked_arenas(
+        hkv, writer):
+    """Write -> read round trip: the rows ``_paged_write_rows`` (every lane's,
+    and a decode chunk's live lanes') and ``_paged_insert_jit`` put into the
+    packed arena ``init_paged_cache`` builds come back from ``paged_gather_kv``
+    and ``_paged_gather_prefix_jit`` bit for bit as those of an arena with a
+    tile a KV head (made by hand: a program learns the form from the array it
+    is handed)."""
+    layers, n_pages, pt, lanes, pps = 2, 40, 8, 6, 4
+    cfg = _head64_cfg(hkv, layers=layers)
+    packed = generation.init_paged_cache(cfg, n_pages, pt)
+    assert packed["k"].shape == (layers, n_pages, hkv // 2, pt, 2 * HEAD64)
+    plain = {side: jnp.zeros((layers, n_pages, hkv, pt, HEAD64), jnp.float32)
+             for side in ("k", "v")}
+    assert sum(a.nbytes for a in packed.values()) == sum(
+        a.nbytes for a in plain.values())
+    rng = np.random.default_rng(hkv)
+    tables = 1 + rng.permutation(lanes * pps).reshape(lanes, pps).astype(np.int32)
+    if writer == "insert":
+        p_pad, lane = 16, 2
+        pk, pv = (jnp.asarray(rng.standard_normal(
+            (layers, 1, hkv, p_pad, HEAD64)), jnp.float32) for _ in range(2))
+        out = {}
+        for name, cache in (("packed", packed), ("plain", plain)):
+            k, v, _ = generation._paged_insert_jit(
+                cache["k"], cache["v"], None, pk, pv, tables[lane], np.int32(3),
+                page_tokens=pt)
+            out[name] = {"k": k, "v": v}
+    else:
+        t_q = 3
+        pos = rng.integers(0, pps * pt - t_q, lanes)
+        positions = pos[:, None] + np.arange(t_q)[None]
+        pages = jnp.asarray(np.take_along_axis(tables, positions // pt, axis=1))
+        off = jnp.asarray(positions % pt)
+        rows = [jnp.asarray(rng.standard_normal((lanes, t_q, hkv, HEAD64)),
+                            jnp.float32) for _ in range(2)]
+        active = jnp.asarray([1, 0, 1, 1, 0, 1], bool)
+        live = generation._live_lanes(active) if writer == "write_rows_live" else None
+        out = {name: generation._paged_write_rows(cache, 1, pages, off, *rows, live)
+               for name, cache in (("packed", packed), ("plain", plain))}
+    assert out["packed"]["k"].shape == packed["k"].shape
+    tables = jnp.asarray(tables)
+    for side in ("k", "v"):
+        assert np.asarray(out["plain"][side]).any()
+        for layer in range(layers):
+            np.testing.assert_array_equal(
+                np.asarray(att.paged_gather_kv(out["packed"][side], tables, pt,
+                                               layer, head_dim=HEAD64)),
+                np.asarray(att.paged_gather_kv(out["plain"][side], tables, pt,
+                                               layer, head_dim=HEAD64)))
+    some = jnp.asarray(np.asarray(tables)[2, :3])
+    for a, b in zip(
+            generation._paged_gather_prefix_jit(
+                out["packed"]["k"], out["packed"]["v"], None, some, width=HEAD64),
+            generation._paged_gather_prefix_jit(
+                out["plain"]["k"], out["plain"]["v"], None, some, width=HEAD64)):
+        assert a.shape == (layers, 1, hkv, 3 * pt, HEAD64)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("arena, shape", [
+    ("head64_even", (2, 9, 4, 8, 128)),          # packed
+    ("head64_odd", (2, 9, 5, 8, 64)),            # SmolLM2's 5 KV heads
+    ("head64_int8", (2, 9, 8, 8, 64)),
+    ("head64_mesh", (2, 9, 8, 8, 64)),
+    ("head128", (2, 9, 8, 8, 128)),
+    ("head32", (2, 9, 8, 8, 32)),
+    ("latent", (2, 9, 1, 8, 384)),
+])
+def test_only_a_two_sided_head_64_row_with_even_heads_is_stored_packed(
+        arena, shape, monkeypatch):
+    """``init_paged_cache`` decides, from the row's shape alone; every other
+    arena has the shape it had. The gate then records the packed arena's decode
+    as ``kernel`` (the interpreter here; ``pallas`` on a chip), its verify pass
+    as ``reference`` by name, and the others as they were recorded."""
+    from tfservingcache_tpu.models.registry import CacheRow
+
+    kw = {}
+    if arena == "latent":
+        cfg, kw["row"] = {"n_layers": 2, "dtype": "float32"}, CacheRow(1, 1, 384, 256)
+    else:
+        hkv = 5 if arena == "head64_odd" else 8
+        head = {"head128": 128, "head32": 32}.get(arena, HEAD64)
+        cfg = {"n_layers": 2, "n_kv_heads": hkv, "n_heads": hkv,
+               "d_model": hkv * head, "dtype": "float32"}
+    if arena == "head64_int8":
+        kw["arena_dtype"] = "int8"
+    if arena == "head64_mesh":
+        from jax.sharding import Mesh
+
+        kw["mesh"] = Mesh(np.asarray(jax.devices()[:1]), ("model",))
+    cache = generation.init_paged_cache(cfg, 9, 8, **kw)
+    assert cache["k"].shape == shape
+    assert ("v" in cache) == (arena != "latent")
+    if arena == "head64_int8":
+        assert cache["k_scale"].shape == shape[:-1]
+    if arena == "latent":
+        return
+    monkeypatch.setattr(att, "PAGED_KERNEL_INTERPRET", True)
+    hq, head = cfg["n_heads"], cfg["d_model"] // cfg["n_heads"]
+    tables = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
+    pos = jnp.asarray([5, 17], jnp.int32)
+    scales = [cache[s] for s in ("k_scale", "v_scale") if s in cache]
+    packed = arena == "head64_even"
+
+    def traced(gate, fn, t_q):
+        q = jnp.ones((2, hq, t_q, head), jnp.float32)
+        before = att.dispatch_tally()
+        out = fn(q, cache["k"], cache["v"], tables, pos, 8, *scales)
+        assert out.shape == q.shape
+        after = att.dispatch_tally()
+        return [key[1:] for key in after
+                if key[0] == gate and after[key] != before.get(key, 0)]
+
+    assert traced("paged_attention", paged_attention, 1) == [
+        ("kernel", "interpret")]
+    assert traced("paged_attention_verify", att.paged_attention_verify, 3) == [
+        ("reference", "two kv heads a stored row: the decode kernel alone "
+                      "reads it") if packed else ("kernel", "interpret")]
+    # on this backend, with the interpreter off, every shape is refused alike
+    monkeypatch.setattr(att, "PAGED_KERNEL_INTERPRET", False)
+    assert traced("paged_attention", paged_attention, 1) == [
+        ("reference", "backend=cpu")]
+
+
+def test_the_gate_takes_a_packed_arena_and_refuses_a_64_wide_row_by_name(
+        monkeypatch):
+    """What the gate answers on a TPU, asked here by patching the backend's
+    name (nothing is run): a packed arena's decode is the Pallas kernel; a
+    head of 64 stored a tile a KV head (an odd number of heads, int8) is
+    refused with the text it always had; a head of 128 is taken as before."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q64 = jnp.ones((2, 8, 1, 64), jnp.bfloat16)
+    abstract = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
+    gate = lambda q, pages, **kw: att._paged_kernel_traced(  # noqa: E731
+        "paged_attention", True, q, pages, head_multiple=128, **kw)
+    before = att.dispatch_tally()
+    assert gate(q64, abstract(3, 9, 4, 16, 128), reads_packed=True)
+    assert not gate(q64, abstract(3, 9, 4, 16, 128))
+    assert not gate(q64, abstract(3, 9, 8, 16, 64), reads_packed=True)
+    assert not gate(jnp.ones((2, 5, 1, 64)), abstract(3, 9, 5, 16, 64),
+                    reads_packed=True)
+    assert gate(jnp.ones((2, 8, 1, 128)), abstract(3, 9, 8, 16, 128),
+                reads_packed=True)
+    after = att.dispatch_tally()
+    moved = {key[1:]: after[key] - before.get(key, 0) for key in after
+             if key[0] == "paged_attention" and after[key] != before.get(key, 0)}
+    assert moved == {
+        ("kernel", "pallas"): 2,
+        ("reference",
+         "two kv heads a stored row: the decode kernel alone reads it"): 1,
+        ("reference", "head_dim=64 not a multiple of 128"): 2}
+
+
 # -- engine-level greedy parity ----------------------------------------------
 
 @pytest.fixture
@@ -497,6 +711,65 @@ def test_paged_verify_shapes_on_tpu(g, d, quantized):
     assert np.isfinite(err) and err < 3e-2, (
         f"verify kernel diverges at g={g} d={d} quantized={quantized}: {err}"
     )
+
+
+@pytest.mark.skipif(
+    jax.default_backend() != "tpu",
+    reason="needs real TPU (conftest forces CPU; run via tools/tpu_kernel_check.py)",
+)
+@pytest.mark.parametrize("live", [1, 6, 16, 32])
+def test_paged_decode_packed_on_tpu(live):
+    """PR 34 on the chip: the decode kernel over a PACKED arena at the LFM2
+    cell's shape (``lfm2-longgen-steady``: 32 query heads over 8 KV heads of
+    64, stored as 4 pairs a 128-lane row, 16-token pages, 256 table slots a
+    lane, an arena of 8193 pages x 3 layers, read at its last layer) with
+    ``live`` of 32 lanes active at ragged lengths 300-1500, the rest retired.
+    Mosaic-compiles, matches the gather + einsum reference on the same arena
+    (which gathers every table slot of every lane and unpacks), and both
+    times are PRINTED for PERF.md, not asserted."""
+    from tfservingcache_tpu.utils.benchtime import chained_device_time
+
+    lanes, hq, hkv, d, pt, pps, layers, n_pages = 32, 32, 8, 64, 16, 256, 3, 8193
+    rng = np.random.default_rng(live)
+    pos = rng.integers(300, 1500, lanes).astype(np.int32)
+    active = np.arange(lanes) < live
+    tables = np.zeros((lanes, pps), np.int32)
+    nxt = 1
+    for lane in np.flatnonzero(active):
+        n = int(pos[lane]) // pt + 1
+        tables[lane, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    cfg = _head64_cfg(hkv, hq // hkv, layers=layers, dtype="bfloat16")
+    keys = jax.random.split(jax.random.PRNGKey(live), 3)
+    arena = generation.init_paged_cache(cfg, n_pages, pt)
+    assert arena["k"].shape == (layers, n_pages, hkv // 2, pt, 2 * d)
+    kp, vp = (jax.random.normal(key, arena["k"].shape, jnp.bfloat16)
+              for key in keys[:2])
+    q = jax.random.normal(keys[2], (lanes, hq, 1, d), jnp.bfloat16)
+    tables, pos, act = jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(active)
+    layer = layers - 1
+
+    out = paged_decode_attention_kernel(q, kp, vp, tables, pos, active=act,
+                                        page_tokens=pt, layer=layer)
+    ref = paged_decode_attention(q, kp, vp, tables, pos, pt, layer)
+    assert out.shape == ref.shape == (lanes, hq, 1, d)
+    assert not np.asarray(out)[~active].any()
+    err = float(jnp.max(jnp.abs(out - ref)[act]))
+    assert err < 3e-2, f"packed paged kernel diverges: max abs err {err}"
+    args = (q, kp, vp, tables, pos, act)
+    t_kern = chained_device_time(
+        lambda q, kp, vp, tables, pos, act: paged_decode_attention_kernel(
+            q, kp, vp, tables, pos, active=act, page_tokens=pt, layer=layer),
+        args)
+    t_ref = chained_device_time(
+        lambda q, kp, vp, tables, pos, act: paged_decode_attention(
+            q, kp, vp, tables, pos, pt, layer), args)
+    live_tokens = int((np.asarray(pos) + 1)[active].sum())
+    kv_bytes = 2 * live_tokens * hkv * d * 2
+    print(f"\n[paged_decode packed] head 64, 8 KV heads as 4 pairs, {live} of "
+          f"{lanes} lanes live ({live_tokens} tokens): kernel {t_kern*1e3:.4f} ms "
+          f"({kv_bytes/t_kern/1e9:.0f} GB/s of live KV), gather+einsum "
+          f"{t_ref*1e3:.3f} ms, max_abs_err {err:.4f}", flush=True)
 
 
 def _random_bf16_params(family, cfg):

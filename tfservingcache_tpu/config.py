@@ -136,11 +136,14 @@ class ServingConfig:
     # compute online-softmax attention straight from the page arena — one
     # pass over the KV bytes instead of paged_gather_kv's materialized
     # pages[tables] round-trip. true (default) uses the kernel on one TPU
-    # chip when shapes qualify (head_dim % 128 == 0 since PR 24: the kernel
-    # copies pages out of HBM in whole 128-lane tiles; heads divisible by
-    # kv heads) and falls back to the gather+einsum reference everywhere
-    # else (CPU, a mesh, head 64); false forces the reference path
-    # unconditionally, the lever of the parity tests.
+    # chip when shapes qualify (the arena's stored row a multiple of 128
+    # lanes, since PR 24: the kernel copies pages out of HBM in whole
+    # 128-lane tiles. A head of 128 is one; so, since PR 34, is a head of
+    # 64 with an even number of KV heads, whose arena stores two heads a
+    # row; heads divisible by kv heads) and falls back to the gather+einsum
+    # reference everywhere else (CPU, a mesh, a head of 64 with an odd
+    # number of KV heads or in an int8 arena); false forces the reference
+    # path unconditionally, the lever of the parity tests.
     kv_paged_kernel: bool = True
     # KV page arena element type. "" (default) stores pages in the model's
     # own dtype. "int8" quantizes pages symmetrically per (page, kv_head,

@@ -59,6 +59,7 @@ from tfservingcache_tpu.models.transformer_lm import (
     _qkv,
     _rmsnorm,
 )
+from tfservingcache_tpu.ops.attention import pack_rows, unpack_pages
 
 # The slot-decode jits donate their K/V buffers (in-place update on TPU);
 # CPU/interpreter backends cannot honor donation and warn on EVERY dispatch
@@ -493,11 +494,31 @@ def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
     is ONE side, ``k``, of one shared row a token; there is no ``v``. The
     arena's first axis counts the model's layers that keep rows: ``cfg``
     carries ``layer_state`` where some layers keep a lane state instead
-    (``static_config``), and those have no layer here."""
+    (``static_config``), and those have no layer here.
+
+    **A K/V row 64 wide with an even number of heads is stored PACKED, two KV
+    heads a 128-lane row**: ``(layers, n_pages, heads // 2, page_tokens,
+    128)``, row ``[l, p, j, t] = [k(head 2j, t) | k(head 2j + 1, t)]``. The
+    same bytes, pages and block tables; the shape a head-128 arena has, which
+    the TPU keeps row-major and no program converts, and whose pages the
+    paged decode kernel can copy out of HBM (a 64-wide row fills half a
+    128-lane tile: the device stores such an arena with the PAGES minor, every
+    program that addresses rows converts all of it, in and out, and the
+    kernel's gate refuses it: PR 33's traces, PERF.md). Decided here, from
+    the row's shape alone. An odd number of heads, an int8 arena (its scales
+    are a row a head), a one-sided arena, a mesh (the arena is sharded over
+    its KV heads there and the kernel is off) and every other width keep
+    ``(heads, width)``."""
     row = row or _cache_row(cfg)
     dtype = jnp.dtype(cfg["dtype"])
+    heads, width = row.heads, row.width
+    if (row.sides == 2 and width == 64 and heads % 2 == 0
+            and arena_dtype != "int8" and mesh is None):
+        # THE place that decides a packed arena; every program learns it from
+        # the array it is handed (``ops.attention.pack_rows``)
+        heads, width = heads // 2, 128
     # a layer of the arena a model layer that HAS pages (``_layer_slots``)
-    shape = (_row_layers(cfg), n_pages, row.heads, page_tokens, row.width)
+    shape = (_row_layers(cfg), n_pages, heads, page_tokens, width)
     if row.sides == 1:
         if arena_dtype == "int8":
             raise ValueError("a latent (one-sided) arena has no int8 form")
@@ -575,7 +596,9 @@ def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows, live=None):
     unrolled).
 
     The kv head is an INDEX of the scatter too, not a slice of its window:
-    the update is one ``hd`` row a (lane, position, head). With the heads in
+    the update is one ``hd`` row a (lane, position, head), or, in a packed
+    arena (``init_paged_cache``), one 128-wide row a (lane, position, head
+    pair): half the rows, the same scatter. With the heads in
     the window (``.at[li, pages, :, off, :]``) the TPU compiler gives the
     scatter a layout of its own, heads next to ``hd``, and converts the WHOLE
     arena into it and back around every layer's write, because the paged
@@ -603,6 +626,10 @@ def _paged_write_rows(cache, li: int, pages, off, k_rows, v_rows, live=None):
     ``v_rows`` is None. Returns the updated cache."""
 
     def write(cache, pages, off, k_rows, v_rows):
+        # as the arena stores them: a packed arena's row is a PAIR of heads
+        k_rows = pack_rows(k_rows, cache["k"])
+        if v_rows is not None:
+            v_rows = pack_rows(v_rows, cache["v"])
         heads = jnp.arange(k_rows.shape[2])[None, None, :]
         at = (li, pages[:, :, None], heads, off[:, :, None])     # (S, T, n_kv)
         if v_rows is None:
@@ -880,8 +907,8 @@ def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
         layers = jnp.arange(arena_k.shape[0])[:, None]
         return (arena_k.at[layers, pages[None, :], 0, offs[None, :]].set(
             pk[:, 0, 0].astype(arena_k.dtype)), None, None)
-    kv = pk[:, 0].transpose(2, 0, 1, 3)
-    vv = pv[:, 0].transpose(2, 0, 1, 3)
+    kv = pack_rows(pk[:, 0].transpose(2, 0, 1, 3), arena_k)
+    vv = pack_rows(pv[:, 0].transpose(2, 0, 1, 3), arena_v)
     if scales is not None:
         kv, k_s = _quantize_kv_rows(kv)
         vv, v_s = _quantize_kv_rows(vv)
@@ -894,18 +921,20 @@ def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
     return arena_k, arena_v, scales
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("width",))
 @jax.named_scope("kv_read")
-def _paged_gather_prefix_jit(arena_k, arena_v, scales, pages):
+def _paged_gather_prefix_jit(arena_k, arena_v, scales, pages, *, width):
     """Gather ``n`` full shared-prefix pages into the dense
     (layers, 1, n_kv, n*page_tokens, hd) layout `_slot_prefill_from_cache_jit`
     expects as its cached prefix. Read-only on the arena (no donation — the
     shared pages stay live for every other referencing lane). One compile
     per distinct page count, bounded by pages_per_slot. An int8 arena
     (``scales`` not None) is dequantized here: the suffix prefill runs on
-    dense f32 rows either way."""
+    dense f32 rows either way. ``width`` is the model's row (``CacheRow.width``):
+    a packed arena's pages are unpacked to it after the gather."""
     # arena: (layers, n_pages, n_kv, page_tokens, hd); pages: (n,) i32
-    k, v = _each_side(lambda a: a[:, pages], (arena_k, arena_v))  # (L, n, n_kv, pt, hd)
+    k, v = _each_side(lambda a: unpack_pages(a[:, pages], width),
+                      (arena_k, arena_v))           # (L, n, n_kv, pt, hd)
     if scales is not None:
         k = k.astype(jnp.float32) * scales["k"][:, pages][..., None]
         v = v.astype(jnp.float32) * scales["v"][:, pages][..., None]
@@ -977,7 +1006,7 @@ def _pages_import_jit(arena_k, arena_v, scales, pages, pk, pv, pscales):
 )
 def _paged_decode_chunk_jit(
     params,
-    arena_k,             # (layers, n_pages, n_kv, page_tokens, hd) — donated
+    arena_k,             # (layers, n_pages, n_kv, page_tokens, hd), or packed (init_paged_cache) — donated
     arena_v,
     scales,              # {"k","v"} int8 per-row scale buffers | None — donated
     tables,              # (S, pages_per_slot) i32 block tables

@@ -539,14 +539,53 @@ def _as_arena(pages: jax.Array, ndim: int = 5) -> jax.Array:
     ``layer``, and index it where it lies: a caller never slices a layer
     out, which XLA would materialise (268 MB a layer in the chat cells).
     One layer's pages alone, one dimension fewer than ``ndim`` (4 for the
-    scales), are a one-layer arena."""
+    scales), are a one-layer arena.
+
+    A K/V arena of head 64 with an even number of KV heads is stored PACKED,
+    two KV heads a 128-lane row: ``(layers, n_pages, Hkv // 2, page_tokens,
+    128)``, row ``[l, p, j, t] = [row(head 2j, t) | row(head 2j + 1, t)]``
+    (``generation.init_paged_cache`` decides, once, from the row's shape).
+    Every program here learns it from the array it is handed: the stored row
+    is twice the head it computes with (``_packed``)."""
     return pages if pages.ndim == ndim else pages[None]
+
+
+def _packed(pages: jax.Array, head_dim: int) -> bool:
+    """Does this arena store two KV heads of ``head_dim`` a row?"""
+    return pages.shape[-1] == 2 * head_dim
+
+
+def _arena_kv_heads(pages: jax.Array, head_dim: int) -> int:
+    """The model's KV heads, whatever the arena stores a row."""
+    return pages.shape[-3] * (pages.shape[-1] // head_dim)
+
+
+def pack_rows(rows: jax.Array, arena: jax.Array) -> jax.Array:
+    """New rows ``(..., Hkv, D)`` as ``arena`` stores them: ``(..., Hkv // 2,
+    2 D)`` for a packed arena (a free reshape: heads ``2j`` and ``2j + 1``
+    are neighbours), the rows themselves for every other."""
+    if not _packed(arena, rows.shape[-1]):
+        return rows
+    *lead, hkv, d = rows.shape
+    return rows.reshape(*lead, hkv // 2, 2 * d)
+
+
+def unpack_pages(pages: jax.Array, head_dim: int) -> jax.Array:
+    """Pages taken out of an arena, ``(..., H, page_tokens, W)``, with a tile
+    a KV head: ``(..., Hkv, page_tokens, D)``. The inverse of ``pack_rows``
+    bit for bit; the identity where ``W`` is the head already."""
+    if not _packed(pages, head_dim):
+        return pages
+    *lead, h, pt, _ = pages.shape
+    return jnp.moveaxis(
+        pages.reshape(*lead, h, pt, 2, head_dim), -2, -3
+    ).reshape(*lead, 2 * h, pt, head_dim)
 
 
 @jax.named_scope("kv_read")
 def paged_gather_kv(
     pages: jax.Array, tables: jax.Array, page_tokens: int, layer: int = 0,
-    scale: jax.Array | None = None,
+    scale: jax.Array | None = None, head_dim: int | None = None,
 ) -> jax.Array:
     """Assemble each lane's logical K or V row from the shared page arena.
 
@@ -564,7 +603,10 @@ def paged_gather_kv(
     (``scale``: its per-(page, head, token) f32 scales, the arena's shape
     without D) is dequantized to f32 rows AFTER the gather, the lanes' pages
     only: the REFERENCE dequant — the Pallas paged kernels apply the same
-    scales in VMEM to the block they just fetched.
+    scales in VMEM to the block they just fetched. A packed arena (two KV
+    heads a stored row, ``_as_arena``) is unpacked after the gather too, the
+    lanes' pages only, to the head ``head_dim`` the caller computes with
+    (default: the stored row is the head).
 
     SILENT-JUNK HAZARD (documented + checked, ISSUE 14): a table entry of
     0 is the trash page — last-writer junk from every parked lane. Junk is
@@ -577,11 +619,13 @@ def paged_gather_kv(
     (model_runtime._check_trash_unreachable)."""
     s_lanes, pps = tables.shape
     pages = _as_arena(pages)
-    _, _, hkv, pt, d = pages.shape
     gathered = pages[layer, tables]                # (S, PPS, Hkv, pt, D)
     if scale is not None:
         gathered = dequantize_pages(
             gathered, _as_arena(scale, 4)[layer, tables])
+    if head_dim is not None:
+        gathered = unpack_pages(gathered, head_dim)
+    _, _, hkv, pt, d = gathered.shape
     return gathered.transpose(0, 2, 1, 3, 4).reshape(
         s_lanes, hkv, pps * pt, d
     )
@@ -617,12 +661,12 @@ def paged_decode_attention(
     unreserved table entries and a lane's own not-yet-written positions —
     sit strictly above ``pos`` and are masked before the softmax."""
     s_lanes, hq, _, d = q.shape
-    hkv = k_pages.shape[-3]
+    hkv = _arena_kv_heads(k_pages, d)
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     g = hq // hkv
-    kc = paged_gather_kv(k_pages, tables, page_tokens, layer, k_scale)
-    vc = paged_gather_kv(v_pages, tables, page_tokens, layer, v_scale)
+    kc = paged_gather_kv(k_pages, tables, page_tokens, layer, k_scale, d)
+    vc = paged_gather_kv(v_pages, tables, page_tokens, layer, v_scale, d)
     qg = q.reshape(s_lanes, hkv, g, 1, d)                # kc: (S, Hkv, L, D)
     s = jnp.einsum(
         "bkgqd,bkld->bkgql", qg, kc, preferred_element_type=jnp.float32
@@ -668,12 +712,12 @@ def paged_verify_attention(
     ``pos..pos+T-1``; rows above the eventually-accepted prefix are junk a
     later round overwrites — same discipline as the solo verify chunk."""
     s_lanes, hq, t, d = q.shape
-    hkv = k_pages.shape[-3]
+    hkv = _arena_kv_heads(k_pages, d)
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     g = hq // hkv
-    kc = paged_gather_kv(k_pages, tables, page_tokens, layer, k_scale)
-    vc = paged_gather_kv(v_pages, tables, page_tokens, layer, v_scale)
+    kc = paged_gather_kv(k_pages, tables, page_tokens, layer, k_scale, d)
+    vc = paged_gather_kv(v_pages, tables, page_tokens, layer, v_scale, d)
     qg = q.reshape(s_lanes, hkv, g, t, d)                # kc: (S, Hkv, L, D)
     s = jnp.einsum(
         "bkgqd,bkld->bkgql", qg, kc, preferred_element_type=jnp.float32
@@ -904,27 +948,47 @@ def paged_decode_attention_kernel(
 
     Tables/pos/active are TRACED data (SMEM), same discipline as the
     reference path: page recycling/admission churn never mints a new
-    program."""
+    program.
+
+    A PACKED arena (two KV heads of 64 a 128-lane row, ``_as_arena``) runs
+    the same kernel on half the heads, twice the group and a 128-wide row:
+    pair ``j``'s queries go in padded with zeros, rows ``0..g-1`` =
+    ``[q(head 2j) | 0]`` and rows ``g..2g-1`` = ``[0 | q(head 2j + 1)]``, so a
+    row's product with the stored ``[k(2j) | k(2j + 1)]`` over 128 lanes IS
+    its own head's score (a zero lane adds an exact zero) and every row keeps
+    its own softmax; the value product gives ``[out(2j) | out(2j + 1)]`` for
+    every row, of which each keeps its own head's half. Twice the MXU work of
+    a memory-bound product and not a byte more from HBM: the page copies are
+    whole 128-lane tiles, which a 64-wide page is not."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    s_lanes, hq, _, d = q.shape
+    s_lanes, hq, _, head = q.shape
     k_pages, v_pages = _as_arena(k_pages), _as_arena(v_pages)
-    _, _, hkv, pt, _ = k_pages.shape
-    if hq % hkv:
-        raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
+    _, _, hkv, pt, d = k_pages.shape                # as STORED
+    packed = _packed(k_pages, head)
+    kv_heads = _arena_kv_heads(k_pages, head)
+    if hq % kv_heads:
+        raise ValueError(f"q heads {hq} not a multiple of kv heads {kv_heads}")
     if pt != page_tokens:
         raise ValueError(f"arena page_tokens {pt} != {page_tokens}")
     if d % 128 and not interpret:
         # Mosaic: "Slice shape along dimension 3 must be aligned to tiling
         # (128)" — a manual copy cannot take a narrower page out of HBM
         raise ValueError(f"head_dim {d} not a multiple of 128")
-    g = hq // hkv
-    sm_scale = 1.0 / math.sqrt(d)
+    g = hq // hkv                                   # packed: both heads' rows
+    sm_scale = 1.0 / math.sqrt(head)
     quantized = k_scale is not None
     block_pages = max(1, PAGED_BLOCK_TOKENS // page_tokens)
 
-    qg = q.reshape(s_lanes, hkv, g, d)
+    if packed:
+        # (S, pair, half, g/2, 1, head) x (half, 1, half', 1): a row's own
+        # half holds its query, the other half zeros
+        own = jnp.eye(2, dtype=q.dtype)[:, None, :, None]
+        qg = (q.reshape(s_lanes, hkv, 2, g // 2, 1, head) * own).reshape(
+            s_lanes, hkv, g, d)
+    else:
+        qg = q.reshape(s_lanes, hkv, g, d)
     tables = tables.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
     if active is None:
@@ -975,7 +1039,11 @@ def paged_decode_attention_kernel(
             dimension_semantics=("parallel",)
         ),
     )(tables, pos, active, *operands)
-    return out.reshape(s_lanes, hq, 1, d)
+    if packed:
+        # row (pair, half, gi) keeps columns [half * head, (half + 1) * head)
+        out = out.reshape(s_lanes, hkv, 2, g // 2, 2, head)
+        out = jnp.stack([out[:, :, 0, :, 0], out[:, :, 1, :, 1]], axis=2)
+    return out.reshape(s_lanes, hq, 1, head)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # static-bounded: causal, partitioned -- boolean domains (two programs max each)
@@ -1013,16 +1081,23 @@ PAGED_KERNEL_INTERPRET = False
 
 
 def _paged_kernel_traced(gate: str, kernel: bool, q: jax.Array,
-                         k_pages: jax.Array, head_multiple: int = 64) -> bool:
+                         k_pages: jax.Array, head_multiple: int = 64,
+                         reads_packed: bool = False) -> bool:
     """The paged gates' shared decision, recorded: True = trace the Pallas
-    kernel, False = trace the gather+einsum reference."""
+    kernel, False = trace the gather+einsum reference. What a kernel copies
+    out of HBM is the STORED row, so that is the width the gate holds to
+    ``head_multiple``: the head itself, or 128 for a packed arena
+    (``_as_arena``), which only a kernel that ``reads_packed`` takes."""
+    head = q.shape[-1]
     if not kernel:
         why = "kernel=False"
+    elif _packed(k_pages, head) and not reads_packed:
+        why = "two kv heads a stored row: the decode kernel alone reads it"
     elif PAGED_KERNEL_INTERPRET:
         why = None
     else:
-        why = _kernel_refusal(q.shape[-1], q.shape[1], k_pages.shape[-3],
-                              head_multiple)
+        why = _kernel_refusal(k_pages.shape[-1], q.shape[1],
+                              _arena_kv_heads(k_pages, head), head_multiple)
     shapes = (q.shape, k_pages.shape, (str(k_pages.dtype),))
     if why is None:
         _record_dispatch(
@@ -1050,10 +1125,12 @@ def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL
     """Paged decode dispatch over the arena ``(layers, n_pages, Hkv,
     page_tokens, D)`` at the static ``layer`` (``_as_arena``), mirroring
     ``attention``'s gate: the fused Pallas kernel on the TPU backend when
-    shapes qualify (head_dim a
-    multiple of 128: the kernel copies whole pages out of the arena itself,
-    and Mosaic slices an HBM operand only in whole 128-lane tiles; GQA
-    divisibility), the gather+einsum reference everywhere else.
+    shapes qualify (the STORED row a multiple of 128: the kernel copies whole
+    pages out of the arena itself, and Mosaic slices an HBM operand only in
+    whole 128-lane tiles. That is a head of 128, or a head of 64 whose arena
+    is packed, two KV heads a row, ``_as_arena``; a head of 64 in an arena
+    that could not pack (an odd number of KV heads, int8) is refused as
+    before. GQA divisibility), the gather+einsum reference everywhere else.
     ``kernel=False`` (serving.kv_paged_kernel, and every mesh runtime)
     forces the reference path unconditionally. On a TPU with ``kernel=True``
     and qualifying shapes there is no quiet way out: the kernel is traced,
@@ -1065,7 +1142,7 @@ def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL
     work for the others and returns zeros there, the reference computes them
     like any lane. The branch taken is recorded (``dispatch_tally``)."""
     if _paged_kernel_traced("paged_attention", kernel, q, k_pages,
-                            head_multiple=128):
+                            head_multiple=128, reads_packed=True):
         return paged_decode_attention_kernel(
             q, k_pages, v_pages, tables, pos, k_scale, v_scale, active,
             page_tokens=page_tokens, interpret=PAGED_KERNEL_INTERPRET,

@@ -2492,6 +2492,7 @@ class TPUModelRuntime(BaseRuntime):
         import jax
 
         from tfservingcache_tpu.models.generation import (
+            _cache_row,
             _paged_gather_prefix_jit,
             _sample_logits_jit,
             _slot_prefill_from_cache_jit,
@@ -2519,7 +2520,8 @@ class TPUModelRuntime(BaseRuntime):
         cfg_key = static_config(loaded.model_def)
         covered = plan.covered
         ck, cv = _paged_gather_prefix_jit(
-            state.k, state.v, state.scales, np.asarray(plan.pages, np.int32)
+            state.k, state.v, state.scales, np.asarray(plan.pages, np.int32),
+            width=_cache_row(dict(cfg_key)).width,
         )
         suffix_len = p - covered
         s_pad = next_bucket(suffix_len)
@@ -2747,6 +2749,7 @@ class TPUModelRuntime(BaseRuntime):
         import jax
 
         from tfservingcache_tpu.models.generation import (
+            _cache_row,
             _paged_gather_prefix_jit,
             _pages_import_jit,
             _slot_prefill_from_cache_jit,
@@ -2773,7 +2776,8 @@ class TPUModelRuntime(BaseRuntime):
             state.k, state.v, state.scales, pages, pk_pg, pv_pg, pscales
         )
         ck, cv = _paged_gather_prefix_jit(
-            state.k, state.v, state.scales, pages
+            state.k, state.v, state.scales, pages,
+            width=_cache_row(dict(cfg_key)).width,
         )
         suffix_len = p - covered
         s_pad = next_bucket(suffix_len)

@@ -47,9 +47,12 @@ def main() -> int:
     # and one of the OLMoE cell's shape at 2 live lanes of 32 against 32 of 32,
     # as the tree writes its KV rows and with every lane's rows written (PR 32:
     # the write follows the live lanes; at 2 lanes at least 0.3 ms a step less,
-    # at 32 within 6 %). `layer/kv_write` a step of a chat cell comes from a
-    # kept capture of a traced benchmark run read by tools/trace_scopes.py
-    # (PERF.md section 5 has parent beside change).
+    # at 32 within 6 %), and (PR 34) the decode kernel over a PACKED arena at
+    # the LFM2 cell's shape (8 KV heads of 64 stored as 4 pairs a 128-lane
+    # row) at 1 / 6 / 16 / 32 live lanes of 32 against the gather + einsum
+    # reference: `-k packed_on_tpu`, ~1 min. `layer/kv_write` a step of a chat
+    # cell comes from a kept capture of a traced benchmark run read by
+    # tools/trace_scopes.py (PERF.md section 5 has parent beside change).
     # test_olmoe.py carries the grouped expert kernel's rows at
     # OLMoE-1B-7B's widths: the grouped product with 25 / 64 of 64 experts
     # hit by 1, 4 and 48 rows each and with 63 experts empty, and the whole
