@@ -677,7 +677,7 @@ def test_sixteen_field_dump_still_renders(tmp_path, capsys):
     assert mod.main([path]) == 0
     out = capsys.readouterr().out
     assert "step=    1.50ms chunk=  8" in out          # the 16-field row
-    assert "(prefill=0.00 chunk=2.00 emit=0.25 self=0.25)" in out
+    assert "(prefill=0.00 chunk=2.00 launch=0.00 emit=0.25 self=0.25)" in out
 
 
 def test_record_with_split_fields_under_50us():
@@ -693,3 +693,60 @@ def test_record_with_split_fields_under_50us():
                       prefill_ms=0.31234, chunk_ms=0.54321, emit_ms=0.01234)
         per_rec.append((time.perf_counter() - t0) / 1000)
     assert statistics.median(per_rec) < 50e-6, per_rec
+
+
+# -- the chunk's launch path (ISSUE 35) -----------------------------------------
+
+def test_launch_ms_is_the_last_ring_field():
+    """Appended, never inserted: the 23 older names keep their positions."""
+    assert STEP_FIELDS[-1] == "launch_ms" and len(STEP_FIELDS) == 24
+    assert STEP_FIELDS[16:19] == ("prefill_ms", "chunk_ms", "emit_ms")
+    assert STEP_FIELDS[19:23] == ("experts_hit", "expert_rows_max",
+                                  "expert_rows_local", "write_lanes")
+    fr = FlightRecorder()
+    fr.record("m@1", "continuous", step_ms=9.0, chunk=8, active=4, admitted=0,
+              retired=0, chunk_ms=5.0, launch_ms=1.23456)
+    step = fr.snapshot()["models"]["m@1"]["steps"][0]
+    assert list(step) == list(STEP_FIELDS) and step["launch_ms"] == 1.2346
+    # a caller that does not know the field (a spec round, a boundary without
+    # a chunk) records 0.0
+    fr.record("m@1", "continuous", step_ms=9.0, chunk=0, active=0, admitted=1,
+              retired=0)
+    assert fr.snapshot()["models"]["m@1"]["steps"][1]["launch_ms"] == 0.0
+
+
+@pytest.mark.parametrize("width", [19, 23])
+def test_a_dump_of_an_older_ring_still_renders(width, tmp_path, capsys):
+    """Dumps written before ``launch_ms`` (23 fields) and before the expert
+    fields (19) go through the zip fallback; the tool prints the split without
+    ``launch=``, and with it for a row of today's width."""
+    fr = FlightRecorder(flight_dir=str(tmp_path))
+    fr.record("m@1", "continuous", step_ms=3.0, chunk=8, active=4, admitted=0,
+              retired=0, prefill_ms=0.0, chunk_ms=2.5, emit_ms=0.25,
+              experts_hit=7.5, write_lanes=4, launch_ms=0.75)
+    ring = fr._ring("m@1")
+    full = ring.tail(1)[0]
+    ring.append(full[:width])
+    new, old = fr.snapshot()["models"]["m@1"]["steps"]
+    assert list(old) == list(STEP_FIELDS[:width]) and "launch_ms" not in old
+    assert ("write_lanes" in old) is (width == 23)
+    path = fr.dump("slo_breach", dedup_key=("slo", f"old{width}"))
+    assert _load_engine_dump_module().main([path]) == 0
+    out = capsys.readouterr().out
+    assert "(prefill=0.00 chunk=2.50 launch=0.75 emit=0.25 self=0.25)" in out
+    assert "(prefill=0.00 chunk=2.50 emit=0.25 self=0.25)" in out
+    assert out.count("write_lanes=4") == (2 if width == 23 else 1)
+
+
+def test_stub_engine_records_no_launch_time():
+    """A runtime that keeps no ``launched_t`` (the stub) records 0.0, never a
+    negative or a stale number."""
+    slots = 4
+    eng = ContinuousGenerateEngine(_StubRuntime(slots), slots=slots, chunk_tokens=4)
+    try:
+        eng.generate(ModelId("stub", 1), np.ones((6, 4), np.int32), max_new_tokens=9)
+    finally:
+        eng.close()
+    steps = RECORDER.snapshot(tail=RECORDER.ring_entries)["models"]["stub@1"]["steps"]
+    assert any(s["chunk"] > 0 for s in steps)
+    assert {s["launch_ms"] for s in steps} == {0.0}

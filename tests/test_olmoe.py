@@ -470,7 +470,7 @@ def test_ring_routing_fields_sit_at_the_end_and_older_dumps_render(tmp_path, cap
     fallback and ``tools/engine_dump.py`` prints it without them."""
     assert STEP_FIELDS[19:22] == ("experts_hit", "expert_rows_max",
                                   "expert_rows_local")
-    assert STEP_FIELDS[22:] == ("write_lanes",)
+    assert STEP_FIELDS[22:23] == ("write_lanes",)
     assert STEP_FIELDS[16:19] == ("prefill_ms", "chunk_ms", "emit_ms")
     fr = FlightRecorder(flight_dir=str(tmp_path))
     old = (time.time(), "continuous", 1.5, 8, 4, 1, 1, 3, 5, 2, 1, 2.0, 1, 1, 0, 0,

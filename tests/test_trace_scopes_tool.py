@@ -29,8 +29,16 @@ def rows():
         return [tuple(r) for r in json.load(f)]
 
 
-def test_device_seconds_by_program(tool, rows):
-    assert tool.by_program(rows) == [("jit_step", pytest.approx(332.688e-6), 3)]
+def test_executions_by_program(tool, rows):
+    """A program's executions: how many, device wall, busy (the union of the
+    operations inside them), idle inside, the mean idle gap before one."""
+    (name, count, wall, busy, idle, gap), = tool.by_execution(rows)
+    assert (name, count) == ("jit_step", 3)
+    # the first execution's wall begins 16 ns before its first operation: outside the span
+    assert wall == pytest.approx(332.688e-6, rel=1e-3)
+    assert 0.9 * wall < busy <= wall and idle == pytest.approx(wall - busy)
+    assert gap == pytest.approx(3.4e-3, rel=0.1)         # two gaps: 2 ms asleep in tpusc.emit each
+    assert tool.by_execution([]) == []
 
 
 def test_device_seconds_by_scope_names_each_operations_owner(tool, rows):
@@ -46,7 +54,7 @@ def test_device_seconds_by_scope_names_each_operations_owner(tool, rows):
     assert not [r for r in table if r[2] == "while"]
     assert [r[3] for r in table] == sorted((r[3] for r in table), reverse=True)
     total = sum(r[3] for r in table)
-    assert total == pytest.approx(tool.by_program(rows)[0][1], rel=0.05)
+    assert total == pytest.approx(tool.by_execution(rows)[0][2], rel=0.05)
 
 
 def test_clock_shift_is_bounded_by_every_launch(tool, rows):
@@ -55,20 +63,37 @@ def test_clock_shift_is_bounded_by_every_launch(tool, rows):
     # without the runtime's launch events the capture cannot say
     quiet = [r for r in rows if r[2] not in ("DoEnqueueProgram", "CompleteCallbacks")]
     assert tool.clock_shift_ns(quiet) == (0, 0, 0)
+    # the clocks drift over a span: bounds that cross by a few microseconds still
+    # give the shift (their middle); by half a millisecond, nothing
+    def completed_sooner(by_ns):
+        return [(p, l, n, s - by_ns if n == "CompleteCallbacks" else s, d, x)
+                for p, l, n, s, d, x in rows]
+    assert tool.clock_shift_ns(completed_sooner(430_000)) == (1264245, 1261159, 3)
+    assert tool.clock_shift_ns(completed_sooner(1_000_000)) == (0, 0, 0)
 
 
-def test_idle_time_goes_to_the_innermost_annotation_open_meanwhile(tool, rows):
+def test_idle_time_goes_to_its_cause(tool, rows):
+    """Inside a program under its name; between two under the innermost annotation
+    the engine's thread had open (``tpusc.emit`` and the boundary itself read
+    "boundary host work"); the causes sum to the span's idle time."""
     lo, hi, _ = tool.clock_shift_ns(rows)
-    table = {r[0]: r[1:] for r in tool.idle_by_annotation(rows, (lo + hi) // 2)}
+    table = dict(tool.idle_by_cause(rows, (lo + hi) // 2))
+    work, chunk = tool.CAUSE["tpusc.boundary"], tool.CAUSE["tpusc.decode_chunk"]
     # two gaps between three launches: the host slept 2 ms in tpusc.emit in each
-    assert table["tpusc.emit"] == (pytest.approx(5.04e-3, rel=0.02), 2)
-    assert 1.0e-3 < table["tpusc.decode_chunk"][0] < 2.5e-3
-    assert table["tpusc.boundary"][0] < 1e-4
+    assert table[work] == pytest.approx(5.04e-3, rel=0.03)
+    # the toy program has no child span: its launch path reads under decode_chunk
+    assert 1.0e-3 < table[chunk] < 2.5e-3
+    assert tool.CAUSE["tpusc.chunk_launch"] not in table
+    assert table["inside jit_step"] < 0.1e-3
+    runs = tool.executions(rows)
+    span_idle = (runs[-1][2] - runs[0][1] - sum(r[3] for r in runs)) / 1e9
+    assert sum(table.values()) == pytest.approx(span_idle)
     # unshifted, the device's events sit before the host launched them: the
     # same idle time lands elsewhere, which is why the shift is applied
-    raw = {r[0]: r[1] for r in tool.idle_by_annotation(rows)}
-    assert raw.get("tpusc.emit", 0.0) != pytest.approx(table["tpusc.emit"][0], rel=0.02)
-    assert tool.idle_by_annotation([]) == []
+    raw = dict(tool.idle_by_cause(rows))
+    assert raw.get(work, 0.0) != pytest.approx(table[work], rel=0.02)
+    assert sum(raw.values()) == pytest.approx(span_idle)
+    assert tool.idle_by_cause([]) == []
 
 
 def test_loader_reads_scopes_off_the_capture(tool, rows):

@@ -700,6 +700,9 @@ class SlotDecodeState:
     temps: np.ndarray                # (S,) f32 per-lane temperature
     topks: np.ndarray                # (S,) i32 per-lane top_k
     chunk_counter: int = 0           # host-side PRNG stream for chunk keys
+    # time.monotonic() at which the last decode chunk's program call returned
+    # its futures: the end of the chunk's launch path (ring ``launch_ms``)
+    launched_t: float = 0.0
     # the last decode chunk's routing stats of a model with expert layers:
     # (experts_hit, expert_rows_max, expert_rows_local: generation.MOE_STATS),
     # chunk means; None for a dense model
@@ -2840,35 +2843,47 @@ class TPUModelRuntime(BaseRuntime):
         dispatch; updates the state's device K/V and host tok/pos mirrors
         and returns the (S, chunk) emitted tokens. Raises
         ModelNotLoadedError when the model was evicted mid-decode (the
-        engine fails its in-flight requests and drops the state)."""
+        engine fails its in-flight requests and drops the state).
+        Two profiler annotations split the call (inside the engine's
+        ``tpusc.decode_chunk``): ``tpusc.chunk_launch`` until the program
+        call has returned its futures, ``tpusc.chunk_fetch`` around the one
+        fetch; ``state.launched_t`` is the clock between them."""
         import jax
 
         from tfservingcache_tpu.models.generation import (
             _paged_decode_chunk_jit,
         )
 
-        loaded = self._resident.get(state.model_id)
-        if loaded is None:
-            raise ModelNotLoadedError(f"model {state.model_id} is not loaded")
-        state.chunk_counter += 1
-        rngs = jax.random.split(
-            jax.random.PRNGKey(state.chunk_counter), chunk
-        )
-        if _PAGECHECK:
-            _check_trash_unreachable(state)
-        (state.k, state.v, state.scales, tok, pos,
-         toks, stats, state.lane_state) = _paged_decode_chunk_jit(
-            loaded.params, state.k, state.v, state.scales,
-            np.asarray(state.block_tables, np.int32),
-            state.tok, state.pos, state.active, rngs,
-            state.temps, state.topks, state.lane_state,
-            cfg_key=state.cfg_key, family=state.family, chunk=chunk,
-            page_tokens=state.page_tokens, kernel=state.kernel,
-        )
+        # the launch path: everything the host does before the device has the
+        # chunk (residency lookup, the key programs, the mirrors' conversion,
+        # the call). The device stands still for it unless an admission's
+        # programs are still running
+        with host_span("chunk_launch"):
+            loaded = self._resident.get(state.model_id)
+            if loaded is None:
+                raise ModelNotLoadedError(
+                    f"model {state.model_id} is not loaded")
+            state.chunk_counter += 1
+            rngs = jax.random.split(
+                jax.random.PRNGKey(state.chunk_counter), chunk
+            )
+            if _PAGECHECK:
+                _check_trash_unreachable(state)
+            (state.k, state.v, state.scales, tok, pos,
+             toks, stats, state.lane_state) = _paged_decode_chunk_jit(
+                loaded.params, state.k, state.v, state.scales,
+                np.asarray(state.block_tables, np.int32),
+                state.tok, state.pos, state.active, rngs,
+                state.temps, state.topks, state.lane_state,
+                cfg_key=state.cfg_key, family=state.family, chunk=chunk,
+                page_tokens=state.page_tokens, kernel=state.kernel,
+            )
+        state.launched_t = time.monotonic()
         # np.array (not asarray): device_get hands back READ-ONLY views and
         # the scheduler writes these mirrors at the next admission
         # one fetch: an expert model's routing numbers ride with the tokens
-        tok, pos, toks, stats = jax.device_get((tok, pos, toks, stats))
+        with host_span("chunk_fetch"):
+            tok, pos, toks, stats = jax.device_get((tok, pos, toks, stats))
         state.tok = np.array(tok, dtype=np.int32)
         state.pos = np.array(pos, dtype=np.int32)
         state.moe_stats = None if stats is None else tuple(

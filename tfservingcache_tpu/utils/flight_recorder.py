@@ -98,6 +98,13 @@ STEP_FIELDS = (
     # (generation.kv_write_lanes; the built width for a spec round; 0 for a
     # boundary that ran no chunk)
     "write_lanes",
+    # appended field (ISSUE 35 the chunk's launch path): the part of chunk_ms
+    # the host spent BEFORE the device had the chunk (residency lookup, the
+    # key programs, the argument upload, the program call), host clock; the
+    # chip stands still for it unless an admission's programs still run, and
+    # chunk_ms - launch_ms is the wait for the device plus the fetch. 0.0 for
+    # a spec round and for a boundary that ran no chunk
+    "launch_ms",
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -110,7 +117,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 23:
+    if len(e) == 24:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -121,6 +128,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "prefill_ms": e[16], "chunk_ms": e[17], "emit_ms": e[18],
             "experts_hit": e[19], "expert_rows_max": e[20],
             "expert_rows_local": e[21], "write_lanes": e[22],
+            "launch_ms": e[23],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -280,6 +288,7 @@ class FlightRecorder:
         expert_rows_max: float = 0.0,
         expert_rows_local: float = 0.0,
         write_lanes: int = 0,
+        launch_ms: float = 0.0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -288,7 +297,7 @@ class FlightRecorder:
             drafted, accepted,
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
             round(experts_hit, 3), round(expert_rows_max, 3),
-            round(expert_rows_local, 3), write_lanes,
+            round(expert_rows_local, 3), write_lanes, round(launch_ms, 4),
         ))
 
     def note_phases(

@@ -65,7 +65,11 @@ def _fmt_step(s: dict) -> str:
     if "chunk_ms" in s:   # the three fields come together
         pre, chunk, emit = s["prefill_ms"], s["chunk_ms"], s["emit_ms"]
         own = max(0.0, s.get("step_ms", 0) - pre - chunk - emit)
-        split = f"(prefill={pre:.2f} chunk={chunk:.2f} emit={emit:.2f} self={own:.2f}) "
+        # the chunk's launch path (ISSUE 35): the part of chunk= before
+        # the device had the chunk; absent in dumps of rings up to 23 fields
+        launch = f"launch={s['launch_ms']:.2f} " if "launch_ms" in s else ""
+        split = (f"(prefill={pre:.2f} chunk={chunk:.2f} {launch}emit={emit:.2f} "
+                 f"self={own:.2f}) ")
     # expert routing (ISSUE 25); absent in older dumps, 0 for dense models
     if s.get("experts_hit"):
         split += (f"experts={s['experts_hit']:.1f} "

@@ -1,9 +1,16 @@
 #!/usr/bin/env python3
 """Read a profiler capture (``POST /monitoring/profiler``) by the program's own
 names: ``python tools/trace_scopes.py <file.xplane.pb[.gz] | capture dir>`` prints
-device seconds by program (``XLA Modules``), by program | ``jax.named_scope`` path |
-operation (``XLA Ops``, wrappers left out), and the device's idle time by the
-innermost ``tpusc.*`` host annotation (``utils/tracing.host_span``) open meanwhile.
+device seconds by program | ``jax.named_scope`` path | operation (``XLA Ops``,
+wrappers left out); every program's executions (``XLA Modules``: how many, device
+wall, busy = the union of the operations inside them, idle INSIDE them, the mean
+idle gap BEFORE one); and the device's idle time by cause: inside a program, or
+between two under the innermost ``tpusc.*`` annotation the engine's thread had
+open meanwhile (``utils/tracing.host_span``: ``tpusc.chunk_launch`` and
+``tpusc.chunk_fetch`` split ``tpusc.decode_chunk``). A device that waits inside
+a program has bubbles between its operations; one that waits between two waits
+for the host. ``benchmark/capture_programs.py`` reads a traced benchmark run's
+capture the same way (one test holds the two to the same figures).
 
 Events come from ``jax.profiler.ProfileData``. It hides an event's METADATA stats,
 where libtpu keeps an operation's scope path (``tf_op``) and ``program_id``: those
@@ -15,6 +22,7 @@ capture; ``clock_shift_ns`` bounds the lag from every launch, matched by ``run_i
 
 from __future__ import annotations
 
+import bisect
 import glob
 import gzip
 import os
@@ -24,10 +32,18 @@ from collections import defaultdict
 
 DEVICE, OPS, MODULES, MARK = "/device:TPU:", "XLA Ops", "XLA Modules", "tpusc."
 WRAPPERS = ("while", "conditional", "call")
+DRIFT_NS = 500_000
 NOISE = re.compile(r"^(jit\(.*\)|jit|while|body|cond|closed_call|checkpoint|pjit|)$")
-# the engine's nesting; any other annotation ranks below, latest start first
-RANK = {"tpusc.boundary": 1, "tpusc.admit": 2, "tpusc.prefill": 3,
-        "tpusc.decode_chunk": 3, "tpusc.emit": 3}
+# idle time between two programs, by the innermost engine annotation open; an
+# ``admit`` that holds a prefill is an admission's
+CAUSE = {"tpusc.chunk_launch": "launch path (under tpusc.chunk_launch)",
+         "tpusc.chunk_fetch": "a chunk's device end to tpusc.chunk_fetch's close",
+         "tpusc.decode_chunk": "under tpusc.decode_chunk outside its two child spans",
+         "tpusc.boundary": "boundary host work (emit, ring, admit)",
+         "tpusc.prefill": "admission path (before prefill / insert / lane insert)",
+         None: "no boundary open"}
+CAUSE.update({"tpusc.emit": CAUSE["tpusc.boundary"], "tpusc.admit": CAUSE["tpusc.boundary"],
+              "tpusc.state_insert": CAUSE["tpusc.prefill"]})
 # a row: (plane, line, name, start_ns, dur_ns, {"scope", "program", "run_id"})
 
 
@@ -122,10 +138,6 @@ def totals(keyed) -> list[tuple]:
     return sorted(((*k, v[0], v[1]) for k, v in acc.items()), key=lambda r: -r[-2])
 
 
-def by_program(rows) -> list[tuple]:
-    return totals(((short(r[2]),), r[4]) for r in device_rows(rows, MODULES))
-
-
 def by_scope(rows) -> list[tuple]:
     programs = {int(m.group(1)): short(r[2]) for r in device_rows(rows, MODULES)
                 if (m := re.search(r"\((\d+)\)$", r[2]))}
@@ -144,25 +156,112 @@ def clock_shift_ns(rows) -> tuple[int, int, int]:
                 lo, n = max(lo, s - run[x["run_id"]][0]), n + 1
             elif name == "CompleteCallbacks":
                 hi = min(hi, s - run[x["run_id"]][1])
-    return (lo, hi, n) if n and lo <= hi else (0, 0, 0)
+    # the clocks drift by up to 0.1 ms over a 4 s span: over hundreds of launches
+    # the bounds often cross by that much, and the middle is still the shift
+    return (lo, hi, n) if n and hi < 1 << 62 and lo - hi <= DRIFT_NS else (0, 0, 0)
 
 
-def idle_by_annotation(rows, shift_ns: int = 0) -> list[tuple]:
-    """The first device's idle time between its operations, each part of a gap
-    under the innermost ``tpusc.*`` event open on the host during it."""
-    ops = sorted((r[3] + shift_ns, r[3] + r[4] + shift_ns) for r in device_rows(rows, OPS))
-    marks = [(s, s + d, n) for p, _l, n, s, d, _x in rows
-             if not p.startswith(DEVICE) and n.startswith(MARK)]
-    parts, edge = [], ops[0][1] if ops else 0
+def executions(rows) -> list[tuple]:
+    """[(program, start, end, busy, idle gap before)] of the first device, ns, in
+    time order, clipped to the operations' span; one sweep: the busy intervals
+    (the operations' union) are met in order by gap, execution, gap, execution."""
+    ops = sorted((r[3], r[3] + r[4]) for r in device_rows(rows, OPS))
+    busy: list[list[int]] = []
     for s, e in ops:
-        if s > edge:
-            cuts = sorted({edge, s, *(t for m in marks for t in m[:2] if edge < t < s)})
-            for a, b in zip(cuts, cuts[1:]):
-                live = [m for m in marks if m[0] <= a and b <= m[1]]
-                top = max(live, key=lambda m: (RANK.get(m[2], 0), m[0]), default=None)
-                parts.append(((top[2] if top else "(none open)",), b - a))
-        edge = max(edge, e)
-    return totals(parts)
+        if busy and s <= busy[-1][1]:
+            busy[-1][1] = max(busy[-1][1], e)
+        else:
+            busy.append([s, e])
+    if not busy:
+        return []
+    lo, hi, at = busy[0][0], busy[-1][1], 0
+
+    def take(a: int, b: int) -> int:
+        """Busy time inside [a, b); calls come in rising order."""
+        nonlocal at
+        while at < len(busy) and busy[at][1] <= a:
+            at += 1
+        total, k = 0, at
+        while k < len(busy) and busy[k][0] < b:
+            total += min(busy[k][1], b) - max(busy[k][0], a)
+            k += 1
+        return total
+
+    out, last = [], None
+    for r in sorted(device_rows(rows, MODULES), key=lambda r: r[3]):
+        s, e = max(r[3], lo), min(r[3] + r[4], hi)
+        if e <= s:
+            continue
+        gap = None if last is None else (s - last) - take(last, s)
+        out.append((short(r[2]), s, e, take(s, e), gap))
+        last = e
+    return out
+
+
+def by_execution(rows) -> list[tuple]:
+    """[(program, executions, device wall s, busy s, idle inside s, mean gap before
+    s)], the largest wall first."""
+    acc = defaultdict(lambda: [0, 0.0, 0.0, []])
+    for name, s, e, busy, gap in executions(rows):
+        row = acc[name]
+        row[0], row[1], row[2] = row[0] + 1, row[1] + (e - s) / 1e9, row[2] + busy / 1e9
+        if gap is not None:
+            row[3].append(gap / 1e9)
+    return sorted(((n, r[0], r[1], r[2], r[1] - r[2], sum(r[3]) / len(r[3]) if r[3] else 0.0)
+                   for n, r in acc.items()), key=lambda r: -r[2])
+
+
+def engine_marks(rows) -> list[tuple]:
+    """[(name, start, end)] of the thread that opens ``tpusc.boundary``: the
+    engine's. A request's spans on the serving threads name no idle time."""
+    lines = {(r[0], r[1]) for r in rows if r[2] == "tpusc.boundary"}
+    return [(r[2], r[3], r[3] + r[4]) for r in rows
+            if (r[0], r[1]) in lines and r[2].startswith(MARK)]
+
+
+def idle_by_cause(rows, shift_ns: int = 0) -> list[tuple]:
+    """[(cause, idle seconds)], largest first, summing to the span's idle time: the
+    idle time inside each program under its name; the idle time between two
+    executions under the innermost annotation the engine's thread had open
+    (device clock + ``shift_ns``). One sweep: gaps and annotations both in time
+    order, the annotations (they nest: one thread) as a stack."""
+    marks = sorted(engine_marks(rows), key=lambda m: (m[1], -m[2]))
+    prefills = [m[1] for m in marks if m[0] == "tpusc.prefill"]
+    cuts, names, stack = [], [], []      # innermost annotation from each cut on
+
+    def close(until) -> None:
+        while stack and stack[-1][2] <= until:
+            cuts.append(stack.pop()[2])
+            names.append(stack[-1][0] if stack else None)
+
+    for name, s, e in marks:
+        close(s)
+        if name == "tpusc.admit":
+            i = bisect.bisect_left(prefills, s)
+            if i < len(prefills) and prefills[i] < e:
+                name = "tpusc.prefill"
+        stack.append((name, s, e))
+        cuts.append(s)
+        names.append(name)
+    close(float("inf"))
+    acc: dict[str, float] = defaultdict(float)
+    runs, k = executions(rows), -1
+    for (name, s, e, busy, gap), before in zip(runs, [None] + runs[:-1]):
+        acc[f"inside {name}"] += (e - s - busy) / 1e9
+        if not gap or before is None:
+            continue
+        a, b = before[2] + shift_ns, s + shift_ns
+        scale = gap / (b - a)             # 1 unless something ran outside a program
+        while k + 1 < len(cuts) and cuts[k + 1] <= a:
+            k += 1
+        while a < b:
+            nxt = min(b, cuts[k + 1]) if k + 1 < len(cuts) else b
+            acc[CAUSE.get(names[k] if k >= 0 else None, CAUSE["tpusc.boundary"])] += (
+                (nxt - a) * scale / 1e9)
+            if nxt < b:
+                k += 1
+            a = nxt
+    return sorted(acc.items(), key=lambda kv: -kv[1])
 
 
 def main(argv: list[str]) -> int:
@@ -171,15 +270,20 @@ def main(argv: list[str]) -> int:
         return 2
     rows = load(argv[0])
     lo, hi, n = clock_shift_ns(rows)
-    idle = (f"device idle seconds by innermost tpusc.* annotation (device clock + "
-            f"{(lo + hi) / 2e6:.3f} ms; bounds {lo / 1e6:.3f}..{hi / 1e6:.3f} from {n} launches)")
-    for title, table in (("device seconds by program", by_program(rows)),
-                         ("device seconds by program | scope | operation (top 40)",
-                          by_scope(rows)[:40]),
-                         (idle, idle_by_annotation(rows, (lo + hi) // 2))):
-        print(f"\n{title}\n   seconds   count")
-        for *key, sec, count in table:
-            print(f"  {sec:8.4f} {count:7d}  {' | '.join(key)}")
+    print("\ndevice seconds by program | scope | operation (top 40)\n   seconds   count")
+    for *key, sec, count in by_scope(rows)[:40]:
+        print(f"  {sec:8.4f} {count:7d}  {' | '.join(key)}")
+    print("\nprogram executions: count, device wall s, busy s, idle inside s, "
+          "mean idle gap before ms")
+    for name, count, wall, busy, idle, gap in by_execution(rows):
+        print(f"  {count:7d} {wall:8.4f} {busy:8.4f} {idle:8.4f} {gap * 1e3:8.3f}  {name}")
+    causes = idle_by_cause(rows, (lo + hi) // 2)
+    print(f"\ndevice idle seconds by cause, {sum(sec for _c, sec in causes):.4f} in all "
+          f"(device clock + {(lo + hi) / 2e6:.3f} ms; bounds {lo / 1e6:.3f}..{hi / 1e6:.3f} "
+          f"from {n} launches)")
+    for cause, sec in causes:
+        if sec > 0:
+            print(f"  {sec:8.4f}  {cause}")
     return 0
 
 
