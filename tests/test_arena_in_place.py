@@ -89,7 +89,7 @@ def _program(which, family, cfg, cache):
                                chunk=CHUNK, **static)
         args = (params, cache["k"], cache["v"], scales, i32((LANES, PPS)),
                 lane, lane, jax.ShapeDtypeStruct((LANES,), jnp.bool_),
-                jax.ShapeDtypeStruct((CHUNK, 2), jnp.uint32),
+                jax.ShapeDtypeStruct((), jnp.uint32),
                 jax.ShapeDtypeStruct((LANES,), jnp.float32), lane)
     else:
         fn = functools.partial(generation._paged_prefill_chunk_jit, **static)
@@ -249,7 +249,7 @@ def _compile_for_v5e_main(case="dense"):
     lane = s((lanes,), jnp.int32)
     args = (params, cache["k"], cache["v"], None,
             s((lanes, cfg["max_seq"] // pt), jnp.int32), lane, lane,
-            s((lanes,), jnp.bool_), s((chunk, 2), jnp.uint32),
+            s((lanes,), jnp.bool_), s((), jnp.uint32),
             s((lanes,), jnp.float32), lane,
             jax.eval_shape(lambda: generation.init_lane_state(cfg, lanes)))
     args = jax.tree_util.tree_map(

@@ -328,6 +328,19 @@ def test_readers_give_nothing_where_nothing_is_to_be_read(bench, name, monkeypat
     assert reader(bench, name)(make_run(bench, idle_ring, platform="cpu")) is None
 
 
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_chunk_uploads_mean_reads_the_ring_and_nothing_of_an_older_one(bench, platform):
+    """PR 36's reader: the mean of ring ``uploads`` over the window's boundaries
+    that ran a chunk (a count, so a rehearsal shows it too); the parent's ring
+    has no such field and gives nothing."""
+    read = reader(bench, "chunk_uploads_mean")
+    steps = [dict(s, uploads=u) for s, u in zip(STEPS, (7, 3, 0, 0, 7))]
+    assert read(make_run(bench, steps, platform=platform)) == (pytest.approx(10 / 3), 3)
+    assert read(make_run(bench, STEPS, platform=platform)) is None
+    assert read(make_run(bench, [ring_step(1005.0, chunk=0, uploads=0)],
+                         platform=platform)) is None
+
+
 def test_the_capture_is_found_as_capture_scopes_finds_it_and_reported_once(
         bench, monkeypatch, capsys):
     monkeypatch.setattr(bench.cs, "find_capture", lambda wall: CUT)
@@ -348,11 +361,13 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
 
     with open(os.path.join(HERE, "..", "BENCHMARK.json")) as f:
         bench_json = json.load(f)
-    tail = bench_json["per_layer"][-5:]
-    assert [m["name"] for m in tail] == NEW
+    # PR 35's five, then PR 36's count of what a chunk's launch uploaded
+    tail = bench_json["per_layer"][-6:]
+    assert [m["name"] for m in tail] == NEW + ["chunk_uploads_mean"]
+    assert tail[-1]["unit"] == "operands" and tail[-1]["source"] == "program_counter"
     cells = ["mistral7b-chat-steady", "olmoe-chat-steady", "mistral4-docqa-steady",
              "lfm2-longgen-steady"]
-    layers = {m["layer"] for m in bench_json["per_layer"][:-5]}
+    layers = {m["layer"] for m in bench_json["per_layer"][:-6]}
     for m in tail:
         assert m["workloads"] == cells and m["moves"] == "tpot_p50_ms"
         assert m["better"] == "lower" and m["layer"] in layers

@@ -6,11 +6,15 @@
       ``tpusc.chunk_launch`` and ``tpusc.chunk_fetch`` nested in
       ``tpusc.decode_chunk``, the fetch after the launch;
   (e) the spans and the clock change nothing the device sees: the decode chunk
-      is called with the parent's operands (it traces to the parent's jaxpr)
-      and its tokens are bit for bit the parent's.
+      is called with the parent's operands but for the keys (ISSUE 36: the
+      chunk counter goes in and the program derives the parent's keys) and
+      its tokens are bit for bit the parent's.
 """
 
+import functools
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 import tfservingcache_tpu.models.generation as generation
@@ -102,8 +106,33 @@ def _admitted_state(rt, mid):
     return state
 
 
+@functools.partial(jax.jit, static_argnames=("cfg_key", "family", "page_tokens"))
+def _parent_chunk(params, k, v, tables, tok, pos, active, rngs, temperature, top_k,
+                  *, cfg_key, family, page_tokens):
+    """The parent's decode chunk (2bfac44): the same scan over keys the HOST
+    split from the chunk counter."""
+    cfg = dict(cfg_key)
+    live = generation._live_lanes(active)
+
+    def step(carry, rng):
+        cache, tok, pos = carry
+        logits, cache = generation._paged_forward_step(
+            params, tok, cache, tables, pos, cfg, family, page_tokens,
+            active=active, live=live)
+        nxt = generation._sample_per_row(logits[:, 0], rng, temperature, top_k, active)
+        nxt = jnp.where(active, nxt, tok)
+        return (cache, nxt, pos + active.astype(jnp.int32)), nxt
+
+    (_cache, tok, pos), toks = jax.lax.scan(
+        step, (generation._arena_cache(k, v, None, None), tok, pos), rngs)
+    return tok, pos, toks.T
+
+
 def test_e_the_decode_chunk_gets_the_parents_operands_and_gives_its_tokens(
         tmp_path, monkeypatch):
+    """ISSUE 36: the chunk takes the COUNTER where the parent took keys the
+    host had split from it, and the mirrors' values as device arrays; tokens,
+    ``tok`` and ``pos`` are bit for bit the parent's call's, greedy and drawn."""
     rt, mid = _load(tmp_path, "launch_same")
     try:
         calls = []
@@ -115,6 +144,7 @@ def test_e_the_decode_chunk_gets_the_parents_operands_and_gives_its_tokens(
 
         monkeypatch.setattr(generation, "_paged_decode_chunk_jit", spy)
         state = _admitted_state(rt, mid)
+        state.temps[0], state.topks[0] = 0.9, 5          # a lane that draws
         before = {k: np.array(getattr(state, k)) for k in
                   ("tok", "pos", "active", "temps", "topks", "block_tables")}
         counter = state.chunk_counter
@@ -122,17 +152,16 @@ def test_e_the_decode_chunk_gets_the_parents_operands_and_gives_its_tokens(
         (args, kw), = calls
         monkeypatch.setattr(generation, "_paged_decode_chunk_jit", real)
 
-        # the parent's call, as e0f619f wrote it: these operands, in this
-        # order, the keys split on the host from the chunk counter; on a fresh
-        # state (the runtime keeps ONE state a model)
+        # the parent's call, as 2bfac44 wrote it: these operands, in this
+        # order, but for the keys, which it split on the host from the chunk
+        # counter; on a fresh state (the runtime keeps ONE state a model)
         rt.drop_slot_state(mid)
         twin = _admitted_state(rt, mid)
         assert twin is not state
         loaded = rt._resident.get(mid)
-        rngs = jax.random.split(jax.random.PRNGKey(counter + 1), 4)
         parent_args = (loaded.params, twin.k, twin.v, twin.scales,
-                       np.asarray(before["block_tables"], np.int32), before["tok"],
-                       before["pos"], before["active"], rngs, before["temps"],
+                       before["block_tables"], before["tok"], before["pos"],
+                       before["active"], np.uint32(counter + 1), before["temps"],
                        before["topks"], twin.lane_state)
         parent_kw = dict(cfg_key=twin.cfg_key, family=twin.family, chunk=4,
                          page_tokens=twin.page_tokens, kernel=twin.kernel)
@@ -140,16 +169,17 @@ def test_e_the_decode_chunk_gets_the_parents_operands_and_gives_its_tokens(
         shapes = lambda tree: [(x.shape, str(x.dtype)) for x in  # noqa: E731
                                jax.tree_util.tree_leaves(tree)]
         assert shapes(args) == shapes(parent_args)
-        np.testing.assert_array_equal(np.asarray(args[8]), np.asarray(rngs))
-        trace = lambda a: str(jax.make_jaxpr(                    # noqa: E731
-            lambda *x: real(*x, **parent_kw))(*a))
-        abstract = lambda a: jax.tree_util.tree_map(             # noqa: E731
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
-        assert trace(abstract(args)) == trace(abstract(parent_args))
-        out = real(*parent_args, **parent_kw)
-        np.testing.assert_array_equal(toks, np.asarray(out[5]))
-        np.testing.assert_array_equal(state.tok, np.asarray(out[3]))
-        np.testing.assert_array_equal(state.pos, np.asarray(out[4]))
+        for got, want in zip(args[4:11], parent_args[4:11]):
+            np.testing.assert_array_equal(np.asarray(got), want)
+        # the counter in, the parent's keys derived
+        rngs = jax.random.split(jax.random.PRNGKey(counter + 1), 4)
+        tok, pos, want = _parent_chunk(
+            loaded.params, twin.k, twin.v, before["block_tables"], before["tok"],
+            before["pos"], before["active"], rngs, before["temps"], before["topks"],
+            cfg_key=twin.cfg_key, family=twin.family, page_tokens=twin.page_tokens)
+        np.testing.assert_array_equal(toks, np.asarray(want))
+        np.testing.assert_array_equal(state.tok, np.asarray(tok))
+        np.testing.assert_array_equal(state.pos, np.asarray(pos))
         assert state.launched_t > 0.0
     finally:
         rt.close()

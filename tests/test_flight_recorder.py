@@ -677,7 +677,7 @@ def test_sixteen_field_dump_still_renders(tmp_path, capsys):
     assert mod.main([path]) == 0
     out = capsys.readouterr().out
     assert "step=    1.50ms chunk=  8" in out          # the 16-field row
-    assert "(prefill=0.00 chunk=2.00 launch=0.00 emit=0.25 self=0.25)" in out
+    assert "(prefill=0.00 chunk=2.00 launch=0.00 uploads=0 emit=0.25 self=0.25)" in out
 
 
 def test_record_with_split_fields_under_50us():
@@ -698,44 +698,50 @@ def test_record_with_split_fields_under_50us():
 # -- the chunk's launch path (ISSUE 35) -----------------------------------------
 
 def test_launch_ms_is_the_last_ring_field():
-    """Appended, never inserted: the 23 older names keep their positions."""
-    assert STEP_FIELDS[-1] == "launch_ms" and len(STEP_FIELDS) == 24
+    """Appended, never inserted: the 23 older names keep their positions
+    (``uploads``, ISSUE 36, came after it the same way)."""
+    assert STEP_FIELDS[23:] == ("launch_ms", "uploads") and len(STEP_FIELDS) == 25
     assert STEP_FIELDS[16:19] == ("prefill_ms", "chunk_ms", "emit_ms")
     assert STEP_FIELDS[19:23] == ("experts_hit", "expert_rows_max",
                                   "expert_rows_local", "write_lanes")
     fr = FlightRecorder()
     fr.record("m@1", "continuous", step_ms=9.0, chunk=8, active=4, admitted=0,
-              retired=0, chunk_ms=5.0, launch_ms=1.23456)
+              retired=0, chunk_ms=5.0, launch_ms=1.23456, uploads=2)
     step = fr.snapshot()["models"]["m@1"]["steps"][0]
     assert list(step) == list(STEP_FIELDS) and step["launch_ms"] == 1.2346
+    assert step["uploads"] == 2
     # a caller that does not know the field (a spec round, a boundary without
     # a chunk) records 0.0
     fr.record("m@1", "continuous", step_ms=9.0, chunk=0, active=0, admitted=1,
               retired=0)
-    assert fr.snapshot()["models"]["m@1"]["steps"][1]["launch_ms"] == 0.0
+    last = fr.snapshot()["models"]["m@1"]["steps"][1]
+    assert last["launch_ms"] == 0.0 and last["uploads"] == 0
 
 
-@pytest.mark.parametrize("width", [19, 23])
+@pytest.mark.parametrize("width", [19, 23, 24])
 def test_a_dump_of_an_older_ring_still_renders(width, tmp_path, capsys):
-    """Dumps written before ``launch_ms`` (23 fields) and before the expert
-    fields (19) go through the zip fallback; the tool prints the split without
-    ``launch=``, and with it for a row of today's width."""
+    """Dumps written before ``uploads`` (24 fields), before ``launch_ms`` (23)
+    and before the expert fields (19) go through the zip fallback; the tool
+    prints the split without ``launch=`` / ``uploads=``, and with them for a
+    row of today's width."""
     fr = FlightRecorder(flight_dir=str(tmp_path))
     fr.record("m@1", "continuous", step_ms=3.0, chunk=8, active=4, admitted=0,
               retired=0, prefill_ms=0.0, chunk_ms=2.5, emit_ms=0.25,
-              experts_hit=7.5, write_lanes=4, launch_ms=0.75)
+              experts_hit=7.5, write_lanes=4, launch_ms=0.75, uploads=3)
     ring = fr._ring("m@1")
     full = ring.tail(1)[0]
     ring.append(full[:width])
     new, old = fr.snapshot()["models"]["m@1"]["steps"]
-    assert list(old) == list(STEP_FIELDS[:width]) and "launch_ms" not in old
-    assert ("write_lanes" in old) is (width == 23)
+    assert list(old) == list(STEP_FIELDS[:width]) and "uploads" not in old
+    assert ("launch_ms" in old) is (width == 24)
+    assert ("write_lanes" in old) is (width >= 23)
     path = fr.dump("slo_breach", dedup_key=("slo", f"old{width}"))
     assert _load_engine_dump_module().main([path]) == 0
     out = capsys.readouterr().out
-    assert "(prefill=0.00 chunk=2.50 launch=0.75 emit=0.25 self=0.25)" in out
-    assert "(prefill=0.00 chunk=2.50 emit=0.25 self=0.25)" in out
-    assert out.count("write_lanes=4") == (2 if width == 23 else 1)
+    assert "(prefill=0.00 chunk=2.50 launch=0.75 uploads=3 emit=0.25 self=0.25)" in out
+    assert ("(prefill=0.00 chunk=2.50 launch=0.75 emit=0.25 self=0.25)" if width == 24
+            else "(prefill=0.00 chunk=2.50 emit=0.25 self=0.25)") in out
+    assert out.count("write_lanes=4") == (2 if width >= 23 else 1)
 
 
 def test_stub_engine_records_no_launch_time():
@@ -750,3 +756,4 @@ def test_stub_engine_records_no_launch_time():
     steps = RECORDER.snapshot(tail=RECORDER.ring_entries)["models"]["stub@1"]["steps"]
     assert any(s["chunk"] > 0 for s in steps)
     assert {s["launch_ms"] for s in steps} == {0.0}
+    assert {s["uploads"] for s in steps} == {0}          # nor an upload count

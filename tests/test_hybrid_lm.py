@@ -319,9 +319,9 @@ def test_b_head_64_decode_chunk_through_the_kernel_emits_the_references_tokens(
     active = np.arange(LANES) == lane
     tok = np.zeros((LANES,), np.int32)
     tok[lane] = first
-    k, _, _, _, _, toks, _, _ = generation._paged_decode_chunk_jit(
+    k, _, _, _, _, toks, _, _, _ = generation._paged_decode_chunk_jit(
         dev, cache["k"], cache["v"], None, tables, tok, pos, active,
-        jax.random.split(jax.random.PRNGKey(1), 4),
+        np.uint32(1),
         np.zeros((LANES,), np.float32), np.zeros((LANES,), np.int32),
         cache["lane"], cfg_key=tuple(sorted(cfg.items())), family="hybrid_lm",
         chunk=4, page_tokens=PT, kernel=True)
@@ -359,9 +359,8 @@ def test_b_decode_chunk_program_greedy_tokens_and_stats():
     active = np.arange(LANES) == lane
     tok = np.zeros((LANES,), np.int32)
     tok[lane] = first
-    rngs = jax.random.split(jax.random.PRNGKey(1), 4)
-    k, v, scales, _, _, toks, stats, lanes = generation._paged_decode_chunk_jit(
-        dev, cache["k"], cache["v"], None, tables, tok, pos, active, rngs,
+    k, v, scales, _, _, toks, stats, lanes, _ = generation._paged_decode_chunk_jit(
+        dev, cache["k"], cache["v"], None, tables, tok, pos, active, np.uint32(1),
         np.zeros((LANES,), np.float32), np.zeros((LANES,), np.int32),
         cache["lane"], cfg_key=tuple(sorted(cfg.items())), family="hybrid_lm",
         chunk=4, page_tokens=PT)
@@ -380,9 +379,8 @@ def test_b_decode_chunk_program_greedy_tokens_and_stats():
 # -- (c) the lane state by itself ----------------------------------------------
 
 def _chunk(dev, cfg, cache, tables, tok, pos, active, chunk=4):
-    rngs = jax.random.split(jax.random.PRNGKey(7), chunk)
-    k, v, _, tok, pos, toks, _, lanes = generation._paged_decode_chunk_jit(
-        dev, cache["k"], cache["v"], None, tables, tok, pos, active, rngs,
+    k, v, _, tok, pos, toks, _, lanes, _ = generation._paged_decode_chunk_jit(
+        dev, cache["k"], cache["v"], None, tables, tok, pos, active, np.uint32(7),
         np.zeros((LANES,), np.float32), np.zeros((LANES,), np.int32),
         cache["lane"], cfg_key=tuple(sorted(cfg.items())), family="hybrid_lm",
         chunk=chunk, page_tokens=PT)
@@ -716,7 +714,7 @@ def test_g_accepted_families_decode_chunk_is_traced_as_before(which, monkeypatch
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     args = (params, cache["k"], cache.get("v"), None, i32(lanes, pps), i32(lanes),
             i32(lanes), jax.ShapeDtypeStruct((lanes,), jnp.bool_),
-            jax.ShapeDtypeStruct((chunk, 2), jnp.uint32),
+            jax.ShapeDtypeStruct((), jnp.uint32),
             jax.ShapeDtypeStruct((lanes,), jnp.float32), i32(lanes))
     static = dict(cfg_key=tuple(sorted(cfg.items())), family=family, chunk=chunk,
                   page_tokens=4, kernel=False)
@@ -728,9 +726,9 @@ def test_g_accepted_families_decode_chunk_is_traced_as_before(which, monkeypatch
     n_in = len(jax.tree_util.tree_leaves(args))
     assert len(traced.jaxpr.invars) == n_in
     out = jax.eval_shape(fn, *args)
-    assert out[-1] is None and len(out) == 8
+    assert out[-2] is None and len(out) == 9
     sides = [a for a in (cache["k"], cache.get("v")) if a is not None]
-    n_out = len(sides) + 3 + (0 if out[6] is None else 1)
+    n_out = len(sides) + 4 + (0 if out[6] is None else 1)   # + tok, pos, toks, counter
     assert len(traced.jaxpr.outvars) == n_out
     # the parent's program: what is traced when nothing can pack or unpack
     monkeypatch.setattr(generation, "pack_rows", lambda rows, arena: rows)

@@ -134,7 +134,7 @@ def _both(family, arena_dtype, live, parked=()):
               if "k_scale" in cache else None)
     out = _paged_decode_chunk_jit(
         params, cache["k"], cache.get("v"), scales, tables, tok, pos, active,
-        rngs, temps, topks, cfg_key=key, family=name, chunk=CHUNK,
+        np.uint32(5), temps, topks, cfg_key=key, family=name, chunk=CHUNK,
         page_tokens=PT, kernel=False)
     got = generation._arena_cache(*out[:3])
     return before, want, (got, *out[3:6]), tables, active

@@ -257,9 +257,8 @@ def test_b_prefill_then_paged_decode_matches_the_reference_at_every_position(
     cfg, cache, tables, pos, first, _ = _paged_setup(dev, prompt, lane=lane)
     tok = np.zeros((4,), np.int32)
     tok[lane] = first
-    rngs = jax.random.split(jax.random.PRNGKey(1), 4)
-    k, v, scales, *_, toks, stats, _lane = generation._paged_decode_chunk_jit(
-        dev, cache["k"], None, None, tables, tok, pos, active, rngs,
+    k, v, scales, *_, toks, stats, _lane, _next = generation._paged_decode_chunk_jit(
+        dev, cache["k"], None, None, tables, tok, pos, active, np.uint32(1),
         np.zeros((4,), np.float32), np.zeros((4,), np.int32),
         cfg_key=tuple(sorted(cfg.items())), family="mla_moe_lm", chunk=4,
         page_tokens=PT, kernel=kernel)

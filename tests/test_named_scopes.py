@@ -46,7 +46,7 @@ def test_decode_chunk_program_carries_every_scope(model):
         params, cache["k"], cache["v"], None,
         np.zeros((lanes, 4), np.int32), np.zeros(lanes, np.int32),
         np.zeros(lanes, np.int32), np.ones(lanes, bool),
-        jax.random.split(jax.random.PRNGKey(1), 2),
+        np.uint32(1),
         np.zeros(lanes, np.float32), np.zeros(lanes, np.int32),
         cfg_key=cfg_key, chunk=2, page_tokens=pt, kernel=False,
     ))

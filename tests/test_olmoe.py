@@ -183,9 +183,8 @@ def test_b_prefill_then_paged_decode_matches_the_reference_at_every_position():
     cfg, cache, tables, pos, first, _ = _paged_setup(dev, prompt)
     tok = np.zeros((4,), np.int32)
     tok[0] = first
-    rngs = jax.random.split(jax.random.PRNGKey(1), 4)
-    *_, toks, stats, _lane = generation._paged_decode_chunk_jit(
-        dev, cache["k"], cache["v"], None, tables, tok, pos, active, rngs,
+    *_, toks, stats, _lane, _next = generation._paged_decode_chunk_jit(
+        dev, cache["k"], cache["v"], None, tables, tok, pos, active, np.uint32(1),
         np.zeros((4,), np.float32), np.zeros((4,), np.int32),
         cfg_key=tuple(sorted(cfg.items())), family="moe_lm", chunk=4,
         page_tokens=PT, kernel=False)
@@ -510,7 +509,7 @@ def test_expert_layer_scopes_split_the_ffn_in_the_decode_chunk_and_in_apply():
     has = scoped(generation._paged_decode_chunk_jit.lower(
         params, cache["k"], cache["v"], None, np.zeros((2, 16), np.int32),
         np.zeros(2, np.int32), np.zeros(2, np.int32), np.ones(2, bool),
-        jax.random.split(jax.random.PRNGKey(1), 2), np.zeros(2, np.float32),
+        np.uint32(1), np.zeros(2, np.float32),
         np.zeros(2, np.int32), cfg_key=tuple(sorted(cfg.items())),
         family="moe_lm", chunk=2, page_tokens=4, kernel=False))
     for path in ("layer/ffn/route", "layer/ffn/experts", "layer/attn", "lm_head"):

@@ -813,7 +813,7 @@ def test_decode_chunk_time_does_not_follow_the_arena_on_tpu():
     pos = np.zeros((lanes,), np.int32)
     pos[:len(live)] = live
     active = np.arange(lanes) < len(live)
-    rngs = jax.random.split(jax.random.PRNGKey(1), chunk)
+    counter = np.uint32(1)
     ms = {}
     for n_pages in (512, 4096):
         cache = generation.init_paged_cache(cfg, n_pages + 1, pt)
@@ -822,8 +822,8 @@ def test_decode_chunk_time_does_not_follow_the_arena_on_tpu():
         best = float("inf")
         for _ in range(6):                   # the first call compiles
             t0 = time.perf_counter()
-            k, v, _, _, _, toks, _, _ = generation._paged_decode_chunk_jit(
-                params, k, v, None, tables, tok, pos, active, rngs,
+            k, v, _, _, _, toks, _, _, _ = generation._paged_decode_chunk_jit(
+                params, k, v, None, tables, tok, pos, active, counter,
                 np.zeros((lanes,), np.float32), np.zeros((lanes,), np.int32),
                 cfg_key=tuple(sorted(cfg.items())), chunk=chunk,
                 page_tokens=pt, kernel=True)
@@ -861,7 +861,7 @@ def test_decode_chunk_write_follows_the_live_lanes_on_tpu(monkeypatch):
     params = _random_bf16_params("moe_lm", cfg)
     lanes, pt, chunk, n_pages = 32, 16, 8, 2048
     pps = cfg["max_seq"] // pt
-    rngs = jax.random.split(jax.random.PRNGKey(1), chunk)
+    counter = np.uint32(1)
     live_lanes = generation._live_lanes
     ms = {}
     for write in ("live", "every"):
@@ -888,8 +888,8 @@ def test_decode_chunk_write_follows_the_live_lanes_on_tpu(monkeypatch):
             best = float("inf")
             for _ in range(6):                   # the first call compiles
                 t0 = time.perf_counter()
-                k, v, _, _, _, toks, _, _ = generation._paged_decode_chunk_jit(
-                    params, k, v, None, tables, tok, pos, active, rngs,
+                k, v, _, _, _, toks, _, _, _ = generation._paged_decode_chunk_jit(
+                    params, k, v, None, tables, tok, pos, active, counter,
                     np.zeros((lanes,), np.float32), np.zeros((lanes,), np.int32),
                     cfg_key=tuple(sorted(cfg.items())), family="moe_lm",
                     chunk=chunk, page_tokens=pt, kernel=True)

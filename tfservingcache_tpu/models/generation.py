@@ -1013,7 +1013,7 @@ def _paged_decode_chunk_jit(
     tok,                 # (S,) last sampled token per lane
     pos,                 # (S,) i32 write position per lane
     active,              # (S,) bool — frozen for the whole chunk
-    rngs,                # (chunk, 2) uint32 — one PRNG key per step
+    counter,             # () uint32 — the chunk's number in its state's key stream
     temperature,         # (S,) f32 per-lane
     top_k,               # (S,) i32 per-lane
     lane_state=None,     # (lane layers, S, rows, width) | None — donated
@@ -1033,21 +1033,31 @@ def _paged_decode_chunk_jit(
     page; the host ignores their emitted tokens. Admission/retirement happen on the
     host BETWEEN chunks; a row finishing mid-chunk keeps decoding from its
     own EOS until the chunk ends (the < chunk overshoot the wasted-steps
-    counter measures). ``tables`` is traced (a tiny
-    (S, pages_per_slot) i32 H2D copy per chunk), so recycling pages never
+    counter measures). ``tables`` is traced, so recycling pages never
     mints a new program; compiled-program count stays one per chunk size
     (x2 for the ``kernel`` boolean — the serving.kv_paged_kernel gate).
 
-    The output before the last is the chunk's routing stats for a model with
-    expert layers: float32 ``(experts_hit, expert_rows_max,
+    The small operands (``tables`` … ``top_k``) may be NumPy mirrors or device
+    arrays the caller kept from an earlier chunk (``runtime.slot_decode_chunk``
+    sends one only when its mirror changed; ``tok`` / ``pos`` are the last
+    chunk's own outputs). The chunk's keys, one a step, are derived HERE from
+    ``counter``: ``jax.random.split(jax.random.PRNGKey(counter), chunk)``, bit
+    for bit what a host made of the same number, so no key program runs
+    between two chunks; the last output is ``counter + 1``, the next chunk's
+    operand, so the counter need not be sent either.
+
+    The output after the tokens is the chunk's routing stats for a model
+    with expert layers: float32 ``(experts_hit, expert_rows_max,
     expert_rows_local)``, each a mean over the chunk's steps and the layers
     that hold experts, computed by the program and fetched with the tokens;
     ``None`` (no output at all) for a dense model, whose program is therefore
-    the one it was. The last is ``lane_state`` after the chunk (donated like
+    the one it was. The next is ``lane_state`` after the chunk (donated like
     the arena and carried by the same scan; an inactive lane's slice comes
-    back bit for bit), ``None`` in and out for a model that keeps rows only."""
+    back bit for bit), ``None`` in and out for a model that keeps rows only;
+    ``counter + 1`` follows it."""
     cfg = dict(cfg_key)
     live = _live_lanes(active)       # once a chunk: ``active`` is frozen
+    rngs = jax.random.split(jax.random.PRNGKey(counter), chunk)
 
     def step(carry, rng):
         cache, tok, pos = carry
@@ -1072,7 +1082,7 @@ def _paged_decode_chunk_jit(
         stats = jnp.mean(stats, axis=0)
     return (*_cache_arena(cache), tok, pos,
             jnp.transpose(toks, (1, 0)), stats,  # (S, chunk), (3,) | None
-            cache.get("lane"))
+            cache.get("lane"), counter + 1)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -1243,6 +1243,8 @@ class _ContinuousScheduler:
             # round (or a runtime that keeps no such clock) leaves an older
             # time there and records 0
             launch_s=max(0.0, getattr(state, "launched_t", 0.0) - chunk_t0),
+            # what a plain chunk's launch had to send the device
+            uploads=getattr(state, "uploads", 0) if accept is None else 0,
         )
         return state
 
@@ -1250,13 +1252,13 @@ class _ContinuousScheduler:
         self, state, chunk, active, admitted, retired, wasted, step_t0,
         prefix_hits=0, prefill_s=0.0, tokens_in=0,
         drafted=0, accepted=0, emitted=None, chunk_s=0.0, emit_s=0.0,
-        write_lanes=0, launch_s=0.0,
+        write_lanes=0, launch_s=0.0, uploads=0,
     ) -> None:
         """One flight-recorder ring entry per chunk boundary (``step_ms``
         split into the prefill clocks ``_step`` already keeps, the decode
         chunk and the emission loop; the rest is the engine's own;
         ``launch_s`` is the part of ``chunk_s`` before the device had the
-        chunk), plus the
+        chunk, ``uploads`` the operands that launch sent), plus the
         oldest-queued-age gauge (`gen_admission_wait` only observes at
         admission — a row starved behind page exhaustion is invisible there
         until it finally admits; this gauge shows it starving)."""
@@ -1315,7 +1317,7 @@ class _ContinuousScheduler:
             emit_ms=emit_s * 1e3,
             experts_hit=moe_stats[0], expert_rows_max=moe_stats[1],
             expert_rows_local=moe_stats[2], write_lanes=write_lanes,
-            launch_ms=launch_s * 1e3,
+            launch_ms=launch_s * 1e3, uploads=uploads,
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

@@ -682,7 +682,10 @@ class SlotDecodeState:
     ``block_tables`` row; the free-list hands pages out at admission and
     recycles them at retirement. The host mirrors (tok/pos/active/temps/
     topks, block tables, free-list) are owned by the engine's scheduler
-    thread; the runtime only reads them to build chunk inputs.
+    thread, which writes them in many places; the runtime reads them at a
+    chunk's launch and sends the device only those that differ from what it
+    last sent (``resident``: a comparison of values, not flags a writer
+    could forget).
     ``lane_state`` holds what the model's layers with a fixed state keep
     (``registry.LaneState``), one slice a lane beside the arena; an admission
     overwrites its lane's slice, so retirement needs no device work."""
@@ -699,7 +702,15 @@ class SlotDecodeState:
     active: np.ndarray               # (S,) bool
     temps: np.ndarray                # (S,) f32 per-lane temperature
     topks: np.ndarray                # (S,) i32 per-lane top_k
-    chunk_counter: int = 0           # host-side PRNG stream for chunk keys
+    # the chunks' key stream: chunk number n draws from
+    # split(PRNGKey(n), chunk), derived inside the chunk's program
+    chunk_counter: int = 0
+    # the decode chunk's small operands as the device last had them, by name
+    # (CHUNK_OPERANDS and "counter"): (device array, the host values it
+    # holds). For tok / pos / counter the array is the last chunk's own output
+    resident: dict = field(default_factory=dict)
+    # how many of them the last chunk's launch had to upload (ring ``uploads``)
+    uploads: int = 0
     # time.monotonic() at which the last decode chunk's program call returned
     # its futures: the end of the chunk's launch path (ring ``launch_ms``)
     launched_t: float = 0.0
@@ -903,6 +914,51 @@ def _check_trash_unreachable(state: SlotDecodeState) -> None:
                 f"block-table slot {bad} below pos={int(state.pos[lane])} "
                 f"(live pages={live}) — attention would read junk KV"
             )
+
+
+# the mirrors a decode chunk takes between the arena and its counter, in the
+# program's argument order
+CHUNK_OPERANDS = ("block_tables", "tok", "pos", "active", "temps", "topks")
+
+
+def _counter_word(n: int) -> np.uint32:
+    """Chunk number ``n`` as the program takes it: its low 32 bits, which is
+    all ``jax.random.PRNGKey`` ever read of a Python int."""
+    return np.uint32(n & 0xFFFFFFFF)
+
+
+def _chunk_operands(state: SlotDecodeState) -> list:
+    """The decode chunk's small operands for this launch, ``CHUNK_OPERANDS``
+    then the counter. Where the arena lives on ONE device, each is the device
+    array ``state.resident`` kept if the mirror still holds the values that
+    array was made from, else a fresh upload (recorded with a copy of the
+    values: the engine writes its mirrors in place); ``state.uploads`` counts
+    the uploads. So a decode-only boundary sends nothing, a retirement
+    ``active`` and the tables (whose row ``release_pages`` zeroes), an
+    admission what it wrote, and a write made anywhere else (park, resume,
+    preemption, a speculation round, a test) is seen like any other. On a
+    mesh the mirrors go as they are, as before: an operand kept from the
+    program's outputs would carry the placement the compiler chose for that
+    output, another program's signature."""
+    import jax
+
+    mirrors = [(name, getattr(state, name)) for name in CHUNK_OPERANDS]
+    mirrors.append(("counter", _counter_word(state.chunk_counter)))
+    devices = state.k.devices()
+    if len(devices) != 1:
+        state.resident.clear()
+        state.uploads = len(mirrors)
+        return [host for _name, host in mirrors]
+    stale = [(name, np.array(host)) for name, host in mirrors
+             if (kept := state.resident.get(name)) is None
+             or not np.array_equal(kept[1], host)]
+    if stale:
+        # one call for all of them: a device_put costs the host 0.3 ms
+        sent = jax.device_put([host for _name, host in stale], *devices)
+        state.resident.update(
+            (name, (array, host)) for (name, host), array in zip(stale, sent))
+    state.uploads = len(stale)
+    return [state.resident[name][0] for name, _host in mirrors]
 
 
 def _mesh_serialized(fn):
@@ -2855,37 +2911,42 @@ class TPUModelRuntime(BaseRuntime):
         )
 
         # the launch path: everything the host does before the device has the
-        # chunk (residency lookup, the key programs, the mirrors' conversion,
-        # the call). The device stands still for it unless an admission's
-        # programs are still running
+        # chunk: the residency lookup, a comparison a mirror, an upload of
+        # each mirror a boundary changed (none on a decode-only one), ONE
+        # program call (the chunk derives its own keys). The device stands
+        # still for it unless an admission's programs are still running
         with host_span("chunk_launch"):
             loaded = self._resident.get(state.model_id)
             if loaded is None:
                 raise ModelNotLoadedError(
                     f"model {state.model_id} is not loaded")
             state.chunk_counter += 1
-            rngs = jax.random.split(
-                jax.random.PRNGKey(state.chunk_counter), chunk
-            )
             if _PAGECHECK:
                 _check_trash_unreachable(state)
+            tables, tok, pos, active, temps, topks, counter = _chunk_operands(
+                state)
             (state.k, state.v, state.scales, tok, pos,
-             toks, stats, state.lane_state) = _paged_decode_chunk_jit(
+             toks, stats, state.lane_state, counter) = _paged_decode_chunk_jit(
                 loaded.params, state.k, state.v, state.scales,
-                np.asarray(state.block_tables, np.int32),
-                state.tok, state.pos, state.active, rngs,
-                state.temps, state.topks, state.lane_state,
+                tables, tok, pos, active, counter, temps, topks,
+                state.lane_state,
                 cfg_key=state.cfg_key, family=state.family, chunk=chunk,
                 page_tokens=state.page_tokens, kernel=state.kernel,
             )
         state.launched_t = time.monotonic()
-        # np.array (not asarray): device_get hands back READ-ONLY views and
-        # the scheduler writes these mirrors at the next admission
         # one fetch: an expert model's routing numbers ride with the tokens
         with host_span("chunk_fetch"):
-            tok, pos, toks, stats = jax.device_get((tok, pos, toks, stats))
-        state.tok = np.array(tok, dtype=np.int32)
-        state.pos = np.array(pos, dtype=np.int32)
+            tok_h, pos_h, toks, stats = jax.device_get((tok, pos, toks, stats))
+        if state.resident:
+            # the next chunk's tok / pos / counter are this chunk's outputs,
+            # where they are; the fetched values are what they hold
+            state.resident.update(
+                tok=(tok, tok_h), pos=(pos, pos_h),
+                counter=(counter, _counter_word(state.chunk_counter + 1)))
+        # np.array (not asarray): device_get hands back READ-ONLY views and
+        # the scheduler writes these mirrors at the next admission
+        state.tok = np.array(tok_h, dtype=np.int32)
+        state.pos = np.array(pos_h, dtype=np.int32)
         state.moe_stats = None if stats is None else tuple(
             float(x) for x in stats)
         return np.asarray(toks)

@@ -100,11 +100,20 @@ STEP_FIELDS = (
     "write_lanes",
     # appended field (ISSUE 35 the chunk's launch path): the part of chunk_ms
     # the host spent BEFORE the device had the chunk (residency lookup, the
-    # key programs, the argument upload, the program call), host clock; the
-    # chip stands still for it unless an admission's programs still run, and
+    # upload of the operands a boundary changed, the program call), host
+    # clock; the chip stands still for it unless an admission's programs still run, and
     # chunk_ms - launch_ms is the wait for the device plus the fetch. 0.0 for
     # a spec round and for a boundary that ran no chunk
     "launch_ms",
+    # appended field (ISSUE 36 resident operands): how many of the decode
+    # chunk's seven small operands (block tables, tok, pos, active, temps,
+    # topks, the counter) this chunk's launch had to upload because a mirror
+    # no longer held what the device had: 0 on a decode-only boundary, 2
+    # (``active`` and the freed table row) after a retirement, what an
+    # admission wrote after one, all seven on a state's first chunk and on a
+    # mesh. 0 for a spec round and
+    # for a boundary that ran no chunk
+    "uploads",
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -117,7 +126,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 24:
+    if len(e) == 25:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -128,7 +137,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "prefill_ms": e[16], "chunk_ms": e[17], "emit_ms": e[18],
             "experts_hit": e[19], "expert_rows_max": e[20],
             "expert_rows_local": e[21], "write_lanes": e[22],
-            "launch_ms": e[23],
+            "launch_ms": e[23], "uploads": e[24],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -289,6 +298,7 @@ class FlightRecorder:
         expert_rows_local: float = 0.0,
         write_lanes: int = 0,
         launch_ms: float = 0.0,
+        uploads: int = 0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -298,6 +308,7 @@ class FlightRecorder:
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
             round(experts_hit, 3), round(expert_rows_max, 3),
             round(expert_rows_local, 3), write_lanes, round(launch_ms, 4),
+            uploads,
         ))
 
     def note_phases(

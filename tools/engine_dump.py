@@ -68,6 +68,10 @@ def _fmt_step(s: dict) -> str:
         # the chunk's launch path (ISSUE 35): the part of chunk= before
         # the device had the chunk; absent in dumps of rings up to 23 fields
         launch = f"launch={s['launch_ms']:.2f} " if "launch_ms" in s else ""
+        # what that launch had to upload (ISSUE 36); rings up to 24 fields
+        # have none
+        if "uploads" in s:
+            launch += f"uploads={s['uploads']} "
         split = (f"(prefill={pre:.2f} chunk={chunk:.2f} {launch}emit={emit:.2f} "
                  f"self={own:.2f}) ")
     # expert routing (ISSUE 25); absent in older dumps, 0 for dense models
