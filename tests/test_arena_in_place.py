@@ -28,7 +28,10 @@ program holds no layer- or arena-sized result but the scatters', and its
 temporaries stay those of the program that writes every lane. It runs for a
 head-128 decoder and, since PR 34, for the LFM2 cell's attention (8 KV heads
 of 64, stored two a 128-lane row): the kernel is in the program and the arena
-is row-major wherever it appears.
+is row-major wherever it appears. Since PR 39 it also runs for a model with
+WINDOW layers: both arenas are donated and carried, neither is copied, both
+kernels are in the program, and that model's fresh prefill holds the windowed
+flash kernel and no score block.
 """
 
 import functools
@@ -209,6 +212,19 @@ def _compile_only_models():
             "n_experts": 8, "top_k": 4, "norm_topk_prob": True,
             "route_score": "sigmoid", "route_norm_eps": 1e-6, "max_seq": 4096,
             "rope_theta": 1e6, "dtype": "bfloat16"}, 8193),
+        # the Mellum2 cell's attention (``mellum2-codectx-mixed``: 4 KV heads
+        # of 128 that the hidden size does not give, three window layers of
+        # 1024 to a global one, a rotary a kind) over the cell's arenas: 16385
+        # pages for the global layer, 32 lanes x 65 pages a window layer
+        "window": ("moe_lm", {
+            "vocab_size": 4096, "d_model": 1024, "n_layers": 4, "n_heads": 8,
+            "n_kv_heads": 4, "head_dim": 128, "d_ff": 256, "n_experts": 8,
+            "top_k": 2, "norm_topk_prob": True, "tie_embeddings": False,
+            "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+            "sliding_window": 1024, "max_seq": 8192, "rope_theta": 5e5,
+            "rope_full": {"yarn": 16.0, "original_max": 8192, "beta_fast": 32.0,
+                          "beta_slow": 1.0, "attention_factor": 1.2772588722239782},
+            "rms_eps": 1e-6, "dtype": "bfloat16"}, 16385),
     }
 
 
@@ -240,7 +256,9 @@ def _compile_for_v5e_main(case="dense"):
     md = build(family, config)
     cfg = dict(static_config(md))
     cache = jax.eval_shape(
-        lambda: generation.init_paged_cache(cfg, n_pages, pt, row=md.cache_row))
+        lambda: generation.init_paged_cache(cfg, n_pages, pt, row=md.cache_row,
+                                            lanes=lanes))
+    ring = (cache["wk"], cache["wv"]) if "wk" in cache else None
     s = jax.ShapeDtypeStruct
     # weights as a server holds them: in the model's dtype
     params = jax.tree_util.tree_map(
@@ -251,10 +269,11 @@ def _compile_for_v5e_main(case="dense"):
             s((lanes, cfg["max_seq"] // pt), jnp.int32), lane, lane,
             s((lanes,), jnp.bool_), s((), jnp.uint32),
             s((lanes,), jnp.float32), lane,
-            jax.eval_shape(lambda: generation.init_lane_state(cfg, lanes)))
+            jax.eval_shape(lambda: generation.init_lane_state(cfg, lanes)), ring)
     args = jax.tree_util.tree_map(
         lambda a: s(a.shape, a.dtype, sharding=one), args)
-    layer_elems = cache["k"].size // cache["k"].shape[0]
+    # a layer of the SMALLER arena: a copy of either shows
+    layer_elems = min(a.size // a.shape[0] for a in (cache["k"], *(ring or ())))
     live_lanes = generation._live_lanes
     for name, form in (("live", live_lanes), ("every", lambda active: None)):
         generation._live_lanes = form
@@ -265,6 +284,10 @@ def _compile_for_v5e_main(case="dense"):
         hlo = compiled.as_text()
         layouts = set(re.findall(
             r"\[%s\](\{[0-9,]*)" % ",".join(map(str, cache["k"].shape)), hlo))
+        if ring:
+            layouts |= set(re.findall(
+                r"\[%s\](\{[0-9,]*)" % ",".join(map(str, ring[0].shape)), hlo))
+            assert "paged_window_decode_kernel" in hlo, "no window kernel"
         print("COMPILED", name,
               "temp", compiled.memory_analysis().temp_size_in_bytes,
               "kernel", int("paged_decode_attention_kernel" in hlo),
@@ -272,9 +295,23 @@ def _compile_for_v5e_main(case="dense"):
               "large", json.dumps(_large_results(hlo, layer_elems)),
               "layouts", json.dumps(sorted(layouts)))
     print("LAYER_BYTES", layer_elems * 2, "ARENA", json.dumps(cache["k"].shape))
+    if ring:
+        # the same model's fresh prefill of 2048 tokens: through the attention
+        # gate in every layer, so no score block
+        tokens = 2048
+        args = (params, s((1, tokens), jnp.int32), s((1,), jnp.int32),
+                s((2,), jnp.uint32), s((), jnp.float32), s((), jnp.int32))
+        args = jax.tree_util.tree_map(
+            lambda a: s(a.shape, a.dtype, sharding=one), args)
+        compiled = generation._slot_prefill_jit.lower(
+            *args, cfg_key=static_config(md), family=family).compile()
+        hlo = compiled.as_text()
+        print("PREFILL temp", compiled.memory_analysis().temp_size_in_bytes,
+              "window_kernels", len(re.findall(r"flash_window_kernel", hlo)),
+              "score_block", cfg["n_heads"] * tokens * tokens * 4)
 
 
-@pytest.mark.parametrize("case", ["dense", "hybrid_head64"])
+@pytest.mark.parametrize("case", ["dense", "hybrid_head64", "window"])
 def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy(case):
     """The decode chunk compiled for a v5e, off the chip: every layer- or
     arena-sized result is a scatter's (in place on the donated arena), inside
@@ -290,7 +327,14 @@ def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy(case):
     was kept with the PAGES minor and converted whole, in and out, every
     chunk), and the temporaries are far under one layer of the arena (the
     parent's gather of every table slot of every lane was a layer a side:
-    2.2 GB over the cell's 3 layers)."""
+    2.2 GB over the cell's 3 layers).
+
+    ``window`` (PR 39): a model with window layers. Both arenas ride in
+    donated and come out of scatters alone (no copy of a layer of EITHER),
+    both are row-major wherever they appear, the window call's kernel is in
+    the program beside the global one's, and the same model's fresh prefill
+    of 2048 tokens holds the windowed flash kernel in its window layers and
+    temporaries far under one ``(heads, S, S)`` float32 score block."""
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
     env.update(_COMPILE_ONLY_ENV)
@@ -324,3 +368,11 @@ def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy(case):
     if case == "hybrid_head64":
         assert arena == [2, 8193, 4, 16, 128]
         assert temp["live"] < layer_bytes // 4, (temp, layer_bytes)
+    if case == "window":
+        assert arena == [1, 16385, 4, 16, 128]           # the ONE global layer
+        assert layer_bytes == 32 * 65 * 4 * 16 * 128 * 2   # a window layer's ring
+        m = re.search(r"PREFILL temp (\d+) window_kernels (\d+) score_block (\d+)",
+                      r.stdout)
+        assert m, (r.stdout[-3000:], r.stderr[-3000:])
+        assert int(m.group(2)) >= 3, m.group(0)
+        assert int(m.group(1)) < int(m.group(3)) // 2, m.group(0)

@@ -11,7 +11,10 @@ the device (ISSUE 36):
         a write to a mirror by ANY of its writers is sent before the next
         chunk; the ring carries the count; a mesh uploads as before;
   (iv)  a CPU profiler capture of back-to-back chunks holds no program
-        execution and no upload between them but the chunk.
+        execution and no upload between them but the chunk;
+  (v)   a model with WINDOW layers (ISSUE 39) brings no operand of its own:
+        what a window call reads of a lane's ring is worked out from ``pos``
+        inside the program, so its decode-only boundary uploads nothing too.
 """
 
 import itertools
@@ -408,3 +411,56 @@ def test_d_a_capture_holds_one_program_and_no_upload_a_decode_only_launch(tmp_pa
     assert counted[0] >= 3 and counted[1:] == [0, 0]
     assert [len(uploads(*span)) for span in launches] == counted
     assert uploads(launches[0][1], launches[2][1]) == []
+
+
+# -- (v) a window model's decode-only boundary --------------------------------------
+
+WINDOWED = {
+    "vocab_size": 97, "d_model": 48, "n_layers": 4, "n_heads": 4, "n_kv_heads": 2,
+    "head_dim": 16, "d_ff": 32, "n_experts": 4, "top_k": 2, "norm_topk_prob": True,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+    "sliding_window": 16, "max_seq": 128, "rope_theta": 10000.0,
+    "rope_full": {"yarn": 4.0, "original_max": 32, "attention_factor": 1.1},
+    "dtype": "float32"}
+
+
+def test_e_a_window_models_decode_only_boundary_uploads_nothing(tmp_path,
+                                                                 monkeypatch):
+    """Two lanes decoding through several turns of their rings (window 16,
+    pages of 8: a ring of 3 pages): the first chunk sends all seven operands,
+    every decode-only one after it none, and no chunk takes an operand beyond
+    the seven and the ring arena itself. The ring field says what a window
+    call read."""
+    export_artifact("moe_lm", str(tmp_path), name="windowed", version=1,
+                    config=WINDOWED)
+    rt = TPUModelRuntime(ServingConfig(platform="cpu"), None)
+    mid = ModelId("windowed", 1)
+    rt.ensure_loaded(Model(identifier=mid, path=str(tmp_path / "windowed" / "1")))
+    try:
+        state = rt.slot_decode_state(mid, 3, page_tokens=PT, arena_pages=48)
+        assert state.window is not None and state.ring_pages == 3
+        for lane, prompt in enumerate((np.arange(1, 8), np.arange(3, 43))):
+            assert state.reserve_pages(lane, 120)
+            tok, pk, pv, _hit = rt.slot_prefill(mid, prompt, 0.0, 0, seed=1)
+            rt.slot_admit(state, lane, pk, pv)
+            state.tok[lane], state.pos[lane], state.active[lane] = (
+                tok, len(prompt), True)
+        calls = []
+        real = generation._paged_decode_chunk_jit
+        monkeypatch.setattr(generation, "_paged_decode_chunk_jit",
+                            lambda *a, **kw: calls.append(a) or real(*a, **kw))
+        rt.slot_decode_chunk(state, 4)
+        assert state.uploads == len(NAMES)      # a state's first chunk sends all
+        for _ in range(12):                      # 48 steps: the rings turn twice
+            before = {name: state.resident[name][0] for name in NAMES}
+            rt.slot_decode_chunk(state, 4)
+            assert state.uploads == 0
+            for name, got in zip(("block_tables", "tok", "pos", "active",
+                                  "counter", "temps", "topks"), calls[-1][4:11]):
+                assert got is before[name], name
+        # params, the global arena (k, v, scales), the seven, the lane state
+        # (None), the ring arena: nothing else goes in
+        assert len(calls[-1]) == 13 and calls[-1][11] is None
+        assert isinstance(calls[-1][12], tuple) and len(calls[-1][12]) == 2
+    finally:
+        rt.close()

@@ -522,7 +522,7 @@ def test_d_families_of_one_kind_declare_what_they_declared(family):
     assert "layer_state" not in key and key["cache_row"] == model.cache_row
     assert generation.init_lane_state(key, 4) is None
     assert generation._layer_slots(key) == [
-        (False, i) for i in range(model.config["n_layers"])]
+        (False, i, i, 0) for i in range(model.config["n_layers"])]
 
 
 # -- (e) through the engine ----------------------------------------------------

@@ -561,6 +561,8 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_gen_admission_wait_seconds",
     "tpusc_gen_kv_arena_bytes",
     "tpusc_lane_state_bytes",
+    "tpusc_kv_arena_bytes",
+    "tpusc_gen_window_rows_dropped",
     "tpusc_gen_kv_page_waste_tokens",
     "tpusc_gen_kv_pages_shared",
     "tpusc_gen_kv_pages_total",

@@ -9,7 +9,13 @@ What a layer keeps is the ModelDef's to say (``registry.static_config`` puts
 programs' config): a layer with a ``CacheRow`` has a layer of the cache or
 the arena (``_layer_slots``: the arena has as many layers as the model has
 such layers), a layer with a ``LaneState`` has a slice of the ``lane`` array
-that rides in the same cache dict, ``(lane layers, lanes, rows, width)``.
+that rides in the same cache dict, ``(lane layers, lanes, rows, width)``. A
+layer whose ``CacheRow`` has a ``window`` keeps one window of pages a lane:
+in the dense cache it is a layer like any other (every row kept, the mask
+applied), in the paged arena it has a layer of a SECOND arena beside the
+global one, ``wk`` / ``wv`` ``(window layers, lanes x ring pages, heads,
+page_tokens, width)``, in which a lane owns its ring of pages for life
+(``_layer_slots``: a ``Slot``'s ``arena`` and ``window``).
 
 No reference counterpart (the reference proxies opaque Predict calls —
 SURVEY.md §5); generation is where a TPU-native LM server must not re-run
@@ -32,7 +38,7 @@ from __future__ import annotations
 import functools
 import math
 import warnings
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +64,7 @@ from tfservingcache_tpu.models.transformer_lm import (
     _output_logits,
     _qkv,
     _rmsnorm,
+    rope_of,
 )
 from tfservingcache_tpu.ops.attention import pack_rows, unpack_pages
 
@@ -78,27 +85,54 @@ def _cache_row(cfg) -> CacheRow:
     return cfg.get("cache_row") or kv_cache_row(cfg)
 
 
-def _layer_slots(cfg) -> list[tuple[bool, int]]:
-    """For each layer of the model ``(keeps a lane state, its index)``: the
-    index into the ``lane`` array for a layer with a ``LaneState``, into the
-    cache's or the arena's layers for a layer with a ``CacheRow``. A config
-    with no ``layer_state`` (every family of one kind) has a cache layer a
-    model layer, in order."""
+class Slot(NamedTuple):
+    """What one layer of the model keeps, and where (``_layer_slots``)."""
+    lane: bool    # a ``LaneState`` (no rows)
+    index: int    # into the ``lane`` array, or into the DENSE cache's layers
+    arena: int    # a row layer's index into ITS paged arena (``index`` again
+                  # for a lane state): the ring arena's layers for a window
+                  # layer, the global arena's for every other row layer
+    window: int   # rows a lane keeps in the paged arena; 0 = every row
+
+
+def _layer_slots(cfg) -> list[Slot]:
+    """One ``Slot`` a layer of the model. A config with no ``layer_state``
+    (every family of one kind) has a cache layer a model layer, in order, and
+    the arena's index is the dense cache's. THE reader of ``layer_state``:
+    which layers keep a window is derived here and nowhere else in the
+    programs."""
     kinds = cfg.get("layer_state") or (None,) * int(cfg["n_layers"])
-    out, rows, lanes = [], 0, 0
+    out, rows, lanes, ring = [], 0, 0, 0
     for kind in kinds:
         if isinstance(kind, LaneState):
-            out.append((True, lanes))
+            out.append(Slot(True, lanes, lanes, 0))
             lanes += 1
-        else:
-            out.append((False, rows))
-            rows += 1
+            continue
+        window = getattr(kind, "window", 0)
+        out.append(Slot(False, rows, ring if window else rows - ring, window))
+        rows += 1
+        ring += bool(window)
     return out
+
+
+def _window_of(cfg) -> int:
+    """The window of the model's window layers, 0 for a model with none
+    (one window a model: the ring arena has one shape)."""
+    windows = {s.window for s in _layer_slots(cfg)} - {0}
+    if len(windows) > 1:
+        raise ValueError(f"window layers of several windows: {windows}")
+    return windows.pop() if windows else 0
+
+
+def window_rows(cfg) -> tuple[int, ...]:
+    """The window layers' indices among the model's ROW layers: where a
+    prefill's K/V (the dense cache's layers) holds their rows."""
+    return tuple(s.index for s in _layer_slots(cfg) if s.window)
 
 
 def _row_layers(cfg) -> int:
     """The model's layers that keep rows: the cache's and the arena's layers."""
-    return sum(not lane for lane, _ in _layer_slots(cfg))
+    return sum(not s.lane for s in _layer_slots(cfg))
 
 
 def _lane_layer(layer: dict, x, state, real_len, dtype, eps):
@@ -298,15 +332,16 @@ def _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family):
     rows the per-step mask keeps invisible until overwritten; a lane state is
     the one AT ``prompt_len``, which no pad token has touched. A latent family
     projects that one position through the head and no other (a long
-    prompt's ``S_pad x V`` float32 logits are a gigabyte at 8192 x 32768)."""
+    prompt's ``S_pad x V`` float32 logits are a gigabyte at 8192 x 32768), and
+    so does a model with window layers (3.2 GB at 8192 x 98304)."""
     b = input_ids.shape[0]
-    latent = _cache_row(cfg).sides == 1
+    one = _cache_row(cfg).sides == 1 or bool(_window_of(cfg))
     logits, cache = _forward_cached_dyn(
         params, input_ids, cache, jnp.zeros((b,), jnp.int32), cfg, family,
-        fresh=True, logits_at=prompt_len - 1 if latent else None,
+        fresh=True, logits_at=prompt_len - 1 if one else None,
         real_len=prompt_len,
     )
-    if latent:
+    if one:
         return logits[:, 0], cache
     return jnp.take_along_axis(
         logits, (prompt_len - 1)[:, None, None], axis=1)[:, 0], cache
@@ -462,7 +497,7 @@ def _sample_logits_jit(last, rng, temperature, top_k):
 
 def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
                      arena_dtype: str = "", mesh=None,
-                     row: CacheRow | None = None) -> dict:
+                     row: CacheRow | None = None, lanes: int = 0) -> dict:
     """Preallocated paged KV arena shared by every lane of one model's
     continuous-decode state: fixed-size pages instead of per-lane
     ``max_seq`` rows, so HBM is sized by tokens in flight, not worst case.
@@ -508,17 +543,37 @@ def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
     the row's shape alone. An odd number of heads, an int8 arena (its scales
     are a row a head), a one-sided arena, a mesh (the arena is sharded over
     its KV heads there and the kernel is off) and every other width keep
-    ``(heads, width)``."""
+    ``(heads, width)``.
+
+    **A model with WINDOW layers** (a ``CacheRow`` with a ``window`` in its
+    ``layer_state``) gets a second arena beside this one: ``wk`` / ``wv``
+    ``(window layers, lanes x R, heads, page_tokens, width)`` with ``R =
+    ops.attention.window_ring_pages(window, page_tokens)``, and ``k`` / ``v``
+    hold its GLOBAL layers only. Lane ``s`` owns pages ``s R .. s R + R - 1``
+    of every window layer for life and position ``p`` lives in its page ``(p
+    // page_tokens) % R``: no free list, no reservation, no host table, and
+    not one page more whatever a request's length. ``lanes`` is the state's
+    lane count (needed for such a model only). No int8 form and no mesh."""
+    from tfservingcache_tpu.ops.attention import window_ring_pages
+
     row = row or _cache_row(cfg)
     dtype = jnp.dtype(cfg["dtype"])
     heads, width = row.heads, row.width
+    window = _window_of(cfg)
+    if window and (arena_dtype == "int8" or mesh is not None or lanes < 1
+                   or row.sides != 2):
+        raise ValueError(
+            "window layers keep a ring of pages a lane: no int8 form, no "
+            f"mesh, K and V sides, and the lane count (got lanes={lanes})")
     if (row.sides == 2 and width == 64 and heads % 2 == 0
             and arena_dtype != "int8" and mesh is None):
         # THE place that decides a packed arena; every program learns it from
         # the array it is handed (``ops.attention.pack_rows``)
         heads, width = heads // 2, 128
-    # a layer of the arena a model layer that HAS pages (``_layer_slots``)
-    shape = (_row_layers(cfg), n_pages, heads, page_tokens, width)
+    # a layer of the arena a model layer that HAS pages (``_layer_slots``);
+    # a window layer's pages are the ring arena's
+    n_window = len(window_rows(cfg))
+    shape = (_row_layers(cfg) - n_window, n_pages, heads, page_tokens, width)
     if row.sides == 1:
         if arena_dtype == "int8":
             raise ValueError("a latent (one-sided) arena has no int8 form")
@@ -535,6 +590,11 @@ def init_paged_cache(cfg: dict, n_pages: int, page_tokens: int,
         if arena_dtype:
             dtype = jnp.dtype(arena_dtype)
         cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        if window:
+            ring = (n_window, lanes * window_ring_pages(window, page_tokens),
+                    heads, page_tokens, width)
+            cache["wk"] = jnp.zeros(ring, dtype)
+            cache["wv"] = jnp.zeros(ring, dtype)
     if mesh is not None:
         from tfservingcache_tpu.parallel.sharding import shard_kv_arena
 
@@ -576,6 +636,21 @@ def kv_write_lanes(active) -> int:
     if active.size <= _WRITE_GROUP:
         return active.size
     return min(active.size, int(_write_trips(int(active.sum()))) * _WRITE_GROUP)
+
+
+def window_pages_read(pos, active, chunk: int, window: int,
+                      page_tokens: int) -> float:
+    """Pages a window layer's decode call reads a live lane, mean over the
+    live lanes and the ``chunk`` steps, worked out on the host from the
+    ``pos`` / ``active`` mirrors the chunk is dispatched with (the ring's
+    ``window_pages``): the pages that hold tokens ``max(0, p - window + 1) ..
+    p`` at each step's position ``p``. 0.0 with no live lane."""
+    pos = np.asarray(pos, np.int64)[np.asarray(active, bool)]
+    if not pos.size:
+        return 0.0
+    p = pos[:, None] + np.arange(int(chunk))[None, :]
+    first = np.maximum(p - (int(window) - 1), 0)
+    return float(np.mean(p // page_tokens - first // page_tokens + 1))
 
 
 def _live_lanes(active):
@@ -721,6 +796,7 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
         paged_attention,
         paged_attention_verify,
         paged_latent_attention,
+        paged_window_attention,
     )
 
     dtype = jnp.dtype(cfg["dtype"])
@@ -740,17 +816,29 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     off = positions % page_tokens
 
     slots = _layer_slots(cfg)
-    if t_q != 1 and any(lane for lane, _ in slots):
+    if t_q != 1 and any(s.lane for s in slots):
         raise ValueError(
             f"{family}: a forward of {t_q} positions a lane over the paged "
             "arena (a speculative verify pass, a prefill chunk) does not "
             "carry a lane state")
+    windowed = any(s.window for s in slots)
+    if windowed and (t_q != 1 or "wk" not in cache):
+        raise ValueError(
+            f"{family}: a forward of {t_q} positions a lane over the paged "
+            "arena (a speculative verify pass, a prefill chunk) does not "
+            "turn a window layer's ring")
+    if windowed:
+        # a window layer's write goes to the lane's own ring, wherever its
+        # block table points: page (p // page_tokens) % R of the lane's R
+        ring_pages = cache["wk"].shape[1] // s_lanes
+        ring_at = (jnp.arange(s_lanes)[:, None] * ring_pages
+                   + (positions // page_tokens) % ring_pages)
     # a lane nobody reads keeps its state: it takes 0 of the step's 1 token
     took = None if active is None else active.astype(jnp.int32)
 
     with jax.named_scope("embed"):
         x = params["embed"][toks].astype(dtype)                  # (S, T, d)
-    for layer, (lane, li) in zip(params["layers"], slots):
+    for layer, (lane, _dense, li, window) in zip(params["layers"], slots):
         with jax.named_scope("layer"):
             if lane:
                 out, after = _lane_layer(
@@ -769,11 +857,19 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                     k, v = k[:, :, None], None                   # (S, T, 1, W)
                 else:
                     q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"])
-                    q = _rope_per_example(q, positions, cfg["rope_theta"])
-                    k = _rope_per_example(k, positions, cfg["rope_theta"])
+                    rope = rope_of(cfg, window)
+                    q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
+                    k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
                     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            cache = _paged_write_rows(cache, li, pages, off, k, v, live)
-            with jax.named_scope("attn"):
+            if window:
+                ring = _paged_write_rows(
+                    {"k": cache["wk"], "v": cache["wv"]}, li, ring_at, off,
+                    k, v, live)
+                cache = {**cache, "wk": ring["k"], "wv": ring["v"]}
+            else:
+                cache = _paged_write_rows(cache, li, pages, off, k, v, live)
+            with jax.named_scope("attn"), _kind_scope(
+                    windowed, "window" if window else "global"):
                 if row.sides == 1:
                     out = paged_latent_attention(
                         q, cache["k"], tables, pos, page_tokens,
@@ -784,7 +880,11 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                     operands = (q, cache["k"], cache["v"], tables, pos,
                                 page_tokens, cache.get("k_scale"),
                                 cache.get("v_scale"))
-                    if t_q == 1:
+                    if window:
+                        out = paged_window_attention(
+                            q, cache["wk"], cache["wv"], pos, page_tokens,
+                            window, kernel=kernel, active=active, layer=li)
+                    elif t_q == 1:
                         out = paged_attention(*operands, kernel=kernel,
                                               active=active, layer=li)
                     else:
@@ -792,10 +892,21 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                                                      layer=li)
                     out = out.reshape(s_lanes, cfg["n_heads"], t_q, row.width)
                     out = out.astype(x.dtype).transpose(0, 2, 1, 3)
-                    x = x + out.reshape(s_lanes, t_q, cfg["d_model"]) @ attn["wo"]
+                    # heads x head width: the hidden size for most models
+                    x = x + out.reshape(s_lanes, t_q, -1) @ attn["wo"]
             x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
                                moe_stats=moe_stats)
     return _output_logits(params, x, dtype, eps), cache
+
+
+def _kind_scope(windowed: bool, kind: str = "global"):
+    """The scope that tells a GLOBAL layer's attention from a window layer's
+    (``layer/attn/global`` beside ``layer/attn/window``) in a model that has
+    both; no scope at all in a model of one kind, whose programs keep the
+    names they had."""
+    import contextlib
+
+    return jax.named_scope(kind) if windowed else contextlib.nullcontext()
 
 
 @functools.partial(
@@ -836,14 +947,18 @@ def _paged_prefill_chunk_jit(params, arena_k, arena_v, scales, table_row,
     return (*_cache_arena(cache), last)
 
 
-def _arena_cache(arena_k, arena_v, scales, lane_state=None) -> dict:
+def _arena_cache(arena_k, arena_v, scales, lane_state=None,
+                 window=None) -> dict:
     """The arena as the jits take it (``k``, ``v`` or None for a one-sided
-    arena, the int8 arena's ``scales`` or None) and the lane states beside
-    it (None for a model whose layers all keep rows) -> the cache dict the
-    steps carry."""
+    arena, the int8 arena's ``scales`` or None), the lane states beside
+    it (None for a model whose layers all keep rows) and the window layers'
+    ring arena ``(wk, wv)`` (None for a model with no window layer) -> the
+    cache dict the steps carry."""
     cache = {"k": arena_k}
     if lane_state is not None:
         cache["lane"] = lane_state
+    if window is not None:
+        cache["wk"], cache["wv"] = window
     if arena_v is not None:
         cache["v"] = arena_v
     if scales is not None:
@@ -854,10 +969,16 @@ def _arena_cache(arena_k, arena_v, scales, lane_state=None) -> dict:
 
 def _cache_arena(cache: dict) -> tuple:
     """``_arena_cache``'s inverse -> (k, v | None, scales | None); a lane
-    state is ``cache.get("lane")``."""
+    state is ``cache.get("lane")``, the ring arena ``_cache_window``."""
     scales = ({"k": cache["k_scale"], "v": cache["v_scale"]}
               if "k_scale" in cache else None)
     return cache["k"], cache.get("v"), scales
+
+
+def _cache_window(cache: dict) -> tuple:
+    """The window layers' ring arena of a cache dict, ``(wk, wv)``; ``()``
+    (no output at all) for a model with no window layer."""
+    return ((cache["wk"], cache["wv"]),) if "wk" in cache else ()
 
 
 # ``fn`` over every side of an arena that exists: a one-sided (latent) arena's
@@ -865,30 +986,10 @@ def _cache_arena(cache: dict) -> tuple:
 _each_side = jax.tree_util.tree_map
 
 
-@functools.partial(
-    jax.jit, donate_argnums=(0, 1, 2), static_argnames=("page_tokens",)
-)
-@jax.named_scope("kv_write")
-def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
-                      page_tokens):
-    """Scatter one admitted request's prefill K/V (layers, 1, n_kv, P_pad,
-    hd) into its reserved pages: logical row ``r`` goes to page
-    ``table_row[r // page_tokens]`` offset ``r % page_tokens``. ``table_row``
-    is the lane's FULL (pages_per_slot,) block-table row — entries beyond
-    the reservation are 0, so prefill-pad rows past the reserved budget
-    (P_pad is a pow2 bucket and can overshoot it) land in the trash page.
-    Junk pad rows inside the reservation are never visible: a query at pos
-    p sees only rows <= p, and the decode step writes row p before
-    attending. ``base`` (traced i32) is
-    the shared-prefix boundary: rows < base belong to pages another lane /
-    the prefix index owns READ-ONLY, so their scatter is redirected to the
-    trash page — prefill stops at the shared boundary and only private
-    pages are written. base=0 is the plain unshared insert. One compile
-    per P_pad bucket, same bound as the prefill itself (base is data, not
-    a signature). ``scales`` is the int8 arena's {"k", "v"} per-row scale
-    buffers (donated; None for an arena in the model's dtype): prefill rows are
-    quantized here with the same per-row absmax discipline as the decode
-    write, so a page is bit-identical whether filled by prefill or steps."""
+def _insert_rows(arena_k, arena_v, scales, pk, pv, table_row, base,
+                 page_tokens: int):
+    """``_paged_insert_jit``'s scatter (its docstring says what goes where),
+    shared with ``_window_paged_insert_jit``'s global layers."""
     p_pad = pk.shape[3]
     pps = table_row.shape[0]
     rows = jnp.arange(p_pad)
@@ -919,6 +1020,84 @@ def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
     arena_k = arena_k.at[:, pages, :, offs, :].set(kv.astype(arena_k.dtype))
     arena_v = arena_v.at[:, pages, :, offs, :].set(vv.astype(arena_v.dtype))
     return arena_k, arena_v, scales
+
+
+@functools.partial(
+    jax.jit, donate_argnums=(0, 1, 2), static_argnames=("page_tokens",)
+)
+@jax.named_scope("kv_write")
+def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
+                      page_tokens):
+    """Scatter one admitted request's prefill K/V (layers, 1, n_kv, P_pad,
+    hd) into its reserved pages: logical row ``r`` goes to page
+    ``table_row[r // page_tokens]`` offset ``r % page_tokens``. ``table_row``
+    is the lane's FULL (pages_per_slot,) block-table row — entries beyond
+    the reservation are 0, so prefill-pad rows past the reserved budget
+    (P_pad is a pow2 bucket and can overshoot it) land in the trash page.
+    Junk pad rows inside the reservation are never visible: a query at pos
+    p sees only rows <= p, and the decode step writes row p before
+    attending. ``base`` (traced i32) is
+    the shared-prefix boundary: rows < base belong to pages another lane /
+    the prefix index owns READ-ONLY, so their scatter is redirected to the
+    trash page — prefill stops at the shared boundary and only private
+    pages are written. base=0 is the plain unshared insert. One compile
+    per P_pad bucket, same bound as the prefill itself (base is data, not
+    a signature). ``scales`` is the int8 arena's {"k", "v"} per-row scale
+    buffers (donated; None for an arena in the model's dtype): prefill rows are
+    quantized here with the same per-row absmax discipline as the decode
+    write, so a page is bit-identical whether filled by prefill or steps."""
+    return _insert_rows(arena_k, arena_v, scales, pk, pv, table_row, base,
+                        page_tokens)
+
+
+@functools.partial(
+    jax.jit, donate_argnums=(0, 1, 2, 3),
+    static_argnames=("page_tokens", "window_layers", "ring_pages"))
+@jax.named_scope("kv_write")
+def _window_paged_insert_jit(arena_k, arena_v, wk, wv, pk, pv, table_row, lane,
+                            prompt_len, *, page_tokens, window_layers,
+                            ring_pages):
+    """``_paged_insert_jit``'s sibling for a model with WINDOW layers: one
+    admitted request's prefill K/V ``(row layers, 1, n_kv, P_pad, hd)``, all
+    the model's row layers in order, into both arenas in one dispatch.
+
+    The global layers' rows (every row layer not in the static
+    ``window_layers``, indices into ``pk``) go to the lane's reserved pages
+    exactly as ``_paged_insert_jit`` puts them (no shared-prefix boundary:
+    such a model shares no prefix). Of each window layer's rows only the last
+    ``min(prompt_len, R x page_tokens)`` are stored, in lane ``lane``'s ring:
+    slot ``j`` of the ring's ``R x page_tokens`` holds the LAST prompt position
+    ``r < prompt_len`` with ``r % (R x page_tokens) == j`` (position ``p``
+    lives in page ``(p // page_tokens) % R``, offset ``p % page_tokens``), a
+    gather of the kept rows and one contiguous update of the lane's R pages.
+    Slots no prompt position maps to hold junk a decode step overwrites
+    before any query reads it. ``prompt_len`` is the TRUE length: the pow2
+    bucket's pad rows are never stored."""
+    keep = jnp.asarray(
+        [i for i in range(pk.shape[0]) if i not in window_layers], jnp.int32)
+    arena_k, arena_v, _ = _insert_rows(
+        arena_k, arena_v, None, pk[keep], pv[keep], table_row, jnp.int32(0),
+        page_tokens)
+    p_pad = pk.shape[3]
+    ring = ring_pages * page_tokens
+    slot = jnp.arange(ring)
+    plen = prompt_len.astype(jnp.int32)
+    # the last prompt position that lives in each slot of the ring
+    src = slot + (jnp.maximum(plen - 1 - slot, 0) // ring) * ring
+    src = jnp.clip(jnp.where(slot < plen, src, 0), 0, p_pad - 1)
+    at = (0, lane.astype(jnp.int32) * ring_pages, 0, 0, 0)
+    take = jnp.asarray(window_layers, jnp.int32)
+
+    def lane_ring(rows, arena):
+        # (window layers, n_kv, P_pad, hd) -> (window layers, R, heads, pt,
+        # width) as the arena stores a row (two heads a row where packed)
+        kept = pack_rows(rows[take, 0][:, :, src].transpose(0, 2, 1, 3), arena)
+        wl, _, heads, width = kept.shape
+        kept = kept.reshape(wl, ring_pages, page_tokens, heads, width)
+        return jax.lax.dynamic_update_slice(
+            arena, kept.transpose(0, 1, 3, 2, 4).astype(arena.dtype), at)
+
+    return arena_k, arena_v, lane_ring(pk, wk), lane_ring(pv, wv)
 
 
 @functools.partial(jax.jit, static_argnames=("width",))
@@ -1002,7 +1181,7 @@ def _pages_import_jit(arena_k, arena_v, scales, pages, pk, pv, pscales):
 @functools.partial(
     jax.jit,
     static_argnames=("cfg_key", "family", "chunk", "page_tokens", "kernel"),
-    donate_argnums=(1, 2, 3, 11),
+    donate_argnums=(1, 2, 3, 11, 12),
 )
 def _paged_decode_chunk_jit(
     params,
@@ -1017,6 +1196,7 @@ def _paged_decode_chunk_jit(
     temperature,         # (S,) f32 per-lane
     top_k,               # (S,) i32 per-lane
     lane_state=None,     # (lane layers, S, rows, width) | None — donated
+    window=None,         # (wk, wv) the window layers' ring arena | None — donated
     *,
     cfg_key,
     family: str = "transformer_lm",
@@ -1054,7 +1234,11 @@ def _paged_decode_chunk_jit(
     the one it was. The next is ``lane_state`` after the chunk (donated like
     the arena and carried by the same scan; an inactive lane's slice comes
     back bit for bit), ``None`` in and out for a model that keeps rows only;
-    ``counter + 1`` follows it."""
+    ``counter + 1`` follows it. A model with window layers takes their ring
+    arena as ``window`` (donated and carried like the global arena; what a
+    window call reads of it is worked out from ``pos`` inside the program, so
+    it brings no operand of its own) and returns it LAST, one output more than
+    every other model's program has."""
     cfg = dict(cfg_key)
     live = _live_lanes(active)       # once a chunk: ``active`` is frozen
     rngs = jax.random.split(jax.random.PRNGKey(counter), chunk)
@@ -1075,14 +1259,15 @@ def _paged_decode_chunk_jit(
         return (cache, nxt, pos), (nxt, stats)
 
     (cache, tok, pos), (toks, stats) = jax.lax.scan(
-        step, (_arena_cache(arena_k, arena_v, scales, lane_state), tok, pos),
+        step, (_arena_cache(arena_k, arena_v, scales, lane_state, window),
+               tok, pos),
         rngs, length=chunk
     )
     if stats is not None:
         stats = jnp.mean(stats, axis=0)
     return (*_cache_arena(cache), tok, pos,
             jnp.transpose(toks, (1, 0)), stats,  # (S, chunk), (3,) | None
-            cache.get("lane"), counter + 1)
+            cache.get("lane"), counter + 1, *_cache_window(cache))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -1150,18 +1335,31 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
     through the head -> logits ``(B, 1, V)``; None = every position.
     ``real_len (B,)`` is how many of the tokens at hand are real (None = all):
     a layer that keeps a lane state (``cache["lane"]``) leaves the state after
-    that many, not after a right-padded prompt's pad tokens."""
+    that many, not after a right-padded prompt's pad tokens.
+
+    A model with WINDOW layers keeps every row of every layer in this dense
+    cache and applies each layer's mask (a window layer's query reads itself
+    and the ``window - 1`` positions before it). Its ``fresh`` forward attends
+    among the tokens at hand through ``ops.attention.attention`` in EVERY
+    layer (the flash kernel where its gate admits, with the window's blocks
+    skipped in a window layer), so no ``(S, max_len)`` score block is built: an
+    8192-token prompt's would be 8.6 GB. The other K/V families' programs are
+    the ones they were."""
+    from tfservingcache_tpu.ops.attention import attention
+
     dtype = jnp.dtype(cfg["dtype"])
     b, s_len = input_ids.shape
     positions = start_pos[:, None] + jnp.arange(s_len)[None, :]   # (B, S)
     latent = _cache_row(cfg).sides == 1
     eps = cfg.get("rms_eps", 1e-5)
+    windowed = bool(_window_of(cfg))
 
     with jax.named_scope("embed"):
         x = params["embed"][input_ids].astype(dtype)
     new_k, new_v, new_lane = [], [], []
     n_heads, n_kv = cfg["n_heads"], cfg.get("n_kv_heads")
-    for layer, (lane, li) in zip(params["layers"], _layer_slots(cfg)):
+    for layer, (lane, li, _arena, window) in zip(
+            params["layers"], _layer_slots(cfg)):
         with jax.named_scope("layer"):
             if lane:
                 out, after = _lane_layer(
@@ -1183,9 +1381,10 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
                 continue
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"]), n_heads, n_kv)
-                q = _rope_per_example(q, positions, cfg["rope_theta"])
-                k = _rope_per_example(k, positions, cfg["rope_theta"])
+                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"], eps), n_heads, n_kv)
+                rope = rope_of(cfg, window)
+                q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
+                k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
             with jax.named_scope("kv_read"):
                 k_layer, v_layer = cache["k"][li], cache["v"][li]
 
@@ -1205,29 +1404,40 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
             # query heads fold into (kv_head, group) so the cache is read as-is,
             # never repeated up to n_heads (the repeat would materialize
             # group x cache bytes every step at exactly the scale GQA exists for)
-            with jax.named_scope("attn"):
+            with jax.named_scope("attn"), _kind_scope(
+                    windowed, "window" if window else "global"):
                 d = q.shape[-1]
-                group = n_heads // n_kv
-                # dots read the caches in their stored dtype: upcasting K/V to f32
-                # here doubled the HBM bytes of the cache read EVERY decode step —
-                # the read that dominates decode. Scores/softmax still accumulate
-                # f32 via preferred_element_type (the flash-kernel recipe).
-                qg = q.reshape(b, n_kv, group, s_len, d)
-                s = jnp.einsum(
-                    "bkgqd,bkld->bkgql", qg, k_cache,
-                    preferred_element_type=jnp.float32,
-                )
-                s = s / math.sqrt(d)
-                k_pos = jnp.arange(k_cache.shape[2])
-                mask = k_pos[None, None, :] <= positions[:, :, None]      # (B, S, max_len)
-                s = jnp.where(mask[:, None, None], s, -1e30)
-                p = jax.nn.softmax(s, axis=-1)
-                out = jnp.einsum(
-                    "bkgql,bkld->bkgqd", p.astype(v_cache.dtype), v_cache,
-                    preferred_element_type=jnp.float32,
-                )
+                if windowed and fresh:
+                    # the tokens at hand are all there is: no score block over
+                    # the cache's length, a window layer's blocks skipped
+                    out = attention(q, k, v, causal=True, window=window)
+                else:
+                    group = n_heads // n_kv
+                    # dots read the caches in their stored dtype: upcasting K/V
+                    # to f32 here doubled the HBM bytes of the cache read EVERY
+                    # decode step — the read that dominates decode.
+                    # Scores/softmax still accumulate f32 via
+                    # preferred_element_type (the flash-kernel recipe).
+                    qg = q.reshape(b, n_kv, group, s_len, d)
+                    s = jnp.einsum(
+                        "bkgqd,bkld->bkgql", qg, k_cache,
+                        preferred_element_type=jnp.float32,
+                    )
+                    s = s / math.sqrt(d)
+                    k_pos = jnp.arange(k_cache.shape[2])
+                    mask = k_pos[None, None, :] <= positions[:, :, None]  # (B, S, max_len)
+                    if window:
+                        mask &= (positions[:, :, None] - k_pos[None, None, :]
+                                 < window)
+                    s = jnp.where(mask[:, None, None], s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1)
+                    out = jnp.einsum(
+                        "bkgql,bkld->bkgqd", p.astype(v_cache.dtype), v_cache,
+                        preferred_element_type=jnp.float32,
+                    )
                 out = out.reshape(b, n_heads, s_len, d).astype(x.dtype)
-                out = out.transpose(0, 2, 1, 3).reshape(b, s_len, cfg["d_model"])
+                # heads x head width: the hidden size for most models
+                out = out.transpose(0, 2, 1, 3).reshape(b, s_len, -1)
                 x = x + out @ attn["wo"]
             x = x + _ffn_block(layer, x, cfg, dtype)
     if logits_at is not None:
@@ -1242,13 +1452,19 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
     return logits, new_cache
 
 
-def _rope_per_example(x, positions, theta):
-    """Rotary embedding with per-example positions (B, S) over (B, H, S, D)."""
+def _rope_per_example(x, positions, theta, rope=(None, 1.0)):
+    """Rotary embedding with per-example positions (B, S) over (B, H, S, D);
+    ``rope`` is ``transformer_lm.rope_of``'s answer for the layer: the plain
+    ``theta`` frequencies, or a kind's own with cos and sin times its factor."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[..., None].astype(jnp.float32) * freqs[None, None, :]  # (B,S,d/2)
+    freqs, factor = rope
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(freqs)[None, None, :]  # (B,S,d/2)
     cos = jnp.cos(angles)[:, None]                                            # (B,1,S,d/2)
     sin = jnp.sin(angles)[:, None]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., 0::2], x[..., 1::2]
     rot = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return rot.reshape(x.shape).astype(x.dtype)

@@ -61,7 +61,11 @@ from tfservingcache_tpu.models.registry import (
     latent_cache_row,
     register,
 )
-from tfservingcache_tpu.models.transformer_lm import _output_logits, _rmsnorm
+from tfservingcache_tpu.models.transformer_lm import (
+    _output_logits,
+    _rmsnorm,
+    yarn_frequencies,
+)
 from tfservingcache_tpu.ops.attention import attention
 
 DEFAULT_CONFIG: dict[str, Any] = {
@@ -99,26 +103,11 @@ DEFAULT_CONFIG: dict[str, Any] = {
 
 def yarn_inv_freq(cfg: dict) -> np.ndarray:
     """The ``qk_rope_head_dim / 2`` rotary frequencies: plain ``theta^(-2i/d)``
-    at ``rope_factor`` 1, else YaRN's blend of those (dimensions that turn
-    more than ``rope_beta_fast`` times within ``rope_original_max``
-    positions) with the same divided by ``rope_factor`` (fewer than
-    ``rope_beta_slow`` turns), a linear ramp between."""
-    d = int(cfg["qk_rope_head_dim"])
-    base = float(cfg["rope_theta"])
-    extra = base ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    factor = float(cfg["rope_factor"])
-    if factor == 1.0:
-        return extra.astype(np.float32)
-
-    def correction_dim(turns: float) -> float:
-        return (d * math.log(float(cfg["rope_original_max"])
-                             / (turns * 2 * math.pi))) / (2 * math.log(base))
-
-    low = max(math.floor(correction_dim(float(cfg["rope_beta_fast"]))), 0)
-    high = min(math.ceil(correction_dim(float(cfg["rope_beta_slow"]))), d - 1)
-    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
-                   / max(high - low, 1e-3), 0.0, 1.0)
-    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+    at ``rope_factor`` 1, else YaRN's blend (``transformer_lm.
+    yarn_frequencies``, shared with ``moe_lm``'s global layers)."""
+    return yarn_frequencies(
+        int(cfg["qk_rope_head_dim"]), cfg["rope_theta"], cfg["rope_factor"],
+        cfg["rope_original_max"], cfg["rope_beta_fast"], cfg["rope_beta_slow"])
 
 
 def softmax_scale(cfg: dict) -> float:

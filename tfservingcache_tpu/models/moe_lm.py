@@ -20,6 +20,18 @@ token, and a row's answer does not depend on the rows beside it, so the
 family is engine-ready: it co-batches and shares decode steps like the
 dense one.
 
+A model may mix two kinds of attention layer (``layer_types``, one entry a
+layer): ``full_attention`` keeps every row, ``sliding_attention`` the last
+``sliding_window`` (a query reads itself and the ``sliding_window - 1``
+positions before it). The ModelDef declares that a layer (``layer_state``: a
+``CacheRow`` with a ``window``), and the shared code gives such a layer one
+window of pages a lane in an arena of its own (``models/generation.py``). The
+rotary goes by kind: window layers the plain ``rope_theta`` frequencies,
+global layers what ``rope_full`` states (YaRN's blend, cos and sin times its
+``attention_factor``; ``transformer_lm.rope_of``). ``head_dim`` is the head
+width where it is not ``d_model / n_heads``. A config with none of these keys
+(OLMoE's) builds the program it always built.
+
 Expert weights carry an ``("expert", None, None)`` partition rule, so on a
 mesh with an "expert" axis each chip group holds E/ep experts; there the
 grouped product is ``jax.lax.ragged_dot`` (the Pallas kernel is single-chip).
@@ -36,6 +48,7 @@ import jax.numpy as jnp
 from tfservingcache_tpu.models.registry import (
     ModelDef,
     TensorSpec,
+    head_width,
     kv_cache_row,
     register,
 )
@@ -63,6 +76,28 @@ DEFAULT_CONFIG: dict[str, Any] = {
     "rope_theta": 10000.0,
     "dtype": "bfloat16",
 }
+
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+def layer_state_of(cfg: dict) -> tuple:
+    """What each layer keeps of a request, from ``layer_types``: the K/V row,
+    with ``sliding_window`` as its window for a ``sliding_attention`` layer.
+    () for a model whose layers are all global (no ``layer_types``: the
+    ModelDef then fills in ``cache_row`` a layer)."""
+    types = list(cfg.get("layer_types") or ())
+    if not types:
+        return ()
+    window = int(cfg.get("sliding_window") or 0)
+    if len(types) != int(cfg["n_layers"]) or set(types) - {SLIDING, FULL}:
+        raise ValueError(
+            f"layer_types must name {cfg['n_layers']} layers of "
+            f"{[SLIDING, FULL]}, got {types}")
+    if SLIDING in types and window < 1:
+        raise ValueError("sliding_attention layers need a sliding_window >= 1")
+    return tuple(kv_cache_row(cfg, window if t == SLIDING else 0)
+                 for t in types)
 
 
 @jax.named_scope("ffn")
@@ -104,20 +139,23 @@ def _forward(params: dict, input_ids: jax.Array, cfg: dict, mesh=None) -> tuple[
     with jax.named_scope("embed"):
         x = params["embed"][input_ids].astype(dtype)
     aux_total = jnp.zeros((), jnp.float32)
-    for layer in params["layers"]:
+    eps = cfg.get("rms_eps", 1e-5)
+    kinds = layer_state_of(cfg) or (None,) * len(params["layers"])
+    for layer, kind in zip(params["layers"], kinds):
         with jax.named_scope("layer"):
             x = x + _attention_block(
                 jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"]),
-                _rmsnorm(x, layer["ln1"]),
+                _rmsnorm(x, layer["ln1"], eps),
                 cfg,
                 mesh,
+                window=kind.window if kind else 0,
             )
             y, stats = _moe_block(layer, x, cfg, dtype, partitioned=partitioned)
             x = x + y
         # Switch load-balance aux loss: e * sum_e(frac_tokens_e * mean_prob_e)
         frac = jnp.mean(jnp.sum(jax.nn.one_hot(stats["experts"], e), axis=1), axis=0)
         aux_total = aux_total + e * jnp.sum(frac * jnp.mean(stats["probs"], axis=0))
-    logits = _output_logits(params, x, dtype)
+    logits = _output_logits(params, x, dtype, eps)
     return logits, aux_total / max(len(params["layers"]), 1)
 
 
@@ -139,7 +177,7 @@ def build(config: dict) -> ModelDef:
     def init(rng):
         d, v, ff, e = cfg["d_model"], cfg["vocab_size"], cfg["d_ff"], cfg["n_experts"]
         n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"]
-        head_dim = d // n_heads
+        head_dim = head_width(cfg)
         keys = jax.random.split(rng, cfg["n_layers"] + 1)
 
         def dense(key, fan_in, shape):
@@ -234,4 +272,5 @@ def build(config: dict) -> ModelDef:
         # no capacity, no dropped token: a row's answer is its own
         engine_ready=True,
         cache_row=kv_cache_row(cfg),
+        layer_state=layer_state_of(cfg),
     )

@@ -69,12 +69,21 @@ class CacheRow:
     ``(n_kv_heads, head_dim)``; latent attention has ONE side of one shared
     row a token (``[c_kv | rope(k_r)]``, stored ``width`` wide), which every
     query head reads as its key and, in its first ``value_width`` columns, as
-    its value."""
+    its value.
+
+    ``window`` > 0 makes it the row of a WINDOW layer: a query at position
+    ``i`` reads the rows of positions ``(i - window, i]`` and no other, so a
+    lane keeps one window of pages in that layer whatever its request's
+    length (a ring of ``window / page_tokens + 1`` pages in an arena of its
+    own beside the global one, ``generation.init_paged_cache``); 0 = every
+    row is kept (a global layer). One declaration a layer in
+    ``ModelDef.layer_state``, read through ``generation._layer_slots``."""
 
     sides: int
     heads: int
     width: int
     value_width: int = 0     # latent rows only: the value is a prefix of the key
+    window: int = 0          # > 0: the layer keeps the last ``window`` rows only
 
 
 @dataclass(frozen=True)
@@ -89,11 +98,19 @@ class LaneState:
     width: int
 
 
-def kv_cache_row(cfg: Mapping[str, Any]) -> CacheRow:
-    """The decoder-LM families' row: K and V of ``(n_kv_heads, d_model /
-    n_heads)`` — the one place that derives it."""
-    return CacheRow(2, int(cfg["n_kv_heads"]),
-                    int(cfg["d_model"]) // int(cfg["n_heads"]))
+def head_width(cfg: Mapping[str, Any]) -> int:
+    """A decoder-LM family's head width: its ``head_dim`` where the config
+    states one (a model whose heads are not ``d_model / n_heads`` wide), else
+    ``d_model / n_heads`` — the one place that derives it."""
+    return int(cfg.get("head_dim") or
+               int(cfg["d_model"]) // int(cfg["n_heads"]))
+
+
+def kv_cache_row(cfg: Mapping[str, Any], window: int = 0) -> CacheRow:
+    """The decoder-LM families' row: K and V of ``(n_kv_heads, head_width)``;
+    ``window`` > 0 for a layer that keeps the last ``window`` rows only."""
+    return CacheRow(2, int(cfg["n_kv_heads"]), head_width(cfg),
+                    window=int(window))
 
 
 def latent_cache_row(cfg: Mapping[str, Any]) -> CacheRow:
@@ -187,19 +204,35 @@ def static_config(model: ModelDef) -> tuple:
     kind enters shared code: nothing there derives either from a config key
     of some family. (A model of one kind carries no ``layer_state`` item, so
     its programs' key is the one it was.)"""
-    items = {k: tuple(v) if isinstance(v, list) else v
-             for k, v in model.config.items()}
+    items = {k: _hashable(v) for k, v in model.config.items()}
     if model.cache_row is not None:
         items["cache_row"] = model.cache_row
-    if lane_layers(model.layer_state):
+    if any(kind != model.cache_row for kind in model.layer_state):
         items["layer_state"] = tuple(model.layer_state)
     return tuple(sorted(items.items()))
+
+
+def _hashable(value):
+    """A config value as a program's static key can hold it: a list a tuple,
+    a mapping the sorted tuple of its items (``dict()`` of it is the mapping
+    again)."""
+    if isinstance(value, Mapping):
+        return tuple(sorted((k, _hashable(v)) for k, v in value.items()))
+    if isinstance(value, (list, tuple)):
+        return tuple(_hashable(v) for v in value)
+    return value
 
 
 def lane_layers(layer_state) -> tuple[int, ...]:
     """The layers (model indices) that keep a fixed ``LaneState``."""
     return tuple(i for i, s in enumerate(layer_state)
                  if isinstance(s, LaneState))
+
+
+def window_layers(layer_state) -> tuple[int, ...]:
+    """The layers (model indices) that keep one window of rows a lane."""
+    return tuple(i for i, s in enumerate(layer_state)
+                 if isinstance(s, CacheRow) and s.window)
 
 
 _REGISTRY: dict[str, Callable[[dict[str, Any]], ModelDef]] = {}

@@ -22,10 +22,12 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tfservingcache_tpu.models.registry import (
     ModelDef,
     TensorSpec,
+    head_width,
     kv_cache_row,
     register,
 )
@@ -68,13 +70,57 @@ def _rmsnorm(x: jax.Array, gain: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (x32 * scale).astype(x.dtype) * gain.astype(x.dtype)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over (B, H, S, D)."""
+def yarn_frequencies(d: int, theta: float, factor: float, original_max: float,
+                     beta_fast: float, beta_slow: float) -> np.ndarray:
+    """The ``d / 2`` rotary frequencies of a ``d``-wide head: plain
+    ``theta^(-2i/d)`` at ``factor`` 1, else YaRN's blend of those (dimensions
+    that turn more than ``beta_fast`` times within ``original_max`` positions)
+    with the same divided by ``factor`` (fewer than ``beta_slow`` turns), a
+    linear ramp between; the same blend at every position."""
+    extra = float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if float(factor) == 1.0:
+        return extra.astype(np.float32)
+
+    def correction_dim(turns: float) -> float:
+        return (d * math.log(float(original_max) / (turns * 2 * math.pi))
+                ) / (2 * math.log(float(theta)))
+
+    low = max(math.floor(correction_dim(float(beta_fast))), 0)
+    high = min(math.ceil(correction_dim(float(beta_slow))), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (extra / float(factor) * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_of(cfg: dict, window: int = 0) -> tuple:
+    """The rotary of one layer of ``cfg``'s model -> ``(frequencies | None,
+    factor)``: None = the plain ``rope_theta`` frequencies computed where they
+    are applied (every model with one rotary, bit for bit as before). A model
+    with a rotary a layer KIND states the global layers' under ``rope_full``
+    (``yarn``: the factor, ``original_max``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``, which multiplies cos and sin); its window layers
+    (``window`` > 0) keep the plain frequencies."""
+    full = dict(cfg.get("rope_full") or ())
+    if window or not full:
+        return None, 1.0
+    freqs = yarn_frequencies(
+        head_width(cfg), cfg["rope_theta"], full["yarn"], full["original_max"],
+        full.get("beta_fast", 32.0), full.get("beta_slow", 1.0))
+    return freqs, float(full.get("attention_factor", 1.0))
+
+
+def _rope(x: jax.Array, positions: jax.Array, theta: float,
+          rope: tuple = (None, 1.0)) -> jax.Array:
+    """Rotary embedding over (B, H, S, D); ``rope`` = ``rope_of``'s answer."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)      # (d/2,)
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]     # (S, d/2)
+    freqs, factor = rope
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (d/2,)
+    angles = positions[:, None].astype(jnp.float32) * jnp.asarray(freqs)[None, :]
     cos = jnp.cos(angles)[None, None]                                    # (1,1,S,d/2)
     sin = jnp.sin(angles)[None, None]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., 0::2], x[..., 1::2]
     rot = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return rot.reshape(x.shape).astype(x.dtype)
@@ -120,12 +166,16 @@ def _output_logits(params: dict, x: jax.Array, dtype,
 
 
 @jax.named_scope("attn")
-def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None) -> jax.Array:  # static-bounded: mesh -- one Mesh object per runtime lifetime
-    b, s, d_model = x.shape
+def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None,  # static-bounded: mesh, window -- one Mesh object per runtime lifetime; one window per model config
+                     window: int = 0) -> jax.Array:
+    """``window`` > 0: a window layer (a query reads itself and the
+    ``window - 1`` positions before it), with the rotary of its kind."""
+    b, s, _ = x.shape
     q, k, v = _qkv(params, x, cfg["n_heads"], cfg["n_kv_heads"])
     positions = jnp.arange(s)
-    q = _rope(q, positions, cfg["rope_theta"])
-    k = _rope(k, positions, cfg["rope_theta"])
+    rope = rope_of(cfg, window)
+    q = _rope(q, positions, cfg["rope_theta"], rope)
+    k = _rope(k, positions, cfg["rope_theta"], rope)
     if (
         mesh is not None
         and cfg.get("attention") == "ring"
@@ -144,8 +194,10 @@ def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None) -> jax.Ar
         # On a chip group this block is traced into a GSPMD-partitioned
         # program, which the gate must know: it cannot see it from shapes.
         out = attention(q, k, v, causal=True,
-                        partitioned=mesh is not None and mesh.size > 1)  # (b,h,s,hd)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, d_model)
+                        partitioned=mesh is not None and mesh.size > 1,
+                        window=window)                                   # (b,h,s,hd)
+    # heads x head width: the hidden size for most models, not for all
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return out @ params["wo"]
 
 

@@ -56,7 +56,8 @@ def _record_dispatch(gate: str, branch: str, reason: str, *shapes: tuple) -> Non
 
 def dispatch_tally() -> dict[tuple[str, str, str], int]:
     """Snapshot of ``{(gate, branch, reason): traces}`` since process start.
-    Gates: ``attention``, ``paged_attention``, ``paged_attention_verify``,
+    Gates: ``attention``, ``attention_window``, ``paged_attention``,
+    ``paged_window_attention``, ``paged_attention_verify``,
     ``paged_latent_attention``, ``ring_attention``, ``moe_experts``; branch
     is ``"kernel"`` or ``"reference"``."""
     with _DISPATCH_LOCK:
@@ -85,9 +86,11 @@ def _kernel_refusal(head_dim: int, hq: int, hkv: int,
 # ---------------------------------------------------------------------------
 
 def attention_reference(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
+    window: int = 0,
 ) -> jax.Array:
     """(B, Hq, S, D) x (B, Hkv, S, D) attention, fp32 softmax, out in q.dtype.
+    ``window`` > 0 (causal only): query ``i`` reads keys ``(i - window, i]``.
 
     GQA-native: Hkv may divide Hq; query heads are grouped over their shared
     K/V head via a reshape, so repeated K/V are never materialized (the whole
@@ -107,6 +110,8 @@ def attention_reference(
     ) / math.sqrt(d)
     if causal:
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # p @ v stays f32: a bf16-rounded p makes the sharded (TP/EP) einsum
@@ -122,8 +127,11 @@ def attention_reference(
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, *, sm_scale: float, causal: bool, block_q: int,
-    block_k: int, valid_len: int,
+    block_k: int, valid_len: int, window: int = 0,
 ):
+    """``window`` > 0 (causal): query ``i`` reads keys ``(i - window, i]``;
+    the K blocks wholly before a Q block's first row's window are skipped by
+    the loop's start as those after its last row are by its end."""
     from jax.experimental import pallas as pl
 
     # Keep operands in their input dtype (bf16) for the MXU dots: a bf16
@@ -143,6 +151,9 @@ def _flash_kernel(
         )
     else:
         num_k_blocks = seq_len // block_k
+    # the first K block that holds a key of the first row's window
+    first_k_block = (jnp.maximum(q_offset - window + 1, 0) // block_k
+                     if window else 0)
 
     def body(j, carry):
         acc, m, l = carry
@@ -156,9 +167,16 @@ def _flash_kernel(
         if causal:
             q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             valid = valid & (q_pos >= k_pos)
+            if window:
+                valid = valid & (q_pos - k_pos < window)
         s = jnp.where(valid, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))          # (bq, 1)
-        p = jnp.exp(s - m_new)
+        if window:
+            # a row's window may begin after this block: all of it masked and
+            # m still NEG_INF, where exp(s - m) would be 1
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        else:
+            p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
@@ -170,7 +188,8 @@ def _flash_kernel(
     acc = jnp.zeros((q.shape[0], q_ref.shape[2]), jnp.float32)
     m = jnp.full((q.shape[0], 1), NEG_INF, jnp.float32)
     l = jnp.zeros((q.shape[0], 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, num_k_blocks, body, (acc, m, l))
+    acc, m, l = jax.lax.fori_loop(
+        first_k_block, num_k_blocks, body, (acc, m, l))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -191,10 +210,13 @@ def flash_variant(s_padded: int, d: int, itemsize: int) -> str:
 def _flash_streamed_kernel(
     q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale: float,
     causal: bool, block_q: int, block_k: int, valid_len: int, num_k: int,
+    window: int = 0,
 ):
     """One (q-block, k-block) grid step: online-softmax update of the VMEM
     scratch accumulators. K/V arrive one block per step (double-buffered by
-    the Pallas pipeline), so VMEM use is independent of sequence length."""
+    the Pallas pipeline), so VMEM use is independent of sequence length.
+    ``window`` > 0: a block wholly before the Q block's first row's window is
+    skipped like one wholly after its last row."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -221,11 +243,16 @@ def _flash_streamed_kernel(
         if causal:
             q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             valid = valid & (q_pos >= k_pos)
+            if window:
+                valid = valid & (q_pos - k_pos < window)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[:, :1]                                   # (bq, 1)
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        if window:
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)   # see _flash_kernel
+        else:
+            p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
@@ -242,7 +269,10 @@ def _flash_streamed_kernel(
         # a causal block whose first key strictly follows this q block's last
         # row is fully masked: skip its MXU work (its DMA is already in
         # flight — the bandwidth cost of a static grid — but no compute)
-        pl.when(k_offset <= q_offset + block_q - 1)(_body)
+        live = k_offset <= q_offset + block_q - 1
+        if window:
+            live = live & (k_offset + block_k - 1 >= q_offset - window + 1)
+        pl.when(live)(_body)
     else:
         _body()
 
@@ -253,10 +283,7 @@ def _flash_streamed_kernel(
         ).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
-)
-def flash_attention(
+def _flash_attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
@@ -264,6 +291,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool = False,
+    window: int = 0,
 ) -> jax.Array:
     """Flash attention over (B, Hq, S, D) x (B, Hkv, S, D). S is padded to a
     block multiple internally. GQA-native: the kernel instance for query head
@@ -282,10 +310,20 @@ def flash_attention(
     then block_q/block_k take the largest of (256)/(512, 256) that divides the
     padded length, falling back to 128 — the v5e-tuned sizes without the
     pathological lcm-padding an asymmetric fixed default would hit on
-    non-power-of-two sequence lengths (e.g. generate's exact-size fallback)."""
+    non-power-of-two sequence lengths (e.g. generate's exact-size fallback).
+
+    ``window`` > 0 (``flash_window_attention``; causal): query ``i`` reads keys
+    ``(i - window, i]``, the K blocks wholly outside a Q block's windows are
+    skipped (the resident kernel never visits them, the streamed one does no
+    MXU work for them), and the call carries its own name in the device
+    trace, ``flash_window_kernel``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if window and not causal:
+        raise ValueError("a window is a causal mask's")
+    # a windowed call is told apart in the device trace by its name
+    name = "flash_window_kernel" if window else None
     b, h, s, d = q.shape
     hkv = k.shape[1]
     if h % hkv:
@@ -313,7 +351,7 @@ def flash_attention(
     if flash_variant(sp, d, q.dtype.itemsize) == "resident":
         kernel = functools.partial(
             _flash_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, valid_len=s,
+            block_q=block_q, block_k=block_k, valid_len=s, window=window,
         )
         grid = (b * h, sp // block_q)
         # program i covers flat (batch, q-head) index i; its K/V row is the
@@ -333,12 +371,14 @@ def flash_attention(
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")
             ),
+            name=name,
         )(qf, kf, vf)
     else:
         num_k = sp // block_k
         kernel = functools.partial(
             _flash_streamed_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, valid_len=s, num_k=num_k,
+            window=window,
         )
         grid = (b * h, sp // block_q, num_k)
         kv_index = lambda i, j, kj: (i // h * hkv + (i % h) // g, kj, 0)
@@ -361,11 +401,46 @@ def flash_attention(
             compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+            name=name,
         )(qf, kf, vf)
     out = out.reshape(b, h, sp, d)
     if pad:
         out = out[:, :, :s, :]
     return out
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+)
+def flash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    causal: bool = True,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """``_flash_attention`` with no window: every key at or before the query."""
+    return _flash_attention(q, k, v, causal, block_q, block_k, interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("window", "block_q", "block_k", "interpret")
+)
+def flash_window_attention(  # static-bounded: window -- one value per model config (sliding_window)
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    window: int,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal flash attention in which query ``i`` reads keys ``(i - window,
+    i]`` (``_flash_attention``): a window layer's fresh prefill."""
+    return _flash_attention(q, k, v, True, block_q, block_k, interpret,
+                            window=int(window))
 
 
 def _flash_carry_kernel(
@@ -641,9 +716,12 @@ def paged_decode_attention(
     layer: int = 0,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    first: jax.Array | None = None,
 ) -> jax.Array:
     """Single-position attention over a paged KV arena — the decode-step
     counterpart of the dense slot read in ``_forward_cached_dyn``.
+    ``first`` (``(S,)``, a window layer's call) is each lane's first valid
+    token: the mask is then ``first <= k_pos <= pos``.
 
     Shapes: q ``(S, Hq, 1, D)`` (one query per lane, post-RoPE),
     k_pages/v_pages the arena ``(layers, n_pages, Hkv, page_tokens, D)``
@@ -673,6 +751,8 @@ def paged_decode_attention(
     ) / math.sqrt(d)
     k_pos = jnp.arange(kc.shape[2])
     mask = k_pos[None, None, :] <= pos[:, None, None]    # (S, 1, L)
+    if first is not None:
+        mask &= k_pos[None, None, :] >= first[:, None, None]
     s = jnp.where(mask[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum(
@@ -771,9 +851,9 @@ PAGED_BLOCK_TOKENS = 128
 
 
 def _paged_decode_kernel(
-    tables_ref, pos_ref, active_ref, q_ref, k_hbm, v_hbm, *rest,
+    tables_ref, pos_ref, active_ref, *rest,
     sm_scale: float, page_tokens: int, block_pages: int, quantized: bool,
-    layer: int,
+    layer: int, windowed: bool = False,
 ):
     """One LANE of paged decode attention: a grid step per lane, and inside
     it a loop over the lane's LIVE pages only, ``block_pages`` at a time.
@@ -800,10 +880,21 @@ def _paged_decode_kernel(
     ``(1, hkv, 1, tokens)`` come as ordinary VMEM blocks (the wrapper
     gathered them: a scale page's 16-wide rows are under the 128 lanes a
     manual copy can slice) and are applied to scores and probabilities
-    (``_widen_int8``) — int8 halves the HBM bytes per KV token."""
+    (``_widen_int8``) — int8 halves the HBM bytes per KV token.
+
+    ``windowed``: a fourth scalar operand, ``first_ref``, gives each lane's
+    FIRST valid token (a window layer: the query reads tokens ``first..pos``
+    of its table's view): the tokens before it are masked. The view a window
+    call hands in (``window_ring_view``) BEGINS at the page that holds it, so
+    no page wholly before the window is in the table, let alone copied, and
+    the loop needs no second beginning of its own. Not with ``quantized``
+    (the wrapper refuses)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if windowed:
+        first_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, *rest = rest
     if quantized:
         ks_ref, vs_ref, o_ref, k_buf, v_buf, sem, acc_s, m_s, l_s = rest
     else:
@@ -872,7 +963,10 @@ def _paged_decode_kernel(
         k_pos = blk * block_tokens + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2
         )
-        s = jnp.where(k_pos <= pos, s, NEG_INF)
+        mask = k_pos <= pos
+        if windowed:
+            mask &= k_pos >= first_ref[lane]
+        s = jnp.where(mask, s, NEG_INF)
         m_prev = m_s[:, :, :1]
         l_prev = l_s[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -917,9 +1011,7 @@ def _lane_scale_rows(scale, tables, pos, block_tokens: int, layer: int):
     return jnp.pad(rows, ((0, 0), (0, 0), (0, pad)))[:, :, None, :]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("page_tokens", "interpret", "layer"))
-def paged_decode_attention_kernel(
+def _paged_decode_call(
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -928,6 +1020,7 @@ def paged_decode_attention_kernel(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     active: jax.Array | None = None,
+    first: jax.Array | None = None,
     *,
     page_tokens: int,
     interpret: bool = False,
@@ -959,10 +1052,20 @@ def paged_decode_attention_kernel(
     its own softmax; the value product gives ``[out(2j) | out(2j + 1)]`` for
     every row, of which each keeps its own head's half. Twice the MXU work of
     a memory-bound product and not a byte more from HBM: the page copies are
-    whole 128-lane tiles, which a 64-wide page is not."""
+    whole 128-lane tiles, which a 64-wide page is not.
+
+    ``first`` (``(S,)`` int32; ``paged_window_decode_attention_kernel``) is
+    each lane's first valid token: the lane attends over tokens
+    ``first..pos`` of its table's view and masks the tokens before ``first``
+    (a view that begins at ``first``'s page, as ``window_ring_view`` makes it,
+    holds no page wholly before the window). The same kernel body under its
+    own name in the device trace, ``paged_window_decode_kernel``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    windowed = first is not None
+    if windowed and k_scale is not None:
+        raise ValueError("a window call over an int8 arena is not supported")
     s_lanes, hq, _, head = q.shape
     k_pages, v_pages = _as_arena(k_pages), _as_arena(v_pages)
     _, _, hkv, pt, d = k_pages.shape                # as STORED
@@ -995,7 +1098,7 @@ def paged_decode_attention_kernel(
         active = jnp.ones((s_lanes,), jnp.int32)
     active = active.astype(jnp.int32)
 
-    def lane_index(s, tbl, ps, act):
+    def lane_index(s, *_scalars):       # tables, pos, active (and first)
         return (s, 0, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
@@ -1019,12 +1122,16 @@ def paged_decode_attention_kernel(
         pltpu.VMEM((hkv, g, 128), jnp.float32),     # l (lane-bcast)
     ]
 
+    scalars = [tables, pos, active]
+    if windowed:
+        scalars.append(first.astype(jnp.int32))
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, page_tokens=page_tokens,
         block_pages=block_pages, quantized=quantized, layer=layer,
+        windowed=windowed,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(s_lanes,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, g, d), lane_index),
@@ -1038,7 +1145,9 @@ def paged_decode_attention_kernel(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
-    )(tables, pos, active, *operands)
+        # a window call is told apart in the device trace by its name
+        name="paged_window_decode_kernel" if windowed else None,
+    )(*scalars, *operands)
     if packed:
         # row (pair, half, gi) keeps columns [half * head, (half + 1) * head)
         out = out.reshape(s_lanes, hkv, 2, g // 2, 2, head)
@@ -1046,8 +1155,52 @@ def paged_decode_attention_kernel(
     return out.reshape(s_lanes, hq, 1, head)
 
 
-def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # static-bounded: causal, partitioned -- boolean domains (two programs max each)
-              partitioned: bool = False) -> jax.Array:
+@functools.partial(
+    jax.jit, static_argnames=("page_tokens", "interpret", "layer"))
+def paged_decode_attention_kernel(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    tables: jax.Array,
+    pos: jax.Array,
+    k_scale: jax.Array | None = None,
+    v_scale: jax.Array | None = None,
+    active: jax.Array | None = None,
+    *,
+    page_tokens: int,
+    interpret: bool = False,
+    layer: int = 0,
+) -> jax.Array:
+    """``_paged_decode_call`` over every token ``0..pos`` of a lane."""
+    return _paged_decode_call(
+        q, k_pages, v_pages, tables, pos, k_scale, v_scale, active,
+        page_tokens=page_tokens, interpret=interpret, layer=layer)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("page_tokens", "interpret", "layer"))
+def paged_window_decode_attention_kernel(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    tables: jax.Array,
+    pos: jax.Array,
+    first: jax.Array,
+    active: jax.Array | None = None,
+    *,
+    page_tokens: int,
+    interpret: bool = False,
+    layer: int = 0,
+) -> jax.Array:
+    """``_paged_decode_call`` over tokens ``first..pos`` of a lane's view: a
+    window layer's decode call (``paged_window_attention``)."""
+    return _paged_decode_call(
+        q, k_pages, v_pages, tables, pos, None, None, active, first,
+        page_tokens=page_tokens, interpret=interpret, layer=layer)
+
+
+def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # static-bounded: causal, partitioned, window -- boolean domains (two programs max each); window is one value per model config (sliding_window)
+              partitioned: bool = False, window: int = 0) -> jax.Array:
     """Dispatch: Pallas flash kernel on TPU, jnp reference elsewhere (the
     kernel's interpret mode is for tests, too slow for CPU serving).
 
@@ -1058,7 +1211,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # 
     Mosaic kernel cannot be partitioned automatically (the TPU lowering
     refuses it), so there the reference runs — like the paged kernel, the
     flash kernel is single-chip until it gets a shard_map wrapper (ROADMAP
-    S7). The branch taken is recorded (``dispatch_tally``)."""
+    S7). The branch taken is recorded (``dispatch_tally``). ``window`` > 0 is a
+    window layer's call (query ``i`` reads keys ``(i - window, i]``): the same
+    gate under its own name, ``attention_window``, and the windowed kernel."""
+    gate = "attention_window" if window else "attention"
     why = _kernel_refusal(q.shape[-1], q.shape[1], k.shape[1])
     if why is None and partitioned:
         why = "partitioned program (kernel is single-chip)"
@@ -1066,11 +1222,15 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # 
         why = f"seq={q.shape[2]} < 128"
     if why is None and k.shape[2] != q.shape[2]:
         why = "kernel assumes self-attention lengths"
+    if why is None and window and not causal:
+        why = "a window without a causal mask"
     if why is None:
-        _record_dispatch("attention", "kernel", "flash", q.shape, k.shape)
+        _record_dispatch(gate, "kernel", "flash", q.shape, k.shape)
+        if window:
+            return flash_window_attention(q, k, v, window=window)
         return flash_attention(q, k, v, causal=causal)
-    _record_dispatch("attention", "reference", why, q.shape, k.shape)
-    return attention_reference(q, k, v, causal=causal)
+    _record_dispatch(gate, "reference", why, q.shape, k.shape)
+    return attention_reference(q, k, v, causal=causal, window=window)
 
 
 # Tests flip this to force the Pallas paged kernel through its interpreter
@@ -1150,6 +1310,66 @@ def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL
         )
     return paged_decode_attention(q, k_pages, v_pages, tables, pos,
                                   page_tokens, layer, k_scale, v_scale)
+
+
+def window_ring_pages(window: int, page_tokens: int) -> int:
+    """Pages a lane keeps in a window layer: the most that ``window``
+    consecutive tokens touch (``window / page_tokens + 1`` where the page
+    divides the window)."""
+    return (int(window) + int(page_tokens) - 2) // int(page_tokens) + 1
+
+
+def window_ring_view(pos: jax.Array, window: int, page_tokens: int,
+                     ring_pages: int):
+    """What a window layer's decode call reads of each lane's ring, worked
+    out from ``pos (S,)`` alone -> ``(tables (S, ring_pages), pos in the view,
+    first valid token in the view)``. Position ``p`` of lane ``s`` lives in
+    page ``s * ring_pages + (p // page_tokens) % ring_pages`` of the window
+    arena; the table is the lane's ring ROTATED so that the page holding the
+    oldest needed token, ``max(0, pos - window + 1)``, comes first, and the
+    two positions are counted from that page's first token."""
+    s_lanes = pos.shape[0]
+    first = jnp.maximum(pos - (window - 1), 0)
+    page0 = first // page_tokens
+    ring = (page0[:, None] + jnp.arange(ring_pages)[None, :]) % ring_pages
+    tables = jnp.arange(s_lanes)[:, None] * ring_pages + ring
+    base = page0 * page_tokens
+    return tables.astype(jnp.int32), pos - base, first - base
+
+
+def paged_window_attention(  # static-bounded: kernel, page_tokens, window, layer, PAGED_KERNEL_INTERPRET -- as paged_attention; window is one value per model config (sliding_window)
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    pos: jax.Array,
+    page_tokens: int,
+    window: int,
+    kernel: bool = True,
+    active: jax.Array | None = None,
+    layer: int = 0,
+) -> jax.Array:
+    """A WINDOW layer's decode dispatch over its ring arena ``(window layers,
+    lanes x ring_pages, Hkv, page_tokens, D)`` at the static ``layer``: each
+    lane attends over the last ``window`` tokens up to ``pos`` in the pages
+    it owns for life (``window_ring_view``: no table is an operand, the view
+    is derived here from ``pos``). ``paged_attention``'s gate under its own
+    name, ``paged_window_attention``: the fused kernel with a first valid
+    token (the view begins at its page, so no page before the window is
+    copied; its device-trace name is ``paged_window_decode_kernel``), else the
+    gather + einsum reference with the same bound."""
+    s_lanes = q.shape[0]
+    ring_pages = _as_arena(k_pages).shape[1] // s_lanes
+    tables, pos_v, first_v = window_ring_view(
+        pos, window, page_tokens, ring_pages)
+    if _paged_kernel_traced("paged_window_attention", kernel, q, k_pages,
+                            head_multiple=128, reads_packed=True):
+        return paged_window_decode_attention_kernel(
+            q, k_pages, v_pages, tables, pos_v, first_v, active,
+            page_tokens=page_tokens, interpret=PAGED_KERNEL_INTERPRET,
+            layer=layer,
+        )
+    return paged_decode_attention(q, k_pages, v_pages, tables, pos_v,
+                                  page_tokens, layer, first=first_v)
 
 
 # ---------------------------------------------------------------------------

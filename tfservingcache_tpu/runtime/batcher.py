@@ -1152,6 +1152,7 @@ class _ContinuousScheduler:
         from tfservingcache_tpu.models.generation import (
             kv_write_lanes,
             sample_path,
+            window_pages_read,
         )
 
         path = None
@@ -1161,6 +1162,11 @@ class _ContinuousScheduler:
                 dict(state.cfg_key)["vocab_size"],
             )
         write_lanes = kv_write_lanes(state.active)
+        # what a window layer's call will read a live lane (0 with no such layer)
+        window = getattr(state, "window_tokens", 0)
+        window_pages = window_pages_read(
+            state.pos, state.active, chunk, window, state.page_tokens
+        ) if window else 0.0
         chunk_t0 = time.monotonic()
         with host_span("decode_chunk"):
             accept = None
@@ -1189,6 +1195,7 @@ class _ContinuousScheduler:
                 chunk = state.spec_tokens + 1
                 # the draft scan and the verify pass write every lane's rows
                 write_lanes = state.slots
+                window_pages = 0.0
         eng.chunks += 1
         now = time.monotonic()
         wasted = 0
@@ -1245,6 +1252,7 @@ class _ContinuousScheduler:
             launch_s=max(0.0, getattr(state, "launched_t", 0.0) - chunk_t0),
             # what a plain chunk's launch had to send the device
             uploads=getattr(state, "uploads", 0) if accept is None else 0,
+            window_pages=window_pages,
         )
         return state
 
@@ -1252,7 +1260,7 @@ class _ContinuousScheduler:
         self, state, chunk, active, admitted, retired, wasted, step_t0,
         prefix_hits=0, prefill_s=0.0, tokens_in=0,
         drafted=0, accepted=0, emitted=None, chunk_s=0.0, emit_s=0.0,
-        write_lanes=0, launch_s=0.0, uploads=0,
+        write_lanes=0, launch_s=0.0, uploads=0, window_pages=0.0,
     ) -> None:
         """One flight-recorder ring entry per chunk boundary (``step_ms``
         split into the prefill clocks ``_step`` already keeps, the decode
@@ -1318,6 +1326,7 @@ class _ContinuousScheduler:
             experts_hit=moe_stats[0], expert_rows_max=moe_stats[1],
             expert_rows_local=moe_stats[2], write_lanes=write_lanes,
             launch_ms=launch_s * 1e3, uploads=uploads,
+            window_pages=window_pages,
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

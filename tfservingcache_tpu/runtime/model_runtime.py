@@ -37,6 +37,7 @@ from tfservingcache_tpu.models.registry import (
     ModelDef,
     TensorSpec,
     lane_layers,
+    window_layers,
     load_artifact,
     static_config,
 )
@@ -665,12 +666,15 @@ class LoadedModel:
 
 
 class PrefillRows(NamedTuple):
-    """The ``pk`` a prefill of a model with lane-state layers hands to
-    ``slot_admit`` (opaque to the engine between the two): its K rows for the
-    arena and the lane state at ``prompt_len`` for the admitted lane."""
+    """The ``pk`` a prefill of a model with lane-state or window layers hands
+    to ``slot_admit`` (opaque to the engine between the two): its K rows for
+    the arena, the lane state at ``prompt_len`` for the admitted lane (None
+    for a model with no such layer) and the prompt's true length (a window
+    layer's ring takes the prompt's LAST rows, never the bucket's pad)."""
 
     k: Any
     lane: Any
+    prompt_len: int = 0
 
 
 @dataclass
@@ -688,7 +692,13 @@ class SlotDecodeState:
     could forget).
     ``lane_state`` holds what the model's layers with a fixed state keep
     (``registry.LaneState``), one slice a lane beside the arena; an admission
-    overwrites its lane's slice, so retirement needs no device work."""
+    overwrites its lane's slice, so retirement needs no device work.
+    ``window`` holds the WINDOW layers' ring arena ``(wk, wv)``, ``(window
+    layers, slots x ring_pages, n_kv, page_tokens, hd)`` each (a ``CacheRow``
+    with a ``window``): ``k`` / ``v``, the tables and the free list are then
+    the GLOBAL layers' alone. A lane owns its ``ring_pages`` pages of every
+    window layer for life, so the ring has no free list, no reservation and
+    no host table, and retirement needs no work there either."""
 
     model_id: ModelId
     cfg_key: tuple
@@ -721,6 +731,13 @@ class SlotDecodeState:
     # device array (lane layers, slots, rows, width) in the model's dtype;
     # None for a model whose layers all keep rows in the arena
     lane_state: Any = None
+    # (wk, wv) device ring arena of the model's window layers; None for a
+    # model with none. ``window_tokens`` is their window, ``window_rows`` the
+    # window layers' indices among the model's row layers (where a prefill's
+    # K/V holds their rows); ``ring_pages`` the pages a lane owns in each
+    window: Any = None
+    window_tokens: int = 0
+    window_rows: tuple = ()
     # -- arena bookkeeping (scheduler-thread-owned) --
     page_tokens: int = 0             # tokens a page; >= 1 in a built state
     arena_pages: int = 0             # usable pages (excludes trash page 0)
@@ -757,6 +774,10 @@ class SlotDecodeState:
     spec_draft_id: Any = None        # ModelId of the attached draft
     spec_draft: Any = None           # the draft's SlotDecodeState
     spec_tokens: int = 0             # draft proposals per verify round
+
+    @property
+    def ring_pages(self) -> int:
+        return 0 if self.window is None else self.window[0].shape[1] // self.slots
 
     def pages_needed(self, tokens: int) -> int:
         return -(-int(tokens) // self.page_tokens)
@@ -2023,9 +2044,12 @@ class TPUModelRuntime(BaseRuntime):
                 draft = None
             # a cached prefix is K/V rows: a model with lane-state layers
             # would continue from it without its state, so it skips the cache
+            # (and so does one with window layers: its fresh prefill is the
+            # path that builds no score block over the cache's length)
             prefix_capable = (
                 self._prefix_cache is not None and ids.shape[0] == 1
                 and not lane_layers(loaded.model_def.layer_state)
+                and not window_layers(loaded.model_def.layer_state)
             )
             if prefix_rows is not None:
                 if prefix_rows < 0:
@@ -2175,8 +2199,10 @@ class TPUModelRuntime(BaseRuntime):
         paged_kernel: bool | None = None,
     ) -> SlotDecodeState:
         from tfservingcache_tpu.models.generation import (
+            _window_of,
             init_lane_state,
             init_paged_cache,
+            window_rows,
         )
 
         if page_tokens is None:
@@ -2195,8 +2221,9 @@ class TPUModelRuntime(BaseRuntime):
         if arena_dtype == "int8":
             self._refuse_latent(loaded, "the int8 arena (kv_arena_dtype)")
         # a mesh, and the serving options whose machinery moves K/V pages and
-        # would leave a lane state behind: refused HERE, once, by the name of
-        # the first that is set, so such a model's first :generate says so
+        # would leave a lane state (or a window layer's ring) behind: refused
+        # HERE, once, by the name of the first that is set, so such a model's
+        # first :generate says so
         self._refuse_lane_state(loaded, next((what for option, what in (
             (arena_dtype == "int8", "the int8 arena (kv_arena_dtype)"),
             (share_prefix_bytes, "shared-prefix KV (kv_share_prefix_bytes)"),
@@ -2240,8 +2267,9 @@ class TPUModelRuntime(BaseRuntime):
         # +1: page 0 is the trash page, permanently reserved
         cache = init_paged_cache(
             cfg, usable + 1, page_tokens, arena_dtype, mesh=arena_mesh,
-            row=loaded.model_def.cache_row,
+            row=loaded.model_def.cache_row, lanes=slots,
         )
+        window = (cache.pop("wk"), cache.pop("wv")) if "wk" in cache else None
         scales = None
         if "k_scale" in cache:
             scales = {"k": cache["k_scale"], "v": cache["v_scale"]}
@@ -2272,6 +2300,9 @@ class TPUModelRuntime(BaseRuntime):
             k=cache["k"],
             v=cache.get("v"),
             lane_state=init_lane_state(cfg, slots),
+            window=window,
+            window_tokens=_window_of(cfg),
+            window_rows=window_rows(cfg),
             scales=scales,
             arena_dtype=arena_dtype,
             kernel=bool(paged_kernel),
@@ -2308,11 +2339,15 @@ class TPUModelRuntime(BaseRuntime):
         nbytes = actual(state.k) + (actual(state.v) if state.v is not None else 0)
         if state.scales is not None:
             nbytes += sum(actual(a) for a in state.scales.values())
-        self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(nbytes)
-        self.metrics.lane_state_bytes.labels(
-            self.metrics.model_label(state.model_id.name,
-                                     state.model_id.version)
-        ).set(0 if state.lane_state is None else actual(state.lane_state))
+        ring = sum(actual(a) for a in state.window or ())
+        # both arenas under the dtype; each under its kind
+        self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(nbytes + ring)
+        model = self.metrics.model_label(state.model_id.name,
+                                         state.model_id.version)
+        self.metrics.kv_arena_bytes.labels(model, "global").set(nbytes)
+        self.metrics.kv_arena_bytes.labels(model, "window").set(ring)
+        self.metrics.lane_state_bytes.labels(model).set(
+            0 if state.lane_state is None else actual(state.lane_state))
 
     def mesh_topology(self) -> dict | None:
         """Structural stamp for /monitoring/engine: a number without its
@@ -2331,8 +2366,11 @@ class TPUModelRuntime(BaseRuntime):
         if st is not None and self.metrics is not None:
             label = st.arena_dtype or str(st.k.dtype)
             self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(0)
-            self.metrics.lane_state_bytes.labels(self.metrics.model_label(
-                st.model_id.name, st.model_id.version)).set(0)
+            model = self.metrics.model_label(
+                st.model_id.name, st.model_id.version)
+            self.metrics.lane_state_bytes.labels(model).set(0)
+            for kind in ("global", "window"):
+                self.metrics.kv_arena_bytes.labels(model, kind).set(0)
 
     @_mesh_serialized
     def slot_prefill(
@@ -2387,7 +2425,8 @@ class TPUModelRuntime(BaseRuntime):
         tk = np.int32(top_k)
 
         hit = None
-        if self._prefix_cache is not None and not lane_layers(
+        windowed = bool(window_layers(loaded.model_def.layer_state))
+        if self._prefix_cache is not None and not windowed and not lane_layers(
                 loaded.model_def.layer_state):
             hit = self._prefix_cache.lookup(model_id, prompt)
             if hit is not None:
@@ -2418,8 +2457,8 @@ class TPUModelRuntime(BaseRuntime):
                 rng, temp, tk, cfg_key=cfg_key,
                 family=loaded.model_def.family,
             )
-            if lane is not None:
-                pk = PrefillRows(pk, lane)
+            if lane is not None or windowed:
+                pk = PrefillRows(pk, lane, p)
         return int(np.asarray(tok)[0]), pk, pv, hit is not None, last
 
     # -- chunked prefill over the paged arena (ISSUE 19) ---------------------
@@ -2870,22 +2909,44 @@ class TPUModelRuntime(BaseRuntime):
         from tfservingcache_tpu.models.generation import (
             _lane_insert_jit,
             _paged_insert_jit,
+            _window_paged_insert_jit,
         )
 
+        prompt_len = 0
         if isinstance(pk, PrefillRows):
-            # a child span where a trace is open; on the engine's thread (no
-            # trace open: a span there would be a root of its own an
-            # admission) the profiler's ``tpusc.state_insert`` annotation alone
-            span = (functools.partial(TRACER.span, lane=int(idx))
-                    if current_span() is not None else host_span)
-            with span("state_insert"):
-                state.lane_state = _lane_insert_jit(
-                    state.lane_state, pk.lane, np.int32(idx))
-            pk = pk.k
-        elif state.lane_state is not None:
+            if pk.lane is not None:
+                # a child span where a trace is open; on the engine's thread
+                # (no trace open: a span there would be a root of its own an
+                # admission) the profiler's ``tpusc.state_insert`` annotation
+                # alone
+                span = (functools.partial(TRACER.span, lane=int(idx))
+                        if current_span() is not None else host_span)
+                with span("state_insert"):
+                    state.lane_state = _lane_insert_jit(
+                        state.lane_state, pk.lane, np.int32(idx))
+            pk, prompt_len = pk.k, pk.prompt_len
+        elif state.lane_state is not None or state.window is not None:
             raise RuntimeError_(
-                f"{state.family}: an admission without its lane state (a "
-                "prefill that continued from cached rows?)")
+                f"{state.family}: an admission without its lane state or its "
+                "prompt's length (a prefill that continued from cached rows?)")
+        if state.window is not None:
+            # both arenas in one dispatch: every row of a global layer into
+            # the lane's pages, a window layer's last rows into its ring
+            state.k, state.v, *state.window = _window_paged_insert_jit(
+                state.k, state.v, *state.window, pk, pv,
+                np.asarray(state.block_tables[idx], np.int32),
+                np.int32(idx), np.int32(prompt_len),
+                page_tokens=state.page_tokens, window_layers=state.window_rows,
+                ring_pages=state.ring_pages,
+            )
+            state.window = tuple(state.window)
+            dropped = max(0, prompt_len - state.ring_pages * state.page_tokens)
+            if self.metrics is not None and dropped:
+                self.metrics.gen_window_rows_dropped.labels(
+                    self.metrics.model_label(
+                        state.model_id.name, state.model_id.version)
+                ).inc(dropped * len(state.window_rows))
+            return
         state.k, state.v, state.scales = _paged_insert_jit(
             state.k, state.v, state.scales, pk, pv,
             np.asarray(state.block_tables[idx], np.int32),
@@ -2925,14 +2986,20 @@ class TPUModelRuntime(BaseRuntime):
                 _check_trash_unreachable(state)
             tables, tok, pos, active, temps, topks, counter = _chunk_operands(
                 state)
+            # a model with window layers hands in their ring arena and gets
+            # it back last (one output more); every other call is as it was
+            ring = () if state.window is None else (state.window,)
             (state.k, state.v, state.scales, tok, pos,
-             toks, stats, state.lane_state, counter) = _paged_decode_chunk_jit(
+             toks, stats, state.lane_state, counter, *ring
+             ) = _paged_decode_chunk_jit(
                 loaded.params, state.k, state.v, state.scales,
                 tables, tok, pos, active, counter, temps, topks,
-                state.lane_state,
+                state.lane_state, *ring,
                 cfg_key=state.cfg_key, family=state.family, chunk=chunk,
                 page_tokens=state.page_tokens, kernel=state.kernel,
             )
+            if ring:
+                state.window = ring[0]
         state.launched_t = time.monotonic()
         # one fetch: an expert model's routing numbers ride with the tokens
         with host_span("chunk_fetch"):
@@ -3558,27 +3625,34 @@ class TPUModelRuntime(BaseRuntime):
 
     def _refuse_lane_state(self, loaded: LoadedModel,
                            what: str | None = None) -> None:
-        """What a model with lane-state layers (``registry.LaneState``: a
-        fixed state a request beside its pages) cannot do yet is refused by
-        name, never answered wrongly: generation on a chip-group mesh always
-        (the state array is not partitioned), and ``what`` where the caller is
-        about to use it. Everything refused moves or reuses K/V PAGES and
-        would leave the lane's state behind: shared-prefix hits, conversation
-        park/resume, a ``draft_model``, chunked prefill, the int8 arena.
+        """What a model that keeps state BESIDE the global arena cannot do yet
+        is refused by name, never answered wrongly: a model with lane-state
+        layers (``registry.LaneState``: a fixed state a request beside its
+        pages) and a model with window layers (a ``registry.CacheRow`` with a
+        ``window``: a ring of pages a lane, with no block table and no row
+        older than a window). Generation on a chip-group mesh always (neither
+        is partitioned), and ``what`` where the caller is about to use it.
+        Everything refused moves or reuses K/V PAGES by a lane's table, or
+        runs a forward of several positions over the arena, and would leave
+        the lane's state behind or find no ring to turn: shared-prefix hits,
+        conversation park/resume, a ``draft_model``, chunked prefill, the int8
+        arena.
 
         One site an entrance: the serving options where the slot state is
         built; a request's ``draft_model`` in ``generate``; and the three
         methods an engine reaches with the runtime's option unset
         (``park_lane`` by priority preemption, ``slot_prefill_chunk`` and
         ``slot_attach_draft`` by the engine's own constructor arguments)."""
-        if not lane_layers(loaded.model_def.layer_state):
+        state = loaded.model_def.layer_state
+        kind = ("lane-state layers" if lane_layers(state) else
+                "window layers" if window_layers(state) else None)
+        if kind is None:
             return
         if self.mesh is not None:
             what = "generation on a chip-group mesh"
         if what:
             raise RuntimeError_(
-                f"{loaded.model_def.family} (lane-state layers) does not "
-                f"support {what}")
+                f"{loaded.model_def.family} ({kind}) does not support {what}")
 
     def signature(self, model_id: ModelId):
         loaded = self._resident.get(model_id, touch=False)

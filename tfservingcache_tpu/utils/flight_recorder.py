@@ -114,6 +114,13 @@ STEP_FIELDS = (
     # mesh. 0 for a spec round and
     # for a boundary that ran no chunk
     "uploads",
+    # appended field (ISSUE 39 window layers): the pages a WINDOW layer's
+    # decode call read a live lane, mean over the chunk's steps, worked out
+    # from the ``pos`` / ``active`` mirrors the chunk was dispatched with
+    # (generation.window_pages_read: the pages that hold the last ``window``
+    # tokens, at most a ring's). 0.0 for a model with no window layer, for a
+    # spec round and for a boundary that ran no chunk
+    "window_pages",
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -126,7 +133,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 25:
+    if len(e) == 26:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -137,7 +144,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "prefill_ms": e[16], "chunk_ms": e[17], "emit_ms": e[18],
             "experts_hit": e[19], "expert_rows_max": e[20],
             "expert_rows_local": e[21], "write_lanes": e[22],
-            "launch_ms": e[23], "uploads": e[24],
+            "launch_ms": e[23], "uploads": e[24], "window_pages": e[25],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -299,6 +306,7 @@ class FlightRecorder:
         write_lanes: int = 0,
         launch_ms: float = 0.0,
         uploads: int = 0,
+        window_pages: float = 0.0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -308,7 +316,7 @@ class FlightRecorder:
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
             round(experts_hit, 3), round(expert_rows_max, 3),
             round(expert_rows_local, 3), write_lanes, round(launch_ms, 4),
-            uploads,
+            uploads, round(window_pages, 3),
         ))
 
     def note_phases(

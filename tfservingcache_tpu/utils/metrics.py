@@ -396,6 +396,21 @@ class Metrics:
             "pages)",
             ["model"], registry=r,
         )
+        self.kv_arena_bytes = Gauge(
+            "tpusc_kv_arena_bytes",
+            "Device bytes of a model's paged KV arenas by kind: global (the "
+            "layers that keep every row: pages handed out by the free list) "
+            "and window (the layers that keep one window of rows: a ring of "
+            "pages a lane, 0 for a model with no window layer)",
+            ["model", "kind"], registry=r,
+        )
+        self.gen_window_rows_dropped = Counter(
+            "tpusc_gen_window_rows_dropped_total",
+            "Prompt rows an admission did not store in a model's window "
+            "layers (rows older than a ring of pages holds, summed over the "
+            "window layers)",
+            ["model"], registry=r,
+        )
         self.gen_kv_arena_bytes = Gauge(
             "tpusc_gen_kv_arena_bytes",
             "Device bytes allocated to the paged KV arena (pages plus, "

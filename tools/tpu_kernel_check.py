@@ -64,12 +64,20 @@ def main() -> int:
     # pages, an arena of 16384 pages x 6 layers): parity with the gather +
     # einsum reference, ms, the share of the 640 B-a-token roofline, the same
     # attention with the row stored as two arrays, and the block sizes.
+    # test_window_layers.py carries the window layers' rows at Mellum2's
+    # shapes (32 query heads over 4 KV heads of 128, window 1024, a ring of
+    # 65 pages a lane): the decode kernel with a first valid token at 1 / 6 /
+    # 16 / 32 live lanes of 600-8000 tokens against the reference and against
+    # a global call over the same lanes, and the windowed flash kernel at
+    # 1024 / 4096 / 8192 tokens against the whole causal triangle:
+    # `-k "window and on_tpu"`, ~2 min.
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
         os.path.join(REPO, "tests", "test_paged_kernel.py"),
         os.path.join(REPO, "tests", "test_olmoe.py"),
         os.path.join(REPO, "tests", "test_mla_moe.py"),
+        os.path.join(REPO, "tests", "test_window_layers.py"),
         "-v", "-rs", "-s", "--no-header",
         *extra,
     ]
