@@ -4,7 +4,8 @@
       run that ran a chunk, 0 on one that ran none;
   (c) a CPU profiler capture of a two-chunk ``:generate`` holds
       ``tpusc.chunk_launch`` and ``tpusc.chunk_fetch`` nested in
-      ``tpusc.decode_chunk``, the fetch after the launch;
+      ``tpusc.decode_chunk``, a chunk's fetch after its launch (and, since
+      ISSUE 40, the second chunk's launch before the first one's fetch);
   (e) the spans and the clock change nothing the device sees: the decode chunk
       is called with the parent's operands but for the keys (ISSUE 36: the
       chunk counter goes in and the program derives the parent's keys) and
@@ -59,8 +60,11 @@ def test_b_launch_ms_is_a_part_of_chunk_ms(tmp_path):
     ran = [s for s in steps if s["chunk"] > 0]
     assert len(ran) >= 2
     for s in ran:
-        # each field is rounded to 1e-4 ms on its own
-        assert 0.0 < s["launch_ms"] <= s["chunk_ms"] + 1e-4, s
+        # each field is rounded to 1e-4 ms on its own; a boundary whose chunk
+        # the last one launched ahead (ISSUE 40) launches the next or nothing
+        assert 0.0 <= s["launch_ms"] <= s["chunk_ms"] + 1e-4, s
+        assert s["launch_ms"] > 0.0 or s["ahead"] == 1, s
+    assert ran[0]["ahead"] == 0 and any(s["ahead"] for s in ran)
     assert all(s["launch_ms"] == 0.0 for s in steps if s["chunk"] == 0)
 
 
@@ -91,9 +95,16 @@ def test_c_a_capture_holds_the_two_child_spans_inside_decode_chunk(tmp_path):
                     spans.setdefault(n, []).append((s, e))
     chunks = sorted(spans["tpusc.decode_chunk"])
     launches, fetches = sorted(spans["tpusc.chunk_launch"]), sorted(spans["tpusc.chunk_fetch"])
-    assert len(chunks) == len(launches) == len(fetches) >= 2
-    for (cs, ce), (ls, le), (fs, fe) in zip(chunks, launches, fetches):
-        assert cs <= ls <= le <= fs <= fe <= ce
+    # a boundary fetches one chunk; since ISSUE 40 the first one launches two
+    # (its own and the next, ahead of its fetch) and the second none
+    assert len(chunks) == len(launches) == len(fetches) == 2
+    inside = lambda span: [c for c in chunks if c[0] <= span[0] and span[1] <= c[1]]  # noqa: E731
+    for (ls, le), (fs, fe) in zip(launches, fetches):
+        assert len(inside((ls, le))) == len(inside((fs, fe))) == 1
+        assert le <= fs                      # a chunk's fetch follows its launch
+    assert launches[1][1] <= fetches[0][0]   # chunk 2 is up before chunk 1 is fetched
+    assert inside(launches[1]) == inside(fetches[0]) == [chunks[0]]
+    assert inside(fetches[1]) == [chunks[1]]
 
 
 def _admitted_state(rt, mid):

@@ -114,13 +114,18 @@ def _scenario(tmp_path, monkeypatch, every_chunk, arena_dtype="", config=TINY):
     monkeypatch.setattr(batcher_mod.secrets, "randbits", lambda _b: next(seeds))
     rt, mid = _load(tmp_path, "lm", config=config, kv_arena_dtype=arena_dtype)
     if every_chunk:
-        real = rt.slot_decode_chunk
+        # the engine calls the launch half itself (ISSUE 40); forgetting what
+        # the device holds is only sound with no chunk in flight, so this side
+        # also launches every chunk at its own boundary
+        real = rt.slot_decode_chunk_launch
 
-        def forget_then_decode(state, chunk):
+        def forget_then_launch(state, chunk):
             state.resident.clear()
             return real(state, chunk)
 
-        monkeypatch.setattr(rt, "slot_decode_chunk", forget_then_decode)
+        monkeypatch.setattr(rt, "slot_decode_chunk_launch", forget_then_launch)
+        monkeypatch.setattr(batcher_mod._ContinuousScheduler, "_chain",
+                            lambda self, *_a: None)
     RECORDER.clear()
     eng = ContinuousGenerateEngine(
         rt, slots=3, chunk_tokens=4, page_tokens=PT, arena_pages=48,

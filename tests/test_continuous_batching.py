@@ -14,6 +14,7 @@ from tfservingcache_tpu.models.registry import export_artifact
 from tfservingcache_tpu.runtime.batcher import ContinuousGenerateEngine
 from tfservingcache_tpu.runtime.model_runtime import TPUModelRuntime
 from tfservingcache_tpu.types import Model, ModelId
+from tfservingcache_tpu.utils.flight_recorder import RECORDER
 from tfservingcache_tpu.utils.metrics import Metrics
 
 TINY = {
@@ -82,7 +83,9 @@ def test_deterministic_eos_waste_bounded_by_chunk(tmp_path, chunk):
     rollout deterministically hits EOS early, the row retires AT the EOS
     step and is zero-padded after it; chunk=1 records ZERO wasted steps,
     chunk=k at most k-1 (here exactly the rest of the chunk the EOS fell
-    into) — waste is bounded per retirement, not per batch drain."""
+    into) — waste is bounded per retirement, not per batch drain. A lane that
+    meets EOS with the next chunk already launched (ISSUE 40: an EOS is found
+    only at its chunk's fetch) adds that one whole chunk, dropped."""
     prompt, roll, eos, at = _eos_probe(tmp_path)
     metrics = Metrics()
     rt, mid = _load(
@@ -92,17 +95,28 @@ def test_deterministic_eos_waste_bounded_by_chunk(tmp_path, chunk):
     eng = ContinuousGenerateEngine(
         rt, slots=2, chunk_tokens=chunk, metrics=metrics
     )
+    RECORDER.clear()
     try:
         out = eng.generate(mid, prompt, max_new_tokens=16)
         # stopped AT the eos step: the rollout up to it, zero-padded after
         assert (out[0, : at + 1] == roll[: at + 1]).all()
         assert int(out[0, at]) == eos
         assert (out[0, at + 1:] == 0).all()
-        wasted = metrics.gen_wasted_steps.labels("continuous")._value.get()
-        assert 0 <= wasted <= chunk - 1
+        sched = eng._scheds[mid]
+        deadline = time.monotonic() + 10.0
+        while sched._flight is not None and time.monotonic() < deadline:
+            time.sleep(0.005)       # the chunk launched ahead has its ring entry
+        steps = RECORDER.snapshot(tail=RECORDER.ring_entries)["models"][str(mid)]["steps"]
+        found = next(i for i, s in enumerate(steps) if s["retired"])
         # token 0 is the prefill's; decode token d = at - 1 fell at place
         # d % chunk of its chunk, whose remaining steps are the waste
-        assert wasted == chunk - 1 - (at - 1) % chunk
+        assert steps[found]["wasted"] == chunk - 1 - (at - 1) % chunk <= chunk - 1
+        # the row had budget left, so the next chunk was up when the EOS was found
+        dropped = steps[found + 1:]
+        assert [(s["ahead"], s["active"], s["wasted"]) for s in dropped] == [
+            (1, 1, dropped[0]["chunk"])]
+        wasted = metrics.gen_wasted_steps.labels("continuous")._value.get()
+        assert wasted == steps[found]["wasted"] + dropped[0]["chunk"] < 2 * chunk
     finally:
         eng.close()
         rt.close()

@@ -559,6 +559,7 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_disk_cache_bytes_in_use",
     "tpusc_evictions",
     "tpusc_gen_admission_wait_seconds",
+    "tpusc_gen_chunks",
     "tpusc_gen_kv_arena_bytes",
     "tpusc_lane_state_bytes",
     "tpusc_kv_arena_bytes",

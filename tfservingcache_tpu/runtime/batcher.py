@@ -436,6 +436,29 @@ class _ContinuousReq:
     pf_seed: int = 0
 
 
+@dataclass
+class _Flight:
+    """A dispatched decode chunk the scheduler has not emitted yet, with what
+    its boundary's ring entry says of it (worked out from the mirrors it was
+    launched with). ``handle`` is the runtime's (``slot_decode_chunk_launch``)
+    for a chunk still to fetch; ``toks`` is set instead where the dispatch
+    came back fetched (a speculation round, whose per-row ``accept`` counts
+    ride along, or a runtime with no split)."""
+
+    chunk: int
+    reqs: list                      # the lanes' rows at the launch
+    handle: Any = None
+    toks: Any = None
+    accept: Any = None
+    ahead: int = 0                  # 1: launched before the chunk before it was fetched
+    path: str | None = None
+    write_lanes: int = 0
+    window_pages: float = 0.0
+    launch_s: float = 0.0
+    uploads: int = 0
+    drafted: int = 0
+
+
 @lockchecked
 class _ContinuousScheduler:
     """One model's decode loop: a dedicated thread that admits pending rows
@@ -458,6 +481,9 @@ class _ContinuousScheduler:
         # later boundary decodes plain without re-raising. Scheduler-thread
         # only, like `lanes`/`state`.
         self._spec_broken = False
+        # the chunk launched AHEAD of the last boundary's fetch, which the
+        # next boundary fetches (``_chain``); scheduler-thread only
+        self._flight: _Flight | None = None
         self.thread = threading.Thread(
             target=self._loop, daemon=True,
             name=f"tpusc-cdecode-{model_id.name}",
@@ -605,6 +631,7 @@ class _ContinuousScheduler:
                 while (
                     not self.pending
                     and not any(l is not None for l in lanes)
+                    and self._flight is None
                     and not self.stopped
                 ):
                     self.cv.wait()
@@ -630,6 +657,9 @@ class _ContinuousScheduler:
                     queued = list(self.pending)
                     self.pending.clear()
                 lanes = [None] * self.engine.slots
+                # a chunk in flight dies with the state: its tokens were
+                # never emitted, recovery re-prefills prompt + emitted
+                self._flight = None
                 survivors = self._triage(inflight, queued, e)
                 RECORDER.dump(
                     "engine_crash", model=str(self.model_id),
@@ -651,6 +681,15 @@ class _ContinuousScheduler:
                         return
                     # engine closing mid-crash: nowhere to requeue
                     self._fail(survivors, e)
+        if self._flight is not None:
+            # a launched chunk is fetched or its state dropped: the state
+            # outlives this engine and its mirrors must not trail the device
+            try:
+                rt.slot_decode_chunk_fetch(state, self._flight.handle)
+            except Exception:  # noqa: BLE001 - best-effort at close
+                log.debug("fetch of the chunk in flight failed at close",
+                          exc_info=True)
+            self._flight = None
         self._fail(doomed, RuntimeError_("continuous generate engine closed"))
         self.engine._set_active(self.model_id, 0)
         self.engine._set_pages(self.model_id, 0, 0)
@@ -666,8 +705,15 @@ class _ContinuousScheduler:
         lab_faults.fire("engine_step", model=str(self.model_id))
         step_t0 = time.monotonic()
         eos = getattr(rt, "eos_id_of", lambda _m: None)(self.model_id)
-        free = [i for i, l in enumerate(lanes) if l is None]
-        if state is not None:
+        # a chunk the last boundary launched ahead is this boundary's: no
+        # admission while it is in flight (the mirrors trail the device until
+        # its fetch); ``_chain`` launched it because none was possible and
+        # launches no further one once a queued row can be admitted, so the
+        # boundary after this one admits it
+        flight = self._flight
+        free = [] if flight is not None else [
+            i for i, l in enumerate(lanes) if l is None]
+        if state is not None and flight is None:
             # draft attach/detach happens at the boundary, before admission,
             # so every row admitted below sees the final spec configuration
             # (page budgets include draft headroom iff the draft is on)
@@ -1071,7 +1117,7 @@ class _ContinuousScheduler:
                 self.model_id, sum(l is not None for l in lanes)
             )
         pf_chunks = 0
-        if eng.prefill_chunk_tokens > 0 and state is not None:
+        if eng.prefill_chunk_tokens > 0 and state is not None and flight is None:
             # chunked-prefill interleave: every PREFILLING lane advances
             # exactly ONE chunk per boundary, so a long prompt's prefill is
             # spread across boundaries instead of monopolizing one dispatch
@@ -1087,7 +1133,9 @@ class _ContinuousScheduler:
                     self.model_id, sum(l is not None for l in lanes)
                 )
         self._update_page_gauge(state)
-        if not any(l is not None and l.pf_pos is None for l in lanes):
+        if flight is None and not any(
+            l is not None and l.pf_pos is None for l in lanes
+        ):
             if admitted_n or retired_n or pf_chunks:
                 # prefill-only boundary (every admitted row finished at its
                 # first token, or every occupied lane is still PREFILLING):
@@ -1097,6 +1145,100 @@ class _ContinuousScheduler:
                     prefix_hits_n, prefill_s_sum, tokens_in_n,
                 )
             return state
+        chunk_t0 = time.monotonic()
+        # host time of the launches this boundary made, what they had to send
+        launch_s, uploads = 0.0, 0
+        with host_span("decode_chunk"):
+            if flight is None:
+                flight = self._dispatch(rt, state, lanes)
+                launch_s, uploads = flight.launch_s, flight.uploads
+            # the next chunk goes up BEFORE this one is fetched where the next
+            # boundary is foreseen decode-only: the device then runs on while
+            # the host fetches, emits and comes round
+            nxt = self._chain(rt, state, lanes, flight)
+            if nxt is not None:
+                # held from here on, before the emission can wake a client:
+                # ``_flight`` is None only when every launched chunk has its
+                # ring entry
+                self._flight = nxt
+                launch_s += nxt.launch_s
+                uploads += nxt.uploads
+            toks = flight.toks
+            if toks is None:
+                toks = rt.slot_decode_chunk_fetch(state, flight.handle)
+        chunk, accept = flight.chunk, flight.accept
+        eng.chunks += 1
+        now = time.monotonic()
+        wasted = 0
+        active_rows = 0
+        accepted = int(accept.sum()) if accept is not None else 0
+        with host_span("emit"):
+            for idx, req in enumerate(flight.reqs):
+                if req is None or req.pf_pos is not None:
+                    continue
+                active_rows += 1
+                # spec rounds emit a VARIABLE per-row prefix (the accepted
+                # draft run + the verify's correction token); plain chunks
+                # emit exactly `chunk` tokens per live lane
+                n_emit = chunk if accept is None else int(accept[idx])
+                if lanes[idx] is not req:
+                    # the row met EOS in the chunk before, found at that
+                    # chunk's fetch with this one already launched: the
+                    # device computed these steps for a finished request
+                    wasted += n_emit
+                    continue
+                for j in range(n_emit):
+                    t = int(toks[idx, j])
+                    self._emit(req, t)
+                    if (eos is not None and t == eos) or len(req.tokens) >= req.max_new:
+                        # retire NOW: steps the chunk computed past this point
+                        # were for a finished request — the waste continuous
+                        # batching exists to bound (< chunk). Under spec this
+                        # also drops accepted tokens past a mid-round EOS.
+                        wasted += n_emit - (j + 1)
+                        state.active[idx] = False
+                        lanes[idx] = None
+                        self._retire_pages(state, idx, req)
+                        req.finish_t = now
+                        req.done.set()
+                        retired_n += 1
+                        break
+        emit_s = time.monotonic() - now
+        if eng.metrics is not None:
+            eng.metrics.gen_sample_steps.labels(flight.path).inc(chunk)
+            eng.metrics.gen_kv_write_steps.labels(
+                str(flight.write_lanes)).inc(chunk)
+            eng.metrics.gen_chunks.labels(
+                "ahead" if flight.ahead else "boundary").inc()
+            if wasted:
+                eng.metrics.gen_wasted_steps.labels("continuous").inc(wasted)
+        if accept is not None and hasattr(rt, "_spec_observe"):
+            # acceptance health + cumulative counters: one verify round per
+            # active lane this boundary
+            rt._spec_observe(
+                self.model_id, state.spec_draft_id, accepted, active_rows,
+                engine="continuous",
+            )
+        eng._set_active(self.model_id, sum(l is not None for l in lanes))
+        self._update_page_gauge(state)
+        self._record_step(
+            state, chunk, active_rows, admitted_n, retired_n, wasted, step_t0,
+            prefix_hits_n, prefill_s_sum, tokens_in_n,
+            drafted=flight.drafted, accepted=accepted,
+            emitted=accepted if accept is not None else None,
+            chunk_s=now - chunk_t0, emit_s=emit_s,
+            write_lanes=flight.write_lanes,
+            launch_s=launch_s, uploads=uploads,
+            window_pages=flight.window_pages, ahead=flight.ahead,
+        )
+        self._flight = nxt
+        return state
+
+    def _dispatch(self, rt, state, lanes) -> _Flight:
+        """A boundary's own dispatch, after its admissions: a speculation
+        round where the draft is attached and healthy (it comes back
+        fetched), else one plain chunk, launched."""
+        eng = self.engine
         # chunk clamped to the pow2 cover of the largest remaining budget:
         # when every active row needs < chunk_tokens more, a smaller
         # compiled chunk (log2-bounded program count) trims the overshoot.
@@ -1107,9 +1249,6 @@ class _ContinuousScheduler:
             for l in lanes if l is not None and l.pf_pos is None
         )
         chunk = max(1, min(eng.chunk_tokens, _next_bucket(max_remaining)))
-        active_rows = sum(
-            l is not None and l.pf_pos is None for l in lanes
-        )
         d_st = getattr(state, "spec_draft", None)
         use_spec = (
             d_st is not None
@@ -1146,127 +1285,153 @@ class _ContinuousScheduler:
                     pg = int(state.block_tables[cidx, slot])
                     if pg and int(state.page_refs[pg]) > 1:
                         rt.slot_cow(state, cidx, slot)
-        # what the sampler and the KV write will pay for, from the mirrors
-        # this dispatch takes (the emission loop below clears ``active`` for
-        # the rows it retires)
+        if use_spec:
+            path = self._sample_path(state)
+            try:
+                toks, accept = rt.slot_decode_spec_round(state)
+            except ModelNotLoadedError as e:
+                if not rt.is_loaded(self.model_id):
+                    raise
+                # the draft was evicted between the residency check and
+                # the round: detach and decode plain — target lanes are
+                # untouched (the round failed before any state update)
+                log.info(
+                    "continuous spec detach model=%s (%s)", self.model_id, e,
+                )
+                state.spec_draft = None
+                state.spec_draft_id = None
+                state.spec_tokens = 0
+            else:
+                # ring/ledger semantics: a spec round can emit up to spec+1
+                # tokens per lane in one dispatch — that is its "chunk"; the
+                # draft scan and the verify pass write every lane's rows
+                rows = sum(l is not None and l.pf_pos is None for l in lanes)
+                return _Flight(
+                    chunk=state.spec_tokens + 1, reqs=list(lanes), toks=toks,
+                    accept=accept, write_lanes=state.slots,
+                    drafted=spec_span * rows, path=path,
+                )
+        return self._launch(rt, state, lanes, chunk, state.pos)
+
+    def _sample_path(self, state) -> str | None:
+        """What the sampler will pay for, from the mirrors a dispatch takes
+        (the emission loop clears ``active`` for the rows it retires)."""
+        if self.engine.metrics is None:
+            return None
+        from tfservingcache_tpu.models.generation import sample_path
+
+        return sample_path(
+            state.active, state.temps, state.topks,
+            dict(state.cfg_key)["vocab_size"],
+        )
+
+    def _launch(self, rt, state, lanes, chunk: int, pos, ahead: int = 0) -> _Flight:
+        """Launch one plain chunk of ``chunk`` steps from the lanes' positions
+        ``pos`` (the mirror, or what the host foresees where a chunk in flight
+        has the device ahead of it). A runtime without the split decodes the
+        chunk whole and it comes back fetched."""
         from tfservingcache_tpu.models.generation import (
             kv_write_lanes,
-            sample_path,
             window_pages_read,
         )
 
-        path = None
-        if eng.metrics is not None:
-            path = sample_path(
-                state.active, state.temps, state.topks,
-                dict(state.cfg_key)["vocab_size"],
-            )
+        # what the sampler and the KV write will pay for
+        path = self._sample_path(state)
         write_lanes = kv_write_lanes(state.active)
         # what a window layer's call will read a live lane (0 with no such layer)
         window = getattr(state, "window_tokens", 0)
         window_pages = window_pages_read(
-            state.pos, state.active, chunk, window, state.page_tokens
+            pos, state.active, chunk, window, state.page_tokens
         ) if window else 0.0
-        chunk_t0 = time.monotonic()
-        with host_span("decode_chunk"):
-            accept = None
-            if use_spec:
-                try:
-                    toks, accept = rt.slot_decode_spec_round(state)
-                except ModelNotLoadedError as e:
-                    if rt.is_loaded(self.model_id):
-                        # the draft was evicted between the residency check and
-                        # the round: detach and decode plain — target lanes are
-                        # untouched (the round failed before any state update)
-                        log.info(
-                            "continuous spec detach model=%s (%s)",
-                            self.model_id, e,
-                        )
-                        state.spec_draft = None
-                        state.spec_draft_id = None
-                        state.spec_tokens = 0
-                    else:
-                        raise
-            if accept is None:
-                toks = rt.slot_decode_chunk(state, chunk)
-            else:
-                # ring/ledger semantics: a spec round can emit up to spec+1
-                # tokens per lane in one dispatch — that is its "chunk"
-                chunk = state.spec_tokens + 1
-                # the draft scan and the verify pass write every lane's rows
-                write_lanes = state.slots
-                window_pages = 0.0
-        eng.chunks += 1
-        now = time.monotonic()
-        wasted = 0
-        drafted = spec_span * active_rows if accept is not None else 0
-        accepted = int(accept.sum()) if accept is not None else 0
-        with host_span("emit"):
-            for idx, req in enumerate(lanes):
-                if req is None or req.pf_pos is not None:
-                    continue
-                # spec rounds emit a VARIABLE per-row prefix (the accepted
-                # draft run + the verify's correction token); plain chunks
-                # emit exactly `chunk` tokens per live lane
-                n_emit = chunk if accept is None else int(accept[idx])
-                for j in range(n_emit):
-                    t = int(toks[idx, j])
-                    self._emit(req, t)
-                    if (eos is not None and t == eos) or len(req.tokens) >= req.max_new:
-                        # retire NOW: steps the chunk computed past this point
-                        # were for a finished request — the waste continuous
-                        # batching exists to bound (< chunk). Under spec this
-                        # also drops accepted tokens past a mid-round EOS.
-                        wasted += n_emit - (j + 1)
-                        state.active[idx] = False
-                        lanes[idx] = None
-                        self._retire_pages(state, idx, req)
-                        req.finish_t = now
-                        req.done.set()
-                        retired_n += 1
-                        break
-        emit_s = time.monotonic() - now
-        if eng.metrics is not None:
-            eng.metrics.gen_sample_steps.labels(path).inc(chunk)
-            eng.metrics.gen_kv_write_steps.labels(str(write_lanes)).inc(chunk)
-            if wasted:
-                eng.metrics.gen_wasted_steps.labels("continuous").inc(wasted)
-        if accept is not None and hasattr(rt, "_spec_observe"):
-            # acceptance health + cumulative counters: one verify round per
-            # active lane this boundary
-            rt._spec_observe(
-                self.model_id, state.spec_draft_id, accepted, active_rows,
-                engine="continuous",
-            )
-        eng._set_active(self.model_id, sum(l is not None for l in lanes))
-        self._update_page_gauge(state)
-        self._record_step(
-            state, chunk, active_rows, admitted_n, retired_n, wasted, step_t0,
-            prefix_hits_n, prefill_s_sum, tokens_in_n,
-            drafted=drafted, accepted=accepted,
-            emitted=accepted if accept is not None else None,
-            chunk_s=now - chunk_t0, emit_s=emit_s, write_lanes=write_lanes,
-            # a plain chunk's launch path ended at ``launched_t``; a spec
-            # round (or a runtime that keeps no such clock) leaves an older
-            # time there and records 0
-            launch_s=max(0.0, getattr(state, "launched_t", 0.0) - chunk_t0),
-            # what a plain chunk's launch had to send the device
-            uploads=getattr(state, "uploads", 0) if accept is None else 0,
+        t0 = time.monotonic()
+        handle = toks = None
+        if hasattr(rt, "slot_decode_chunk_launch"):
+            handle = rt.slot_decode_chunk_launch(state, chunk)
+        else:
+            toks = rt.slot_decode_chunk(state, chunk)
+        return _Flight(
+            chunk=chunk, reqs=list(lanes), handle=handle, toks=toks,
+            ahead=ahead, path=path, write_lanes=write_lanes,
             window_pages=window_pages,
+            # the launch path ended at ``launched_t`` (a runtime that keeps
+            # no such clock leaves an older time there and records 0)
+            launch_s=max(0.0, getattr(state, "launched_t", 0.0) - t0),
+            # what the launch had to send the device
+            uploads=getattr(state, "uploads", 0),
         )
-        return state
+
+    def _chain(self, rt, state, lanes, cur: _Flight) -> _Flight | None:
+        """Launch the chunk AFTER ``cur`` now, with ``cur`` still to fetch,
+        if the boundary between them is foreseen decode-only. Its operands
+        are ``cur``'s own outputs on the device and residents the host did
+        not touch, and the host knows every live lane gets exactly
+        ``cur.chunk`` tokens, so it needs nothing from ``cur``'s fetch. None
+        (the next boundary is then today's) where the runtime has no split,
+        the operands are not resident (a mesh), a draft is attached, a lane
+        is PREFILLING or ends at ``max_new`` inside ``cur`` (its retirement
+        rewrites ``active`` and the tables), a queued row could be admitted,
+        or a lane would write a page it does not own alone. A lane that meets
+        EOS inside ``cur`` is found only at its fetch: the chunk launched
+        here computes for it on its own pages, and its tokens are dropped."""
+        if cur.handle is None or not getattr(state, "resident", None) \
+                or getattr(state, "spec_draft", None) is not None:
+            return None
+        left = []
+        for req in lanes:
+            if req is None:
+                continue
+            n = req.max_new - len(req.tokens) - cur.chunk
+            if req.pf_pos is not None or n <= 0:
+                return None
+            left.append(n)
+        if not left or self._could_admit(rt, state, lanes):
+            return None
+        pos = state.pos + cur.chunk * state.active
+        refs = getattr(state, "page_refs", None)
+        if refs is not None:
+            # the boundary's copy-on-write net, one chunk on
+            slot = np.minimum(pos // state.page_tokens, state.pages_per_slot - 1)
+            pages = state.block_tables[np.arange(len(slot)), slot][state.active]
+            if (refs[pages[pages > 0]] > 1).any():
+                return None
+        chunk = max(1, min(self.engine.chunk_tokens, _next_bucket(max(left))))
+        return self._launch(rt, state, lanes, chunk, pos, ahead=1)
+
+    def _could_admit(self, rt, state, lanes) -> bool:
+        """Whether the next boundary would find a queued row it can admit
+        (or must refuse): the first in admission's order, a free lane, and
+        the pages of its budget free or to be had from the prefix index or a
+        preemption."""
+        with self.cv:
+            if not self.pending:
+                return False
+            req = min(self.pending, key=lambda r: (r.rank, r.seq))
+        if all(l is not None for l in lanes):
+            return False
+        if getattr(state, "prefix_index", None) is not None:
+            return True
+        # prompt + emitted + what is left of max_new
+        tokens = req.prompt.shape[0] + req.max_new
+        need = state.pages_needed(
+            min(tokens, state.pages_per_slot * state.page_tokens))
+        if tokens > state.max_seq or need > state.arena_pages \
+                or need <= len(state.free_pages):
+            return True
+        return hasattr(rt, "park_lane") and \
+            self._pick_victim(lanes, req) is not None
 
     def _record_step(
         self, state, chunk, active, admitted, retired, wasted, step_t0,
         prefix_hits=0, prefill_s=0.0, tokens_in=0,
         drafted=0, accepted=0, emitted=None, chunk_s=0.0, emit_s=0.0,
-        write_lanes=0, launch_s=0.0, uploads=0, window_pages=0.0,
+        write_lanes=0, launch_s=0.0, uploads=0, window_pages=0.0, ahead=0,
     ) -> None:
         """One flight-recorder ring entry per chunk boundary (``step_ms``
         split into the prefill clocks ``_step`` already keeps, the decode
         chunk and the emission loop; the rest is the engine's own;
-        ``launch_s`` is the part of ``chunk_s`` before the device had the
-        chunk, ``uploads`` the operands that launch sent), plus the
+        ``launch_s`` is the part of ``chunk_s`` the boundary's launches took,
+        ``uploads`` the operands they sent, ``ahead`` whether the chunk it
+        fetched had been launched before the last one's fetch), plus the
         oldest-queued-age gauge (`gen_admission_wait` only observes at
         admission — a row starved behind page exhaustion is invisible there
         until it finally admits; this gauge shows it starving)."""
@@ -1326,7 +1491,7 @@ class _ContinuousScheduler:
             experts_hit=moe_stats[0], expert_rows_max=moe_stats[1],
             expert_rows_local=moe_stats[2], write_lanes=write_lanes,
             launch_ms=launch_s * 1e3, uploads=uploads,
-            window_pages=window_pages,
+            window_pages=window_pages, ahead=ahead,
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

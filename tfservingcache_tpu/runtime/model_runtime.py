@@ -677,6 +677,18 @@ class PrefillRows(NamedTuple):
     prompt_len: int = 0
 
 
+class ChunkInFlight(NamedTuple):
+    """A launched decode chunk's output futures that the host reads
+    (``slot_decode_chunk_launch`` -> ``slot_decode_chunk_fetch``): the lanes'
+    last token and next position after it, the (S, chunk) emitted tokens and
+    an expert model's routing stats (None for a dense model)."""
+
+    tok: Any
+    pos: Any
+    toks: Any
+    stats: Any
+
+
 @dataclass
 class SlotDecodeState:
     """Device + host state of one model's continuous-decode lanes
@@ -717,7 +729,8 @@ class SlotDecodeState:
     chunk_counter: int = 0
     # the decode chunk's small operands as the device last had them, by name
     # (CHUNK_OPERANDS and "counter"): (device array, the host values it
-    # holds). For tok / pos / counter the array is the last chunk's own output
+    # holds). For tok / pos / counter the array is the last chunk's own
+    # output; tok / pos hold None for values until that chunk is fetched
     resident: dict = field(default_factory=dict)
     # how many of them the last chunk's launch had to upload (ring ``uploads``)
     uploads: int = 0
@@ -957,8 +970,11 @@ def _chunk_operands(state: SlotDecodeState) -> list:
     the uploads. So a decode-only boundary sends nothing, a retirement
     ``active`` and the tables (whose row ``release_pages`` zeroes), an
     admission what it wrote, and a write made anywhere else (park, resume,
-    preemption, a speculation round, a test) is seen like any other. On a
-    mesh the mirrors go as they are, as before: an operand kept from the
+    preemption, a speculation round, a test) is seen like any other. ``tok`` /
+    ``pos`` kept from a chunk still IN FLIGHT hold no host values (None): the
+    mirrors trail them until that chunk's fetch, and the engine writes no
+    mirror meanwhile, so they are current by construction and go as they
+    are. On a mesh the mirrors go as they are, as before: an operand kept from the
     program's outputs would carry the placement the compiler chose for that
     output, another program's signature."""
     import jax
@@ -972,7 +988,7 @@ def _chunk_operands(state: SlotDecodeState) -> list:
         return [host for _name, host in mirrors]
     stale = [(name, np.array(host)) for name, host in mirrors
              if (kept := state.resident.get(name)) is None
-             or not np.array_equal(kept[1], host)]
+             or (kept[1] is not None and not np.array_equal(kept[1], host))]
     if stale:
         # one call for all of them: a device_put costs the host 0.3 ms
         sent = jax.device_put([host for _name, host in stale], *devices)
@@ -2955,27 +2971,23 @@ class TPUModelRuntime(BaseRuntime):
         )
 
     @_mesh_serialized
-    def slot_decode_chunk(self, state: SlotDecodeState, chunk: int) -> np.ndarray:  # static-bounded: chunk -- engine clamps to a pow2 cover (batcher: min(chunk_tokens, _next_bucket(...)))
-        """Advance every active lane by ``chunk`` decode steps in one
-        dispatch; updates the state's device K/V and host tok/pos mirrors
-        and returns the (S, chunk) emitted tokens. Raises
-        ModelNotLoadedError when the model was evicted mid-decode (the
-        engine fails its in-flight requests and drops the state).
-        Two profiler annotations split the call (inside the engine's
-        ``tpusc.decode_chunk``): ``tpusc.chunk_launch`` until the program
-        call has returned its futures, ``tpusc.chunk_fetch`` around the one
-        fetch; ``state.launched_t`` is the clock between them."""
-        import jax
-
+    def slot_decode_chunk_launch(self, state: SlotDecodeState, chunk: int) -> ChunkInFlight:  # static-bounded: chunk -- engine clamps to a pow2 cover (batcher: min(chunk_tokens, _next_bucket(...)))
+        """The launch half of ``slot_decode_chunk``: everything the host does
+        before the device has the chunk: the residency lookup, a comparison a
+        mirror, an upload of each mirror a boundary changed (none on a
+        decode-only one), ONE program call (the chunk derives its own keys).
+        The state's arenas and its resident ``tok`` / ``pos`` / counter are
+        rebound to the program's output futures, so the NEXT chunk can be
+        launched before this one is fetched: its operands are this chunk's
+        outputs where they are (``tok`` / ``pos`` are not donated, so this
+        chunk's stay fetchable) and residents the host did not touch. Raises
+        ModelNotLoadedError when the model was evicted mid-decode.
+        ``tpusc.chunk_launch`` covers the call; ``state.launched_t`` is the
+        clock at its end."""
         from tfservingcache_tpu.models.generation import (
             _paged_decode_chunk_jit,
         )
 
-        # the launch path: everything the host does before the device has the
-        # chunk: the residency lookup, a comparison a mirror, an upload of
-        # each mirror a boundary changed (none on a decode-only one), ONE
-        # program call (the chunk derives its own keys). The device stands
-        # still for it unless an admission's programs are still running
         with host_span("chunk_launch"):
             loaded = self._resident.get(state.model_id)
             if loaded is None:
@@ -3000,23 +3012,53 @@ class TPUModelRuntime(BaseRuntime):
             )
             if ring:
                 state.window = ring[0]
+            if state.resident:
+                # the next chunk's tok / pos / counter are this chunk's
+                # outputs, where they are. Until this chunk is fetched the
+                # mirrors TRAIL them: no host values to compare with (None),
+                # the arrays are current by construction
+                state.resident.update(
+                    tok=(tok, None), pos=(pos, None),
+                    counter=(counter, _counter_word(state.chunk_counter + 1)))
         state.launched_t = time.monotonic()
-        # one fetch: an expert model's routing numbers ride with the tokens
+        return ChunkInFlight(tok, pos, toks, stats)
+
+    def slot_decode_chunk_fetch(self, state: SlotDecodeState,
+                                flight: ChunkInFlight) -> np.ndarray:
+        """The fetch half: ONE ``device_get`` of a launched chunk's outputs
+        (an expert model's routing numbers ride with the tokens), under
+        ``tpusc.chunk_fetch``; the ``tok`` / ``pos`` mirrors take what the
+        chunk left and the (S, chunk) emitted tokens are returned. Chunks are
+        fetched in the order they were launched."""
+        import jax
+
         with host_span("chunk_fetch"):
-            tok_h, pos_h, toks, stats = jax.device_get((tok, pos, toks, stats))
-        if state.resident:
-            # the next chunk's tok / pos / counter are this chunk's outputs,
-            # where they are; the fetched values are what they hold
-            state.resident.update(
-                tok=(tok, tok_h), pos=(pos, pos_h),
-                counter=(counter, _counter_word(state.chunk_counter + 1)))
+            got = jax.device_get(flight)
+        for name in ("tok", "pos"):
+            kept = state.resident.get(name)
+            if kept is not None and kept[0] is getattr(flight, name):
+                # no later chunk took them: the mirrors caught up
+                state.resident[name] = (kept[0], getattr(got, name))
         # np.array (not asarray): device_get hands back READ-ONLY views and
         # the scheduler writes these mirrors at the next admission
-        state.tok = np.array(tok_h, dtype=np.int32)
-        state.pos = np.array(pos_h, dtype=np.int32)
-        state.moe_stats = None if stats is None else tuple(
-            float(x) for x in stats)
-        return np.asarray(toks)
+        state.tok = np.array(got.tok, dtype=np.int32)
+        state.pos = np.array(got.pos, dtype=np.int32)
+        state.moe_stats = None if got.stats is None else tuple(
+            float(x) for x in got.stats)
+        return np.asarray(got.toks)
+
+    @_mesh_serialized
+    def slot_decode_chunk(self, state: SlotDecodeState, chunk: int) -> np.ndarray:  # static-bounded: chunk -- engine clamps to a pow2 cover (batcher: min(chunk_tokens, _next_bucket(...)))
+        """Advance every active lane by ``chunk`` decode steps in one
+        dispatch; updates the state's device K/V and host tok/pos mirrors
+        and returns the (S, chunk) emitted tokens: launch, then fetch (the
+        engine calls the halves itself to keep one chunk in flight). Two
+        profiler annotations split the call (inside the engine's
+        ``tpusc.decode_chunk``): ``tpusc.chunk_launch`` until the program
+        call has returned its futures, ``tpusc.chunk_fetch`` around the one
+        fetch; ``state.launched_t`` is the clock between them."""
+        return self.slot_decode_chunk_fetch(
+            state, self.slot_decode_chunk_launch(state, chunk))
 
     @_mesh_serialized
     def slot_attach_draft(self, state: SlotDecodeState, draft_id: ModelId,

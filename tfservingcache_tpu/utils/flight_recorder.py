@@ -121,6 +121,16 @@ STEP_FIELDS = (
     # tokens, at most a ring's). 0.0 for a model with no window layer, for a
     # spec round and for a boundary that ran no chunk
     "window_pages",
+    # appended field (ISSUE 40 one chunk in flight): 1 where the chunk this
+    # boundary fetched had been launched BEFORE the chunk before it was
+    # fetched (the device went from one to the next without waiting for the
+    # host), 0 where it was launched at its own boundary; 0 for a spec round
+    # and for a boundary that ran no chunk. With a chunk launched ahead,
+    # ``launch_ms`` / ``uploads`` are those of the launches THIS boundary made
+    # (the next chunk's, and its own where it launched that too; 0 where it
+    # only fetched) and ``chunk_ms`` runs from the first of them to the
+    # fetch's return, so the split of ``step_ms`` holds
+    "ahead",
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -133,7 +143,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 26:
+    if len(e) == 27:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -145,6 +155,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "experts_hit": e[19], "expert_rows_max": e[20],
             "expert_rows_local": e[21], "write_lanes": e[22],
             "launch_ms": e[23], "uploads": e[24], "window_pages": e[25],
+            "ahead": e[26],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -307,6 +318,7 @@ class FlightRecorder:
         launch_ms: float = 0.0,
         uploads: int = 0,
         window_pages: float = 0.0,
+        ahead: int = 0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -316,7 +328,7 @@ class FlightRecorder:
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
             round(experts_hit, 3), round(expert_rows_max, 3),
             round(expert_rows_local, 3), write_lanes, round(launch_ms, 4),
-            uploads, round(window_pages, 3),
+            uploads, round(window_pages, 3), ahead,
         ))
 
     def note_phases(

@@ -216,6 +216,17 @@ class Metrics:
             "lanes a trip (the engine's built width for a speculation round)",
             ["lanes"], registry=r,
         )
+        self.gen_chunks = Counter(
+            "tpusc_gen_chunks",
+            "Decode dispatches of the engine (boundaries whose ring entry "
+            "has chunk > 0) by when the chunk was launched: ahead = before "
+            "the chunk before it was fetched, so the device went from one "
+            "to the next without waiting for the host; boundary = at its "
+            "own boundary, after the last fetch (the first chunk after an "
+            "admission or a retirement, every speculation round, every "
+            "chunk on a mesh)",
+            ["launch"], registry=r,
+        )
         self.gen_admission_wait = Histogram(
             "tpusc_gen_admission_wait_seconds",
             "Time a generate request waited before decoding began on its "
