@@ -2,7 +2,10 @@
 (transformer_lm, moe_lm — both share the attention/cache layout; the FFN
 half is pluggable: dense silu-gate MLP vs routed expert block; mla_moe_lm
 with its one-sided latent row; hybrid_lm, whose convolution layers keep a
-fixed state a request beside the rows its attention layers keep).
+fixed state a request beside the rows its attention layers keep; sambay_lm,
+whose Mamba layers keep a two-part state a scan carries, whose cross-attention
+layers read ONE full-attention layer's rows and whose gated memory units keep
+nothing).
 
 What a layer keeps is the ModelDef's to say (``registry.static_config`` puts
 ``cache_row`` and, for a model of several kinds, ``layer_state`` into the
@@ -15,7 +18,13 @@ in the dense cache it is a layer like any other (every row kept, the mask
 applied), in the paged arena it has a layer of a SECOND arena beside the
 global one, ``wk`` / ``wv`` ``(window layers, lanes x ring pages, heads,
 page_tokens, width)``, in which a lane owns its ring of pages for life
-(``_layer_slots``: a ``Slot``'s ``arena`` and ``window``).
+(``_layer_slots``: a ``Slot``'s ``arena`` and ``window``). A ``LaneState`` of
+several parts has an array a part, and ``lane`` is then the tuple of them. A
+layer with ``SharedRows`` has no layer of any cache: its ``Slot`` is the one
+of the layer whose rows it reads, and it writes nothing. A layer with
+``NoState`` has no ``Slot`` to speak of. The layers that keep no rows bring
+their operators in the declaration (``_lane_layer``; ``NoState.operator``):
+nothing here knows a family's name.
 
 No reference counterpart (the reference proxies opaque Predict calls —
 SURVEY.md §5); generation is where a TPU-native LM server must not re-run
@@ -44,7 +53,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tfservingcache_tpu.models.hybrid_lm import conv_operator
 from tfservingcache_tpu.models.mla_moe_lm import (
     absorbed_output,
     absorbed_query,
@@ -57,16 +65,25 @@ from tfservingcache_tpu.models.moe_lm import _moe_block
 from tfservingcache_tpu.models.registry import (
     CacheRow,
     LaneState,
+    NoState,
+    SharedRows,
     kv_cache_row,
     static_config,
 )
+from tfservingcache_tpu.models.sambay_lm import diff_finish, diff_project
 from tfservingcache_tpu.models.transformer_lm import (
+    _norm,
     _output_logits,
     _qkv,
     _rmsnorm,
     rope_of,
 )
-from tfservingcache_tpu.ops.attention import pack_rows, unpack_pages
+from tfservingcache_tpu.ops.attention import (
+    diff_outputs,
+    diff_queries,
+    pack_rows,
+    unpack_pages,
+)
 
 # The slot-decode jits donate their K/V buffers (in-place update on TPU);
 # CPU/interpreter backends cannot honor donation and warn on EVERY dispatch
@@ -95,18 +112,42 @@ class Slot(NamedTuple):
     window: int   # rows a lane keeps in the paged arena; 0 = every row
 
 
+# a layer that keeps nothing and reads no cache (``NoState``)
+_NO_SLOT = Slot(False, -1, -1, 0)
+
+
+def _layer_kinds(cfg) -> tuple:
+    """What each layer declares (``ModelDef.layer_state`` as ``static_config``
+    carries it); None a layer for a config with no ``layer_state`` (every
+    family of one kind: the K/V or latent row in every layer)."""
+    return cfg.get("layer_state") or (None,) * int(cfg["n_layers"])
+
+
 def _layer_slots(cfg) -> list[Slot]:
     """One ``Slot`` a layer of the model. A config with no ``layer_state``
     (every family of one kind) has a cache layer a model layer, in order, and
-    the arena's index is the dense cache's. THE reader of ``layer_state``:
-    which layers keep a window is derived here and nowhere else in the
-    programs."""
-    kinds = cfg.get("layer_state") or (None,) * int(cfg["n_layers"])
+    the arena's index is the dense cache's. A layer with ``SharedRows`` gets
+    the ``Slot`` of the layer whose rows it reads (an EARLIER layer: its rows
+    are written before they are read), a layer with ``NoState`` ``_NO_SLOT``.
+    THE reader of ``layer_state``: which layers keep a window is derived here
+    and nowhere else in the programs."""
+    kinds = _layer_kinds(cfg)
     out, rows, lanes, ring = [], 0, 0, 0
-    for kind in kinds:
+    for depth, kind in enumerate(kinds):
         if isinstance(kind, LaneState):
             out.append(Slot(True, lanes, lanes, 0))
             lanes += 1
+            continue
+        if isinstance(kind, NoState):
+            out.append(_NO_SLOT)
+            continue
+        if isinstance(kind, SharedRows):
+            if not (0 <= kind.layer < depth
+                    and isinstance(kinds[kind.layer], CacheRow)):
+                raise ValueError(
+                    f"layer {depth} reads the rows of layer {kind.layer}, "
+                    "which is no earlier layer with rows of its own")
+            out.append(out[kind.layer])
             continue
         window = getattr(kind, "window", 0)
         out.append(Slot(False, rows, ring if window else rows - ring, window))
@@ -115,10 +156,16 @@ def _layer_slots(cfg) -> list[Slot]:
     return out
 
 
+def _own_rows(cfg) -> list[Slot]:
+    """The ``Slot`` s of the layers that keep rows of their OWN, in order."""
+    return [slot for slot, kind in zip(_layer_slots(cfg), _layer_kinds(cfg))
+            if kind is None or isinstance(kind, CacheRow)]
+
+
 def _window_of(cfg) -> int:
     """The window of the model's window layers, 0 for a model with none
     (one window a model: the ring arena has one shape)."""
-    windows = {s.window for s in _layer_slots(cfg)} - {0}
+    windows = {s.window for s in _own_rows(cfg)} - {0}
     if len(windows) > 1:
         raise ValueError(f"window layers of several windows: {windows}")
     return windows.pop() if windows else 0
@@ -127,37 +174,85 @@ def _window_of(cfg) -> int:
 def window_rows(cfg) -> tuple[int, ...]:
     """The window layers' indices among the model's ROW layers: where a
     prefill's K/V (the dense cache's layers) holds their rows."""
-    return tuple(s.index for s in _layer_slots(cfg) if s.window)
+    return tuple(s.index for s in _own_rows(cfg) if s.window)
 
 
 def _row_layers(cfg) -> int:
     """The model's layers that keep rows: the cache's and the arena's layers."""
-    return sum(not s.lane for s in _layer_slots(cfg))
+    return len(_own_rows(cfg))
 
 
-def _lane_layer(layer: dict, x, state, real_len, dtype, eps):
+def shared_readers(cfg) -> int:
+    """Layers whose decode call reads a GLOBAL arena layer that more than one
+    layer reads (the layer that writes it and every ``SharedRows`` layer over
+    it); 0 for a model in which every layer reads its own rows."""
+    kinds = _layer_kinds(cfg)
+    read = {k.layer for k in kinds if isinstance(k, SharedRows)}
+    return len(read) + sum(isinstance(k, SharedRows) for k in kinds)
+
+
+def shared_pages_read(pos, active, chunk: int, readers: int,
+                      page_tokens: int) -> float:
+    """Pages the ``readers`` decode calls over a shared global layer read a
+    live lane a step, summed over the readers, mean over the live lanes and
+    the ``chunk`` steps, worked out on the host from the ``pos`` / ``active``
+    mirrors the chunk is dispatched with (the ring's ``shared_pages``): the
+    pages that hold tokens ``0 .. p`` at each step's position ``p``, once a
+    reader. 0.0 with no live lane."""
+    pos = np.asarray(pos, np.int64)[np.asarray(active, bool)]
+    if not pos.size:
+        return 0.0
+    p = pos[:, None] + np.arange(int(chunk))[None, :]
+    return float(np.mean(p // page_tokens + 1)) * int(readers)
+
+
+def _lane_layer(layer: dict, x, state, real_len, kind: LaneState, cfg):
     """A layer that keeps a lane state, its operator half, for both cached
     loops: the residual stream ``x`` BEFORE its norm and the layer's slice
-    ``state (B, rows, width)`` -> (residual delta, the slice after
-    ``real_len`` of the tokens at hand; ``conv_operator``)."""
-    with jax.named_scope("conv"):
-        conv = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["conv"])
-        return conv_operator(conv, _rmsnorm(x, layer["ln1"], eps), state,
-                             real_len)
+    ``state`` (``(B, rows, width)``, or a tuple of such a part) -> (residual
+    delta, the slice after ``real_len`` of the tokens at hand, what the layer
+    hands on to the layers after it or None): the operator the layer's
+    declaration brings (``hybrid_lm.conv_layer``, ``sambay_lm.mamba_layer``)."""
+    return kind.operator(layer, x, state, real_len, cfg)
+
+
+def _lane_slice(lane, li: int):
+    """Layer ``li``'s slice of the lane state, an array or a tuple a part."""
+    return jax.tree_util.tree_map(lambda a: a[li], lane)
 
 
 def init_lane_state(cfg: dict, lanes: int):
-    """Zeros ``(lane layers, lanes, rows, width)`` in the model's dtype: what
-    the layers with a ``LaneState`` keep, a slice a lane (a request's
-    beginning is zeros); None for a model with no such layer."""
+    """Zeros ``(lane layers, lanes, rows, width)``: what the layers with a
+    ``LaneState`` keep, a slice a lane (a request's beginning is zeros), in
+    the part's dtype (the model's where it names none); a tuple of such
+    arrays, one a part, for a state of several parts; None for a model with
+    no such layer."""
     kinds = [k for k in cfg.get("layer_state") or ()
              if isinstance(k, LaneState)]
     if not kinds:
         return None
     if len(set(kinds)) != 1:
         raise ValueError(f"lane states of several shapes: {set(kinds)}")
-    return jnp.zeros((len(kinds), lanes, kinds[0].rows, kinds[0].width),
-                     jnp.dtype(cfg["dtype"]))
+    parts = tuple(
+        jnp.zeros((len(kinds), lanes, rows, width),
+                  jnp.dtype(dtype or cfg["dtype"]))
+        for rows, width, dtype in kinds[0].parts())
+    return parts[0] if len(parts) == 1 else parts
+
+
+def _norm_eps(cfg) -> float:
+    return cfg.get("rms_eps", cfg.get("norm_eps", 1e-5))
+
+
+def _differential_qkv(attn: dict, a, cfg):
+    """A differential layer's projections for both cached loops (a layer that
+    holds ``lam_q1``): two softmaxes a head pair over rows that hold a pair of
+    KV heads, no rotary -> (queries padded with zeros in grouped-query order,
+    ``ops.attention.diff_queries``; the rows the layer keeps, ``(B, pairs, T,
+    2 D)``, None for a layer that reads another's; the head's own
+    ``sm_scale``)."""
+    q, k, v = diff_project(attn, a, cfg["n_heads"], cfg["n_kv_heads"])
+    return diff_queries(q), k, v, q.shape[-1] ** -0.5
 
 
 def init_cache(cfg: dict, batch: int, max_len: int, mesh=None) -> dict:
@@ -802,7 +897,7 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     dtype = jnp.dtype(cfg["dtype"])
     s_lanes, t_q = toks.shape
     row = _cache_row(cfg)
-    eps = cfg.get("rms_eps", 1e-5)
+    eps = _norm_eps(cfg)
     pps = tables.shape[1]
     positions = pos[:, None] + jnp.arange(t_q)[None, :]          # (S, T)
     pages = jnp.take_along_axis(
@@ -835,33 +930,51 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                    + (positions // page_tokens) % ring_pages)
     # a lane nobody reads keeps its state: it takes 0 of the step's 1 token
     took = None if active is None else active.astype(jnp.int32)
+    handed: dict = {}     # what a layer's operator hands on to later layers
 
     with jax.named_scope("embed"):
         x = params["embed"][toks].astype(dtype)                  # (S, T, d)
-    for layer, (lane, _dense, li, window) in zip(params["layers"], slots):
+    for depth, (layer, kind, (lane, _dense, li, window)) in enumerate(
+            zip(params["layers"], _layer_kinds(cfg), slots)):
         with jax.named_scope("layer"):
-            if lane:
-                out, after = _lane_layer(
-                    layer, x, cache["lane"][li], took, dtype, eps)
-                cache = {**cache, "lane": cache["lane"].at[li].set(after)}
+            if lane or isinstance(kind, NoState):
+                if lane:
+                    out, after, extras = _lane_layer(
+                        layer, x, _lane_slice(cache["lane"], li), took, kind,
+                        cfg)
+                    cache = {**cache, "lane": jax.tree_util.tree_map(
+                        lambda a, n: a.at[li].set(n.astype(a.dtype)),
+                        cache["lane"], after)}
+                    handed.update(extras or {})
+                else:
+                    out = kind.operator(layer, x, handed, cfg)
                 x = x + out
                 x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
                                    moe_stats=moe_stats)
                 continue
+            shared = isinstance(kind, SharedRows)
+            scale = None
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                a = _rmsnorm(x, layer["ln1"], eps)
+                a = _norm(layer, "ln1", x, eps)
+                differential = "lam_q1" in attn
                 if row.sides == 1:
                     q_n, q_r, k = latent_project(attn, a, positions, cfg)
                     q = absorbed_query(attn, q_n, q_r, cfg)
                     k, v = k[:, :, None], None                   # (S, T, 1, W)
+                elif differential:
+                    q, k, v, scale = _differential_qkv(attn, a, cfg)
+                    if not shared:
+                        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
                 else:
                     q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"])
                     rope = rope_of(cfg, window)
                     q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
                     k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
                     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            if window:
+            if shared:
+                pass     # the rows are another layer's, written when it ran
+            elif window:
                 ring = _paged_write_rows(
                     {"k": cache["wk"], "v": cache["wv"]}, li, ring_at, off,
                     k, v, live)
@@ -869,7 +982,8 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
             else:
                 cache = _paged_write_rows(cache, li, pages, off, k, v, live)
             with jax.named_scope("attn"), _kind_scope(
-                    windowed, "window" if window else "global"):
+                    windowed, "cross" if shared else
+                    "window" if window else "global"):
                 if row.sides == 1:
                     out = paged_latent_attention(
                         q, cache["k"], tables, pos, page_tokens,
@@ -883,17 +997,24 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
                     if window:
                         out = paged_window_attention(
                             q, cache["wk"], cache["wv"], pos, page_tokens,
-                            window, kernel=kernel, active=active, layer=li)
+                            window, kernel=kernel, active=active, layer=li,
+                            sm_scale=scale)
                     elif t_q == 1:
                         out = paged_attention(*operands, kernel=kernel,
-                                              active=active, layer=li)
+                                              active=active, layer=li,
+                                              sm_scale=scale)
                     else:
                         out = paged_attention_verify(*operands, kernel=kernel,
                                                      layer=li)
-                    out = out.reshape(s_lanes, cfg["n_heads"], t_q, row.width)
-                    out = out.astype(x.dtype).transpose(0, 2, 1, 3)
-                    # heads x head width: the hidden size for most models
-                    x = x + out.reshape(s_lanes, t_q, -1) @ attn["wo"]
+                    if differential:
+                        x = x + diff_finish(attn, diff_outputs(out), depth,
+                                            dtype)
+                    else:
+                        out = out.reshape(
+                            s_lanes, cfg["n_heads"], t_q, row.width)
+                        out = out.astype(x.dtype).transpose(0, 2, 1, 3)
+                        # heads x head width: the hidden size for most models
+                        x = x + out.reshape(s_lanes, t_q, -1) @ attn["wo"]
             x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
                                moe_stats=moe_stats)
     return _output_logits(params, x, dtype, eps), cache
@@ -1275,8 +1396,11 @@ def _paged_decode_chunk_jit(
 def _lane_insert_jit(lane_state, new, lane):
     """An admitted request's lane state ``new (lane layers, 1, rows, width)``
     (``_slot_prefill_jit``'s last output) into slice ``lane`` of the state
-    array, in place (donated). ``lane`` is traced: one program a model."""
-    return lane_state.at[:, lane].set(new[:, 0].astype(lane_state.dtype))
+    array, in place (donated); every part of a state of several parts in the
+    one dispatch. ``lane`` is traced: one program a model."""
+    return jax.tree_util.tree_map(
+        lambda state, part: state.at[:, lane].set(
+            part[:, 0].astype(state.dtype)), lane_state, new)
 
 
 # what a decode chunk reports of its expert layers, in the order of its last
@@ -1299,7 +1423,7 @@ def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
             moe_stats.append(jnp.stack([stats[name] for name in MOE_STATS]))
         return y
     with jax.named_scope("ffn"):
-        h = _rmsnorm(x, layer["ln2"])
+        h = _norm(layer, "ln2", x)
         mlp = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"])
         return (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
 
@@ -1351,20 +1475,27 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
     b, s_len = input_ids.shape
     positions = start_pos[:, None] + jnp.arange(s_len)[None, :]   # (B, S)
     latent = _cache_row(cfg).sides == 1
-    eps = cfg.get("rms_eps", 1e-5)
+    eps = _norm_eps(cfg)
     windowed = bool(_window_of(cfg))
 
     with jax.named_scope("embed"):
         x = params["embed"][input_ids].astype(dtype)
     new_k, new_v, new_lane = [], [], []
+    fresh_rows: dict = {}    # a row layer's K/V of the tokens at hand, by index
+    handed: dict = {}        # what a layer's operator hands on to later layers
     n_heads, n_kv = cfg["n_heads"], cfg.get("n_kv_heads")
-    for layer, (lane, li, _arena, window) in zip(
-            params["layers"], _layer_slots(cfg)):
+    for depth, (layer, kind, (lane, li, _arena, window)) in enumerate(
+            zip(params["layers"], _layer_kinds(cfg), _layer_slots(cfg))):
         with jax.named_scope("layer"):
-            if lane:
-                out, after = _lane_layer(
-                    layer, x, cache["lane"][li], real_len, dtype, eps)
-                new_lane.append(after)
+            if lane or isinstance(kind, NoState):
+                if lane:
+                    out, after, extras = _lane_layer(
+                        layer, x, _lane_slice(cache["lane"], li), real_len,
+                        kind, cfg)
+                    new_lane.append(after)
+                    handed.update(extras or {})
+                else:
+                    out = kind.operator(layer, x, handed, cfg)
                 x = x + out
                 x = x + _ffn_block(layer, x, cfg, dtype)
                 continue
@@ -1379,51 +1510,67 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
                 x = x + out
                 x = x + _ffn_block(layer, x, cfg, dtype)
                 continue
+            shared = isinstance(kind, SharedRows)
+            scale = None
             with jax.named_scope("attn"):
                 attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"], eps), n_heads, n_kv)
-                rope = rope_of(cfg, window)
-                q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
-                k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
-            with jax.named_scope("kv_read"):
-                k_layer, v_layer = cache["k"][li], cache["v"][li]
+                differential = "lam_q1" in attn
+                if differential:
+                    q, k, v, scale = _differential_qkv(
+                        attn, _norm(layer, "ln1", x, eps), cfg)
+                else:
+                    q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"], eps), n_heads, n_kv)
+                    rope = rope_of(cfg, window)
+                    q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
+                    k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
+            if shared:
+                # another layer's rows, written when it ran
+                k, v = fresh_rows[li]
+                k_cache, v_cache = new_k[li], new_v[li]
+            else:
+                with jax.named_scope("kv_read"):
+                    k_layer, v_layer = cache["k"][li], cache["v"][li]
 
-            # scatter each example's K/V row into its own cache offset
-            def upd(cache_l, kv):
-                def one(c, kv_b, p):
-                    return jax.lax.dynamic_update_slice(c, kv_b, (0, p, 0))
-                return jax.vmap(one)(cache_l, kv, start_pos)
+                # scatter each example's K/V row into its own cache offset
+                def upd(cache_l, kv):
+                    def one(c, kv_b, p):
+                        return jax.lax.dynamic_update_slice(c, kv_b, (0, p, 0))
+                    return jax.vmap(one)(cache_l, kv, start_pos)
 
-            with jax.named_scope("kv_write"):
-                k_cache = upd(k_layer, k.astype(cache["k"].dtype))
-                v_cache = upd(v_layer, v.astype(cache["v"].dtype))
-                new_k.append(k_cache)
-                new_v.append(v_cache)
+                with jax.named_scope("kv_write"):
+                    k_cache = upd(k_layer, k.astype(cache["k"].dtype))
+                    v_cache = upd(v_layer, v.astype(cache["v"].dtype))
+                    new_k.append(k_cache)
+                    new_v.append(v_cache)
+                fresh_rows[li] = (k, v)
 
             # per-example visibility: key pos <= query pos. GQA grouped-K/V form:
             # query heads fold into (kv_head, group) so the cache is read as-is,
             # never repeated up to n_heads (the repeat would materialize
             # group x cache bytes every step at exactly the scale GQA exists for)
             with jax.named_scope("attn"), _kind_scope(
-                    windowed, "window" if window else "global"):
+                    windowed, "cross" if shared else
+                    "window" if window else "global"):
                 d = q.shape[-1]
                 if windowed and fresh:
                     # the tokens at hand are all there is: no score block over
                     # the cache's length, a window layer's blocks skipped
-                    out = attention(q, k, v, causal=True, window=window)
+                    out = attention(q, k, v, causal=True, window=window,
+                                    sm_scale=scale)
                 else:
-                    group = n_heads // n_kv
+                    heads = k_cache.shape[1]
+                    group = q.shape[1] // heads
                     # dots read the caches in their stored dtype: upcasting K/V
                     # to f32 here doubled the HBM bytes of the cache read EVERY
                     # decode step — the read that dominates decode.
                     # Scores/softmax still accumulate f32 via
                     # preferred_element_type (the flash-kernel recipe).
-                    qg = q.reshape(b, n_kv, group, s_len, d)
+                    qg = q.reshape(b, heads, group, s_len, d)
                     s = jnp.einsum(
                         "bkgqd,bkld->bkgql", qg, k_cache,
                         preferred_element_type=jnp.float32,
                     )
-                    s = s / math.sqrt(d)
+                    s = s / math.sqrt(d) if scale is None else s * scale
                     k_pos = jnp.arange(k_cache.shape[2])
                     mask = k_pos[None, None, :] <= positions[:, :, None]  # (B, S, max_len)
                     if window:
@@ -1435,10 +1582,15 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
                         "bkgql,bkld->bkgqd", p.astype(v_cache.dtype), v_cache,
                         preferred_element_type=jnp.float32,
                     )
-                out = out.reshape(b, n_heads, s_len, d).astype(x.dtype)
-                # heads x head width: the hidden size for most models
-                out = out.transpose(0, 2, 1, 3).reshape(b, s_len, -1)
-                x = x + out @ attn["wo"]
+                if differential:
+                    x = x + diff_finish(
+                        attn, diff_outputs(out.reshape(b, n_heads, s_len, d)),
+                        depth, dtype)
+                else:
+                    out = out.reshape(b, n_heads, s_len, d).astype(x.dtype)
+                    # heads x head width: the hidden size for most models
+                    out = out.transpose(0, 2, 1, 3).reshape(b, s_len, -1)
+                    x = x + out @ attn["wo"]
             x = x + _ffn_block(layer, x, cfg, dtype)
     if logits_at is not None:
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
@@ -1448,7 +1600,9 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
         if new_v:
             new_cache["v"] = jnp.stack(new_v)
     if new_lane:
-        new_cache["lane"] = jnp.stack(new_lane).astype(cache["lane"].dtype)
+        new_cache["lane"] = jax.tree_util.tree_map(
+            lambda old, *parts: jnp.stack(parts).astype(old.dtype),
+            cache["lane"], *new_lane)
     return logits, new_cache
 
 

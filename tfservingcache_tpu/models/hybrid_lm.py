@@ -108,11 +108,26 @@ def conv_operator(conv: dict, u: jax.Array, state: jax.Array | None = None,
     return out, after
 
 
+@jax.named_scope("conv")
+def conv_layer(layer: dict, x: jax.Array, state, real_len, cfg: dict):
+    """A convolution layer's operator half as ``registry.LaneState`` declares
+    it: the residual stream ``x`` before its norm and the lanes' slice ``state
+    (B, L-1, d)`` -> (residual delta, the slice after ``real_len`` of the
+    tokens at hand, nothing handed on); ``conv_operator`` under its norm."""
+    dtype = jnp.dtype(cfg["dtype"])
+    conv = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["conv"])
+    out, after = conv_operator(
+        conv, _rmsnorm(x, layer["ln1"], cfg.get("rms_eps", 1e-5)), state,
+        real_len)
+    return out, after, None
+
+
 def layer_state_of(cfg: dict) -> tuple:
     """What each layer keeps of a request, from ``layer_types``: the K/V row
     for an attention layer, ``LaneState(L - 1, d_model)`` for a convolution."""
     row = kv_cache_row(cfg)
-    lane = LaneState(int(cfg["conv_kernel"]) - 1, int(cfg["d_model"]))
+    lane = LaneState(int(cfg["conv_kernel"]) - 1, int(cfg["d_model"]),
+                     operator=conv_layer)
     kinds = {CONV: lane, ATTENTION: row}
     types = list(cfg["layer_types"])
     if len(types) != int(cfg["n_layers"]) or set(types) - set(kinds):

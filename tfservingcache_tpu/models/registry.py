@@ -89,13 +89,62 @@ class CacheRow:
 @dataclass(frozen=True)
 class LaneState:
     """What a request keeps in a layer whose state does NOT grow with its
-    length: ``rows`` rows of ``width`` in the model's dtype, fixed for the
-    life of the request (a gated short convolution keeps its last
-    ``kernel - 1`` inputs). It lives beside the paged arena, one slice a lane
-    (``SlotDecodeState.lane_state``), not in pages."""
+    length: ``rows`` rows of ``width``, fixed for the life of the request (a
+    gated short convolution keeps its last ``kernel - 1`` inputs). It lives
+    beside the paged arena, one slice a lane
+    (``SlotDecodeState.lane_state``), not in pages.
+
+    ``dtype`` ("" = the model's) is what the part is stored in: a scanned
+    state stays float32 whatever the model computes in. ``beside`` are further
+    PARTS of the same layer's state, each a ``LaneState`` with an array of its
+    own (a Mamba layer keeps its last 3 convolution inputs in the model's
+    dtype and a ``(state, channels)`` float32 scan state): the layer's state
+    is then the tuple ``(this part, *beside)``, one array a part, and a model's
+    lane-state layers all declare the same parts.
+
+    ``operator`` is the layer's operator half, brought by the family:
+    ``(layer params, x, state, real_len, cfg) -> (residual delta, the state
+    after real_len of the tokens at hand, extras)`` with ``x`` the residual
+    stream BEFORE the layer's norm, ``state`` the lane's slice (an array, or
+    a tuple a part) and ``extras`` None or a dict the layers after it read
+    (``generation._lane_layer`` calls it; a module-level function, so that
+    the declaration stays hashable and equal across builds)."""
 
     rows: int
     width: int
+    dtype: str = ""
+    beside: tuple = ()
+    # not part of the declaration's identity: two layers that keep the same
+    # parts compare equal (a program's static key also holds the family's name
+    # and its whole config, which is what tells two operators apart)
+    operator: Callable | None = field(default=None, compare=False)
+
+    def parts(self) -> tuple:
+        """The arrays a layer of this kind keeps: ``(rows, width, dtype)``
+        a part."""
+        return ((self.rows, self.width, self.dtype),
+                *(p for b in self.beside for p in b.parts()))
+
+
+@dataclass(frozen=True)
+class SharedRows:
+    """A layer that keeps NOTHING of a request and reads the rows another
+    layer keeps: model layer ``layer``'s pages, through the same block table
+    at the same positions (a cross-attention layer of a decoder whose ONE
+    full-attention layer's K/V every later attention layer reads). It has a
+    query and an output projection only and writes no row."""
+
+    layer: int
+
+
+@dataclass(frozen=True)
+class NoState:
+    """A layer that keeps nothing at all and reads no cache: its operator maps
+    the token at hand and what earlier layers of the SAME forward handed on
+    (a lane-state operator's ``extras``) to a residual delta,
+    ``(layer params, x, handed, cfg) -> delta`` (a gated memory unit)."""
+
+    operator: Callable
 
 
 def head_width(cfg: Mapping[str, Any]) -> int:
@@ -185,9 +234,10 @@ class ModelDef:
     # ``engine_ready`` family declares one.
     cache_row: CacheRow | None = None
     # what each layer keeps of a request, one entry a layer: the model's
-    # ``cache_row`` (the layer has pages in the arena) or a ``LaneState`` (a
-    # fixed state a lane). A family whose layers are all of one kind declares
-    # ``cache_row`` alone and gets ``(cache_row,) * n_layers``.
+    # ``cache_row`` (the layer has pages in the arena), a ``LaneState`` (a
+    # fixed state a lane), ``SharedRows`` (nothing: it reads another layer's
+    # pages) or ``NoState`` (nothing at all). A family whose layers are all of
+    # one kind declares ``cache_row`` alone and gets ``(cache_row,) * n_layers``.
     layer_state: tuple = ()
 
     def __post_init__(self) -> None:
@@ -284,7 +334,7 @@ def build(family: str, config: dict[str, Any] | None = None) -> ModelDef:
 
 _BUILTIN_MODULES = (
     "half_plus_two", "mnist_cnn", "bert", "resnet", "transformer_lm", "t5", "moe_lm",
-    "mla_moe_lm", "hybrid_lm",
+    "mla_moe_lm", "hybrid_lm", "sambay_lm",
 )
 
 

@@ -70,6 +70,25 @@ def _rmsnorm(x: jax.Array, gain: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (x32 * scale).astype(x.dtype) * gain.astype(x.dtype)
 
 
+def _layernorm(x: jax.Array, gain: jax.Array, bias: jax.Array,
+               eps: float = 1e-5) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return ((x32 * scale).astype(x.dtype) * gain.astype(x.dtype)
+            + bias.astype(x.dtype))
+
+
+def _norm(holder: dict, name: str, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """The norm a layer (or the params' root) holds under ``name``, chosen by
+    what it holds: a LayerNorm (mean and variance, gain and bias) where a
+    ``<name>_b`` leaf is there, else the RMSNorm every other model has, which
+    traces exactly what it traced."""
+    if name + "_b" in holder:
+        return _layernorm(x, holder[name], holder[name + "_b"], eps)
+    return _rmsnorm(x, holder[name], eps)
+
+
 def yarn_frequencies(d: int, theta: float, factor: float, original_max: float,
                      beta_fast: float, beta_slow: float) -> np.ndarray:
     """The ``d / 2`` rotary frequencies of a ``d``-wide head: plain
@@ -159,7 +178,7 @@ def _output_logits(params: dict, x: jax.Array, dtype,
     """Final norm and output head -> float32 logits (a stable softmax/argmax
     downstream). An ``lm_head (d, vocab)`` leaf is the untied head; without
     it the head is the embedding."""
-    x = _rmsnorm(x, params["ln_f"], eps)
+    x = _norm(params, "ln_f", x, eps)
     if "lm_head" in params:
         return (x @ params["lm_head"].astype(dtype)).astype(jnp.float32)
     return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)

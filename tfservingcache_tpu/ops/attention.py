@@ -87,10 +87,12 @@ def _kernel_refusal(head_dim: int, hq: int, hkv: int,
 
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
-    window: int = 0,
+    window: int = 0, sm_scale: float | None = None,
 ) -> jax.Array:
     """(B, Hq, S, D) x (B, Hkv, S, D) attention, fp32 softmax, out in q.dtype.
     ``window`` > 0 (causal only): query ``i`` reads keys ``(i - window, i]``.
+    ``sm_scale`` (None = ``D ** -0.5``) is the scores' scale where the head
+    computed with is not the row handed in (``diff_queries``).
 
     GQA-native: Hkv may divide Hq; query heads are grouped over their shared
     K/V head via a reshape, so repeated K/V are never materialized (the whole
@@ -107,7 +109,8 @@ def attention_reference(
     qg = q.reshape(b, hkv, g, sq, d)
     s = jnp.einsum(
         "bkgqd,bkKd->bkgqK", qg, k, preferred_element_type=jnp.float32
-    ) / math.sqrt(d)
+    )
+    s = s / math.sqrt(d) if sm_scale is None else s * sm_scale
     if causal:
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         if window:
@@ -292,6 +295,7 @@ def _flash_attention(
     block_k: int | None = None,
     interpret: bool = False,
     window: int = 0,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Flash attention over (B, Hq, S, D) x (B, Hkv, S, D). S is padded to a
     block multiple internally. GQA-native: the kernel instance for query head
@@ -329,7 +333,8 @@ def _flash_attention(
     if h % hkv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
     g = h // hkv
-    sm_scale = 1.0 / math.sqrt(d)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
     sp_tile = s + ((-s) % 128)
     if block_q is None:
         block_q = 256 if sp_tile % 256 == 0 else 128
@@ -410,9 +415,10 @@ def _flash_attention(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret",
+                              "sm_scale")
 )
-def flash_attention(
+def flash_attention(  # static-bounded: sm_scale -- one value per model config (None, or the head's own scale of a differential model)
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
@@ -420,15 +426,18 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """``_flash_attention`` with no window: every key at or before the query."""
-    return _flash_attention(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_attention(q, k, v, causal, block_q, block_k, interpret,
+                            sm_scale=sm_scale)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("window", "block_q", "block_k", "interpret",
+                              "sm_scale")
 )
-def flash_window_attention(  # static-bounded: window -- one value per model config (sliding_window)
+def flash_window_attention(  # static-bounded: window, sm_scale -- one value each per model config (sliding_window; None, or the head's own scale of a differential model)
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
@@ -436,11 +445,12 @@ def flash_window_attention(  # static-bounded: window -- one value per model con
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Causal flash attention in which query ``i`` reads keys ``(i - window,
     i]`` (``_flash_attention``): a window layer's fresh prefill."""
     return _flash_attention(q, k, v, True, block_q, block_k, interpret,
-                            window=int(window))
+                            window=int(window), sm_scale=sm_scale)
 
 
 def _flash_carry_kernel(
@@ -605,6 +615,50 @@ def flash_attention_carry(
 
 
 # ---------------------------------------------------------------------------
+# Differential attention over rows that hold a PAIR of KV heads
+# ---------------------------------------------------------------------------
+#
+# Differential attention (arXiv:2410.05258) takes query heads in pairs
+# ``(q1, q2) = (head 2i, head 2i + 1)`` and KV heads in pairs ``j = i // 2``:
+# ``k1 = k(2j)``, ``k2 = k(2j + 1)``, ``V = [v(2j) | v(2j + 1)]``, and
+# ``o_i = softmax(q1 k1^T) V - lam softmax(q2 k2^T) V``. A 128-lane row
+# ``[k(2j) | k(2j + 1)]`` is what a packed head-64 arena stores (``_as_arena``),
+# and with it the two softmaxes are ORDINARY grouped-query attention at a row
+# of 2 D: a query padded with zeros, ``[q1 | 0]`` or ``[0 | q2]``, scores its
+# own key half exactly (a zero lane adds an exact zero) at ``sm_scale = D **
+# -0.5``, and the value product gives the whole ``2 D``-wide ``p . V``. So
+# every attention function here serves it unchanged: the model keeps its K and
+# V as rows of a pair (``sambay_lm.pair_row``: a free reshape of the
+# projection), ``diff_queries`` pads the queries, the caller passes
+# ``sm_scale``, and ``diff_outputs`` hands back the two terms for the caller's
+# ``- lam``.
+
+def diff_queries(q: jax.Array) -> jax.Array:
+    """``q (B, Hq, T, D)`` in the model's head order -> ``(B, Hq, T, 2 D)``
+    padded with zeros, in the ORDER grouped-query attention over ``Hq / 4``
+    row pairs wants. The four query heads over KV pair ``j`` are ``q1, q2, q1,
+    q2`` (heads ``4j .. 4j + 3``): the first and third read ``k(2j)`` and go
+    first, as ``[q | 0]``; the second and fourth read ``k(2j + 1)`` and follow,
+    as ``[0 | q]`` (NOT the GQA map ``head // 2``, which would hand heads
+    ``4j, 4j + 1`` the first key)."""
+    b, hq, t, d = q.shape
+    # (B, j, a, c, T, D): head 4j + 2a + c, c = which key of the pair it reads
+    qd = q.reshape(b, hq // 4, 2, 2, t, d).swapaxes(2, 3)   # (B, j, c, a, T, D)
+    own = jnp.eye(2, dtype=q.dtype)[:, None, None, :, None]  # (c, 1, 1, c', 1)
+    return (qd[..., None, :] * own).reshape(b, hq, t, 2 * d)
+
+
+def diff_outputs(out: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """An attention call's output over ``diff_queries``' heads, ``(B, Hq, T,
+    2 D)`` -> the two terms ``(softmax(q1 k1^T) V, softmax(q2 k2^T) V)``, each
+    ``(B, Hq / 2, T, 2 D)`` with pair ``i = 2j + a`` in the model's order."""
+    b, hq, t, w = out.shape
+    out = out.reshape(b, hq // 4, 2, 2, t, w)               # (B, j, c, a, T, W)
+    return (out[:, :, 0].reshape(b, hq // 2, t, w),
+            out[:, :, 1].reshape(b, hq // 2, t, w))
+
+
+# ---------------------------------------------------------------------------
 # Paged-KV attention (continuous decode engine)
 # ---------------------------------------------------------------------------
 
@@ -717,11 +771,13 @@ def paged_decode_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     first: jax.Array | None = None,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Single-position attention over a paged KV arena — the decode-step
     counterpart of the dense slot read in ``_forward_cached_dyn``.
     ``first`` (``(S,)``, a window layer's call) is each lane's first valid
-    token: the mask is then ``first <= k_pos <= pos``.
+    token: the mask is then ``first <= k_pos <= pos``. ``sm_scale`` (None =
+    ``D ** -0.5``) as in ``attention_reference``.
 
     Shapes: q ``(S, Hq, 1, D)`` (one query per lane, post-RoPE),
     k_pages/v_pages the arena ``(layers, n_pages, Hkv, page_tokens, D)``
@@ -748,7 +804,8 @@ def paged_decode_attention(
     qg = q.reshape(s_lanes, hkv, g, 1, d)                # kc: (S, Hkv, L, D)
     s = jnp.einsum(
         "bkgqd,bkld->bkgql", qg, kc, preferred_element_type=jnp.float32
-    ) / math.sqrt(d)
+    )
+    s = s / math.sqrt(d) if sm_scale is None else s * sm_scale
     k_pos = jnp.arange(kc.shape[2])
     mask = k_pos[None, None, :] <= pos[:, None, None]    # (S, 1, L)
     if first is not None:
@@ -1025,6 +1082,7 @@ def _paged_decode_call(
     page_tokens: int,
     interpret: bool = False,
     layer: int = 0,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Fused paged decode attention: same contract as
     ``paged_decode_attention`` (q ``(S, Hq, 1, D)``, the arena
@@ -1080,7 +1138,8 @@ def _paged_decode_call(
         # (128)" — a manual copy cannot take a narrower page out of HBM
         raise ValueError(f"head_dim {d} not a multiple of 128")
     g = hq // hkv                                   # packed: both heads' rows
-    sm_scale = 1.0 / math.sqrt(head)
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(head)
     quantized = k_scale is not None
     block_pages = max(1, PAGED_BLOCK_TOKENS // page_tokens)
 
@@ -1156,8 +1215,8 @@ def _paged_decode_call(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("page_tokens", "interpret", "layer"))
-def paged_decode_attention_kernel(
+    jax.jit, static_argnames=("page_tokens", "interpret", "layer", "sm_scale"))
+def paged_decode_attention_kernel(  # static-bounded: sm_scale -- one value per model config (None, or the head's own scale of a differential model)
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -1170,16 +1229,18 @@ def paged_decode_attention_kernel(
     page_tokens: int,
     interpret: bool = False,
     layer: int = 0,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """``_paged_decode_call`` over every token ``0..pos`` of a lane."""
     return _paged_decode_call(
         q, k_pages, v_pages, tables, pos, k_scale, v_scale, active,
-        page_tokens=page_tokens, interpret=interpret, layer=layer)
+        page_tokens=page_tokens, interpret=interpret, layer=layer,
+        sm_scale=sm_scale)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("page_tokens", "interpret", "layer"))
-def paged_window_decode_attention_kernel(
+    jax.jit, static_argnames=("page_tokens", "interpret", "layer", "sm_scale"))
+def paged_window_decode_attention_kernel(  # static-bounded: sm_scale -- as paged_decode_attention_kernel
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -1191,16 +1252,19 @@ def paged_window_decode_attention_kernel(
     page_tokens: int,
     interpret: bool = False,
     layer: int = 0,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """``_paged_decode_call`` over tokens ``first..pos`` of a lane's view: a
     window layer's decode call (``paged_window_attention``)."""
     return _paged_decode_call(
         q, k_pages, v_pages, tables, pos, None, None, active, first,
-        page_tokens=page_tokens, interpret=interpret, layer=layer)
+        page_tokens=page_tokens, interpret=interpret, layer=layer,
+        sm_scale=sm_scale)
 
 
-def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # static-bounded: causal, partitioned, window -- boolean domains (two programs max each); window is one value per model config (sliding_window)
-              partitioned: bool = False, window: int = 0) -> jax.Array:
+def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # static-bounded: causal, partitioned, window, sm_scale -- boolean domains (two programs max each); window and sm_scale are one value each per model config (sliding_window; the head's own scale of a differential model)
+              partitioned: bool = False, window: int = 0,
+              sm_scale: float | None = None) -> jax.Array:
     """Dispatch: Pallas flash kernel on TPU, jnp reference elsewhere (the
     kernel's interpret mode is for tests, too slow for CPU serving).
 
@@ -1213,7 +1277,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # 
     flash kernel is single-chip until it gets a shard_map wrapper (ROADMAP
     S7). The branch taken is recorded (``dispatch_tally``). ``window`` > 0 is a
     window layer's call (query ``i`` reads keys ``(i - window, i]``): the same
-    gate under its own name, ``attention_window``, and the windowed kernel."""
+    gate under its own name, ``attention_window``, and the windowed kernel.
+    ``sm_scale`` (None = ``D ** -0.5``) reaches whichever branch runs."""
     gate = "attention_window" if window else "attention"
     why = _kernel_refusal(q.shape[-1], q.shape[1], k.shape[1])
     if why is None and partitioned:
@@ -1227,10 +1292,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,  # 
     if why is None:
         _record_dispatch(gate, "kernel", "flash", q.shape, k.shape)
         if window:
-            return flash_window_attention(q, k, v, window=window)
-        return flash_attention(q, k, v, causal=causal)
+            return flash_window_attention(q, k, v, window=window,
+                                          sm_scale=sm_scale)
+        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     _record_dispatch(gate, "reference", why, q.shape, k.shape)
-    return attention_reference(q, k, v, causal=causal, window=window)
+    return attention_reference(q, k, v, causal=causal, window=window,
+                               sm_scale=sm_scale)
 
 
 # Tests flip this to force the Pallas paged kernel through its interpreter
@@ -1269,7 +1336,7 @@ def _paged_kernel_traced(gate: str, kernel: bool, q: jax.Array,
     return False
 
 
-def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL_INTERPRET -- kernel and the interpret flag are booleans (two programs max); page_tokens is one value per slot state (ServingConfig kv_page_tokens); layer is the caller's unrolled loop index, below the model's depth
+def paged_attention(  # static-bounded: kernel, page_tokens, layer, sm_scale, PAGED_KERNEL_INTERPRET -- kernel and the interpret flag are booleans (two programs max); page_tokens is one value per slot state (ServingConfig kv_page_tokens); layer is the caller's unrolled loop index, below the model's depth; sm_scale is one value per model config
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -1281,6 +1348,7 @@ def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL
     kernel: bool = True,
     active: jax.Array | None = None,
     layer: int = 0,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Paged decode dispatch over the arena ``(layers, n_pages, Hkv,
     page_tokens, D)`` at the static ``layer`` (``_as_arena``), mirroring
@@ -1306,10 +1374,11 @@ def paged_attention(  # static-bounded: kernel, page_tokens, layer, PAGED_KERNEL
         return paged_decode_attention_kernel(
             q, k_pages, v_pages, tables, pos, k_scale, v_scale, active,
             page_tokens=page_tokens, interpret=PAGED_KERNEL_INTERPRET,
-            layer=layer,
+            layer=layer, sm_scale=sm_scale,
         )
     return paged_decode_attention(q, k_pages, v_pages, tables, pos,
-                                  page_tokens, layer, k_scale, v_scale)
+                                  page_tokens, layer, k_scale, v_scale,
+                                  sm_scale=sm_scale)
 
 
 def window_ring_pages(window: int, page_tokens: int) -> int:
@@ -1337,7 +1406,7 @@ def window_ring_view(pos: jax.Array, window: int, page_tokens: int,
     return tables.astype(jnp.int32), pos - base, first - base
 
 
-def paged_window_attention(  # static-bounded: kernel, page_tokens, window, layer, PAGED_KERNEL_INTERPRET -- as paged_attention; window is one value per model config (sliding_window)
+def paged_window_attention(  # static-bounded: kernel, page_tokens, window, layer, sm_scale, PAGED_KERNEL_INTERPRET -- as paged_attention; window is one value per model config (sliding_window)
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -1347,6 +1416,7 @@ def paged_window_attention(  # static-bounded: kernel, page_tokens, window, laye
     kernel: bool = True,
     active: jax.Array | None = None,
     layer: int = 0,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """A WINDOW layer's decode dispatch over its ring arena ``(window layers,
     lanes x ring_pages, Hkv, page_tokens, D)`` at the static ``layer``: each
@@ -1366,10 +1436,11 @@ def paged_window_attention(  # static-bounded: kernel, page_tokens, window, laye
         return paged_window_decode_attention_kernel(
             q, k_pages, v_pages, tables, pos_v, first_v, active,
             page_tokens=page_tokens, interpret=PAGED_KERNEL_INTERPRET,
-            layer=layer,
+            layer=layer, sm_scale=sm_scale,
         )
     return paged_decode_attention(q, k_pages, v_pages, tables, pos_v,
-                                  page_tokens, layer, first=first_v)
+                                  page_tokens, layer, first=first_v,
+                                  sm_scale=sm_scale)
 
 
 # ---------------------------------------------------------------------------

@@ -454,6 +454,7 @@ class _Flight:
     path: str | None = None
     write_lanes: int = 0
     window_pages: float = 0.0
+    shared_pages: float = 0.0
     launch_s: float = 0.0
     uploads: int = 0
     drafted: int = 0
@@ -1230,6 +1231,7 @@ class _ContinuousScheduler:
             write_lanes=flight.write_lanes,
             launch_s=launch_s, uploads=uploads,
             window_pages=flight.window_pages, ahead=flight.ahead,
+            shared_pages=flight.shared_pages,
         )
         self._flight = nxt
         return state
@@ -1332,6 +1334,7 @@ class _ContinuousScheduler:
         chunk whole and it comes back fetched."""
         from tfservingcache_tpu.models.generation import (
             kv_write_lanes,
+            shared_pages_read,
             window_pages_read,
         )
 
@@ -1343,6 +1346,11 @@ class _ContinuousScheduler:
         window_pages = window_pages_read(
             pos, state.active, chunk, window, state.page_tokens
         ) if window else 0.0
+        # what the calls over a shared global layer will read (0 with none)
+        readers = getattr(state, "shared_readers", 0)
+        shared_pages = shared_pages_read(
+            pos, state.active, chunk, readers, state.page_tokens
+        ) if readers else 0.0
         t0 = time.monotonic()
         handle = toks = None
         if hasattr(rt, "slot_decode_chunk_launch"):
@@ -1352,7 +1360,7 @@ class _ContinuousScheduler:
         return _Flight(
             chunk=chunk, reqs=list(lanes), handle=handle, toks=toks,
             ahead=ahead, path=path, write_lanes=write_lanes,
-            window_pages=window_pages,
+            window_pages=window_pages, shared_pages=shared_pages,
             # the launch path ended at ``launched_t`` (a runtime that keeps
             # no such clock leaves an older time there and records 0)
             launch_s=max(0.0, getattr(state, "launched_t", 0.0) - t0),
@@ -1425,6 +1433,7 @@ class _ContinuousScheduler:
         prefix_hits=0, prefill_s=0.0, tokens_in=0,
         drafted=0, accepted=0, emitted=None, chunk_s=0.0, emit_s=0.0,
         write_lanes=0, launch_s=0.0, uploads=0, window_pages=0.0, ahead=0,
+        shared_pages=0.0,
     ) -> None:
         """One flight-recorder ring entry per chunk boundary (``step_ms``
         split into the prefill clocks ``_step`` already keeps, the decode
@@ -1492,6 +1501,7 @@ class _ContinuousScheduler:
             expert_rows_local=moe_stats[2], write_lanes=write_lanes,
             launch_ms=launch_s * 1e3, uploads=uploads,
             window_pages=window_pages, ahead=ahead,
+            shared_pages=shared_pages,
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

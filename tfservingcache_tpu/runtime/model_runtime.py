@@ -35,6 +35,7 @@ from tfservingcache_tpu.native import make_lru_cache
 from tfservingcache_tpu.config import ServingConfig
 from tfservingcache_tpu.models.registry import (
     ModelDef,
+    SharedRows,
     TensorSpec,
     lane_layers,
     window_layers,
@@ -751,6 +752,10 @@ class SlotDecodeState:
     window: Any = None
     window_tokens: int = 0
     window_rows: tuple = ()
+    # layers whose decode call reads a global arena layer that several layers
+    # read (``generation.shared_readers``; the ring's ``shared_pages``); 0 for
+    # a model in which every layer reads its own rows
+    shared_readers: int = 0
     # -- arena bookkeeping (scheduler-thread-owned) --
     page_tokens: int = 0             # tokens a page; >= 1 in a built state
     arena_pages: int = 0             # usable pages (excludes trash page 0)
@@ -2218,6 +2223,7 @@ class TPUModelRuntime(BaseRuntime):
             _window_of,
             init_lane_state,
             init_paged_cache,
+            shared_readers,
             window_rows,
         )
 
@@ -2319,6 +2325,7 @@ class TPUModelRuntime(BaseRuntime):
             window=window,
             window_tokens=_window_of(cfg),
             window_rows=window_rows(cfg),
+            shared_readers=shared_readers(cfg),
             scales=scales,
             arena_dtype=arena_dtype,
             kernel=bool(paged_kernel),
@@ -2362,8 +2369,12 @@ class TPUModelRuntime(BaseRuntime):
                                          state.model_id.version)
         self.metrics.kv_arena_bytes.labels(model, "global").set(nbytes)
         self.metrics.kv_arena_bytes.labels(model, "window").set(ring)
-        self.metrics.lane_state_bytes.labels(model).set(
-            0 if state.lane_state is None else actual(state.lane_state))
+        import jax
+
+        # every part of a state of several parts (a tuple of arrays)
+        self.metrics.lane_state_bytes.labels(model).set(sum(
+            actual(part)
+            for part in jax.tree_util.tree_leaves(state.lane_state)))
 
     def mesh_topology(self) -> dict | None:
         """Structural stamp for /monitoring/engine: a number without its
@@ -3686,9 +3697,13 @@ class TPUModelRuntime(BaseRuntime):
         (``park_lane`` by priority preemption, ``slot_prefill_chunk`` and
         ``slot_attach_draft`` by the engine's own constructor arguments)."""
         state = loaded.model_def.layer_state
-        kind = ("lane-state layers" if lane_layers(state) else
-                "window layers" if window_layers(state) else None)
-        if kind is None:
+        kind = ", ".join(name for name, has in (
+            ("lane-state layers", lane_layers(state)),
+            ("window layers", window_layers(state)),
+            ("layers that read another layer's rows",
+             any(isinstance(s, SharedRows) for s in state)),
+        ) if has)
+        if not kind:
             return
         if self.mesh is not None:
             what = "generation on a chip-group mesh"

@@ -131,6 +131,15 @@ STEP_FIELDS = (
     # only fetched) and ``chunk_ms`` runs from the first of them to the
     # fetch's return, so the split of ``step_ms`` holds
     "ahead",
+    # appended field (ISSUE 41 a layer whose rows other layers read): the
+    # pages the decode calls over a SHARED global arena layer read a live lane
+    # a step, summed over the layers that read it (the one that writes it and
+    # every ``registry.SharedRows`` layer over it), mean over the chunk's
+    # steps, worked out from the ``pos`` / ``active`` mirrors the chunk was
+    # dispatched with (generation.shared_pages_read). 0.0 for a model in
+    # which every layer reads its own rows, for a spec round and for a
+    # boundary that ran no chunk
+    "shared_pages",
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -143,7 +152,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 27:
+    if len(e) == 28:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -155,7 +164,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "experts_hit": e[19], "expert_rows_max": e[20],
             "expert_rows_local": e[21], "write_lanes": e[22],
             "launch_ms": e[23], "uploads": e[24], "window_pages": e[25],
-            "ahead": e[26],
+            "ahead": e[26], "shared_pages": e[27],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -319,6 +328,7 @@ class FlightRecorder:
         uploads: int = 0,
         window_pages: float = 0.0,
         ahead: int = 0,
+        shared_pages: float = 0.0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -328,7 +338,7 @@ class FlightRecorder:
             round(prefill_ms, 4), round(chunk_ms, 4), round(emit_ms, 4),
             round(experts_hit, 3), round(expert_rows_max, 3),
             round(expert_rows_local, 3), write_lanes, round(launch_ms, 4),
-            uploads, round(window_pages, 3), ahead,
+            uploads, round(window_pages, 3), ahead, round(shared_pages, 3),
         ))
 
     def note_phases(

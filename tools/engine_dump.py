@@ -84,6 +84,8 @@ def _fmt_step(s: dict) -> str:
         split += f"write_lanes={s['write_lanes']} "
     if s.get("window_pages"):   # ISSUE 39: pages a window call read a live lane
         split += f"window_pages={s['window_pages']:.1f} "
+    if s.get("shared_pages"):   # ISSUE 41: pages the calls over a shared layer read
+        split += f"shared_pages={s['shared_pages']:.1f} "
     if s.get("ahead"):  # ISSUE 40: the chunk fetched here was launched ahead
         split += "ahead=1 "
     return (

@@ -71,6 +71,13 @@ def main() -> int:
     # a global call over the same lanes, and the windowed flash kernel at
     # 1024 / 4096 / 8192 tokens against the whole causal triangle:
     # `-k "window and on_tpu"`, ~2 min.
+    # test_sambay_lm.py carries the rows of a differential, scanned model at
+    # Phi-4-mini-flash's shapes (40 query heads of 64 over 10 rows of a KV
+    # pair, E 5120, N 16): both softmax terms of every pair out of the global
+    # and the window decode kernel (queries padded with zeros, sm_scale 1/8)
+    # against two dense float64 softmaxes on the host, and the selective scan
+    # (a bucket of 1024, the step over 32 lanes) against the recurrence in
+    # float64: largest errors and times, `-k "sambay and on_tpu"`, ~1.5 min.
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
@@ -78,6 +85,7 @@ def main() -> int:
         os.path.join(REPO, "tests", "test_olmoe.py"),
         os.path.join(REPO, "tests", "test_mla_moe.py"),
         os.path.join(REPO, "tests", "test_window_layers.py"),
+        os.path.join(REPO, "tests", "test_sambay_lm.py"),
         "-v", "-rs", "-s", "--no-header",
         *extra,
     ]
