@@ -31,7 +31,10 @@ of 64, stored two a 128-lane row): the kernel is in the program and the arena
 is row-major wherever it appears. Since PR 39 it also runs for a model with
 WINDOW layers: both arenas are donated and carried, neither is copied, both
 kernels are in the program, and that model's fresh prefill holds the windowed
-flash kernel and no score block.
+flash kernel and no score block. Since PR 42 the same holds for an ADMISSION:
+the insert programs write whole pages, so their compiled form is the in-place
+write alone (until then a row was the unit, and four copies of the arena
+stood around every insert).
 """
 
 import functools
@@ -228,16 +231,12 @@ def _compile_only_models():
     }
 
 
-def _compile_for_v5e_main(case="dense"):
-    """Child-process body of the test below: the decode chunk of ``case``'s
-    model at 32 lanes over an arena far larger than anything else it touches,
-    compiled for a described v5e, as the tree writes it and with every lane's
-    rows written (the parent's write: no order handed down). Prints what it
-    found, or NO_TOPOLOGY."""
+def _described_v5e():
+    """One chip of a v5e that libtpu describes with none attached, as a
+    sharding for abstract operands (a child process's call: it loads the
+    library); prints NO_TOPOLOGY and returns None where it cannot."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-
-    from tfservingcache_tpu.models.registry import static_config
 
     try:
         topo = topologies.get_topology_desc(
@@ -246,9 +245,47 @@ def _compile_for_v5e_main(case="dense"):
             num_slices=1)
     except Exception as e:  # noqa: BLE001 - reported; the parent skips
         print("NO_TOPOLOGY", type(e).__name__, e)
-        return
-    one = SingleDeviceSharding(topo.devices[0])
+        return None
     jax.config.update("jax_enable_compilation_cache", False)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _layouts(hlo: str, arenas) -> list:
+    """The layouts a compiled module gives the arenas' shapes, wherever they
+    appear: ``["{4,3,2,1,0"]`` is row-major everywhere."""
+    found = set()
+    for arena in arenas:
+        found |= set(re.findall(
+            r"\[%s\](\{[0-9,]*)" % ",".join(map(str, arena.shape)), hlo))
+    return sorted(found)
+
+
+def _child(call: str):
+    """``test_arena_in_place.<call>`` in a process of its own, set up to
+    compile for a chip it does not have -> (stdout, stderr)."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
+    env.update(_COMPILE_ONLY_ENV)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, 'tests'); import test_arena_in_place;"
+         f" test_arena_in_place.{call}"],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=600)
+    return r.stdout, r.stderr
+
+
+def _compile_for_v5e_main(case="dense"):
+    """Child-process body of the test below: the decode chunk of ``case``'s
+    model at 32 lanes over an arena far larger than anything else it touches,
+    compiled for a described v5e, as the tree writes it and with every lane's
+    rows written (the parent's write: no order handed down). Prints what it
+    found, or NO_TOPOLOGY."""
+    from tfservingcache_tpu.models.registry import static_config
+
+    one = _described_v5e()
+    if one is None:
+        return
     # the gates ask the backend, "cpu" in this process: answer for the chip
     jax.default_backend = lambda: "tpu"
     family, config, n_pages = _compile_only_models()[case]
@@ -282,18 +319,14 @@ def _compile_for_v5e_main(case="dense"):
             *args, cfg_key=static_config(md), family=family,
             chunk=chunk, page_tokens=pt, kernel=True).compile()
         hlo = compiled.as_text()
-        layouts = set(re.findall(
-            r"\[%s\](\{[0-9,]*)" % ",".join(map(str, cache["k"].shape)), hlo))
         if ring:
-            layouts |= set(re.findall(
-                r"\[%s\](\{[0-9,]*)" % ",".join(map(str, ring[0].shape)), hlo))
             assert "paged_window_decode_kernel" in hlo, "no window kernel"
         print("COMPILED", name,
               "temp", compiled.memory_analysis().temp_size_in_bytes,
               "kernel", int("paged_decode_attention_kernel" in hlo),
               "loops", len(re.findall(r" while\(", hlo)),
               "large", json.dumps(_large_results(hlo, layer_elems)),
-              "layouts", json.dumps(sorted(layouts)))
+              "layouts", json.dumps(_layouts(hlo, (cache["k"], *(ring or ())[:1]))))
     print("LAYER_BYTES", layer_elems * 2, "ARENA", json.dumps(cache["k"].shape))
     if ring:
         # the same model's fresh prefill of 2048 tokens: through the attention
@@ -335,22 +368,14 @@ def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy(case):
     the program beside the global one's, and the same model's fresh prefill
     of 2048 tokens holds the windowed flash kernel in its window layers and
     temporaries far under one ``(heads, S, S)`` float32 score block."""
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
-    env.update(_COMPILE_ONLY_ENV)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r = subprocess.run(
-        [sys.executable, "-c",
-         "import sys; sys.path.insert(0, 'tests'); import test_arena_in_place;"
-         f" test_arena_in_place._compile_for_v5e_main({case!r})"],
-        cwd=repo, env=env, capture_output=True, text=True, timeout=600)
-    if "NO_TOPOLOGY" in r.stdout:
+    out, err = _child(f"_compile_for_v5e_main({case!r})")
+    if "NO_TOPOLOGY" in out:
         pytest.skip("libtpu compile-only topology unavailable: "
-                    + r.stdout.strip()[-300:])
-    found = dict(re.findall(r"COMPILED (\w+) (.*)", r.stdout))
-    assert set(found) == {"live", "every"}, (r.stdout[-3000:], r.stderr[-3000:])
+                    + out.strip()[-300:])
+    found = dict(re.findall(r"COMPILED (\w+) (.*)", out))
+    assert set(found) == {"live", "every"}, (out[-3000:], err[-3000:])
     temp, loops = {}, {}
-    sizes = re.search(r"LAYER_BYTES (\d+) ARENA (.*)", r.stdout)
+    sizes = re.search(r"LAYER_BYTES (\d+) ARENA (.*)", out)
     layer_bytes, arena = int(sizes.group(1)), json.loads(sizes.group(2))
     for name, line in found.items():
         m = re.match(
@@ -372,7 +397,110 @@ def test_decode_chunk_compiled_for_v5e_holds_no_arena_sized_copy(case):
         assert arena == [1, 16385, 4, 16, 128]           # the ONE global layer
         assert layer_bytes == 32 * 65 * 4 * 16 * 128 * 2   # a window layer's ring
         m = re.search(r"PREFILL temp (\d+) window_kernels (\d+) score_block (\d+)",
-                      r.stdout)
-        assert m, (r.stdout[-3000:], r.stderr[-3000:])
+                      out)
+        assert m, (out[-3000:], err[-3000:])
         assert int(m.group(2)) >= 3, m.group(0)
         assert int(m.group(1)) < int(m.group(3)) // 2, m.group(0)
+
+
+# -- an admission's insert, compiled for the same v5e --------------------------
+
+INSERT_BUCKETS = (512, 4096)
+
+
+def _compile_inserts_for_v5e_main(case="dense"):
+    """Child-process body of the test below: ``case``'s insert program
+    (``_window_paged_insert_jit`` for the model with window layers,
+    ``_paged_insert_jit`` for the others) at every bucket of
+    ``INSERT_BUCKETS``, compiled for a described v5e over the arena the decode
+    chunk's case has (the dense one at the Mistral cell's 4097 pages: a layer
+    is then eight times the 4096 bucket's rows). Prints what it found, or
+    NO_TOPOLOGY."""
+    from tfservingcache_tpu.models.registry import static_config
+
+    one = _described_v5e()
+    if one is None:
+        return
+    family, config, n_pages = _compile_only_models()[case]
+    n_pages = max(n_pages, 4097)
+    lanes, pt = 32, 16
+    md = build(family, config)
+    cfg = dict(static_config(md))
+    cache = jax.eval_shape(
+        lambda: generation.init_paged_cache(cfg, n_pages, pt, row=md.cache_row,
+                                            lanes=lanes))
+    rings = (cache["wk"], cache["wv"]) if "wk" in cache else ()
+    arenas = (cache["k"], cache["v"], *rings)
+    layer_elems = min(a.size // a.shape[0] for a in arenas)
+    s = jax.ShapeDtypeStruct
+    i32 = s((), jnp.int32)
+    for bucket in INSERT_BUCKETS:
+        rows = s((generation._row_layers(cfg), 1, cfg["n_kv_heads"], bucket,
+                  md.cache_row.width), jnp.bfloat16)
+        table = s((max(cfg["max_seq"], bucket) // pt,), jnp.int32)
+        if rings:
+            fn, args, static = generation._window_paged_insert_jit, (
+                *arenas, rows, rows, table, i32, i32), dict(
+                window_layers=generation.window_rows(cfg),
+                ring_pages=rings[0].shape[1] // lanes)
+        else:
+            fn, args, static = generation._paged_insert_jit, (
+                *arenas, None, rows, rows, table, i32), {}
+        args = jax.tree_util.tree_map(
+            lambda a: s(a.shape, a.dtype, sharding=one), args)
+        compiled = fn.lower(*args, page_tokens=pt, **static).compile()
+        hlo = compiled.as_text()
+        print("INSERT", bucket,
+              "temp", compiled.memory_analysis().temp_size_in_bytes,
+              "large", json.dumps(_large_results(hlo, layer_elems)),
+              "layouts", json.dumps(_layouts(hlo, arenas)))
+    print("LAYER_BYTES", layer_elems * 2,
+          "ARENAS", json.dumps([a.shape for a in arenas[::2]]))
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_inserts(case):
+    """The child's output for ``case``: one process compiles every bucket."""
+    return _child(f"_compile_inserts_for_v5e_main({case!r})")
+
+
+@pytest.mark.parametrize("bucket", INSERT_BUCKETS)
+@pytest.mark.parametrize("case", ["dense", "hybrid_head64", "window"])
+def test_insert_compiled_for_v5e_holds_no_arena_sized_copy(case, bucket):
+    """An admission's insert compiled for a v5e, off the chip, at a 512 and a
+    4096 bucket: every layer- or arena-sized result is the in-place write's
+    (the page scatter on each side of the donated arena and, for the model
+    with window layers, the one contiguous update of the lane's ring): no
+    ``copy``, no ``transpose``, no layout conversion; every arena is row-major
+    wherever its shape appears; the temporaries (one side's rows in page form,
+    pages first as the TPU's scatter takes its updates: bucket x layers x a
+    row's bytes) are under a quarter of one arena layer. Skipped where libtpu
+    cannot describe the topology.
+
+    What the PARENT's programs showed under this harness (a row was the
+    scatter's unit, layers and KV heads in its window): FOUR arena-sized
+    ``copy`` results around the two scatters in every case at both buckets
+    (each side converted to a layout of the scatter's own, ``{4,2,3,1,0``, and
+    back), EIGHT in ``hybrid_head64`` at the 4096 bucket (``{4,3,1,2,0``; over
+    the LFM2 cell's 3-layer arena at its 1024 bucket too), and temporaries of
+    one whole side of the arena whatever the bucket: 268.7 MB in each case
+    here, 1.07 GB over the Mistral cell's 8 layers."""
+    out, err = _compiled_inserts(case)
+    if "NO_TOPOLOGY" in out:
+        pytest.skip("libtpu compile-only topology unavailable: "
+                    + out.strip()[-300:])
+    found = dict(re.findall(r"INSERT (\d+) (.*)", out))
+    assert set(found) == set(map(str, INSERT_BUCKETS)), (out[-3000:], err[-3000:])
+    sizes = re.search(r"LAYER_BYTES (\d+) ARENAS (.*)", out)
+    layer_bytes, arenas = int(sizes.group(1)), json.loads(sizes.group(2))
+    m = re.match(r"temp (\d+) large (.*) layouts (.*)", found[str(bucket)])
+    large = json.loads(m.group(2))
+    writes = {"scatter", "fusion:scatter"}
+    if case == "window":
+        writes |= {"dynamic-update-slice", "fusion:dynamic-update-slice"}
+        assert arenas == [[1, 16385, 4, 16, 128], [3, 32 * 65, 4, 16, 128]]
+    if case == "hybrid_head64":
+        assert arenas == [[2, 8193, 4, 16, 128]]          # packed
+    assert large and set(large) <= writes, (case, bucket, large)
+    assert json.loads(m.group(3)) == ["{4,3,2,1,0"], found[str(bucket)]
+    assert int(m.group(1)) < layer_bytes // 4, (found[str(bucket)], layer_bytes)

@@ -365,6 +365,118 @@ def test_rows_written_into_a_packed_arena_come_back_as_the_unpacked_arenas(
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _rows_written_one_by_one(arena, rows, table, base, pt):
+    """The row scatter's contract in NumPy: row ``r >= base`` of ``rows
+    (layers, heads as stored, P_pad, ...)`` goes to page ``table[r // pt]``
+    (the trash page past the table), offset ``r % pt``; nothing else moves."""
+    out = np.array(arena)
+    for r in range(int(base), rows.shape[2]):
+        page = table[r // pt] if r // pt < len(table) else 0
+        out[:, page, :, r % pt] = rows[:, :, r]
+    return out
+
+
+@pytest.mark.parametrize("arena, p_pad, base, reserved", [
+    ("head128", 32, 0, 4),
+    ("head64_packed", 32, 0, 4),
+    ("int8", 32, 0, 4),
+    ("one_sided", 32, 0, 4),
+    ("head128", 32, 16, 4),            # base on a page's edge: pages 0, 1 stay
+    ("head128", 32, 21, 4),            # base cuts page 2: its rows 16..20 stay
+    ("head64_packed", 32, 21, 4),
+    ("int8", 32, 21, 4),
+    ("one_sided", 32, 21, 4),
+    ("head128", 32, 32, 4),            # nothing to write
+    ("head128", 64, 0, 3),             # the bucket overshoots the reservation
+    ("head128", 64, 0, 10),            # ... and the table (6 pages a lane)
+    ("head64_packed", 64, 13, 3),
+    ("head128", 4, 0, 1),              # a bucket under one page
+    ("int8", 4, 3, 1),
+    ("head128", 20, 0, 3),             # 16 cached rows + a suffix bucket of 4
+    ("one_sided", 20, 18, 3),
+    ("window", 32, 0, 4),
+    ("window", 64, 0, 3),
+])
+def test_the_page_insert_puts_every_row_where_the_row_scatter_put_it(
+        arena, p_pad, base, reserved):
+    """``_paged_insert_jit`` (and ``_window_paged_insert_jit``'s global layers)
+    write whole pages; the arena must come out bit for bit as a row-by-row
+    write leaves it, on every page but the trash page: the lane's pages hold
+    the prompt's rows from ``base`` up, the rows under ``base`` of the page it
+    cuts and the rows past the bucket keep their old bytes, pages wholly under
+    ``base`` and every other lane's pages are untouched, and what overshoots
+    the reservation (table entries 0) or the table lands on page 0. The arena
+    starts random, so a row that moved shows."""
+    layers, pt, pps, lanes, lane = 3, 8, 6, 3, 1
+    head = 64 if arena == "head64_packed" else 128
+    hkv = 1 if arena == "one_sided" else 4
+    n_pages = lanes * pps + 1
+    rng = np.random.default_rng(p_pad + base)
+    stored = (layers, n_pages, hkv // 2, pt, 128) if head == 64 else (
+        layers, n_pages, hkv, pt, head)
+    sides = ("k",) if arena == "one_sided" else ("k", "v")
+    if arena == "int8":
+        start = {s: rng.integers(-127, 128, stored).astype(np.int8) for s in sides}
+        scales = {s: rng.random(stored[:-1]).astype(np.float32) for s in sides}
+    else:
+        start = {s: rng.standard_normal(stored).astype(np.float32) for s in sides}
+        scales = None
+    table = np.zeros(pps, np.int32)
+    table[:min(reserved, pps)] = 1 + rng.permutation(n_pages - 1)[:min(reserved, pps)]
+    n_rows = layers + 2 if arena == "window" else layers   # two window layers
+    new = {s: rng.standard_normal((n_rows, 1, hkv, p_pad, head)).astype(np.float32)
+           for s in sides}
+    operands = [jnp.asarray(start["k"]), jnp.asarray(start["v"]) if "v" in start else None]
+    rows_in = [jnp.asarray(new["k"]), jnp.asarray(new["v"]) if "v" in new else None]
+    if arena == "window":
+        ring_pages, window_layers = 2, (1, 3)
+        rings = [jnp.asarray(rng.standard_normal(
+            (2, lanes * ring_pages, hkv, pt, head)).astype(np.float32))
+            for _ in sides]
+        ring_start = [np.asarray(r) for r in rings]
+        k, v, wk, wv = generation._window_paged_insert_jit(
+            *operands, *rings, *rows_in, table, np.int32(lane), np.int32(p_pad - 1),
+            page_tokens=pt, window_layers=window_layers, ring_pages=ring_pages)
+        got, got_scales = {"k": k, "v": v}, None
+        for ring, before, side in zip((wk, wv), ring_start, sides):
+            # the rings as the parent's program left them: another lane's ring
+            # bit for bit, this lane's slot of position p holds row p
+            ring = np.asarray(ring)
+            other = np.ones(lanes * ring_pages, bool)
+            other[lane * ring_pages:(lane + 1) * ring_pages] = False
+            np.testing.assert_array_equal(ring[:, other], before[:, other])
+            for p in range(p_pad - 1 - ring_pages * pt, p_pad - 1):
+                np.testing.assert_array_equal(
+                    ring[:, lane * ring_pages + (p // pt) % ring_pages, :, p % pt],
+                    new[side][list(window_layers), 0, :, p])
+        new = {s: np.delete(new[s], window_layers, axis=0) for s in sides}
+    else:
+        k, v, got_scales = generation._paged_insert_jit(
+            *operands, None if scales is None else {
+                s: jnp.asarray(scales[s]) for s in sides},
+            *rows_in, table, np.int32(base), page_tokens=pt)
+        got = {"k": k, "v": v}
+    assert (got["v"] is None) == (arena == "one_sided")
+    for side in sides:
+        rows = new[side][:, 0]                              # (layers, hkv, P, head)
+        if head == 64:                                      # two heads a row
+            rows = rows.reshape(layers, hkv // 2, 2, p_pad, head).transpose(
+                0, 1, 3, 2, 4).reshape(layers, hkv // 2, p_pad, 128)
+        if arena == "int8":
+            # under jit, as every program that writes the arena runs it
+            rows, row_scales = map(
+                np.asarray, jax.jit(generation._quantize_kv_rows)(rows))
+            want = _rows_written_one_by_one(scales[side], row_scales, table, base, pt)
+            np.testing.assert_array_equal(np.asarray(got_scales[side])[:, 1:], want[:, 1:])
+        want = _rows_written_one_by_one(start[side], rows, table, base, pt)
+        assert got[side].dtype == start[side].dtype
+        np.testing.assert_array_equal(np.asarray(got[side])[:, 1:], want[:, 1:])
+        written = (want != start[side]).any(axis=(0, 2, 3, 4))
+        assert set(np.flatnonzero(written)) <= {0, *table}, "the case moves another lane"
+        if base < min(p_pad, reserved * pt):
+            assert written[1:].any()
+
+
 @pytest.mark.parametrize("arena, shape", [
     ("head64_even", (2, 9, 4, 8, 128)),          # packed
     ("head64_odd", (2, 9, 5, 8, 64)),            # SmolLM2's 5 KV heads

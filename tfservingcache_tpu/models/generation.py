@@ -1109,38 +1109,51 @@ _each_side = jax.tree_util.tree_map
 
 def _insert_rows(arena_k, arena_v, scales, pk, pv, table_row, base,
                  page_tokens: int):
-    """``_paged_insert_jit``'s scatter (its docstring says what goes where),
+    """``_paged_insert_jit``'s write (its docstring says what goes where),
     shared with ``_window_paged_insert_jit``'s global layers."""
     p_pad = pk.shape[3]
-    pps = table_row.shape[0]
-    rows = jnp.arange(p_pad)
-    pages = table_row[jnp.clip(rows // page_tokens, 0, pps - 1)]  # (P_pad,)
-    pages = jnp.where(rows >= base.astype(jnp.int32), pages, 0)
-    offs = rows % page_tokens
-    # (layers, 1, n_kv, P_pad, hd) -> (P_pad, layers, n_kv, hd): the two
-    # advanced indices below are non-adjacent, so their broadcast dim moves
-    # to the front of the updated slice
-    if arena_v is None:
-        # one-sided (latent) arena, one head: layer, page, head and offset are
-        # all INDICES of the scatter and its window is one row, as in
-        # ``_paged_write_rows`` (with the layers and the head in the window
-        # the TPU compiler converts the WHOLE arena to a layout of the
-        # scatter's own and back, two arena-sized copies an admission)
-        layers = jnp.arange(arena_k.shape[0])[:, None]
-        return (arena_k.at[layers, pages[None, :], 0, offs[None, :]].set(
-            pk[:, 0, 0].astype(arena_k.dtype)), None, None)
-    kv = pack_rows(pk[:, 0].transpose(2, 0, 1, 3), arena_k)
-    vv = pack_rows(pv[:, 0].transpose(2, 0, 1, 3), arena_v)
+    n_pg = -(-p_pad // page_tokens)
+    base = base.astype(jnp.int32)
+    slot = jnp.arange(n_pg)
+    pages = table_row[jnp.minimum(slot, table_row.shape[0] - 1)]
+    # past the table's end or wholly under ``base``: the trash page
+    pages = jnp.where((slot < table_row.shape[0])
+                      & ((slot + 1) * page_tokens > base), pages, 0)
+    # the EDGE pages hold rows that are not this insert's: the one ``base``
+    # cuts and, where the bucket is no whole number of pages, the last. Each
+    # is read, takes its new rows by a select and is written after the whole
+    # pages, among which it goes to the trash page
+    edges = [jnp.clip(base // page_tokens, 0, n_pg - 1)]
+    if p_pad % page_tokens:
+        edges.append(jnp.int32(n_pg - 1))
+    edge = jnp.stack(edges)
+    edge_pages = pages[edge]
+    pages = jnp.concatenate([pages.at[edge].set(0), edge_pages])
+    row = edge[:, None] * page_tokens + jnp.arange(page_tokens)   # (edges, pt)
+    ours = ((row >= base) & (row < p_pad))[None, :, None, :]
+
+    def page_form(rows, arena):
+        # (layers, 1, n_kv, P_pad, hd) -> (layers, n_pg, heads, pt, width) as
+        # ``arena`` stores a page (two heads a row where packed)
+        rows = jnp.pad(rows[:, 0], (
+            (0, 0), (0, 0), (0, n_pg * page_tokens - p_pad), (0, 0)))
+        layers, n_kv, _, hd = rows.shape
+        rows = rows.reshape(layers, n_kv, n_pg, page_tokens, hd)
+        return pack_rows(rows.transpose(0, 2, 3, 1, 4), arena).transpose(
+            0, 1, 3, 2, 4)
+
+    def set_pages(buf, new):
+        new = new.astype(buf.dtype)
+        mine = ours if new.ndim == 4 else ours[..., None]   # a scale has no width
+        kept = jnp.where(mine, new[:, edge], buf[:, edge_pages])
+        return buf.at[:, pages].set(jnp.concatenate([new, kept], axis=1))
+
+    kv, vv = _each_side(page_form, (pk, pv), (arena_k, arena_v))
     if scales is not None:
         kv, k_s = _quantize_kv_rows(kv)
         vv, v_s = _quantize_kv_rows(vv)
-        scales = {
-            "k": scales["k"].at[:, pages, :, offs].set(k_s),
-            "v": scales["v"].at[:, pages, :, offs].set(v_s),
-        }
-    arena_k = arena_k.at[:, pages, :, offs, :].set(kv.astype(arena_k.dtype))
-    arena_v = arena_v.at[:, pages, :, offs, :].set(vv.astype(arena_v.dtype))
-    return arena_k, arena_v, scales
+        scales = _each_side(set_pages, scales, {"k": k_s, "v": v_s})
+    return (*_each_side(set_pages, (arena_k, arena_v), (kv, vv)), scales)
 
 
 @functools.partial(
@@ -1149,24 +1162,42 @@ def _insert_rows(arena_k, arena_v, scales, pk, pv, table_row, base,
 @jax.named_scope("kv_write")
 def _paged_insert_jit(arena_k, arena_v, scales, pk, pv, table_row, base, *,
                       page_tokens):
-    """Scatter one admitted request's prefill K/V (layers, 1, n_kv, P_pad,
-    hd) into its reserved pages: logical row ``r`` goes to page
-    ``table_row[r // page_tokens]`` offset ``r % page_tokens``. ``table_row``
+    """Write one admitted request's prefill K/V (layers, 1, n_kv, P_pad, hd)
+    into its reserved pages AS WHOLE PAGES: the rows are brought into the
+    arena's own page form ``(layers, P_pad / page_tokens, heads as stored,
+    page_tokens, stored width)`` (a transposition of the prompt's rows, two
+    heads a row through ``pack_rows`` where the arena is packed, quantised
+    first where it is int8: the scale buffers take the same form without the
+    width) and page ``j`` of that form is set at ``table_row[j]``, in place on
+    the donated arena: ONE scatter a buffer whose only index is the page, the
+    form ``_pages_import_jit`` has. Logical row ``r`` so lies in page
+    ``table_row[r // page_tokens]`` at offset ``r % page_tokens``, bit for bit
+    where a write row by row put it. (A row was the unit until PR 42, with
+    layers and heads in the scatter's window: the TPU compiler re-laid each
+    side of the arena out for that scatter and back, four arena-sized copies
+    an admission whatever the prompt's length.) ``table_row``
     is the lane's FULL (pages_per_slot,) block-table row — entries beyond
-    the reservation are 0, so prefill-pad rows past the reserved budget
-    (P_pad is a pow2 bucket and can overshoot it) land in the trash page.
+    the reservation are 0, so the pages of prefill-pad rows past the reserved
+    budget (P_pad is a pow2 bucket and can overshoot it) land in the trash
+    page, as do pages past the table's end.
     Junk pad rows inside the reservation are never visible: a query at pos
     p sees only rows <= p, and the decode step writes row p before
     attending. ``base`` (traced i32) is
     the shared-prefix boundary: rows < base belong to pages another lane /
-    the prefix index owns READ-ONLY, so their scatter is redirected to the
-    trash page — prefill stops at the shared boundary and only private
-    pages are written. base=0 is the plain unshared insert. One compile
+    the prefix index owns READ-ONLY, so a page wholly under ``base`` is
+    redirected to the trash page — prefill stops at the shared boundary and
+    only private pages are written — and the ONE page ``base`` may cut keeps
+    its rows under ``base``: that page is read, takes the rows from ``base``
+    up by a select, and is written with the others. So is the last page where
+    the bucket is no whole number of pages (a cached prefix + a small suffix
+    bucket), whose rows past P_pad keep their bytes. base=0 is the plain
+    unshared insert. One compile
     per P_pad bucket, same bound as the prefill itself (base is data, not
     a signature). ``scales`` is the int8 arena's {"k", "v"} per-row scale
     buffers (donated; None for an arena in the model's dtype): prefill rows are
     quantized here with the same per-row absmax discipline as the decode
-    write, so a page is bit-identical whether filled by prefill or steps."""
+    write, so a page is bit-identical whether filled by prefill or steps.
+    A one-sided (latent) arena's ``arena_v`` / ``pv`` are None and stay so."""
     return _insert_rows(arena_k, arena_v, scales, pk, pv, table_row, base,
                         page_tokens)
 
