@@ -12,7 +12,10 @@ holds and leaves the rest out. What ties the share to the model:
     bit: OLMoE's path does not change;
   * an assignment to an expert held elsewhere reads no weight: ``experts_hit``
     and ``expert_rows_max`` count held experts, ``expert_rows_local`` the
-    assignments that landed here.
+    assignments that landed here;
+  * a prefill-sized call of a share is the reference path's answer and the
+    same bytes at every row tile, and its tile follows the assignments of the
+    router's full width, not the held experts' count.
 
 Float32 throughout; the tolerance against the reference (1e-5 of answers of
 size 1) is the order of float32 sums.
@@ -28,6 +31,7 @@ import pytest
 
 from tfservingcache_tpu.models.moe_lm import _moe_block
 from tfservingcache_tpu.ops import moe
+from tfservingcache_tpu.ops.attention import dispatch_tally
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 T, D, FF, E, K = 29, 32, 16, 8, 3
@@ -153,3 +157,30 @@ def test_assignments_to_experts_held_elsewhere_read_no_weight(monkeypatch):
     assert none_here.any() and not np.asarray(y)[none_here].any()
     with pytest.raises(ValueError, match="the weights hold 2 experts"):
         moe.moe_experts(x, held, K, held=(0, 4))
+
+
+@pytest.mark.parametrize("first", [0, 4], ids=["first_half", "second_half"])
+def test_a_prefill_sized_share_is_the_reference_and_itself_at_every_tile(
+        monkeypatch, first):
+    """400 tokens x 3 = 1200 assignments over a router 8 wide with 4 experts
+    held (fewer than ``DECODE_ROWS`` of them land here): the tile follows
+    the call's ``tokens x top_k``, so the share takes ``PREFILL_TM`` like the
+    uncut layer; the kernel's answer is the ``partitioned=True`` path's to
+    float32 rounding, and the same bytes with the tile forced to 128, 256 and
+    512."""
+    monkeypatch.setattr(moe, "MOE_KERNEL_INTERPRET", True)
+    layer, _ = _layer(5, shared=False)
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((400, D)), jnp.float32)
+    cut, kw = _share(layer, first, 4), dict(
+        norm_topk=True, score="sigmoid", held=(first, 4))
+    before = dict(dispatch_tally())
+    y, stats = moe.moe_experts(x, cut, K, **kw)
+    key = ("moe_experts", "kernel", f"interpret tm={moe.PREFILL_TM}")
+    assert dispatch_tally().get(key, 0) == before.get(key, 0) + 1
+    assert 0 < float(stats["expert_rows_local"]) <= moe.DECODE_ROWS < 400 * K
+    want, _ = moe.moe_experts(x, cut, K, partitioned=True, **kw)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5, rtol=0)
+    for tm in (128, 256, 512):
+        monkeypatch.setattr(moe, "row_tile", lambda a, e, tm=tm: tm)
+        again, _ = moe.moe_experts(x, cut, K, **kw)
+        assert np.array_equal(np.asarray(again), np.asarray(y))

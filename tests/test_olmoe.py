@@ -16,7 +16,13 @@ keeps (``benchmark/families/olmoe.py``), at a small size on the CPU.
       and answer as they do alone, also under the int8 arena, chunked
       prefill, shared-prefix pages and in-engine speculation;
   (g) a dense model traces the jaxpr it traced before the q/k/v and output
-      head helpers replaced the inline copies.
+      head helpers replaced the inline copies;
+  (h) the grouped kernel's row tile: a row's product is its own whatever
+      the tile (bit for bit at 128 / 256 / 512), the tile a call takes is a
+      function of its shapes alone (the four expert cells' buckets over the
+      router's width; a decode step keeps 128), the visits the kernel plans
+      and the rows they multiply at the cells' shapes, and the dispatch
+      tally's record of the tile.
 
 Logits are compared, never sampled tokens, in float32 models wherever the
 comparison is against the reference: every tolerance is then about the order
@@ -30,6 +36,7 @@ used). The hardware-gated rows at the end are run on the chip by
 """
 
 import importlib.util
+import json
 import os
 import time
 
@@ -308,7 +315,7 @@ def test_e_grouped_product_matches_per_token_loop(path, request):
     want = _per_token_loop(x, m, k)
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(want),
                                atol=8e-3, rtol=0)
-    key = ("moe_experts", "kernel", "interpret") if path == "kernel_interpret" \
+    key = ("moe_experts", "kernel", "interpret tm=128") if path == "kernel_interpret" \
         else ("moe_experts", "reference", "backend=cpu")
     assert dispatch_tally().get(key, 0) == before.get(key, 0) + 1
 
@@ -330,6 +337,163 @@ def test_e_kernel_rows_cross_tiles_and_masked_rows_hit_nothing(interpret_moe):
     want = _per_token_loop(x[:40], m, k)
     np.testing.assert_allclose(np.asarray(full[:40], np.float32),
                                np.asarray(want), atol=8e-3, rtol=0)
+
+
+# -- (h) the row tile ------------------------------------------------------------
+
+TILES = (128, 256, 512)
+# the prompt buckets (powers of two) each expert cell's traffic fills
+CELL_BUCKETS = {
+    "olmoe-1b-7b-0125": (64, 128, 256, 512, 1024, 2048),
+    "mistral-small-4-119b-2603": (512, 1024, 2048, 4096, 8192),
+    "lfm2-8b-a1b": (64, 128, 256, 512, 1024),
+    "mellum2-12b-a2.5b-instruct": (256, 512, 1024, 4096, 8192),
+}
+
+
+def _cell_experts(config):
+    """(hidden, expert width, the router's width, experts held, top_k) of an
+    expert cell, from ``benchmark/configs/<config>.json`` as it is run."""
+    with open(os.path.join(ROOT, "benchmark", "configs", config + ".json")) as f:
+        c = json.load(f)
+    held = c.get("num_experts", c.get("n_routed_experts"))
+    width = c.get("source_values", {}).get("n_routed_experts", held)
+    return (c["hidden_size"], c.get("moe_intermediate_size", c["intermediate_size"]),
+            width, held, c["num_experts_per_tok"])
+
+
+GROUPS = {
+    "uniform": [96] * 8,
+    "one_heavy_group": [1000, 1, 8, 3, 5, 2, 7, 4],
+    "empty_groups": [0, 200, 0, 0, 313, 0, 90, 0],
+    "rows_past_the_total": [40, 0, 130, 17, 0, 60, 3, 50],
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPS))
+def test_h_a_rows_product_does_not_depend_on_the_tile(case):
+    """``moe_grouped_matmul`` (through its interpreter) at row tiles of 128,
+    256 and 512: the SwiGLU product and the down product of every row under
+    the groups' total are the same bytes at each, and what ``ragged_dot``
+    gives to float32 rounding. k is not tiled, so a row's product is computed
+    from that row alone whichever rows share its tile."""
+    sizes = np.asarray(GROUPS[case], np.int32)
+    total = int(sizes.sum())
+    m = 1024 if case == "rows_past_the_total" else -(-total // 512) * 512
+    d, ff = 128, 256
+    w = _experts(jax.random.PRNGKey(5), len(sizes), d, ff, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(6), (m, d), jnp.float32)
+    gs = jnp.asarray(sizes)
+
+    def both(tm):
+        h = moe.moe_grouped_matmul(x, w["w1"], gs, w["w3"], tm=tm, interpret=True)
+        y = moe.moe_grouped_matmul(h, w["w2"], gs, tm=tm, interpret=True)
+        return np.asarray(h)[:total], np.asarray(y)[:total]
+
+    got = {tm: both(tm) for tm in TILES}
+    for tm in TILES[1:]:
+        np.testing.assert_array_equal(got[tm][0], got[TILES[0]][0])
+        np.testing.assert_array_equal(got[tm][1], got[TILES[0]][1])
+    h = moe.grouped_matmul_reference(x, w["w1"], gs, w["w3"])
+    y = moe.grouped_matmul_reference(h, w["w2"], gs)
+    np.testing.assert_allclose(got[256][0], np.asarray(h)[:total], atol=1e-4, rtol=0)
+    np.testing.assert_allclose(got[256][1], np.asarray(y)[:total], atol=1e-4, rtol=0)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["every_row", "row_mask"])
+def test_h_a_prefill_sized_call_is_the_reference_and_itself_at_every_tile(
+        interpret_moe, monkeypatch, masked):
+    """700 tokens x 2 of 8 experts = 1400 assignments, more than a decode
+    step's: the layer through the kernel equals the ``partitioned=True``
+    (``ragged_dot``) path to float32 rounding, takes ``PREFILL_TM`` and says
+    so in the dispatch tally, and is the same bytes with the tile forced to
+    128, 256 and 512."""
+    t, d, ff, e, k = 700, 128, 128, 8, 2
+    m = _experts(jax.random.PRNGKey(8), e, d, ff, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(9), (t, d), jnp.float32)
+    mask = jnp.arange(t) % 5 != 0 if masked else None
+    before = dict(dispatch_tally())
+    y, stats = moe.moe_experts(x, m, k, row_mask=mask)
+    key = ("moe_experts", "kernel", f"interpret tm={moe.PREFILL_TM}")
+    assert dispatch_tally().get(key, 0) == before.get(key, 0) + 1
+    want, _ = moe.moe_experts(x, m, k, row_mask=mask, partitioned=True)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-4, rtol=0)
+    assert float(stats["expert_rows_local"]) == (int(mask.sum()) if masked else t) * k
+    for tm in TILES:
+        monkeypatch.setattr(moe, "row_tile", lambda a, e, tm=tm: tm)
+        again, _ = moe.moe_experts(x, m, k, row_mask=mask)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(y))
+
+
+@pytest.mark.parametrize("config", list(CELL_BUCKETS))
+def test_h_the_tile_is_a_function_of_the_calls_shapes(config):
+    """Every prompt bucket of the four expert cells: a call of at most
+    ``DECODE_ROWS`` assignments keeps the decode tile; so does one whose
+    experts draw at most ``NARROW_ROWS`` rows each on average over the
+    ROUTER's width; every larger one takes ``PREFILL_TM`` = 256, the tile at
+    the chip's ridge: the largest bucket that stays narrow, cell by cell.
+    Nothing but ``tokens x top_k`` and the router's width enters: a chip's
+    share (32 of 128 experts held) takes the tile of the uncut layer."""
+    _, _, width, held, top_k = _cell_experts(config)
+    assert (moe.DECODE_TM, moe.PREFILL_TM, moe.NARROW_ROWS) == (128, 256, 64)
+    tiles = {tokens: moe.row_tile(tokens * top_k, width)
+             for tokens in CELL_BUCKETS[config]}
+    narrow_to = {"olmoe-1b-7b-0125": 512, "mistral-small-4-119b-2603": 2048,
+                 "lfm2-8b-a1b": 512, "mellum2-12b-a2.5b-instruct": 512}[config]
+    assert tiles == {t: 128 if t <= narrow_to else 256 for t in CELL_BUCKETS[config]}
+    if held != width:       # the held count alone would say 256 from 512 tokens on
+        assert moe.row_tile(2048 * top_k, width) == 128
+        assert moe.row_tile(2048 * top_k, held) == 256
+
+
+@pytest.mark.parametrize("lanes,top_k", [(1, 8), (32, 4), (32, 8), (128, 8), (256, 4)])
+def test_h_a_decode_step_keeps_its_tile(lanes, top_k):
+    """No decode program changes: ``a <= 1024`` (32 lanes x 8, a verify pass
+    of 128 rows x 8) takes ``DECODE_TM`` as it did, whatever the router's
+    width (a test model's 4 experts, the cells' 32 to 128)."""
+    assert lanes * top_k <= moe.DECODE_ROWS
+    for width in (4, 8, 32, 64, 128):
+        assert moe.row_tile(lanes * top_k, width) == moe.DECODE_TM == 128
+    assert moe.row_tile(moe.DECODE_ROWS + 1, 8) == moe.PREFILL_TM
+
+
+@pytest.mark.parametrize("config", list(CELL_BUCKETS))
+def test_h_visits_and_the_rows_they_multiply_by_tile(config):
+    """What the kernel's grid does at the cells' prefill shapes, counted from
+    ``make_group_metadata`` (no clock): the assignments of a bucket drawn over
+    the router's width, the held experts' groups kept. A visit multiplies a
+    whole tile, so the rows multiplied fall with the tile wherever an expert
+    draws fewer rows than the wider one (never by more than a tile an expert
+    otherwise), while the visits grow by at most one an expert a halving. At
+    the tile the layer takes the rows multiplied are under those at 512, the
+    tile it took before, in every bucket; in units of one expert's weight read,
+    a visit costing ``max(1, tm / 240)`` (240 rows: the v5e's ridge), 256 is
+    never behind 512."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
+
+    _, _, width, held, top_k = _cell_experts(config)
+    rng = np.random.default_rng(width + top_k)
+    for tokens in CELL_BUCKETS[config]:
+        a = tokens * top_k
+        if a <= moe.DECODE_ROWS:
+            continue
+        sizes = rng.multinomial(a, np.full(width, 1.0 / width))[:held].astype(np.int32)
+        visits = {}
+        for tm in TILES:
+            *_, n = make_group_metadata(
+                group_sizes=jnp.asarray(sizes), m=-(-a // tm) * tm, tm=tm,
+                start_group=jnp.int32(0), num_nonzero_groups=held,
+                visit_empty_groups=False)
+            visits[tm] = int(n)
+            assert held <= visits[tm] <= int(np.sum(-(-sizes // tm) + 1))
+        multiplied = {tm: visits[tm] * tm for tm in TILES}
+        for narrow, wide in ((128, 256), (256, 512)):
+            assert visits[wide] <= visits[narrow] <= 2 * visits[wide] + held
+            assert multiplied[narrow] <= multiplied[wide] + held * narrow
+            if sizes.max() < wide:
+                assert multiplied[narrow] < multiplied[wide], (config, tokens, visits)
+        assert multiplied[moe.row_tile(a, width)] < multiplied[512], (config, tokens)
+        assert visits[256] * max(1.0, 256 / 240) < visits[512] * 512 / 240
 
 
 # -- (f) through the engine ------------------------------------------------------
@@ -585,7 +749,7 @@ def test_moe_grouped_matmul_on_tpu(hit, rows):
     sizes = np.zeros(E, np.int32)
     sizes[np.random.default_rng(hit).choice(E, hit, replace=False)] = rows
     total = int(sizes.sum())
-    tm = moe.DECODE_TM if total <= moe.DECODE_ROWS else moe.PREFILL_TM
+    tm = moe.row_tile(total, E)
     padded = -(-total // tm) * tm
     x = jax.random.normal(jax.random.PRNGKey(7), (padded, D)).astype(jnp.bfloat16)
     gs = jnp.asarray(sizes)
@@ -654,3 +818,69 @@ def test_moe_layer_on_tpu(tokens, live):
           f"kernel path {t_k*1e3:.3f} ms ({gb/t_k:.0f} GB/s of routed weights), "
           f"ragged_dot path {t_r*1e3:.3f} ms, ratio {t_r/t_k:.2f}x, route+sort "
           f"alone {t_route*1e3:.3f} ms, max_abs_err {err:.4f}", flush=True)
+
+
+@ON_TPU
+@pytest.mark.parametrize("config", list(CELL_BUCKETS))
+def test_moe_prefill_tile_on_tpu(config, monkeypatch):
+    """``moe_experts`` as a prefill at an expert cell's widths (read from its
+    ``benchmark/configs`` file: hidden, expert width, the router's width, the
+    experts held, top_k) at each prompt bucket whose call is larger than a
+    decode step, with the row tile forced to 128, 256 and 512: ms a call and
+    the GB/s of the hit experts' weights under a uniform router and under one
+    whose selection bias sends EVERY token to the first held expert (one
+    group of ``tokens`` rows beside the uniform rest); parity with the
+    ``ragged_dot`` path asserted at every tile, and whether the tiles agree
+    bit for bit printed. The row the layer itself takes is marked ``*``."""
+    from tfservingcache_tpu.utils.benchtime import chained_device_time
+
+    d, ff, width, held, top_k = _cell_experts(config)
+    share = (0, held) if held != width else None
+    m = _experts(jax.random.PRNGKey(width), held, d, ff)
+    router = jax.random.normal(jax.random.PRNGKey(3), (d, width), jnp.float32) / np.sqrt(d)
+    biases = {"uniform": jnp.zeros((width,), jnp.float32),
+              "one_heavy": jnp.zeros((width,), jnp.float32).at[0].set(10.0)}
+
+    def layer(partitioned):
+        def f(x, router, bias, w1, w3, w2):
+            y, st = moe.moe_experts(
+                x, {"router": router, "bias": bias, "w1": w1, "w3": w3, "w2": w2},
+                top_k, held=share, partitioned=partitioned)
+            return y, st["experts_hit"], st["expert_rows_max"]
+        return f
+
+    def routing(x, router, bias):
+        gates, idx, _ = moe.route(x, router, top_k, bias=bias)
+        order = jnp.argsort(idx.reshape(-1), stable=True)
+        return gates[0, 0] + jnp.argsort(order)[0] + order[0]
+
+    taken = moe.row_tile
+    for tokens in CELL_BUCKETS[config]:
+        a = tokens * top_k
+        if a <= moe.DECODE_ROWS:
+            continue
+        x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, d)).astype(jnp.bfloat16)
+        weights = (m["w1"], m["w3"], m["w2"])
+        want = {name: np.asarray(jax.jit(layer(True))(x, router, b, *weights)[0],
+                                 np.float32) for name, b in biases.items()}
+        t_route = chained_device_time(routing, (x, router, biases["uniform"]))
+        got = {}
+        for tm in TILES:
+            monkeypatch.setattr(moe, "row_tile", lambda a, e, tm=tm: tm)
+            for name, b in biases.items():
+                args = (x, router, b, *weights)
+                y, hit, most = jax.jit(layer(False))(*args)
+                y = np.asarray(y, np.float32)
+                err = float(np.max(np.abs(y - want[name])))
+                assert err < 5e-2, f"{config} {tokens} tm={tm} {name}: max abs err {err}"
+                same = got.setdefault(name, y) is y or bool(np.array_equal(got[name], y))
+                t_k = chained_device_time(layer(False), args, iters=8)
+                gb = float(hit) * 3 * d * ff * 2 / 1e9
+                print(f"\n[moe_prefill_tile] {config} tokens={tokens} a={a} "
+                      f"a/E={a / width:.0f} {name} tm={tm}"
+                      f"{'*' if tm == taken(a, width) else ''}: {t_k*1e3:.3f} ms a call "
+                      f"({gb/t_k:.0f} GB/s of {float(hit):.0f} hit experts' weights, "
+                      f"most rows {float(most):.0f}), route+sort alone "
+                      f"{t_route*1e3:.3f} ms, max_abs_err {err:.4f}, "
+                      f"bit_equal_to_tm128={same}", flush=True)
+        monkeypatch.setattr(moe, "row_tile", taken)

@@ -28,7 +28,10 @@ rows, so an expert no token chose is never read from HBM, with the SwiGLU
 folded into the first product. Elsewhere (the CPU, a partitioned
 program) ``jax.lax.ragged_dot`` computes the same arithmetic. The gate records
 which was traced under ``('moe_experts', 'kernel'|'reference', reason)`` in
-``ops.attention.dispatch_tally()``.
+``ops.attention.dispatch_tally()``; the kernel's reason carries the row tile
+the program took (``'pallas tm=256'``), which ``row_tile`` reads off the
+call's shapes: 128 rows for a decode step and for a call whose experts draw
+few rows each, the chip's ridge (256 on a v5e) for anything larger.
 
 ``row_mask`` marks rows whose answer nobody reads (the engine's inactive
 lanes): their assignments go to no expert, so they hit none and read none,
@@ -55,12 +58,32 @@ from tfservingcache_tpu.ops.attention import _record_dispatch
 # (trace-time only, like ops.attention.PAGED_KERNEL_INTERPRET).
 MOE_KERNEL_INTERPRET = False
 
-# Row tiles: decode (at most 32 lanes x top_k rows, most of them masked at low
-# load) takes narrow tiles so that a step's few live rows sit in one tile;
-# prefill takes wide ones.
-DECODE_ROWS, DECODE_TM, PREFILL_TM = 1024, 128, 512
+# Row tiles. A visit multiplies a WHOLE tile by one expert's blocks and keeps
+# that expert's rows, so it costs the larger of the expert's weights' read and
+# the tile's product, and an expert that draws R rows takes about R / tm + 1
+# visits. The widest tile worth taking is the one at the chip's RIDGE, where a
+# visit's product takes as long as its read whatever the widths (rows x 6kn /
+# 197 TFLOP/s = 6kn B / 819 GB/s: 240 rows on a v5e, so 256): past it every
+# visit is compute-bound on rows that are mostly another expert's (512 rows
+# cost 0.9-2.0 ms a call more at the benchmark's widths). A call whose experts
+# draw well under a tile each (at most NARROW_ROWS on average over the router's
+# width; a decode step, at most 32 lanes x top_k rows with most of them masked
+# at low load, always) keeps the narrow tile, whose visits are bound by the
+# read alone: on the chip 2-11 % ahead of 256 at 16-32 rows an expert, level
+# at 64, behind from 128 on (PERF.md section 6, PR 44).
+DECODE_ROWS, DECODE_TM, PREFILL_TM = 1024, 128, 256
+NARROW_ROWS = 64
 TN = 512
 VMEM_LIMIT = 64 << 20
+
+
+def row_tile(a: int, n_experts: int) -> int:
+    """The row tile of a call with ``a`` assignments (``tokens x top_k``) over
+    a router ``n_experts`` wide: a function of the call's shapes alone, fixed
+    when the program is traced. The router's FULL width counts, not the
+    experts held here: a chip's share draws the same rows an expert."""
+    narrow = a <= max(DECODE_ROWS, NARROW_ROWS * n_experts)
+    return DECODE_TM if narrow else PREFILL_TM
 
 
 def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk: bool = False,
@@ -208,7 +231,7 @@ def moe_experts(x: jax.Array, moe: dict, top_k: int, *, norm_topk: bool = False,
             idx = jnp.where((idx >= 0) & (idx < e), idx, e)   # held elsewhere
         a = t * top_k
         why = _kernel_refusal(moe["w1"], partitioned)
-        tm = DECODE_TM if a <= DECODE_ROWS else PREFILL_TM
+        tm = row_tile(a, probs.shape[-1])
         rows = -(-a // tm) * tm if why is None else a
         flat = jnp.pad(idx.reshape(a), (0, rows - a), constant_values=e)
         order = jnp.argsort(flat, stable=True)
@@ -221,7 +244,8 @@ def moe_experts(x: jax.Array, moe: dict, top_k: int, *, norm_topk: bool = False,
         if why is None:
             _record_dispatch(
                 "moe_experts", "kernel",
-                "interpret" if MOE_KERNEL_INTERPRET else "pallas", *shapes)
+                f"{'interpret' if MOE_KERNEL_INTERPRET else 'pallas'} tm={tm}",
+                *shapes)
             gmm = functools.partial(moe_grouped_matmul, tm=tm,
                                     interpret=MOE_KERNEL_INTERPRET)
         else:

@@ -58,7 +58,12 @@ def main() -> int:
     # hit by 1, 4 and 48 rows each and with 63 experts empty, and the whole
     # layer at a decode step of 4 / 8 / 32 live lanes and prefills of 512 /
     # 2048 tokens, kernel against jax.lax.ragged_dot (ms, GB/s of the routed
-    # experts' weights).
+    # experts' weights), and (PR 44) the layer as a PREFILL at the four expert
+    # cells' widths (read from benchmark/configs/) and prompt buckets with the
+    # row tile forced to 128 / 256 / 512, under a uniform router and one that
+    # sends every token to one expert: ms a call, GB/s of the hit experts'
+    # weights, parity with ragged_dot at every tile, the row the layer takes
+    # marked `*`: `-k moe_prefill_tile_on_tpu`, ~10 min.
     # test_mla_moe.py carries the latent (MLA) decode kernel's rows at
     # Mistral-Small-4's shapes (32 heads over one 384-wide row, 16-token
     # pages, an arena of 16384 pages x 6 layers): parity with the gather +
