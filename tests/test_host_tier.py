@@ -27,6 +27,7 @@ from tfservingcache_tpu.cache.manager import CacheManager
 from tfservingcache_tpu.cache.providers.disk import DiskModelProvider
 from tfservingcache_tpu.config import ServingConfig
 from tfservingcache_tpu.models.registry import export_artifact
+from tfservingcache_tpu.runtime.base import ModelNotLoadedError
 from tfservingcache_tpu.runtime.model_runtime import TPUModelRuntime
 from tfservingcache_tpu.types import Model, ModelId
 from tfservingcache_tpu.utils.metrics import Metrics
@@ -124,7 +125,11 @@ def test_promotion_parity_int8_and_token_level_generate(tmp_path):
 def test_round_trip_under_concurrent_requests(tmp_path):
     """Two models thrashing through a 1-slot HBM budget from several
     threads: every request must see correct outputs while each hit demotes
-    the other model and promotes its own."""
+    the other model and promotes its own. Between a thread's ``ensure_loaded``
+    and its ``predict`` the other model's threads may take the one place:
+    that is what such a budget does under six threads, not a fault, and a
+    request answers it as the serving path does (``local_backend.
+    _predict_sync``): load again, then predict."""
     models = [
         export_model("half_plus_two", tmp_path, f"c{i}", seed=i) for i in range(2)
     ]
@@ -137,13 +142,19 @@ def test_round_trip_under_concurrent_requests(tmp_path):
             refs.append(rt.predict(m.identifier, x)["y"])
         errors = []
 
+        def served(m):
+            for _ in range(100):
+                rt.ensure_loaded(m)
+                try:
+                    return rt.predict(m.identifier, x)["y"]
+                except ModelNotLoadedError:  # eviction raced: load again
+                    continue
+            raise AssertionError(f"{m.identifier}: evicted before 100 predicts")
+
         def worker(m, ref):
             try:
                 for _ in range(25):
-                    rt.ensure_loaded(m)
-                    np.testing.assert_array_equal(
-                        rt.predict(m.identifier, x)["y"], ref
-                    )
+                    np.testing.assert_array_equal(served(m), ref)
             except Exception as e:  # noqa: BLE001 - surfaced below
                 errors.append(e)
 
@@ -155,7 +166,8 @@ def test_round_trip_under_concurrent_requests(tmp_path):
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
         assert not errors, errors
         rt.drain_demotions()
         # both models ended up tier-resident; at most one still in HBM

@@ -1,8 +1,19 @@
 """Named scopes on the device side (ISSUE 23): the step programs carry
 ``jax.named_scope`` names so a profiler capture says whose a ``copy`` or a
 ``fusion`` is. Trace-time only: this lowers the engine's programs at a tiny
-size and looks for every name in the lowered text's locations."""
+size and looks for every name in the lowered text's locations.
 
+Since PR 45 the decode chunk and the slot prefill walk a model's layers
+through ONE body (``generation._walk_layers``) over two row stores, so both
+programs are lowered for every cached family, at the rehearsal widths of its
+cell's configuration, and held to the ``layer/...`` paths the readers of
+``benchmark/layer_metrics``, ``benchmark/capture_scopes.py`` and
+``tools/trace_scopes.py`` look for: a scope that moves makes a per-layer
+metric ``null``, which nothing else off the chip notices."""
+
+import importlib.util
+import json
+import os
 import re
 
 import jax
@@ -10,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tfservingcache_tpu.models import generation
+from tfservingcache_tpu.models import generation, registry
 from tfservingcache_tpu.models.transformer_lm import build
 
 SCOPES = ("embed", "layer", "attn", "kv_read", "kv_write", "ffn", "lm_head",
@@ -20,6 +31,53 @@ TINY = {
     "n_kv_heads": 2, "d_ff": 96, "max_seq": 64, "dtype": "float32",
     "rope_theta": 10000.0,
 }
+BENCHMARK = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+
+# what each kind of row store alone carries, whatever the family: the paged
+# arena is read where it lies, inside the attention call, and no layer's slice
+# is ever taken out of it; the dense cache's layer is read before its write
+KV_PAGED, KV_DENSE = {"layer/attn/kv_read"}, {"layer/kv_read"}
+WINDOWED_PAGED = {"layer/attn/global/kv_read", "layer/attn/window/kv_read"}
+EXPERTS = {"layer/ffn/route", "layer/ffn/experts"}
+# family case -> (configuration file, paths BOTH programs carry, the decode
+# chunk's alone, the slot prefill's alone)
+FAMILIES = {
+    "transformer_lm": (
+        "mistral-7b-v0.3", {"layer/attn", "layer/kv_write", "layer/ffn"},
+        KV_PAGED, KV_DENSE),
+    "moe_lm": (
+        "olmoe-1b-7b-0125",
+        {"layer/attn", "layer/kv_write", "layer/ffn"} | EXPERTS,
+        KV_PAGED, KV_DENSE),
+    "mla_moe_lm": (
+        "mistral-small-4-119b-2603",
+        {"layer/attn", "layer/attn/q_lora", "layer/attn/kv_lora", "layer/ffn",
+         "layer/ffn/shared"} | EXPERTS,
+        # the one row a token is written between the two halves of the paged
+        # layer's attention, and inside the dense one's
+        KV_PAGED | {"layer/attn/absorb", "layer/kv_write"},
+        {"layer/attn/kv_write"}),
+    "hybrid_lm": (
+        "lfm2-8b-a1b",
+        {"layer/attn", "layer/conv", "layer/kv_write", "layer/ffn"} | EXPERTS,
+        KV_PAGED, KV_DENSE),
+    "moe_lm-window": (
+        "mellum2-12b-a2.5b-instruct",
+        {"layer/attn", "layer/attn/global", "layer/attn/window",
+         "layer/kv_write", "layer/ffn"} | EXPERTS,
+        WINDOWED_PAGED, KV_DENSE),
+    "sambay_lm": (
+        "phi-4-mini-flash-reasoning",
+        {"layer/attn", "layer/attn/global", "layer/attn/window",
+         "layer/attn/cross", "layer/attn/global/diff", "layer/attn/window/diff",
+         "layer/attn/cross/diff", "layer/ssm", "layer/gmu", "layer/kv_write",
+         "layer/ffn"},
+        WINDOWED_PAGED | {"layer/attn/cross/kv_read", "layer/ssm/step"},
+        KV_DENSE | {"layer/ssm/scan"}),
+}
+# every path a case names: a program must carry its own and none of the others
+VOCABULARY = set().union(*(both | paged | dense
+                           for _, both, paged, dense in FAMILIES.values()))
 
 
 @pytest.fixture(scope="module")
@@ -30,43 +88,100 @@ def model():
     return cfg, tuple(sorted(cfg.items())), params
 
 
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def family_case(request):
+    """-> (ModelDef at the rehearsal widths of the case's configuration, its
+    static config, abstract params, the three path sets)."""
+    name, *paths = FAMILIES[request.param]
+    with open(os.path.join(BENCHMARK, "configs", f"{name}.json")) as f:
+        config = json.load(f)
+    config = _merged(config, config["rehearsal"])
+    spec = importlib.util.spec_from_file_location(
+        f"scopes_family_{config['family']}",
+        os.path.join(BENCHMARK, "families", f"{config['family']}.py"))
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    mdef = registry.build(family.PROGRAM_FAMILY, family.program_config(config))
+    params = jax.eval_shape(mdef.init, jax.random.PRNGKey(0))
+    return mdef, registry.static_config(mdef), params, paths
+
+
+def _merged(base: dict, over: dict) -> dict:
+    """``over`` laid over ``base``, dict by dict (``benchmark/run.py``'s)."""
+    return {**base, **{k: _merged(base[k], v) if isinstance(v, dict)
+                       and isinstance(base.get(k), dict) else v
+                       for k, v in over.items()}}
+
+
+def _locations(lowered) -> list:
+    return re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True))
+
+
 def _scoped(lowered):
     """-> ``has(path)``: does some location of the lowered program carry
     ``path`` (``layer/attn``) as consecutive components of its scope path?"""
-    locs = ["/" + name + "/" for name in
-            re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True))]
+    locs = ["/" + name + "/" for name in _locations(lowered)]
     return lambda path: any("/" + path + "/" in loc for loc in locs)
 
 
-def test_decode_chunk_program_carries_every_scope(model):
-    cfg, cfg_key, params = model
+def _layer_paths(lowered) -> set:
+    """The paths of ``VOCABULARY`` that some operation of the program lies
+    under, read FROM ``layer`` on (``layer/kv_read`` is not found inside
+    ``layer/attn/kv_read``)."""
+    found = set()
+    for name in _locations(lowered):
+        parts = name.split("/")
+        if "layer" in parts:
+            at = parts.index("layer")
+            found.update("/".join(parts[at:end])
+                         for end in range(at + 2, len(parts)))
+    return found & VOCABULARY
+
+
+def _decode_chunk(mdef, cfg_key, params):
     lanes, pages, pt = 2, 8, 4
-    cache = generation.init_paged_cache(cfg, pages, pt)
-    has = _scoped(generation._paged_decode_chunk_jit.lower(
-        params, cache["k"], cache["v"], None,
+    arena = generation.init_paged_cache(dict(cfg_key), pages, pt, lanes=lanes)
+    ring = ((arena["wk"], arena["wv"]),) if "wk" in arena else ()
+    return generation._paged_decode_chunk_jit.lower(
+        params, arena["k"], arena.get("v"), None,
         np.zeros((lanes, 4), np.int32), np.zeros(lanes, np.int32),
         np.zeros(lanes, np.int32), np.ones(lanes, bool),
         np.uint32(1),
         np.zeros(lanes, np.float32), np.zeros(lanes, np.int32),
-        cfg_key=cfg_key, chunk=2, page_tokens=pt, kernel=False,
-    ))
+        generation.init_lane_state(dict(cfg_key), lanes), *ring,
+        cfg_key=cfg_key, family=mdef.family, chunk=2, page_tokens=pt,
+        kernel=False,
+    )
+
+
+def _slot_prefill(mdef, cfg_key, params):
+    return generation._slot_prefill_jit.lower(
+        params, np.zeros((1, 8), np.int32), np.asarray([5], np.int32),
+        jax.random.PRNGKey(2), np.float32(0.0), np.int32(0), cfg_key=cfg_key,
+        family=mdef.family,
+    )
+
+
+def test_decode_chunk_program_carries_every_scope(family_case):
+    mdef, cfg_key, params, (both, paged, _) = family_case
+    lowered = _decode_chunk(mdef, cfg_key, params)
+    has = _scoped(lowered)
     assert [s for s in SCOPES if not has(s)] == []
+    assert _layer_paths(lowered) == both | paged
     # the reads of the arena (the reference's gather of the lanes' pages) and
     # its update (one scatter of the new rows a layer) are told apart inside a
     # layer; nothing re-stacks slices after the loop (PR 26: there are none)
-    for path in ("layer/attn/kv_read/gather", "layer/kv_write/scatter",
-                 "layer/attn", "layer/ffn"):
-        assert has(path), path
-    assert not has("layer/kv_read")            # no layer's slice is taken out
+    assert has("kv_read/gather") and has("layer/kv_write/scatter")
 
 
-def test_slot_prefill_program_carries_every_scope(model):
-    cfg, cfg_key, params = model
-    has = _scoped(generation._slot_prefill_jit.lower(
-        params, np.zeros((1, 8), np.int32), np.asarray([5], np.int32),
-        jax.random.PRNGKey(2), np.float32(0.0), np.int32(0), cfg_key=cfg_key,
-    ))
-    assert [s for s in SCOPES if not has(s)] == []
+def test_slot_prefill_program_carries_every_scope(family_case):
+    mdef, cfg_key, params, (both, _, dense) = family_case
+    lowered = _slot_prefill(mdef, cfg_key, params)
+    has = _scoped(lowered)
+    # a latent row is read where it is written, inside the layer's attention
+    assert [s for s in SCOPES if not has(s)] == (
+        ["kv_read"] if mdef.family == "mla_moe_lm" else [])
+    assert _layer_paths(lowered) == both | dense
 
 
 def test_insert_and_predict_programs_are_named(model):

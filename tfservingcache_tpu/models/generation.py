@@ -26,6 +26,14 @@ of the layer whose rows it reads, and it writes nothing. A layer with
 their operators in the declaration (``_lane_layer``; ``NoState.operator``):
 nothing here knows a family's name.
 
+Every cached forward walks the model's layers in ONE place (``_walk_layers``,
+``_attend_rows``): a layer kind is written there once. Where a layer's rows
+live, how new rows are written and how the queries attend them is a row
+store's: ``_PagedRows`` (the arena through block tables: the decode step, the
+verify pass, a prefill chunk; ``_paged_verify_step``) and ``_DenseRows`` (a
+cache of ``max_len`` rows an example: the admission prefill and the solo
+decoder; ``_forward_cached_dyn``).
+
 No reference counterpart (the reference proxies opaque Predict calls —
 SURVEY.md §5); generation is where a TPU-native LM server must not re-run
 the full sequence per token. Design:
@@ -79,9 +87,14 @@ from tfservingcache_tpu.models.transformer_lm import (
     rope_of,
 )
 from tfservingcache_tpu.ops.attention import (
+    attention,
     diff_outputs,
     diff_queries,
     pack_rows,
+    paged_attention,
+    paged_attention_verify,
+    paged_latent_attention,
+    paged_window_attention,
     unpack_pages,
 )
 
@@ -207,8 +220,8 @@ def shared_pages_read(pos, active, chunk: int, readers: int,
 
 
 def _lane_layer(layer: dict, x, state, real_len, kind: LaneState, cfg):
-    """A layer that keeps a lane state, its operator half, for both cached
-    loops: the residual stream ``x`` BEFORE its norm and the layer's slice
+    """A layer that keeps a lane state, its operator half, for the cached
+    walk: the residual stream ``x`` BEFORE its norm and the layer's slice
     ``state`` (``(B, rows, width)``, or a tuple of such a part) -> (residual
     delta, the slice after ``real_len`` of the tokens at hand, what the layer
     hands on to the layers after it or None): the operator the layer's
@@ -245,7 +258,7 @@ def _norm_eps(cfg) -> float:
 
 
 def _differential_qkv(attn: dict, a, cfg):
-    """A differential layer's projections for both cached loops (a layer that
+    """A differential layer's projections for the cached walk (a layer that
     holds ``lam_q1``): two softmaxes a head pair over rows that hold a pair of
     KV heads, no rotary -> (queries padded with zeros in grouped-query order,
     ``ops.attention.diff_queries``; the rows the layer keeps, ``(B, pairs, T,
@@ -887,137 +900,113 @@ def _paged_verify_step(params, toks, cache, tables, pos, cfg, family,
     stays bit for bit. Only T = 1 carries it: a verify pass or a prefill
     chunk of such a model is refused by name at trace time (the runtime
     refuses them before, ``_refuse_lane_state``)."""
-    from tfservingcache_tpu.ops.attention import (
-        paged_attention,
-        paged_attention_verify,
-        paged_latent_attention,
-        paged_window_attention,
-    )
+    rows = _PagedRows(cache, tables, pos, toks.shape[1], cfg, family,
+                      page_tokens, kernel, active, live)
+    logits = _walk_layers(params, toks, rows, cfg, moe_stats=moe_stats)
+    return logits, rows.cache
 
-    dtype = jnp.dtype(cfg["dtype"])
-    s_lanes, t_q = toks.shape
-    row = _cache_row(cfg)
-    eps = _norm_eps(cfg)
-    pps = tables.shape[1]
-    positions = pos[:, None] + jnp.arange(t_q)[None, :]          # (S, T)
-    pages = jnp.take_along_axis(
-        tables, jnp.clip(positions // page_tokens, 0, pps - 1), axis=1
-    )                                                            # (S, T)
-    # past-the-table positions redirect to the trash page EXPLICITLY — the
-    # clip alone would alias them onto the lane's own LAST slot, which is a
-    # live reserved page when the lane's budget fills the whole table (a
-    # draft scan near max_seq under spec headroom capping can get here)
-    pages = jnp.where(positions // page_tokens >= pps, 0, pages)
-    off = positions % page_tokens
 
-    slots = _layer_slots(cfg)
-    if t_q != 1 and any(s.lane for s in slots):
-        raise ValueError(
-            f"{family}: a forward of {t_q} positions a lane over the paged "
-            "arena (a speculative verify pass, a prefill chunk) does not "
-            "carry a lane state")
-    windowed = any(s.window for s in slots)
-    if windowed and (t_q != 1 or "wk" not in cache):
-        raise ValueError(
-            f"{family}: a forward of {t_q} positions a lane over the paged "
-            "arena (a speculative verify pass, a prefill chunk) does not "
-            "turn a window layer's ring")
-    if windowed:
-        # a window layer's write goes to the lane's own ring, wherever its
-        # block table points: page (p // page_tokens) % R of the lane's R
-        ring_pages = cache["wk"].shape[1] // s_lanes
-        ring_at = (jnp.arange(s_lanes)[:, None] * ring_pages
-                   + (positions // page_tokens) % ring_pages)
-    # a lane nobody reads keeps its state: it takes 0 of the step's 1 token
-    took = None if active is None else active.astype(jnp.int32)
-    handed: dict = {}     # what a layer's operator hands on to later layers
+class _PagedRows:
+    """Where ``_walk_layers`` keeps a layer's rows for ``_paged_verify_step``:
+    in the paged arena, through the lanes' block tables (that docstring says
+    what goes where). New rows go in through ``_paged_write_rows``, in place:
+    the global arena at the pages the tables name, a window layer's into the
+    lane's own ring; the queries attend the arena where it lies. A lane state
+    is updated in place in ``cache["lane"]``. ``cache`` is the arena after
+    the layers walked so far."""
 
-    with jax.named_scope("embed"):
-        x = params["embed"][toks].astype(dtype)                  # (S, T, d)
-    for depth, (layer, kind, (lane, _dense, li, window)) in enumerate(
-            zip(params["layers"], _layer_kinds(cfg), slots)):
-        with jax.named_scope("layer"):
-            if lane or isinstance(kind, NoState):
-                if lane:
-                    out, after, extras = _lane_layer(
-                        layer, x, _lane_slice(cache["lane"], li), took, kind,
-                        cfg)
-                    cache = {**cache, "lane": jax.tree_util.tree_map(
-                        lambda a, n: a.at[li].set(n.astype(a.dtype)),
-                        cache["lane"], after)}
-                    handed.update(extras or {})
-                else:
-                    out = kind.operator(layer, x, handed, cfg)
-                x = x + out
-                x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
-                                   moe_stats=moe_stats)
-                continue
-            shared = isinstance(kind, SharedRows)
-            scale = None
-            with jax.named_scope("attn"):
-                attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                a = _norm(layer, "ln1", x, eps)
-                differential = "lam_q1" in attn
-                if row.sides == 1:
-                    q_n, q_r, k = latent_project(attn, a, positions, cfg)
-                    q = absorbed_query(attn, q_n, q_r, cfg)
-                    k, v = k[:, :, None], None                   # (S, T, 1, W)
-                elif differential:
-                    q, k, v, scale = _differential_qkv(attn, a, cfg)
-                    if not shared:
-                        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-                else:
-                    q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"])
-                    rope = rope_of(cfg, window)
-                    q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
-                    k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
-                    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            if shared:
-                pass     # the rows are another layer's, written when it ran
-            elif window:
-                ring = _paged_write_rows(
-                    {"k": cache["wk"], "v": cache["wv"]}, li, ring_at, off,
-                    k, v, live)
-                cache = {**cache, "wk": ring["k"], "wv": ring["v"]}
-            else:
-                cache = _paged_write_rows(cache, li, pages, off, k, v, live)
-            with jax.named_scope("attn"), _kind_scope(
-                    windowed, "cross" if shared else
-                    "window" if window else "global"):
-                if row.sides == 1:
-                    out = paged_latent_attention(
-                        q, cache["k"], tables, pos, page_tokens,
-                        row.value_width, softmax_scale(cfg), kernel=kernel,
-                        active=active if t_q == 1 else None, layer=li)
-                    x = x + absorbed_output(attn, out, cfg, dtype)
-                else:
-                    operands = (q, cache["k"], cache["v"], tables, pos,
-                                page_tokens, cache.get("k_scale"),
-                                cache.get("v_scale"))
-                    if window:
-                        out = paged_window_attention(
-                            q, cache["wk"], cache["wv"], pos, page_tokens,
-                            window, kernel=kernel, active=active, layer=li,
-                            sm_scale=scale)
-                    elif t_q == 1:
-                        out = paged_attention(*operands, kernel=kernel,
-                                              active=active, layer=li,
-                                              sm_scale=scale)
-                    else:
-                        out = paged_attention_verify(*operands, kernel=kernel,
-                                                     layer=li)
-                    if differential:
-                        x = x + diff_finish(attn, diff_outputs(out), depth,
-                                            dtype)
-                    else:
-                        out = out.reshape(
-                            s_lanes, cfg["n_heads"], t_q, row.width)
-                        out = out.astype(x.dtype).transpose(0, 2, 1, 3)
-                        # heads x head width: the hidden size for most models
-                        x = x + out.reshape(s_lanes, t_q, -1) @ attn["wo"]
-            x = x + _ffn_block(layer, x, cfg, dtype, row_mask=active,
-                               moe_stats=moe_stats)
-    return _output_logits(params, x, dtype, eps), cache
+    def __init__(self, cache, tables, pos, t_q: int, cfg, family,
+                 page_tokens: int, kernel: bool, active, live):
+        s_lanes = pos.shape[0]
+        pps = tables.shape[1]
+        positions = pos[:, None] + jnp.arange(t_q)[None, :]          # (S, T)
+        pages = jnp.take_along_axis(
+            tables, jnp.clip(positions // page_tokens, 0, pps - 1), axis=1
+        )                                                            # (S, T)
+        # past-the-table positions redirect to the trash page EXPLICITLY — the
+        # clip alone would alias them onto the lane's own LAST slot, which is a
+        # live reserved page when the lane's budget fills the whole table (a
+        # draft scan near max_seq under spec headroom capping can get here)
+        self.pages = jnp.where(positions // page_tokens >= pps, 0, pages)
+        self.off = positions % page_tokens
+
+        slots = _layer_slots(cfg)
+        if t_q != 1 and any(s.lane for s in slots):
+            raise ValueError(
+                f"{family}: a forward of {t_q} positions a lane over the paged "
+                "arena (a speculative verify pass, a prefill chunk) does not "
+                "carry a lane state")
+        windowed = any(s.window for s in slots)
+        if windowed and (t_q != 1 or "wk" not in cache):
+            raise ValueError(
+                f"{family}: a forward of {t_q} positions a lane over the paged "
+                "arena (a speculative verify pass, a prefill chunk) does not "
+                "turn a window layer's ring")
+        if windowed:
+            # a window layer's write goes to the lane's own ring, wherever its
+            # block table points: page (p // page_tokens) % R of the lane's R
+            ring_pages = cache["wk"].shape[1] // s_lanes
+            self.ring_at = (jnp.arange(s_lanes)[:, None] * ring_pages
+                            + (positions // page_tokens) % ring_pages)
+        # a lane nobody reads keeps its state: it takes 0 of the step's 1 token
+        self.took = None if active is None else active.astype(jnp.int32)
+        self.cache, self.tables, self.pos, self.positions = (
+            cache, tables, pos, positions)
+        self.t_q, self.cfg, self.page_tokens = t_q, cfg, page_tokens
+        self.kernel, self.active, self.live = kernel, active, live
+
+    def keep_lane_state(self, slot: Slot, after) -> None:
+        self.cache = {**self.cache, "lane": jax.tree_util.tree_map(
+            lambda a, n: a.at[slot.index].set(n.astype(a.dtype)),
+            self.cache["lane"], after)}
+
+    def write(self, slot: Slot, k, v) -> None:
+        with jax.named_scope("attn"):    # the projection's: (S, T, heads, width)
+            k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        if slot.window:
+            ring = _paged_write_rows(
+                {"k": self.cache["wk"], "v": self.cache["wv"]}, slot.arena,
+                self.ring_at, self.off, k, v, self.live)
+            self.cache = {**self.cache, "wk": ring["k"], "wv": ring["v"]}
+        else:
+            self.cache = _paged_write_rows(
+                self.cache, slot.arena, self.pages, self.off, k, v, self.live)
+
+    def attend(self, slot: Slot, q, scale):
+        cache = self.cache
+        if slot.window:
+            return paged_window_attention(
+                q, cache["wk"], cache["wv"], self.pos, self.page_tokens,
+                slot.window, kernel=self.kernel, active=self.active,
+                layer=slot.arena, sm_scale=scale)
+        operands = (q, cache["k"], cache["v"], self.tables, self.pos,
+                    self.page_tokens, cache.get("k_scale"),
+                    cache.get("v_scale"))
+        if self.t_q == 1:
+            return paged_attention(*operands, kernel=self.kernel,
+                                   active=self.active, layer=slot.arena,
+                                   sm_scale=scale)
+        return paged_attention_verify(*operands, kernel=self.kernel,
+                                      layer=slot.arena)
+
+    def latent_layer(self, attn, a, x, slot: Slot):
+        """A latent layer's attention half: the ONE row a token written, then
+        the absorbed form over the lane's pages."""
+        cfg = self.cfg
+        with jax.named_scope("attn"):
+            q_n, q_r, k = latent_project(attn, a, self.positions, cfg)
+            q = absorbed_query(attn, q_n, q_r, cfg)
+            k = k[:, :, None]                                    # (S, T, 1, W)
+        self.cache = _paged_write_rows(
+            self.cache, slot.arena, self.pages, self.off, k, None, self.live)
+        with jax.named_scope("attn"):
+            out = paged_latent_attention(
+                q, self.cache["k"], self.tables, self.pos, self.page_tokens,
+                _cache_row(cfg).value_width, softmax_scale(cfg),
+                kernel=self.kernel,
+                active=self.active if self.t_q == 1 else None,
+                layer=slot.arena)
+            return x + absorbed_output(attn, out, cfg, x.dtype)
 
 
 def _kind_scope(windowed: bool, kind: str = "global"):
@@ -1459,24 +1448,81 @@ def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
         return (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
 
 
-def _latent_cached_layer(attn, a, rows_layer, start_pos, positions, cfg,
-                         fresh: bool):
-    """The attention half of a latent layer against a DENSE cache: the new
-    rows written at each example's ``start_pos`` -> (residual delta, the
-    layer's rows ``(B, 1, L, W)``). ``fresh`` (every ``start_pos`` is 0 and
-    the cache holds nothing) attends among the tokens at hand in the expanded
-    form, through ``ops.attention.attention``; otherwise the absorbed form
-    reads the whole cache."""
-    q_n, q_r, rows = latent_project(attn, a, positions, cfg)
-    with jax.named_scope("kv_write"):
-        rows_layer = jax.vmap(
-            lambda c, new, p: jax.lax.dynamic_update_slice(c, new[None], (0, p, 0))
-        )(rows_layer, rows.astype(rows_layer.dtype), start_pos)
-    if fresh:
-        return expanded_attention(attn, q_n, q_r, rows, cfg), rows_layer
-    out = dense_absorbed_attention(
-        absorbed_query(attn, q_n, q_r, cfg), rows_layer[:, 0], positions, cfg)
-    return absorbed_output(attn, out, cfg, a.dtype), rows_layer
+def _walk_layers(params, ids, rows, cfg, logits_at=None,
+                 moe_stats: list | None = None):
+    """THE walk over a model's layers for a cached forward of the tokens
+    ``ids (B, T)`` -> logits f32: every layer kind is written here, once.
+    ``rows`` says where a layer's rows live, how new rows are written and how
+    the queries attend them: ``_PagedRows`` (the arena, ``_paged_verify_step``)
+    or ``_DenseRows`` (a cache of ``max_len`` rows an example,
+    ``_forward_cached_dyn``). It also holds the tokens' ``positions``, how
+    many of them each lane ``took``, which rows are ``active`` (None = all)
+    and the ``cache`` a lane state is read from. A layer with a ``LaneState``
+    or ``NoState`` brings its operator; a layer with rows, its own or another
+    layer's, is ``_attend_rows``. ``logits_at (B,)`` projects that one
+    position of each example through the head."""
+    dtype = jnp.dtype(cfg["dtype"])
+    handed: dict = {}     # what a layer's operator hands on to later layers
+
+    with jax.named_scope("embed"):
+        x = params["embed"][ids].astype(dtype)                   # (B, T, d)
+    for depth, (layer, kind, slot) in enumerate(
+            zip(params["layers"], _layer_kinds(cfg), _layer_slots(cfg))):
+        with jax.named_scope("layer"):
+            if slot.lane:
+                out, after, extras = _lane_layer(
+                    layer, x, _lane_slice(rows.cache["lane"], slot.index),
+                    rows.took, kind, cfg)
+                rows.keep_lane_state(slot, after)
+                handed.update(extras or {})
+                x = x + out
+            elif isinstance(kind, NoState):
+                x = x + kind.operator(layer, x, handed, cfg)
+            else:
+                x = _attend_rows(layer, x, kind, slot, depth, rows, cfg)
+            x = x + _ffn_block(layer, x, cfg, dtype, row_mask=rows.active,
+                               moe_stats=moe_stats)
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
+    return _output_logits(params, x, dtype, _norm_eps(cfg))
+
+
+def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
+    """The attention half of layer ``depth``, a layer with rows -> the residual
+    stream after it. The weights' cast and the norm; then a latent row's
+    projection, write and attention are the store's own (``latent_layer``);
+    every other layer is projected here (differential, or rotary by the
+    layer's kind), its rows written by the store (unless they are another
+    layer's, ``SharedRows``: written when that one ran), its queries attended
+    by the store, and the heads finished here."""
+    b, t, _ = x.shape
+    shared = isinstance(kind, SharedRows)
+    with jax.named_scope("attn"):
+        attn = jax.tree_util.tree_map(lambda w: w.astype(x.dtype), layer["attn"])
+        a = _norm(layer, "ln1", x, _norm_eps(cfg))
+    if _cache_row(cfg).sides == 1:
+        return rows.latent_layer(attn, a, x, slot)
+    differential = "lam_q1" in attn
+    with jax.named_scope("attn"):
+        if differential:
+            q, k, v, scale = _differential_qkv(attn, a, cfg)
+        else:
+            q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"])
+            rope, scale = rope_of(cfg, slot.window), None
+            q = _rope_per_example(q, rows.positions, cfg["rope_theta"], rope)
+            k = _rope_per_example(k, rows.positions, cfg["rope_theta"], rope)
+    if not shared:
+        rows.write(slot, k, v)
+    with jax.named_scope("attn"), _kind_scope(
+            bool(_window_of(cfg)),
+            "cross" if shared else "window" if slot.window else "global"):
+        out = rows.attend(slot, q, scale).reshape(
+            b, cfg["n_heads"], t, q.shape[-1])
+        if differential:
+            return x + diff_finish(attn, diff_outputs(out), depth, x.dtype)
+        out = out.astype(x.dtype).transpose(0, 2, 1, 3)
+        # heads x head width: the hidden size for most models
+        return x + out.reshape(b, t, -1) @ attn["wo"]
 
 
 def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
@@ -1500,141 +1546,117 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
     skipped in a window layer), so no ``(S, max_len)`` score block is built: an
     8192-token prompt's would be 8.6 GB. The other K/V families' programs are
     the ones they were."""
-    from tfservingcache_tpu.ops.attention import attention
+    rows = _DenseRows(cache, start_pos, input_ids.shape[1], cfg, fresh,
+                      real_len)
+    logits = _walk_layers(params, input_ids, rows, cfg, logits_at=logits_at)
+    return logits, rows.cache_after()
 
-    dtype = jnp.dtype(cfg["dtype"])
-    b, s_len = input_ids.shape
-    positions = start_pos[:, None] + jnp.arange(s_len)[None, :]   # (B, S)
-    latent = _cache_row(cfg).sides == 1
-    eps = _norm_eps(cfg)
-    windowed = bool(_window_of(cfg))
 
-    with jax.named_scope("embed"):
-        x = params["embed"][input_ids].astype(dtype)
-    new_k, new_v, new_lane = [], [], []
-    fresh_rows: dict = {}    # a row layer's K/V of the tokens at hand, by index
-    handed: dict = {}        # what a layer's operator hands on to later layers
-    n_heads, n_kv = cfg["n_heads"], cfg.get("n_kv_heads")
-    for depth, (layer, kind, (lane, li, _arena, window)) in enumerate(
-            zip(params["layers"], _layer_kinds(cfg), _layer_slots(cfg))):
-        with jax.named_scope("layer"):
-            if lane or isinstance(kind, NoState):
-                if lane:
-                    out, after, extras = _lane_layer(
-                        layer, x, _lane_slice(cache["lane"], li), real_len,
-                        kind, cfg)
-                    new_lane.append(after)
-                    handed.update(extras or {})
-                else:
-                    out = kind.operator(layer, x, handed, cfg)
-                x = x + out
-                x = x + _ffn_block(layer, x, cfg, dtype)
-                continue
-            if latent:
-                with jax.named_scope("attn"):
-                    attn = jax.tree_util.tree_map(
-                        lambda w: w.astype(dtype), layer["attn"])
-                    out, rows = _latent_cached_layer(
-                        attn, _rmsnorm(x, layer["ln1"], eps), cache["k"][li],
-                        start_pos, positions, cfg, fresh)
-                    new_k.append(rows)
-                x = x + out
-                x = x + _ffn_block(layer, x, cfg, dtype)
-                continue
-            shared = isinstance(kind, SharedRows)
-            scale = None
-            with jax.named_scope("attn"):
-                attn = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"])
-                differential = "lam_q1" in attn
-                if differential:
-                    q, k, v, scale = _differential_qkv(
-                        attn, _norm(layer, "ln1", x, eps), cfg)
-                else:
-                    q, k, v = _qkv(attn, _rmsnorm(x, layer["ln1"], eps), n_heads, n_kv)
-                    rope = rope_of(cfg, window)
-                    q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
-                    k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
-            if shared:
-                # another layer's rows, written when it ran
-                k, v = fresh_rows[li]
-                k_cache, v_cache = new_k[li], new_v[li]
+class _DenseRows:
+    """Where ``_walk_layers`` keeps a layer's rows for ``_forward_cached_dyn``:
+    in a dense cache ``(row layers, B, heads, max_len, width)``, each example's
+    new rows written at its own ``start_pos`` and the queries attending the
+    layer's whole length under a mask (that docstring says when the tokens at
+    hand alone are attended). Nothing is updated in place: the layers' rows
+    and lane states after the forward are gathered a layer and stacked at the
+    end (``cache_after``)."""
+
+    active = None      # every row's answer is read
+
+    def __init__(self, cache, start_pos, s_len: int, cfg, fresh: bool,
+                 real_len):
+        self.positions = start_pos[:, None] + jnp.arange(s_len)[None, :]  # (B, S)
+        self.cache, self.start_pos, self.took = cache, start_pos, real_len
+        self.cfg, self.fresh = cfg, fresh
+        self.flash = fresh and bool(_window_of(cfg))
+        self.k, self.v, self.lane = [], [], []    # after the forward, a layer
+        self.fresh_rows: dict = {}   # a row layer's K/V of the tokens at hand
+
+    def keep_lane_state(self, slot: Slot, after) -> None:
+        self.lane.append(after)
+
+    def write(self, slot: Slot, k, v) -> None:
+        cache = self.cache
+        with jax.named_scope("kv_read"):
+            k_layer, v_layer = cache["k"][slot.index], cache["v"][slot.index]
+
+        # scatter each example's K/V row into its own cache offset
+        def upd(cache_l, kv):
+            def one(c, kv_b, p):
+                return jax.lax.dynamic_update_slice(c, kv_b, (0, p, 0))
+            return jax.vmap(one)(cache_l, kv, self.start_pos)
+
+        with jax.named_scope("kv_write"):
+            self.k.append(upd(k_layer, k.astype(cache["k"].dtype)))
+            self.v.append(upd(v_layer, v.astype(cache["v"].dtype)))
+        self.fresh_rows[slot.index] = (k, v)
+
+    def attend(self, slot: Slot, q, scale):
+        """Per-example visibility: key pos <= query pos. GQA grouped-K/V form:
+        query heads fold into (kv_head, group) so the cache is read as-is,
+        never repeated up to n_heads (the repeat would materialize group x
+        cache bytes every step at exactly the scale GQA exists for)."""
+        if self.flash:
+            # the tokens at hand are all there is: no score block over the
+            # cache's length, a window layer's blocks skipped
+            k, v = self.fresh_rows[slot.index]
+            return attention(q, k, v, causal=True, window=slot.window,
+                             sm_scale=scale)
+        k_cache, v_cache = self.k[slot.index], self.v[slot.index]
+        b, _, s_len, d = q.shape
+        heads = k_cache.shape[1]
+        # dots read the caches in their stored dtype: upcasting K/V to f32 here
+        # doubled the HBM bytes of the cache read EVERY decode step — the read
+        # that dominates decode. Scores/softmax still accumulate f32 via
+        # preferred_element_type (the flash-kernel recipe).
+        qg = q.reshape(b, heads, q.shape[1] // heads, s_len, d)
+        s = jnp.einsum("bkgqd,bkld->bkgql", qg, k_cache,
+                       preferred_element_type=jnp.float32)
+        s = s / math.sqrt(d) if scale is None else s * scale
+        k_pos = jnp.arange(k_cache.shape[2])
+        positions = self.positions
+        mask = k_pos[None, None, :] <= positions[:, :, None]  # (B, S, max_len)
+        if slot.window:
+            mask &= positions[:, :, None] - k_pos[None, None, :] < slot.window
+        s = jnp.where(mask[:, None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bkgql,bkld->bkgqd", p.astype(v_cache.dtype), v_cache,
+                          preferred_element_type=jnp.float32)
+
+    def latent_layer(self, attn, a, x, slot: Slot):
+        """A latent layer's attention half: the ONE row a token written at
+        each example's ``start_pos``; then ``fresh`` (the cache holds nothing
+        else) attends among the tokens at hand in the expanded form, through
+        ``ops.attention.attention``; otherwise the absorbed form reads the
+        layer's whole length."""
+        cfg = self.cfg
+        with jax.named_scope("attn"):
+            rows_layer = self.cache["k"][slot.index]             # (B, 1, L, W)
+            q_n, q_r, rows = latent_project(attn, a, self.positions, cfg)
+            with jax.named_scope("kv_write"):
+                rows_layer = jax.vmap(lambda c, new, p: jax.lax.dynamic_update_slice(
+                    c, new[None], (0, p, 0))
+                )(rows_layer, rows.astype(rows_layer.dtype), self.start_pos)
+            self.k.append(rows_layer)
+            if self.fresh:
+                out = expanded_attention(attn, q_n, q_r, rows, cfg)
             else:
-                with jax.named_scope("kv_read"):
-                    k_layer, v_layer = cache["k"][li], cache["v"][li]
+                out = dense_absorbed_attention(
+                    absorbed_query(attn, q_n, q_r, cfg), rows_layer[:, 0],
+                    self.positions, cfg)
+                out = absorbed_output(attn, out, cfg, a.dtype)
+        return x + out
 
-                # scatter each example's K/V row into its own cache offset
-                def upd(cache_l, kv):
-                    def one(c, kv_b, p):
-                        return jax.lax.dynamic_update_slice(c, kv_b, (0, p, 0))
-                    return jax.vmap(one)(cache_l, kv, start_pos)
-
-                with jax.named_scope("kv_write"):
-                    k_cache = upd(k_layer, k.astype(cache["k"].dtype))
-                    v_cache = upd(v_layer, v.astype(cache["v"].dtype))
-                    new_k.append(k_cache)
-                    new_v.append(v_cache)
-                fresh_rows[li] = (k, v)
-
-            # per-example visibility: key pos <= query pos. GQA grouped-K/V form:
-            # query heads fold into (kv_head, group) so the cache is read as-is,
-            # never repeated up to n_heads (the repeat would materialize
-            # group x cache bytes every step at exactly the scale GQA exists for)
-            with jax.named_scope("attn"), _kind_scope(
-                    windowed, "cross" if shared else
-                    "window" if window else "global"):
-                d = q.shape[-1]
-                if windowed and fresh:
-                    # the tokens at hand are all there is: no score block over
-                    # the cache's length, a window layer's blocks skipped
-                    out = attention(q, k, v, causal=True, window=window,
-                                    sm_scale=scale)
-                else:
-                    heads = k_cache.shape[1]
-                    group = q.shape[1] // heads
-                    # dots read the caches in their stored dtype: upcasting K/V
-                    # to f32 here doubled the HBM bytes of the cache read EVERY
-                    # decode step — the read that dominates decode.
-                    # Scores/softmax still accumulate f32 via
-                    # preferred_element_type (the flash-kernel recipe).
-                    qg = q.reshape(b, heads, group, s_len, d)
-                    s = jnp.einsum(
-                        "bkgqd,bkld->bkgql", qg, k_cache,
-                        preferred_element_type=jnp.float32,
-                    )
-                    s = s / math.sqrt(d) if scale is None else s * scale
-                    k_pos = jnp.arange(k_cache.shape[2])
-                    mask = k_pos[None, None, :] <= positions[:, :, None]  # (B, S, max_len)
-                    if window:
-                        mask &= (positions[:, :, None] - k_pos[None, None, :]
-                                 < window)
-                    s = jnp.where(mask[:, None, None], s, -1e30)
-                    p = jax.nn.softmax(s, axis=-1)
-                    out = jnp.einsum(
-                        "bkgql,bkld->bkgqd", p.astype(v_cache.dtype), v_cache,
-                        preferred_element_type=jnp.float32,
-                    )
-                if differential:
-                    x = x + diff_finish(
-                        attn, diff_outputs(out.reshape(b, n_heads, s_len, d)),
-                        depth, dtype)
-                else:
-                    out = out.reshape(b, n_heads, s_len, d).astype(x.dtype)
-                    # heads x head width: the hidden size for most models
-                    out = out.transpose(0, 2, 1, 3).reshape(b, s_len, -1)
-                    x = x + out @ attn["wo"]
-            x = x + _ffn_block(layer, x, cfg, dtype)
-    if logits_at is not None:
-        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
-    logits = _output_logits(params, x, dtype, eps)
-    with jax.named_scope("kv_write"):
-        new_cache = {"k": jnp.stack(new_k)}
-        if new_v:
-            new_cache["v"] = jnp.stack(new_v)
-    if new_lane:
-        new_cache["lane"] = jax.tree_util.tree_map(
-            lambda old, *parts: jnp.stack(parts).astype(old.dtype),
-            cache["lane"], *new_lane)
-    return logits, new_cache
+    def cache_after(self) -> dict:
+        with jax.named_scope("kv_write"):
+            new_cache = {"k": jnp.stack(self.k)}
+            if self.v:
+                new_cache["v"] = jnp.stack(self.v)
+        if self.lane:
+            new_cache["lane"] = jax.tree_util.tree_map(
+                lambda old, *parts: jnp.stack(parts).astype(old.dtype),
+                self.cache["lane"], *self.lane)
+        return new_cache
 
 
 def _rope_per_example(x, positions, theta, rope=(None, 1.0)):
