@@ -504,3 +504,106 @@ def test_insert_compiled_for_v5e_holds_no_arena_sized_copy(case, bucket):
     assert large and set(large) <= writes, (case, bucket, large)
     assert json.loads(m.group(3)) == ["{4,3,2,1,0"], found[str(bucket)]
     assert int(m.group(1)) < layer_bytes // 4, (found[str(bucket)], layer_bytes)
+
+
+# -- a lane state of two parts, 2.2 MB a layer a lane, compiled for the same v5e --
+
+def _compile_lane_state_for_v5e_main():
+    """Child-process body of the test below: the decode chunk and
+    ``_lane_insert_jit`` of a model with Olmo-Hybrid-7B's linear-attention
+    layers (6 of 8 layers as the benchmark runs it, 30 heads of 96 x 192: the
+    state is two arrays, ``(6, 16, 96, 5760)`` float32 = 212 MB and ``(6, 16,
+    3, 11520)`` bf16) at
+    16 lanes, compiled for a described v5e. The hidden size, the MLP and the
+    vocabulary are small: they are not what is compiled here. Prints what it
+    found, or NO_TOPOLOGY."""
+    from tfservingcache_tpu.models.registry import static_config
+
+    one = _described_v5e()
+    if one is None:
+        return
+    jax.default_backend = lambda: "tpu"
+    family, lanes, pt, n_pages = "olmo_hybrid_lm", 16, 16, 1025
+    md = build(family, {
+        "vocab_size": 4096, "d_model": 512, "n_layers": 8,
+        "layer_types": ["linear_attention"] * 3 + ["full_attention"]
+        + ["linear_attention"] * 3 + ["full_attention"],
+        "n_heads": 4, "n_kv_heads": 4, "d_ff": 1024, "linear_heads": 30,
+        "linear_key_dim": 96, "linear_value_dim": 192, "linear_conv": 4,
+        "max_seq": 2048, "dtype": "bfloat16"})
+    cfg = dict(static_config(md))
+    s = jax.ShapeDtypeStruct
+    on = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: s(a.shape, a.dtype, sharding=one), tree)
+    cache = jax.eval_shape(lambda: generation.init_paged_cache(
+        cfg, n_pages, pt, row=md.cache_row, lanes=lanes))
+    state = jax.eval_shape(lambda: generation.init_lane_state(cfg, lanes))
+    params = jax.tree_util.tree_map(
+        lambda a: s(a.shape, jnp.bfloat16),
+        jax.eval_shape(md.init, jax.random.PRNGKey(0)))
+    lane = s((lanes,), jnp.int32)
+    args = on((params, cache["k"], cache["v"], None,
+               s((lanes, cfg["max_seq"] // pt), jnp.int32), lane, lane,
+               s((lanes,), jnp.bool_), s((), jnp.uint32),
+               s((lanes,), jnp.float32), lane, state, None))
+    compiled = generation._paged_decode_chunk_jit.lower(
+        *args, cfg_key=static_config(md), family=family, chunk=8,
+        page_tokens=pt, kernel=True).compile()
+    hlo = compiled.as_text()
+    whole = state[0].size
+    print("CHUNK temp", compiled.memory_analysis().temp_size_in_bytes,
+          "kernel", int("paged_decode_attention_kernel" in hlo),
+          "large", json.dumps(_large_results(hlo, whole)),
+          "layouts", json.dumps(_layouts(hlo, state[:1])))
+    new = jax.tree_util.tree_map(
+        lambda a: s((a.shape[0], 1, *a.shape[2:]), a.dtype), state)
+    compiled = generation._lane_insert_jit.lower(
+        *on((state, new, s((), jnp.int32)))).compile()
+    hlo = compiled.as_text()
+    print("INSERT temp", compiled.memory_analysis().temp_size_in_bytes,
+          "large", json.dumps(_large_results(hlo, whole)),
+          "layouts", json.dumps(_layouts(hlo, state[:1])))
+    print("STATE_BYTES", whole * 4, "LAYER_BYTES", whole * 4 // state[0].shape[0])
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_lane_state():
+    return _child("_compile_lane_state_for_v5e_main()")
+
+
+@pytest.mark.parametrize("program", ["CHUNK", "INSERT"])
+def test_lane_state_of_matrix_states_compiled_for_v5e_is_held_in_place(program):
+    """A lane state of Olmo-Hybrid-7B's size (two parts; the float32 part 212
+    MB at 16 lanes, where phi4's is 0.10 GB and LFM2's 2.9 MB in all) rides
+    donated through the decode chunk and through ``_lane_insert_jit`` and is
+    written where it lies: every result as large as the state's float32 array
+    is the in-place write's (in the chunk a ``dynamic-update-slice`` a lane
+    of a trip a linear layer, ``ops.delta_rule.delta_step_live`` putting a
+    live lane's slice back; in the insert a lane's slice set) and nothing
+    else: no ``copy``, no ``transpose``, no layout conversion, no gather or
+    scatter that the compiler serves through a copy of the array, no layer's
+    slice set whole; the array is row-major wherever its shape appears; the
+    chunk's temporaries, the whole program's, are under two layers' slices
+    of the state and the insert's are next to nothing. Skipped where libtpu
+    cannot describe the topology."""
+    out, err = _compiled_lane_state()
+    if "NO_TOPOLOGY" in out:
+        pytest.skip("libtpu compile-only topology unavailable: "
+                    + out.strip()[-300:])
+    m = re.search(program + r" temp (\d+) (?:kernel (\d) )?large (.*) layouts (.*)", out)
+    sizes = re.search(r"STATE_BYTES (\d+) LAYER_BYTES (\d+)", out)
+    assert m and sizes, (out[-3000:], err[-3000:])
+    state_bytes, layer_bytes = int(sizes.group(1)), int(sizes.group(2))
+    assert state_bytes == 6 * 16 * 96 * 5760 * 4
+    large = json.loads(m.group(3))
+    assert json.loads(m.group(4)) == ["{3,2,1,0"], m.group(0)
+    if program == "CHUNK":
+        # a trip's four lanes put back, a dynamic update a lane a linear layer
+        assert large == {"dynamic-update-slice": 24,
+                         "fusion:dynamic-update-slice": 24}, large
+        assert m.group(2) == "1", "the paged kernel was not traced"
+        assert int(m.group(1)) < 2 * layer_bytes, m.group(0)
+    else:
+        assert large and set(large) <= {
+            "dynamic-update-slice", "fusion:dynamic-update-slice"}, large
+        assert int(m.group(1)) < layer_bytes // 16, m.group(0)

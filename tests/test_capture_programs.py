@@ -363,21 +363,23 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
         bench_json = json.load(f)
     # PR 35's five, then PR 36's count of what a chunk's launch uploaded; PR 39
     # appended its window layers' five after them and its cell to their lists,
-    # PR 40 the share of chunks launched ahead, PR 41 its five and its cell
-    tail = bench_json["per_layer"][-17:-11]
+    # PR 40 the share of chunks launched ahead, PR 41 its five and its cell,
+    # PR 46 its four and its cell
+    tail = bench_json["per_layer"][-21:-15]
     assert [m["name"] for m in tail] == NEW + ["chunk_uploads_mean"]
     assert tail[-1]["unit"] == "operands" and tail[-1]["source"] == "program_counter"
-    assert [m["name"] for m in bench_json["per_layer"][-11:]] == [
+    assert [m["name"] for m in bench_json["per_layer"][-15:]] == [
         "window_decode_ms_per_call", "window_decode_roofline",
         "window_prefill_roofline", "window_pages_read_mean",
         "global_decode_roofline", "chunks_ahead_share",
         "ssm_layers_ms_per_step", "gmu_layers_ms_per_step",
         "ssm_prefill_ms_per_ktok", "shared_kv_decode_roofline",
-        "shared_pages_read_mean"]
+        "shared_pages_read_mean", "gdn_layers_ms_per_step",
+        "gdn_prefill_ms_per_ktok", "gdn_step_roofline", "state_write_lanes_mean"]
     cells = ["mistral7b-chat-steady", "olmoe-chat-steady", "mistral4-docqa-steady",
              "lfm2-longgen-steady", "mellum2-codectx-mixed",
-             "phi4flash-reasoning-steady"]
-    layers = {m["layer"] for m in bench_json["per_layer"][:-17]}
+             "phi4flash-reasoning-steady", "olmohybrid-longdoc-steady"]
+    layers = {m["layer"] for m in bench_json["per_layer"][:-21]}
     for m in tail:
         assert m["workloads"] == cells and m["moves"] == "tpot_p50_ms"
         assert m["better"] == "lower" and m["layer"] in layers

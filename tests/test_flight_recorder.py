@@ -700,10 +700,11 @@ def test_record_with_split_fields_under_50us():
 def test_launch_ms_is_the_last_ring_field():
     """Appended, never inserted: the 23 older names keep their positions
     (``uploads``, ISSUE 36, ``window_pages``, ISSUE 39, ``ahead``, ISSUE 40,
-    and ``shared_pages``, ISSUE 41, came after it the same way)."""
+    ``shared_pages``, ISSUE 41, and ``state_lanes``, ISSUE 46, came after it
+    the same way)."""
     assert STEP_FIELDS[23:] == ("launch_ms", "uploads", "window_pages", "ahead",
-                                "shared_pages")
-    assert len(STEP_FIELDS) == 28
+                                "shared_pages", "state_lanes")
+    assert len(STEP_FIELDS) == 29
     assert STEP_FIELDS[16:19] == ("prefill_ms", "chunk_ms", "emit_ms")
     assert STEP_FIELDS[19:23] == ("experts_hit", "expert_rows_max",
                                   "expert_rows_local", "write_lanes")

@@ -5,7 +5,8 @@ with its one-sided latent row; hybrid_lm, whose convolution layers keep a
 fixed state a request beside the rows its attention layers keep; sambay_lm,
 whose Mamba layers keep a two-part state a scan carries, whose cross-attention
 layers read ONE full-attention layer's rows and whose gated memory units keep
-nothing).
+nothing; olmo_hybrid_lm, whose linear-attention layers keep a float32 matrix
+state a head, whose block norms what a mixer gives and which has no rotary).
 
 What a layer keeps is the ModelDef's to say (``registry.static_config`` puts
 ``cache_row`` and, for a model of several kinds, ``layer_state`` into the
@@ -97,6 +98,7 @@ from tfservingcache_tpu.ops.attention import (
     paged_window_attention,
     unpack_pages,
 )
+from tfservingcache_tpu.ops.delta_rule import STEP_GROUP
 
 # The slot-decode jits donate their K/V buffers (in-place update on TPU);
 # CPU/interpreter backends cannot honor donation and warn on EVERY dispatch
@@ -433,6 +435,26 @@ def _with_prefix(cache: dict, cached_k, cached_v) -> dict:
     }
 
 
+# The dense prefill's score block, ``(B, heads, S, max_len)`` float32, from
+# which a fresh forward attends among the tokens at hand instead (a GiB: 32
+# heads at a 2048 bucket build half of it; 30 heads at 16384 would build 32 GB).
+_SCORE_BLOCK_BYTES = 1 << 30
+
+
+def _attends_tokens_at_hand(cfg, cache, s_len: int) -> bool:
+    """Whether a FRESH forward of ``s_len`` tokens into ``cache`` attends among
+    the tokens at hand through ``ops.attention.attention`` (the flash kernel
+    where its gate admits) and projects ONE position through the head: a model
+    with window layers always; a K/V model without them where the score block
+    over the cache's length would reach ``_SCORE_BLOCK_BYTES``. Below that the
+    programs are the ones they were."""
+    if _window_of(cfg):
+        return True
+    _, batch, _, max_len, _ = cache["k"].shape
+    return (batch * int(cfg["n_heads"]) * s_len * max_len * 4
+            >= _SCORE_BLOCK_BYTES)
+
+
 def _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family):
     """The forward of whole (right-padded) prompts into a fresh cache, the
     start_pos = 0 case of ``_forward_cached_dyn`` -> (the last REAL prompt
@@ -441,9 +463,12 @@ def _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family):
     the one AT ``prompt_len``, which no pad token has touched. A latent family
     projects that one position through the head and no other (a long
     prompt's ``S_pad x V`` float32 logits are a gigabyte at 8192 x 32768), and
-    so does a model with window layers (3.2 GB at 8192 x 98304)."""
+    so does a model with window layers (3.2 GB at 8192 x 98304) and any
+    forward that attends among the tokens at hand (``_attends_tokens_at_hand``:
+    6.6 GB at 16384 x 100352)."""
     b = input_ids.shape[0]
-    one = _cache_row(cfg).sides == 1 or bool(_window_of(cfg))
+    one = _cache_row(cfg).sides == 1 or _attends_tokens_at_hand(
+        cfg, cache, input_ids.shape[1])
     logits, cache = _forward_cached_dyn(
         params, input_ids, cache, jnp.zeros((b,), jnp.int32), cfg, family,
         fresh=True, logits_at=prompt_len - 1 if one else None,
@@ -746,6 +771,26 @@ def kv_write_lanes(active) -> int:
     return min(active.size, int(_write_trips(int(active.sum()))) * _WRITE_GROUP)
 
 
+def state_write_lanes(active, cfg) -> int:
+    """The lanes whose slice of the LANE STATE a decode step reads and writes,
+    worked out on the host from the ``active`` mirror the chunk is dispatched
+    with (the ring's ``state_lanes``). A model whose lane-state layers bring a
+    ``LaneState.step`` advances the live lanes rounded up to whole trips of
+    that step's loop (``ops.delta_rule.STEP_GROUP`` lanes a trip, as the KV
+    write's); any other sets each layer's slice whole
+    (``_PagedRows.keep_lane_state``: every lane, live or not; an inactive
+    lane's comes back bit for bit, but it is read and written). 0 for a model
+    with no lane-state layer."""
+    kinds = [k for k in _layer_kinds(cfg) if isinstance(k, LaneState)]
+    if not kinds:
+        return 0
+    active = np.asarray(active, bool)
+    if any(k.step is None for k in kinds) or active.size <= STEP_GROUP:
+        return int(active.size)
+    trips = (int(active.sum()) + STEP_GROUP - 1) // STEP_GROUP
+    return min(int(active.size), trips * STEP_GROUP)
+
+
 def window_pages_read(pos, active, chunk: int, window: int,
                       page_tokens: int) -> float:
     """Pages a window layer's decode call reads a live lane, mean over the
@@ -954,6 +999,9 @@ class _PagedRows:
             cache, tables, pos, positions)
         self.t_q, self.cfg, self.page_tokens = t_q, cfg, page_tokens
         self.kernel, self.active, self.live = kernel, active, live
+
+    # one token a lane on a donated carry: a ``LaneState.step`` may update it
+    in_place = True
 
     def keep_lane_state(self, slot: Slot, after) -> None:
         self.cache = {**self.cache, "lane": jax.tree_util.tree_map(
@@ -1432,7 +1480,9 @@ def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
                moe_stats: list | None = None):
     """The second half of a decoder layer (input is the residual stream
     BEFORE its norm; returns the residual delta), chosen by what the layer
-    holds: ``moe`` = the routed expert layer, else the dense SwiGLU ``mlp``.
+    holds: ``moe`` = the routed expert layer, else the dense SwiGLU ``mlp``
+    (normed by ``ln2`` before it, or by ``ln2_post`` after it in a layer that
+    holds that leaf: the Olmo 2/3 family's reordered block).
     ``row_mask`` (one flag a row of ``x`` flattened) marks rows whose answer
     nobody reads: the expert layer routes them nowhere. An expert layer's
     routing stats (``MOE_STATS``) are appended to ``moe_stats`` where the
@@ -1443,9 +1493,11 @@ def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
             moe_stats.append(jnp.stack([stats[name] for name in MOE_STATS]))
         return y
     with jax.named_scope("ffn"):
-        h = _norm(layer, "ln2", x)
+        after = "ln2_post" in layer      # a reordered block: the norm follows
+        h = x if after else _norm(layer, "ln2", x)
         mlp = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"])
-        return (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
+        y = (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
+        return _norm(layer, "ln2_post", y, _norm_eps(cfg)) if after else y
 
 
 def _walk_layers(params, ids, rows, cfg, logits_at=None,
@@ -1458,9 +1510,11 @@ def _walk_layers(params, ids, rows, cfg, logits_at=None,
     ``_forward_cached_dyn``). It also holds the tokens' ``positions``, how
     many of them each lane ``took``, which rows are ``active`` (None = all)
     and the ``cache`` a lane state is read from. A layer with a ``LaneState``
-    or ``NoState`` brings its operator; a layer with rows, its own or another
-    layer's, is ``_attend_rows``. ``logits_at (B,)`` projects that one
-    position of each example through the head."""
+    or ``NoState`` brings its operator (a ``LaneState`` that also brings a
+    ``step`` takes that in a store that is ``in_place``: the paged decode
+    step, on the state arrays where they lie); a layer with rows, its own or
+    another layer's, is ``_attend_rows``. ``logits_at (B,)`` projects that
+    one position of each example through the head."""
     dtype = jnp.dtype(cfg["dtype"])
     handed: dict = {}     # what a layer's operator hands on to later layers
 
@@ -1469,7 +1523,15 @@ def _walk_layers(params, ids, rows, cfg, logits_at=None,
     for depth, (layer, kind, slot) in enumerate(
             zip(params["layers"], _layer_kinds(cfg), _layer_slots(cfg))):
         with jax.named_scope("layer"):
-            if slot.lane:
+            if slot.lane and rows.in_place and kind.step is not None:
+                # the state arrays where they lie: the live lanes' slices
+                out, lane, extras = kind.step(
+                    layer, x, rows.cache["lane"], slot.index, rows.took,
+                    rows.live, cfg)
+                rows.cache = {**rows.cache, "lane": lane}
+                handed.update(extras or {})
+                x = x + out
+            elif slot.lane:
                 out, after, extras = _lane_layer(
                     layer, x, _lane_slice(rows.cache["lane"], slot.index),
                     rows.took, kind, cfg)
@@ -1489,17 +1551,21 @@ def _walk_layers(params, ids, rows, cfg, logits_at=None,
 
 def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
     """The attention half of layer ``depth``, a layer with rows -> the residual
-    stream after it. The weights' cast and the norm; then a latent row's
+    stream after it. The weights' cast and the norm (of what the layer takes,
+    ``ln1``, or of what it gives, ``ln1_post``: the leaf it holds says
+    which); then a latent row's
     projection, write and attention are the store's own (``latent_layer``);
     every other layer is projected here (differential, or rotary by the
     layer's kind), its rows written by the store (unless they are another
     layer's, ``SharedRows``: written when that one ran), its queries attended
-    by the store, and the heads finished here."""
+    by the store, and the heads finished here. A config whose ``rope_theta``
+    is None applies no rotary."""
     b, t, _ = x.shape
     shared = isinstance(kind, SharedRows)
     with jax.named_scope("attn"):
         attn = jax.tree_util.tree_map(lambda w: w.astype(x.dtype), layer["attn"])
-        a = _norm(layer, "ln1", x, _norm_eps(cfg))
+        after = "ln1_post" in layer      # a reordered block: the norm follows
+        a = x if after else _norm(layer, "ln1", x, _norm_eps(cfg))
     if _cache_row(cfg).sides == 1:
         return rows.latent_layer(attn, a, x, slot)
     differential = "lam_q1" in attn
@@ -1507,10 +1573,13 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
         if differential:
             q, k, v, scale = _differential_qkv(attn, a, cfg)
         else:
-            q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"])
+            # a QK-norm's own eps only where the model states one
+            eps = (cfg["qk_norm_eps"],) if "qk_norm_eps" in cfg else ()
+            q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"], *eps)
             rope, scale = rope_of(cfg, slot.window), None
-            q = _rope_per_example(q, rows.positions, cfg["rope_theta"], rope)
-            k = _rope_per_example(k, rows.positions, cfg["rope_theta"], rope)
+            if cfg["rope_theta"] is not None:     # None: no rotary at all
+                q = _rope_per_example(q, rows.positions, cfg["rope_theta"], rope)
+                k = _rope_per_example(k, rows.positions, cfg["rope_theta"], rope)
     if not shared:
         rows.write(slot, k, v)
     with jax.named_scope("attn"), _kind_scope(
@@ -1522,7 +1591,9 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
             return x + diff_finish(attn, diff_outputs(out), depth, x.dtype)
         out = out.astype(x.dtype).transpose(0, 2, 1, 3)
         # heads x head width: the hidden size for most models
-        return x + out.reshape(b, t, -1) @ attn["wo"]
+        out = out.reshape(b, t, -1) @ attn["wo"]
+        return x + (_norm(layer, "ln1_post", out, _norm_eps(cfg)) if after
+                    else out)
 
 
 def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
@@ -1544,8 +1615,10 @@ def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
     among the tokens at hand through ``ops.attention.attention`` in EVERY
     layer (the flash kernel where its gate admits, with the window's blocks
     skipped in a window layer), so no ``(S, max_len)`` score block is built: an
-    8192-token prompt's would be 8.6 GB. The other K/V families' programs are
-    the ones they were."""
+    8192-token prompt's would be 8.6 GB. So does the ``fresh`` forward of any
+    other K/V model whose score block would reach a GiB
+    (``_attends_tokens_at_hand``); below that the other K/V families' programs
+    are the ones they were."""
     rows = _DenseRows(cache, start_pos, input_ids.shape[1], cfg, fresh,
                       real_len)
     logits = _walk_layers(params, input_ids, rows, cfg, logits_at=logits_at)
@@ -1562,13 +1635,15 @@ class _DenseRows:
     end (``cache_after``)."""
 
     active = None      # every row's answer is read
+    in_place = False   # a layer's state after the forward is stacked at the end
+    live = None
 
     def __init__(self, cache, start_pos, s_len: int, cfg, fresh: bool,
                  real_len):
         self.positions = start_pos[:, None] + jnp.arange(s_len)[None, :]  # (B, S)
         self.cache, self.start_pos, self.took = cache, start_pos, real_len
         self.cfg, self.fresh = cfg, fresh
-        self.flash = fresh and bool(_window_of(cfg))
+        self.flash = fresh and _attends_tokens_at_hand(cfg, cache, s_len)
         self.k, self.v, self.lane = [], [], []    # after the forward, a layer
         self.fresh_rows: dict = {}   # a row layer's K/V of the tokens at hand
 

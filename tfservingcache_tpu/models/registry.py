@@ -108,7 +108,20 @@ class LaneState:
     stream BEFORE the layer's norm, ``state`` the lane's slice (an array, or
     a tuple a part) and ``extras`` None or a dict the layers after it read
     (``generation._lane_layer`` calls it; a module-level function, so that
-    the declaration stays hashable and equal across builds)."""
+    the declaration stays hashable and equal across builds).
+
+    ``step`` (optional) is the operator's ONE-TOKEN form on the state arrays
+    where they lie, for a state too large to read and write whole every
+    step: ``(layer params, x (S, 1, d), lane, index, took, live, cfg) ->
+    (residual delta, lane after, extras)`` with ``lane`` the model's WHOLE
+    lane-state arrays ``(lane layers, S, rows, width)`` (a tuple a part),
+    ``index`` this layer's, ``took (S,)`` the lanes whose token is real (None
+    = all) and ``live`` ``generation._live_lanes`` of them (or None). It
+    touches the slices of the lanes that took a token and no other, in place
+    on the decode chunk's donated carry. The paged decode step calls it
+    where a declaration brings one (``generation._walk_layers``); every other
+    forward, and a declaration without it, takes ``operator`` on the layer's
+    slice."""
 
     rows: int
     width: int
@@ -118,6 +131,7 @@ class LaneState:
     # parts compare equal (a program's static key also holds the family's name
     # and its whole config, which is what tells two operators apart)
     operator: Callable | None = field(default=None, compare=False)
+    step: Callable | None = field(default=None, compare=False)
 
     def parts(self) -> tuple:
         """The arrays a layer of this kind keeps: ``(rows, width, dtype)``
@@ -334,7 +348,7 @@ def build(family: str, config: dict[str, Any] | None = None) -> ModelDef:
 
 _BUILTIN_MODULES = (
     "half_plus_two", "mnist_cnn", "bert", "resnet", "transformer_lm", "t5", "moe_lm",
-    "mla_moe_lm", "hybrid_lm", "sambay_lm",
+    "mla_moe_lm", "hybrid_lm", "sambay_lm", "olmo_hybrid_lm",
 )
 
 

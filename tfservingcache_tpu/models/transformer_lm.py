@@ -145,7 +145,8 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float,
     return rot.reshape(x.shape).astype(x.dtype)
 
 
-def _qkv(attn: dict, h: jax.Array, n_heads: int, n_kv: int):
+def _qkv(attn: dict, h: jax.Array, n_heads: int, n_kv: int,
+         eps: float = 1e-5):
     """The normed activations ``h (B, S, d)`` -> q ``(B, n_heads, S, hd)`` and
     k, v ``(B, n_kv, S, hd)``, not yet rotated: the one projection of every
     forward (here and the four in models/generation.py). ``q_norm`` /
@@ -153,8 +154,8 @@ def _qkv(attn: dict, h: jax.Array, n_heads: int, n_kv: int):
     RMSNorm with a learned gain over the WHOLE query and key projection,
     before the heads are split. A gain of ONE head's length is the per-head
     form (an RMSNorm over each head's columns, the gain shared by the heads
-    of a side): the leaf's shape says which. A layer without the leaves
-    traces the three products and nothing else."""
+    of a side): the leaf's shape says which; ``eps`` is the QK-norm's. A layer
+    without the leaves traces the three products and nothing else."""
     b, s, _ = h.shape
 
     def proj(w, n, gain=None):
@@ -162,10 +163,10 @@ def _qkv(attn: dict, h: jax.Array, n_heads: int, n_kv: int):
         hd = t.shape[-1] // n
         per_head = gain in attn and attn[gain].shape[-1] == hd
         if gain in attn and not per_head:
-            t = _rmsnorm(t, attn[gain])
+            t = _rmsnorm(t, attn[gain], eps)
         t = t.reshape(b, s, n, hd)
         if per_head:
-            t = _rmsnorm(t, attn[gain])
+            t = _rmsnorm(t, attn[gain], eps)
         return t.transpose(0, 2, 1, 3)
 
     return (proj("wq", n_heads, "q_norm"), proj("wk", n_kv, "k_norm"),

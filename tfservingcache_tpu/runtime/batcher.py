@@ -455,6 +455,7 @@ class _Flight:
     write_lanes: int = 0
     window_pages: float = 0.0
     shared_pages: float = 0.0
+    state_lanes: int = 0
     launch_s: float = 0.0
     uploads: int = 0
     drafted: int = 0
@@ -1232,6 +1233,7 @@ class _ContinuousScheduler:
             launch_s=launch_s, uploads=uploads,
             window_pages=flight.window_pages, ahead=flight.ahead,
             shared_pages=flight.shared_pages,
+            state_lanes=flight.state_lanes,
         )
         self._flight = nxt
         return state
@@ -1335,6 +1337,7 @@ class _ContinuousScheduler:
         from tfservingcache_tpu.models.generation import (
             kv_write_lanes,
             shared_pages_read,
+            state_write_lanes,
             window_pages_read,
         )
 
@@ -1351,6 +1354,9 @@ class _ContinuousScheduler:
         shared_pages = shared_pages_read(
             pos, state.active, chunk, readers, state.page_tokens
         ) if readers else 0.0
+        # the lanes whose lane state a step will read and write (0 with none)
+        state_lanes = state_write_lanes(state.active, dict(state.cfg_key)) \
+            if getattr(state, "lane_state", None) is not None else 0
         t0 = time.monotonic()
         handle = toks = None
         if hasattr(rt, "slot_decode_chunk_launch"):
@@ -1361,6 +1367,7 @@ class _ContinuousScheduler:
             chunk=chunk, reqs=list(lanes), handle=handle, toks=toks,
             ahead=ahead, path=path, write_lanes=write_lanes,
             window_pages=window_pages, shared_pages=shared_pages,
+            state_lanes=state_lanes,
             # the launch path ended at ``launched_t`` (a runtime that keeps
             # no such clock leaves an older time there and records 0)
             launch_s=max(0.0, getattr(state, "launched_t", 0.0) - t0),
@@ -1433,7 +1440,7 @@ class _ContinuousScheduler:
         prefix_hits=0, prefill_s=0.0, tokens_in=0,
         drafted=0, accepted=0, emitted=None, chunk_s=0.0, emit_s=0.0,
         write_lanes=0, launch_s=0.0, uploads=0, window_pages=0.0, ahead=0,
-        shared_pages=0.0,
+        shared_pages=0.0, state_lanes=0,
     ) -> None:
         """One flight-recorder ring entry per chunk boundary (``step_ms``
         split into the prefill clocks ``_step`` already keeps, the decode
@@ -1501,7 +1508,7 @@ class _ContinuousScheduler:
             expert_rows_local=moe_stats[2], write_lanes=write_lanes,
             launch_ms=launch_s * 1e3, uploads=uploads,
             window_pages=window_pages, ahead=ahead,
-            shared_pages=shared_pages,
+            shared_pages=shared_pages, state_lanes=state_lanes,
         )
 
     def _retire_pages(self, state, idx: int, req: _ContinuousReq) -> None:

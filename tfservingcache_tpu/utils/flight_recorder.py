@@ -140,6 +140,12 @@ STEP_FIELDS = (
     # which every layer reads its own rows, for a spec round and for a
     # boundary that ran no chunk
     "shared_pages",
+    # appended field (ISSUE 46 a matrix state a head): the lanes whose slice of
+    # the LANE STATE each step of this chunk read and wrote
+    # (generation.state_write_lanes: every lane of the state, live or not,
+    # while a step updates the state's arrays whole). 0 for a model with no
+    # lane-state layer, for a spec round and for a boundary that ran no chunk
+    "state_lanes",
 )
 
 DEFAULT_RING_ENTRIES = 4096
@@ -152,7 +158,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
     than dict(zip) — snapshot() materializes tail*models of these and is
     budgeted at < 5 ms for 128 tenant rings); short tuples (deserialized
     from dumps older than the newest appended field) fall back to zip."""
-    if len(e) == 28:
+    if len(e) == 29:
         return {
             "t_wall": e[0], "engine": e[1], "step_ms": e[2], "chunk": e[3],
             "active": e[4], "admitted": e[5], "retired": e[6],
@@ -164,7 +170,7 @@ def _step_dict(e: tuple) -> dict[str, Any]:
             "experts_hit": e[19], "expert_rows_max": e[20],
             "expert_rows_local": e[21], "write_lanes": e[22],
             "launch_ms": e[23], "uploads": e[24], "window_pages": e[25],
-            "ahead": e[26], "shared_pages": e[27],
+            "ahead": e[26], "shared_pages": e[27], "state_lanes": e[28],
         }
     return dict(zip(STEP_FIELDS, e))
 
@@ -329,6 +335,7 @@ class FlightRecorder:
         window_pages: float = 0.0,
         ahead: int = 0,
         shared_pages: float = 0.0,
+        state_lanes: int = 0,
     ) -> None:
         self._ring(model).append((
             time.time(), engine, round(step_ms, 4), chunk, active, admitted,
@@ -339,6 +346,7 @@ class FlightRecorder:
             round(experts_hit, 3), round(expert_rows_max, 3),
             round(expert_rows_local, 3), write_lanes, round(launch_ms, 4),
             uploads, round(window_pages, 3), ahead, round(shared_pages, 3),
+            state_lanes,
         ))
 
     def note_phases(

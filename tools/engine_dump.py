@@ -86,6 +86,8 @@ def _fmt_step(s: dict) -> str:
         split += f"window_pages={s['window_pages']:.1f} "
     if s.get("shared_pages"):   # ISSUE 41: pages the calls over a shared layer read
         split += f"shared_pages={s['shared_pages']:.1f} "
+    if s.get("state_lanes"):    # ISSUE 46: the lanes whose lane state a step wrote
+        split += f"state_lanes={s['state_lanes']} "
     if s.get("ahead"):  # ISSUE 40: the chunk fetched here was launched ahead
         split += "ahead=1 "
     return (

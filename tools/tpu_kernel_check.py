@@ -83,6 +83,11 @@ def main() -> int:
     # against two dense float64 softmaxes on the host, and the selective scan
     # (a bucket of 1024, the step over 32 lanes) against the recurrence in
     # float64: largest errors and times, `-k "sambay and on_tpu"`, ~1.5 min.
+    # test_olmo_hybrid_lm.py carries the gated delta rule's rows at
+    # Olmo-Hybrid-7B's widths (30 heads of 96 / 192, bf16 operands, float32
+    # state): ``delta_step`` over 16 lanes and ``delta_chunked`` over a bucket
+    # of 2048 (``real_len`` 1500) against the step iterated: largest errors
+    # of the output and of the state, `-k "delta_rule_on_tpu"`, ~1 min.
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
@@ -91,6 +96,7 @@ def main() -> int:
         os.path.join(REPO, "tests", "test_mla_moe.py"),
         os.path.join(REPO, "tests", "test_window_layers.py"),
         os.path.join(REPO, "tests", "test_sambay_lm.py"),
+        os.path.join(REPO, "tests", "test_olmo_hybrid_lm.py"),
         "-v", "-rs", "-s", "--no-header",
         *extra,
     ]
