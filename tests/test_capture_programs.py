@@ -364,22 +364,30 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     # PR 35's five, then PR 36's count of what a chunk's launch uploaded; PR 39
     # appended its window layers' five after them and its cell to their lists,
     # PR 40 the share of chunks launched ahead, PR 41 its five and its cell,
-    # PR 46 its four and its cell
-    tail = bench_json["per_layer"][-21:-15]
+    # PR 46 its four and its cell, PR 47 the chunked rule's kernel's share
+    tail = bench_json["per_layer"][-22:-16]
     assert [m["name"] for m in tail] == NEW + ["chunk_uploads_mean"]
     assert tail[-1]["unit"] == "operands" and tail[-1]["source"] == "program_counter"
-    assert [m["name"] for m in bench_json["per_layer"][-15:]] == [
+    assert [m["name"] for m in bench_json["per_layer"][-16:]] == [
         "window_decode_ms_per_call", "window_decode_roofline",
         "window_prefill_roofline", "window_pages_read_mean",
         "global_decode_roofline", "chunks_ahead_share",
         "ssm_layers_ms_per_step", "gmu_layers_ms_per_step",
         "ssm_prefill_ms_per_ktok", "shared_kv_decode_roofline",
         "shared_pages_read_mean", "gdn_layers_ms_per_step",
-        "gdn_prefill_ms_per_ktok", "gdn_step_roofline", "state_write_lanes_mean"]
+        "gdn_prefill_ms_per_ktok", "gdn_step_roofline", "state_write_lanes_mean",
+        "gdn_chunk_roofline"]
+    last = bench_json["per_layer"][-1]
+    assert last["workloads"] == ["olmohybrid-longdoc-steady"]
+    assert (last["unit"], last["better"], last["source"]) == (
+        "%", "higher", "device_trace")
+    assert last["moves"] == "tpot_p50_ms"
+    assert os.path.exists(os.path.join(BENCH, "layer_metrics", last["name"] + ".py"))
     cells = ["mistral7b-chat-steady", "olmoe-chat-steady", "mistral4-docqa-steady",
              "lfm2-longgen-steady", "mellum2-codectx-mixed",
              "phi4flash-reasoning-steady", "olmohybrid-longdoc-steady"]
-    layers = {m["layer"] for m in bench_json["per_layer"][:-21]}
+    layers = {m["layer"] for m in bench_json["per_layer"][:-22]}
+    assert last["layer"] in layers
     for m in tail:
         assert m["workloads"] == cells and m["moves"] == "tpot_p50_ms"
         assert m["better"] == "lower" and m["layer"] in layers

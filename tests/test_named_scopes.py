@@ -74,6 +74,13 @@ FAMILIES = {
          "layer/ffn"},
         WINDOWED_PAGED | {"layer/attn/cross/kv_read", "layer/ssm/step"},
         KV_DENSE | {"layer/ssm/scan"}),
+    "olmo_hybrid_lm": (
+        "olmo-hybrid-7b",
+        {"layer/attn", "layer/gdn", "layer/gdn/proj", "layer/gdn/conv",
+         "layer/gdn/gate", "layer/kv_write", "layer/ffn"},
+        # the delta rule's one-token step in the decode chunk, its chunked
+        # form over the prompt's bucket in the prefill
+        KV_PAGED | {"layer/gdn/step"}, KV_DENSE | {"layer/gdn/chunk"}),
 }
 # every path a case names: a program must carry its own and none of the others
 VOCABULARY = set().union(*(both | paged | dense
@@ -200,3 +207,62 @@ def test_insert_and_predict_programs_are_named(model):
         params, {"input_ids": np.zeros((1, 8), np.int32)}))
     for path in ("embed", "layer/attn", "layer/ffn", "lm_head"):
         assert has(path), path
+
+
+# -- the chunked delta rule's kernel in the prefill it serves (PR 47) ----------
+
+def test_olmo_hybrid_prefill_for_the_chip_holds_the_rule_as_one_kernel(monkeypatch):
+    """The cell's slot prefill at the published widths (8 layers, six of them
+    linear; abstract weights) lowered FOR the TPU: every linear layer's
+    chunked rule is ONE ``delta_chunk_kernel`` under ``layer/gdn/chunk``
+    (``gdn_prefill_ms_per_ktok`` reads its time by that scope,
+    ``gdn_chunk_roofline`` by the kernel's name), and nothing of the block
+    form it replaced is left under that scope: no loop over chunks, no
+    triangular solve, no transpose of anything as large as a lane's state."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(BENCHMARK, "configs", "olmo-hybrid-7b.json")) as f:
+        config = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "scopes_family_olmo_hybrid_chip",
+        os.path.join(BENCHMARK, "families", f"{config['family']}.py"))
+    family = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(family)
+    mdef = registry.build(family.PROGRAM_FAMILY, family.program_config(config))
+    cfg = mdef.config
+    linear = list(cfg["layer_types"]).count("linear_attention")
+    assert linear == 6 and cfg["n_layers"] == 8
+    state = cfg["linear_key_dim"] * cfg["linear_heads"] * cfg["linear_value_dim"]
+    params = jax.eval_shape(mdef.init, jax.random.PRNGKey(0))
+    sds = jax.ShapeDtypeStruct
+    lowered = generation._slot_prefill_jit.trace(
+        params, sds((1, 1024), jnp.int32), sds((1,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(2)), sds((), jnp.float32),
+        sds((), jnp.int32), cfg_key=registry.static_config(mdef),
+        family=mdef.family).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+
+    def scope_of(line):
+        return names.get((re.findall(r"loc\((#loc\d+)\)\s*$", line) or [""])[0], "")
+
+    # the kernel's call is a jit of its own (one trace and one lowering of its
+    # body a program): every linear layer calls it under the scope, and the
+    # function it calls holds the one custom call
+    lines = text.splitlines()
+    under = [ln for ln in lines if "layer/gdn/chunk" in scope_of(ln)]
+    calls = [ln for ln in under if "call @delta_chunk_kernel" in ln]
+    assert len(calls) == linear, len(calls)
+    assert all(scope_of(ln).endswith("layer/gdn/chunk/jit(delta_chunk_kernel)")
+               for ln in calls)
+    first = next(i for i, ln in enumerate(lines)
+                 if "func.func private @delta_chunk_kernel" in ln)
+    callee = lines[first:next(i for i in range(first, len(lines))
+                              if lines[i].startswith("  }"))]
+    kernels = [ln for ln in callee if "tpu_custom_call" in ln]
+    assert len(kernels) == 1 and 'kernel_name = "delta_chunk_kernel"' in kernels[0]
+    assert not [ln for ln in under + callee if "stablehlo.while" in ln]
+    assert "triangular_solve" not in text and "triangular-solve" not in text
+    for ln in under + callee:
+        if "stablehlo.transpose" in ln:
+            shape = re.findall(r"tensor<([0-9x]+)x[a-z]", ln)[-1]
+            assert np.prod([int(d) for d in shape.split("x")]) < state, ln

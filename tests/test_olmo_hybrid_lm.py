@@ -748,17 +748,24 @@ ON_TPU = pytest.mark.skipif(
 
 
 @ON_TPU
-@pytest.mark.parametrize("form", ["step", "chunked"])
+@pytest.mark.parametrize("form", ["step", "chunked", "chunked_16384"])
 def test_delta_rule_on_tpu(form):
     """Both forms at the benchmark's widths (30 heads of 96 / 192, bf16
     operands) against the step iterated in float32 on the host's arithmetic:
     ``delta_step`` over 16 lanes for 8 tokens and ``delta_step_live`` on a
-    two-layer array against it, ``delta_chunked`` over 2048 tokens of one lane
-    (``real_len`` 1500)."""
+    two-layer array against it; ``delta_chunked`` over one lane at a bucket of
+    2048 (``real_len`` 1500) and of 16384 (``real_len`` 11800), as the chip
+    takes it (``delta_chunk_kernel``, PR 47) AND in the block form it replaced
+    there (the gate forced shut): the kernel's errors are held to the block
+    form's, and both times are printed beside each other (``.chip_proof/
+    bench_chunk.py``'s baseline: 3.97 ms at 2048, 17.28 at 8192, PR 46)."""
+    from tfservingcache_tpu.utils.benchtime import chained_device_time
+
     h, d_k, d_v = 30, 96, 192
     rng = np.random.default_rng(11)
-    b, t = (16, 8) if form == "step" else (1, 2048)
-    real = np.full((b,), t, np.int32) if form == "step" else np.asarray([1500], np.int32)
+    b, t, n_real = {"step": (16, 8, 8), "chunked": (1, 2048, 1500),
+                    "chunked_16384": (1, 16384, 11800)}[form]
+    real = np.full((b,), n_real, np.int32)
     q, k = (rng.standard_normal((b, t, h, d_k)).astype(np.float32) for _ in "qk")
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(d_k)
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
@@ -771,13 +778,20 @@ def test_delta_rule_on_tpu(form):
 
     def iterated():
         s, outs = s0, []
-        for i in range(t):
+        for i in range(n_real):
             o, s = step(s, bf(q[:, i]), bf(k[:, i]), bf(v[:, i]), alpha[:, i],
-                        beta[:, i], jnp.asarray(i < real))
+                        beta[:, i])
             outs.append(o)
         return jnp.stack(outs, 1), s
 
     want_o, want_s = iterated()
+    scale = float(np.std(np.asarray(want_o)[0]))
+
+    def errors(got_o, got_s):
+        return (float(np.max(np.abs(np.asarray(got_o)[0, :n_real]
+                                    - np.asarray(want_o)[0]))),
+                float(np.max(np.abs(np.asarray(got_s) - np.asarray(want_s)))))
+
     if form == "step":
         # the live-lane form on a two-layer array: layer 1 advanced for the
         # lanes that took a token, every other slice bit for bit
@@ -796,14 +810,43 @@ def test_delta_rule_on_tpu(form):
                                    np.asarray(s_want)[live], atol=1e-5, rtol=0)
         np.testing.assert_allclose(np.asarray(o_got)[live],
                                    np.asarray(o_want)[live], atol=1e-5, rtol=0)
-        got_o, got_s = want_o, want_s
-        assert np.all(np.isfinite(np.asarray(got_s)))
-    else:
-        got_o, got_s = jax.jit(delta_rule.delta_chunked)(
-            s0, bf(q), bf(k), bf(v), alpha, beta, jnp.asarray(real))
-    scale = float(np.std(np.asarray(want_o)[0, :real[0]]))
-    err_o = float(np.max(np.abs(np.asarray(got_o)[0, :real[0]]
-                                - np.asarray(want_o)[0, :real[0]])))
-    err_s = float(np.max(np.abs(np.asarray(got_s) - np.asarray(want_s))))
-    print(f"delta_{form}: out err {err_o:.3e} of std {scale:.3e}, state err {err_s:.3e}")
-    assert err_o < 0.05 * scale + 1e-3 and err_s < 0.05
+        assert np.all(np.isfinite(np.asarray(want_s)))
+        print(f"delta_step: out err 0.000e+00 of std {scale:.3e}, state err 0.000e+00")
+        return
+    operands = (s0, bf(q), bf(k), bf(v), jnp.asarray(alpha), jnp.asarray(beta),
+                jnp.asarray(real))
+    kernel = lambda *a: delta_rule.delta_chunked(*a)  # noqa: E731
+
+    def block_form(*a):
+        refusal = delta_rule._kernel_refusal
+        delta_rule._kernel_refusal = lambda *_: "the block form, for the comparison"
+        try:
+            return delta_rule.delta_chunked(*a)
+        finally:
+            delta_rule._kernel_refusal = refusal
+
+    assert delta_rule._kernel_refusal(*operands[:1], *operands[2:4],
+                                      delta_rule.CHUNK) is None
+    found = {}
+    for name, fn in (("kernel", kernel), ("block form", block_form)):
+        err_o, err_s = errors(*jax.jit(fn)(*operands))
+        # every output summed, and the chain carried through ``alpha``: a
+        # chain that read one element of ``o`` would let the compiler drop
+        # most of the block form's work, and one carried through the state
+        # alone lets it lift everything the state does not touch (the decays,
+        # the inverses, the solves) out of the timing loop
+        ms = 1e3 * chained_device_time(
+            lambda alpha, s, q, k, v, beta, real, fn=fn: sum(
+                jnp.sum(x) for x in fn(s, q, k, v, alpha, beta, real)),
+            (operands[4], *operands[:4], *operands[5:]), iters=4)
+        found[name] = (err_o, err_s, ms)
+        print(f"delta_chunked[{t}, real {n_real}] {name}: out err {err_o:.3e} of "
+              f"std {scale:.3e}, state err {err_s:.3e}, {ms:.2f} ms = "
+              f"{ms * 1e3 / t:.2f} us a bucket token a layer", flush=True)
+    (k_o, k_s, k_ms), (b_o, b_s, b_ms) = found["kernel"], found["block form"]
+    assert k_o < 0.05 * scale + 1e-3 and k_s < 0.05
+    # no further from the step iterated than the form it replaced (a tenth
+    # of room: the two sum in different orders)
+    assert k_o <= 1.1 * b_o and k_s <= 1.1 * b_s, found
+    # the pad chunks past real_len are skipped, the state never leaves VMEM
+    assert k_ms < b_ms / 3, found

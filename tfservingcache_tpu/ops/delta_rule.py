@@ -1,5 +1,5 @@
 """The gated delta rule of a linear-attention layer (Gated DeltaNet,
-arXiv:2412.06464), in plain ``jax.numpy``.
+arXiv:2412.06464).
 
 A head keeps a MATRIX state ``S (d_k, d_v)`` in float32. For token ``t`` with
 key ``k_t`` and query ``q_t`` (``d_k``, both already L2-normalised, the query
@@ -13,8 +13,8 @@ strength ``beta_t`` in (0, 2)::
 The state of every head of a lane is ONE array ``(d_k, H x d_v)``, heads side
 by side in the minor dimension (30 heads of 192 are 5760 columns, 45 whole
 128-lane rows, where a head's own 192 would fill one and a half): that is the
-layout ``registry.LaneState`` stores, and both forms below take it in and hand
-it out, which is what lets a request's state live beside the paged arena
+layout ``registry.LaneState`` stores, and every form below takes it in and
+hands it out, which is what lets a request's state live beside the paged arena
 between the programs that advance it.
 
 Two forms of the one recurrence:
@@ -35,11 +35,9 @@ Two forms of the one recurrence:
       (I + A) U = diag(beta) (V - diag(g) K S_0),
       A[t, i] = beta_t (g_t / g_i) (k_t . k_i)   for i < t
 
-  which is solved ONCE a chunk for both right-hand sides, in parallel over
-  the chunks (``W = (I + A)^-1 diag(beta g) K``, ``U_0 = (I + A)^-1
-  diag(beta) V``, the inverse formed by ``_unit_lower_inverse``); what is
-  left for the sequential pass over the chunks is three matrix products a
-  chunk::
+  which is solved ONCE a chunk for both right-hand sides (``W = (I + A)^-1
+  diag(beta g) K``, ``U_0 = (I + A)^-1 diag(beta) V``); what is left is three
+  matrix products a chunk with the state carried from chunk to chunk::
 
       U = U_0 - W S_0
       O = diag(g) Q S_0 + ((Q K^T) * D) U,   D[t, i] = g_t / g_i  for i <= t
@@ -47,14 +45,39 @@ Two forms of the one recurrence:
 
   The state returned is the one after ``real_len`` tokens: past it ``alpha =
   1`` and ``beta = 0`` make a token the identity. ``g_t / g_i`` is formed as
-  ``exp(log g_t - log g_i)`` with ``i <= t`` only, so it never exceeds 1. A
-  prompt longer than ``BLOCK`` tokens is taken a block at a time.
+  ``exp(log g_t - log g_i)`` with ``i <= t`` only, so it never exceeds 1.
+
+Which chunked form runs where (``_kernel_refusal`` decides from the backend
+and the shapes when the program is traced; ``ops.attention.dispatch_tally()``
+records it under ``delta_chunked``):
+
+* on one TPU chip, for bfloat16 operands with heads in pairs, ``d_k`` a
+  multiple of 16, ``d_v`` a multiple of 64 and a lane's state within the
+  kernel's VMEM: ``delta_chunk_kernel``, ONE Pallas kernel a call. The state
+  goes in and comes out in the stored layout and stays in VMEM from a lane's
+  first chunk to its last; a grid step is one chunk of every head, read from
+  the operands as the projection left them (``(T, H x d)``), two heads at a
+  time stacked along the 128 rows of a matrix-unit pass; ``(I + A)^-1`` is
+  formed inside it (``_unit_lower_inverses``); chunks wholly past ``real_len``
+  are skipped, not multiplied as identities. Same mathematics and the same
+  roundings as the block form on that chip: the inverse and the solve at
+  float32's exactness, the products with the state on bfloat16 operands (what
+  the matrix unit makes of a float32 ``dot``'s) into a float32 state.
+* everywhere else (the CPU, another width, float32 operands on the chip):
+  ``_chunked_block`` in plain ``jax.numpy``, the reference the tests hold the
+  kernel to: the inverse by ``_unit_lower_inverse`` for all of a block's
+  chunks at once, a ``lax.scan`` over the chunks, a prompt longer than
+  ``BLOCK`` tokens a block at a time.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from tfservingcache_tpu.ops.attention import _record_dispatch
 
 # Tokens a chunk of ``delta_chunked``: inside one the updates are matrix
 # products, between two the state is carried (the published kernels' size).
@@ -193,9 +216,12 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
     (``o (B, T, H, d_v)`` float32, the state after ``real_len (B,)`` of the
     tokens; None = all ``T``). ``o`` past ``real_len`` is junk nobody reads.
     ``T`` need not be a multiple of ``chunk``: the tail is padded with
-    identity tokens. A prompt of more than ``block`` tokens is taken ``block``
-    tokens at a time, the state carried from one to the next, so that the
-    float32 operands of the triangular systems exist for one block only."""
+    identity tokens. Where ``_kernel_refusal`` has no objection (one TPU chip,
+    the widths the kernel takes) the chunks go through ``delta_chunk_kernel``;
+    elsewhere through ``_chunked_block``, a prompt of more than ``block``
+    tokens ``block`` tokens at a time, the state carried from one to the next,
+    so that the float32 operands of the triangular systems exist for one
+    block only."""
     f32 = jnp.float32
     b, t_len, h, _ = k.shape
     if real_len is not None:
@@ -203,13 +229,27 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
                 < real_len.astype(jnp.int32)[:, None])[..., None]   # (B, T, 1)
         alpha = jnp.where(real, alpha.astype(f32), 1.0)
         beta = jnp.where(real, beta.astype(f32), 0.0)
-    pad = -t_len % (chunk if t_len <= block else block)
+    why = _kernel_refusal(state, k, v, chunk)
+    _record_dispatch(
+        "delta_chunked", "reference" if why else "kernel",
+        why or ("interpret" if DELTA_KERNEL_INTERPRET else "pallas"),
+        state.shape, k.shape, v.shape)
+    pad = -t_len % (chunk if why is None or t_len <= block else block)
     if pad:
         widths = ((0, 0), (0, pad))
         q, k, v = (jnp.pad(a, widths + ((0, 0), (0, 0))) for a in (q, k, v))
         alpha = jnp.pad(alpha, widths + ((0, 0),), constant_values=1.0)
         beta = jnp.pad(beta, widths + ((0, 0),))
     padded = t_len + pad
+    if why is None:
+        log_g = jnp.cumsum(jnp.log(alpha.astype(f32)).reshape(
+            b, padded // chunk, chunk, h), axis=2).reshape(b, padded, h)
+        if real_len is None:
+            real_len = jnp.full((b,), t_len, jnp.int32)
+        o, s = delta_chunk_kernel(
+            state, *(a.reshape(b, padded, -1) for a in (q, k, v)), log_g,
+            beta.astype(f32), real_len, chunk, interpret=bool(DELTA_KERNEL_INTERPRET))
+        return o.reshape(b, padded, h, -1)[:, :t_len], s
     s = _heads(state.astype(f32), h)
     if padded <= block:
         o, s = _chunked_block(s, q, k, v, alpha, beta, chunk)
@@ -320,3 +360,343 @@ def _chunked_block(s, q, k, v, alpha, beta, chunk: int):
     s, o = jax.lax.scan(carry, s, (w, u0, q, qk, k_out, g, g_last))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)               # (B, n, C, H, d_v)
     return o.reshape(b, t_len, h, d_v), s
+
+
+# -- the chunked form as one Pallas kernel --------------------------------------
+
+# Tests flip this to run the kernel through its interpreter on the CPU
+# (trace-time only, like ``ops.moe.MOE_KERNEL_INTERPRET``).
+DELTA_KERNEL_INTERPRET = False
+
+# What the kernel may hold in VMEM: the lane's state in and out, each twice
+# (the pipeline's two buffers), and once more as the kernel keeps it between
+# a lane's first chunk and its last, beside a chunk's operands and its float32
+# temporaries. 2.2 MB of state at 30 heads of 96 x 192 are 11 MB of it.
+VMEM_LIMIT = 64 << 20
+
+
+def _kernel_refusal(state, k, v, chunk: int) -> str | None:
+    """Why ``delta_chunk_kernel`` cannot take these operands on this backend
+    (None = it can): the TPU (one chip: the family binds no mesh), bfloat16
+    operands, heads in pairs whose value columns are whole 128-lane rows (so
+    a pair's slice of the state is cut where the rows are), key widths of
+    whole sublane tiles, a chunk of whole bfloat16 tiles that is a power of
+    two, and the lane's state five times within half the kernel's VMEM."""
+    h, d_k, d_v = k.shape[2], k.shape[3], v.shape[3]
+    if not DELTA_KERNEL_INTERPRET:
+        if jax.default_backend() != "tpu":
+            return f"backend={jax.default_backend()}"
+        if k.dtype != jnp.bfloat16 or v.dtype != jnp.bfloat16:
+            return f"operands {k.dtype} / {v.dtype}, not bfloat16"
+    if h % 2:
+        return f"heads={h} not in pairs"
+    if d_k % 16 or (2 * d_v) % 128:
+        return f"d_k={d_k} no multiple of 16 or d_v={d_v} no multiple of 64"
+    if chunk < 16 or chunk & (chunk - 1):
+        return f"chunk={chunk} not a power of two of at least 16"
+    if 5 * state.shape[1] * state.shape[2] * 4 > VMEM_LIMIT // 2:
+        return f"a lane's state ({d_k} x {h * d_v} float32) past the VMEM budget"
+    return None
+
+
+def _split3(m):
+    """Float32 ``m`` as the sum of three parts that are bfloat16 values (8 +
+    8 + 8 of its 24 bits), each still float32: ``m == m1 + m2 + m3`` with
+    nothing rounded."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    m1 = m.astype(bf16).astype(f32)
+    r1 = m - m1
+    m2 = r1.astype(bf16).astype(f32)
+    return m1, m2, r1 - m2
+
+
+def _exact_dot(m, rhs):
+    """``m @ rhs`` for float32 ``m`` and bfloat16 ``rhs`` with nothing
+    rounded but the float32 sums: ``m``'s three parts stacked along the rows
+    (the matrix unit holds ``rhs`` once), each part's product exact. What
+    HIGHEST gives two float32 operands in six passes, in three, because the
+    second operand IS bfloat16."""
+    rows = m.shape[0]
+    parts = jnp.dot(
+        jnp.concatenate([p.astype(jnp.bfloat16) for p in _split3(m)], axis=0),
+        rhs, preferred_element_type=jnp.float32)
+    return (parts[2 * rows:] + parts[rows:2 * rows]) + parts[:rows]
+
+
+def _unit_lower_inverses(a: list, chunk: int) -> list:
+    """``(I + a)^-1`` for each of the kernel's pair matrices ``a (2 chunk, 2
+    chunk)`` float32, block-diagonal (a head a block) and strictly lower
+    triangular: the 2 x 2 diagonal blocks outright, then neighbours joined as
+    ``_unit_lower_inverse`` joins them, ``[[P, 0], [R, Q]]^-1 = [[P^-1, 0],
+    [-Q^-1 R P^-1, Q^-1]] = X - X N X`` with ``X`` the blocks' inverses and
+    ``N`` the ``R``'s, until a head's block is whole. Both products are
+    HIGHEST's: the six products of the operands' bfloat16 parts that are not
+    below float32's last bit, each exact in the float32 accumulator. They
+    take three passes of the matrix unit, not six: ``N``'s columns are the
+    FIRST halves of the joined blocks and ``N X``'s rows the SECOND halves, so
+    half of either product's inner dimension is empty, and a second part's
+    product rides there, moved over by the blocks' size. Every pair's product
+    is written before the next stage's, so that one pair's runs while
+    another's is awaited (the ten products of a pair are one chain)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    two = 2 * chunk
+    row = jax.lax.broadcasted_iota(jnp.int32, (two, two), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (two, two), 1)
+    roll = pltpu.roll
+
+    def three_passes(l12, l13, r1, r2, r3):
+        """``l1 r1 + l2 r1 + l1 r2 + l2 r2 + l1 r3 + l3 r1`` from operands
+        that hold two parts each, the second moved into the empty half."""
+        dot = functools.partial(jnp.dot, preferred_element_type=f32)
+        l12, l13, r1, r2, r3 = (m.astype(bf16) for m in (l12, l13, r1, r2, r3))
+        return (dot(l13, r3) + dot(l12, r2)) + dot(l12, r1)
+
+    def joined(parts, xs, size: int):
+        top = (row // size) % 2 == 0            # rows of first halves
+        right = (col // size) % 2 == 1          # columns of second halves
+        below = ((row // (2 * size)) == (col // (2 * size))) & ~top & ~right
+        out = []
+        x3 = [_split3(x) for x in xs]
+        # N X: N's parts as they are and moved right by ``size``; X's
+        # first-half rows as they are and copied down by ``size``
+        nx = []
+        for n, (x1, x2, x3_) in zip(parts, x3):
+            n1, n2, n3 = (jnp.where(below, m, 0.0) for m in n)
+            down = roll(x1, size, 0)
+            nx.append(three_passes(
+                n1 + roll(n2, size, 1), n1 + roll(n3, size, 1),
+                jnp.where(top, x1, down), jnp.where(top, x2, roll(x2, size, 0)),
+                jnp.where(top, x3_, down)))
+        # X (N X): X's second-half columns as they are and moved left by
+        # ``size``; N X's rows (all in second halves) as they are and copied up
+        for x, (x1, x2, x3_), t in zip(xs, x3, nx):
+            t1, t2, t3 = _split3(t)
+            up = roll(t1, two - size, 0)
+            out.append(x - three_passes(
+                jnp.where(right, x1, roll(x2, two - size, 1)),
+                jnp.where(right, x1, roll(x3_, two - size, 1)),
+                t1 + up, t2 + roll(t2, two - size, 0), t3 + up))
+        return out
+
+    eye = (row == col).astype(f32)
+    x = [eye - jnp.where((row // 2) == (col // 2), m, 0.0) for m in a]
+    parts = [_split3(m) for m in a]
+    size = 2
+    while size < chunk:
+        x = joined(parts, x, size)
+        size *= 2
+    return x
+
+
+@jax.jit
+def _advance_pairs(k2, q2, v1, lg_r, be_r, lg_c, be_c, s):
+    """One chunk of a few pairs of heads, from values to values; every
+    argument a tuple with an entry a pair: ``k2`` / ``q2 (2 chunk, d_k)`` (the
+    pair's second head's rows under the first's), ``v1 (chunk, 2 d_v)``,
+    ``lg_r`` / ``be_r (1, 2 chunk)`` and ``lg_c`` / ``be_c (2 chunk, 1)`` the
+    running log-decay and the write strength as rows and as columns, ``s (d_k,
+    2 d_v)`` the pair's state -> (``o (chunk, 2 d_v)`` a pair, the states
+    after). A pair's matrices are block-diagonal, a head a block. Every stage
+    is written for all the pairs before the next, so that one pair's products
+    run while another's are awaited. A ``jit`` of its own, so that the
+    kernel's every trace (a prompt bucket, a layer) finds THIS traced once a
+    process."""
+    f32 = jnp.float32
+    # what a product with the state takes is rounded to the operands' own
+    # dtype: bfloat16 in a serving program, which is what the matrix unit
+    # makes of a float32 ``dot``'s operands anyway; float32 operands (the
+    # CPU's tests) keep every product float32
+    rounded = k2[0].dtype
+    chunk, d_v = v1[0].shape[0], v1[0].shape[1] // 2
+    two = 2 * chunk
+    js = range(len(k2))
+    row = jax.lax.broadcasted_iota(jnp.int32, (two, two), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (two, two), 1)
+    same = (row < chunk) == (col < chunk)           # the two diagonal blocks
+    lower, strict = same & (col <= row), same & (col < row)
+    # a pair's first head: the stacked rows' upper half, the value columns'
+    # left half
+    first = jax.lax.broadcasted_iota(jnp.int32, (two, 1), 0) < chunk
+    left = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * d_v), 1) < d_v
+    mine = first == left                # a head's rows over its own columns
+    nt = (((1,), (1,)), ((), ()))
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+    # D[t, i] = g_t / g_i where i <= t in one head, else 0
+    decay = [jnp.exp(jnp.where(lower, lg_c[j] - lg_r[j], -jnp.inf)) for j in js]
+    both = [jax.lax.dot_general(jnp.concatenate([k2[j], q2[j]], axis=0), k2[j],
+                                nt, preferred_element_type=f32)
+            for j in js]                                    # K K^T over Q K^T
+    a = [be_c[j] * jnp.where(strict, decay[j] * both[j][:two], 0.0) for j in js]
+    qk = [(decay[j] * both[j][two:]).astype(rounded) for j in js]
+    x = _unit_lower_inverses(a, chunk)
+    # W = (I + a)^-1 diag(beta g) K and U_0 = (I + a)^-1 diag(beta) V
+    w = [_exact_dot(x[j] * (be_r[j] * jnp.exp(lg_r[j])), k2[j]).astype(rounded)
+         for j in js]
+    u0 = [_exact_dot(x[j] * be_r[j], jnp.concatenate([v1[j], v1[j]], axis=0))
+          for j in js]                                      # (2C, 2 d_v)
+    s_b = [s[j].astype(rounded) for j in js]
+    ws_qs = [dot(jnp.concatenate([w[j], q2[j]], axis=0), s_b[j]) for j in js]
+    u = [jnp.where(mine, u0[j] - ws_qs[j][:two], 0.0).astype(rounded) for j in js]
+    o2 = [jnp.exp(lg_c[j]) * ws_qs[j][two:] + dot(qk[j], u[j]) for j in js]
+    # diag(g_C / g) K and g_C, each head's own last token
+    ends = [(lg_r[j][:, chunk - 1:chunk], lg_r[j][:, two - 1:two]) for j in js]
+    k_out = [(jnp.exp(jnp.where(first, *ends[j]) - lg_c[j])
+              * k2[j].astype(f32)).astype(rounded) for j in js]
+    grown = [jax.lax.dot_general(k_out[j], u[j], (((0,), (0,)), ((), ())),
+                                 preferred_element_type=f32) for j in js]
+    return (tuple(jnp.where(left, o2[j][:chunk], o2[j][chunk:]) for j in js),
+            tuple(jnp.exp(jnp.where(left, *ends[j])) * s[j] + grown[j] for j in js))
+
+
+# Pairs of heads a trip of the kernel's loop: the more there are, the more of
+# a pair's chain of dependent products (some twenty deep) is hidden behind the
+# others' (on the v5e 3 / 5 / 15 pairs a trip: 4.63 / 4.41 / 4.47 ms a layer
+# at a bucket of 8192; my chip run, PR 47); the fewer, the shorter the kernel's
+# body, which Python traces and lowers for every program that holds it,
+# cached or not: set-up pays it on every start.
+PAIRS_A_TRIP = 5
+
+
+def _pairs_a_trip(n_pairs: int) -> int:
+    """The largest divisor of ``n_pairs`` that is at most ``PAIRS_A_TRIP``."""
+    return max(g for g in range(1, PAIRS_A_TRIP + 1) if n_pairs % g == 0)
+
+
+def _delta_chunk_body(real_ref, s_in, q_ref, k_ref, v_ref, rows_ref, cols_ref,
+                      o_ref, s_ref, s_at, k_at, q_at, v_at, c_at, o_at, *,
+                      n_pairs: int, chunk: int, d_k: int, d_v: int):
+    """A grid step: every head of one lane over one chunk. ``s_in`` / ``s_ref
+    (d_k, H x d_v)`` are the lane's state in and out, ``q_ref`` / ``k_ref
+    (chunk, H x d_k)``, ``v_ref`` / ``o_ref (chunk, H x d_v)``; ``rows_ref (H,
+    2 chunk)`` holds, a pair of heads, the running log-decay (row ``p``) and
+    the write strength (row ``H / 2 + p``) as rows (head 2p's tokens, then
+    head 2p + 1's), ``cols_ref (2 chunk, H)`` the same as columns.
+
+    The heads go two at a time, stacked along the rows: a pair's 2 x ``chunk``
+    tokens fill the 128 rows of a matrix-unit pass (``_advance_pairs``). The
+    scratch arrays hold everything A PAIR FIRST, so that a loop can take its
+    pairs by a leading index where the operands' own layout would need a slice
+    of the lanes at a traced offset: ``s_at (pairs, d_k, 2 d_v)`` is THE STATE
+    between a lane's first chunk (when it is cut out of ``s_in``) and its last
+    (when it is put together in ``s_ref``), and lives in VMEM all the while;
+    ``k_at`` / ``q_at (pairs, 2 chunk, d_k)``, ``v_at`` / ``o_at (pairs, chunk,
+    2 d_v)`` and ``c_at (H, 2 chunk, 1)`` are the chunk's. A chunk wholly past
+    ``real_len`` does nothing: the state stays bit for bit and ``o`` is not
+    written."""
+    from jax.experimental import pallas as pl
+
+    lane, c = pl.program_id(0), pl.program_id(1)
+    pairs = range(n_pairs)
+    a_trip = _pairs_a_trip(n_pairs)
+    columns = [slice(2 * p * d_v, 2 * (p + 1) * d_v) for p in pairs]
+
+    @pl.when(c == 0)
+    def _():
+        for p in pairs:
+            s_at[p] = s_in[:, columns[p]]
+
+    @pl.when(c * chunk < real_ref[lane])
+    def _():
+        for p in pairs:
+            # a pair's key columns, the second head's rows under the first's
+            for ref, at in ((k_ref, k_at), (q_ref, q_at)):
+                win = ref[:, 2 * p * d_k:2 * (p + 1) * d_k]
+                at[p] = jnp.concatenate([win[:, :d_k], win[:, d_k:]], axis=0)
+            v_at[p] = v_ref[:, columns[p]]
+        for head in range(2 * n_pairs):
+            c_at[head] = cols_ref[:, head:head + 1]
+
+        def trip(t, _):
+            here = [t * a_trip + j for j in range(a_trip)]
+            o, s = _advance_pairs(
+                *(tuple(at[p] for p in here) for at in (k_at, q_at, v_at)),
+                tuple(rows_ref[pl.ds(p, 1), :] for p in here),
+                tuple(rows_ref[pl.ds(n_pairs + p, 1), :] for p in here),
+                tuple(c_at[p] for p in here),
+                tuple(c_at[n_pairs + p] for p in here),
+                tuple(s_at[p] for p in here))
+            for j, p in enumerate(here):
+                o_at[p], s_at[p] = o[j], s[j]
+            return 0
+
+        jax.lax.fori_loop(0, n_pairs // a_trip, trip, 0)
+        for p in pairs:
+            o_ref[:, columns[p]] = o_at[p]
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _():
+        for p in pairs:
+            s_ref[:, columns[p]] = s_at[p]
+
+
+def _pair_rows(x, chunk: int):
+    """``(B, T, H)`` float32, a value a token a head -> ``(B, T / chunk, H /
+    2, 2 chunk)``: a row a chunk a pair of heads, head ``2p``'s tokens then
+    head ``2p + 1``'s."""
+    b, t_len, h = x.shape
+    x = x.reshape(b, t_len // chunk, chunk, h // 2, 2)
+    return x.transpose(0, 1, 3, 4, 2).reshape(b, t_len // chunk, h // 2, 2 * chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def delta_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is the module's CHUNK (a power of two the gate checks); interpret is boolean (the tests' flag)
+        state, q, k, v, log_g, beta, real_len, chunk: int = CHUNK,
+        interpret: bool = False):
+    """The chunked rule as ONE kernel a call: ``state (B, d_k, H x d_v)``
+    float32 in the layout it is stored in, ``q`` / ``k (B, T, H x d_k)`` and
+    ``v (B, T, H x d_v)`` as the projection left them, ``log_g`` / ``beta (B,
+    T, H)`` float32 (the running log-decay INSIDE each chunk; tokens past
+    ``real_len`` already identities), ``real_len (B,)`` int32, ``T`` a
+    multiple of ``chunk`` -> (``o (B, T, H x d_v)`` float32, the state after).
+    The grid is (lane, chunk): a lane's state is read from HBM when its first
+    chunk starts and written when its last ends, and lives in VMEM between.
+    Chunks wholly past ``real_len`` are not computed and their blocks not
+    fetched (the index maps stay on the last real chunk). A ``jit`` of its
+    own: a program's linear layers share ONE trace and one lowering of the
+    kernel's body (Python's work, which no compile cache saves a start)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    b, d_k, width = state.shape
+    t_len, h = q.shape[1], beta.shape[-1]
+    n = t_len // chunk
+    rows = jnp.concatenate([_pair_rows(log_g, chunk), _pair_rows(beta, chunk)],
+                           axis=2)                       # (B, n, H, 2C)
+    cols = jnp.swapaxes(rows, 2, 3)                      # (B, n, 2C, H)
+
+    def at_chunk(c, lane, real):
+        last = jnp.maximum((real[lane] + chunk - 1) // chunk - 1, 0)
+        return jnp.minimum(c, last)
+
+    tokens = lambda columns: pl.BlockSpec(                           # noqa: E731
+        (None, chunk, columns), lambda i, c, real: (i, at_chunk(c, i, real), 0))
+    vectors = lambda *shape: pl.BlockSpec(                           # noqa: E731
+        (None, None) + shape, lambda i, c, real: (i, at_chunk(c, i, real), 0, 0))
+    whole = pl.BlockSpec((None, d_k, width), lambda i, c, real: (i, 0, 0))
+    n_pairs, d_v = h // 2, width // h
+    body = functools.partial(_delta_chunk_body, n_pairs=n_pairs, chunk=chunk,
+                             d_k=d_k, d_v=d_v)
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, n),
+            in_specs=[whole, tokens(h * d_k), tokens(h * d_k), tokens(width),
+                      vectors(h, 2 * chunk), vectors(2 * chunk, h)],
+            out_specs=[tokens(width), whole],
+            scratch_shapes=[
+                pltpu.VMEM((n_pairs, d_k, 2 * d_v), f32),
+                pltpu.VMEM((n_pairs, 2 * chunk, d_k), k.dtype),
+                pltpu.VMEM((n_pairs, 2 * chunk, d_k), q.dtype),
+                pltpu.VMEM((n_pairs, chunk, 2 * d_v), v.dtype),
+                pltpu.VMEM((h, 2 * chunk, 1), f32),
+                pltpu.VMEM((n_pairs, chunk, 2 * d_v), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, t_len, width), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret, name="delta_chunk_kernel",
+    )(real_len.astype(jnp.int32), state.astype(f32), q, k, v, rows, cols)
