@@ -364,11 +364,12 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     # PR 35's five, then PR 36's count of what a chunk's launch uploaded; PR 39
     # appended its window layers' five after them and its cell to their lists,
     # PR 40 the share of chunks launched ahead, PR 41 its five and its cell,
-    # PR 46 its four and its cell, PR 47 the chunked rule's kernel's share
-    tail = bench_json["per_layer"][-22:-16]
+    # PR 46 its four and its cell, PR 47 the chunked rule's kernel's share,
+    # PR 48 the rows a prefill computes a real row (the seven generating cells)
+    tail = bench_json["per_layer"][-23:-17]
     assert [m["name"] for m in tail] == NEW + ["chunk_uploads_mean"]
     assert tail[-1]["unit"] == "operands" and tail[-1]["source"] == "program_counter"
-    assert [m["name"] for m in bench_json["per_layer"][-16:]] == [
+    assert [m["name"] for m in bench_json["per_layer"][-17:]] == [
         "window_decode_ms_per_call", "window_decode_roofline",
         "window_prefill_roofline", "window_pages_read_mean",
         "global_decode_roofline", "chunks_ahead_share",
@@ -376,8 +377,8 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
         "ssm_prefill_ms_per_ktok", "shared_kv_decode_roofline",
         "shared_pages_read_mean", "gdn_layers_ms_per_step",
         "gdn_prefill_ms_per_ktok", "gdn_step_roofline", "state_write_lanes_mean",
-        "gdn_chunk_roofline"]
-    last = bench_json["per_layer"][-1]
+        "gdn_chunk_roofline", "prefill_rows_per_real_row"]
+    last = bench_json["per_layer"][-2]
     assert last["workloads"] == ["olmohybrid-longdoc-steady"]
     assert (last["unit"], last["better"], last["source"]) == (
         "%", "higher", "device_trace")
@@ -386,9 +387,43 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     cells = ["mistral7b-chat-steady", "olmoe-chat-steady", "mistral4-docqa-steady",
              "lfm2-longgen-steady", "mellum2-codectx-mixed",
              "phi4flash-reasoning-steady", "olmohybrid-longdoc-steady"]
-    layers = {m["layer"] for m in bench_json["per_layer"][:-22]}
+    layers = {m["layer"] for m in bench_json["per_layer"][:-23]}
     assert last["layer"] in layers
+    rows = bench_json["per_layer"][-1]
+    assert rows["workloads"] == cells and rows["layer"] in layers
+    assert (rows["unit"], rows["better"], rows["source"], rows["moves"]) == (
+        "rows", "lower", "program_counter", "tpot_p50_ms")
+    assert os.path.exists(os.path.join(BENCH, "layer_metrics", rows["name"] + ".py"))
     for m in tail:
         assert m["workloads"] == cells and m["moves"] == "tpot_p50_ms"
         assert m["better"] == "lower" and m["layer"] in layers
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
+
+
+# Since PR 48 a long slot prefill's token-wise stages run in a loop over the row
+# blocks that hold real rows, INSIDE the stage's scope: what the device then
+# reports for a looped stage's operations.
+LOOPED_PREFILL = {
+    "jit(_slot_prefill_jit)/layer/ffn/while/body/dot_general": [0.0120, 48],
+    "jit(_slot_prefill_jit)/layer/ffn/while/body/dynamic_update_slice": [0.0010, 48],
+    "jit(_slot_prefill_jit)/layer/gdn/proj/while/body/dot_general": [0.0070, 36],
+    "jit(_slot_prefill_jit)/layer/gdn/gate/while/body/mul": [0.0020, 36],
+    "jit(_slot_prefill_jit)/layer/gdn/chunk/jit(delta_chunk_kernel)/pallas_call": [0.0030, 6],
+    "jit(_slot_prefill_jit)/layer/attn/global/while/body/dot_general": [0.0015, 8],
+    # where a loop OUTSIDE the stage's scope would put the same operations
+    "jit(_slot_prefill_jit)/layer/while/body/ffn/dot_general": [9.0, 1],
+    "jit(_paged_decode_chunk_jit)/while/body/closed_call/layer/ffn/dot_general": [0.5, 64],
+}
+
+
+@pytest.mark.parametrize("scope, seconds, events", [
+    ("layer/ffn", 0.0130, 96), ("layer/gdn", 0.0120, 78),
+    ("layer/gdn/proj", 0.0070, 36), ("layer/gdn/gate", 0.0020, 36),
+    ("layer/gdn/chunk", 0.0030, 6), ("layer/attn", 0.0015, 8),
+    ("layer/attn/global", 0.0015, 8)])
+def test_a_looped_stage_is_read_under_its_scope(bench, scope, seconds, events):
+    """``layer/ffn/while/body/...`` runs through ``layer/ffn`` for every reader
+    by scope; ``layer/while/body/ffn`` would not, and the decode chunk's own
+    operations are another program's."""
+    got = bench.cs.scope_seconds(LOOPED_PREFILL, "_slot_prefill_jit", scope)
+    assert got == (pytest.approx(seconds), events)

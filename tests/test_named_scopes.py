@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tfservingcache_tpu.models import generation, registry
+from tfservingcache_tpu.models import generation, real_rows, registry
 from tfservingcache_tpu.models.transformer_lm import build
 
 SCOPES = ("embed", "layer", "attn", "kv_read", "kv_write", "ffn", "lm_head",
@@ -189,6 +189,62 @@ def test_slot_prefill_program_carries_every_scope(family_case):
     assert [s for s in SCOPES if not has(s)] == (
         ["kv_read"] if mdef.family == "mla_moe_lm" else [])
     assert _layer_paths(lowered) == both | dense
+
+
+# Since PR 48 a LONG slot prefill runs its token-wise stages over the row
+# blocks that hold real rows (``models/real_rows.over_real_rows``): the scopes
+# whose operations then lie in a loop's body, a family. The loop is INSIDE the
+# stage's scope (``layer/ffn/while/body/...`` still runs through
+# ``layer/ffn``), so every reader by scope finds what it found.
+LOOPED = {
+    "transformer_lm": {"layer/attn", "layer/ffn"},
+    "moe_lm": {"layer/attn"},
+    "mla_moe_lm": {"layer/attn", "layer/attn/q_lora", "layer/attn/kv_lora",
+                   "layer/ffn/shared"},
+    "hybrid_lm": {"layer/attn", "layer/ffn"},
+    "moe_lm-window": {"layer/attn", "layer/attn/global", "layer/attn/window"},
+    "sambay_lm": {"layer/ffn"},
+    "olmo_hybrid_lm": {"layer/attn", "layer/ffn", "layer/gdn/proj",
+                       "layer/gdn/conv", "layer/gdn/gate"},
+}
+
+
+def _loop_scopes(lowered) -> set:
+    """The paths, from ``layer`` on, under which a ``while`` opens."""
+    found = set()
+    for name in _locations(lowered):
+        parts = name.split("/")
+        if "layer" in parts:
+            at = parts.index("layer")
+            if "while" in parts[at:]:
+                found.add("/".join(parts[at:parts.index("while", at)]))
+    return found
+
+
+def test_long_slot_prefill_loops_inside_its_stage_scopes(family_case,
+                                                         monkeypatch, request):
+    """The slot prefill at a bucket the loop engages in (the block floor
+    dropped to 1 row for the 8-row bucket) carries the SAME ``layer/...``
+    paths as the whole one, and its new ``while/body`` elements open inside
+    the stages' scopes and nowhere else; at the bucket as it stands (every
+    bucket under 4096) no loop is gained."""
+    mdef, cfg_key, params, (both, _, dense) = family_case
+    case = request.node.callspec.params["family_case"]
+    generation._slot_prefill_jit.clear_cache()
+    whole = _slot_prefill(mdef, cfg_key, params)
+    monkeypatch.setattr(real_rows, "MIN_BLOCK_ROWS", 1)
+    generation._slot_prefill_jit.clear_cache()
+    looped = _slot_prefill(mdef, cfg_key, params)
+    generation._slot_prefill_jit.clear_cache()
+    assert _layer_paths(looped) == _layer_paths(whole) == both | dense
+    assert _loop_scopes(looped) - _loop_scopes(whole) == LOOPED[case]
+    # the decode chunk never loops a stage: one token a lane
+    generation._paged_decode_chunk_jit.clear_cache()
+    chunk = _decode_chunk(mdef, cfg_key, params)
+    generation._paged_decode_chunk_jit.clear_cache()
+    assert not {p for p in _loop_scopes(chunk)
+                if p.split("/")[-1] in ("ffn", "proj", "conv", "gate",
+                                        "shared", "q_lora", "kv_lora")}
 
 
 def test_insert_and_predict_programs_are_named(model):

@@ -564,6 +564,7 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_lane_state_bytes",
     "tpusc_kv_arena_bytes",
     "tpusc_gen_window_rows_dropped",
+    "tpusc_prefill_rows",
     "tpusc_gen_kv_page_waste_tokens",
     "tpusc_gen_kv_pages_shared",
     "tpusc_gen_kv_pages_total",

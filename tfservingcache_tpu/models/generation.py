@@ -71,6 +71,7 @@ from tfservingcache_tpu.models.mla_moe_lm import (
     softmax_scale,
 )
 from tfservingcache_tpu.models.moe_lm import _moe_block
+from tfservingcache_tpu.models.real_rows import over_real_rows
 from tfservingcache_tpu.models.registry import (
     CacheRow,
     LaneState,
@@ -408,7 +409,7 @@ def _generate_from_cache_jit(
     cache = _with_prefix(init_cache(cfg, b, max_len), cached_k, cached_v)
     start = cached_len.astype(jnp.int32)                  # (1,)
     logits, cache = _forward_cached_dyn(
-        params, suffix_ids, cache, start, cfg, family
+        params, suffix_ids, cache, start, cfg, family, real_len=suffix_len
     )
     last = jnp.take_along_axis(
         logits, (suffix_len - 1)[:, None, None], axis=1
@@ -458,8 +459,11 @@ def _attends_tokens_at_hand(cfg, cache, s_len: int) -> bool:
 def _prefill_fresh(params, input_ids, prompt_len, cache, cfg, family):
     """The forward of whole (right-padded) prompts into a fresh cache, the
     start_pos = 0 case of ``_forward_cached_dyn`` -> (the last REAL prompt
-    token's logits ``(B, V)`` f32, the cache). Padding positions write junk
-    rows the per-step mask keeps invisible until overwritten; a lane state is
+    token's logits ``(B, V)`` f32, the cache). A row past ``prompt_len`` is
+    not computed where that saves a block (``over_real_rows``: a long bucket's
+    token-wise stages run the row blocks that hold real rows, and a pad row
+    goes to no expert); what a pad position does write is junk or zeros the
+    per-step mask keeps invisible until overwritten; a lane state is
     the one AT ``prompt_len``, which no pad token has touched. A latent family
     projects that one position through the head and no other (a long
     prompt's ``S_pad x V`` float32 logits are a gigabyte at 8192 x 32768), and
@@ -607,7 +611,7 @@ def _slot_prefill_from_cache_jit(
     cache = _with_prefix(init_cache(cfg, b, l_pad + s_pad), cached_k, cached_v)
     start = cached_len.astype(jnp.int32)
     logits, cache = _forward_cached_dyn(
-        params, suffix_ids, cache, start, cfg, family
+        params, suffix_ids, cache, start, cfg, family, real_len=suffix_len
     )
     last = jnp.take_along_axis(
         logits, (suffix_len - 1)[:, None, None], axis=1
@@ -1477,27 +1481,34 @@ MOE_STATS = ("experts_hit", "expert_rows_max", "expert_rows_local")
 
 
 def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
-               moe_stats: list | None = None):
+               moe_stats: list | None = None, took=None):
     """The second half of a decoder layer (input is the residual stream
     BEFORE its norm; returns the residual delta), chosen by what the layer
     holds: ``moe`` = the routed expert layer, else the dense SwiGLU ``mlp``
     (normed by ``ln2`` before it, or by ``ln2_post`` after it in a layer that
     holds that leaf: the Olmo 2/3 family's reordered block).
     ``row_mask`` (one flag a row of ``x`` flattened) marks rows whose answer
-    nobody reads: the expert layer routes them nowhere. An expert layer's
-    routing stats (``MOE_STATS``) are appended to ``moe_stats`` where the
-    caller gives a list."""
+    nobody reads: the expert layer routes them nowhere. ``took (B,)`` says how
+    many of each example's rows are real (None = all): the dense MLP, and an
+    expert layer's shared expert, run over the row blocks that hold them
+    (``over_real_rows``). An expert layer's routing stats (``MOE_STATS``) are
+    appended to ``moe_stats`` where the caller gives a list."""
     if "moe" in layer:
-        y, stats = _moe_block(layer, x, cfg, dtype, row_mask=row_mask)
+        y, stats = _moe_block(layer, x, cfg, dtype, row_mask=row_mask,
+                              took=took)
         if moe_stats is not None:
             moe_stats.append(jnp.stack([stats[name] for name in MOE_STATS]))
         return y
     with jax.named_scope("ffn"):
         after = "ln2_post" in layer      # a reordered block: the norm follows
-        h = x if after else _norm(layer, "ln2", x)
         mlp = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"])
-        y = (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
-        return _norm(layer, "ln2_post", y, _norm_eps(cfg)) if after else y
+
+        def rows_mlp(x):
+            h = x if after else _norm(layer, "ln2", x)
+            y = (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
+            return _norm(layer, "ln2_post", y, _norm_eps(cfg)) if after else y
+
+        return over_real_rows(rows_mlp, (x,), took)
 
 
 def _walk_layers(params, ids, rows, cfg, logits_at=None,
@@ -1543,7 +1554,7 @@ def _walk_layers(params, ids, rows, cfg, logits_at=None,
             else:
                 x = _attend_rows(layer, x, kind, slot, depth, rows, cfg)
             x = x + _ffn_block(layer, x, cfg, dtype, row_mask=rows.active,
-                               moe_stats=moe_stats)
+                               moe_stats=moe_stats, took=rows.took)
     if logits_at is not None:
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     return _output_logits(params, x, dtype, _norm_eps(cfg))
@@ -1559,27 +1570,45 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
     layer's kind), its rows written by the store (unless they are another
     layer's, ``SharedRows``: written when that one ran), its queries attended
     by the store, and the heads finished here. A config whose ``rope_theta``
-    is None applies no rotary."""
+    is None applies no rotary. The two token-wise halves written here (norm,
+    projection and rotary before the store; ``wo`` and a following norm after
+    it) run over the row blocks that hold the ``rows.took`` real rows
+    (``over_real_rows``: a long prefill; any other forward traces them
+    whole)."""
     b, t, _ = x.shape
     shared = isinstance(kind, SharedRows)
     with jax.named_scope("attn"):
         attn = jax.tree_util.tree_map(lambda w: w.astype(x.dtype), layer["attn"])
         after = "ln1_post" in layer      # a reordered block: the norm follows
-        a = x if after else _norm(layer, "ln1", x, _norm_eps(cfg))
-    if _cache_row(cfg).sides == 1:
+
+    def normed(x):
+        return x if after else _norm(layer, "ln1", x, _norm_eps(cfg))
+
+    latent, differential = _cache_row(cfg).sides == 1, "lam_q1" in attn
+    if latent or differential:    # their projections take the bucket's norm
+        with jax.named_scope("attn"):
+            a = normed(x)
+    if latent:
         return rows.latent_layer(attn, a, x, slot)
-    differential = "lam_q1" in attn
     with jax.named_scope("attn"):
         if differential:
             q, k, v, scale = _differential_qkv(attn, a, cfg)
         else:
             # a QK-norm's own eps only where the model states one
             eps = (cfg["qk_norm_eps"],) if "qk_norm_eps" in cfg else ()
-            q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"], *eps)
             rope, scale = rope_of(cfg, slot.window), None
-            if cfg["rope_theta"] is not None:     # None: no rotary at all
-                q = _rope_per_example(q, rows.positions, cfg["rope_theta"], rope)
-                k = _rope_per_example(k, rows.positions, cfg["rope_theta"], rope)
+
+            def project(x, positions):
+                q, k, v = _qkv(attn, normed(x), cfg["n_heads"],
+                               cfg["n_kv_heads"], *eps)
+                if cfg["rope_theta"] is not None:     # None: no rotary at all
+                    q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
+                    k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
+                return q, k, v
+
+            # the heads' rows lie along axis 2: (B, heads, T, width)
+            q, k, v = over_real_rows(project, (x, rows.positions), rows.took,
+                                     out_axis=2)
     if not shared:
         rows.write(slot, k, v)
     with jax.named_scope("attn"), _kind_scope(
@@ -1589,11 +1618,15 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
             b, cfg["n_heads"], t, q.shape[-1])
         if differential:
             return x + diff_finish(attn, diff_outputs(out), depth, x.dtype)
-        out = out.astype(x.dtype).transpose(0, 2, 1, 3)
-        # heads x head width: the hidden size for most models
-        out = out.reshape(b, t, -1) @ attn["wo"]
-        return x + (_norm(layer, "ln1_post", out, _norm_eps(cfg)) if after
+
+        def finish(out):
+            out = out.astype(x.dtype).transpose(0, 2, 1, 3)
+            # heads x head width: the hidden size for most models
+            out = out.reshape(b, out.shape[1], -1) @ attn["wo"]
+            return (_norm(layer, "ln1_post", out, _norm_eps(cfg)) if after
                     else out)
+
+        return x + over_real_rows(finish, (out,), rows.took, in_axis=2)
 
 
 def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,
@@ -1632,9 +1665,10 @@ class _DenseRows:
     layer's whole length under a mask (that docstring says when the tokens at
     hand alone are attended). Nothing is updated in place: the layers' rows
     and lane states after the forward are gathered a layer and stacked at the
-    end (``cache_after``)."""
+    end (``cache_after``). ``real_len (B,)`` (None = every token at hand is
+    real) becomes ``took``, which the lane states and the token-wise stages
+    follow, and ``active``, the rows somebody reads."""
 
-    active = None      # every row's answer is read
     in_place = False   # a layer's state after the forward is stacked at the end
     live = None
 
@@ -1642,6 +1676,10 @@ class _DenseRows:
                  real_len):
         self.positions = start_pos[:, None] + jnp.arange(s_len)[None, :]  # (B, S)
         self.cache, self.start_pos, self.took = cache, start_pos, real_len
+        # one flag a row flattened (None = all): a right-padded prompt's pad
+        # rows go to no expert, as the paged step's inactive lanes
+        self.active = None if real_len is None else (
+            jnp.arange(s_len)[None, :] < real_len[:, None]).reshape(-1)
         self.cfg, self.fresh = cfg, fresh
         self.flash = fresh and _attends_tokens_at_hand(cfg, cache, s_len)
         self.k, self.v, self.lane = [], [], []    # after the forward, a layer
@@ -1707,14 +1745,16 @@ class _DenseRows:
         cfg = self.cfg
         with jax.named_scope("attn"):
             rows_layer = self.cache["k"][slot.index]             # (B, 1, L, W)
-            q_n, q_r, rows = latent_project(attn, a, self.positions, cfg)
+            q_n, q_r, rows = latent_project(attn, a, self.positions, cfg,
+                                            self.took)
             with jax.named_scope("kv_write"):
                 rows_layer = jax.vmap(lambda c, new, p: jax.lax.dynamic_update_slice(
                     c, new[None], (0, p, 0))
                 )(rows_layer, rows.astype(rows_layer.dtype), self.start_pos)
             self.k.append(rows_layer)
             if self.fresh:
-                out = expanded_attention(attn, q_n, q_r, rows, cfg)
+                out = expanded_attention(attn, q_n, q_r, rows, cfg,
+                                         took=self.took)
             else:
                 out = dense_absorbed_attention(
                     absorbed_query(attn, q_n, q_r, cfg), rows_layer[:, 0],

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tfservingcache_tpu.models.moe_lm import _moe_block
+from tfservingcache_tpu.models.real_rows import over_real_rows
 from tfservingcache_tpu.models.registry import (
     ModelDef,
     TensorSpec,
@@ -140,46 +141,68 @@ def _rope_rows(x: jax.Array, positions: jax.Array, inv_freq: np.ndarray) -> jax.
     return rot.reshape(x.shape).astype(x.dtype)
 
 
-def latent_project(attn: dict, a: jax.Array, positions: jax.Array, cfg: dict):
+def latent_project(attn: dict, a: jax.Array, positions: jax.Array, cfg: dict,
+                   took=None):
     """The normed activations ``a (B, S, d)`` at ``positions (B, S)`` ->
     ``q_n (B, S, H, nope)``, ``q_r (B, S, H, rope)`` (rotated; both carry
     ``query_factor``) and the cache rows ``(B, S, W)`` = ``[c_kv | rope(k_r) |
-    zeros]`` at the stored width: the projections every forward shares."""
-    b, s, _ = a.shape
+    zeros]`` at the stored width: the projections every forward shares.
+    ``took (B,)`` says how many of each example's rows are real (None = all):
+    a long prefill projects the row blocks that hold them
+    (``over_real_rows``)."""
     h, nope, rope = cfg["n_heads"], cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
     rank, eps = cfg["kv_lora_rank"], cfg["rms_eps"]
     inv_freq = yarn_inv_freq(cfg)
-    with jax.named_scope("q_lora"):
+
+    def queries(a, positions):
+        b, s, _ = a.shape
         c_q = _rmsnorm(a @ attn["wq_a"], attn["q_a_norm"], eps)
         q = (c_q @ attn["wq_b"]).reshape(b, s, h, nope + rope)
         q = (q * query_factor(cfg, positions)[:, :, None, None]).astype(a.dtype)
-        q_n, q_r = q[..., :nope], _rope_rows(q[..., nope:], positions, inv_freq)
-    with jax.named_scope("kv_lora"):
+        return q[..., :nope], _rope_rows(q[..., nope:], positions, inv_freq)
+
+    def cache_rows(a, positions):
+        b, s, _ = a.shape
         ckr = a @ attn["wkv_a"]                                   # (B, S, rank + rope)
         c_kv = _rmsnorm(ckr[..., :rank], attn["kv_a_norm"], eps)
         k_r = _rope_rows(ckr[..., rank:], positions, inv_freq)
         pad = latent_cache_row(cfg).width - rank - rope
-        rows = jnp.concatenate(
+        return jnp.concatenate(
             [c_kv, k_r, jnp.zeros((b, s, pad), a.dtype)], axis=-1)
+
+    with jax.named_scope("q_lora"):
+        q_n, q_r = over_real_rows(queries, (a, positions), took)
+    with jax.named_scope("kv_lora"):
+        rows = over_real_rows(cache_rows, (a, positions), took)
     return q_n, q_r, rows
 
 
 def expanded_attention(attn: dict, q_n, q_r, rows, cfg: dict,
-                       partitioned: bool = False) -> jax.Array:
+                       partitioned: bool = False, took=None) -> jax.Array:
     """Causal self-attention among the S tokens at hand in the EXPANDED form
     -> the residual delta ``(B, S, d)``: ``k_n`` and ``v`` made from the rows'
     ``c_kv``, the shared ``rope(k_r)`` given to every head, then
-    ``ops.attention.attention`` (the flash kernel where its gate admits)."""
-    b, s, h, nope = q_n.shape
+    ``ops.attention.attention`` (the flash kernel where its gate admits).
+    The expansion before the kernel and ``wo`` after it follow ``took`` as
+    ``latent_project`` does."""
+    b, _, h, nope = q_n.shape
     rank, rope, vd = cfg["kv_lora_rank"], cfg["qk_rope_head_dim"], cfg["v_head_dim"]
-    kv = (rows[..., :rank] @ attn["wkv_b"]).reshape(b, s, h, nope + vd)
-    k_r = jnp.broadcast_to(rows[:, :, None, rank:rank + rope], (b, s, h, rope))
-    q = jnp.concatenate([q_n, q_r], axis=-1).transpose(0, 2, 1, 3)
-    k = jnp.concatenate([kv[..., :nope], k_r], axis=-1).transpose(0, 2, 1, 3)
-    v = kv[..., nope:].transpose(0, 2, 1, 3)
+
+    def expand(q_n, q_r, rows):
+        s = rows.shape[1]
+        kv = (rows[..., :rank] @ attn["wkv_b"]).reshape(b, s, h, nope + vd)
+        k_r = jnp.broadcast_to(rows[:, :, None, rank:rank + rope], (b, s, h, rope))
+        q = jnp.concatenate([q_n, q_r], axis=-1).transpose(0, 2, 1, 3)
+        k = jnp.concatenate([kv[..., :nope], k_r], axis=-1).transpose(0, 2, 1, 3)
+        return q, k, kv[..., nope:].transpose(0, 2, 1, 3)
+
+    def finish(out):
+        out = out.transpose(0, 2, 1, 3)
+        return out.reshape(b, out.shape[1], h * vd).astype(q_n.dtype) @ attn["wo"]
+
+    q, k, v = over_real_rows(expand, (q_n, q_r, rows), took, out_axis=2)
     out = attention(q, k, v, causal=True, partitioned=partitioned)
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, h * vd).astype(q_n.dtype)
-    return out @ attn["wo"]
+    return over_real_rows(finish, (out,), took, in_axis=2)
 
 
 def absorbed_query(attn: dict, q_n, q_r, cfg: dict) -> jax.Array:

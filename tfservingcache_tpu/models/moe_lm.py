@@ -45,6 +45,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from tfservingcache_tpu.models.real_rows import over_real_rows, row_block
 from tfservingcache_tpu.models.registry import (
     ModelDef,
     TensorSpec,
@@ -102,7 +103,7 @@ def layer_state_of(cfg: dict) -> tuple:
 
 @jax.named_scope("ffn")
 def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
-               partitioned: bool = False) -> tuple[jax.Array, dict]:
+               partitioned: bool = False, took=None) -> tuple[jax.Array, dict]:
     """The expert half of a layer over the residual stream ``x (B, S, D)``
     BEFORE its norm -> (residual delta, the layer's routing stats).
     ``row_mask (B*S,)`` marks rows whose answer nobody reads. What the config
@@ -110,7 +111,8 @@ def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
     ``route_scale`` / ``route_norm_eps``, the chip's share ``n_experts_held`` experts from
     ``expert_first`` (``ops.moe.moe_experts``' ``held``), and, where the layer
     holds ``moe/shared``, a dense SwiGLU expert every token takes, computed
-    once beside the routed ones."""
+    once beside the routed ones, over the row blocks that hold the ``took
+    (B,)`` real rows of each example (None = all; ``over_real_rows``)."""
     b, s, d = x.shape
     moe = {w: layer["moe"][w] for w in ("router", "bias")
            if w in layer["moe"]}                        # routing stays f32
@@ -128,7 +130,16 @@ def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
         with jax.named_scope("shared"):
             sh = jax.tree_util.tree_map(lambda w: w.astype(dtype),
                                         layer["moe"]["shared"])
-            y = y + (jax.nn.silu(z @ sh["w1"]) * (z @ sh["w3"])) @ sh["w2"]
+
+            def shared(z):
+                return (jax.nn.silu(z @ sh["w1"]) * (z @ sh["w3"])) @ sh["w2"]
+
+            if took is not None and row_block(s):
+                # the loop's blocks are rows of an example: not the flat rows
+                y = y + over_real_rows(
+                    shared, (z.reshape(b, s, d),), took).reshape(b * s, d)
+            else:
+                y = y + shared(z)
     return y.reshape(b, s, d), stats
 
 

@@ -48,6 +48,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from tfservingcache_tpu.models.real_rows import over_real_rows
 from tfservingcache_tpu.models.registry import (
     LaneState,
     ModelDef,
@@ -104,43 +105,56 @@ def _l2norm(x: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
 
 
-def _mixer_inputs(layer: dict, x: jax.Array, conv, cfg: dict):
+def _mixer_inputs(layer: dict, x: jax.Array, conv, cfg: dict, took=None):
     """What the rule takes of the tokens ``x (B, T, d)`` and the lanes' last
     convolution inputs ``conv (B, taps - 1, W)``: ``(q, k, v, alpha, beta, z,
     rows)`` with ``q`` / ``k (B, T, H, d_k)`` normalised, ``v (B, T, H,
     d_v)``, ``alpha`` / ``beta (B, T, H)`` float32, ``z`` the output gate's
     projection and ``rows (B, taps - 1 + T, W)`` the convolution's inputs,
-    whose tail is the state after."""
+    whose tail is the state after. ``took (B,)`` says how many of each
+    example's tokens are real (None = all): a long prefill projects and
+    convolves the row blocks that hold them (``over_real_rows``; a block of
+    the convolution begins ``taps - 1`` rows early)."""
     f32 = jnp.float32
     dtype = jnp.dtype(cfg["dtype"])
     gdn = _cast(layer["gdn"], dtype)
-    b, t, _ = x.shape
+    b = x.shape[0]
     h, d_k, d_v = (int(cfg[key]) for key in (
         "linear_heads", "linear_key_dim", "linear_value_dim"))
     taps = gdn["conv_w"].shape[-1]
     with jax.named_scope("proj"):
-        qkv = x @ gdn["w_qkv"]                                 # (B, T, 2 H d_k + H d_v)
-        z = x @ gdn["w_g"]                                     # (B, T, H d_v)
-        a_in = (x @ gdn["w_a"]).astype(f32)                    # (B, T, H)
-        b_in = (x @ gdn["w_b"]).astype(f32)
+        def project(x):
+            qkv = x @ gdn["w_qkv"]                             # (B, T, 2 H d_k + H d_v)
+            z = x @ gdn["w_g"]                                 # (B, T, H d_v)
+            a_in = (x @ gdn["w_a"]).astype(f32)                # (B, T, H)
+            b_in = (x @ gdn["w_b"]).astype(f32)
+            return qkv, z, a_in, b_in
+
+        qkv, z, a_in, b_in = over_real_rows(project, (x,), took)
     with jax.named_scope("conv"):
         rows = jnp.concatenate([conv.astype(dtype), qkv], axis=1)
         w = gdn["conv_w"].astype(f32)                          # (W, taps)
 
-        def mixed(first: int, width: int):
-            """The taps and silu over ``width`` columns from ``first``: a part
-            at a time, so that no float32 array of all ``W`` columns exists
-            (0.75 GB at a 16384-token bucket)."""
-            cols = slice(first, first + width)
-            return jax.nn.silu(sum(
-                w[cols, j] * rows[:, j:j + t, cols].astype(f32)
-                for j in range(taps)))
+        def convolve(rows):
+            t = rows.shape[1] - (taps - 1)
 
-        q = mixed(0, h * d_k).reshape(b, t, h, d_k)
-        k = mixed(h * d_k, h * d_k).reshape(b, t, h, d_k)
-        q = (_l2norm(q) * d_k ** -0.5).astype(dtype)
-        k = _l2norm(k).astype(dtype)
-        v = mixed(2 * h * d_k, h * d_v).astype(dtype).reshape(b, t, h, d_v)
+            def mixed(first: int, width: int):
+                """The taps and silu over ``width`` columns from ``first``: a
+                part at a time, so that no float32 array of all ``W`` columns
+                exists (0.75 GB at a 16384-token bucket)."""
+                cols = slice(first, first + width)
+                return jax.nn.silu(sum(
+                    w[cols, j] * rows[:, j:j + t, cols].astype(f32)
+                    for j in range(taps)))
+
+            q = mixed(0, h * d_k).reshape(b, t, h, d_k)
+            k = mixed(h * d_k, h * d_k).reshape(b, t, h, d_k)
+            q = (_l2norm(q) * d_k ** -0.5).astype(dtype)
+            k = _l2norm(k).astype(dtype)
+            v = mixed(2 * h * d_k, h * d_v).astype(dtype).reshape(b, t, h, d_v)
+            return q, k, v
+
+        q, k, v = over_real_rows(convolve, (rows,), took, halo=taps - 1)
     with jax.named_scope("gate"):
         # from the leaves as they are stored, not through the compute dtype
         alpha = jnp.exp(-jnp.exp(layer["gdn"]["a_log"].astype(f32))
@@ -151,19 +165,27 @@ def _mixer_inputs(layer: dict, x: jax.Array, conv, cfg: dict):
     return q, k, v, alpha, beta, z, rows
 
 
-def _mixer_output(layer: dict, o: jax.Array, z: jax.Array, cfg: dict):
+def _mixer_output(layer: dict, o: jax.Array, z: jax.Array, cfg: dict,
+                  took=None):
     """The rule's outputs ``o (B, T, H, d_v)`` float32 -> the residual delta:
     the per-head RMSNorm times ``silu(z)``, ``w_o``, the norm that follows
-    the mixer."""
+    the mixer; each over the row blocks that hold the ``took`` real tokens
+    (None = all)."""
     dtype = jnp.dtype(cfg["dtype"])
-    b, t = o.shape[:2]
+    b = o.shape[0]
     with jax.named_scope("gate"):
-        o = _rmsnorm(o, layer["gdn"]["o_norm"].astype(jnp.float32),
-                     cfg["rms_eps"]).astype(dtype)
-        o = o.reshape(b, t, -1) * jax.nn.silu(z)
+        o_norm = layer["gdn"]["o_norm"].astype(jnp.float32)
+
+        def gate(o, z):
+            o = _rmsnorm(o, o_norm, cfg["rms_eps"]).astype(dtype)
+            return o.reshape(b, o.shape[1], -1) * jax.nn.silu(z)
+
+        o = over_real_rows(gate, (o, z), took)
     with jax.named_scope("proj"):
-        return _rmsnorm(o @ layer["gdn"]["w_o"].astype(dtype),
-                        layer["ln1_post"], cfg["rms_eps"])
+        w_o = layer["gdn"]["w_o"].astype(dtype)
+        return over_real_rows(
+            lambda o: _rmsnorm(o @ w_o, layer["ln1_post"], cfg["rms_eps"]),
+            (o,), took)
 
 
 def _conv_after(rows: jax.Array, t: int, real_len):
@@ -196,14 +218,15 @@ def gdn_layer(layer: dict, x: jax.Array, state, real_len, cfg: dict):
                  jnp.zeros((b, taps - 1, h * (2 * d_k + d_v)),
                            jnp.dtype(cfg["dtype"])))
     s, conv = state
-    q, k, v, alpha, beta, z, rows = _mixer_inputs(layer, x, conv, cfg)
+    q, k, v, alpha, beta, z, rows = _mixer_inputs(layer, x, conv, cfg,
+                                                  real_len)
     if t == 1:
         o, s = delta_step(s, q[:, 0], k[:, 0], v[:, 0], alpha[:, 0], beta[:, 0],
                           real_len)
         o = o[:, None]
     else:
         o, s = delta_chunked(s, q, k, v, alpha, beta, real_len)
-    return (_mixer_output(layer, o, z, cfg),
+    return (_mixer_output(layer, o, z, cfg, real_len),
             (s, _conv_after(rows, t, real_len)), None)
 
 

@@ -679,7 +679,13 @@ def delta_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is the modu
     n_pairs, d_v = h // 2, width // h
     body = functools.partial(_delta_chunk_body, n_pairs=n_pairs, chunk=chunk,
                              d_k=d_k, d_v=d_v)
-    return pl.pallas_call(
+    # Behind a barrier: where the state's consumer is an in-place update (the
+    # prefill's stack of its layers' states) the v5e compiler may wrap the
+    # call and that update into ONE fusion, whose scoped VMEM is the default
+    # 16 MB and not the ``VMEM_LIMIT`` asked here: 17 MB at 30 heads of 96 x
+    # 192 refuse to compile (PR 48: a prefill whose gate reads ``o`` a block
+    # at a time made it choose so).
+    return jax.lax.optimization_barrier(pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, n),
@@ -699,4 +705,4 @@ def delta_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is the modu
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret, name="delta_chunk_kernel",
-    )(real_len.astype(jnp.int32), state.astype(f32), q, k, v, rows, cols)
+    )(real_len.astype(jnp.int32), state.astype(f32), q, k, v, rows, cols))

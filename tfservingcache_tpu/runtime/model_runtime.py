@@ -2399,6 +2399,17 @@ class TPUModelRuntime(BaseRuntime):
             for kind in ("global", "window"):
                 self.metrics.kv_arena_bytes.labels(model, kind).set(0)
 
+    def _count_prefill_rows(self, real: int, bucket: int) -> None:
+        """``tpusc_prefill_rows_total`` for one admission prefill of ``real``
+        tokens padded to ``bucket`` rows."""
+        if self.metrics is None:
+            return
+        from tfservingcache_tpu.models.real_rows import rows_computed
+
+        for kind, rows in (("real", real), ("bucket", bucket),
+                           ("computed", rows_computed(real, bucket))):
+            self.metrics.prefill_rows.labels(kind).inc(rows)
+
     @_mesh_serialized
     def slot_prefill(
         self,
@@ -2466,6 +2477,7 @@ class TPUModelRuntime(BaseRuntime):
         if hit is not None:
             ids = prompt[None, :]
             suffix, suffix_len = self._prefix_suffix(ids, p, hit)
+            self._count_prefill_rows(suffix_len, suffix.shape[1])
             tok, pk, pv, last = _slot_prefill_from_cache_jit(
                 loaded.params, suffix,
                 np.asarray([suffix_len], np.int32),
@@ -2479,6 +2491,7 @@ class TPUModelRuntime(BaseRuntime):
                 s_pad = p  # bucket overshoot: exact size (same rule as generate)
             ids = np.zeros((1, s_pad), np.int32)
             ids[0, :p] = prompt
+            self._count_prefill_rows(p, s_pad)
             tok, pk, pv, last, lane = _slot_prefill_jit(
                 loaded.params, ids, np.asarray([p], np.int32),
                 rng, temp, tk, cfg_key=cfg_key,
@@ -2652,6 +2665,7 @@ class TPUModelRuntime(BaseRuntime):
         s_pad = next_bucket(suffix_len)
         suffix = np.zeros((1, s_pad), np.int32)
         suffix[0, :suffix_len] = prompt[covered:]
+        self._count_prefill_rows(suffix_len, s_pad)
         tok, pk, pv, last = _slot_prefill_from_cache_jit(
             loaded.params, suffix,
             np.asarray([suffix_len], np.int32),
@@ -2908,6 +2922,7 @@ class TPUModelRuntime(BaseRuntime):
         s_pad = next_bucket(suffix_len)
         suffix = np.zeros((1, s_pad), np.int32)
         suffix[0, :suffix_len] = prompt[covered:]
+        self._count_prefill_rows(suffix_len, s_pad)
         rng = jax.random.PRNGKey(seed)
         tok, pk, pv, last = _slot_prefill_from_cache_jit(
             loaded.params, suffix,

@@ -422,6 +422,16 @@ class Metrics:
             "window layers)",
             ["model"], registry=r,
         )
+        self.prefill_rows = Counter(
+            "tpusc_prefill_rows_total",
+            "Rows of the admission prefills by kind: real (the prompt's "
+            "tokens the prefill ran), bucket (the power-of-two bucket they "
+            "were padded to) and computed (the rows the prefill's token-wise "
+            "stages ran: the row blocks that hold a real row, "
+            "models/real_rows.rows_computed). computed / real is the pad "
+            "still paid, bucket / real what it was",
+            ["kind"], registry=r,
+        )
         self.gen_kv_arena_bytes = Gauge(
             "tpusc_gen_kv_arena_bytes",
             "Device bytes allocated to the paged KV arena (pages plus, "
