@@ -365,7 +365,17 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     # appended its window layers' five after them and its cell to their lists,
     # PR 40 the share of chunks launched ahead, PR 41 its five and its cell,
     # PR 46 its four and its cell, PR 47 the chunked rule's kernel's share,
-    # PR 48 the rows a prefill computes a real row (the seven generating cells)
+    # PR 48 the rows a prefill computes a real row (the seven generating cells),
+    # PR 49 its Kimi-delta-attention layers' three and its cell
+    kda = bench_json["per_layer"][-3:]
+    assert [m["name"] for m in kda] == [
+        "kda_layers_ms_per_step", "kda_prefill_ms_per_ktok", "kda_step_roofline"]
+    for m in kda:
+        assert m["workloads"] == ["solaropen2-docreport-steady"]
+        assert (m["source"], m["moves"]) == ("device_trace", "tpot_p50_ms")
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
+    assert (kda[-1]["unit"], kda[-1]["better"]) == ("%", "higher")
+    bench_json["per_layer"] = bench_json["per_layer"][:-3]
     tail = bench_json["per_layer"][-23:-17]
     assert [m["name"] for m in tail] == NEW + ["chunk_uploads_mean"]
     assert tail[-1]["unit"] == "operands" and tail[-1]["source"] == "program_counter"
@@ -386,8 +396,10 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     assert os.path.exists(os.path.join(BENCH, "layer_metrics", last["name"] + ".py"))
     cells = ["mistral7b-chat-steady", "olmoe-chat-steady", "mistral4-docqa-steady",
              "lfm2-longgen-steady", "mellum2-codectx-mixed",
-             "phi4flash-reasoning-steady", "olmohybrid-longdoc-steady"]
+             "phi4flash-reasoning-steady", "olmohybrid-longdoc-steady",
+             "solaropen2-docreport-steady"]
     layers = {m["layer"] for m in bench_json["per_layer"][:-23]}
+    assert {m["layer"] for m in kda} <= layers
     assert last["layer"] in layers
     rows = bench_json["per_layer"][-1]
     assert rows["workloads"] == cells and rows["layer"] in layers

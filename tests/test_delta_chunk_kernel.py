@@ -199,16 +199,26 @@ REFUSED = {
 }
 
 
+# on the chip, at widths the kernel takes (Solar-Open2's heads): what is
+# refused is the decay's shape, a value a CHANNEL (PR 49)
+REFUSED["a_decay_a_channel"] = (
+    dict(backend="tpu", channel=True), (64, 128, 128), 64, jnp.bfloat16,
+    "a decay a channel (d_k=128)")
+
+
 @pytest.mark.parametrize("case", sorted(REFUSED))
 def test_the_gate_is_what_the_code_can_see(monkeypatch, case):
     patch, (h, d_k, d_v), chunk, dtype, why = REFUSED[case]
     if "backend" in patch:
         monkeypatch.setattr(jax, "default_backend", lambda: patch["backend"])
     struct = jax.ShapeDtypeStruct
-    got = delta_rule._kernel_refusal(
-        struct((1, d_k, h * d_v), jnp.float32), struct((1, 128, h, d_k), dtype),
-        struct((1, 128, h, d_v), dtype), chunk)
+    operands = (struct((1, d_k, h * d_v), jnp.float32),
+                struct((1, 128, h, d_k), dtype), struct((1, 128, h, d_v), dtype))
+    got = delta_rule._kernel_refusal(*operands, chunk,
+                                     *([True] if patch.get("channel") else []))
     assert got is not None and why in got, got
+    if patch.get("channel"):       # the same operands with a decay a head pass
+        assert delta_rule._kernel_refusal(*operands, chunk) is None
 
 
 @pytest.mark.parametrize("widths", [WIDE, (4, 32, 64), (16, 128, 128)],

@@ -54,46 +54,67 @@ def _share(layer, first, count):
     return cut
 
 
+def _reference_experts(family: str):
+    """The ``gates`` and ``add_experts`` of a benchmark family's plain
+    reference holding EVERY expert of the layer."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_family_{family}", os.path.join(ROOT, "benchmark", "families", f"{family}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    routing = {"rms_eps": 1e-6, "top_k": K, "expert_first": 0, "n_experts_held": E,
+               "norm_topk_prob": True, "route_scale": 2.5}
+    if family == "mla_moe":
+        mc = dict(routing, n_heads=2, qk_nope_head_dim=8, qk_rope_head_dim=8,
+                  v_head_dim=16, kv_lora_rank=8, rope_theta=10000.0,
+                  rope_factor=4.0, rope_beta_fast=32.0, rope_beta_slow=1.0,
+                  rope_original_max=32, rope_mscale_all_dim=1.0, llama4_beta=0.1)
+        *_, gates, add_experts, _head = module._fns(tuple(sorted(mc.items())))
+    else:       # kda_moe: Solar-Open2's expert layer behind either mixer
+        import json
+
+        mc = dict(routing, n_heads=4, n_kv_heads=2, head_dim=8, linear_heads=2,
+                  linear_key_dim=8, linear_value_dim=8,
+                  linear_allow_neg_eigval=True)
+        *_, gates, add_experts, _head = module._fns(json.dumps(mc, sort_keys=True))
+    return gates, add_experts
+
+
+# the deployments the benchmark's two share configurations stand for: an EP-4
+# host (Mistral-Small-4) and an EP-8 host (Solar-Open2), each against its own
+# family's plain reference
+@pytest.mark.parametrize("family,shares", [("mla_moe", 4), ("kda_moe", 8)],
+                         ids=["four_shares_mla_moe", "eight_shares_kda_moe"])
 @pytest.mark.parametrize("interpret", [False, True], ids=["ragged_dot", "kernel"])
-def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
-        monkeypatch, interpret):
+def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
+        monkeypatch, interpret, family, shares):
     """Through ``_moe_block`` (norm, routed share, shared expert): the sum of
-    the four shares' answers minus three of the four shared-expert answers is
-    the reference's whole layer (every expert held, benchmark/families/
-    mla_moe.py's ``gates`` and ``add_experts``)."""
+    every share's answer minus all but one of the shared-expert answers is
+    the reference's whole layer (every expert held: the family's ``gates`` and
+    ``add_experts``)."""
     monkeypatch.setattr(moe, "MOE_KERNEL_INTERPRET", interpret)
     layer, x = _layer(1)
     cfg = {"top_k": K, "norm_topk_prob": True, "route_score": "sigmoid",
            "route_scale": 2.5, "rms_eps": 1e-6, "n_experts": E}
     ln2 = jnp.asarray(1.0 + 0.1 * np.random.default_rng(2).standard_normal(D),
                       jnp.float32)
+    held = E // shares
     parts, locals_ = [], []
-    for first in range(0, E, 2):
+    for first in range(0, E, held):
         y, stats = _moe_block(
-            {"moe": dict(_share(layer, first, 2), shared=layer["shared"]),
+            {"moe": dict(_share(layer, first, held), shared=layer["shared"]),
              "ln2": ln2}, x[None],
-            dict(cfg, n_experts_held=2, expert_first=first), jnp.float32)
+            dict(cfg, n_experts_held=held, expert_first=first), jnp.float32)
         parts.append(np.asarray(y[0], np.float64))
         locals_.append(float(stats["expert_rows_local"]))
-        assert float(stats["experts_hit"]) <= 2
+        assert float(stats["experts_hit"]) <= held
     assert sum(locals_) == T * K            # every assignment landed on one chip
     only_shared, _ = _moe_block(
-        {"moe": dict(_share(layer, 0, 2), shared=layer["shared"]), "ln2": ln2},
-        x[None], dict(cfg, n_experts_held=2, expert_first=0), jnp.float32,
+        {"moe": dict(_share(layer, 0, held), shared=layer["shared"]), "ln2": ln2},
+        x[None], dict(cfg, n_experts_held=held, expert_first=0), jnp.float32,
         row_mask=jnp.zeros((T,), bool))     # no row routed: the shared expert alone
-    total = sum(parts) - 3 * np.asarray(only_shared[0], np.float64)
+    total = sum(parts) - (shares - 1) * np.asarray(only_shared[0], np.float64)
 
-    spec = importlib.util.spec_from_file_location(
-        "bench_family_mla_moe", os.path.join(ROOT, "benchmark", "families", "mla_moe.py"))
-    family = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(family)
-    mc = {"n_heads": 2, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 16,
-          "kv_lora_rank": 8, "rms_eps": 1e-6, "top_k": K, "expert_first": 0,
-          "n_experts_held": E, "norm_topk_prob": True, "route_scale": 2.5,
-          "rope_theta": 10000.0, "rope_factor": 4.0, "rope_beta_fast": 32.0,
-          "rope_beta_slow": 1.0, "rope_original_max": 32,
-          "rope_mscale_all_dim": 1.0, "llama4_beta": 0.1}
-    *_, gates, add_experts, _head = family._fns(tuple(sorted(mc.items())))
+    gates, add_experts = _reference_experts(family)
     with jax.default_matmul_precision("highest"):
         z, y, weight = gates(x, ln2, layer["router"], layer["bias"], layer["shared"])
         want = add_experts(y, z, weight, layer["w1"], layer["w3"], layer["w2"]) - x

@@ -81,6 +81,14 @@ FAMILIES = {
         # the delta rule's one-token step in the decode chunk, its chunked
         # form over the prompt's bucket in the prefill
         KV_PAGED | {"layer/gdn/step"}, KV_DENSE | {"layer/gdn/chunk"}),
+    "kda_moe_lm": (
+        "solar-open2-250b",
+        {"layer/attn", "layer/kda", "layer/kda/proj", "layer/kda/conv",
+         "layer/kda/gate", "layer/kv_write", "layer/ffn", "layer/ffn/shared"}
+        | EXPERTS,
+        # as Olmo-Hybrid's: the rule's step in the chunk, its chunked form
+        # (a decay a channel: the block form, no kernel yet) in the prefill
+        KV_PAGED | {"layer/kda/step"}, KV_DENSE | {"layer/kda/chunk"}),
 }
 # every path a case names: a program must carry its own and none of the others
 VOCABULARY = set().union(*(both | paged | dense
@@ -131,13 +139,16 @@ def _scoped(lowered):
     return lambda path: any("/" + path + "/" in loc for loc in locs)
 
 
-def _layer_paths(lowered) -> set:
+def _layer_paths(lowered, through_loops: bool = False) -> set:
     """The paths of ``VOCABULARY`` that some operation of the program lies
     under, read FROM ``layer`` on (``layer/kv_read`` is not found inside
-    ``layer/attn/kv_read``)."""
+    ``layer/attn/kv_read``). ``through_loops``: a scope opened inside a loop's
+    body counts under the scope the loop lies in (``layer/kda/while/body/proj``
+    is ``layer/kda/proj``)."""
     found = set()
     for name in _locations(lowered):
-        parts = name.split("/")
+        parts = [p for p in name.split("/")
+                 if not (through_loops and p in ("while", "body", "closed_call"))]
         if "layer" in parts:
             at = parts.index("layer")
             found.update("/".join(parts[at:end])
@@ -206,6 +217,9 @@ LOOPED = {
     "sambay_lm": {"layer/ffn"},
     "olmo_hybrid_lm": {"layer/attn", "layer/ffn", "layer/gdn/proj",
                        "layer/gdn/conv", "layer/gdn/gate"},
+    # the WHOLE mixer of a linear layer a block of tokens at a time (one loop
+    # under ``layer/kda``, its stages inside a trip)
+    "kda_moe_lm": {"layer/attn", "layer/kda", "layer/ffn/shared"},
 }
 
 
@@ -236,7 +250,7 @@ def test_long_slot_prefill_loops_inside_its_stage_scopes(family_case,
     generation._slot_prefill_jit.clear_cache()
     looped = _slot_prefill(mdef, cfg_key, params)
     generation._slot_prefill_jit.clear_cache()
-    assert _layer_paths(looped) == _layer_paths(whole) == both | dense
+    assert _layer_paths(looped, True) == _layer_paths(whole) == both | dense
     assert _loop_scopes(looped) - _loop_scopes(whole) == LOOPED[case]
     # the decode chunk never loops a stage: one token a lane
     generation._paged_decode_chunk_jit.clear_cache()

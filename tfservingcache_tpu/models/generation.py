@@ -1569,7 +1569,9 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
     every other layer is projected here (differential, or rotary by the
     layer's kind), its rows written by the store (unless they are another
     layer's, ``SharedRows``: written when that one ran), its queries attended
-    by the store, and the heads finished here. A config whose ``rope_theta``
+    by the store, and the heads finished here (times ``sigmoid`` of a
+    projection of the layer's normed input where ``attn`` holds ``w_gate``:
+    an output gate). A config whose ``rope_theta``
     is None applies no rotary. The two token-wise halves written here (norm,
     projection and rotary before the store; ``wo`` and a following norm after
     it) run over the row blocks that hold the ``rows.took`` real rows
@@ -1599,16 +1601,20 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
             rope, scale = rope_of(cfg, slot.window), None
 
             def project(x, positions):
-                q, k, v = _qkv(attn, normed(x), cfg["n_heads"],
-                               cfg["n_kv_heads"], *eps)
+                a = normed(x)
+                q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"], *eps)
                 if cfg["rope_theta"] is not None:     # None: no rotary at all
                     q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
                     k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
-                return q, k, v
+                if "w_gate" not in attn:
+                    return q, k, v, None
+                # an output gate a head column, laid out as the queries are
+                gate = (a @ attn["w_gate"]).reshape(b, -1, *q.shape[1::2])
+                return q, k, v, jax.nn.sigmoid(gate).transpose(0, 2, 1, 3)
 
             # the heads' rows lie along axis 2: (B, heads, T, width)
-            q, k, v = over_real_rows(project, (x, rows.positions), rows.took,
-                                     out_axis=2)
+            q, k, v, gate = over_real_rows(
+                project, (x, rows.positions), rows.took, out_axis=2)
     if not shared:
         rows.write(slot, k, v)
     with jax.named_scope("attn"), _kind_scope(
@@ -1619,14 +1625,19 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
         if differential:
             return x + diff_finish(attn, diff_outputs(out), depth, x.dtype)
 
-        def finish(out):
-            out = out.astype(x.dtype).transpose(0, 2, 1, 3)
+        def finish(out, gate=None):
+            out = out.astype(x.dtype)
+            if gate is not None:          # a layer that holds ``w_gate``
+                out = out * gate
+            out = out.transpose(0, 2, 1, 3)
             # heads x head width: the hidden size for most models
             out = out.reshape(b, out.shape[1], -1) @ attn["wo"]
             return (_norm(layer, "ln1_post", out, _norm_eps(cfg)) if after
                     else out)
 
-        return x + over_real_rows(finish, (out,), rows.took, in_axis=2)
+        return x + over_real_rows(
+            finish, (out,) if gate is None else (out, gate), rows.took,
+            in_axis=2)
 
 
 def _forward_cached_dyn(params, input_ids, cache, start_pos, cfg,

@@ -81,6 +81,12 @@ DEFAULT_CONFIG: dict[str, Any] = {
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
+# From this many bytes of routed rows on (every assignment's float32 answer
+# before the gates sum them) a prefill's expert layer runs a block of rows at
+# a time; every accepted cell's longest bucket stays below (Mistral-4's 8192
+# x 4 x 4096 are half of it) and keeps its one call.
+EXPERT_ROWS_BYTES = 1 << 30
+
 
 def layer_state_of(cfg: dict) -> tuple:
     """What each layer keeps of a request, from ``layer_types``: the K/V row,
@@ -106,7 +112,11 @@ def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
                partitioned: bool = False, took=None) -> tuple[jax.Array, dict]:
     """The expert half of a layer over the residual stream ``x (B, S, D)``
     BEFORE its norm -> (residual delta, the layer's routing stats).
-    ``row_mask (B*S,)`` marks rows whose answer nobody reads. What the config
+    ``row_mask (B*S,)`` marks rows whose answer nobody reads. A prefill whose
+    routed rows would fill ``EXPERT_ROWS_BYTES`` (``B x S x top_k`` float32
+    rows of the model's width: 2.1 GB at a 16384-token bucket of 8 experts a
+    token at 4096) takes them a block of real rows at a time
+    (``over_real_rows``) and hands back no stats: its caller reads none. What the config
     may add to the plain top-k layer: the router's ``route_score`` /
     ``route_scale`` / ``route_norm_eps``, the chip's share ``n_experts_held`` experts from
     ``expert_first`` (``ops.moe.moe_experts``' ``held``), and, where the layer
@@ -120,12 +130,27 @@ def _moe_block(layer: dict, x: jax.Array, cfg: dict, dtype, row_mask=None,
     held = ((int(cfg.get("expert_first", 0)), int(cfg["n_experts_held"]))
             if "n_experts_held" in cfg else None)
     z = _rmsnorm(x, layer["ln2"], cfg.get("rms_eps", 1e-5)).reshape(b * s, d)
-    y, stats = moe_experts(
-        z, moe, int(cfg["top_k"]),
-        norm_topk=bool(cfg["norm_topk_prob"]), row_mask=row_mask,
-        partitioned=partitioned, score=cfg.get("route_score", "softmax"),
-        route_scale=float(cfg.get("route_scale", 1.0)), held=held,
-        norm_eps=float(cfg.get("route_norm_eps", 0.0)))
+
+    def routed(z, row_mask):
+        return moe_experts(
+            z, moe, int(cfg["top_k"]),
+            norm_topk=bool(cfg["norm_topk_prob"]), row_mask=row_mask,
+            partitioned=partitioned, score=cfg.get("route_score", "softmax"),
+            route_scale=float(cfg.get("route_scale", 1.0)), held=held,
+            norm_eps=float(cfg.get("route_norm_eps", 0.0)))
+
+    if (took is not None and row_block(s)
+            and b * s * int(cfg["top_k"]) * d * 4 >= EXPERT_ROWS_BYTES):
+        # a row's experts are its own: the real rows a block at a time
+        def block(z, *mask):
+            y, _ = routed(z.reshape(-1, d), mask[0].reshape(-1) if mask else None)
+            return y.reshape(z.shape)
+
+        masks = () if row_mask is None else (row_mask.reshape(b, s),)
+        y = over_real_rows(block, (z.reshape(b, s, d), *masks), took)
+        y, stats = y.reshape(b * s, d), None
+    else:
+        y, stats = routed(z, row_mask)
     if "shared" in layer["moe"]:
         with jax.named_scope("shared"):
             sh = jax.tree_util.tree_map(lambda w: w.astype(dtype),

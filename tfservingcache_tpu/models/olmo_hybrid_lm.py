@@ -105,6 +105,34 @@ def _l2norm(x: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
 
 
+def conv_heads(rows: jax.Array, w: jax.Array, h: int, d_k: int, d_v: int,
+               dtype):
+    """The causal depthwise convolution and silu over ``rows (B, taps - 1 + T,
+    2 H d_k + H d_v)``, the inputs ``[q' | k' | v']`` with their ``taps - 1``
+    leading rows, under the float32 taps ``w (W, taps)`` -> ``q`` / ``k (B, T,
+    H, d_k)`` L2-normalised a head (``q`` also scaled by ``1 / sqrt(d_k)``)
+    and ``v (B, T, H, d_v)``, in ``dtype``."""
+    f32 = jnp.float32
+    b, taps = rows.shape[0], w.shape[-1]
+    t = rows.shape[1] - (taps - 1)
+
+    def mixed(first: int, width: int):
+        """The taps and silu over ``width`` columns from ``first``: a part at
+        a time, so that no float32 array of all ``W`` columns exists (0.75 GB
+        at a 16384-token bucket of Olmo-Hybrid's 11,520)."""
+        cols = slice(first, first + width)
+        return jax.nn.silu(sum(
+            w[cols, j] * rows[:, j:j + t, cols].astype(f32)
+            for j in range(taps)))
+
+    q = mixed(0, h * d_k).reshape(b, t, h, d_k)
+    k = mixed(h * d_k, h * d_k).reshape(b, t, h, d_k)
+    q = (_l2norm(q) * d_k ** -0.5).astype(dtype)
+    k = _l2norm(k).astype(dtype)
+    v = mixed(2 * h * d_k, h * d_v).astype(dtype).reshape(b, t, h, d_v)
+    return q, k, v
+
+
 def _mixer_inputs(layer: dict, x: jax.Array, conv, cfg: dict, took=None):
     """What the rule takes of the tokens ``x (B, T, d)`` and the lanes' last
     convolution inputs ``conv (B, taps - 1, W)``: ``(q, k, v, alpha, beta, z,
@@ -135,26 +163,9 @@ def _mixer_inputs(layer: dict, x: jax.Array, conv, cfg: dict, took=None):
         rows = jnp.concatenate([conv.astype(dtype), qkv], axis=1)
         w = gdn["conv_w"].astype(f32)                          # (W, taps)
 
-        def convolve(rows):
-            t = rows.shape[1] - (taps - 1)
-
-            def mixed(first: int, width: int):
-                """The taps and silu over ``width`` columns from ``first``: a
-                part at a time, so that no float32 array of all ``W`` columns
-                exists (0.75 GB at a 16384-token bucket)."""
-                cols = slice(first, first + width)
-                return jax.nn.silu(sum(
-                    w[cols, j] * rows[:, j:j + t, cols].astype(f32)
-                    for j in range(taps)))
-
-            q = mixed(0, h * d_k).reshape(b, t, h, d_k)
-            k = mixed(h * d_k, h * d_k).reshape(b, t, h, d_k)
-            q = (_l2norm(q) * d_k ** -0.5).astype(dtype)
-            k = _l2norm(k).astype(dtype)
-            v = mixed(2 * h * d_k, h * d_v).astype(dtype).reshape(b, t, h, d_v)
-            return q, k, v
-
-        q, k, v = over_real_rows(convolve, (rows,), took, halo=taps - 1)
+        q, k, v = over_real_rows(
+            lambda rows: conv_heads(rows, w, h, d_k, d_v, dtype), (rows,), took,
+            halo=taps - 1)
     with jax.named_scope("gate"):
         # from the leaves as they are stored, not through the compute dtype
         alpha = jnp.exp(-jnp.exp(layer["gdn"]["a_log"].astype(f32))

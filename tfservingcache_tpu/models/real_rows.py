@@ -38,7 +38,7 @@ def row_block(t: int) -> int:
     return block if t % ROW_BLOCKS == 0 and block >= MIN_BLOCK_ROWS else 0
 
 
-def _blocks(longest, block: int):
+def real_blocks(longest, block: int):
     """Blocks that hold a real row where the longest example has ``longest``.
     Operators only: the device (a traced scalar) and the host
     (``rows_computed``) run this line."""
@@ -50,7 +50,7 @@ def rows_computed(real: int, bucket: int) -> int:
     prompt has ``real`` tokens, worked out on the host as the device works out
     its trip count (``tpusc_prefill_rows_total{kind="computed"}``)."""
     block = row_block(bucket)
-    return int(_blocks(int(real), block)) * block if block else int(bucket)
+    return int(real_blocks(int(real), block)) * block if block else int(bucket)
 
 
 def over_real_rows(fn, xs: tuple, took, *, in_axis: int = 1,
@@ -65,7 +65,7 @@ def over_real_rows(fn, xs: tuple, took, *, in_axis: int = 1,
     ``halo`` leading rows) hands operands ``halo`` rows longer than its
     outputs.
 
-    A ``lax.fori_loop`` whose bound the device computes (``_blocks`` of the
+    A ``lax.fori_loop`` whose bound the device computes (``real_blocks`` of the
     longest example): each trip slices a block of every operand, runs ``fn``
     on it and sets the block of every output, in place on the carry; ``fn`` is
     traced once for the body. Whatever ``fn`` closes over (weights, already
@@ -95,4 +95,4 @@ def over_real_rows(fn, xs: tuple, took, *, in_axis: int = 1,
                 out, part.astype(out.dtype), start, out_axis),
             outs, fn(*take(start)))
 
-    return jax.lax.fori_loop(0, _blocks(jnp.max(took), block), trip, outs)
+    return jax.lax.fori_loop(0, real_blocks(jnp.max(took), block), trip, outs)

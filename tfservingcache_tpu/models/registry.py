@@ -348,7 +348,7 @@ def build(family: str, config: dict[str, Any] | None = None) -> ModelDef:
 
 _BUILTIN_MODULES = (
     "half_plus_two", "mnist_cnn", "bert", "resnet", "transformer_lm", "t5", "moe_lm",
-    "mla_moe_lm", "hybrid_lm", "sambay_lm", "olmo_hybrid_lm",
+    "mla_moe_lm", "hybrid_lm", "sambay_lm", "olmo_hybrid_lm", "kda_moe_lm",
 )
 
 

@@ -47,6 +47,23 @@ Two forms of the one recurrence:
   1`` and ``beta = 0`` make a token the identity. ``g_t / g_i`` is formed as
   ``exp(log g_t - log g_i)`` with ``i <= t`` only, so it never exceeds 1.
 
+A decay a CHANNEL (Kimi Delta Attention, arXiv:2510.26692): ``alpha_t`` is a
+vector over ``d_k``, ``S <- diag(alpha_t) S`` scales the state's ROWS, and
+every form takes ``alpha`` with that one more axis (``(..., H, d_k)`` where a
+decay a head is ``(..., H)``). In a chunk the decays no longer factor out of
+``K K^T`` and ``Q K^T``: with ``G_t (d_k)`` the running sum of ``log alpha``::
+
+    A[t, i] = beta_t sum_c k_t[c] k_i[c] exp(G_t[c] - G_i[c])      for i < t
+
+and ``exp(-G)`` alone overflows, so ``_chunked_block_channel`` forms the
+products in sub-blocks of ``SUB`` tokens with no exponent above 0: a pair ``(t,
+i)`` in ONE sub-block takes ``exp(G_t - G_i)`` directly, summed over the
+channel; a pair in two takes the later sub-block's first row ``r`` as its
+reference, ``(k_t exp(G_t - G_r)) . (k_i exp(G_r - G_i))``, a matrix product
+of two factors <= 1. ``W = (I + A)^-1 diag(beta) (K exp(G))``, ``O = (Q
+exp(G)) S_0 + P U`` with ``P`` the same products of ``Q`` and ``K`` for ``i <=
+t``, ``S_C = diag(exp(G_C)) S_0 + (K exp(G_C - G))^T U``.
+
 Which chunked form runs where (``_kernel_refusal`` decides from the backend
 and the shapes when the program is traced; ``ops.attention.dispatch_tally()``
 records it under ``delta_chunked``):
@@ -67,7 +84,9 @@ records it under ``delta_chunked``):
   ``_chunked_block`` in plain ``jax.numpy``, the reference the tests hold the
   kernel to: the inverse by ``_unit_lower_inverse`` for all of a block's
   chunks at once, a ``lax.scan`` over the chunks, a prompt longer than
-  ``BLOCK`` tokens a block at a time.
+  ``BLOCK`` tokens a block at a time. A decay a channel takes
+  ``_chunked_block_channel`` everywhere: the kernel is written for a decay a
+  head and refuses the other by name.
 """
 
 from __future__ import annotations
@@ -126,13 +145,17 @@ def _over_columns(x, d_v: int):
 def _advance(state, q, k, v, alpha, beta):
     """One token a row, every row real: ``state (B, d_k, H x d_v)`` float32,
     ``q`` / ``k (B, H, d_k)``, ``v (B, H, d_v)``, ``alpha`` / ``beta (B, H)``
-    -> (``o (B, H x d_v)`` float32, the state after), all in the state's own
-    layout: nothing of the state's size is reshaped."""
+    (``alpha (B, H, d_k)``: a decay a channel, the state's rows each their
+    own) -> (``o (B, H x d_v)`` float32, the state after), all in the state's
+    own layout: nothing of the state's size is reshaped."""
     f32 = jnp.float32
     d_v = v.shape[-1]
     k_col = _over_columns(k.transpose(0, 2, 1), d_v)            # (B, d_k, H d_v)
     q_col = _over_columns(q.transpose(0, 2, 1), d_v)
-    state = _over_columns(alpha.astype(f32), d_v)[:, None, :] * state
+    if alpha.ndim == k.ndim:            # a channel: (B, d_k, H) over columns
+        state = _over_columns(alpha.astype(f32).transpose(0, 2, 1), d_v) * state
+    else:
+        state = _over_columns(alpha.astype(f32), d_v)[:, None, :] * state
     u = _over_columns(beta.astype(f32), d_v) * (
         v.astype(f32).reshape(v.shape[0], -1) - jnp.sum(k_col * state, axis=1))
     state = state + k_col * u[:, None, :]
@@ -212,7 +235,8 @@ def delta_step_live(states, layer: int, q, k, v, alpha, beta, took=None,
 def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK,
                   block: int = BLOCK):
     """``T`` tokens a row: ``state (B, d_k, H x d_v)`` float32 in, ``q`` / ``k
-    (B, T, H, d_k)``, ``v (B, T, H, d_v)``, ``alpha`` / ``beta (B, T, H)`` ->
+    (B, T, H, d_k)``, ``v (B, T, H, d_v)``, ``alpha`` / ``beta (B, T, H)``
+    (``alpha (B, T, H, d_k)``: a decay a channel) ->
     (``o (B, T, H, d_v)`` float32, the state after ``real_len (B,)`` of the
     tokens; None = all ``T``). ``o`` past ``real_len`` is junk nobody reads.
     ``T`` need not be a multiple of ``chunk``: the tail is padded with
@@ -224,12 +248,14 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
     block only."""
     f32 = jnp.float32
     b, t_len, h, _ = k.shape
+    channel = alpha.ndim == k.ndim          # a decay a channel
     if real_len is not None:
         real = (jnp.arange(t_len)[None, :]
                 < real_len.astype(jnp.int32)[:, None])[..., None]   # (B, T, 1)
-        alpha = jnp.where(real, alpha.astype(f32), 1.0)
+        alpha = jnp.where(real[..., None] if channel else real,
+                          alpha.astype(f32), 1.0)
         beta = jnp.where(real, beta.astype(f32), 0.0)
-    why = _kernel_refusal(state, k, v, chunk)
+    why = _kernel_refusal(state, k, v, chunk, channel)
     _record_dispatch(
         "delta_chunked", "reference" if why else "kernel",
         why or ("interpret" if DELTA_KERNEL_INTERPRET else "pallas"),
@@ -238,7 +264,8 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
     if pad:
         widths = ((0, 0), (0, pad))
         q, k, v = (jnp.pad(a, widths + ((0, 0), (0, 0))) for a in (q, k, v))
-        alpha = jnp.pad(alpha, widths + ((0, 0),), constant_values=1.0)
+        alpha = jnp.pad(alpha, widths + ((0, 0),) * (alpha.ndim - 2),
+                        constant_values=1.0)
         beta = jnp.pad(beta, widths + ((0, 0),))
     padded = t_len + pad
     if why is None:
@@ -251,8 +278,9 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
             beta.astype(f32), real_len, chunk, interpret=bool(DELTA_KERNEL_INTERPRET))
         return o.reshape(b, padded, h, -1)[:, :t_len], s
     s = _heads(state.astype(f32), h)
+    chunked_block = _chunked_block_channel if channel else _chunked_block
     if padded <= block:
-        o, s = _chunked_block(s, q, k, v, alpha, beta, chunk)
+        o, s = chunked_block(s, q, k, v, alpha, beta, chunk)
     else:
         def blocks(a):
             """``(B, T, ...)`` -> ``(T / block, B, block, ...)``."""
@@ -260,7 +288,7 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
                 a.reshape(b, padded // block, block, *a.shape[2:]), 1, 0)
 
         def one(s, rows):
-            o, s = _chunked_block(s, *rows, chunk)
+            o, s = chunked_block(s, *rows, chunk)
             return s, o
 
         s, o = jax.lax.scan(one, s, tuple(map(blocks, (q, k, v, alpha, beta))))
@@ -362,6 +390,104 @@ def _chunked_block(s, q, k, v, alpha, beta, chunk: int):
     return o.reshape(b, t_len, h, d_v), s
 
 
+# Tokens a sub-block of a chunk where the decay is a channel's: inside one the
+# decays between two tokens are taken directly, between two through the later
+# one's first row (``_chunked_block_channel``).
+SUB = 16
+# The least ``log alpha`` the chunked form reckons with: a decay that
+# underflowed to 0 would give ``G = -inf`` and ``exp(G_t - G_i)`` a NaN; at
+# e^-80 a row of the state is gone all the same (float32's least normal value
+# is e^-87).
+LOG_DECAY_FLOOR = -80.0
+
+
+def _decayed_products(x, k, log_g, sub: int, precision=None):
+    """``P[t, i] = sum_c x_t[c] k_i[c] exp(G_t[c] - G_i[c])`` for ``i <= t``
+    inside each chunk, 0 above the diagonal: ``x`` / ``k`` / ``log_g (..., C,
+    d_k)`` float32 (``log_g`` the running log-decay a channel) -> ``(..., C,
+    C)``. No exponent is above 0: the diagonal sub-blocks of ``sub`` tokens
+    take ``exp(G_t - G_i)`` itself, multiply-and-sum over the channel; the
+    sub-blocks below them a matrix product of ``x_t exp(G_t - G_r)`` and ``k_i
+    exp(G_r - G_i)`` with ``r`` the later sub-block's first row."""
+    f32 = jnp.float32
+    *lead, c, d_k = k.shape
+    n = c // sub
+    cut = lambda a: a.reshape(*lead, n, sub, d_k)                # noqa: E731
+    xs, ks, gs = cut(x), cut(k), cut(log_g)
+    within = jnp.tril(jnp.ones((sub, sub), bool))[..., None]     # i <= t
+    direct = jnp.sum(
+        xs[..., :, None, :] * ks[..., None, :, :] * jnp.exp(jnp.where(
+            within, gs[..., :, None, :] - gs[..., None, :, :], -jnp.inf)),
+        axis=-1)                                                 # (..., n, sub, sub)
+    rows = []
+    for a in range(n):
+        ref = gs[..., a, :1, :]                                  # G_r (..., 1, d_k)
+        parts = []
+        if a:
+            earlier = slice(0, a * sub)
+            parts.append(jnp.einsum(
+                "...td,...id->...ti", xs[..., a, :, :] * jnp.exp(gs[..., a, :, :] - ref),
+                k[..., earlier, :] * jnp.exp(ref - log_g[..., earlier, :]),
+                preferred_element_type=f32, precision=precision))
+        parts.append(direct[..., a, :, :])
+        if a + 1 < n:
+            parts.append(jnp.zeros((*lead, sub, c - (a + 1) * sub), f32))
+        rows.append(jnp.concatenate(parts, axis=-1))
+    return jnp.concatenate(rows, axis=-2)
+
+
+def _chunked_block_channel(s, q, k, v, alpha, beta, chunk: int):
+    """``_chunked_block`` for a decay a channel, ``alpha (B, T, H, d_k)``: the
+    same state ``s (B, H, d_k, d_v)`` float32 in and out and the same
+    operands -> (``o (B, T, H, d_v)`` float32, the state after). The decays
+    lie INSIDE the intra-chunk products (``_decayed_products``), every other
+    factor is ``exp`` of a running log-decay (<= 0) or of the chunk's end
+    against a token's (<= 0)."""
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    b, t_len, h, d_k = k.shape
+    d_v = v.shape[-1]
+    q, k, v, alpha, beta = (t.astype(f32) for t in (q, k, v, alpha, beta))
+    n = t_len // chunk
+    sub = SUB if chunk % SUB == 0 else chunk
+
+    def chunks(a):
+        """``(B, T, H, ...)`` -> ``(n, B, H, chunk, ...)``."""
+        a = a.reshape(b, n, chunk, h, *a.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)                   # (n, B, H, C, d)
+    log_g = jnp.cumsum(jnp.maximum(jnp.log(chunks(alpha)), LOG_DECAY_FLOOR),
+                       axis=-2)                                 # (n, B, H, C, d_k)
+    beta = chunks(beta)[..., None]                              # (n, B, H, C, 1)
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    # the products the inverse is made of at HIGHEST: the pseudo-values
+    # inherit their error
+    a_mat = beta * jnp.where(strict, _decayed_products(k, k, log_g, sub, hi), 0.0)
+    g = jnp.exp(log_g)                                          # <= 1
+    solved = jnp.einsum(
+        "...ti,...id->...td", _unit_lower_inverse(a_mat),
+        jnp.concatenate([beta * g * k, beta * v], axis=-1), precision=hi)
+    w, u0 = solved[..., :d_k], solved[..., d_k:]                # (n, B, H, C, d)
+    qk = _decayed_products(q, k, log_g, sub)
+    g_end = log_g[..., -1:, :]                                  # G_C (n, B, H, 1, d_k)
+    k_out = jnp.exp(g_end - log_g) * k                          # K exp(G_C - G)
+    g_last = jnp.exp(jnp.swapaxes(g_end, -1, -2))               # (n, B, H, d_k, 1)
+
+    def carry(s, rows):
+        w_c, u0_c, qg_c, qk_c, k_c, g_end = rows
+        u = u0_c - jnp.einsum("bhtk,bhkv->bhtv", w_c, s, preferred_element_type=f32)
+        o = (jnp.einsum("bhtk,bhkv->bhtv", qg_c, s, preferred_element_type=f32)
+             + jnp.einsum("bhti,bhiv->bhtv", qk_c, u, preferred_element_type=f32))
+        s = g_end * s + jnp.einsum("bhtk,bhtv->bhkv", k_c, u,
+                                   preferred_element_type=f32)
+        return s, o
+
+    s, o = jax.lax.scan(carry, s, (w, u0, g * q, qk, k_out, g_last))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)               # (B, n, C, H, d_v)
+    return o.reshape(b, t_len, h, d_v), s
+
+
 # -- the chunked form as one Pallas kernel --------------------------------------
 
 # Tests flip this to run the kernel through its interpreter on the CPU
@@ -375,14 +501,18 @@ DELTA_KERNEL_INTERPRET = False
 VMEM_LIMIT = 64 << 20
 
 
-def _kernel_refusal(state, k, v, chunk: int) -> str | None:
+def _kernel_refusal(state, k, v, chunk: int, channel: bool = False) -> str | None:
     """Why ``delta_chunk_kernel`` cannot take these operands on this backend
-    (None = it can): the TPU (one chip: the family binds no mesh), bfloat16
+    (None = it can): a decay a head (``channel``: a decay a channel, whose
+    running log-decay is ``(T, H, d_k)`` where the kernel reads ``(T, H)``),
+    the TPU (one chip: the family binds no mesh), bfloat16
     operands, heads in pairs whose value columns are whole 128-lane rows (so
     a pair's slice of the state is cut where the rows are), key widths of
     whole sublane tiles, a chunk of whole bfloat16 tiles that is a power of
     two, and the lane's state five times within half the kernel's VMEM."""
     h, d_k, d_v = k.shape[2], k.shape[3], v.shape[3]
+    if channel:
+        return f"a decay a channel (d_k={d_k}): the kernel takes a decay a head"
     if not DELTA_KERNEL_INTERPRET:
         if jax.default_backend() != "tpu":
             return f"backend={jax.default_backend()}"

@@ -88,6 +88,11 @@ def main() -> int:
     # state): ``delta_step`` over 16 lanes and ``delta_chunked`` over a bucket
     # of 2048 (``real_len`` 1500) against the step iterated: largest errors
     # of the output and of the state, `-k "delta_rule_on_tpu"`, ~1 min.
+    # test_kda_moe_lm.py carries the same rule with a decay a CHANNEL at
+    # Solar-Open2's widths (64 heads of 128 / 128): the live-lane step on 8 of
+    # 32 lanes (us a lane a layer beside the 8.5 MB a lane the state's bytes
+    # allow) and the block form over a bucket of 2048 against the step
+    # iterated, decays down to 0.05 a step: `-k "kda_rule_on_tpu"`, ~1 min.
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
@@ -97,6 +102,7 @@ def main() -> int:
         os.path.join(REPO, "tests", "test_window_layers.py"),
         os.path.join(REPO, "tests", "test_sambay_lm.py"),
         os.path.join(REPO, "tests", "test_olmo_hybrid_lm.py"),
+        os.path.join(REPO, "tests", "test_kda_moe_lm.py"),
         "-v", "-rs", "-s", "--no-header",
         *extra,
     ]
