@@ -366,7 +366,14 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     # PR 40 the share of chunks launched ahead, PR 41 its five and its cell,
     # PR 46 its four and its cell, PR 47 the chunked rule's kernel's share,
     # PR 48 the rows a prefill computes a real row (the seven generating cells),
-    # PR 49 its Kimi-delta-attention layers' three and its cell
+    # PR 49 its Kimi-delta-attention layers' three and its cell, PR 50 the
+    # channel-decay chunked rule's kernel's share (the twin of PR 47's)
+    twin = bench_json["per_layer"].pop()
+    assert twin["name"] == "kda_chunk_roofline"
+    assert twin["workloads"] == ["solaropen2-docreport-steady"]
+    assert (twin["unit"], twin["better"], twin["source"], twin["moves"]) == (
+        "%", "higher", "device_trace", "tpot_p50_ms")
+    assert os.path.exists(os.path.join(BENCH, "layer_metrics", twin["name"] + ".py"))
     kda = bench_json["per_layer"][-3:]
     assert [m["name"] for m in kda] == [
         "kda_layers_ms_per_step", "kda_prefill_ms_per_ktok", "kda_step_roofline"]
@@ -399,7 +406,7 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
              "phi4flash-reasoning-steady", "olmohybrid-longdoc-steady",
              "solaropen2-docreport-steady"]
     layers = {m["layer"] for m in bench_json["per_layer"][:-23]}
-    assert {m["layer"] for m in kda} <= layers
+    assert {m["layer"] for m in kda} | {twin["layer"]} <= layers
     assert last["layer"] in layers
     rows = bench_json["per_layer"][-1]
     assert rows["workloads"] == cells and rows["layer"] in layers

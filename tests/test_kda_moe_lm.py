@@ -11,7 +11,7 @@ published RATIOS (one full layer then three linear, ``d_k = d_v``, GQA group
       step iterated (``T`` 64 / 200 / 2048 / 4160, ``real_len`` None / 0 /
       mid-chunk / ``T``, a nonzero incoming state, decays down to 1e-3 a step,
       where a naive ``exp(-G)`` overflows); a row that took nothing keeps its
-      state bit for bit; the kernel's gate refuses the decay by name;
+      state bit for bit; the kernel's gate gives the decay its own kernel (ISSUE 50);
   (c) prefill, then decode through the ENGINE's programs (``_slot_prefill_jit``,
       ``_paged_insert_jit``, ``_lane_insert_jit``, ``_paged_forward_step`` /
       ``_paged_decode_chunk_jit``), logits against the reference's full forward
@@ -323,25 +323,33 @@ def test_b_the_live_lane_step_takes_a_decay_a_channel():
             == np.asarray(states)[1][~took].tobytes())
 
 
-def test_b_the_kernels_gate_refuses_a_decay_a_channel_by_name(monkeypatch):
-    """Even where every other condition holds (the interpreter's flag, the
-    kernel's widths), and the tally says which form ran."""
+def test_b_the_kernels_gate_gives_a_decay_a_channel_its_kernel(monkeypatch):
+    """Where every condition holds (the interpreter's flag, the kernel's
+    widths) a decay a channel is no longer refused by name (ISSUE 50): it
+    takes ``delta_channel_chunk_kernel``, the tally says which form ran, and
+    it is the block form's result."""
     monkeypatch.setattr(delta_rule, "DELTA_KERNEL_INTERPRET", True)
-    state = jnp.zeros((1, 16, 2 * 64), jnp.float32)
-    k = jnp.zeros((1, 64, 2, 16), jnp.bfloat16)
-    v = jnp.zeros((1, 64, 2, 64), jnp.bfloat16)
+    rng = np.random.default_rng(4)
+    state = jnp.asarray(rng.standard_normal((1, 16, 2 * 64)), jnp.float32)
+    q, k = (jnp.asarray(rng.standard_normal((1, 64, 2, 16)) / 4, jnp.float32)
+            for _ in "qk")
+    v = jnp.asarray(rng.standard_normal((1, 64, 2, 64)), jnp.float32)
+    alpha = jnp.asarray(rng.uniform(0.05, 1.0, (1, 64, 2, 16)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 2.0, (1, 64, 2)), jnp.float32)
     assert delta_rule._kernel_refusal(state, k, v, 64) is None
-    why = delta_rule._kernel_refusal(state, k, v, 64, True)
-    assert why and "a decay a channel" in why
+    assert delta_rule._kernel_refusal(state, k, v, 64, True) is None
     before = {key: n for key, n in dispatch_tally().items()
               if key[0] == "delta_chunked"}
-    delta_rule.delta_chunked(state, k, k, v, jnp.ones((1, 64, 2, 16)),
-                             jnp.ones((1, 64, 2)))
+    o, s = delta_rule.delta_chunked(state, q, k, v, alpha, beta)
     new = {key: n for key, n in dispatch_tally().items()
            if key[0] == "delta_chunked" and n != before.get(key)}
     assert len(new) == 1
     (key,) = new
-    assert key[1] == "reference" and "a decay a channel" in key[2]
+    assert key[1:3] == ("kernel", "interpret")
+    o_blk, s_blk = delta_rule._chunked_block_channel(
+        delta_rule._heads(state, 2), q, k, v, alpha, beta, 64)
+    np.testing.assert_allclose(o, o_blk, atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(s, delta_rule._flat(s_blk), atol=2e-5, rtol=1e-5)
 
 
 # -- (c) prefill, then decode through the arena and the state -------------------
@@ -711,8 +719,10 @@ def test_kda_rule_on_tpu(form):
     against the step iterated: ``delta_step_live`` on layer 1 of a three-layer
     array of 32 lanes of which 8 took a token (every other slice bit for bit;
     its time a live lane beside what the state's bytes allow), and
-    ``delta_chunked`` over one lane at a bucket of 2048 (``real_len`` 1500),
-    which takes the block form (the kernel refuses the decay by name)."""
+    ``delta_chunked`` over one lane at a bucket of 2048 (``real_len`` 1500)
+    and of 8192 (6000), through the kernel the chip is given
+    (``delta_channel_chunk_kernel``, ISSUE 50) and through the block form it is
+    held to: largest errors and ms a layer side by side."""
     from tfservingcache_tpu.utils.benchtime import chained_device_time
 
     h, d_k, d_v = 64, 128, 128
@@ -770,16 +780,35 @@ def test_kda_rule_on_tpu(form):
     want_o, want_s = _iterated(s0, bf(q), bf(k), bf(v), jnp.asarray(alpha),
                                jnp.asarray(beta), real)
     operands = (s0, bf(q), bf(k), bf(v), jnp.asarray(alpha), jnp.asarray(beta), real)
-    got_o, got_s = jax.jit(delta_rule.delta_chunked)(*operands)
     scale = float(np.std(np.asarray(want_o)[0, :n_real]))
-    err_o = float(np.max(np.abs(np.asarray(got_o)[0, :n_real]
-                                - np.asarray(want_o)[0, :n_real])))
-    err_s = float(np.max(np.abs(np.asarray(got_s) - np.asarray(want_s))))
-    ms = 1e3 * chained_device_time(
-        lambda alpha, s, q, k, v, beta, real: sum(
-            jnp.sum(x) for x in delta_rule.delta_chunked(s, q, k, v, alpha, beta, real)),
-        (operands[4], *operands[:4], *operands[5:]), iters=4)
-    print(f"delta_chunked[{t}, real {n_real}], a decay a channel (block form): "
-          f"out err {err_o:.3e} of std {scale:.3e}, state err {err_s:.3e}, "
-          f"{ms:.2f} ms = {ms * 1e3 / t:.2f} us a bucket token a layer", flush=True)
-    assert err_o < 0.05 * scale + 1e-3 and err_s < 0.05
+
+    def timed(operands):
+        alpha, rest = operands[4], (*operands[:4], *operands[5:])
+        return 1e3 * chained_device_time(
+            lambda alpha, s, q, k, v, beta, real: sum(
+                jnp.sum(x) for x in delta_rule.delta_chunked(s, q, k, v, alpha, beta, real)),
+            (alpha, *rest), iters=4)
+
+    # the same numbers four times over, 6000 of 8192 real: the longer bucket
+    long = (s0, *(jnp.concatenate([a] * 4, axis=1) for a in operands[1:6]),
+            jnp.asarray([6000], jnp.int32))
+    for name in ("kernel", "block form"):
+        with pytest.MonkeyPatch.context() as m:
+            if name == "block form":
+                m.setattr(delta_rule, "_kernel_refusal", lambda *a: "the block form")
+            kernels = lambda: sum(n for key, n in dispatch_tally().items()  # noqa: E731
+                                  if key[:2] == ("delta_chunked", "kernel"))
+            before = kernels()
+            got_o, got_s = jax.jit(lambda *a: delta_rule.delta_chunked(*a))(*operands)
+            assert (kernels() > before) == (name == "kernel"), dispatch_tally()
+            err_o = float(np.max(np.abs(np.asarray(got_o)[0, :n_real]
+                                        - np.asarray(want_o)[0, :n_real])))
+            err_s = float(np.max(np.abs(np.asarray(got_s) - np.asarray(want_s))))
+            ms, ms_long = timed(operands), timed(long)
+        print(f"delta_chunked[{t}, real {n_real}], a decay a channel ({name}): "
+              f"out err {err_o:.3e} of std {scale:.3e}, state err {err_s:.3e}, "
+              f"{ms:.2f} ms = {ms * 1e3 / t:.2f} us a bucket token a layer; "
+              f"[{4 * t}, real 6000] {ms_long:.2f} ms = "
+              f"{ms_long * 1e3 / 6000:.2f} us a real token", flush=True)
+        assert np.isfinite(np.asarray(got_o)).all()
+        assert err_o < 0.05 * scale + 1e-3 and err_s < 0.05

@@ -80,13 +80,25 @@ records it under ``delta_chunked``):
   roundings as the block form on that chip: the inverse and the solve at
   float32's exactness, the products with the state on bfloat16 operands (what
   the matrix unit makes of a float32 ``dot``'s) into a float32 state.
+* the same chip and operands with a decay a CHANNEL:
+  ``delta_channel_chunk_kernel``, the other's twin (the same grid, the same
+  residence of the state, the same skipping) and not a mode of it: what it
+  adds is what a decay a head needs none of. It takes each token's OWN
+  log-decay ``(T, H x d_k)`` float32 beside the keys and forms the running sum
+  inside a chunk itself (a product with a triangle of ones, the decay's three
+  bfloat16 parts each exact); the diagonal sub-blocks' ``exp(G_t - G_i)`` a
+  column at a time on the vector unit, once for ``K K^T`` and ``Q K^T``, the
+  sub-blocks below them through the later one's first row as matrix products
+  (``K K^T``'s and ``W``'s at float32's exactness, ``_exact_dot_f32``); every
+  exponent clamped at 0. The state goes a HEAD at a time through its products
+  (the decays are its rows' own). The gate counts a chunk's wider operands
+  into the VMEM it allows.
 * everywhere else (the CPU, another width, float32 operands on the chip):
-  ``_chunked_block`` in plain ``jax.numpy``, the reference the tests hold the
-  kernel to: the inverse by ``_unit_lower_inverse`` for all of a block's
-  chunks at once, a ``lax.scan`` over the chunks, a prompt longer than
-  ``BLOCK`` tokens a block at a time. A decay a channel takes
-  ``_chunked_block_channel`` everywhere: the kernel is written for a decay a
-  head and refuses the other by name.
+  ``_chunked_block`` (a decay a head) / ``_chunked_block_channel`` (a decay a
+  channel) in plain ``jax.numpy``, the references the tests hold the kernels
+  to: the inverse by ``_unit_lower_inverse`` for all of a block's chunks at
+  once, a ``lax.scan`` over the chunks, a prompt longer than ``BLOCK`` tokens
+  a block at a time.
 """
 
 from __future__ import annotations
@@ -241,8 +253,9 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
     tokens; None = all ``T``). ``o`` past ``real_len`` is junk nobody reads.
     ``T`` need not be a multiple of ``chunk``: the tail is padded with
     identity tokens. Where ``_kernel_refusal`` has no objection (one TPU chip,
-    the widths the kernel takes) the chunks go through ``delta_chunk_kernel``;
-    elsewhere through ``_chunked_block``, a prompt of more than ``block``
+    the widths the kernel takes) the chunks go through ``delta_chunk_kernel``
+    (``delta_channel_chunk_kernel`` for a decay a channel); elsewhere through
+    ``_chunked_block`` (``_chunked_block_channel``), a prompt of more than ``block``
     tokens ``block`` tokens at a time, the state carried from one to the next,
     so that the float32 operands of the triangular systems exist for one
     block only."""
@@ -269,11 +282,17 @@ def delta_chunked(state, q, k, v, alpha, beta, real_len=None, chunk: int = CHUNK
         beta = jnp.pad(beta, widths + ((0, 0),))
     padded = t_len + pad
     if why is None:
-        log_g = jnp.cumsum(jnp.log(alpha.astype(f32)).reshape(
-            b, padded // chunk, chunk, h), axis=2).reshape(b, padded, h)
+        if channel:     # each token's own log-decay: the kernel sums a chunk's
+            kernel = delta_channel_chunk_kernel
+            log_g = jnp.maximum(jnp.log(alpha.astype(f32)),
+                                LOG_DECAY_FLOOR).reshape(b, padded, -1)
+        else:
+            kernel = delta_chunk_kernel
+            log_g = jnp.cumsum(jnp.log(alpha.astype(f32)).reshape(
+                b, padded // chunk, chunk, h), axis=2).reshape(b, padded, h)
         if real_len is None:
             real_len = jnp.full((b,), t_len, jnp.int32)
-        o, s = delta_chunk_kernel(
+        o, s = kernel(
             state, *(a.reshape(b, padded, -1) for a in (q, k, v)), log_g,
             beta.astype(f32), real_len, chunk, interpret=bool(DELTA_KERNEL_INTERPRET))
         return o.reshape(b, padded, h, -1)[:, :t_len], s
@@ -502,17 +521,19 @@ VMEM_LIMIT = 64 << 20
 
 
 def _kernel_refusal(state, k, v, chunk: int, channel: bool = False) -> str | None:
-    """Why ``delta_chunk_kernel`` cannot take these operands on this backend
-    (None = it can): a decay a head (``channel``: a decay a channel, whose
-    running log-decay is ``(T, H, d_k)`` where the kernel reads ``(T, H)``),
-    the TPU (one chip: the family binds no mesh), bfloat16
-    operands, heads in pairs whose value columns are whole 128-lane rows (so
-    a pair's slice of the state is cut where the rows are), key widths of
-    whole sublane tiles, a chunk of whole bfloat16 tiles that is a power of
-    two, and the lane's state five times within half the kernel's VMEM."""
+    """Why the chunked rule's kernel (``delta_chunk_kernel`` for a decay a
+    head; ``channel``: ``delta_channel_chunk_kernel`` for a decay a channel)
+    cannot take these operands on this backend (None = it can). The same
+    visible conditions for both: the TPU (one chip: the family binds no mesh),
+    bfloat16 operands, heads in pairs whose value columns are whole 128-lane
+    rows (so a pair's slice of the state is cut where the rows are), key
+    widths of whole sublane tiles, a chunk of whole bfloat16 tiles that is a
+    power of two, and the kernel's VMEM: the lane's state five times (in and
+    out, two pipeline buffers each, and the resident copy) within half of it,
+    and for a decay a channel, whose chunk also holds a float32 log-decay as
+    wide as the keys, the state and a chunk's operands (each twice in the
+    pipeline and once a pair first) within three quarters of it."""
     h, d_k, d_v = k.shape[2], k.shape[3], v.shape[3]
-    if channel:
-        return f"a decay a channel (d_k={d_k}): the kernel takes a decay a head"
     if not DELTA_KERNEL_INTERPRET:
         if jax.default_backend() != "tpu":
             return f"backend={jax.default_backend()}"
@@ -524,8 +545,15 @@ def _kernel_refusal(state, k, v, chunk: int, channel: bool = False) -> str | Non
         return f"d_k={d_k} no multiple of 16 or d_v={d_v} no multiple of 64"
     if chunk < 16 or chunk & (chunk - 1):
         return f"chunk={chunk} not a power of two of at least 16"
-    if 5 * state.shape[1] * state.shape[2] * 4 > VMEM_LIMIT // 2:
+    held = 5 * state.shape[1] * state.shape[2] * 4
+    if held > VMEM_LIMIT // 2:
         return f"a lane's state ({d_k} x {h * d_v} float32) past the VMEM budget"
+    if channel:
+        # q, k, log-decay (d_k wide) and v, o (d_v wide) of one chunk
+        operands = chunk * h * (d_k * (2 + 2 + 4) + d_v * (2 + 4))
+        if held + 3 * operands > VMEM_LIMIT * 3 // 4:
+            return (f"a lane's state and a chunk's operands ({h} heads of {d_k} / "
+                    f"{d_v}, a decay a channel) past the VMEM budget")
     return None
 
 
@@ -770,6 +798,27 @@ def _pair_rows(x, chunk: int):
     return x.transpose(0, 1, 3, 4, 2).reshape(b, t_len // chunk, h // 2, 2 * chunk)
 
 
+def _chunk_block_specs(chunk: int, d_k: int, width: int):
+    """The block specs of a chunked-rule kernel's grid (lane, chunk) with
+    ``real_len`` prefetched -> (``tokens(columns)``: a chunk of a ``(B, T,
+    columns)`` operand; ``vectors(*shape)``: a chunk's block of a ``(B, T /
+    chunk, *shape)`` one; ``whole``: the lane's state). The token blocks' index
+    maps stay on the lane's last real chunk, so the chunks wholly past
+    ``real_len`` fetch nothing."""
+    from jax.experimental import pallas as pl
+
+    def at_chunk(c, lane, real):
+        last = jnp.maximum((real[lane] + chunk - 1) // chunk - 1, 0)
+        return jnp.minimum(c, last)
+
+    tokens = lambda columns: pl.BlockSpec(                           # noqa: E731
+        (None, chunk, columns), lambda i, c, real: (i, at_chunk(c, i, real), 0))
+    vectors = lambda *shape: pl.BlockSpec(                           # noqa: E731
+        (None, None) + shape, lambda i, c, real: (i, at_chunk(c, i, real), 0, 0))
+    whole = pl.BlockSpec((None, d_k, width), lambda i, c, real: (i, 0, 0))
+    return tokens, vectors, whole
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def delta_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is the module's CHUNK (a power of two the gate checks); interpret is boolean (the tests' flag)
         state, q, k, v, log_g, beta, real_len, chunk: int = CHUNK,
@@ -797,15 +846,7 @@ def delta_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is the modu
                            axis=2)                       # (B, n, H, 2C)
     cols = jnp.swapaxes(rows, 2, 3)                      # (B, n, 2C, H)
 
-    def at_chunk(c, lane, real):
-        last = jnp.maximum((real[lane] + chunk - 1) // chunk - 1, 0)
-        return jnp.minimum(c, last)
-
-    tokens = lambda columns: pl.BlockSpec(                           # noqa: E731
-        (None, chunk, columns), lambda i, c, real: (i, at_chunk(c, i, real), 0))
-    vectors = lambda *shape: pl.BlockSpec(                           # noqa: E731
-        (None, None) + shape, lambda i, c, real: (i, at_chunk(c, i, real), 0, 0))
-    whole = pl.BlockSpec((None, d_k, width), lambda i, c, real: (i, 0, 0))
+    tokens, vectors, whole = _chunk_block_specs(chunk, d_k, width)
     n_pairs, d_v = h // 2, width // h
     body = functools.partial(_delta_chunk_body, n_pairs=n_pairs, chunk=chunk,
                              d_k=d_k, d_v=d_v)
@@ -836,3 +877,294 @@ def delta_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is the modu
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret, name="delta_chunk_kernel",
     )(real_len.astype(jnp.int32), state.astype(f32), q, k, v, rows, cols))
+
+
+# -- the same, for a decay a channel -----------------------------------------------
+
+def _exact_dot_f32(m, rhs, dims=(((1,), (0,)), ((), ()))):
+    """``m @ rhs`` (``dims``: the contraction, a ``dot_general``'s) for float32
+    ``m`` AND float32 ``rhs`` at HIGHEST's exactness: the six products of
+    their bfloat16 parts that are not below float32's last bit, each exact in
+    the float32 accumulator, in six passes of ``m``'s rows (its parts stacked
+    along them, so the matrix unit holds each part of ``rhs`` once)."""
+    bf16 = jnp.bfloat16
+    rows = m.shape[0]
+    m1, m2, m3 = (p.astype(bf16) for p in _split3(m))
+    r1, r2, r3 = (p.astype(bf16) for p in _split3(rhs))
+    dot = functools.partial(jax.lax.dot_general, dimension_numbers=dims,
+                            preferred_element_type=jnp.float32)
+    by1 = dot(jnp.concatenate([m1, m2, m3], axis=0), r1)
+    by2 = dot(jnp.concatenate([m1, m2], axis=0), r2)
+    small = (dot(m1, r3) + by1[2 * rows:]) + by2[rows:]
+    return (small + (by1[rows:2 * rows] + by2[:rows])) + by1[:rows]
+
+
+@jax.jit
+def _advance_pairs_channel(k2, q2, lg2, v2, be_r, be_c, s):
+    """``_advance_pairs`` where the decay is a channel's, every argument again
+    a tuple with an entry a pair: ``k2`` / ``q2 (2 chunk, d_k)``, ``lg2 (2
+    chunk, d_k)`` float32 the tokens' OWN log-decay (<= 0, floored) and ``v2
+    (2 chunk, d_v)``, each the pair's second head's rows under the first's;
+    ``be_r (1, 2 chunk)`` / ``be_c (2 chunk, 1)`` the write strength; ``s`` a
+    PAIR of states ``(d_k, d_v)``, a head each -> (``o (2 chunk, d_v)``
+    stacked as ``v2``, the pairs of states after). The mathematics of
+    ``_chunked_block_channel``: the running sum ``G`` inside the chunk is
+    formed here (a product with a triangle of ones, exact); ``K K^T`` and ``Q
+    K^T`` by sub-blocks of ``SUB`` tokens with no exponent above 0, the
+    diagonal sub-blocks' ``exp(G_t - G_i)`` formed once for both; ``K K^T``,
+    the inverse and the solve at float32's exactness; the products with the
+    state on operands rounded to the operands' dtype, a head's own columns
+    only (the decays are the state's ROWS' here, so nothing is gained by a
+    pair's states side by side)."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    rounded = k2[0].dtype
+    chunk = k2[0].shape[0] // 2
+    two = 2 * chunk
+    sub = SUB if chunk % SUB == 0 else chunk
+    half = sub // 2 if sub % 16 == 0 else sub       # whole float32 tiles
+    n_sub, n_blocks = chunk // sub, two // sub
+    js = range(len(k2))
+    row = jax.lax.broadcasted_iota(jnp.int32, (two, two), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (two, two), 1)
+    same = (row < chunk) == (col < chunk)           # the two diagonal blocks
+    strict = same & (col < row)
+    below = same & ((col // sub) < (row // sub))    # under the diagonal sub-blocks
+    tok = jax.lax.broadcasted_iota(jnp.int32, (two, 1), 0)
+    first = tok < chunk                              # a pair's first head's rows
+    nt = (((1,), (1,)), ((), ()))
+    tn = (((0,), (0,)), ((), ()))
+    dot = functools.partial(jnp.dot, preferred_element_type=f32)
+    heads = (slice(0, chunk), slice(chunk, two))
+
+    def of_head(x, at: int):
+        """Each head's row ``at`` of ``x (2 chunk, d)`` over that head's rows."""
+        return jnp.where(first, x[at:at + 1], x[chunk + at:chunk + at + 1])
+
+    def never_up(e):
+        """Exponents as they go into ``exp``: none above 0 (two running sums
+        that should be equal may differ in float32's last bit)."""
+        return jnp.minimum(e, 0.0)
+
+    def three(m):
+        """Float32 ``m``'s three bfloat16 parts side by side."""
+        return jnp.concatenate([p.astype(bf16) for p in _split3(m)], axis=1)
+
+    def summed(x, axis: int):
+        """The three parts' products, ``d_k`` wide each along ``axis``, added
+        smallest first."""
+        p1, p2, p3 = jnp.split(x, 3, axis=axis)
+        return (p3 + p2) + p1
+
+    kf = [k2[j].astype(f32) for j in js]
+    qf = [q2[j].astype(f32) for j in js]
+    # G: the running log-decay inside the chunk, a head's rows its own, and
+    # its last row G_C as COLUMNS (a head's over its own ``chunk`` lanes):
+    # both by products with 0 / 1, the log-decay's three parts each exact
+    before = jnp.where(same & (col <= row), 1.0, 0.0).astype(bf16)
+    of_one_head = jnp.where(same, 1.0, 0.0).astype(bf16)
+    parts = [three(lg2[j]) for j in js]
+    g = [summed(dot(before, parts[j]), 1) for j in js]
+    g_end_col = [summed(jax.lax.dot_general(parts[j], of_one_head, tn,
+                                            preferred_element_type=f32), 0)
+                 for j in js]                                   # (d_k, 2 chunk)
+    # the diagonal sub-blocks: exp(G_t - G_i) itself, i <= t, a column (every
+    # sub-block's i-th token) at a time, once for K K^T and Q K^T; a
+    # sub-block's upper half of rows has nothing under its later columns
+    cut = lambda x: x.reshape(n_blocks, sub, x.shape[1])         # noqa: E731
+    g3, k3, q3 = ([cut(x[j]) for j in js] for x in (g, kf, qf))
+    shape = (n_blocks, half, two)
+    at_row = jax.lax.broadcasted_iota(jnp.int32, (n_blocks, half, 1), 1)
+    in_block = (jax.lax.broadcasted_iota(jnp.int32, shape, 2) // sub
+                == jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    at_col = jax.lax.broadcasted_iota(jnp.int32, shape, 2) % sub
+    parts_of = range(0, sub, half)                  # the halves' first rows
+    kk3 = [[jnp.zeros(shape, f32) for _ in parts_of] for _ in js]
+    qk3 = [[jnp.zeros(shape, f32) for _ in parts_of] for _ in js]
+    from_first = [[None for _ in parts_of] for _ in js]
+    for i in range(sub):
+        here = in_block & (at_col == i)
+        for j in js:
+            g_i, k_i = g3[j][:, i:i + 1], k3[j][:, i:i + 1]
+            for n, lo in enumerate(parts_of):
+                if lo + half <= i:
+                    continue                        # rows all before token i
+                rows = slice(lo, lo + half)
+                e = g3[j][:, rows] - g_i
+                if lo < i:
+                    e = jnp.where(at_row >= i - lo, e, -jnp.inf)
+                e = jnp.exp(never_up(e))
+                if i == 0:
+                    from_first[j][n] = e
+                m = e * k_i
+                kk3[j][n] = jnp.where(
+                    here, jnp.sum(k3[j][:, rows] * m, axis=2, keepdims=True), kk3[j][n])
+                qk3[j][n] = jnp.where(
+                    here, jnp.sum(q3[j][:, rows] * m, axis=2, keepdims=True), qk3[j][n])
+    whole = lambda x: jnp.concatenate(x, axis=1).reshape(two, -1)   # noqa: E731
+    kk = [whole(kk3[j]) for j in js]
+    qk = [whole(qk3[j]) for j in js]
+    # the sub-blocks below them, through the later sub-block's first row r:
+    # (x_t exp(G_t - G_r)) . (k_i exp(G_r - G_i)), both factors <= 1
+    if n_sub > 1:
+        zeros = jnp.zeros((sub, two), f32)
+        for j in js:
+            e = whole(from_first[j])
+            k_l, q_l = kf[j] * e, qf[j] * e
+            under_k, under_q = [[zeros], [zeros]], [[zeros], [zeros]]
+            for a in range(1, n_sub):
+                at = [slice(h.start + a * sub, h.start + (a + 1) * sub) for h in heads]
+                right = kf[j] * jnp.exp(never_up(jnp.where(
+                    (tok % chunk) < a * sub, of_head(g[j], a * sub) - g[j], -jnp.inf)))
+                under = _exact_dot_f32(
+                    jnp.concatenate([k_l[at[0]], k_l[at[1]]], axis=0), right, nt)
+                under_x = jax.lax.dot_general(
+                    jnp.concatenate([q_l[at[0]], q_l[at[1]]], axis=0).astype(rounded),
+                    right.astype(rounded), nt, preferred_element_type=f32)
+                for head in range(2):
+                    under_k[head].append(under[head * sub:(head + 1) * sub])
+                    under_q[head].append(under_x[head * sub:(head + 1) * sub])
+            kk[j] = jnp.where(below, jnp.concatenate(under_k[0] + under_k[1], axis=0),
+                              kk[j])
+            qk[j] = jnp.where(below, jnp.concatenate(under_q[0] + under_q[1], axis=0),
+                              qk[j])
+    a_mat = [be_c[j] * jnp.where(strict, kk[j], 0.0) for j in js]
+    qk = [qk[j].astype(rounded) for j in js]
+    x = _unit_lower_inverses(a_mat, chunk)
+    # W = (I + a)^-1 diag(beta) (K exp(G)) and U_0 = (I + a)^-1 diag(beta) V
+    decayed = [jnp.exp(g[j]) for j in js]                       # exp(G) <= 1
+    solve = [x[j] * be_r[j] for j in js]
+    w = [_exact_dot_f32(solve[j], kf[j] * decayed[j]).astype(rounded) for j in js]
+    u0 = [_exact_dot(solve[j], v2[j]) for j in js]              # (2C, d_v)
+    q_g = [(qf[j] * decayed[j]).astype(rounded) for j in js]
+    # W S over Q exp(G) S, a head at a time
+    ws_qs = [[dot(jnp.concatenate([w[j][h], q_g[j][h]], axis=0),
+                  s[j][n].astype(rounded)) for n, h in enumerate(heads)] for j in js]
+    stacked = lambda a, rows: jnp.concatenate(                       # noqa: E731
+        [a[0][rows], a[1][rows]], axis=0)
+    u = [(u0[j] - stacked(ws_qs[j], heads[0])).astype(rounded) for j in js]
+    o = [stacked(ws_qs[j], heads[1]) + dot(qk[j], u[j]) for j in js]
+    # K exp(G_C - G); exp(G_C) scales the state's rows
+    k_out = [(kf[j] * jnp.exp(never_up(of_head(g[j], chunk - 1) - g[j]))
+              ).astype(rounded) for j in js]
+    after = [tuple(
+        jnp.exp(g_end_col[j][:, h.start:h.start + 1]) * s[j][n]
+        + jax.lax.dot_general(k_out[j][h], u[j][h], tn, preferred_element_type=f32)
+        for n, h in enumerate(heads)) for j in js]
+    return tuple(o), tuple(after)
+
+
+def _delta_channel_chunk_body(real_ref, s_in, q_ref, k_ref, v_ref, lg_ref, rows_ref,
+                              cols_ref, o_ref, s_ref, s_at, k_at, q_at, lg_at, v_at,
+                              c_at, o_at, *, n_pairs: int, chunk: int, d_k: int,
+                              d_v: int):
+    """``_delta_chunk_body`` for a decay a channel: ``lg_ref (chunk, H x
+    d_k)`` float32 is the tokens' log-decay beside ``k_ref``, and ``rows_ref
+    (H / 2, 2 chunk)`` / ``cols_ref (2 chunk, H / 2)`` hold the write strength
+    alone (``c_at (H / 2, 2 chunk, 1)``). The keys, the queries, the
+    log-decay AND the values are cut a pair at a time with the second head's
+    rows under the first's (``k_at`` / ``q_at`` / ``lg_at (pairs, 2 chunk,
+    d_k)``, ``v_at`` / ``o_at (pairs, 2 chunk, d_v)``), the state a HEAD at a
+    time (``s_at (H, d_k, d_v)``): ``_advance_pairs_channel`` multiplies a
+    head's state alone. Everything else as there: the state lives in ``s_at``
+    from the lane's first chunk to its last, and a chunk wholly past
+    ``real_len`` does nothing."""
+    from jax.experimental import pallas as pl
+
+    lane, c = pl.program_id(0), pl.program_id(1)
+    pairs = range(n_pairs)
+    a_trip = _pairs_a_trip(n_pairs)
+    value_columns = [slice(head * d_v, (head + 1) * d_v) for head in range(2 * n_pairs)]
+
+    def stacked(ref, p, d):
+        """A pair's columns of ``ref``, the second head's rows under the first's."""
+        win = ref[:, 2 * p * d:2 * (p + 1) * d]
+        return jnp.concatenate([win[:, :d], win[:, d:]], axis=0)
+
+    @pl.when(c == 0)
+    def _():
+        for head, columns in enumerate(value_columns):
+            s_at[head] = s_in[:, columns]
+
+    @pl.when(c * chunk < real_ref[lane])
+    def _():
+        for p in pairs:
+            for ref, at in ((k_ref, k_at), (q_ref, q_at), (lg_ref, lg_at)):
+                at[p] = stacked(ref, p, d_k)
+            v_at[p] = stacked(v_ref, p, d_v)
+            c_at[p] = cols_ref[:, p:p + 1]
+
+        def trip(t, _):
+            here = [t * a_trip + j for j in range(a_trip)]
+            o, s = _advance_pairs_channel(
+                *(tuple(at[p] for p in here) for at in (k_at, q_at, lg_at, v_at)),
+                tuple(rows_ref[pl.ds(p, 1), :] for p in here),
+                tuple(c_at[p] for p in here),
+                tuple((s_at[2 * p], s_at[2 * p + 1]) for p in here))
+            for j, p in enumerate(here):
+                o_at[p] = o[j]
+                s_at[2 * p], s_at[2 * p + 1] = s[j]
+            return 0
+
+        jax.lax.fori_loop(0, n_pairs // a_trip, trip, 0)
+        for p in pairs:
+            o_ref[:, value_columns[2 * p]] = o_at[p, :chunk]
+            o_ref[:, value_columns[2 * p + 1]] = o_at[p, chunk:]
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _():
+        for head, columns in enumerate(value_columns):
+            s_ref[:, columns] = s_at[head]
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def delta_channel_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is the module's CHUNK (a power of two the gate checks); interpret is boolean (the tests' flag)
+        state, q, k, v, log_decay, beta, real_len, chunk: int = CHUNK,
+        interpret: bool = False):
+    """``delta_chunk_kernel`` for a decay a channel: the same operands, but
+    ``log_decay (B, T, H x d_k)`` float32 is each token's OWN ``log alpha``
+    (floored at ``LOG_DECAY_FLOOR``; 0 past ``real_len``, where ``beta`` is 0
+    too), beside ``k``'s columns: the running sum inside a chunk is formed in
+    the kernel, so no ``(T, H x d_k)`` float32 array is written for it. The
+    same grid, the same residence of the lane's state, the same skipping of
+    the chunks past ``real_len``; the other kernel's twin, not a mode of it: a
+    decay a head factors out of ``K K^T`` and needs none of this one's
+    exponentials."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    b, d_k, width = state.shape
+    t_len, h = q.shape[1], beta.shape[-1]
+    n = t_len // chunk
+    rows = _pair_rows(beta, chunk)                       # (B, n, H / 2, 2C)
+    cols = jnp.swapaxes(rows, 2, 3)                      # (B, n, 2C, H / 2)
+
+    tokens, vectors, whole = _chunk_block_specs(chunk, d_k, width)
+    n_pairs, d_v = h // 2, width // h
+    body = functools.partial(_delta_channel_chunk_body, n_pairs=n_pairs,
+                             chunk=chunk, d_k=d_k, d_v=d_v)
+    # behind a barrier, as ``delta_chunk_kernel`` and for its reason
+    return jax.lax.optimization_barrier(pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, n),
+            in_specs=[whole, tokens(h * d_k), tokens(h * d_k), tokens(width),
+                      tokens(h * d_k), vectors(n_pairs, 2 * chunk),
+                      vectors(2 * chunk, n_pairs)],
+            out_specs=[tokens(width), whole],
+            scratch_shapes=[
+                pltpu.VMEM((h, d_k, d_v), f32),
+                pltpu.VMEM((n_pairs, 2 * chunk, d_k), k.dtype),
+                pltpu.VMEM((n_pairs, 2 * chunk, d_k), q.dtype),
+                pltpu.VMEM((n_pairs, 2 * chunk, d_k), f32),
+                pltpu.VMEM((n_pairs, 2 * chunk, d_v), v.dtype),
+                pltpu.VMEM((n_pairs, 2 * chunk, 1), f32),
+                pltpu.VMEM((n_pairs, 2 * chunk, d_v), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, t_len, width), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret, name="delta_channel_chunk_kernel",
+    )(real_len.astype(jnp.int32), state.astype(f32), q, k, v,
+      log_decay.astype(f32), rows, cols))

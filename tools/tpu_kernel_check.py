@@ -91,8 +91,11 @@ def main() -> int:
     # test_kda_moe_lm.py carries the same rule with a decay a CHANNEL at
     # Solar-Open2's widths (64 heads of 128 / 128): the live-lane step on 8 of
     # 32 lanes (us a lane a layer beside the 8.5 MB a lane the state's bytes
-    # allow) and the block form over a bucket of 2048 against the step
-    # iterated, decays down to 0.05 a step: `-k "kda_rule_on_tpu"`, ~1 min.
+    # allow) and, since PR 50, the chunked form through its kernel
+    # (``delta_channel_chunk_kernel``) AND through the block form over buckets
+    # of 2048 (1500 real) and 8192 (6000 real) against the step iterated,
+    # decays down to 0.05 a step: largest errors and ms a layer side by side,
+    # `-k "kda_rule_on_tpu"`, ~2 min.
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
