@@ -367,7 +367,19 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     # PR 46 its four and its cell, PR 47 the chunked rule's kernel's share,
     # PR 48 the rows a prefill computes a real row (the seven generating cells),
     # PR 49 its Kimi-delta-attention layers' three and its cell, PR 50 the
-    # channel-decay chunked rule's kernel's share (the twin of PR 47's)
+    # channel-decay chunked rule's kernel's share (the twin of PR 47's), PR 51
+    # the bring-up account's six (every cell; the two of the arena the eight
+    # generating cells)
+    cells = [w["name"] for w in bench_json["workloads"]]
+    account = bench_json["per_layer"][-6:]
+    assert [m["name"] for m in account] == [
+        "setup_trace_lower_s", "setup_compile_s", "setup_load_s",
+        "setup_engine_build_s", "setup_unexplained_s", "device_unowned_peak_bytes"]
+    for m in account:
+        assert (m["better"], m["source"]) == ("lower", "host_clock")
+        assert set(cells) - set(m["workloads"]) <= {"smollm2-tenants-churn"}
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
+    bench_json["per_layer"] = bench_json["per_layer"][:-6]
     twin = bench_json["per_layer"].pop()
     assert twin["name"] == "kda_chunk_roofline"
     assert twin["workloads"] == ["solaropen2-docreport-steady"]

@@ -24,6 +24,7 @@ waits for the host's fetch, emission and launch.
 import importlib.util
 import itertools
 import os
+import re
 import time
 
 import jax
@@ -498,6 +499,12 @@ def test_f_a_capture_holds_the_next_launch_before_the_fetch(tmp_path):
         jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=options)
         try:
             eng.generate(mid, prompt, max_new_tokens=13)      # a prefill token + three chunks
+            # ``generate`` returns from inside the engine's last boundary (the
+            # emission wakes it) and the profiler keeps an event only once it
+            # has ENDED: on a loaded machine the capture stopped first and the
+            # last ``tpusc.boundary`` was missing. The engine's thread is
+            # joined first, so every span it opened is in the capture
+            eng.close()
         finally:
             jax.profiler.stop_trace()
     finally:
@@ -509,27 +516,27 @@ def test_f_a_capture_holds_the_next_launch_before_the_fetch(tmp_path):
         for line in plane.lines:
             mine = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name) for ev in line.events]
             if any(name == "tpusc.boundary" for _s, _e, name in mine):   # the engine's thread
-                events = sorted(mine)
+                events = sorted(mine, key=lambda ev: (ev[0], -ev[1]))   # an outer span first
     spans = lambda name: [(s, e) for s, e, n in events if n == name]   # noqa: E731
     launches, fetches = spans("tpusc.chunk_launch"), spans("tpusc.chunk_fetch")
-    chunks, bounds = spans("tpusc.decode_chunk"), spans("tpusc.boundary")
     calls = [s for s, _e, n in events if n == "PjitFunction(_paged_decode_chunk_jit)"]
-    assert len(launches) == len(fetches) == len(chunks) == 3
+    assert len(launches) == len(fetches) == len(spans("tpusc.decode_chunk")) == 3
     # every call of the program (the tracer names a call more than once) is
     # some launch's, and every launch has one
     held = [[c for c in calls if ls <= c < le] for ls, le in launches]
     assert all(held) and sum(map(len, held)) == len(calls)
-    # launch 1, launch 2, fetch 1, launch 3, fetch 2, fetch 3
-    order = sorted([(s, "launch") for s, _e in launches] + [(s, "fetch") for s, _e in fetches])
-    assert [what for _s, what in order] == ["launch", "launch", "fetch", "launch",
-                                            "fetch", "fetch"]
+    # the ORDER in which the engine's thread opened its spans (one thread: what
+    # opens later inside a span nests in it; no wall-clock containment, which
+    # a loaded machine's clock reads cannot be held to): a boundary, its
+    # decode_chunk, then launch 1, launch 2, fetch 1 | launch 3, fetch 2 |
+    # fetch 3, each boundary's chunk after the boundary opened and before the
+    # next one did. Boundaries that ran no chunk (the drain) may follow
+    letter = {"tpusc.boundary": "b", "tpusc.decode_chunk": "d",
+              "tpusc.chunk_launch": "l", "tpusc.chunk_fetch": "f"}
+    opened = "".join(letter[n] for _s, _e, n in events if n in letter)
+    assert re.fullmatch(r"b*bdllfb+dlfb+dfb*", opened), opened
     for n in (0, 1):
         assert launches[n + 1][1] <= fetches[n][0]
-    # the names nest as they did: both kinds inside a decode_chunk inside a boundary
-    for s, e in launches + fetches:
-        assert sum(cs <= s and e <= ce for cs, ce in chunks) == 1
-    for cs, ce in chunks:
-        assert sum(bs <= cs and ce <= be for bs, be in bounds) == 1
     assert [s["ahead"] for s in _ran(mid)][-3:] == [0, 1, 1]
 
 

@@ -555,7 +555,7 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_coalesced_requests",
     "tpusc_cold_overlap_ratio",
     "tpusc_cold_stage_seconds",
-    "tpusc_compile_duration_seconds",
+    "tpusc_device_bytes",
     "tpusc_disk_cache_bytes_in_use",
     "tpusc_evictions",
     "tpusc_gen_admission_wait_seconds",
@@ -600,6 +600,8 @@ EXPECTED_METRIC_FAMILIES = {
     "tpusc_pool_wait_seconds",
     "tpusc_reload_source",
     "tpusc_prefix_cache_bytes",
+    "tpusc_program_build_seconds",
+    "tpusc_program_builds",
     "tpusc_prefix_cache_hits",
     "tpusc_prefix_cache_misses",
     "tpusc_request_duration_seconds",

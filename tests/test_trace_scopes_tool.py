@@ -96,6 +96,28 @@ def test_idle_time_goes_to_its_cause(tool, rows):
     assert tool.idle_by_cause([]) == []
 
 
+def test_bring_up_host_events_are_listed_by_stage(tool, rows):
+    """The bring-up account's host events (``utils/bring_up.py``): counted and
+    summed by stage, on whatever thread; the ``first_run`` marker's name carries
+    its program. The toy capture holds none."""
+    assert tool.bring_up_marks(rows) == []
+    host = "/host:CPU"
+    more = list(rows) + [
+        (host, "pool-1", "tpusc.load", 10, 3_000_000_000, {}),
+        (host, "pool-1", "tpusc.device_transfer", 20, 2_000_000_000, {}),
+        (host, "pool-2", "tpusc.load", 30, 1_000_000_000, {}),
+        (host, "engine", "tpusc.engine_build", 40, 500_000_000, {}),
+        (host, "engine", "tpusc.first_run#program=_slot_prefill_jit,wall_ms=812.0#",
+         50, 1_000, {}),
+        (host, "main", "tpusc.server_start", 5, 250_000_000, {}),
+        (host, "engine", "tpusc.boundary", 60, 9_000_000, {}),     # not the account's
+    ]
+    assert tool.bring_up_marks(more) == [
+        ("tpusc.server_start", 1, 0.25), ("tpusc.load", 2, 4.0),
+        ("tpusc.device_transfer", 1, 2.0), ("tpusc.engine_build", 1, 0.5),
+        ("tpusc.first_run", 1, 1e-6)]
+
+
 def test_loader_reads_scopes_off_the_capture(tool, rows):
     loaded = tool.load(os.path.join(DATA, "toy_v5e.xplane.pb.gz"))
     assert len(loaded) == len(rows)

@@ -30,6 +30,7 @@ from aiohttp import web
 from tfservingcache_tpu.cluster.status import STATUS_HEADER, STATUS_WANT_HEADER
 from tfservingcache_tpu.protocol.backend import BackendError, RestResponse, ServingBackend
 from tfservingcache_tpu.utils.accounting import LEDGER
+from tfservingcache_tpu.utils.bring_up import ACCOUNT
 from tfservingcache_tpu.utils.flight_recorder import RECORDER
 from tfservingcache_tpu.utils.logging import get_logger
 from tfservingcache_tpu.utils.metrics import Metrics
@@ -204,6 +205,24 @@ class RestServingServer:
                 topo = topo_fn()
                 if topo is not None:
                     snap["mesh"] = topo
+            # the bring-up account (utils/bring_up.py): beside the recorder's
+            # program and stage records, the device bytes the runtime answers
+            # for and what the listeners themselves have cost (build events
+            # seen, the seconds the booking ones took)
+            account = snap["bring_up"]
+            account["listener"] = {
+                "events": ACCOUNT.events,
+                "seconds": round(ACCOUNT.listener_s, 6)}
+            owned_fn = getattr(rt, "owned_device_bytes", None)
+            if owned_fn is not None:
+                account["owned"] = owned_fn()
+            memory_fn = getattr(rt, "program_memory", None)
+            if memory_fn is not None and request.query.get(
+                    "programs", "0").lower() in ("1", "true", "yes", "on"):
+                # on demand only: each kept program is lowered and compiled
+                # again for its memory_analysis() (seconds; off the event loop)
+                account["program_memory"] = await asyncio.get_running_loop(
+                ).run_in_executor(None, memory_fn)
             return web.json_response(snap)
         if path == "/monitoring/tenants":
             # per-tenant cost ledger (utils/accounting.py): ?top=k keeps the

@@ -20,6 +20,7 @@ dynamic shapes would otherwise force an XLA recompile per novel batch.
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
 import queue
@@ -49,6 +50,8 @@ from tfservingcache_tpu.utils.flight_recorder import RECORDER
 from tfservingcache_tpu.utils.lockcheck import lockchecked
 from tfservingcache_tpu.utils.logging import get_logger
 from tfservingcache_tpu.utils.metrics import Metrics
+from tfservingcache_tpu.utils import bring_up
+from tfservingcache_tpu.utils.bring_up import BUILT
 from tfservingcache_tpu.utils.tracing import TRACER, current_span, host_span
 
 log = get_logger("runtime")
@@ -678,6 +681,35 @@ class PrefillRows(NamedTuple):
     prompt_len: int = 0
 
 
+def _state_device_bytes(state: "SlotDecodeState") -> tuple[int, int, int]:
+    """(global arena, window ring arena, lane state) bytes a slot state holds
+    on this host's devices."""
+    import jax
+
+    def actual(arr: Any) -> int:
+        # Sharded arena (ISSUE 20): the bytes actually ALLOCATED on this
+        # host's devices, the per-shard sum, not the logical array size (2x
+        # wrong on a 2-way KV-head split). From the sharding, never from the
+        # buffers: a monitoring thread reads this while the engine's thread
+        # donates the arrays to its next program, and a donated array's
+        # buffers are gone (its shape, dtype and sharding are not)
+        sharding = getattr(arr, "sharding", None)
+        local = getattr(sharding, "addressable_devices", ())
+        if len(local) <= 1:
+            return int(arr.nbytes)
+        shard = sharding.shard_shape(arr.shape)
+        return int(np.prod(shard)) * arr.dtype.itemsize * len(local)
+
+    nbytes = actual(state.k) + (actual(state.v) if state.v is not None else 0)
+    if state.scales is not None:
+        nbytes += sum(actual(a) for a in state.scales.values())
+    ring = sum(actual(a) for a in state.window or ())
+    # every part of a state of several parts (a tuple of arrays)
+    lane = sum(actual(part)
+               for part in jax.tree_util.tree_leaves(state.lane_state))
+    return nbytes, ring, lane
+
+
 class ChunkInFlight(NamedTuple):
     """A launched decode chunk's output futures that the host reads
     (``slot_decode_chunk_launch`` -> ``slot_decode_chunk_fetch``): the lanes'
@@ -1057,7 +1089,10 @@ class TPUModelRuntime(BaseRuntime):
         # LOCAL devices: in a multi-controller (cross-host) deployment
         # jax.devices() includes peers' non-addressable chips — the
         # single-device path and health probe must stay on this process's own
-        self._devices = jax.local_devices(backend=self.cfg.platform or None)
+        # the process's first device discovery initialises the backend (about
+        # 12 s to reach a chip; nothing where the caller already has)
+        with bring_up.stage("backend_init", metrics):
+            self._devices = jax.local_devices(backend=self.cfg.platform or None)
         if mesh is not None:
             from tfservingcache_tpu.parallel.sharding import is_single_process
 
@@ -1148,6 +1183,10 @@ class TPUModelRuntime(BaseRuntime):
         # NOT charged to the resident-model LRU) and dies with the model:
         # _on_evict / reset_group_state / close all drop it.
         self._slot_states: dict[ModelId, SlotDecodeState] = {}
+        # the engine programs' first runs (bring_up.first_run): the jitted
+        # function, its call's abstract arguments and statics, what the call
+        # site said of it; the newest 64, for ``program_memory``
+        self._first_runs: collections.deque = collections.deque(maxlen=64)
         self._slot_lock = threading.Lock()
         # _mesh_serialized: one consistent per-device launch order for
         # partitioned programs (held only when self.mesh is not None)
@@ -1249,20 +1288,25 @@ class TPUModelRuntime(BaseRuntime):
                     self._host_tier.remove(mid)
         self._set_state(mid, ModelState.START)
         t0 = time.monotonic()
+        built0 = BUILT.build_s
         with TRACER.span("load", model=str(mid), tier="disk") as load_span:
             self._load_traced(model, mid, t0, load_span)
+        self._note_load(load_span, built0)
         # Σ(stage)/wall: ~1.0 = strictly serialized stages, >1 = the
         # pipeline overlapped them (AOT compile / per-leaf dequant running
         # during the transfer). Annotated on the span AND observed as a
         # metric so bench artifacts surface the win without re-deriving it.
         if load_span.children and load_span.duration_s > 0:
-            ratio = (
-                sum(c.duration_s for c in load_span.children)
-                / load_span.duration_s
-            )
+            stages_s = sum(c.duration_s for c in load_span.children)
+            ratio = stages_s / load_span.duration_s
             load_span.attrs["cold_overlap_ratio"] = round(ratio, 3)
             if self.metrics is not None:
                 self.metrics.cold_overlap_ratio.observe(ratio)
+                # the same overlap in seconds: what the bring-up account
+                # would count twice, under the stages and under the builds (a
+                # pipelined load's AOT compile runs beside its transfer)
+                self.metrics.cold_stage_seconds.labels("load_overlap").observe(
+                    max(0.0, stages_s - load_span.duration_s))
         if self.metrics is not None:
             # per-stage cold histograms: the in-production "where do my cold
             # seconds go" (and the int8 crossover: device_transfer +
@@ -1285,6 +1329,7 @@ class TPUModelRuntime(BaseRuntime):
         mid = model.identifier
         self._set_state(mid, ModelState.START)
         t0 = time.monotonic()
+        built0 = BUILT.build_s
         hbm = 0
         try:
             with TRACER.span("load", model=str(mid), tier="host") as load_span:
@@ -1367,14 +1412,20 @@ class TPUModelRuntime(BaseRuntime):
             self._set_state(mid, ModelState.END)
             raise RuntimeError_(f"failed to promote {mid}: {e}") from e
         dt = time.monotonic() - t0
-        if self.metrics is not None:
-            self.metrics.compile_duration.labels(
-                self.metrics.model_label(mid.name, mid.version)
-            ).observe(dt)
+        self._note_load(load_span, built0)
         self._update_gauges()
         log.info(
             "promoted %s from host tier in %.3fs (%d HBM bytes)", mid, dt, hbm
         )
+
+    def _note_load(self, load_span: Any, built0: float) -> None:
+        """The bring-up account's ``load`` stage: the span's wall less the
+        builds on this thread (a warm-up that compiled here), the device's
+        bytes at its end, what the runtime answers for beside them."""
+        bring_up.note_stage(
+            "load", load_span.duration_s, built0, self.metrics, self._devices,
+            span=load_span, owned=sum(self.owned_device_bytes().values()),
+            **{k: load_span.attrs[k] for k in ("model", "tier")})
 
     def _load_traced(
         self, model: Model, mid: ModelId, t0: float, load_span: Any
@@ -1593,10 +1644,6 @@ class TPUModelRuntime(BaseRuntime):
             self._set_state(mid, ModelState.END)
             raise RuntimeError_(f"failed to load {mid}: {e}") from e
         dt = time.monotonic() - t0
-        if self.metrics is not None:
-            self.metrics.compile_duration.labels(
-                self.metrics.model_label(mid.name, mid.version)
-            ).observe(dt)
         self._update_gauges()
         log.info("loaded %s in %.2fs (%d HBM bytes)", mid, dt, hbm)
 
@@ -2199,10 +2246,20 @@ class TPUModelRuntime(BaseRuntime):
                 st = self._slot_states.get(model_id)
             if st is not None:
                 return st  # the racer that held the guard built it
-            st = self._build_slot_state(
-                loaded, model_id, slots, page_tokens, arena_pages,
-                share_prefix_bytes, arena_dtype, paged_kernel,
-            )
+            import jax
+
+            with bring_up.stage("engine_build", self.metrics, self._devices,
+                                model=str(model_id)) as late:
+                st = self._build_slot_state(
+                    loaded, model_id, slots, page_tokens, arena_pages,
+                    share_prefix_bytes, arena_dtype, paged_kernel,
+                )
+                # once, on the cold path: the stage ends when the arenas, the
+                # lane state and the window rings ARE on the device
+                jax.block_until_ready(
+                    (st.k, st.v, st.scales, st.lane_state, st.window))
+                late["owned"] = sum(_state_device_bytes(st)) + sum(
+                    self.owned_device_bytes().values())
             with self._slot_lock:
                 st = self._slot_states.setdefault(model_id, st)
                 self._slot_init_guards.pop(model_id, None)
@@ -2348,33 +2405,77 @@ class TPUModelRuntime(BaseRuntime):
         practice — the engine keys slot state by model_id)."""
         if self.metrics is None:
             return
-
-        def actual(arr: Any) -> int:
-            # Sharded arena (ISSUE 20): the gauge reports bytes actually
-            # ALLOCATED on this host's devices — the per-shard sum, not the
-            # logical array size (2x wrong on a 2-way KV-head split)
-            shards = getattr(arr, "addressable_shards", None)
-            if shards:
-                return sum(int(s.data.nbytes) for s in shards)
-            return int(arr.nbytes)
-
+        nbytes, ring, lane = _state_device_bytes(state)
         label = state.arena_dtype or str(state.k.dtype)
-        nbytes = actual(state.k) + (actual(state.v) if state.v is not None else 0)
-        if state.scales is not None:
-            nbytes += sum(actual(a) for a in state.scales.values())
-        ring = sum(actual(a) for a in state.window or ())
         # both arenas under the dtype; each under its kind
         self.metrics.gen_kv_arena_bytes.labels(dtype=label).set(nbytes + ring)
         model = self.metrics.model_label(state.model_id.name,
                                          state.model_id.version)
         self.metrics.kv_arena_bytes.labels(model, "global").set(nbytes)
         self.metrics.kv_arena_bytes.labels(model, "window").set(ring)
+        self.metrics.lane_state_bytes.labels(model).set(lane)
+
+    def owned_device_bytes(self) -> dict[str, int]:
+        """The device bytes somebody answers for (``bring_up.owned`` in
+        ``/monitoring/engine``): the resident models' parameters, the engines'
+        arenas (global and window) and their lane states. What the allocator
+        counts beyond them (``tpusc_device_bytes``) nobody owns."""
+        with self._slot_lock:
+            states = list(self._slot_states.values())
+        states += [st.spec_draft for st in states if st.spec_draft is not None]
+        parts = [_state_device_bytes(st) for st in states]
+        return {"weights": int(self._resident.total_bytes),
+                "arenas": sum(p[0] + p[1] for p in parts),
+                "lane_state": sum(p[2] for p in parts)}
+
+    def _first_run(self, fn: Any, args: tuple, statics: dict, outputs: Any,
+                   **attrs: Any) -> None:
+        """The slow half of a call site's ``if BUILT.flag:`` (a build was
+        booked on this thread): where it was ``fn``'s, this call was a new
+        program's first execution; book it (``bring_up.first_run`` waits for
+        ``outputs`` once) and keep the call's ABSTRACT arguments, so that
+        ``program_memory`` can ask the executable for its temporaries later."""
         import jax
 
-        # every part of a state of several parts (a tuple of arrays)
-        self.metrics.lane_state_bytes.labels(model).set(sum(
-            actual(part)
-            for part in jax.tree_util.tree_leaves(state.lane_state)))
+        rec = bring_up.first_run(
+            (fn.__name__,), outputs, self.metrics, self._devices,
+            owned=sum(self.owned_device_bytes().values()), **attrs)
+        if rec is None:
+            return
+
+        def abstract(x: Any) -> Any:
+            shape, dtype = getattr(x, "shape", None), getattr(x, "dtype", None)
+            if shape is None or dtype is None:
+                return x
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=getattr(x, "sharding", None))
+
+        self._first_runs.append(
+            (fn, jax.tree_util.tree_map(abstract, args), statics, attrs))
+
+    def program_memory(self) -> list[dict[str, Any]]:  # jit-surface: on demand only (/monitoring/engine?programs=1), one lowering a kept first run
+        """What each engine program that ran here keeps on the device while it
+        runs, from its executable (``memory_analysis()`` of the kept abstract
+        arguments, lowered and compiled again: a compilation-cache load where
+        the cache is on, and booked as a build like any other). On a v5e the
+        allocator's ``bytes_in_use`` never shows a program's temporaries, only
+        ``bytes_reserved`` the largest of them (PERF.md); this names them.
+        Never called during a set-up or by the engine."""
+        out = []
+        for fn, args, statics, attrs in list(self._first_runs):
+            row: dict[str, Any] = {"program": fn.__name__, **attrs}
+            try:
+                mem = fn.lower(*args, **statics).compile().memory_analysis()
+                row.update(
+                    temp_bytes=int(mem.temp_size_in_bytes),
+                    argument_bytes=int(mem.argument_size_in_bytes),
+                    output_bytes=int(mem.output_size_in_bytes),
+                    alias_bytes=int(mem.alias_size_in_bytes),
+                    code_bytes=int(mem.generated_code_size_in_bytes))
+            except Exception as e:  # noqa: BLE001 - a diagnostic: say so, go on
+                row["error"] = f"{type(e).__name__}: {e}"[:300]
+            out.append(row)
+        return out
 
     def mesh_topology(self) -> dict | None:
         """Structural stamp for /monitoring/engine: a number without its
@@ -2492,11 +2593,12 @@ class TPUModelRuntime(BaseRuntime):
             ids = np.zeros((1, s_pad), np.int32)
             ids[0, :p] = prompt
             self._count_prefill_rows(p, s_pad)
-            tok, pk, pv, last, lane = _slot_prefill_jit(
-                loaded.params, ids, np.asarray([p], np.int32),
-                rng, temp, tk, cfg_key=cfg_key,
-                family=loaded.model_def.family,
-            )
+            args = (loaded.params, ids, np.asarray([p], np.int32), rng, temp, tk)
+            statics = {"cfg_key": cfg_key, "family": loaded.model_def.family}
+            tok, pk, pv, last, lane = outs = _slot_prefill_jit(*args, **statics)
+            if BUILT.flag:
+                self._first_run(_slot_prefill_jit, args, statics, outs,
+                                bucket=s_pad)
             if lane is not None or windowed:
                 pk = PrefillRows(pk, lane, p)
         return int(np.asarray(tok)[0]), pk, pv, hit is not None, last
@@ -2963,9 +3065,12 @@ class TPUModelRuntime(BaseRuntime):
                 # alone
                 span = (functools.partial(TRACER.span, lane=int(idx))
                         if current_span() is not None else host_span)
+                args = (state.lane_state, pk.lane, np.int32(idx))
                 with span("state_insert"):
-                    state.lane_state = _lane_insert_jit(
-                        state.lane_state, pk.lane, np.int32(idx))
+                    state.lane_state = _lane_insert_jit(*args)
+                if BUILT.flag:
+                    self._first_run(_lane_insert_jit, args, {},
+                                    state.lane_state)
             pk, prompt_len = pk.k, pk.prompt_len
         elif state.lane_state is not None or state.window is not None:
             raise RuntimeError_(
@@ -2974,14 +3079,17 @@ class TPUModelRuntime(BaseRuntime):
         if state.window is not None:
             # both arenas in one dispatch: every row of a global layer into
             # the lane's pages, a window layer's last rows into its ring
-            state.k, state.v, *state.window = _window_paged_insert_jit(
-                state.k, state.v, *state.window, pk, pv,
-                np.asarray(state.block_tables[idx], np.int32),
-                np.int32(idx), np.int32(prompt_len),
-                page_tokens=state.page_tokens, window_layers=state.window_rows,
-                ring_pages=state.ring_pages,
-            )
+            args = (state.k, state.v, *state.window, pk, pv,
+                    np.asarray(state.block_tables[idx], np.int32),
+                    np.int32(idx), np.int32(prompt_len))
+            statics = {"page_tokens": state.page_tokens,
+                       "window_layers": state.window_rows,
+                       "ring_pages": state.ring_pages}
+            state.k, state.v, *state.window = outs = _window_paged_insert_jit(
+                *args, **statics)
             state.window = tuple(state.window)
+            if BUILT.flag:
+                self._first_run(_window_paged_insert_jit, args, statics, outs)
             dropped = max(0, prompt_len - state.ring_pages * state.page_tokens)
             if self.metrics is not None and dropped:
                 self.metrics.gen_window_rows_dropped.labels(
@@ -2989,12 +3097,14 @@ class TPUModelRuntime(BaseRuntime):
                         state.model_id.name, state.model_id.version)
                 ).inc(dropped * len(state.window_rows))
             return
-        state.k, state.v, state.scales = _paged_insert_jit(
-            state.k, state.v, state.scales, pk, pv,
-            np.asarray(state.block_tables[idx], np.int32),
-            np.int32(base_tokens),
-            page_tokens=state.page_tokens,
-        )
+        args = (state.k, state.v, state.scales, pk, pv,
+                np.asarray(state.block_tables[idx], np.int32),
+                np.int32(base_tokens))
+        statics = {"page_tokens": state.page_tokens}
+        state.k, state.v, state.scales = outs = _paged_insert_jit(
+            *args, **statics)
+        if BUILT.flag:
+            self._first_run(_paged_insert_jit, args, statics, outs)
 
     @_mesh_serialized
     def slot_decode_chunk_launch(self, state: SlotDecodeState, chunk: int) -> ChunkInFlight:  # static-bounded: chunk -- engine clamps to a pow2 cover (batcher: min(chunk_tokens, _next_bucket(...)))
@@ -3027,17 +3137,24 @@ class TPUModelRuntime(BaseRuntime):
             # a model with window layers hands in their ring arena and gets
             # it back last (one output more); every other call is as it was
             ring = () if state.window is None else (state.window,)
+            args = (loaded.params, state.k, state.v, state.scales,
+                    tables, tok, pos, active, counter, temps, topks,
+                    state.lane_state, *ring)
+            statics = {"cfg_key": state.cfg_key, "family": state.family,
+                       "chunk": chunk, "page_tokens": state.page_tokens,
+                       "kernel": state.kernel}
             (state.k, state.v, state.scales, tok, pos,
              toks, stats, state.lane_state, counter, *ring
-             ) = _paged_decode_chunk_jit(
-                loaded.params, state.k, state.v, state.scales,
-                tables, tok, pos, active, counter, temps, topks,
-                state.lane_state, *ring,
-                cfg_key=state.cfg_key, family=state.family, chunk=chunk,
-                page_tokens=state.page_tokens, kernel=state.kernel,
-            )
+             ) = outs = _paged_decode_chunk_jit(*args, **statics)
             if ring:
                 state.window = ring[0]
+            if BUILT.flag:      # the one test a launch pays for the account
+                self._first_run(_paged_decode_chunk_jit, args, statics, outs,
+                                chunk=chunk)
+            # the donated operands' last references go HERE, inside the launch
+            # (``launch_ms``), where the call's own argument list let them go
+            # before the account kept them for a first run
+            del args
             if state.resident:
                 # the next chunk's tok / pos / counter are this chunk's
                 # outputs, where they are. Until this chunk is fetched the

@@ -21,6 +21,7 @@ from tfservingcache_tpu.config import Config
 from tfservingcache_tpu.protocol.grpc_server import GrpcServingServer
 from tfservingcache_tpu.protocol.local_backend import LocalServingBackend
 from tfservingcache_tpu.protocol.rest import RestServingServer
+from tfservingcache_tpu.utils import bring_up
 from tfservingcache_tpu.utils.accounting import LEDGER
 from tfservingcache_tpu.utils.flight_recorder import RECORDER
 from tfservingcache_tpu.utils.logging import get_logger
@@ -60,6 +61,16 @@ class CacheNode:
             model_labels=cfg.metrics.model_labels,
             max_model_labels=cfg.metrics.max_model_labels,
         )
+        # the bring-up account (utils/bring_up.py): its listeners before
+        # anything here touches jax, then the node's whole construction as
+        # its ``server_start`` stage (the runtime's first device discovery is
+        # the ``backend_init`` child; binding the sockets, milliseconds,
+        # follows in ``start``)
+        bring_up.install(self.metrics)
+        with bring_up.stage("server_start", self.metrics):
+            self._build(cfg, runtime)
+
+    def _build(self, cfg: Config, runtime) -> None:
         provider = create_provider(cfg.model_provider)
         if cfg.cluster.peer_fetch:
             # peer param distribution: front the store with the peer path

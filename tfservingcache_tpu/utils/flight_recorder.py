@@ -28,6 +28,10 @@ it went wrong. This module is the box's flight recorder:
   ``tpusc_flight_dumps_total{reason,outcome}`` counts both outcomes.
   ``tools/engine_dump.py`` pretty-prints them for postmortems.
 
+- **Bring-up records**: the newest 256 compiled-program builds and the
+  newest 256 bring-up stages (``utils/bring_up.py`` writes both: a bounded
+  deque each, appends lock-free), shown as ``bring_up`` in the snapshot.
+
 Like the tracer (utils/tracing.py) the recorder is a process-wide default
 instance: diagnostics are write-mostly and bounded, so a global keeps
 every call site plumbing-free; tests construct their own instances or
@@ -150,6 +154,7 @@ STEP_FIELDS = (
 
 DEFAULT_RING_ENTRIES = 4096
 _PHASE_NOTES_PER_MODEL = 64
+_BRING_UP_RECORDS = 256
 
 
 def _step_dict(e: tuple) -> dict[str, Any]:
@@ -252,6 +257,12 @@ class FlightRecorder:
         # so /monitoring/engine and tools/engine_dump.py surface the tier
         # without a separate endpoint.
         self._conversation_kv: dict[str, Any] | None = None
+        # the bring-up account (utils/bring_up.py): one dict a compiled
+        # program built, one a stage of a model's way onto the chip
+        self._builds: collections.deque = collections.deque(
+            maxlen=_BRING_UP_RECORDS)
+        self._stages: collections.deque = collections.deque(
+            maxlen=_BRING_UP_RECORDS)
 
     def configure(
         self,
@@ -453,6 +464,19 @@ class FlightRecorder:
         with self._lock:
             return dict(self._conversation_kv) if self._conversation_kv else None
 
+    def note_build(self, rec: dict[str, Any]) -> None:
+        """One compiled program's build: ``{program, t_wall, thread, trace_s,
+        lower_s, compile_s, cache}`` (the jax.monitoring listener's)."""
+        self._builds.append(rec)
+
+    def note_stage(self, rec: dict[str, Any]) -> None:
+        """One finished bring-up stage: ``{stage, t_wall, wall_s, build_s,
+        seconds}`` and, where the backend counts them, the device's bytes."""
+        self._stages.append(rec)
+
+    def bring_up(self) -> dict[str, list[dict[str, Any]]]:
+        return {"programs": list(self._builds), "stages": list(self._stages)}
+
     def note_fault(self, kind: str) -> None:
         """Tally one scenario-lab fault injection (lab/faults.py). Cheap on
         purpose: injections happen at most a handful per drill, never on a
@@ -546,6 +570,7 @@ class FlightRecorder:
             "models": models,
             "phases": phases,
             "watermarks": self.watermarks(reset=reset_watermarks),
+            "bring_up": self.bring_up(),
         }
         ckv = self.conversation_kv_stats()
         if ckv is not None:
@@ -642,6 +667,8 @@ class FlightRecorder:
             self._rings.clear()
             self._phases.clear()
             self._marks.clear()
+            self._builds.clear()
+            self._stages.clear()
             self._dumped_keys.clear()
             self._last_dump.clear()
 

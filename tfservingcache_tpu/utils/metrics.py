@@ -107,12 +107,36 @@ class Metrics:
             buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1, 2, 5, 10, 30, 60),
         )
         # TPU-native additions (no reference counterpart)
-        self.compile_duration = Histogram(
-            "tpusc_compile_duration_seconds",
-            "XLA compile+warmup time per model load",
-            ["model"],
-            registry=r,
-            buckets=(0.05, 0.1, 0.25, 0.5, 1, 2, 5, 10, 30, 60, 120),
+        # The bring-up account (utils/bring_up.py): what jax traced, lowered
+        # and compiled, by program, booked by jax.monitoring listeners where
+        # the build happens. `program` is bounded (64 names a process, the
+        # rest under "other": jax's own small helpers are many).
+        self.program_build_seconds = Counter(
+            "tpusc_program_build_seconds",
+            "Seconds jax spent building a compiled program, by its jitted "
+            "function's name and stage: trace (Python runs the function), "
+            "lower (jaxpr to MLIR, kernel bodies included), compile (XLA, the "
+            "compilation cache missed or is off) or cache_load (it hit). "
+            "Nested traces count inside their outermost program",
+            ["program", "stage"], registry=r,
+        )
+        self.program_builds = Counter(
+            "tpusc_program_builds",
+            "Compiled programs built, by name and what the persistent "
+            "compilation cache did (cache = hit | miss | off). One after "
+            "warm-up is a request that waited for a compile",
+            ["program", "cache"], registry=r,
+        )
+        self.device_bytes = Gauge(
+            "tpusc_device_bytes",
+            "The allocator's own count on the runtime's fullest device, read "
+            "once at the end of a bring-up stage (stage = load | engine_build "
+            "| first_run:<program>): what = in_use (bytes_in_use) | peak "
+            "(peak_bytes_in_use) | reserved (bytes_reserved: the scratch the "
+            "loaded program with the largest temporaries keeps, which "
+            "neither of the others counts). No sample on a backend without "
+            "allocator statistics (the CPU)",
+            ["stage", "what"], registry=r,
         )
         # labeled by chip group: one host may run several group runtimes,
         # each with its own HBM budget (ring members = chip groups)
@@ -507,7 +531,12 @@ class Metrics:
             "Per-stage cold-load time (provider_fetch/artifact_read/"
             "device_transfer/device_dequant/host_dequant/compile_warmup/"
             "transfer_sync; dequant stages appear for quantized artifacts "
-            "only, so encodings stay separable): "
+            "only, so encodings stay separable) and the bring-up account's "
+            "stages (server_start/backend_init/load/engine_build/first_run: "
+            "each its wall LESS the program build seconds booked on its "
+            "thread meanwhile, which tpusc_program_build_seconds holds; "
+            "load_overlap: the seconds a disk load's stages ran beside one "
+            "another, its children's sum less its wall): "
             "the in-production answer to 'where do my cold seconds go' and "
             "to the int8-vs-bf16 crossover (compare device_transfer + "
             "device_dequant across artifact encodings on YOUR link)",

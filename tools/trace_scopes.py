@@ -7,7 +7,11 @@ wall, busy = the union of the operations inside them, idle INSIDE them, the mean
 idle gap BEFORE one); and the device's idle time by cause: inside a program, or
 between two under the innermost ``tpusc.*`` annotation the engine's thread had
 open meanwhile (``utils/tracing.host_span``: ``tpusc.chunk_launch`` and
-``tpusc.chunk_fetch`` split ``tpusc.decode_chunk``). A device that waits inside
+``tpusc.chunk_fetch`` split ``tpusc.decode_chunk``); and the bring-up account's
+host events a capture held (``utils/bring_up.py``: ``tpusc.server_start``,
+``tpusc.load`` and its stages, ``tpusc.engine_build``, the ``tpusc.first_run``
+marker a new program's first execution leaves: a capture of the churn cell
+holds loads, one that met a compile a first run). A device that waits inside
 a program has bubbles between its operations; one that waits between two waits
 for the host. ``benchmark/capture_programs.py`` reads a traced benchmark run's
 capture the same way (one test holds the two to the same figures).
@@ -44,6 +48,10 @@ CAUSE = {"tpusc.chunk_launch": "launch path (under tpusc.chunk_launch)",
          None: "no boundary open"}
 CAUSE.update({"tpusc.emit": CAUSE["tpusc.boundary"], "tpusc.admit": CAUSE["tpusc.boundary"],
               "tpusc.state_insert": CAUSE["tpusc.prefill"]})
+# the bring-up account's stages (utils/bring_up.py) and a load's own
+BRING_UP = ("server_start", "backend_init", "provider_fetch", "load", "artifact_read",
+            "host_dequant", "device_transfer", "device_dequant", "transfer_sync",
+            "compile_warmup", "engine_build", "first_run")
 # a row: (plane, line, name, start_ns, dur_ns, {"scope", "program", "run_id"})
 
 
@@ -264,6 +272,20 @@ def idle_by_cause(rows, shift_ns: int = 0) -> list[tuple]:
     return sorted(acc.items(), key=lambda kv: -kv[1])
 
 
+def bring_up_marks(rows) -> list[tuple]:
+    """[(name, count, seconds)] of the bring-up account's host events, in
+    ``BRING_UP``'s order: a stage's span on whatever thread ran it.
+    ``tpusc.first_run`` is a MARKER at its call's return (the call's wall and
+    the program ride in its name, ``#program=..,wall_ms=..#``)."""
+    acc: dict[str, list] = {}
+    for _plane, _line, name, _s, dur, _x in rows:
+        base = name.split("#", 1)[0]
+        if base.startswith(MARK) and base[len(MARK):] in BRING_UP:
+            row = acc.setdefault(base, [0, 0.0])
+            row[0], row[1] = row[0] + 1, row[1] + dur / 1e9
+    return [(MARK + n, *acc[MARK + n]) for n in BRING_UP if MARK + n in acc]
+
+
 def main(argv: list[str]) -> int:
     if len(argv) != 1:
         print(__doc__, file=sys.stderr)
@@ -284,6 +306,11 @@ def main(argv: list[str]) -> int:
     for cause, sec in causes:
         if sec > 0:
             print(f"  {sec:8.4f}  {cause}")
+    marks = bring_up_marks(rows)
+    if marks:
+        print("\nbring-up host events (utils/bring_up.py): count, seconds")
+        for name, count, sec in marks:
+            print(f"  {count:7d} {sec:8.4f}  {name}")
     return 0
 
 
