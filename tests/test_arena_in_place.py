@@ -506,31 +506,57 @@ def test_insert_compiled_for_v5e_holds_no_arena_sized_copy(case, bucket):
     assert int(m.group(1)) < layer_bytes // 4, (found[str(bucket)], layer_bytes)
 
 
-# -- a lane state of two parts, 2.2 MB a layer a lane, compiled for the same v5e --
+# -- a lane state of two parts, 2.2 / 4.2 MB a layer a lane, compiled for the same v5e --
 
-def _compile_lane_state_for_v5e_main():
-    """Child-process body of the test below: the decode chunk and
-    ``_lane_insert_jit`` of a model with Olmo-Hybrid-7B's linear-attention
-    layers (6 of 8 layers as the benchmark runs it, 30 heads of 96 x 192: the
-    state is two arrays, ``(6, 16, 96, 5760)`` float32 = 212 MB and ``(6, 16,
-    3, 11520)`` bf16) at
-    16 lanes, compiled for a described v5e. The hidden size, the MLP and the
-    vocabulary are small: they are not what is compiled here. Prints what it
-    found, or NO_TOPOLOGY."""
+def _lane_state_models():
+    """{case: (family, config, lanes)} of the lane-state compile test: the
+    linear-attention layers of the two delta-rule cells at their published
+    widths and the benchmark's lanes, the hidden size, the MLP / experts and
+    the vocabulary small (they are not what is compiled here).
+    ``olmo_hybrid``: 6 of 8 layers, 30 heads of 96 x 192, a decay a head: the
+    state is ``(6, 16, 96, 5760)`` float32 = 212 MB and ``(6, 16, 3, 11520)``
+    bf16. ``kda_moe``: Solar-Open2's one period, 3 of 4 layers, 64 heads of
+    128 x 128, a decay a channel: ``(3, 32, 128, 8192)`` float32 = 403 MB."""
+    period = ["linear_attention"] * 3 + ["full_attention"]
+    return {
+        "olmo_hybrid": ("olmo_hybrid_lm", {
+            "vocab_size": 4096, "d_model": 512, "n_layers": 8,
+            "layer_types": period * 2,
+            "n_heads": 4, "n_kv_heads": 4, "d_ff": 1024, "linear_heads": 30,
+            "linear_key_dim": 96, "linear_value_dim": 192, "linear_conv": 4,
+            "max_seq": 2048, "dtype": "bfloat16"}, 16),
+        "kda_moe": ("kda_moe_lm", {
+            "vocab_size": 4096, "d_model": 512, "n_layers": 4,
+            "layer_types": ["full_attention"] + ["linear_attention"] * 3, "n_heads": 4,
+            "n_kv_heads": 2, "head_dim": 128, "linear_heads": 64,
+            "linear_key_dim": 128, "linear_value_dim": 128, "linear_conv": 4,
+            "linear_gate_rank": 64, "linear_allow_neg_eigval": True, "d_ff": 128,
+            "d_ff_shared": 128, "n_experts": 16, "n_experts_held": 8,
+            "expert_first": 0, "top_k": 4, "norm_topk_prob": True,
+            "route_score": "sigmoid", "route_scale": 1.0, "rms_eps": 1e-5,
+            "rope_theta": None, "max_seq": 2048, "dtype": "bfloat16"}, 32),
+    }
+
+
+def _compile_lane_state_for_v5e_main(case="olmo_hybrid"):
+    """Child-process body of the test below: the decode chunk of ``case``'s
+    model (``_lane_state_models``) compiled for a described v5e as the tree
+    writes it there (KERNEL: ``ops.delta_rule.delta_step_kernel``, the gate
+    open because the backend answers "tpu") and with the gate forced shut
+    (LOOP: ``_step_loop``, what every other backend runs), and for
+    ``olmo_hybrid`` ``_lane_insert_jit`` too. Prints what it found, with the
+    step's lines of ``dispatch_tally()``, or NO_TOPOLOGY."""
     from tfservingcache_tpu.models.registry import static_config
+    from tfservingcache_tpu.ops import delta_rule
+    from tfservingcache_tpu.ops.attention import dispatch_tally
 
     one = _described_v5e()
     if one is None:
         return
     jax.default_backend = lambda: "tpu"
-    family, lanes, pt, n_pages = "olmo_hybrid_lm", 16, 16, 1025
-    md = build(family, {
-        "vocab_size": 4096, "d_model": 512, "n_layers": 8,
-        "layer_types": ["linear_attention"] * 3 + ["full_attention"]
-        + ["linear_attention"] * 3 + ["full_attention"],
-        "n_heads": 4, "n_kv_heads": 4, "d_ff": 1024, "linear_heads": 30,
-        "linear_key_dim": 96, "linear_value_dim": 192, "linear_conv": 4,
-        "max_seq": 2048, "dtype": "bfloat16"})
+    family, config, lanes = _lane_state_models()[case]
+    pt, n_pages = 16, 1025
+    md = build(family, config)
     cfg = dict(static_config(md))
     s = jax.ShapeDtypeStruct
     on = lambda tree: jax.tree_util.tree_map(  # noqa: E731
@@ -546,29 +572,43 @@ def _compile_lane_state_for_v5e_main():
                s((lanes, cfg["max_seq"] // pt), jnp.int32), lane, lane,
                s((lanes,), jnp.bool_), s((), jnp.uint32),
                s((lanes,), jnp.float32), lane, state, None))
-    compiled = generation._paged_decode_chunk_jit.lower(
-        *args, cfg_key=static_config(md), family=family, chunk=8,
-        page_tokens=pt, kernel=True).compile()
-    hlo = compiled.as_text()
     whole = state[0].size
-    print("CHUNK temp", compiled.memory_analysis().temp_size_in_bytes,
-          "kernel", int("paged_decode_attention_kernel" in hlo),
-          "large", json.dumps(_large_results(hlo, whole)),
-          "layouts", json.dumps(_layouts(hlo, state[:1])))
-    new = jax.tree_util.tree_map(
-        lambda a: s((a.shape[0], 1, *a.shape[2:]), a.dtype), state)
-    compiled = generation._lane_insert_jit.lower(
-        *on((state, new, s((), jnp.int32)))).compile()
-    hlo = compiled.as_text()
-    print("INSERT temp", compiled.memory_analysis().temp_size_in_bytes,
-          "large", json.dumps(_large_results(hlo, whole)),
-          "layouts", json.dumps(_layouts(hlo, state[:1])))
+    refusal = delta_rule._step_kernel_refusal
+    for name, gate in (("KERNEL", refusal),
+                       ("LOOP", lambda *_: "the loop, for the comparison")):
+        delta_rule._step_kernel_refusal = gate
+        generation._paged_decode_chunk_jit.clear_cache()
+        compiled = generation._paged_decode_chunk_jit.lower(
+            *args, cfg_key=static_config(md), family=family, chunk=8,
+            page_tokens=pt, kernel=True).compile()
+        hlo = compiled.as_text()
+        steps = [line for line in hlo.splitlines()
+                 if "custom-call(" in line and "delta_step_kernel" in line]
+        print(name, "temp", compiled.memory_analysis().temp_size_in_bytes,
+              "kernel", int("paged_decode_attention_kernel" in hlo),
+              "large", json.dumps(_large_results(hlo, whole)),
+              "layouts", json.dumps(_layouts(hlo, state[:1])),
+              "steps", len(steps), "aliased",
+              sum(bool(re.search(r"output_to_operand_aliasing=\{\{0\}: \(\d+, \{\}\)", line))
+                  for line in steps))
+    delta_rule._step_kernel_refusal = refusal
+    print("TALLY", json.dumps(sorted(
+        [*key, n] for key, n in dispatch_tally().items() if key[0] == "delta_step_live")))
+    if case == "olmo_hybrid":
+        new = jax.tree_util.tree_map(
+            lambda a: s((a.shape[0], 1, *a.shape[2:]), a.dtype), state)
+        compiled = generation._lane_insert_jit.lower(
+            *on((state, new, s((), jnp.int32)))).compile()
+        hlo = compiled.as_text()
+        print("INSERT temp", compiled.memory_analysis().temp_size_in_bytes,
+              "large", json.dumps(_large_results(hlo, whole)),
+              "layouts", json.dumps(_layouts(hlo, state[:1])))
     print("STATE_BYTES", whole * 4, "LAYER_BYTES", whole * 4 // state[0].shape[0])
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled_lane_state():
-    return _child("_compile_lane_state_for_v5e_main()")
+def _compiled_lane_state(case):
+    return _child(f"_compile_lane_state_for_v5e_main({case!r})")
 
 
 @pytest.mark.parametrize("program", ["CHUNK", "INSERT"])
@@ -577,20 +617,22 @@ def test_lane_state_of_matrix_states_compiled_for_v5e_is_held_in_place(program):
     MB at 16 lanes, where phi4's is 0.10 GB and LFM2's 2.9 MB in all) rides
     donated through the decode chunk and through ``_lane_insert_jit`` and is
     written where it lies: every result as large as the state's float32 array
-    is the in-place write's (in the chunk a ``dynamic-update-slice`` a lane
-    of a trip a linear layer, ``ops.delta_rule.delta_step_live`` putting a
-    live lane's slice back; in the insert a lane's slice set) and nothing
+    is the in-place write's (in the chunk, with the step's gate shut, a
+    ``dynamic-update-slice`` a lane of a trip a linear layer,
+    ``ops.delta_rule._step_loop`` putting a live lane's slice back; in the
+    insert a lane's slice set) and nothing
     else: no ``copy``, no ``transpose``, no layout conversion, no gather or
     scatter that the compiler serves through a copy of the array, no layer's
     slice set whole; the array is row-major wherever its shape appears; the
     chunk's temporaries, the whole program's, are under two layers' slices
     of the state and the insert's are next to nothing. Skipped where libtpu
     cannot describe the topology."""
-    out, err = _compiled_lane_state()
+    out, err = _compiled_lane_state("olmo_hybrid")
     if "NO_TOPOLOGY" in out:
         pytest.skip("libtpu compile-only topology unavailable: "
                     + out.strip()[-300:])
-    m = re.search(program + r" temp (\d+) (?:kernel (\d) )?large (.*) layouts (.*)", out)
+    name = "LOOP" if program == "CHUNK" else program
+    m = re.search(name + r" temp (\d+) (?:kernel (\d) )?large (.*) layouts (\S*)", out)
     sizes = re.search(r"STATE_BYTES (\d+) LAYER_BYTES (\d+)", out)
     assert m and sizes, (out[-3000:], err[-3000:])
     state_bytes, layer_bytes = int(sizes.group(1)), int(sizes.group(2))
@@ -607,3 +649,42 @@ def test_lane_state_of_matrix_states_compiled_for_v5e_is_held_in_place(program):
         assert large and set(large) <= {
             "dynamic-update-slice", "fusion:dynamic-update-slice"}, large
         assert int(m.group(1)) < layer_bytes // 16, m.group(0)
+
+
+@pytest.mark.parametrize("case", ["olmo_hybrid", "kda_moe"])
+def test_decode_chunk_compiled_for_v5e_holds_the_step_kernel_in_place(case):
+    """With the step's gate open (the backend a TPU) the decode chunk of both
+    delta-rule models advances its matrix states through
+    ``delta_step_kernel``, one custom call a linear layer whose first output
+    IS its state operand (``output_to_operand_aliasing``), and NOTHING else
+    in the compiled program produces a result as large as the state's array:
+    no ``copy``, no gather, no ``dynamic-update-slice``, no layer's slice cut
+    out or set whole; the array is row-major wherever its shape appears and
+    the whole program's temporaries stay under two layers' slices.
+    ``dispatch_tally()`` says ``delta_step_live -> kernel`` there (a trace a
+    linear layer) and ``reference``, with the reason, for the form every other
+    backend takes. Skipped where libtpu cannot describe the topology."""
+    out, err = _compiled_lane_state(case)
+    if "NO_TOPOLOGY" in out:
+        pytest.skip("libtpu compile-only topology unavailable: "
+                    + out.strip()[-300:])
+    m = re.search(r"KERNEL temp (\d+) kernel (\d) large (.*) layouts (\S*) "
+                  r"steps (\d+) aliased (\d+)", out)
+    loop = re.search(r"LOOP temp \d+ kernel \d large (.*) layouts \S* steps (\d+)", out)
+    sizes = re.search(r"STATE_BYTES (\d+) LAYER_BYTES (\d+)", out)
+    tally = re.search(r"TALLY (.*)", out)
+    assert m and loop and sizes and tally, (out[-3000:], err[-3000:])
+    layers = {"olmo_hybrid": 6, "kda_moe": 3}[case]
+    assert int(sizes.group(1)) == {
+        "olmo_hybrid": 6 * 16 * 96 * 5760 * 4, "kda_moe": 3 * 32 * 128 * 8192 * 4}[case]
+    assert json.loads(m.group(3)) == {}, m.group(0)
+    assert json.loads(m.group(4)) == ["{3,2,1,0"], m.group(0)
+    assert int(m.group(5)) == int(m.group(6)) == layers, m.group(0)
+    assert m.group(2) == "1", "the paged kernel was not traced"
+    assert int(m.group(1)) < 2 * int(sizes.group(2)), m.group(0)
+    # the loop, forced: no kernel, its dynamic updates instead
+    assert int(loop.group(2)) == 0 and set(json.loads(loop.group(1))) == {
+        "dynamic-update-slice", "fusion:dynamic-update-slice"}, loop.group(0)
+    assert json.loads(tally.group(1)) == [
+        ["delta_step_live", "kernel", "pallas", layers],
+        ["delta_step_live", "reference", "the loop, for the comparison", layers]]

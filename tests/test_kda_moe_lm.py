@@ -812,3 +812,16 @@ def test_kda_rule_on_tpu(form):
               f"{ms_long * 1e3 / 6000:.2f} us a real token", flush=True)
         assert np.isfinite(np.asarray(got_o)).all()
         assert err_o < 0.05 * scale + 1e-3 and err_s < 0.05
+
+
+@ON_TPU
+def test_kda_rule_on_tpu_step_kernel():
+    """The one-token step at the benchmark's widths (64 heads of 128 x 128, a
+    decay a channel, 32 lanes, three layers) through ``delta_step_kernel``
+    (ISSUE 52) beside today's loop at 1, 3, 4, 5 and 8 live lanes: errors
+    against the float64 step and us a live lane a layer against the 10.4 the
+    state's bytes allow (8,503,552 B a lane at 819 GB/s)."""
+    from tests.test_delta_step_kernel import step_rows_on_tpu
+
+    step_rows_on_tpu("64 heads of 128 x 128, a decay a channel", 64, 128, 128, True,
+                     lanes=32, layers=3, floor_us=10.4)

@@ -461,15 +461,23 @@ def test_d_the_declaration_says_what_each_layer_keeps():
         build(NAME, dict(MC, layer_types=[L, "mamba"] * 4))
 
 
-@pytest.mark.parametrize("live,lanes,wrote", [
-    (0, 16, 0), (1, 16, 4), (4, 16, 4), (5, 16, 8), (13, 16, 16), (16, 16, 16),
-    (2, 3, 3)], ids=lambda v: str(v))
-def test_d_the_state_write_follows_the_live_lanes(live, lanes, wrote):
-    """``state_write_lanes`` on the numpy mirror is what the device's loop
-    takes: the live lanes rounded up to whole trips of ``STEP_GROUP``, every
-    lane of an engine no larger than a trip; every slot for a model whose
-    lane-state layers bring no ``step`` (the whole-array form), 0 for a model
-    with no lane state."""
+@pytest.mark.parametrize("live,lanes,loop,kernel", [
+    (0, 16, 0, 0), (1, 16, 4, 1), (4, 16, 4, 4), (5, 16, 8, 5), (13, 16, 16, 13),
+    (16, 16, 16, 16), (2, 3, 3, 2)], ids=lambda v: str(v))
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+def test_d_the_state_write_follows_the_live_lanes(monkeypatch, form, live, lanes,
+                                                  loop, kernel):
+    """``state_write_lanes`` on the numpy mirror is what the device's step
+    takes (``delta_rule.step_lanes_touched``, the step's own gate): where the
+    gate is shut (the CPU: ``_step_loop``) the live lanes rounded up to whole
+    trips of ``STEP_GROUP``, every lane of an engine no larger than a trip;
+    where it is open (``delta_step_kernel``; here through its interpreter) the
+    live lanes themselves; every slot for a model whose lane-state layers
+    bring no ``step`` (the whole-array form), 0 for a model with no lane
+    state."""
+    if form == "kernel":
+        monkeypatch.setattr(delta_rule, "DELTA_KERNEL_INTERPRET", True)
+    wrote = loop if form == "loop" else kernel
     cfg = dict(static_config(MODEL))
     active = np.arange(lanes) % 2 == 0 if live == 2 else np.arange(lanes) < live
     assert active.sum() == live
@@ -850,3 +858,16 @@ def test_delta_rule_on_tpu(form):
     assert k_o <= 1.1 * b_o and k_s <= 1.1 * b_s, found
     # the pad chunks past real_len are skipped, the state never leaves VMEM
     assert k_ms < b_ms / 3, found
+
+
+@ON_TPU
+def test_delta_rule_on_tpu_step_kernel():
+    """The one-token step at the benchmark's widths (30 heads of 96 x 192, a
+    decay a head, 16 lanes, six layers) through ``delta_step_kernel`` (ISSUE
+    52) beside today's loop at 1, 3, 4, 5 and 8 live lanes: errors against the
+    float64 step and us a live lane a layer against the 5.4 the state's bytes
+    allow (4,470,000 B a lane at 819 GB/s)."""
+    from tests.test_delta_step_kernel import step_rows_on_tpu
+
+    step_rows_on_tpu("30 heads of 96 x 192, a decay a head", 30, 96, 192, False,
+                     lanes=16, layers=6, floor_us=5.4)

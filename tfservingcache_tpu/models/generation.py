@@ -99,7 +99,7 @@ from tfservingcache_tpu.ops.attention import (
     paged_window_attention,
     unpack_pages,
 )
-from tfservingcache_tpu.ops.delta_rule import STEP_GROUP
+from tfservingcache_tpu.ops.delta_rule import step_lanes_touched
 
 # The slot-decode jits donate their K/V buffers (in-place update on TPU);
 # CPU/interpreter backends cannot honor donation and warn on EVERY dispatch
@@ -779,20 +779,20 @@ def state_write_lanes(active, cfg) -> int:
     """The lanes whose slice of the LANE STATE a decode step reads and writes,
     worked out on the host from the ``active`` mirror the chunk is dispatched
     with (the ring's ``state_lanes``). A model whose lane-state layers bring a
-    ``LaneState.step`` advances the live lanes rounded up to whole trips of
-    that step's loop (``ops.delta_rule.STEP_GROUP`` lanes a trip, as the KV
-    write's); any other sets each layer's slice whole
-    (``_PagedRows.keep_lane_state``: every lane, live or not; an inactive
-    lane's comes back bit for bit, but it is read and written). 0 for a model
-    with no lane-state layer."""
+    ``LaneState.step`` advances what ``ops.delta_rule.step_lanes_touched``
+    says: the live lanes where the step's kernel runs, whole trips of its
+    loop where it does not; any other sets each layer's slice whole
+    (``_PagedRows.keep_lane_state``: every lane, live or not: read and
+    written, though it comes back bit for bit). 0 with no lane-state layer."""
     kinds = [k for k in _layer_kinds(cfg) if isinstance(k, LaneState)]
     if not kinds:
         return 0
     active = np.asarray(active, bool)
-    if any(k.step is None for k in kinds) or active.size <= STEP_GROUP:
+    if any(k.step is None for k in kinds):
         return int(active.size)
-    trips = (int(active.sum()) + STEP_GROUP - 1) // STEP_GROUP
-    return min(int(active.size), trips * STEP_GROUP)
+    return step_lanes_touched(
+        int(active.sum()), int(active.size), kinds[0].dtype or cfg["dtype"],
+        *(int(cfg[k]) for k in ("linear_heads", "linear_key_dim", "linear_value_dim")))
 
 
 def window_pages_read(pos, active, chunk: int, window: int,

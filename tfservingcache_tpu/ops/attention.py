@@ -58,8 +58,8 @@ def dispatch_tally() -> dict[tuple[str, str, str], int]:
     """Snapshot of ``{(gate, branch, reason): traces}`` since process start.
     Gates: ``attention``, ``attention_window``, ``paged_attention``,
     ``paged_window_attention``, ``paged_attention_verify``,
-    ``paged_latent_attention``, ``ring_attention``, ``moe_experts``; branch
-    is ``"kernel"`` or ``"reference"``."""
+    ``paged_latent_attention``, ``ring_attention``, ``moe_experts``,
+    ``delta_chunked``, ``delta_step_live``; branch ``"kernel"`` / ``"reference"``."""
     with _DISPATCH_LOCK:
         return dict(_DISPATCH_TALLY)
 

@@ -99,6 +99,20 @@ records it under ``delta_chunked``):
   to: the inverse by ``_unit_lower_inverse`` for all of a block's chunks at
   once, a ``lax.scan`` over the chunks, a prompt longer than ``BLOCK`` tokens
   a block at a time.
+
+Which one-token step runs where (``_step_kernel_refusal`` decides, from the
+backend, the state's dtype and the widths when the program is traced;
+``dispatch_tally()`` records it under ``delta_step_live``):
+
+* on one TPU chip, for a float32 state whose heads go into blocks of whole
+  128-lane rows: ``delta_step_kernel``, ONE Pallas kernel a layer, in place on
+  the whole state array (aliased). Its grid is (block of whole heads, live
+  lane) with the live COUNT as the second bound, so a live lane's state is
+  read once and written once, a block at a time, and a lane that took nothing
+  costs no grid step. A decay a head and a decay a channel are one kernel:
+  the decay's rank is read from its shape.
+* everywhere else: ``_step_loop``, plain ``jax.numpy``, ``STEP_GROUP`` lanes a
+  trip, four passes over their states: the reference of the kernel's tests.
 """
 
 from __future__ import annotations
@@ -132,9 +146,10 @@ def _flat(state):
     return state.transpose(0, 2, 1, 3).reshape(b, d_k, h * d_v)
 
 
-# Lanes a trip of ``delta_step_live``'s loop: a trip takes their states out
-# of the array (2.2 MB each at 30 heads of 96 x 192), advances them and puts
-# them back; the engine runs 2 to 6 live lanes of 16: one or two trips.
+# Lanes a trip of ``_step_loop``, ``delta_step_live``'s form off the TPU: a
+# trip takes their states out of the array (2.2 MB each at 30 heads of 96 x
+# 192), advances them and puts them back; the engine runs 2 to 6 live lanes
+# of 16: one or two trips.
 STEP_GROUP = 4
 
 
@@ -190,21 +205,25 @@ def delta_step(state, q, k, v, alpha, beta, took=None):
 
 
 @jax.named_scope("step")
-def delta_step_live(states, layer: int, q, k, v, alpha, beta, took=None,
-                    live=None, group: int = STEP_GROUP):
+def delta_step_live(  # static-bounded: layer -- a model's linear-layer index (a few a model)
+        states, layer: int, q, k, v, alpha, beta, took=None, live=None):
     """``delta_step`` on layer ``layer``'s slice of the WHOLE state array
     ``states (layers, S, d_k, H x d_v)`` float32, in place on it, touching
     the lanes that took a token and no other -> (``o (S, H, d_v)`` float32,
     zeros for a lane that took none; the array after). The array is a decode
-    chunk's carry (donated): a trip of the loop takes ``group`` lanes'
-    states out of it (a dynamic slice a lane, no slice of a layer), advances
-    them and puts them back (a dynamic update a lane), so a step moves the
-    live lanes' states and the array is never copied, where setting a
-    layer's slice whole reads and writes every lane's. ``live`` is ``(order,
-    count)``, the lanes with those that took a token first and how many they
-    are (None: worked out from ``took``; ``took`` None = every lane). The last trip's
-    lanes past ``count`` took nothing and keep their states bit for bit (a
-    select)."""
+    chunk's carry (donated) and is never copied nor sliced a layer whole.
+    ``live`` is ``(order, count)``, the lanes with those that took a token
+    first and how many they are (None: worked out from ``took``; ``took``
+    None = every lane). Where ``_step_kernel_refusal`` has no objection (one
+    TPU chip, a float32 state, heads in blocks of whole 128-lane rows) the
+    live lanes go through ``delta_step_kernel``, a grid step a block of
+    whole heads of ONE live lane: each live lane's state is read once and
+    written once and no other lane's is touched. Elsewhere (the CPU, another
+    width) through ``_step_loop``, plain ``jax.numpy``, the reference the
+    tests hold the kernel to: a trip takes ``STEP_GROUP`` lanes' states out
+    of the array, live or not, and makes four passes over them.
+    ``ops.attention.dispatch_tally()`` records which under
+    ``delta_step_live``."""
     lanes = states.shape[1]
     q, k, v, alpha, beta = map(jnp.asarray, (q, k, v, alpha, beta))
     took = (jnp.ones((lanes,), bool) if took is None
@@ -212,35 +231,16 @@ def delta_step_live(states, layer: int, q, k, v, alpha, beta, took=None,
     if live is None:
         live = (jnp.argsort(~took, stable=True).astype(jnp.int32),
                 jnp.sum(took, dtype=jnp.int32))
-    order, count = live
-    group = min(group, lanes)
-    size = states.shape[2:]
-
-    def trip(i, carry):
-        states, o = carry
-        # past the end the slice is clamped: a lane an earlier trip took is
-        # met again there, and must not be advanced twice
-        first = jnp.minimum(i * group, lanes - group)
-        at = jax.lax.dynamic_slice(order, (first,), (group,))
-        # a lane's state by a dynamic slice of its own, and put back the same
-        # way: in place on the carry. (ONE gather of the group's lanes,
-        # ``states[layer, at]``, the v5e compiler serves by copying the whole
-        # array: 0.88 ms a trip a layer, my chip run, PR 46.)
-        old = jnp.concatenate([
-            jax.lax.dynamic_slice(states, (layer, at[j], 0, 0), (1, 1) + size)[0]
-            for j in range(group)])
-        o_at, new = _advance(old, q[at], k[at], v[at], alpha[at], beta[at])
-        real = took[at] & (first + jnp.arange(group) >= i * group)
-        new = jnp.where(real[:, None, None], new, old)
-        for j in range(group):
-            states = jax.lax.dynamic_update_slice(
-                states, new[j][None, None], (layer, at[j], 0, 0))
-        return states, o.at[at].set(jnp.where(real[:, None], o_at, o[at]))
-
-    states, o = jax.lax.fori_loop(
-        0, (count + group - 1) // group, trip,
-        (states, jnp.zeros((lanes, states.shape[-1]), jnp.float32)))
-    return o.reshape(v.shape), states
+    why = _step_kernel_refusal(states.dtype, *k.shape[1:], v.shape[-1])
+    _record_dispatch(
+        "delta_step_live", "reference" if why else "kernel",
+        why or ("interpret" if DELTA_KERNEL_INTERPRET else "pallas"),
+        states.shape, k.shape, v.shape)
+    if why is not None:
+        return _step_loop(states, layer, q, k, v, alpha, beta, took, live)
+    o, states = delta_step_kernel(states, layer, q, k, v, alpha, beta, *live,
+                                  interpret=bool(DELTA_KERNEL_INTERPRET))
+    return jnp.where(took[:, None, None], o.reshape(v.shape), 0.0), states
 
 
 @jax.named_scope("chunk")
@@ -1168,3 +1168,234 @@ def delta_channel_chunk_kernel(  # static-bounded: chunk, interpret -- chunk is 
         interpret=interpret, name="delta_channel_chunk_kernel",
     )(real_len.astype(jnp.int32), state.astype(f32), q, k, v,
       log_decay.astype(f32), rows, cols))
+
+
+# -- the one-token step on a layer's slice of the whole state array ------------------
+
+def _step_loop(states, layer: int, q, k, v, alpha, beta, took, live):
+    """``delta_step_live`` in plain ``jax.numpy``, the form every backend
+    takes and the reference of ``delta_step_kernel``: a trip of the loop
+    takes ``STEP_GROUP`` lanes' states out of the carry (a dynamic slice a
+    lane, no slice of a layer), advances them (``_advance``: four passes over
+    them) and puts them back (a dynamic update a lane), so a step moves whole
+    trips of lanes' states and the array is never copied. The last trip's
+    lanes past ``count`` took nothing and keep their states bit for bit (a
+    select)."""
+    lanes = states.shape[1]
+    order, count = live
+    group = min(STEP_GROUP, lanes)
+    size = states.shape[2:]
+
+    def trip(i, carry):
+        states, o = carry
+        # past the end the slice is clamped: a lane an earlier trip took is
+        # met again there, and must not be advanced twice
+        first = jnp.minimum(i * group, lanes - group)
+        at = jax.lax.dynamic_slice(order, (first,), (group,))
+        # a lane's state by a dynamic slice of its own, and put back the same
+        # way: in place on the carry. (ONE gather of the group's lanes,
+        # ``states[layer, at]``, the v5e compiler serves by copying the whole
+        # array: 0.88 ms a trip a layer, my chip run, PR 46.)
+        old = jnp.concatenate([
+            jax.lax.dynamic_slice(states, (layer, at[j], 0, 0), (1, 1) + size)[0]
+            for j in range(group)])
+        o_at, new = _advance(old, q[at], k[at], v[at], alpha[at], beta[at])
+        real = took[at] & (first + jnp.arange(group) >= i * group)
+        new = jnp.where(real[:, None, None], new, old)
+        for j in range(group):
+            states = jax.lax.dynamic_update_slice(
+                states, new[j][None, None], (layer, at[j], 0, 0))
+        return states, o.at[at].set(jnp.where(real[:, None], o_at, o[at]))
+
+    states, o = jax.lax.fori_loop(
+        0, (count + group - 1) // group, trip,
+        (states, jnp.zeros((lanes, states.shape[-1]), jnp.float32)))
+    return o.reshape(v.shape), states
+
+
+# -- the one-token step as a Pallas kernel, for the live lanes ---------------------
+
+# The most of a lane's state a grid step of ``delta_step_kernel`` holds: a
+# block is whole heads, in and out each twice in VMEM (the pipeline's buffers
+# fetch the next block and write the last one back while this one is
+# advanced), within the 16 MB a kernel has without asking. Olmo-Hybrid's lane
+# (96 x 5760 float32) is one block, Solar-Open2's (128 x 8192) two.
+STEP_BLOCK_BYTES = 9 << 18
+
+
+def _step_blocking(heads: int, d_k: int, d_v: int) -> tuple[int, int] | None:
+    """(heads a UNIT, heads a block) of the step kernel: a unit is the fewest
+    heads whose value columns are whole 128-lane rows (one head of 128, two of
+    192); a block is the most units within ``STEP_BLOCK_BYTES`` of state that
+    divide the heads and are a multiple of 16 heads, or all of them (so that a
+    block of ``k (S, H, d_k)`` is whole tiles as the projection left it, with
+    no copy made for the kernel); None where there is no such block."""
+    unit = next(n for n in range(1, 129) if n * d_v % 128 == 0)
+    blocks = [n for n in range(unit, heads + 1, unit)
+              if heads % n == 0 and (n % 16 == 0 or n == heads)
+              and 4 * d_k * n * d_v <= STEP_BLOCK_BYTES]
+    return (unit, max(blocks)) if blocks else None
+
+
+def _step_kernel_refusal(state_dtype, heads: int, d_k: int, d_v: int) -> str | None:
+    """Why ``delta_step_live`` cannot advance its lanes through
+    ``delta_step_kernel`` on this backend (None = it can): the TPU (one chip:
+    the families bind no mesh), a float32 state, key rows of whole sublane
+    tiles and heads that go into blocks of whole 128-lane rows
+    (``_step_blocking``). Numbers, not arrays: the host asks too
+    (``step_lanes_touched``)."""
+    if not DELTA_KERNEL_INTERPRET and jax.default_backend() != "tpu":
+        return f"backend={jax.default_backend()}"
+    if jnp.dtype(state_dtype) != jnp.float32:
+        return f"state {jnp.dtype(state_dtype)}, not float32"
+    if d_k % 8:
+        return f"d_k={d_k} no multiple of 8"
+    if _step_blocking(heads, d_k, d_v) is None:
+        return (f"{heads} heads of {d_k} x {d_v} in no blocks of whole 128-lane "
+                f"rows within {STEP_BLOCK_BYTES} bytes")
+    return None
+
+
+def _delta_step_body(order_ref, beta_ref, *refs, unit: int, d_v: int,
+                     channel: bool):
+    """A grid step: one block of whole heads of one LIVE lane's state, read
+    once and written once. ``s_in`` / ``s_out (d_k, block x d_v)`` are that
+    block in and out (the same bytes of the aliased array), ``k_ref`` / ``q_ref
+    (block, d_k)`` its heads' keys and queries as the projection left them
+    (``a_ref``, the same shape in float32: a decay a channel); ``v_ref (rows,
+    block x d_v)`` holds the lane's value row among its neighbours' and
+    ``o_ref (lanes, block x d_v)`` every lane's output row of these columns
+    (it stays in VMEM while the grid walks the live lanes and takes the
+    lane's row); ``beta_ref (lanes, heads)`` in SMEM holds the write strength
+    a scalar a head (``alpha_ref`` beside it: a decay a head). A head's key,
+    query and channel decay are transposed once a block (a column a head) and
+    spread over the head's ``d_v`` columns by a broadcast along the lanes;
+    every product and sum is float32 on the vector unit, in the order
+    ``_advance`` has them. A unit of heads is whole 128-lane rows: where it
+    holds two heads, a select on the column puts each over its own."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    if channel:
+        s_in, k_ref, q_ref, v_ref, a_ref, s_out, o_ref = refs
+    else:
+        alpha_ref, s_in, k_ref, q_ref, v_ref, s_out, o_ref = refs
+    block, d_k = k_ref.shape
+    first, lane = pl.program_id(0) * block, order_ref[pl.program_id(1)]
+    width = unit * d_v
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    # the lane's row of ``v`` and of ``o``: inside a sublane tile of lanes'
+    # rows, which is what a load or a store at a traced row can address
+    rows = v_ref.shape[0]
+    mine = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) == lane % rows
+    tile = pl.ds(pl.multiple_of(lane // rows * rows, rows), rows)
+    # a column a head: (d_k, block)
+    k_t, q_t = (ref[...].astype(f32).T for ref in (k_ref, q_ref))
+    a_t = a_ref[...].T if channel else None
+
+    def over(values):
+        """The unit's heads' ``values`` (a column or a scalar each), each
+        over its own head's columns."""
+        out = values[0]
+        for n in range(1, unit):
+            out = jnp.where(col >= n * d_v, values[n], out)
+        return out
+
+    def spread(columns, head: int):
+        return over([jnp.broadcast_to(columns[:, head + n:head + n + 1],
+                                      (d_k, width)) for n in range(unit)])
+
+    def scalars(ref, head: int):
+        return over([jnp.full((1, width), ref[lane, first + head + n], f32)
+                     for n in range(unit)])
+
+    for head in range(0, block, unit):
+        at = slice(head * d_v, (head + unit) * d_v)
+        decay = spread(a_t, head) if channel else scalars(alpha_ref, head)
+        s = decay * s_in[:, at]
+        k_col = spread(k_t, head)
+        v_row = jnp.sum(jnp.where(mine, v_ref[:, at], 0.0), axis=0, keepdims=True)
+        u = scalars(beta_ref, head) * (
+            v_row - jnp.sum(k_col * s, axis=0, keepdims=True))
+        s = s + k_col * u
+        s_out[:, at] = s
+        o_row = jnp.sum(spread(q_t, head) * s, axis=0, keepdims=True)
+        o_ref[tile, at] = jnp.where(mine, o_row, o_ref[tile, at])
+
+
+@functools.partial(jax.jit, static_argnames=("layer", "interpret"))
+def delta_step_kernel(  # static-bounded: layer, interpret -- layer is a model's linear-layer index (a few a model); interpret is boolean (the tests' flag)
+        states, layer: int, q, k, v, alpha, beta, order, count,
+        interpret: bool = False):
+    """The one-token step as ONE kernel a layer, for the live lanes: ``states
+    (layers, S, d_k, H x d_v)`` float32 in and out IN PLACE
+    (``input_output_aliases``: the array is never copied nor sliced), ``q`` /
+    ``k (S, H, d_k)``, ``v (S, H, d_v)``, ``alpha (S, H)`` (``(S, H, d_k)``: a
+    decay a channel; the rank is read from the shape) and ``beta (S, H)``,
+    ``order (S,)`` int32 the lanes with the live ones first and ``count`` how
+    many those are -> (``o (S, H x d_v)`` float32, written for the live lanes
+    ONLY: the other rows are whatever the buffer held; the array after). The
+    grid is (block of whole heads, live lane) and its second bound is
+    ``count`` itself, an operand: a lane that took nothing costs no grid step,
+    no fetch and no write, and its state keeps its bytes. A block's index map
+    reads ``(layer, order[i])`` from the prefetched scalars, so a live lane's
+    state crosses HBM once each way, a block at a time through the pipeline's
+    buffers. ``v`` and ``o`` go in and out as ``(S, H x d_v)`` float32, lanes
+    along the sublanes (a lane's row is picked inside the kernel): a row a
+    lane as ``(S, 1, H x d_v)`` made the v5e compiler lay the convolution's
+    arrays beside them out a tap a sublane, and a decode step paid 0.13 ms
+    for it (my chip run, PR 52)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    _, lanes, d_k, width = states.shape
+    h = k.shape[1]
+    d_v = width // h
+    channel = alpha.ndim == k.ndim
+    unit, block = _step_blocking(h, d_k, d_v)
+    rows = 8 if lanes % 8 == 0 else lanes       # a sublane tile of value rows
+    # a decay a head rides with the write strengths, a scalar a head in SMEM
+    scalars = [order.astype(jnp.int32), beta.astype(f32)]
+    operands = [states, k, q, v.astype(f32).reshape(lanes, width)]
+    (operands if channel else scalars).append(alpha.astype(f32))
+
+    state_block = pl.BlockSpec((None, None, d_k, block * d_v),
+                               lambda j, i, order, *_: (layer, order[i], 0, j))
+    head_block = pl.BlockSpec((None, block, d_k),
+                              lambda j, i, order, *_: (order[i], j, 0))
+    value_block = pl.BlockSpec((rows, block * d_v),
+                               lambda j, i, order, *_: (order[i] // rows, j))
+    out_block = pl.BlockSpec((lanes, block * d_v), lambda j, i, *_: (0, j))
+    body = functools.partial(_delta_step_body, unit=unit, d_v=d_v, channel=channel)
+    states, o = pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(h // block, count.astype(jnp.int32)),
+            in_specs=[state_block, head_block, head_block, value_block]
+            + [head_block] * channel,
+            out_specs=[state_block, out_block]),
+        out_shape=[jax.ShapeDtypeStruct(states.shape, f32),
+                   jax.ShapeDtypeStruct((lanes, width), f32)],
+        input_output_aliases={len(scalars): 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret, name="delta_step_kernel",
+    )(*scalars, *operands)
+    return o, states
+
+
+def step_lanes_touched(live: int, lanes: int, state_dtype, heads: int, d_k: int,
+                       d_v: int) -> int:
+    """The lanes whose states one ``delta_step_live`` call reads and writes
+    when ``live`` of ``lanes`` took a token, on this backend at these widths:
+    the live lanes where the gate lets the kernel run, whole trips of
+    ``_step_loop`` where it does not (every lane of an array no larger than a
+    trip). Host arithmetic, for the engine's ring
+    (``generation.state_write_lanes``)."""
+    if _step_kernel_refusal(state_dtype, heads, d_k, d_v) is None:
+        return live
+    if lanes <= STEP_GROUP:
+        return lanes
+    return min(lanes, -(-live // STEP_GROUP) * STEP_GROUP)

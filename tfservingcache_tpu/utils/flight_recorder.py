@@ -146,8 +146,8 @@ STEP_FIELDS = (
     "shared_pages",
     # appended field (ISSUE 46 a matrix state a head): the lanes whose slice of
     # the LANE STATE each step of this chunk read and wrote
-    # (generation.state_write_lanes: every lane of the state, live or not,
-    # while a step updates the state's arrays whole). 0 for a model with no
+    # (generation.state_write_lanes: what a model's ``LaneState.step`` touches,
+    # else every lane of the state, live or not). 0 for a model with no
     # lane-state layer, for a spec round and for a boundary that ran no chunk
     "state_lanes",
 )

@@ -96,6 +96,15 @@ def main() -> int:
     # of 2048 (1500 real) and 8192 (6000 real) against the step iterated,
     # decays down to 0.05 a step: largest errors and ms a layer side by side,
     # `-k "kda_rule_on_tpu"`, ~2 min.
+    # Both files carry (PR 52) the one-token step's KERNEL rows
+    # (``delta_step_kernel``, what ``delta_step_live`` runs on the chip) beside
+    # the loop it replaced there (the gate forced shut) at 1, 3, 4, 5 and 8
+    # live lanes of 32 / 16 at the two cells' widths
+    # (``tests/test_delta_step_kernel.step_rows_on_tpu``): us a layer and a
+    # live lane against the 10.4 / 5.4 the state's bytes allow, the largest
+    # error of the output and of the state against the float64 step, every
+    # other slice bit for bit: `-k "on_tpu_step_kernel"`, ~1 min (also matched
+    # by `-k kda_rule_on_tpu` / `-k delta_rule_on_tpu`).
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
