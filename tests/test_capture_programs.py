@@ -370,7 +370,23 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     # channel-decay chunked rule's kernel's share (the twin of PR 47's), PR 51
     # the bring-up account's six (every cell; the two of the arena the eight
     # generating cells)
+    # PR 53 the five of a model whose query heads go by layer (its cell alone)
+    # and its cell on every list the generating cells are on
     cells = [w["name"] for w in bench_json["workloads"]]
+    heads = bench_json["per_layer"][-5:]
+    assert [m["name"] for m in heads] == [
+        "heads_window_decode_roofline", "heads_global_decode_roofline",
+        "heads_window_prefill_roofline", "attn_kind_ms_per_step",
+        "share_sparse_experts_roofline"]
+    for m in heads:
+        assert m["workloads"] == ["laguna-repoctx-steady"]
+        assert (m["source"], m["moves"]) == ("device_trace", "tpot_p50_ms")
+        assert (m["unit"], m["better"]) == (
+            ("ms", "lower") if m["name"] == "attn_kind_ms_per_step"
+            else ("%", "higher"))
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
+    bench_json["per_layer"] = bench_json["per_layer"][:-5]
+    assert {m["layer"] for m in heads} <= {m["layer"] for m in bench_json["per_layer"]}
     account = bench_json["per_layer"][-6:]
     assert [m["name"] for m in account] == [
         "setup_trace_lower_s", "setup_compile_s", "setup_load_s",
@@ -416,7 +432,7 @@ def test_the_five_metrics_are_appended_for_the_four_generate_cells():
     cells = ["mistral7b-chat-steady", "olmoe-chat-steady", "mistral4-docqa-steady",
              "lfm2-longgen-steady", "mellum2-codectx-mixed",
              "phi4flash-reasoning-steady", "olmohybrid-longdoc-steady",
-             "solaropen2-docreport-steady"]
+             "solaropen2-docreport-steady", "laguna-repoctx-steady"]
     layers = {m["layer"] for m in bench_json["per_layer"][:-23]}
     assert {m["layer"] for m in kda} | {twin["layer"]} <= layers
     assert last["layer"] in layers

@@ -69,8 +69,8 @@ def _reference_experts(family: str):
                   rope_factor=4.0, rope_beta_fast=32.0, rope_beta_slow=1.0,
                   rope_original_max=32, rope_mscale_all_dim=1.0, llama4_beta=0.1)
         *_, gates, add_experts, _head = module._fns(tuple(sorted(mc.items())))
-    else:       # kda_moe: Solar-Open2's expert layer behind either mixer
-        import json
+    else:       # kda_moe: Solar-Open2's expert layer behind either mixer;
+        import json     # laguna: Laguna-S-2.1's behind either kind of attention
 
         mc = dict(routing, n_heads=4, n_kv_heads=2, head_dim=8, linear_heads=2,
                   linear_key_dim=8, linear_value_dim=8,
@@ -79,11 +79,13 @@ def _reference_experts(family: str):
     return gates, add_experts
 
 
-# the deployments the benchmark's two share configurations stand for: an EP-4
-# host (Mistral-Small-4) and an EP-8 host (Solar-Open2), each against its own
-# family's plain reference
-@pytest.mark.parametrize("family,shares", [("mla_moe", 4), ("kda_moe", 8)],
-                         ids=["four_shares_mla_moe", "eight_shares_kda_moe"])
+# the deployments the benchmark's three share configurations stand for: an
+# EP-4 host (Mistral-Small-4) and two EP-8 hosts (Solar-Open2; Laguna-S-2.1,
+# whose router has no selection bias), each against its own family's plain
+# reference
+@pytest.mark.parametrize(
+    "family,shares", [("mla_moe", 4), ("kda_moe", 8), ("laguna", 8)],
+    ids=["four_shares_mla_moe", "eight_shares_kda_moe", "eight_shares_laguna"])
 @pytest.mark.parametrize("interpret", [False, True], ids=["ragged_dot", "kernel"])
 def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
         monkeypatch, interpret, family, shares):
@@ -93,6 +95,8 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
     ``add_experts``)."""
     monkeypatch.setattr(moe, "MOE_KERNEL_INTERPRET", interpret)
     layer, x = _layer(1)
+    if family == "laguna":
+        layer.pop("bias")
     cfg = {"top_k": K, "norm_topk_prob": True, "route_score": "sigmoid",
            "route_scale": 2.5, "rms_eps": 1e-6, "n_experts": E}
     ln2 = jnp.asarray(1.0 + 0.1 * np.random.default_rng(2).standard_normal(D),
@@ -116,7 +120,8 @@ def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
 
     gates, add_experts = _reference_experts(family)
     with jax.default_matmul_precision("highest"):
-        z, y, weight = gates(x, ln2, layer["router"], layer["bias"], layer["shared"])
+        bias = (layer["bias"],) if "bias" in layer else ()
+        z, y, weight = gates(x, ln2, layer["router"], *bias, layer["shared"])
         want = add_experts(y, z, weight, layer["w1"], layer["w3"], layer["w2"]) - x
     np.testing.assert_allclose(total, np.asarray(want), atol=1e-5, rtol=0)
 

@@ -59,12 +59,22 @@ FAMILIES = {
         {"layer/attn/kv_write"}),
     "hybrid_lm": (
         "lfm2-8b-a1b",
-        {"layer/attn", "layer/conv", "layer/kv_write", "layer/ffn"} | EXPERTS,
+        # its leading dense layers lie beside expert layers: ``ffn/dense``
+        {"layer/attn", "layer/conv", "layer/kv_write", "layer/ffn",
+         "layer/ffn/dense"} | EXPERTS,
         KV_PAGED, KV_DENSE),
     "moe_lm-window": (
         "mellum2-12b-a2.5b-instruct",
         {"layer/attn", "layer/attn/global", "layer/attn/window",
          "layer/kv_write", "layer/ffn"} | EXPERTS,
+        WINDOWED_PAGED, KV_DENSE),
+    # query heads a layer, a gate a head under its kind's scope, one dense
+    # layer among the expert ones, a share of the experts beside a shared one
+    "moe_lm-heads": (
+        "laguna-s-2.1",
+        {"layer/attn", "layer/attn/global", "layer/attn/window",
+         "layer/attn/global/gate", "layer/attn/window/gate", "layer/kv_write",
+         "layer/ffn", "layer/ffn/dense", "layer/ffn/shared"} | EXPERTS,
         WINDOWED_PAGED, KV_DENSE),
     "sambay_lm": (
         "phi-4-mini-flash-reasoning",
@@ -212,8 +222,10 @@ LOOPED = {
     "moe_lm": {"layer/attn"},
     "mla_moe_lm": {"layer/attn", "layer/attn/q_lora", "layer/attn/kv_lora",
                    "layer/ffn/shared"},
-    "hybrid_lm": {"layer/attn", "layer/ffn"},
+    "hybrid_lm": {"layer/attn", "layer/ffn/dense"},
     "moe_lm-window": {"layer/attn", "layer/attn/global", "layer/attn/window"},
+    "moe_lm-heads": {"layer/attn", "layer/attn/global", "layer/attn/window",
+                     "layer/ffn/dense", "layer/ffn/shared"},
     "sambay_lm": {"layer/ffn"},
     "olmo_hybrid_lm": {"layer/attn", "layer/ffn", "layer/gdn/proj",
                        "layer/gdn/conv", "layer/gdn/gate"},

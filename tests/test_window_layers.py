@@ -459,8 +459,8 @@ def test_g_the_benchmark_configuration_at_its_published_widths():
     # reference's own
     import tfservingcache_tpu.models.transformer_lm as lm
 
-    plain, one = lm.rope_of(mc, 1024)
-    freqs, factor = lm.rope_of(mc, 0)
+    plain, one, _ = lm.rope_of(mc, 1024)
+    freqs, factor, _ = lm.rope_of(mc, 0)
     assert plain is None and one == 1.0 and factor == 1.2772588722239782
     want, _ = FAMILY.rope_frequencies(mc, F)
     np.testing.assert_allclose(freqs, want, rtol=1e-6)

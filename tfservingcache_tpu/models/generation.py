@@ -78,6 +78,7 @@ from tfservingcache_tpu.models.registry import (
     NoState,
     SharedRows,
     kv_cache_row,
+    query_heads,
     static_config,
 )
 from tfservingcache_tpu.models.sambay_lm import diff_finish, diff_project
@@ -86,6 +87,7 @@ from tfservingcache_tpu.models.transformer_lm import (
     _output_logits,
     _qkv,
     _rmsnorm,
+    head_gate,
     rope_of,
 )
 from tfservingcache_tpu.ops.attention import (
@@ -452,7 +454,7 @@ def _attends_tokens_at_hand(cfg, cache, s_len: int) -> bool:
     if _window_of(cfg):
         return True
     _, batch, _, max_len, _ = cache["k"].shape
-    return (batch * int(cfg["n_heads"]) * s_len * max_len * 4
+    return (batch * query_heads(cfg) * s_len * max_len * 4
             >= _SCORE_BLOCK_BYTES)
 
 
@@ -1481,7 +1483,8 @@ MOE_STATS = ("experts_hit", "expert_rows_max", "expert_rows_local")
 
 
 def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
-               moe_stats: list | None = None, took=None):
+               moe_stats: list | None = None, took=None,
+               beside_experts: bool = False):
     """The second half of a decoder layer (input is the residual stream
     BEFORE its norm; returns the residual delta), chosen by what the layer
     holds: ``moe`` = the routed expert layer, else the dense SwiGLU ``mlp``
@@ -1492,19 +1495,22 @@ def _ffn_block(layer: dict, x, cfg: dict, dtype, row_mask=None,
     many of each example's rows are real (None = all): the dense MLP, and an
     expert layer's shared expert, run over the row blocks that hold them
     (``over_real_rows``). An expert layer's routing stats (``MOE_STATS``) are
-    appended to ``moe_stats`` where the caller gives a list."""
+    appended to ``moe_stats`` where the caller gives a list. A dense layer of
+    a model that holds expert layers too (``beside_experts``) runs under
+    ``layer/ffn/dense``, so that no reader by scope books it to the experts;
+    a model that is dense throughout keeps ``layer/ffn``."""
     if "moe" in layer:
         y, stats = _moe_block(layer, x, cfg, dtype, row_mask=row_mask,
                               took=took)
         if moe_stats is not None:
             moe_stats.append(jnp.stack([stats[name] for name in MOE_STATS]))
         return y
-    with jax.named_scope("ffn"):
+    with jax.named_scope("ffn/dense" if beside_experts else "ffn"):
         after = "ln2_post" in layer      # a reordered block: the norm follows
         mlp = jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"])
 
         def rows_mlp(x):
-            h = x if after else _norm(layer, "ln2", x)
+            h = x if after else _norm(layer, "ln2", x, _norm_eps(cfg))
             y = (jax.nn.silu(h @ mlp["w1"]) * (h @ mlp["w3"])) @ mlp["w2"]
             return _norm(layer, "ln2_post", y, _norm_eps(cfg)) if after else y
 
@@ -1528,6 +1534,7 @@ def _walk_layers(params, ids, rows, cfg, logits_at=None,
     one position of each example through the head."""
     dtype = jnp.dtype(cfg["dtype"])
     handed: dict = {}     # what a layer's operator hands on to later layers
+    beside_experts = any("moe" in layer for layer in params["layers"])
 
     with jax.named_scope("embed"):
         x = params["embed"][ids].astype(dtype)                   # (B, T, d)
@@ -1554,7 +1561,8 @@ def _walk_layers(params, ids, rows, cfg, logits_at=None,
             else:
                 x = _attend_rows(layer, x, kind, slot, depth, rows, cfg)
             x = x + _ffn_block(layer, x, cfg, dtype, row_mask=rows.active,
-                               moe_stats=moe_stats, took=rows.took)
+                               moe_stats=moe_stats, took=rows.took,
+                               beside_experts=beside_experts)
     if logits_at is not None:
         x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     return _output_logits(params, x, dtype, _norm_eps(cfg))
@@ -1579,6 +1587,12 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
     whole)."""
     b, t, _ = x.shape
     shared = isinstance(kind, SharedRows)
+
+    def kind_scope():
+        return _kind_scope(
+            bool(_window_of(cfg)),
+            "cross" if shared else "window" if slot.window else "global")
+
     with jax.named_scope("attn"):
         attn = jax.tree_util.tree_map(lambda w: w.astype(x.dtype), layer["attn"])
         after = "ln1_post" in layer      # a reordered block: the norm follows
@@ -1602,26 +1616,26 @@ def _attend_rows(layer: dict, x, kind, slot: Slot, depth: int, rows, cfg):
 
             def project(x, positions):
                 a = normed(x)
-                q, k, v = _qkv(attn, a, cfg["n_heads"], cfg["n_kv_heads"], *eps)
+                q, k, v = _qkv(attn, a, query_heads(cfg, depth),
+                               cfg["n_kv_heads"], *eps)
                 if cfg["rope_theta"] is not None:     # None: no rotary at all
                     q = _rope_per_example(q, positions, cfg["rope_theta"], rope)
                     k = _rope_per_example(k, positions, cfg["rope_theta"], rope)
                 if "w_gate" not in attn:
                     return q, k, v, None
-                # an output gate a head column, laid out as the queries are
-                gate = (a @ attn["w_gate"]).reshape(b, -1, *q.shape[1::2])
-                return q, k, v, jax.nn.sigmoid(gate).transpose(0, 2, 1, 3)
+                # an output gate a head column or a head (the leaf's width
+                # says which), laid out as the heads' outputs are
+                with kind_scope():
+                    return q, k, v, head_gate(attn, a, q.shape[1])
 
             # the heads' rows lie along axis 2: (B, heads, T, width)
             q, k, v, gate = over_real_rows(
                 project, (x, rows.positions), rows.took, out_axis=2)
     if not shared:
         rows.write(slot, k, v)
-    with jax.named_scope("attn"), _kind_scope(
-            bool(_window_of(cfg)),
-            "cross" if shared else "window" if slot.window else "global"):
+    with jax.named_scope("attn"), kind_scope():
         out = rows.attend(slot, q, scale).reshape(
-            b, cfg["n_heads"], t, q.shape[-1])
+            b, q.shape[1], t, q.shape[-1])
         if differential:
             return x + diff_finish(attn, diff_outputs(out), depth, x.dtype)
 
@@ -1785,12 +1799,17 @@ class _DenseRows:
         return new_cache
 
 
-def _rope_per_example(x, positions, theta, rope=(None, 1.0)):
+def _rope_per_example(x, positions, theta, rope=(None, 1.0, 0)):
     """Rotary embedding with per-example positions (B, S) over (B, H, S, D);
     ``rope`` is ``transformer_lm.rope_of``'s answer for the layer: the plain
-    ``theta`` frequencies, or a kind's own with cos and sin times its factor."""
+    ``theta`` frequencies, or a kind's own with cos and sin times its factor,
+    over the head's first ``turned`` columns where the kind turns a part."""
     d = x.shape[-1]
-    freqs, factor = rope
+    freqs, factor, turned = rope
+    if turned:    # a partial rotary: the leading columns turn, the rest pass
+        head = _rope_per_example(x[..., :turned], positions, theta,
+                                 (freqs, factor, 0))
+        return jnp.concatenate([head, x[..., turned:]], axis=-1)
     if freqs is None:
         freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[..., None].astype(jnp.float32) * jnp.asarray(freqs)[None, None, :]  # (B,S,d/2)

@@ -32,6 +32,20 @@ global layers what ``rope_full`` states (YaRN's blend, cos and sin times its
 width where it is not ``d_model / n_heads``. A config with none of these keys
 (OLMoE's) builds the program it always built.
 
+What a config may state besides, each absent = the program as it was
+(Laguna-S-2.1 is the configuration that states them all): the query heads A
+LAYER (``n_heads_per_layer``, read through ``registry.query_heads``; the KV
+row is the same in every layer, so the arenas do not follow it); a theta of
+the window layers' own (``rope_theta_window``) and a rotary over a share of a
+global layer's head (``rope_full.partial``); an output gate of one value a
+head (``attn_gate: "head"`` makes the ``attn/w_gate (d, heads)`` leaf, whose
+shape says what it is); dense SwiGLU layers among the expert ones
+(``mlp_only_layers``, of width ``d_ff_dense``: such a layer holds ``mlp``, and
+``generation._ffn_block`` goes by what a layer holds); and the expert layer
+of ONE CHIP of an expert-parallel host (``n_experts_held`` from
+``expert_first``, ``route_score`` / ``route_scale``, a shared expert of
+``shared_width`` under ``moe/shared``: ``_moe_block``'s keys).
+
 Expert weights carry an ``("expert", None, None)`` partition rule, so on a
 mesh with an "expert" axis each chip group holds E/ep experts; there the
 grouped product is ``jax.lax.ragged_dot`` (the Pallas kernel is single-chip).
@@ -51,10 +65,12 @@ from tfservingcache_tpu.models.registry import (
     TensorSpec,
     head_width,
     kv_cache_row,
+    query_heads,
     register,
 )
 from tfservingcache_tpu.models.transformer_lm import (
     _attention_block,
+    _mlp_block,
     _output_logits,
     _rmsnorm,
 )
@@ -177,7 +193,7 @@ def _forward(params: dict, input_ids: jax.Array, cfg: dict, mesh=None) -> tuple[
     aux_total = jnp.zeros((), jnp.float32)
     eps = cfg.get("rms_eps", 1e-5)
     kinds = layer_state_of(cfg) or (None,) * len(params["layers"])
-    for layer, kind in zip(params["layers"], kinds):
+    for depth, (layer, kind) in enumerate(zip(params["layers"], kinds)):
         with jax.named_scope("layer"):
             x = x + _attention_block(
                 jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["attn"]),
@@ -185,14 +201,21 @@ def _forward(params: dict, input_ids: jax.Array, cfg: dict, mesh=None) -> tuple[
                 cfg,
                 mesh,
                 window=kind.window if kind else 0,
+                depth=depth,
             )
+            if "mlp" in layer:    # a dense layer among the expert ones
+                x = x + _mlp_block(
+                    jax.tree_util.tree_map(lambda w: w.astype(dtype), layer["mlp"]),
+                    _rmsnorm(x, layer["ln2"], eps))
+                continue
             y, stats = _moe_block(layer, x, cfg, dtype, partitioned=partitioned)
             x = x + y
         # Switch load-balance aux loss: e * sum_e(frac_tokens_e * mean_prob_e)
         frac = jnp.mean(jnp.sum(jax.nn.one_hot(stats["experts"], e), axis=1), axis=0)
         aux_total = aux_total + e * jnp.sum(frac * jnp.mean(stats["probs"], axis=0))
     logits = _output_logits(params, x, dtype, eps)
-    return logits, aux_total / max(len(params["layers"]), 1)
+    expert_layers = sum("moe" in layer for layer in params["layers"])
+    return logits, aux_total / max(expert_layers, 1)
 
 
 @register("moe_lm", DEFAULT_CONFIG)
@@ -211,38 +234,54 @@ def build(config: dict) -> ModelDef:
     apply = make_apply(None)
 
     def init(rng):
-        d, v, ff, e = cfg["d_model"], cfg["vocab_size"], cfg["d_ff"], cfg["n_experts"]
-        n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"]
+        d, v, ff = cfg["d_model"], cfg["vocab_size"], cfg["d_ff"]
+        e = int(cfg.get("n_experts_held", cfg["n_experts"]))   # held HERE
+        n_kv = cfg["n_kv_heads"]
         head_dim = head_width(cfg)
+        dense_layers = set(cfg.get("mlp_only_layers") or ())
         keys = jax.random.split(rng, cfg["n_layers"] + 1)
 
         def dense(key, fan_in, shape):
             return jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
 
+        def swiglu(key, width):
+            k1, k2, k3 = jax.random.split(key, 3)
+            return {"w1": dense(k1, d, (d, width)), "w2": dense(k2, width, (width, d)),
+                    "w3": dense(k3, d, (d, width))}
+
         layers = []
         for i in range(cfg["n_layers"]):
             ks = jax.random.split(keys[i], 8)
-            layers.append(
-                {
-                    "attn": {
-                        "wq": dense(ks[0], d, (d, n_heads * head_dim)),
-                        "wk": dense(ks[1], d, (d, n_kv * head_dim)),
-                        "wv": dense(ks[2], d, (d, n_kv * head_dim)),
-                        "wo": dense(ks[3], n_heads * head_dim, (n_heads * head_dim, d)),
-                    },
-                    "moe": {
-                        "router": dense(ks[4], d, (d, e)),
-                        "w1": dense(ks[5], d, (e, d, ff)),
-                        "w2": dense(ks[6], ff, (e, ff, d)),
-                        "w3": dense(ks[7], d, (e, d, ff)),
-                    },
-                    "ln1": jnp.ones((d,), jnp.float32),
-                    "ln2": jnp.ones((d,), jnp.float32),
+            q = query_heads(cfg, i) * head_dim
+            layer = {
+                "attn": {
+                    "wq": dense(ks[0], d, (d, q)),
+                    "wk": dense(ks[1], d, (d, n_kv * head_dim)),
+                    "wv": dense(ks[2], d, (d, n_kv * head_dim)),
+                    "wo": dense(ks[3], q, (q, d)),
+                },
+                "ln1": jnp.ones((d,), jnp.float32),
+                "ln2": jnp.ones((d,), jnp.float32),
+            }
+            if i in dense_layers:
+                layer["mlp"] = swiglu(ks[4], cfg["d_ff_dense"])
+            else:
+                layer["moe"] = {
+                    "router": dense(ks[4], d, (d, cfg["n_experts"])),
+                    "w1": dense(ks[5], d, (e, d, ff)),
+                    "w2": dense(ks[6], ff, (e, ff, d)),
+                    "w3": dense(ks[7], d, (e, d, ff)),
                 }
-            )
+                if cfg.get("shared_width"):
+                    layer["moe"]["shared"] = swiglu(
+                        jax.random.fold_in(ks[5], 1), cfg["shared_width"])
             if cfg["qk_norm"]:
-                layers[-1]["attn"]["q_norm"] = jnp.ones((n_heads * head_dim,), jnp.float32)
-                layers[-1]["attn"]["k_norm"] = jnp.ones((n_kv * head_dim,), jnp.float32)
+                layer["attn"]["q_norm"] = jnp.ones((q,), jnp.float32)
+                layer["attn"]["k_norm"] = jnp.ones((n_kv * head_dim,), jnp.float32)
+            if cfg.get("attn_gate") == "head":    # one value a head
+                layer["attn"]["w_gate"] = dense(
+                    jax.random.fold_in(ks[0], 1), d, (d, q // head_dim))
+            layers.append(layer)
         params = {
             "embed": dense(keys[-1], d, (v, d)),
             "layers": layers,

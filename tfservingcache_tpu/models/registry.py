@@ -169,6 +169,18 @@ def head_width(cfg: Mapping[str, Any]) -> int:
                int(cfg["d_model"]) // int(cfg["n_heads"]))
 
 
+def query_heads(cfg: Mapping[str, Any], depth: int | None = None) -> int:
+    """How many query heads layer ``depth`` has: ``n_heads_per_layer[depth]``
+    where the config states a count a layer (window layers of 72 beside global
+    ones of 48 over the same KV heads), else ``n_heads`` in every layer.
+    ``depth`` None = the most any layer has. The row a layer keeps does not
+    follow it: the KV side is ``kv_cache_row``'s in every layer."""
+    per_layer = cfg.get("n_heads_per_layer")
+    if not per_layer:
+        return int(cfg["n_heads"])
+    return int(max(per_layer) if depth is None else per_layer[depth])
+
+
 def kv_cache_row(cfg: Mapping[str, Any], window: int = 0) -> CacheRow:
     """The decoder-LM families' row: K and V of ``(n_kv_heads, head_width)``;
     ``window`` > 0 for a layer that keeps the last ``window`` rows only."""

@@ -29,6 +29,7 @@ from tfservingcache_tpu.models.registry import (
     TensorSpec,
     head_width,
     kv_cache_row,
+    query_heads,
     register,
 )
 from tfservingcache_tpu.ops.attention import attention
@@ -89,6 +90,11 @@ def _norm(holder: dict, name: str, x: jax.Array, eps: float = 1e-5) -> jax.Array
     return _rmsnorm(x, holder[name], eps)
 
 
+def plain_frequencies(d: int, theta: float) -> np.ndarray:
+    """The ``d / 2`` plain rotary frequencies ``theta^(-2i/d)``, float64."""
+    return float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+
+
 def yarn_frequencies(d: int, theta: float, factor: float, original_max: float,
                      beta_fast: float, beta_slow: float) -> np.ndarray:
     """The ``d / 2`` rotary frequencies of a ``d``-wide head: plain
@@ -96,7 +102,7 @@ def yarn_frequencies(d: int, theta: float, factor: float, original_max: float,
     that turn more than ``beta_fast`` times within ``original_max`` positions)
     with the same divided by ``factor`` (fewer than ``beta_slow`` turns), a
     linear ramp between; the same blend at every position."""
-    extra = float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    extra = plain_frequencies(d, theta)
     if float(factor) == 1.0:
         return extra.astype(np.float32)
 
@@ -113,26 +119,39 @@ def yarn_frequencies(d: int, theta: float, factor: float, original_max: float,
 
 def rope_of(cfg: dict, window: int = 0) -> tuple:
     """The rotary of one layer of ``cfg``'s model -> ``(frequencies | None,
-    factor)``: None = the plain ``rope_theta`` frequencies computed where they
-    are applied (every model with one rotary, bit for bit as before). A model
-    with a rotary a layer KIND states the global layers' under ``rope_full``
-    (``yarn``: the factor, ``original_max``, ``beta_fast``, ``beta_slow``,
-    ``attention_factor``, which multiplies cos and sin); its window layers
-    (``window`` > 0) keep the plain frequencies."""
+    factor, turned)``: None = the plain ``rope_theta`` frequencies computed
+    where they are applied (every model with one rotary, bit for bit as
+    before); ``turned`` = the leading columns of a head that turn, 0 = all of
+    them. A model with a rotary a layer KIND states the global layers' under
+    ``rope_full`` (``yarn``: the factor, ``original_max``, ``beta_fast``,
+    ``beta_slow``, ``attention_factor``, which multiplies cos and sin;
+    ``partial``: the share of a head that turns, the frequencies and YaRN's
+    ramp then taken over those columns alone); its window layers (``window``
+    > 0) keep plain frequencies, at ``rope_theta_window`` where the config
+    states a theta of their own."""
     full = dict(cfg.get("rope_full") or ())
     if window or not full:
-        return None, 1.0
+        theta = cfg.get("rope_theta_window") if window else None
+        if theta is None:
+            return None, 1.0, 0
+        return plain_frequencies(head_width(cfg), theta).astype(np.float32), 1.0, 0
+    width = head_width(cfg)
+    turned = int(width * float(full.get("partial", 1.0)))
     freqs = yarn_frequencies(
-        head_width(cfg), cfg["rope_theta"], full["yarn"], full["original_max"],
+        turned, cfg["rope_theta"], full["yarn"], full["original_max"],
         full.get("beta_fast", 32.0), full.get("beta_slow", 1.0))
-    return freqs, float(full.get("attention_factor", 1.0))
+    return (freqs, float(full.get("attention_factor", 1.0)),
+            turned if turned < width else 0)
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float,
-          rope: tuple = (None, 1.0)) -> jax.Array:
+          rope: tuple = (None, 1.0, 0)) -> jax.Array:
     """Rotary embedding over (B, H, S, D); ``rope`` = ``rope_of``'s answer."""
     d = x.shape[-1]
-    freqs, factor = rope
+    freqs, factor, turned = rope
+    if turned:    # a partial rotary: the leading columns turn, the rest pass
+        head = _rope(x[..., :turned], positions, theta, (freqs, factor, 0))
+        return jnp.concatenate([head, x[..., turned:]], axis=-1)
     if freqs is None:
         freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (d/2,)
     angles = positions[:, None].astype(jnp.float32) * jnp.asarray(freqs)[None, :]
@@ -185,13 +204,28 @@ def _output_logits(params: dict, x: jax.Array, dtype,
     return (x @ params["embed"].astype(dtype).T).astype(jnp.float32)
 
 
+def head_gate(attn: dict, a: jax.Array, heads: int) -> jax.Array:
+    """The output gate of a layer whose ``attn`` holds ``w_gate``, from the
+    layer's normed input ``a (B, S, d)`` -> ``sigmoid(a w_gate)`` laid out as
+    the heads' outputs are, ``(B, heads, S, columns)``. The leaf's width
+    against the query side's says which gate it is: ``heads x head width``
+    columns are a value a head COLUMN, ``heads`` columns one value a HEAD
+    (``columns`` 1: it multiplies the head's whole output)."""
+    b, s, _ = a.shape
+    with jax.named_scope("gate"):
+        gate = (a @ attn["w_gate"]).reshape(b, s, heads, -1)
+        return jax.nn.sigmoid(gate).transpose(0, 2, 1, 3)
+
+
 @jax.named_scope("attn")
-def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None,  # static-bounded: mesh, window -- one Mesh object per runtime lifetime; one window per model config
-                     window: int = 0) -> jax.Array:
+def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None,  # static-bounded: mesh, window, depth -- one Mesh object per runtime lifetime; one window per model config; depth is the caller's unrolled loop index, below the model's depth
+                     window: int = 0, depth: int | None = None) -> jax.Array:
     """``window`` > 0: a window layer (a query reads itself and the
-    ``window - 1`` positions before it), with the rotary of its kind."""
+    ``window - 1`` positions before it), with the rotary of its kind.
+    ``depth``: the layer's index in a model whose query heads go by layer
+    (``registry.query_heads``)."""
     b, s, _ = x.shape
-    q, k, v = _qkv(params, x, cfg["n_heads"], cfg["n_kv_heads"])
+    q, k, v = _qkv(params, x, query_heads(cfg, depth), cfg["n_kv_heads"])
     positions = jnp.arange(s)
     rope = rope_of(cfg, window)
     q = _rope(q, positions, cfg["rope_theta"], rope)
@@ -216,6 +250,8 @@ def _attention_block(params: dict, x: jax.Array, cfg: dict, mesh=None,  # static
         out = attention(q, k, v, causal=True,
                         partitioned=mesh is not None and mesh.size > 1,
                         window=window)                                   # (b,h,s,hd)
+    if "w_gate" in params:
+        out = out.astype(x.dtype) * head_gate(params, x, q.shape[1])
     # heads x head width: the hidden size for most models, not for all
     out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return out @ params["wo"]

@@ -105,6 +105,46 @@ def main() -> int:
     # error of the output and of the state against the float64 step, every
     # other slice bit for bit: `-k "on_tpu_step_kernel"`, ~1 min (also matched
     # by `-k kda_rule_on_tpu` / `-k delta_rule_on_tpu`).
+    # test_laguna_moe_lm.py carries (PR 53) the shared kernels at Laguna-S-2.1's
+    # GQA groups, which no accepted cell runs: the paged decode kernel at 48 /
+    # 8 / 128 (group 6) and the window decode kernel at 72 / 8 / 128 (group 9:
+    # the first group that spills over one 8-row sublane tile without filling
+    # a second), window 512, 16-token pages, 1 to 32 live lanes of 2k-17k
+    # tokens, each beside group 8 (the accepted shape) against the gather +
+    # einsum reference and the HBM roofline, and both flash kernels at 48 and
+    # 72 heads over 2048 / 8192 / 16384 tokens against the reference and the
+    # bf16 roofline: `-k "laguna and on_tpu"`, ~4 min. WHAT THEY LED TO (my chip
+    # run, PR 53): Mosaic takes groups 6 and 9 as the kernels stand (compiled
+    # for a described v5e before any chip call, then run: no refusal), and no
+    # group is padded. Global decode at 1 / 4 / 16 live lanes: group 6 61.2 /
+    # 72.4 / 73.7 % of the HBM roofline, group 8 60.7 / 72.8 / 73.7, group 9
+    # 55.9 / 70.5 / 71.8; window decode at 1 / 4 / 16 / 32: group 6 16.8 / 36.4
+    # / 47.4 / 49.9, group 8 20.0 / 38.8 / 44.0 / 50.7, group 9 18.5 / 35.1 /
+    # 44.2 / 49.2: every row within a fifth of group 8's share, so
+    # `_paged_decode_call` pads nothing and no model code knows the group
+    # (padding 9 to 16 rows would only add MXU work to a memory-bound call).
+    # The flash kernels read the same share at 48 and 72 heads (41.7 / 53.9 /
+    # 25.7 % of the bf16 roofline at 2048 / 8192 / 16384 tokens, the window one
+    # 21.5 / 21.0 / 6.7): what falls at 16384 is the STREAMED variant
+    # (`flash_variant`: K and V of 16384 x 128 x 2 B pass
+    # `KV_RESIDENT_LIMIT_BYTES`), at any head count (PERF.md section 7).
+    # After review the file also carries the ROUTED half of the cell's expert
+    # layer at its own shapes (`moe_experts`, top 10 of 256 by sigmoid scores
+    # times 2.5, 32 experts of 3072 x 1024 held from 0 and from 96, a decode
+    # step of 2 / 4 / 32 live lanes and prefills of 2048 and 6144 real rows)
+    # against a float32 reference that shares no code with it (every held
+    # expert applied to every row, weighted by the row's gate or by zero):
+    # `-k "laguna_share_experts_on_tpu"`, ~3 min. WHAT IT READ (my chip run,
+    # PR 53): the grouped kernel taken at every shape (`pallas tm=128`, 256 at
+    # the prefills), largest error 0.0023-0.0056 under the limit of 0.02 where
+    # no routed output lies 0.76-1.57 away and the share one place round
+    # 1.32-2.03; the three routing counters equal the reference's counts; a
+    # step of 2 lanes neither of which chose an expert held here answers
+    # exactly zero. A step's call 0.154 ms at 4 live rows (5 experts hit, 611
+    # GB/s of their weights), 0.367 at 32 (23 hit, 1183 GB/s); a prefill of
+    # 2048 rows 3.85 ms, of 6144 in an 8192 bucket 17.3 ms: the sort and the
+    # gather of all `rows x 10` assignments, of which an eighth land here
+    # (PERF.md section 7, (c)).
     cmd = [
         sys.executable, "-m", "pytest",
         os.path.join(REPO, "tests", "test_attention.py"),
@@ -115,6 +155,7 @@ def main() -> int:
         os.path.join(REPO, "tests", "test_sambay_lm.py"),
         os.path.join(REPO, "tests", "test_olmo_hybrid_lm.py"),
         os.path.join(REPO, "tests", "test_kda_moe_lm.py"),
+        os.path.join(REPO, "tests", "test_laguna_moe_lm.py"),
         "-v", "-rs", "-s", "--no-header",
         *extra,
     ]
